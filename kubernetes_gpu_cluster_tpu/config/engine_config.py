@@ -27,7 +27,10 @@ class CacheConfig:
     # small test pools). Set explicitly to pin it.
     page_size: Optional[int] = None
     num_pages: Optional[int] = None    # explicit page count; None = derive from HBM
-    hbm_utilization: float = 0.90      # fraction of free HBM to give the KV cache
+    # Fraction the KV pool takes of the HBM that is free once the weights are
+    # resident and one step program's workspace is set aside
+    # (engine.step_workspace_bytes) — not of the whole chip.
+    hbm_utilization: float = 0.90
     dtype: Optional[str] = None        # KV dtype; None = model dtype
     # Host-DRAM second KV tier (vLLM swap-space parity): GB of host memory
     # for swapped-out pages. 0 (default) disables the tier entirely and is

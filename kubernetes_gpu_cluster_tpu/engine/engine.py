@@ -39,7 +39,8 @@ from ..ops.sampling import (apply_logit_bias, apply_penalties, build_counts,
 from ..resilience.faults import inject as _inject_fault
 from ..utils import cdiv, get_logger
 from .kv_cache import (KVCache, KVPageIO, KVTransferPrograms,
-                       allocate_kv_cache, build_kv_swapper, derive_num_pages)
+                       allocate_kv_cache, build_kv_swapper, derive_num_pages,
+                       kv_cache_dtype)
 from .sampling_params import LOGIT_BIAS_CAP, SamplingParams
 from .scheduler import ScheduledBatch, Scheduler
 from .sequence import FinishReason, Sequence, SequenceStatus
@@ -164,7 +165,29 @@ class LLMEngine:
         self.use_pallas = self._resolve_use_pallas(use_pallas)
         self._key = jax.random.key(config.seed)
 
+        params_sharding, kv_sharding = resolve_shardings(mesh, config.model)
+        if mesh is not None and self.pp_size > 1:
+            logger.info("pipeline-parallel serving: %s", dict(mesh.shape))
+
+        if params is None:
+            logger.info("initializing random weights for %s", config.model.name)
+            params = model_lib.init_params(config.model, jax.random.key(config.seed))
+        if params_sharding is not None:
+            params = jax.device_put(params, params_sharding)
+        self.params = jax.block_until_ready(params)
+
+        # The pool is sized from what is free ONCE THE WEIGHTS ARE RESIDENT
+        # (random-init and loaded alike): sizing first hands 0.9 of an empty
+        # chip to the pool and the weights then have nowhere to go.
+        # Under a mesh this reads the first local device, which holds 1/tp
+        # of the weights, against the UNSHARDED bytes per page — each chip
+        # stores only 1/tp of a page when kv heads divide tp, so a tp mesh's
+        # pool is sized conservatively (about 1/tp of what would fit).
         hbm_free = _device_free_memory()
+        if hbm_free is not None:
+            # ... and once the largest step program's own workspace is set
+            # aside: hbm_utilization applies to what the POOL can have.
+            hbm_free -= step_workspace_bytes(config)
         num_pages = derive_num_pages(
             config.model, config.cache, config.effective_max_len,
             config.scheduler.max_num_seqs, hbm_free)
@@ -193,16 +216,6 @@ class LLMEngine:
                 self.scheduler.qos.default_tier,
                 fallback_budget_ms=config.resilience.default_ttft_budget_ms)
 
-        params_sharding, kv_sharding = resolve_shardings(mesh, config.model)
-        if mesh is not None and self.pp_size > 1:
-            logger.info("pipeline-parallel serving: %s", dict(mesh.shape))
-
-        if params is None:
-            logger.info("initializing random weights for %s", config.model.name)
-            params = model_lib.init_params(config.model, jax.random.key(config.seed))
-        if params_sharding is not None:
-            params = jax.device_put(params, params_sharding)
-        self.params = params
         self.kv_cache = allocate_kv_cache(config.model, config.cache, num_pages,
                                           kv_sharding)
 
@@ -358,6 +371,29 @@ class LLMEngine:
         # KV occupancy both tiers) ride Observability.on_step; the source is
         # O(1) attribute reads, never a device sync (KGCT012).
         self.obs.flight.set_snapshot_source(self._flight_snapshot)
+        logger.info("engine ready: %s", self.runtime_info())
+
+    def runtime_info(self) -> dict:
+        """What this engine actually runs on and with — the device as JAX
+        reports it, the kernels that were proved at construction, and the
+        pool. Logged once at start-up and served on /health, so a result
+        can always be tied to the device and the kernels behind it (a CPU
+        run or an XLA-attention run must never pass for the chip)."""
+        dev = jax.devices()[0]
+        info = {
+            "platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count(),
+            "mesh": dict(self.mesh.shape) if self.mesh is not None else None,
+            "dtype": self.model_config.dtype,
+            "quantization": self.model_config.quantization,
+            "use_pallas": self.use_pallas,
+            "use_pallas_hist": self.use_pallas_hist,
+            "num_pages": self.scheduler.allocator.num_pages,
+            "page_size": self.config.cache.page_size,
+        }
+        if self.pallas_disabled_reason is not None:
+            info["pallas_disabled_reason"] = self.pallas_disabled_reason
+        return info
 
     def _flight_snapshot(self) -> dict:
         sched = self.scheduler
@@ -401,18 +437,21 @@ class LLMEngine:
 
     def _resolve_use_pallas(self, use_pallas: Optional[bool]) -> bool:
         """Decide the kernel path ONCE, at init, from static facts — backend,
-        mesh sharding, lane alignment. Mosaic constraint violations surface at
-        jit-COMPILE time, after tracing succeeded, so the dispatchers' trace-
-        time try/except cannot catch them; deciding eagerly avoids a crash
-        deep in the first step.
+        mesh sharding, lane alignment — and PROVE it: on a TPU every kernel
+        the engine is eligible to use is compiled here at the geometry it
+        will serve, and a kernel that does not compile fails construction
+        with the compiler's message. There is no fallback: a refused kernel
+        that degraded to a warning once put XLA gather attention into the
+        record as the system. The two eligibility decisions below (heads
+        not divisible by tp, lane not 128-aligned) stay explicit choices;
+        their reason is kept in ``pallas_disabled_reason`` and reported on
+        /health.
 
-        Probe granularity matches what the configured engine actually runs:
-        the decode kernel gates everything (every path decodes); the ragged-
-        prefill kernel is probed unless sp>1 (ring attention replaces it);
-        the history-prefill kernel has its OWN flag (self.use_pallas_hist,
-        meshless engines only) so a hist-only Mosaic failure costs just the
-        rare chunked-prefill fast path, not the 1.7-1.9x decode speedup."""
+        The history-prefill kernel has its OWN flag (use_pallas_hist): it
+        is ineligible under pp/sp meshes, where chunked prefill keeps the
+        XLA path while decode keeps its kernel."""
         self.use_pallas_hist = False
+        self.pallas_disabled_reason: Optional[str] = None
         if use_pallas is not None:
             self.use_pallas_hist = use_pallas and self._hist_kernel_eligible()
             return use_pallas
@@ -420,26 +459,25 @@ class LLMEngine:
             return False
         cfg = self.model_config
         tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
-        if cfg.num_heads % tp or cfg.num_kv_heads % tp:
-            logger.warning(
-                "Pallas kernels disabled: heads (%d q / %d kv) not divisible "
-                "by tp=%d; using XLA attention", cfg.num_heads,
-                cfg.num_kv_heads, tp)
-            return False
         lane = (cfg.num_kv_heads * cfg.head_dim) // tp
-        if lane % 128 != 0:
-            logger.warning(
-                "Pallas kernels disabled: per-shard KV lane dim %d (n_kv*hd/tp)"
-                " is not 128-aligned; using XLA attention", lane)
+        if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+            self.pallas_disabled_reason = (
+                f"heads ({cfg.num_heads} q / {cfg.num_kv_heads} kv) not "
+                f"divisible by tp={tp}")
+        elif lane % 128:
+            self.pallas_disabled_reason = (
+                f"per-shard KV lane dim {lane} (n_kv*hd/tp) is not "
+                "128-aligned")
+        if self.pallas_disabled_reason is not None:
+            logger.warning("Pallas kernels disabled: %s; using XLA attention",
+                           self.pallas_disabled_reason)
             return False
         # Under a mesh the kernels run per-shard inside shard_map — the tp
         # wrappers (ops.attention.*_tp) for GSPMD serving, or the pipeline's
         # own shard_map body for pp>1 — so the probes compile the kernels at
         # the PER-SHARD head geometry each device will actually build.
-        if not self._probe_pallas_compile(tp):
-            return False
-        if self._hist_kernel_eligible():
-            self.use_pallas_hist = self._probe_hist_compile(tp)
+        self._probe_pallas_compile(tp)
+        self.use_pallas_hist = self._hist_kernel_eligible()
         return True
 
     def _hist_kernel_eligible(self) -> bool:
@@ -451,92 +489,68 @@ class LLMEngine:
         group — both keep the XLA path."""
         return self.pp_size == 1 and self.sp_size == 1
 
-    def _probe_shapes(self, tp: int):
-        """Tiny probe inputs at the per-shard head geometry. pps >= the
-        decode kernel's DERIVED chunk_pages (max(1, 128 // page_size)): the
-        kernel caps its chunk at min(chunk_pages, pps), so a probe with
-        smaller pps would compile a different (smaller-scratch) kernel than
-        serving runs and could pass while the real configuration fails.
-        pps=8 covers the derivation for every page_size >= 16. The pool is
-        stacked [L, P, ps, kd] with a dynamic layer index — the variant
-        serving actually runs."""
-        cfg = dataclasses.replace(
-            self.model_config,
-            num_heads=self.model_config.num_heads // tp,
-            num_kv_heads=self.model_config.num_kv_heads // tp)
-        ps = self.config.cache.page_size
-        B, pps, T = 4, 8, 128
-        kd = cfg.num_kv_heads * cfg.head_dim
-        return dict(
-            cfg=cfg, scale=cfg.head_dim ** -0.5,
-            q=jnp.zeros((B, cfg.num_heads, cfg.head_dim), cfg.jnp_dtype),
-            pool=jnp.zeros((2, 2, ps, kd), cfg.jnp_dtype),
-            tables=jnp.zeros((B, pps), jnp.int32),
-            ctx=jnp.ones((B,), jnp.int32),
-            cur=jnp.zeros((B, cfg.num_kv_heads, cfg.head_dim), cfg.jnp_dtype),
-            qf=jnp.zeros((T, cfg.num_heads, cfg.head_dim), cfg.jnp_dtype),
-            kf=jnp.zeros((T, cfg.num_kv_heads, cfg.head_dim), cfg.jnp_dtype),
-            seg=jnp.zeros((T,), jnp.int32),
-            pos=jnp.arange(T, dtype=jnp.int32))
-
-    def _probe_pallas_compile(self, tp: int = 1) -> bool:
-        """Compile one tiny call of the decode kernel — and, unless ring
-        attention replaces it (sp>1), the ragged-prefill kernel — ON THE REAL
-        CHIP before committing to the Pallas path. Mosaic layout constraints
-        surface only at jit-compile time (round-2 postmortem: the static lane
-        check passed, the kernel did not compile, and the engine had no
-        fallback), so the only reliable gate is an actual compile. Under a
-        mesh the tp wrappers call the kernels with no runtime fallback, so
-        both probed kernels must pass. ~2s for the tiny shapes, paid once
-        per engine construction (serving builds one engine per process)."""
+    def _probe_pallas_compile(self, tp: int = 1) -> None:
+        """Compile every Pallas kernel this engine will run ON THE CHIP
+        before committing to it, at the per-shard head geometry and the
+        LARGEST shapes the scheduler can dispatch (top decode bucket, full
+        page-table width, top prefill bucket): Mosaic refuses a kernel for
+        VMEM or tiling only at jit-compile time, and a small probe can pass
+        where B=64 / T=2048 is refused. Abstract shapes — nothing is
+        allocated. Raises with the compiler's message on failure."""
         from ..ops.pallas.flash_prefill import flash_ragged_prefill
+        from ..ops.pallas.flash_prefill_hist import flash_prefill_history
         from ..ops.pallas.paged_decode import pallas_paged_decode
 
-        s = self._probe_shapes(tp)
-        scale = s["scale"]
-        try:
-            jax.jit(lambda *a: pallas_paged_decode(
-                *a, scale, layer=jnp.zeros((1,), jnp.int32))).lower(
-                    s["q"], s["pool"], s["pool"], s["tables"], s["ctx"],
-                    s["cur"], s["cur"]).compile()
-        except Exception as e:  # Mosaic errors are plain XlaRuntimeError
-            logger.warning(
-                "Pallas decode kernel failed probe compile (%s); "
-                "falling back to XLA attention", e)
-            return False
-        if self.sp_size == 1:
+        cfg = self.model_config
+        sc = self.config.scheduler
+        nh, nkv, hd = cfg.num_heads // tp, cfg.num_kv_heads // tp, cfg.head_dim
+        ps = self.config.cache.page_size
+        pps = cdiv(self.config.effective_max_len, ps)
+        B, T = sc.decode_buckets[-1], sc.prefill_buckets[-1]
+        scale = hd ** -0.5
+
+        def arr(shape, dtype=cfg.jnp_dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        i32 = jnp.int32
+        # Stacked [L, P, ps, kd] pool with a dynamic layer index — the
+        # variant serving runs; P does not shape the kernel.
+        pool = arr((2, 2, ps, nkv * hd),
+                   kv_cache_dtype(cfg, self.config.cache))
+        compiled = []
+
+        def probe(name, fn, *args):
             try:
-                jax.jit(lambda *a: flash_ragged_prefill(*a, scale)).lower(
-                    s["qf"], s["kf"], s["kf"], s["seg"], s["pos"]).compile()
+                jax.jit(fn).lower(*args).compile()
             except Exception as e:
-                logger.warning(
-                    "Pallas prefill kernel failed probe compile (%s); "
-                    "falling back to XLA attention", e)
-                return False
-        return True
+                raise RuntimeError(
+                    f"Pallas kernel {name} failed to compile at the served "
+                    f"geometry (heads {nh}q/{nkv}kv x {hd}, page_size {ps}, "
+                    f"pages/seq {pps}, B={B}, T={T}): {e}") from e
+            compiled.append(name)
 
-    def _probe_hist_compile(self, tp: int = 1) -> bool:
-        """The history-prefill kernel compiles lazily at the first long
-        prompt — probe it at init (per-shard geometry under a tp mesh) so a
-        Mosaic failure surfaces here and disables ONLY the chunked-prefill
-        fast path (the XLA fallback is correct, and decode keeps its
-        kernels)."""
-        from ..ops.pallas.flash_prefill_hist import flash_prefill_history
-
-        s = self._probe_shapes(tp)
-        scale = s["scale"]
-        try:
-            jax.jit(lambda *a: flash_prefill_history(
-                *a, scale, layer=jnp.zeros((), jnp.int32))).lower(
-                    s["qf"], s["kf"], s["kf"], s["seg"], s["pos"],
-                    s["pool"], s["pool"], s["tables"][0],
-                    jnp.ones((), jnp.int32)).compile()
-        except Exception as e:
-            logger.warning(
-                "Pallas history-prefill kernel failed probe compile (%s); "
-                "chunked prefill uses the XLA path", e)
-            return False
-        return True
+        probe("paged_decode",
+              lambda q, kp, vp, tb, ctx, kc, vc, lyr: pallas_paged_decode(
+                  q, kp, vp, tb, ctx, kc, vc, scale, layer=lyr),
+              arr((B, nh, hd)), pool, pool, arr((B, pps), i32),
+              arr((B,), i32), arr((B, nkv, hd)), arr((B, nkv, hd)),
+              arr((1,), i32))
+        if self.sp_size == 1:   # ring attention replaces it under sp
+            probe("flash_prefill",
+                  lambda q, k, v, seg, pos: flash_ragged_prefill(
+                      q, k, v, seg, pos, scale),
+                  arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hd)),
+                  arr((T,), i32), arr((T,), i32))
+        if self._hist_kernel_eligible():
+            probe("flash_prefill_hist",
+                  lambda q, k, v, seg, pos, kp, vp, pt, hl, lyr:
+                  flash_prefill_history(q, k, v, seg, pos, kp, vp, pt, hl,
+                                        scale, layer=lyr),
+                  arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hd)),
+                  arr((T,), i32), arr((T,), i32), pool, pool,
+                  arr((pps,), i32), arr((), i32), arr((), i32))
+        logger.info("Pallas kernels compiled at the served geometry: %s",
+                    ", ".join(compiled))
 
     def _gspmd_attn_mesh(self):
         """The mesh to run Pallas attention under (shard_map tp wrappers) in
@@ -2310,29 +2324,60 @@ def _stomp_committed_slot(batch, page_size: int, S: int,
 
 
 def _device_free_memory() -> Optional[int]:
-    """Free HBM bytes on the first addressable device, when the backend
-    reports it (TPU does; CPU returns None -> test-sized pool)."""
-    try:
-        dev = jax.local_devices()[0]
-        stats = dev.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
-    except Exception:
-        pass
-    return None
+    """Free HBM bytes on the first addressable device. The CPU backend
+    keeps no memory statistics -> None -> test-sized pool
+    (kv_cache.derive_num_pages). On an accelerator a missing statistic is
+    an error: guessing there would size a 38 GB pool on a 16 GB chip."""
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+    if dev.platform == "cpu":
+        return None
+    raise RuntimeError(
+        f"{dev.device_kind} ({dev.platform}) reports no memory_stats(); "
+        "cannot size the KV pool — set CacheConfig.num_pages explicitly")
 
 
-def device_memory_stats() -> tuple:
-    """(bytes_limit, bytes_in_use) of the first addressable device — the
-    ``kgct_hbm_bytes_{limit,in_use}`` gauges. (0, 0) when the backend
-    reports nothing (CPU) so a fresh scrape is nan-free by construction;
-    reading the runtime's counters is a host-side C call, never a device
-    sync."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            return (int(stats.get("bytes_limit", 0) or 0),
-                    int(stats.get("bytes_in_use", 0) or 0))
-    except Exception:
-        pass
-    return (0, 0)
+def step_workspace_bytes(config: EngineConfig) -> int:
+    """Upper estimate of the HBM one step program needs BESIDE the weights
+    and the pool — set aside before the pool takes its share, or the first
+    full prefill bucket is a compile-time OOM (qwen3-4b on a 16 GB v5e: the
+    pool at 0.90 of everything free left 0.58 GB, the 2048-token chunk
+    program needs more). Counted at the largest shapes the scheduler
+    dispatches, unsharded (conservative under a mesh):
+
+    - the K/V rows the layer scan hands the post-scan pool write;
+    - the widest layer intermediates in f32 plus their model-dtype copy
+      (MLP gate/up/act over ``ff`` — dense-dispatch MoE runs EVERY expert
+      over every token — and q/k/v/attention-out over the heads);
+    - residual-stream copies; and
+    - the ``[rows, vocab]`` f32 sampling buffers (logits, penalties
+      histogram, sort/top-k scratch) at the top decode bucket."""
+    m, sc = config.model, config.scheduler
+    T = sc.prefill_buckets[-1] + sc.decode_buckets[-1]   # mixed step width
+    B = sc.decode_buckets[-1]
+    it = m.jnp_dtype.itemsize
+    kd = m.num_kv_heads * m.head_dim
+    kv_rows = (2 * m.num_layers * T * kd
+               * kv_cache_dtype(m, config.cache).itemsize)
+    mlp = max(m.num_experts, 1) * T * m.intermediate_size * (4 + 4 + it)
+    attn = T * (m.num_heads * m.head_dim + kd) * 2 * (4 + it)
+    resid = 4 * T * m.hidden_size * 4
+    sampling = 8 * B * m.vocab_size * 4
+    return kv_rows + mlp + attn + resid + sampling
+
+
+def device_memory_stats() -> list[tuple[int, int]]:
+    """(bytes_limit, bytes_in_use) of every addressable device, in device
+    order — the ``kgct_hbm_bytes_{limit,in_use}`` gauges read the first,
+    /health reports all (a tp mesh must show its shards balanced). (0, 0)
+    where the backend keeps no statistics (CPU), so a fresh scrape is
+    nan-free by construction; reading the runtime's counters is a
+    host-side C call, never a device sync."""
+    out = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        out.append((int(stats.get("bytes_limit", 0) or 0),
+                    int(stats.get("bytes_in_use", 0) or 0)))
+    return out

@@ -70,13 +70,18 @@ class KVCache(NamedTuple):
         return self.k.shape[2]
 
 
+def kv_cache_dtype(model: ModelConfig, cache: CacheConfig):
+    """The pool's element type: ``cache.dtype`` when set, else the model's."""
+    return jnp.dtype(cache.dtype) if cache.dtype else model.jnp_dtype
+
+
 def allocate_kv_cache(
     model: ModelConfig,
     cache: CacheConfig,
     num_pages: int,
     sharding: Optional[jax.sharding.Sharding] = None,
 ) -> KVCache:
-    dtype = jnp.dtype(cache.dtype) if cache.dtype else model.jnp_dtype
+    dtype = kv_cache_dtype(model, cache)
     shape = (model.num_layers, num_pages, cache.page_size,
              model.num_kv_heads * model.head_dim)
     def mk():
@@ -88,8 +93,8 @@ def allocate_kv_cache(
 
 
 def kv_cache_bytes_per_page(model: ModelConfig, cache: CacheConfig) -> int:
-    dtype = jnp.dtype(cache.dtype) if cache.dtype else model.jnp_dtype
-    per_tok = model.num_kv_heads * model.head_dim * dtype.itemsize
+    per_tok = (model.num_kv_heads * model.head_dim
+               * kv_cache_dtype(model, cache).itemsize)
     return 2 * model.num_layers * cache.page_size * per_tok
 
 

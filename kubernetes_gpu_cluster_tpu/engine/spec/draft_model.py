@@ -157,11 +157,20 @@ class DraftModelRunner(DraftProposer):
         self.chunk_buckets = tuple(
             b for b in (16, 32, 64, 128, 256, 512)
             if b <= max(next_power_of_2(self.max_len), 16))
+        if params is None:
+            # Random init in the draft's own dtype — the bench/test path,
+            # like the target engine. Real checkpoints arrive via
+            # --spec-draft-weights (engine/weights.load_weights).
+            init_seed = config.seed if seed is None else seed
+            params = model_lib.init_params(draft_config,
+                                           jax.random.key(init_seed))
+        self.params = jax.block_until_ready(params)
         draft_cache = CacheConfig(page_size=self.page_size)
         # Draft pool sizing: full coverage (max_num_seqs full-horizon
         # sequences) CAPPED by what actually fits the device — the runner
         # is built AFTER the target pool claimed its hbm_utilization share
-        # of free HBM, so at most half the REMAINDER goes to draft KV. On
+        # of free HBM and after the draft weights above are resident, so at
+        # most half of what is STILL free goes to draft KV. On
         # a production pairing (tinyllama drafting for 8B at
         # max_num_seqs=128 x 8k context) full coverage would be tens of
         # GB; the cap keeps construction alive and rows the pool cannot
@@ -183,14 +192,6 @@ class DraftModelRunner(DraftProposer):
         self.kv_cache = allocate_kv_cache(draft_config, draft_cache,
                                           num_pages)
         self.allocator = PageAllocator(num_pages, self.page_size)
-        if params is None:
-            # Random init in the draft's own dtype — the bench/test path,
-            # like the target engine. Real checkpoints arrive via
-            # --spec-draft-weights (engine/weights.load_weights).
-            init_seed = config.seed if seed is None else seed
-            params = model_lib.init_params(draft_config,
-                                           jax.random.key(init_seed))
-        self.params = params
         self._jit = jit_enabled
         self._decode_fn = self._build_decode_fn()
         self._prefill_fn = self._build_prefill_fn()

@@ -473,7 +473,11 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     shard_map (parallel/pp.py); under GSPMD they stay None and the SPMD
     partitioner inserts the equivalent collectives.
 
-    Returns (h, k_all, v_all) with k_all/v_all: [L, T, n_kv_local, hd].
+    Returns (h, k_all, v_all) with k_all/v_all: [L, T, n_kv_local * hd] —
+    heads flattened per layer, the pool's own row layout, so the post-scan
+    write consumes the scan's output buffers as they are (flattening the
+    stacked [L, T, n_kv, hd] afterwards is a relayout: a second full copy
+    of both, 2 x 144 MB at qwen3-4b T=2048, while the pool leaves no room).
     """
     layers = params["layers"]
     if layer_slice is not None:
@@ -497,7 +501,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         x = _norm(cfg, h, lp, "post_attn_norm")
         h = resid + _mlp_block(lp, cfg, x, tp_axis=tp_axis, ep_axis=ep_axis,
                                use_pallas=use_pallas)
-        return h, (k, v)
+        return h, (k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1))
 
     n_layers = jax.tree.leaves(layers)[0].shape[0]
     h, (k_all, v_all) = jax.lax.scan(

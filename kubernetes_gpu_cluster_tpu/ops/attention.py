@@ -19,22 +19,14 @@ that select the Pallas TPU kernels from ``ops.pallas`` when running on TPU.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from ..utils import get_logger
 
-logger = get_logger("ops.attention")
-
-
-def _on_tpu(x: jax.Array | None = None) -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -44,27 +36,31 @@ def _on_tpu(x: jax.Array | None = None) -> bool:
 def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
                        k_all: jax.Array, v_all: jax.Array,
                        slot_mapping: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Scatter every layer's new K/V vectors into the page pool at once.
+    """Write every layer's new K/V vectors into the page pool at once.
 
     kv_k/kv_v:    [L, P, page_size, n_kv*hd] (the whole pool, heads flattened)
-    k_all/v_all:  [L, T, n_kv, hd] (stacked per-layer new entries, the ys of
-                  the layer scan)
+    k_all/v_all:  [L, T, n_kv*hd] (stacked per-layer new entries, heads
+                  flattened: the ys of the layer scan)
     slot_mapping: [T] int32 flat slot = page_id * page_size + offset.
                   Padding tokens carry slots inside the scrap page 0.
 
-    CRITICAL perf property: this runs OUTSIDE the layer scan on the donated
-    pool, so XLA performs it in place (~0 cost). Threading the pool through
-    the scan as carry/ys forces a full pool copy per step (~4 ms per 200 MB
-    pool on v5e) — that architecture was measured and rejected; attention
-    instead reads the pool pre-write and takes the current token's K/V
-    separately (see paged_decode_attention).
+    CRITICAL property: this runs OUTSIDE the layer scan on the donated pool
+    and must update it IN PLACE — the served pool takes ~0.9 of the HBM the
+    weights leave free, so a single pool-sized temporary is an OOM, not a
+    slowdown. Threading the pool through the scan as carry/ys forces a full
+    pool copy per step; attention instead reads the pool pre-write and takes
+    the current token's K/V separately (see paged_decode_attention).
 
-    Strategy switch (measured on v5e, L=22 kd=256): XLA lowers a batched
-    row-scatter to ~9 ms regardless of T, while a fori_loop of per-token
-    dynamic_update_slices on the donated pool costs ~22 us/token. Decode
-    batches (T<=256) therefore use the loop (1.4 ms at T=64 — was the single
-    largest component of the decode substep); big prefill flushes keep the
-    one-shot scatter.
+    One formulation for every T: a fori_loop of per-token
+    dynamic_update_slices, which XLA performs in place in the pool's own
+    layout. The batched row-scatter (``.at[:, slots].set``) is NOT in place
+    on TPU: the scatter wants the slot axis major-most, so XLA transposes
+    the whole pool to that layout and back, and pinning the pool's layout
+    with ``with_layout_constraint`` still leaves one pool-sized pre-copy at
+    kd=1024 (PR 21, v5e: ``copy.44 = bf16[36,54016,1024]{2,0,1}``, 4.12 GB,
+    compile-time OOM in the first chunked-prefill step of qwen3-4b). What
+    the loop costs per 2048-token flush is in PERF.md; a page-granular DMA
+    write is the known faster design.
     """
     L, P, ps, kd = kv_k.shape
     T = k_all.shape[1]
@@ -72,40 +68,16 @@ def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
     fv = kv_v.reshape(L, P * ps, kd)
     k_rows = k_all.reshape(L, T, kd).astype(kv_k.dtype)
     v_rows = v_all.reshape(L, T, kd).astype(kv_v.dtype)
-    if T <= 256:
-        def body(i, kv):
-            fk, fv = kv
-            kr = jax.lax.dynamic_slice_in_dim(k_rows, i, 1, axis=1)
-            vr = jax.lax.dynamic_slice_in_dim(v_rows, i, 1, axis=1)
-            fk = jax.lax.dynamic_update_slice(fk, kr, (0, slot_mapping[i], 0))
-            fv = jax.lax.dynamic_update_slice(fv, vr, (0, slot_mapping[i], 0))
-            return fk, fv
-        fk, fv = jax.lax.fori_loop(0, T, body, (fk, fv))
-    else:
-        # Without a layout pin, XLA transposes the WHOLE pool to its
-        # preferred scatter layout and back ({3,2,1,0}->{3,0,2,1}->...): 4
-        # pool-sized copies per prefill flush (~4.4 GB HBM traffic on the 1B
-        # pool). Pinning operands+results to the donated buffer's default
-        # layout removes ALL pool copies on the 1B config (compile-verified,
-        # interleaved A/B r5: prefill no worse / slightly better, decode
-        # within drift). On the 8B W=48 geometry the scatter's preference
-        # survives as one pre-copy, so that geometry stays HBM-bound —
-        # W=32/budget-2048 remains the 8B fit. KGCT_POOL_LAYOUT_PIN=0
-        # reverts.
-        if os.environ.get("KGCT_POOL_LAYOUT_PIN", "1") != "0" \
-                and jax.default_backend() == "tpu" \
-                and jax.device_count() == 1:
-            # Single-chip only: under meshes GSPMD owns placement (per-shard
-            # copies are proportionally smaller there anyway).
-            from jax.experimental.layout import Layout, with_layout_constraint
-            fmt = Layout((0, 1, 2))
-            fk, fv = with_layout_constraint((fk, fv), (fmt, fmt))
-            fk = fk.at[:, slot_mapping].set(k_rows)
-            fv = fv.at[:, slot_mapping].set(v_rows)
-            fk, fv = with_layout_constraint((fk, fv), (fmt, fmt))
-        else:
-            fk = fk.at[:, slot_mapping].set(k_rows)
-            fv = fv.at[:, slot_mapping].set(v_rows)
+
+    def body(i, kv):
+        fk, fv = kv
+        kr = jax.lax.dynamic_slice_in_dim(k_rows, i, 1, axis=1)
+        vr = jax.lax.dynamic_slice_in_dim(v_rows, i, 1, axis=1)
+        fk = jax.lax.dynamic_update_slice(fk, kr, (0, slot_mapping[i], 0))
+        fv = jax.lax.dynamic_update_slice(fv, vr, (0, slot_mapping[i], 0))
+        return fk, fv
+
+    fk, fv = jax.lax.fori_loop(0, T, body, (fk, fv))
     return fk.reshape(kv_k.shape), fv.reshape(kv_v.shape)
 
 
@@ -339,46 +311,33 @@ def spec_verify_attention(q, k, v, k_pool, v_pool, page_tables, context_lens,
 # ---------------------------------------------------------------------------
 
 def ragged_prefill_attention(q, k, v, seg_ids, positions, scale, *,
-                             use_pallas=None, strict=False):
-    """``strict=True`` disables the XLA fallback: a kernel trace failure
-    propagates instead of being swallowed. The driver's compile check uses it
-    so a broken kernel fails the check rather than silently passing on the
-    fallback (the round-3 hole: NBUF NameError shipped because every caller
-    caught it)."""
+                             use_pallas=None):
+    """``use_pallas=True`` means the kernel or its exception — there is no
+    XLA fallback behind it (a swallowed kernel failure once put XLA gather
+    attention into the record as the system). None = Pallas on TPU."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
-        try:
-            from .pallas.flash_prefill import flash_ragged_prefill
-            return flash_ragged_prefill(q, k, v, seg_ids, positions, scale)
-        except Exception as e:  # pragma: no cover - fallback safety
-            if strict:
-                raise
-            logger.warning("pallas prefill unavailable (%s); falling back to XLA", e)
+        from .pallas.flash_prefill import flash_ragged_prefill
+        return flash_ragged_prefill(q, k, v, seg_ids, positions, scale)
     return ragged_prefill_attention_xla(q, k, v, seg_ids, positions, scale)
 
 
 def prefill_history_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
                               page_table, hist_len, scale, *, layer=None,
-                              use_pallas=None, strict=False):
+                              use_pallas=None):
     """Chunked-prefill dispatcher: Pallas flash kernel on TPU (streams only
-    the valid history pages), XLA gather fallback elsewhere. Single-device /
+    the valid history pages), XLA gather elsewhere. Single-device /
     shard_map-manual paths only — GSPMD tp meshes use
-    :func:`prefill_history_attention_tp`; pp meshes keep the XLA fallback
+    :func:`prefill_history_attention_tp`; pp meshes keep the XLA path
     (the pool's layer axis is pp-sharded, outside the tp wrapper's specs)."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
-        try:
-            from .pallas.flash_prefill_hist import flash_prefill_history
-            return flash_prefill_history(q, k, v, seg_ids, positions,
-                                         k_pool, v_pool, page_table,
-                                         hist_len, scale, layer=layer)
-        except Exception as e:  # pragma: no cover - fallback safety
-            if strict:
-                raise
-            logger.warning("pallas history prefill unavailable (%s); "
-                           "falling back to XLA", e)
+        from .pallas.flash_prefill_hist import flash_prefill_history
+        return flash_prefill_history(q, k, v, seg_ids, positions,
+                                     k_pool, v_pool, page_table,
+                                     hist_len, scale, layer=layer)
     return prefill_history_attention_xla(q, k, v, seg_ids, positions,
                                          k_pool, v_pool, page_table,
                                          hist_len, scale, layer=layer)
@@ -386,23 +345,17 @@ def prefill_history_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
 
 def paged_decode_attention(q, k_cache_l, v_cache_l, page_tables, context_lens,
                            k_cur, v_cur, scale, *, layer=None,
-                           use_pallas=None, strict=False):
+                           use_pallas=None):
     """``layer`` (with a stacked [L, P, ps, n_kv*hd] pool) lets the Pallas
     kernel address the pool with a dynamic layer index instead of the caller
-    slicing a per-layer copy out — the zero-copy path the decode scan uses.
-    ``strict=True``: no XLA fallback (see ragged_prefill_attention)."""
+    slicing a per-layer copy out — the zero-copy path the decode scan uses."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
-        try:
-            from .pallas.paged_decode import pallas_paged_decode
-            return pallas_paged_decode(q, k_cache_l, v_cache_l, page_tables,
-                                       context_lens, k_cur, v_cur, scale,
-                                       layer=layer)
-        except Exception as e:  # pragma: no cover - fallback safety
-            if strict:
-                raise
-            logger.warning("pallas decode unavailable (%s); falling back to XLA", e)
+        from .pallas.paged_decode import pallas_paged_decode
+        return pallas_paged_decode(q, k_cache_l, v_cache_l, page_tables,
+                                   context_lens, k_cur, v_cur, scale,
+                                   layer=layer)
     return paged_decode_attention_xla(q, k_cache_l, v_cache_l, page_tables,
                                       context_lens, k_cur, v_cur, scale,
                                       layer=layer)
@@ -445,8 +398,8 @@ def mixed_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
     qd, kd, vd = q[n_prefill:], k[n_prefill:], v[n_prefill:]
     segp, posp = seg_ids[:n_prefill], positions[:n_prefill]
     # The two halves gate their kernels INDEPENDENTLY, mirroring the pure
-    # paths: a hist-only Mosaic probe failure (use_pallas_hist False while
-    # use_pallas stays True) must route the chunk half through plain XLA —
+    # paths: where the history kernel is ineligible (use_pallas_hist False
+    # while use_pallas stays True) the chunk half runs plain XLA —
     # GSPMD-partitionable under a tp mesh — while decode keeps its kernel.
     if attn_mesh is not None and use_pallas_hist:
         out_p = prefill_history_attention_tp(

@@ -42,11 +42,13 @@ def _int4_matmul_kernel(x_ref, wp_ref, scale_ref, out_ref, *,
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    wp = wp_ref[:]                                   # [Kt/2, Nt] int8
+    # Widen before shifting: Mosaic does not legalize shifts on i8 vectors
+    # (v5e, libtpu 0.0.34: "failed to legalize operation 'arith.shli'").
+    wp = wp_ref[:].astype(jnp.int32)                 # [Kt/2, Nt]
     half, nt = wp.shape
-    lo = (wp << 4) >> 4                              # sign-extend low nibble
+    lo = (wp << 28) >> 28                            # sign-extend low nibble
     hi = wp >> 4                                     # arithmetic: high nibble
-    w = jnp.stack([lo, hi], axis=1).reshape(half * 2, nt)   # [Kt, Nt] int8
+    w = jnp.stack([lo, hi], axis=1).reshape(half * 2, nt)   # [Kt, Nt] int32
     wf = w.astype(jnp.float32).reshape(-1, group_size, nt)
     wf = (wf * scale_ref[:][:, None, :]).reshape(half * 2, nt)
     out_ref[:] += jax.lax.dot_general(
@@ -55,7 +57,7 @@ def _int4_matmul_kernel(x_ref, wp_ref, scale_ref, out_ref, *,
 
 
 def pallas_int4_matmul(x, w_packed, scale, *, block_n: int = 256,
-                       block_k: int = 512, interpret: bool = False):
+                       block_k: int = 1024, interpret: bool = False):
     """x: [T, K] (bf16/f32); w_packed: [K/2, N] int8 (ops/quant.pack_int4
     layout); scale: [K/group_size, N] f32. Returns f32 [T, N].
 
@@ -81,9 +83,11 @@ def pallas_int4_matmul(x, w_packed, scale, *, block_n: int = 256,
     bn = min(max(128, block_n - block_n % 128), N)
     if N % bn:
         bn = 128
-    if N % 128 or K % bk or (bk // 2) % 32:
+    if N % 128 or K % bk or (bk // 2) % 32 or ((bk // gs) % 8 and bk != K):
         # lane dim must tile at 128; the packed tile's sublane dim (bk/2)
-        # must respect the int8 (32, 128) min tile.
+        # must respect the int8 (32, 128) min tile, and the f32 scale
+        # tile's (bk/gs) the (8, 128) one unless it spans the whole array
+        # (block_k 1024 / group 128 = 8 rows: the default just meets it).
         from ..quant import int4_matmul_xla
         return int4_matmul_xla(x, w_packed, scale)
 
@@ -103,7 +107,7 @@ def pallas_int4_matmul(x, w_packed, scale, *, block_n: int = 256,
         out_specs=pl.BlockSpec((T, bn), lambda n, k: (0, n),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed, scale)

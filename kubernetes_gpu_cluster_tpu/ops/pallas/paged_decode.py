@@ -25,7 +25,7 @@ the lane dimension, and the current token's K/V arrive pre-flattened
 ``[1, n_kv*hd]`` from the host where the reshape is free.
 
 Only ``ceil((ctx-1)/page_size)`` pages per sequence move on the bus — the XLA
-fallback reads the full padded page table.
+reference reads the full padded page table.
 
 Replaces vLLM's CUDA PagedAttention kernel (the engine the reference deployed
 via Helm, reference ``values-01-minimal-example8.yaml:28-38``) with a
@@ -211,10 +211,10 @@ def pallas_paged_decode(q, k_pool, v_pool, page_tables, context_lens,
     page_tables: [B, pages_per_seq]; context_lens: [B] (incl. current token);
     k_cur/v_cur: [B, n_kv, hd]. Returns [B, nh, hd]."""
     if k_pool.shape[-1] % 128 != 0 and not interpret:
-        # Mosaic DMA slices must be 128-lane aligned; raise at TRACE time so
-        # the dispatcher's fallback catches it (the Mosaic failure itself only
-        # surfaces at compile time, after tracing succeeded). Interpret mode
-        # has no Mosaic tiling constraint, so small test shapes are allowed.
+        # Mosaic DMA slices must be 128-lane aligned; raise at TRACE time
+        # with the reason (the Mosaic failure itself only surfaces at compile
+        # time, as an opaque layout error). Interpret mode has no Mosaic
+        # tiling constraint, so small test shapes are allowed.
         raise ValueError(
             f"paged pool lane dim {k_pool.shape[-1]} (n_kv*head_dim) must be "
             f"a multiple of 128 for the Pallas decode kernel")

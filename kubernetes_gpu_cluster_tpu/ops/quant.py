@@ -160,17 +160,14 @@ def int4_matmul_xla(x, w_packed, scale):
     in as elementwise producers), then fold the per-(group, channel) scales
     into the f32 partials. x: [T, in]; returns f32 [T, out].
 
-    Where this jax build carries a native int4 dtype, the nibbles pass
-    through a ``jnp.int4`` intermediate so XLA sees the 4-bit value range
-    (TPU keeps int4 packed through such fusions); numerics are identical
-    either way."""
+    The nibbles pass through a ``jnp.int4`` intermediate so XLA sees the
+    4-bit value range (TPU keeps int4 packed through such fusions)."""
     jnp = _jnp()
     din = w_packed.shape[-2] * 2
     n_groups = scale.shape[-2]
     gs = din // n_groups
     w = unpack_int4(w_packed, xp=jnp)                    # [in, out] int8
-    if hasattr(jnp, "int4"):
-        w = w.astype(jnp.int4)
+    w = w.astype(jnp.int4)
     wg = w.reshape(n_groups, gs, w.shape[-1]).astype(x.dtype)
     xg = x.reshape(x.shape[0], n_groups, gs)
     partial = jnp.einsum("tgi,gio->tgo", xg, wg,

@@ -30,10 +30,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-NEG = jnp.float32(-1e30)
+NEG = -1e30  # python float: a jnp scalar would initialise the backend at import
 
 
 def _block_attend(qg, k_blk, v_blk, q_seg, k_seg, q_pos, k_pos,
@@ -97,11 +96,11 @@ def build_ring_prefill(mesh, num_kv_heads: int, q_per_kv: int, scale: float,
     body = functools.partial(_ring_body, scale=scale, axis=axis,
                              n_kv=num_kv_heads, q_per_kv=q_per_kv)
     seq = P(axis)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(seq, seq, seq, seq, seq),
         out_specs=seq,
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def ring_prefill(q, k, v, seg_ids, positions):

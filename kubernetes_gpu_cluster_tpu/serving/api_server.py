@@ -57,6 +57,7 @@ from aiohttp import web
 from ..config import EngineConfig
 from ..config.engine_config import ResilienceConfig
 from ..engine import SamplingParams
+from ..engine.engine import device_memory_stats
 from ..observability import Histogram
 from ..resilience import (AdmissionController, DrainState, ResilienceHub,
                           StepWatchdog)
@@ -224,6 +225,24 @@ def _logprobs_requested(body: dict):
     return lp, None
 
 
+class _TokenIdStrings:
+    """vLLM's ``return_tokens_as_token_ids`` request field: logprobs
+    ``tokens`` (and ``top_logprobs`` keys) render as ``"token_id:<id>"``
+    instead of decoded text. With random weights and the byte tokenizer —
+    how this server runs wherever no checkpoint is staged — almost no
+    sampled id decodes to text, and ids are the only way a client can
+    compare two generations."""
+
+    @staticmethod
+    def decode(ids) -> str:
+        return "".join(f"token_id:{t}" for t in ids)
+
+
+def _logprobs_tokenizer(body: dict, tokenizer):
+    return _TokenIdStrings if body.get("return_tokens_as_token_ids") \
+        else tokenizer
+
+
 def _stops(body: dict) -> list[str]:
     stop = body.get("stop")
     if stop is None:
@@ -247,6 +266,8 @@ class APIServer:
         self.tokenizer = tokenizer
         self.model_name = model_name
         self.metrics = Metrics(engine.engine)
+        # Fixed at engine construction; /health serves it on every probe.
+        self._runtime_info = engine.engine.runtime_info()
         self.role = role
         self.disagg = DisaggStats(role)
         self.migration = MigrationStats()
@@ -749,6 +770,11 @@ class APIServer:
         body = {"status": "ok", "model": self.model_name, "role": self.role,
                 "waiting": len(sched.waiting), "running": len(sched.running),
                 "swapped": len(sched.swapped)}
+        # Device, kernels and pool as the ENGINE holds them, plus live HBM
+        # per addressable device: what chip_smoke.py and any benchmark
+        # assert before a number is believed.
+        body.update(self._runtime_info)
+        body["hbm_bytes_in_use"] = [u for _, u in device_memory_stats()]
         if self.qos_tiers:
             # Per-tier in-flight requests (the admission ledger) — the
             # operator's one-look answer to "which tenant class is loading
@@ -1823,7 +1849,8 @@ class APIServer:
                     tok_tops = [None] * len(ids) + tok_tops
             return web.json_response(_response_envelope(
                 kind, rid, created, self.model_name,
-                [_choice(kind, 0, text, finish_reason, self.tokenizer,
+                [_choice(kind, 0, text, finish_reason,
+                         _logprobs_tokenizer(body, self.tokenizer),
                          tok_ids, tok_lps, want_lps, tok_tops, n_lp)],
                 prompt_tokens=len(ids), completion_tokens=n_out))
 
@@ -1834,6 +1861,7 @@ class APIServer:
             # must ride here — the middleware cannot amend them later.
             REQUEST_ID_HEADER: rid})
         n_out = 0
+        lp_tok = _logprobs_tokenizer(body, self.tokenizer)
         try:
             # prepare() and the echo frame sit INSIDE the cleanup scope: a
             # client that disconnects right here would otherwise strand the
@@ -1873,14 +1901,13 @@ class APIServer:
                         # tokens are not part of the emitted text (see
                         # _collect).
                         sb["choices"][0]["logprobs"] = {
-                            "tokens": [self.tokenizer.decode([t])
+                            "tokens": [lp_tok.decode([t])
                                        for t in chunk.new_token_ids],
                             "token_logprobs": list(chunk.new_logprobs),
                         }
                         if chunk.new_top_logprobs:
                             sb["choices"][0]["logprobs"]["top_logprobs"] = \
-                                _format_tops(self.tokenizer,
-                                             chunk.new_top_logprobs)
+                                _format_tops(lp_tok, chunk.new_top_logprobs)
                     await resp.write(_sse(sb))
                 if finished:
                     complete = True
@@ -2005,8 +2032,9 @@ class APIServer:
                     tok_lps = [None] * len(ids) + tok_lps
                     tok_tops = [None] * len(ids) + tok_tops
             choices.append(_choice(kind, i, text, finish_reason,
-                                   self.tokenizer, tok_ids, tok_lps,
-                                   want_lps, tok_tops, n_lp))
+                                   _logprobs_tokenizer(body, self.tokenizer),
+                                   tok_ids, tok_lps, want_lps, tok_tops,
+                                   n_lp))
         self.metrics.on_finish(total_out)
         return web.json_response(_response_envelope(
             kind, rid, created, self.model_name, choices,
@@ -2158,6 +2186,9 @@ def main(argv: Optional[list[str]] = None) -> None:
 
     from ..config import CacheConfig, ParallelConfig, get_model_config
     from ..parallel import initialize_distributed, mesh_from_config
+    from ..utils.compile_cache import configure_compile_cache
+
+    cache_dir = configure_compile_cache()
 
     p = argparse.ArgumentParser()
     p.add_argument("--model", required=True)
@@ -2177,7 +2208,9 @@ def main(argv: Optional[list[str]] = None) -> None:
                    help="MoE expert sharding over the ep mesh axis")
     p.add_argument("--hbm-utilization", "--gpu-memory-utilization",
                    dest="hbm_utilization", type=float, default=0.90,
-                   help="fraction of free HBM given to the KV page pool")
+                   help="fraction the KV page pool takes of the HBM that "
+                   "is free once the weights are resident and one step's "
+                   "workspace is set aside")
     p.add_argument("--max-num-seqs", type=int, default=64)
     p.add_argument("--swap-space-gb", "--swap-space", dest="swap_space_gb",
                    type=float, default=0.0,
@@ -2344,6 +2377,14 @@ def main(argv: Optional[list[str]] = None) -> None:
             follower = DirectiveFollower(
                 port=int(os.environ.get("KGCT_CONTROL_PORT", CONTROL_PORT)))
         initialize_distributed()
+    import jax
+    dev0 = jax.devices()[0]
+    # First line of every server log: the device, BEFORE any weights are
+    # built — a launcher that needs the chip (chip_smoke.py) reads it and
+    # stops a CPU start within seconds.
+    logger.info("device: platform=%s device_kind=%s device_count=%d "
+                "compile_cache=%s", dev0.platform, dev0.device_kind,
+                jax.device_count(), cache_dir)
     model_cfg = get_model_config(args.model)
     if args.dtype:
         dtype = {"float16": "bfloat16", "half": "bfloat16",
@@ -2471,7 +2512,6 @@ def main(argv: Optional[list[str]] = None) -> None:
                      liveness_timeout_s=config.resilience.liveness_timeout_s)
         return
     leader = None
-    import jax
     if jax.process_count() > 1:
         from .multihost import DirectiveLeader, follower_addrs_from_env
         leader = DirectiveLeader(

@@ -126,7 +126,7 @@ class Metrics:
         # a GAUGE despite the _total spelling: it reads the live cache, so
         # jax.clear_caches()/engine rebuild can shrink it — a counter TYPE
         # would make rate() report a phantom compile storm on any reset.
-        hbm_limit, hbm_in_use = device_memory_stats()
+        hbm_limit, hbm_in_use = device_memory_stats()[0]
         lines += [
             "# TYPE kgct_hbm_bytes_limit gauge",
             f"kgct_hbm_bytes_limit {hbm_limit}",

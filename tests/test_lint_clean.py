@@ -1,7 +1,8 @@
 """The tier-1 lint gate: the FULL package is kgct-lint clean, no allowlist.
 
 This is the enforcement half of the static-analysis subsystem: every rule
-in analysis/rules runs over every package module (plus bench.py) and the
+in analysis/rules runs over every package module (plus the repo-root
+scripts, bench.py and chip_smoke.py) and the
 baseline is EMPTY. A hot-path host sync, a trace-unsafe branch, a donated
 buffer read, an unbounded metric label — any regression fails here, in
 tests, instead of shipping as a silent perf/correctness cliff. There is
@@ -16,11 +17,11 @@ from kubernetes_gpu_cluster_tpu.analysis.cli import main as lint_main
 
 REPO = Path(__file__).resolve().parent.parent
 PACKAGE = REPO / "kubernetes_gpu_cluster_tpu"
-BENCH = REPO / "bench.py"
+SCRIPTS = [REPO / "bench.py", REPO / "chip_smoke.py"]
 
 
 def test_package_is_lint_clean_empty_baseline():
-    findings = run_lint([PACKAGE, BENCH], root=REPO)
+    findings = run_lint([PACKAGE, *SCRIPTS], root=REPO)
     assert findings == [], (
         "kgct-lint must stay clean (fix the finding, don't allowlist):\n"
         + "\n".join(f.format() for f in findings))

@@ -98,7 +98,16 @@ class TestAPIServer:
         async def go():
             r = await client.get("/health")
             assert r.status == 200
-            assert (await r.json())["status"] == "ok"
+            health = await r.json()
+            assert health["status"] == "ok"
+            # The device, kernels and pool behind every answer — what a
+            # launcher (chip_smoke.py) asserts before it believes a result.
+            assert health["platform"] == "cpu"
+            assert health["device_kind"] and health["device_count"] == 8
+            assert health["use_pallas"] is False
+            assert health["use_pallas_hist"] is False
+            assert (health["num_pages"], health["page_size"]) == (128, 16)
+            assert health["hbm_bytes_in_use"] == [0] * 8
             r = await client.get("/v1/models")
             data = await r.json()
             assert data["data"][0]["id"] == "debug-tiny"
@@ -632,6 +641,19 @@ class TestLogprobsAPI:
             r4 = await client.post("/v1/completions", json={
                 "prompt": [1, 5, 9], "max_tokens": 2, "temperature": 0.0})
             assert "logprobs" not in (await r4.json())["choices"][0]
+
+            # return_tokens_as_token_ids (vLLM's field): tokens and
+            # alternatives render as "token_id:<id>" — the only way to read
+            # a generation back where ids do not decode to text.
+            r5 = await client.post("/v1/completions", json={
+                "prompt": [1, 5, 9], "max_tokens": 4, "temperature": 0.0,
+                "logprobs": 1, "return_tokens_as_token_ids": True})
+            lp5 = (await r5.json())["choices"][0]["logprobs"]
+            assert lp5["token_logprobs"] == lp["token_logprobs"]
+            ids = [int(t.removeprefix("token_id:")) for t in lp5["tokens"]]
+            assert all(0 <= t < 512 for t in ids)
+            for tid, top in zip(ids, lp5["top_logprobs"]):
+                assert f"token_id:{tid}" in top
         loop.run_until_complete(go())
 
     def test_streaming_logprobs_and_chat_rejection(self, api_client):
@@ -640,7 +662,8 @@ class TestLogprobsAPI:
         async def go():
             r = await client.post("/v1/completions", json={
                 "prompt": [1, 5, 9], "max_tokens": 4, "temperature": 0.0,
-                "logprobs": 1, "stream": True})
+                "logprobs": 1, "stream": True,
+                "return_tokens_as_token_ids": True})
             assert r.status == 200
             lps = []
             async for line in r.content:
@@ -650,6 +673,8 @@ class TestLogprobsAPI:
                     lp = ev["choices"][0].get("logprobs")
                     if lp:
                         assert len(lp["tokens"]) == len(lp["token_logprobs"])
+                        assert all(t.startswith("token_id:")
+                                   for t in lp["tokens"])
                         lps.extend(lp["token_logprobs"])
                 if line == "data: [DONE]":
                     break
