@@ -20,10 +20,13 @@ asserted from what the SERVER reports (/health and its first log line).
     python chip_smoke.py --cpu-rehearsal                # debug THIS SCRIPT on
                                                         # the CPU (debug-tiny)
 
-Exit code 0 and one JSON object on the last stdout line when every phase
-passed; anything else — no TPU, a failed phase, a fallback line in the server
-log, a dirty drain — is a non-zero exit with the reason on stderr and no
-result line. Nothing is caught and downgraded to a warning.
+When every phase passed: exit code 0 and two JSON lines on stdout. The LAST
+is the verdict, exactly ``{"ok": true, "device": {"platform", "kind",
+"count"}}`` with the device as the server's JAX reports it; the line before
+it is the report (model, pool, kernels, cold times, per-request status,
+compile cache). Anything else — no TPU, a failed phase, a fallback line in
+the server log, a dirty drain — is a non-zero exit with the reason on stderr
+and nothing on stdout. Nothing is caught and downgraded to a warning.
 """
 
 from __future__ import annotations
@@ -230,7 +233,8 @@ def metric(text: str, name: str) -> float:
     return float(m.group(1))
 
 
-def run(args) -> dict:
+def run(args) -> tuple[dict, dict]:
+    """(report, verdict) when every phase passed; SmokeFailure otherwise."""
     model = "debug-tiny" if args.cpu_rehearsal else "qwen3-4b"
     want_platform = "cpu" if args.cpu_rehearsal else "tpu"
     geom = MODELS[model]
@@ -391,11 +395,9 @@ def run(args) -> dict:
         signal.alarm(0)
         server.kill()
 
-    return {
-        "ok": True,
-        "device": {"platform": health["platform"],
-                   "kind": health["device_kind"],
-                   "count": health["device_count"]},
+    device = {"platform": health["platform"], "kind": health["device_kind"],
+              "count": health["device_count"]}
+    report = {
         "model": model, "dtype": health["dtype"],
         "tensor_parallel_size": tp,
         "pages": health["num_pages"], "page_size": health["page_size"],
@@ -413,6 +415,7 @@ def run(args) -> dict:
         "server_exit_code": rc,
         "server_log": str(server.log_path),
     }
+    return report, {"ok": True, "device": device}
 
 
 def main() -> int:
@@ -433,11 +436,13 @@ def main() -> int:
               "script itself on the CPU)", file=sys.stderr)
         return 1
     try:
-        result = run(args)
+        report, verdict = run(args)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    print(json.dumps(result))
+    print(json.dumps(report))
+    # The contract's line, and the last: these keys and no others.
+    print(json.dumps(verdict), flush=True)
     return 0
 
 

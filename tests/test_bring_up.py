@@ -96,9 +96,14 @@ class TestChipSmokeParent:
         r = subprocess.run([sys.executable, str(SMOKE), "--cpu-rehearsal"],
                            capture_output=True, text=True, timeout=600)
         assert r.returncode == 0, r.stderr[-2000:]
-        out = json.loads(r.stdout.strip().splitlines()[-1])
-        assert out["ok"] is True
-        assert out["device"]["platform"] == "cpu"     # never a chip result
+        report, verdict = map(json.loads, r.stdout.strip().splitlines()[-2:])
+        # The last line is the chip check's contract: these keys, no others.
+        assert verdict.keys() == {"ok", "device"} and verdict["ok"] is True
+        assert verdict["device"].keys() == {"platform", "kind", "count"}
+        assert verdict["device"]["platform"] == "cpu"  # never a chip result
+        assert isinstance(verdict["device"]["kind"], str)
+        assert type(verdict["device"]["count"]) is int
+        out = report
         assert out["model"] == "debug-tiny"
         assert set(out["requests"].values()) == {200}
         assert out["mixed_step_ratio"] > 0
