@@ -408,12 +408,17 @@ class LLMEngine:
         return snap
 
     def compiled_step_variants(self) -> int:
-        """Total jit-cache entries across every step program — the number of
-        distinct XLA compilations serving has paid so far. The same count
-        the tier-1 compile guard bounds (tests/test_compile_guard.py), now
-        exported as ``kgct_jit_compiles_total``: a steady-state serving
-        process holds this flat, so any growth under constant traffic is a
-        recompilation storm in progress."""
+        """Total jit-cache entries across every step program: the number of
+        distinct SHAPES the step programs have met so far. An entry that was
+        loaded from the persistent compilation cache counts like one that
+        was compiled, and the eager one-op programs the host issues between
+        steps are not seen at all; the compilations themselves are counted
+        where they happen (``kgct_xla_compile_*``, utils/compile_cache.py).
+        The same count the tier-1 compile guard bounds
+        (tests/test_compile_guard.py), exported as
+        ``kgct_jit_compiles_total``: a steady-state serving process holds
+        this flat, so growth under constant traffic means new shapes keep
+        arriving."""
         fns = [self._prefill_fn, self._prefill_hist_fn, self._mixed_fn,
                self._decode_fn, self._decode_fn_greedy, self._spec_verify_fn,
                self._spec_mixed_fn]
@@ -1638,7 +1643,11 @@ class LLMEngine:
         # prefill measured it (TTFT decomposition).
         self._ttft_transfer_s = None
         t0 = time.perf_counter()
-        outs = self._step()
+        # The phases' parent in a profiler capture: what of "kgct.step" no
+        # phase covers (the key split, the drains, a window's unphased
+        # parts) is the step's own time there.
+        with self.obs.phases.span("step", step_num=self.stats.steps):
+            outs = self._step()
         dt = time.perf_counter() - t0
         self.stats.steps += 1
         info = self._last_step_info

@@ -61,7 +61,10 @@ class FlightRecorder:
         self.snapshot_interval_s = snapshot_interval_s
         self._ring: deque = deque(maxlen=capacity)
         self._snapshot_source: Optional[Callable[[], dict]] = None
-        self._last_snapshot = 0.0
+        # -inf, not 0.0: time.monotonic() counts from boot on Linux, so on a
+        # machine up for less than the interval "0.0" reads as "a snapshot
+        # was just taken" and the first one never comes.
+        self._last_snapshot = float("-inf")
         self.dumps_total = 0
         self.last_dump_path: Optional[str] = None
 

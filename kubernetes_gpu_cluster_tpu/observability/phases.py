@@ -10,37 +10,66 @@ delta instead of a guess — the attribution VERDICT r5 said was impossible
 ("no way to tell whether the time is queue wait, chunked-prefill stalls,
 device step time, or host-side detokenize").
 
-Cost per phase is two perf-counter reads and a list append; per step a dict
-merge into running totals — amortized nanoseconds against multi-ms steps,
-which is what keeps the tracer's decode-path overhead within the <=1% tok/s
-budget.
+Cost per phase is two clock reads and a list append; per step a dict merge
+into running totals — amortized nanoseconds against multi-ms steps, which is
+what keeps the tracer's decode-path overhead within the <=1% tok/s budget.
+
+While a profiler capture runs (``StepPhaseStats.capturing``, set by
+``POST /debug/profile`` and by nothing else) every phase is ALSO a
+``jax.profiler.TraceAnnotation`` named ``kgct.<phase>``, and ``span()`` gives
+the spans that are no phase (``kgct.step``, the worker's and the HTTP
+layer's): host spans in the profiler's own trace, on the device trace's
+clock, so that a device idle gap can be laid on what the host was doing.
+With ``capturing`` False a phase pays one attribute read for it and no
+annotation object exists.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import deque
 
 PHASES = ("schedule", "host_prep", "device_dispatch", "device_fetch",
           "postproc", "detokenize")
+SPAN_PREFIX = "kgct."
+
+
+# What span() hands out while no capture runs: one shared, reusable object.
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _annotation(name: str, **args):
+    # Looked up at call time, and only while a capture runs: a process that
+    # never profiles (the router) never imports jax for this.
+    import jax.profiler
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
 
 
 class _PhaseCtx:
     """Reusable context manager: ``with stats.phase("host_prep"):``."""
-    __slots__ = ("_stats", "_name", "_t0", "_start")
+    __slots__ = ("_stats", "_name", "_t0", "_span")
 
     def __init__(self, stats: "StepPhaseStats", name: str):
         self._stats = stats
         self._name = name
+        self._span = None
 
     def __enter__(self):
-        self._start = time.monotonic()
-        self._t0 = time.perf_counter()
+        if self._stats.capturing:
+            self._span = _annotation(self._name)
+            self._span.__enter__()
+        # time.monotonic: the request tracer's clock, so a phase's start
+        # lies on the /debug/trace timeline as it is.
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self._stats.record(self._name, time.perf_counter() - self._t0,
-                           start=self._start)
+        dur = time.monotonic() - self._t0
+        if self._span is not None:
+            self._span.__exit__(*exc)
+            self._span = None
+        self._stats.record(self._name, dur, start=self._t0)
         return False
 
 
@@ -59,9 +88,21 @@ class StepPhaseStats:
         # _current/current_durs (the step loop swaps those unsynchronized),
         # so they land in their own ring and merge at export time.
         self._detached: deque = deque(maxlen=256)
+        # True only for the seconds of a profile capture (the handler sets
+        # it before start_trace and clears it before it calls stop_trace):
+        # phases and spans then also write TraceAnnotations.
+        self.capturing = False
 
     def phase(self, name: str) -> _PhaseCtx:
         return _PhaseCtx(self, name)
+
+    def span(self, name: str, **args):
+        """``with phases.span("worker.post"):`` — a host span ``kgct.<name>``
+        in the profiler's trace while a capture runs, nothing otherwise
+        (no timing, no totals: a span that should be counted is a phase)."""
+        if self.capturing:
+            return _annotation(name, **args)
+        return _NO_SPAN
 
     def record(self, name: str, dur: float, start: float = None) -> None:
         """Record one phase occurrence. ``start=None`` marks an out-of-step

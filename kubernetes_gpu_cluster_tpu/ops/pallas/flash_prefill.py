@@ -189,5 +189,6 @@ def flash_ragged_prefill(q, k, v, seg_ids, positions, scale, *,
         out_shape=jax.ShapeDtypeStruct((nh, T, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="flash_prefill",
     )(kb_min, q_hm, k_hm, v_hm, seg2d, seg2d)
     return out.transpose(1, 0, 2)

@@ -261,5 +261,6 @@ def flash_prefill_history(q, k, v, seg_ids, positions, k_pool, v_pool,
         out_shape=jax.ShapeDtypeStruct((T, nh, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="flash_prefill_hist",
     )(page_table.astype(jnp.int32), meta, q, k_pool, v_pool, kc, vc)
     return out

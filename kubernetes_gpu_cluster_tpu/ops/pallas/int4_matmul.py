@@ -110,4 +110,5 @@ def pallas_int4_matmul(x, w_packed, scale, *, block_n: int = 256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="int4_matmul",
     )(x, w_packed, scale)

@@ -300,5 +300,6 @@ def pallas_paged_decode(q, k_pool, v_pool, page_tables, context_lens,
         out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_decode",
     )(page_tables.reshape(-1), context_lens, layer, offsets, q, k_pool,
       v_pool, k_cur, v_cur)

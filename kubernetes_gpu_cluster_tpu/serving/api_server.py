@@ -258,7 +258,8 @@ class APIServer:
                  prefill_pool: Optional[list] = None,
                  peer_pool: Optional[list] = None,
                  fleet_prefix_cache: bool = False,
-                 integrity_checks: bool = True):
+                 integrity_checks: bool = True,
+                 profile_dir: Optional[str] = None):
         if role not in REPLICA_ROLES:
             raise ValueError(f"unknown replica role {role!r} "
                              f"(known: {', '.join(REPLICA_ROLES)})")
@@ -349,6 +350,10 @@ class APIServer:
             engine.engine.obs.seed_peers(sorted(self.prefill_pool))
         self._http: Optional[Any] = None   # lazy aiohttp.ClientSession
         self._profile_busy = False
+        # Where POST /debug/profile tells the profiler to write
+        # (--profile-dir). None: a directory of this process's own, named
+        # on the first capture.
+        self._profile_dir = profile_dir
         # Fleet-wide prefix cache (--fleet-prefix-cache): this replica
         # serves peers' prefix fetches (/internal/fetch_prefix), pulls the
         # ring owner's cached prefix when the router's pick overflowed
@@ -817,48 +822,104 @@ class APIServer:
     def _detok_push(self, detok: IncrementalDetokenizer, ids, final) -> str:
         """detok.push with its wall time attributed to the ``detokenize``
         phase — host-side text assembly is a real TTFT/latency contributor
-        the engine's step loop cannot see (it owns no tokenizer)."""
+        the engine's step loop cannot see (it owns no tokenizer). In a
+        profiler capture it is the span ``kgct.http.detokenize`` on the
+        event loop's thread, beside the worker's spans."""
+        phases = self.engine.engine.obs.phases
         t0 = time.perf_counter()
         try:
-            return detok.push(ids, final=final)
+            with phases.span("http.detokenize"):
+                return detok.push(ids, final=final)
         finally:
-            self.engine.engine.obs.phases.record(
-                "detokenize", time.perf_counter() - t0)
+            phases.record("detokenize", time.perf_counter() - t0)
 
     async def profile(self, request: web.Request) -> web.Response:
         """Capture a jax.profiler trace of live serving traffic.
 
-        ``POST /debug/profile?seconds=3`` blocks for the window and returns
-        the trace directory (under /tmp/kgct-profile; open with
-        xprof/tensorboard). One capture at a time — concurrent requests get
-        409 rather than clobbering the active trace. The observability the
-        reference lacked entirely (SURVEY §5 "Tracing/profiling: none")."""
+        ``POST /debug/profile?seconds=3`` answers when the capture is
+        written, with ``trace_dir`` (the directory handed to the profiler:
+        ``--profile-dir``, else one of this process's own under the
+        temporary directory; open with xprof/tensorboard), ``seconds``, and
+        two ``time.monotonic_ns()`` stamps, ``started_monotonic_ns`` (taken
+        when the profiler's start returned) and ``stopping_monotonic_ns``
+        (when its stop was called). The event loop keeps answering
+        meanwhile: the profiler's start and stop run on a thread of their
+        own. (The stop converts the device's events on the host for tens of
+        seconds on a TPU and takes the GIL for most of that: streams go on
+        at a fraction of their rate, none is silent throughout.) One capture
+        at a time — concurrent requests get 409 rather than clobbering the
+        active trace.
+
+        For the capture's ``seconds`` the step phases, the step, the
+        worker's turns and the HTTP layer's detokenize/write are ``kgct.*``
+        host spans in the trace (observability/phases.py), on the device
+        trace's clock. One annotation ``kgct.clock`` carries
+        ``monotonic_ns`` = ``started_monotonic_ns``: the trace's timestamps
+        count from the session's start, and this one event lays
+        ``/debug/trace`` (on ``time.monotonic``) on the same timeline.
+
+        The profiler's Python function tracer is off: it hooks every Python
+        call of the host code being measured, and the frames it closes when
+        it stops stretch the trace past the time the device was traced. The
+        ``kgct.*`` spans say what the host was doing."""
         import asyncio
 
         import jax
 
         # Atomic try-acquire: the flag flips synchronously (no await between
         # test and set), so concurrent requests cannot both pass the gate and
-        # queue a second blocking capture (the check-then-acquire TOCTOU).
+        # start a second capture (the check-then-acquire TOCTOU).
         if self._profile_busy:
             return _error(409, "a profile capture is already running")
         self._profile_busy = True
+        phases = self.engine.engine.obs.phases
+        loop = asyncio.get_running_loop()
         try:
             seconds = float(request.query.get("seconds", 3))
             seconds = min(max(seconds, 0.1), 60.0)
-            trace_dir = "/tmp/kgct-profile"
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            if self._profile_dir is None:
+                import os
+                import secrets
+                import tempfile
+                # Named here, made by the profiler when it writes: this
+                # process's own, not a name every checkout shares.
+                self._profile_dir = os.path.join(
+                    tempfile.gettempdir(),
+                    f"kgct-profile-{os.getpid()}-{secrets.token_hex(4)}")
+            trace_dir = self._profile_dir
+            phases.capturing = True
             try:
-                jax.profiler.start_trace(trace_dir)
+                # jax.profiler.start_trace is looked up when called: an
+                # embedder (the benchmark's serve.py) may have replaced it.
+                await loop.run_in_executor(
+                    None, lambda: jax.profiler.start_trace(
+                        trace_dir, profiler_options=options))
+            except Exception as e:
+                phases.capturing = False
+                return _error(500, f"profiler start failed: {e}")
+            started_ns = time.monotonic_ns()
+            with phases.span("clock", monotonic_ns=started_ns):
+                pass
+            try:
                 await asyncio.sleep(seconds)
             finally:
+                # Off before the stop, not after it: the stop ends the
+                # recording at once and then works for a long time, in which
+                # a span would cost its price and land nowhere.
+                phases.capturing = False
+                stopping_ns = time.monotonic_ns()
                 try:
-                    jax.profiler.stop_trace()
+                    await loop.run_in_executor(None, jax.profiler.stop_trace)
                 except Exception as e:
                     return _error(500, f"profiler stop failed: {e}")
         finally:
             self._profile_busy = False
         return web.json_response({"trace_dir": trace_dir,
-                                  "seconds": seconds})
+                                  "seconds": seconds,
+                                  "started_monotonic_ns": started_ns,
+                                  "stopping_monotonic_ns": stopping_ns})
 
     async def models(self, request: web.Request) -> web.Response:
         return web.json_response({
@@ -1243,7 +1304,9 @@ class APIServer:
                                        for t in new_ids],
                             "token_logprobs": lps[-len(new_ids):],
                         }
-                    await resp.write(_sse(sb))
+                    with self.engine.engine.obs.phases.span(
+                            "http.write"):
+                        await resp.write(_sse(sb))
                 if finished:
                     complete = True
                     break
@@ -1908,7 +1971,9 @@ class APIServer:
                         if chunk.new_top_logprobs:
                             sb["choices"][0]["logprobs"]["top_logprobs"] = \
                                 _format_tops(lp_tok, chunk.new_top_logprobs)
-                    await resp.write(_sse(sb))
+                    with self.engine.engine.obs.phases.span(
+                            "http.write"):
+                        await resp.write(_sse(sb))
                 if finished:
                     complete = True
                     break
@@ -2158,7 +2223,8 @@ def build_server(config: EngineConfig, tokenizer_path: Optional[str] = None,
                  peer_pool: Optional[list] = None,
                  fleet_prefix_cache: bool = False,
                  integrity_checks: bool = True,
-                 draft_params=None) -> APIServer:
+                 draft_params=None,
+                 profile_dir: Optional[str] = None) -> APIServer:
     tokenizer = load_tokenizer(tokenizer_path)
     engine = AsyncLLMEngine(config, params=params,
                             eos_token_id=tokenizer.eos_token_id, mesh=mesh,
@@ -2167,7 +2233,8 @@ def build_server(config: EngineConfig, tokenizer_path: Optional[str] = None,
                      resilience=config.resilience, role=role,
                      prefill_pool=prefill_pool, peer_pool=peer_pool,
                      fleet_prefix_cache=fleet_prefix_cache,
-                     integrity_checks=integrity_checks)
+                     integrity_checks=integrity_checks,
+                     profile_dir=profile_dir)
 
 
 def main(argv: Optional[list[str]] = None) -> None:
@@ -2328,6 +2395,12 @@ def main(argv: Optional[list[str]] = None) -> None:
                    "wire bytes byte-identical to the pre-integrity "
                    "encoders — only for talking to peers that do not "
                    "speak the integrity dialect yet")
+    p.add_argument("--profile-dir", default=None,
+                   help="directory POST /debug/profile hands to the JAX "
+                   "profiler (the capture lands under "
+                   "<dir>/plugins/profile/<time>/); default: a directory of "
+                   "this process's own under the temporary directory, named "
+                   "in the reply")
     p.add_argument("--drain-grace-s", type=float, default=None,
                    help="SIGTERM drain: max seconds to wait for in-flight "
                    "requests before exiting anyway (default 120). With "
@@ -2529,7 +2602,8 @@ def main(argv: Optional[list[str]] = None) -> None:
                                      if args.peer_pool else None),
                           fleet_prefix_cache=args.fleet_prefix_cache,
                           integrity_checks=not args.no_integrity_checks,
-                          draft_params=draft_params)
+                          draft_params=draft_params,
+                          profile_dir=args.profile_dir)
     app = server.build_app()
 
     async def _arm_sigterm(app_):

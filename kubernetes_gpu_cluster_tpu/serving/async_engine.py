@@ -297,12 +297,16 @@ class AsyncLLMEngine:
 
     def _worker(self) -> None:
         izer = self._interleave
+        # Host spans of a profiler capture (observability/phases.py): what
+        # the worker does outside engine.step(). Nothing while none runs.
+        span = self.engine.obs.phases.span
         while True:
             with self._cv:
                 while not (self._shutdown or self._inbox or self._aborts
                            or self._ops
                            or self.engine.has_unfinished_requests()):
-                    self._cv.wait()
+                    with span("worker.wait"):
+                        self._cv.wait()
                 inbox, self._inbox = self._inbox, []
                 aborts, self._aborts = self._aborts, []
                 ops, self._ops = self._ops, []
@@ -319,116 +323,119 @@ class AsyncLLMEngine:
                 # lock is the KGCT021 bug class itself): widen the window
                 # between inbox capture and ops/admission/step.
                 izer.worker_yield("worker.wake")
-            for fn, fut in ops:
-                try:
-                    result = fn(self.engine)
-                except BaseException as e:
-                    if fut is not None:
-                        fut.set_exception(e)
+            with span("worker.admit"):
+                for fn, fut in ops:
+                    try:
+                        result = fn(self.engine)
+                    except BaseException as e:
+                        if fut is not None:
+                            fut.set_exception(e)
+                        else:
+                            logger.exception("worker op failed")
                     else:
-                        logger.exception("worker op failed")
-                else:
-                    if fut is not None:
-                        fut.set_result(result)
-            # A request whose add and abort arrived in the same wakeup must
-            # not be admitted: the abort would no-op (nothing to abort yet)
-            # and the request would then run orphaned to completion.
-            aborted = set(aborts)
-            inbox = [item for item in inbox if item[0] not in aborted]
-            for rid in aborted:
-                self._handoffs.pop(rid, None)
-                self._holds.discard(rid)
-                self._arrival_t0s.pop(rid, None)
-                self._resumes.pop(rid, None)
-            if self.leader is not None:
-                # Replicate this iteration's events to follower ranks BEFORE
-                # stepping: their engines apply the same events and step
-                # once, keeping the SPMD collectives in lockstep. A broadcast
-                # failure means the process group is broken (a dead follower
-                # hangs the collectives anyway): group-abort all in-flight
-                # work, fail every waiter loudly, and detach the leader —
-                # this rank stays serveable while the StatefulSet restarts
-                # the followers (restart-first recovery).
-                try:
-                    self.leader.broadcast(inbox, aborts)
-                except Exception as e:
-                    logger.exception("directive broadcast failed; "
-                                     "group-aborting in-flight work")
-                    # Waiters fail FIRST: the drain below steps an engine
-                    # whose process group just broke, and on a real
-                    # multi-host mesh those steps can hang on collectives —
-                    # clients must not be held hostage to that.
-                    err = RuntimeError(
-                        f"multihost process group failed: {e}")
-                    for rid in list(self._queues):
-                        self._post_exc(rid, err)
+                        if fut is not None:
+                            fut.set_result(result)
+                # A request whose add and abort arrived in the same wakeup must
+                # not be admitted: the abort would no-op (nothing to abort yet)
+                # and the request would then run orphaned to completion.
+                aborted = set(aborts)
+                inbox = [item for item in inbox if item[0] not in aborted]
+                for rid in aborted:
+                    self._handoffs.pop(rid, None)
+                    self._holds.discard(rid)
+                    self._arrival_t0s.pop(rid, None)
+                    self._resumes.pop(rid, None)
+                if self.leader is not None:
+                    # Replicate this iteration's events to follower ranks
+                    # BEFORE stepping: their engines apply the same events and
+                    # step once, keeping the SPMD collectives in lockstep. A
+                    # broadcast failure means the process group is broken (a
+                    # dead follower hangs the collectives anyway): group-abort
+                    # all in-flight work, fail every waiter loudly, and detach
+                    # the leader — this rank stays serveable while the
+                    # StatefulSet restarts the followers (restart-first
+                    # recovery).
                     try:
-                        self.leader.close()
-                    except Exception:
-                        pass
-                    self.leader = None
-                    from .multihost import group_abort
-                    # Armed watchdog: if the drain DOES hang on a dead
-                    # rank's collectives, /health flips and kubelet
-                    # restarts the pod (restart-first recovery) instead of
-                    # leaving a healthy-looking zombie.
-                    wd = self.watchdog
-                    if wd is not None:
-                        wd.arm()
-                    try:
-                        group_abort(self.engine)
-                    except Exception:
-                        logger.exception("group-abort drain failed")
-                    finally:
-                        if wd is not None:
-                            wd.disarm()
-                    continue
-            for rid in aborts:
-                self.engine.abort_request(rid)
-                self._post(StreamChunk(rid, [], [], True, "abort"))
-            for rid, ids, params in inbox:
-                handoff = self._handoffs.pop(rid, None)
-                arrival_t0 = self._arrival_t0s.pop(rid, None)
-                resume_outputs = self._resumes.pop(rid, None)
-                hold = rid in self._holds
-                self._holds.discard(rid)
-                try:
-                    if handoff is not None:
-                        # import_request pops the stamp; keep a copy so an
-                        # ENGINE-side import failure backdates the recompute
-                        # admission the same way a failed pull does.
-                        if arrival_t0 is None:
-                            arrival_t0 = handoff.get("_ttft_t0")
+                        self.leader.broadcast(inbox, aborts)
+                    except Exception as e:
+                        logger.exception("directive broadcast failed; "
+                                         "group-aborting in-flight work")
+                        # Waiters fail FIRST: the drain below steps an engine
+                        # whose process group just broke, and on a real
+                        # multi-host mesh those steps can hang on collectives —
+                        # clients must not be held hostage to that.
+                        err = RuntimeError(
+                            f"multihost process group failed: {e}")
+                        for rid in list(self._queues):
+                            self._post_exc(rid, err)
                         try:
-                            for out in self.engine.import_request(
-                                    rid, ids, params, handoff):
-                                self._post(_chunk_of(out))
-                            continue
-                        except Exception as e:
-                            # Degrade to local recompute — byte-identical,
-                            # just slower; the trace records the fallback.
-                            logger.warning(
-                                "kv import for %s failed (%s); falling back"
-                                " to local prefill", rid, e,
-                                extra={"request_id": rid})
-                            self.engine.obs.tracer.emit(
-                                "handoff", rid, side="import",
-                                outcome="import_fallback", error=str(e))
-                            if self.on_import_fallback is not None:
-                                try:
-                                    # rid lets the serving layer attribute
-                                    # a MID-STREAM resume import (token-
-                                    # replay rung) separately from a
-                                    # disagg prefill re-run.
-                                    self.on_import_fallback(rid)
-                                except Exception:
-                                    logger.exception(
-                                        "import-fallback hook failed")
-                    self.engine.add_request(rid, ids, params, hold_kv=hold,
-                                            arrival_t0=arrival_t0,
-                                            resume_outputs=resume_outputs)
-                except ValueError as e:   # oversized prompt etc.
-                    self._post_exc(rid, e)
+                            self.leader.close()
+                        except Exception:
+                            pass
+                        self.leader = None
+                        from .multihost import group_abort
+                        # Armed watchdog: if the drain DOES hang on a dead
+                        # rank's collectives, /health flips and kubelet
+                        # restarts the pod (restart-first recovery) instead of
+                        # leaving a healthy-looking zombie.
+                        wd = self.watchdog
+                        if wd is not None:
+                            wd.arm()
+                        try:
+                            group_abort(self.engine)
+                        except Exception:
+                            logger.exception("group-abort drain failed")
+                        finally:
+                            if wd is not None:
+                                wd.disarm()
+                        continue
+                for rid in aborts:
+                    self.engine.abort_request(rid)
+                    self._post(StreamChunk(rid, [], [], True, "abort"))
+                for rid, ids, params in inbox:
+                    handoff = self._handoffs.pop(rid, None)
+                    arrival_t0 = self._arrival_t0s.pop(rid, None)
+                    resume_outputs = self._resumes.pop(rid, None)
+                    hold = rid in self._holds
+                    self._holds.discard(rid)
+                    try:
+                        if handoff is not None:
+                            # import_request pops the stamp; keep a copy so an
+                            # ENGINE-side import failure backdates the
+                            # recompute admission the same way a failed pull
+                            # does.
+                            if arrival_t0 is None:
+                                arrival_t0 = handoff.get("_ttft_t0")
+                            try:
+                                for out in self.engine.import_request(
+                                        rid, ids, params, handoff):
+                                    self._post(_chunk_of(out))
+                                continue
+                            except Exception as e:
+                                # Degrade to local recompute — byte-identical,
+                                # just slower; the trace records the fallback.
+                                logger.warning(
+                                    "kv import for %s failed (%s); falling back"
+                                    " to local prefill", rid, e,
+                                    extra={"request_id": rid})
+                                self.engine.obs.tracer.emit(
+                                    "handoff", rid, side="import",
+                                    outcome="import_fallback", error=str(e))
+                                if self.on_import_fallback is not None:
+                                    try:
+                                        # rid lets the serving layer attribute
+                                        # a MID-STREAM resume import (token-
+                                        # replay rung) separately from a
+                                        # disagg prefill re-run.
+                                        self.on_import_fallback(rid)
+                                    except Exception:
+                                        logger.exception(
+                                            "import-fallback hook failed")
+                        self.engine.add_request(rid, ids, params, hold_kv=hold,
+                                                arrival_t0=arrival_t0,
+                                                resume_outputs=resume_outputs)
+                    except ValueError as e:   # oversized prompt etc.
+                        self._post_exc(rid, e)
             if self.engine.has_unfinished_requests():
                 if izer is not None:
                     # Between admission and dispatch: the window a loop-
@@ -438,8 +445,10 @@ class AsyncLLMEngine:
                 if wd is not None:
                     wd.arm()
                 try:
-                    for out in self.engine.step():
-                        self._post(_chunk_of(out))
+                    outs = self.engine.step()
+                    with span("worker.post"):
+                        for out in outs:
+                            self._post(_chunk_of(out))
                 except Exception as e:  # engine wedged: fail all waiters
                     logger.exception("engine step failed")
                     # Black-box dump: the ring holds the requests/steps that

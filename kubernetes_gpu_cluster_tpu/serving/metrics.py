@@ -21,6 +21,7 @@ from __future__ import annotations
 import time
 
 from ..engine.engine import device_memory_stats
+from ..utils.compile_cache import COMPILE_COUNTERS
 
 
 class Metrics:
@@ -30,6 +31,9 @@ class Metrics:
         self.responses_total = 0
         self.response_tokens_total = 0
         self._started = time.monotonic()
+        # main() installed them before the first compilation; an embedder
+        # that skipped configure_compile_cache() counts from here.
+        COMPILE_COUNTERS.install()
 
     # -- hooks called by the API layer --------------------------------------
 
@@ -122,18 +126,39 @@ class Metrics:
         # straight from the jax runtime's allocator counters (0/0 on CPU —
         # nan-free), and the jit-cache entry count across every step program
         # (the tier-1 compile guard's number; flat in steady state, growth
-        # under constant traffic = recompilation storm). The jit series is
-        # a GAUGE despite the _total spelling: it reads the live cache, so
+        # under constant traffic = new shapes keep arriving). The jit series
+        # is a GAUGE despite the _total spelling: it reads the live cache, so
         # jax.clear_caches()/engine rebuild can shrink it — a counter TYPE
         # would make rate() report a phantom compile storm on any reset.
+        # It counts SHAPES MET by the step programs: a load from the
+        # persistent cache counts, an eager one-op program does not. What
+        # XLA compiled is the kgct_xla_compile_* family below, counted by
+        # JAX's own monitoring events for every program of the process.
         hbm_limit, hbm_in_use = device_memory_stats()[0]
+        cc = COMPILE_COUNTERS
         lines += [
             "# TYPE kgct_hbm_bytes_limit gauge",
             f"kgct_hbm_bytes_limit {hbm_limit}",
             "# TYPE kgct_hbm_bytes_in_use gauge",
             f"kgct_hbm_bytes_in_use {hbm_in_use}",
+            "# HELP kgct_jit_compiles_total jit-cache entries of the step "
+            "programs: shapes met so far, persistent-cache loads included, "
+            "eager one-op programs not seen",
             "# TYPE kgct_jit_compiles_total gauge",
             f"kgct_jit_compiles_total {eng.compiled_step_variants()}",
+            "# HELP kgct_xla_compile_requests_total programs handed to the "
+            "XLA backend, compiled there or loaded from the persistent cache",
+            "# TYPE kgct_xla_compile_requests_total counter",
+            f"kgct_xla_compile_requests_total {cc.requests}",
+            "# HELP kgct_xla_compile_seconds_total seconds spent in those "
+            "requests (compilation, or the cache load)",
+            "# TYPE kgct_xla_compile_seconds_total counter",
+            f"kgct_xla_compile_seconds_total {round(cc.seconds, 6)}",
+            "# HELP kgct_xla_compile_cache_hits_total requests answered by "
+            "the persistent compilation cache; requests minus hits were "
+            "compiled",
+            "# TYPE kgct_xla_compile_cache_hits_total counter",
+            f"kgct_xla_compile_cache_hits_total {cc.cache_hits}",
         ]
         # Histograms (TTFT/TPOT/queue-wait/prefill/step/batch-size/e2e),
         # per-phase step-time counters, and the sampled-decode-ratio gauge —

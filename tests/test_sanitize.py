@@ -288,8 +288,13 @@ class _ScriptedEngine:
     makes that separation testable in milliseconds (no device, no jit)."""
 
     def __init__(self, n: int = 4):
+        from kubernetes_gpu_cluster_tpu.observability.phases import (
+            StepPhaseStats)
         self.n = n
         self._live: dict = {}
+        # the worker brackets its turn in obs.phases spans (no-ops here:
+        # no profiler capture runs)
+        self.obs = types.SimpleNamespace(phases=StepPhaseStats())
 
     def has_unfinished_requests(self):
         return bool(self._live)
