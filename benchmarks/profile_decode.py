@@ -9,7 +9,7 @@ Components timed at the serving bench shape (TinyLlama-1.1B, B=64):
   1. one decode substep (forward + logits), XLA vs Pallas attention
   2. weights-only pass (attention stubbed) - the HBM weight-streaming floor
   3. the attention op alone (both paths), one layer x L
-  4. KV scatter (write_kv_pages_all) alone
+  4. the KV write (write_kv_pages_all) alone: XLA loop and Pallas kernel
 """
 
 from __future__ import annotations
@@ -150,17 +150,22 @@ def main():
     if jax.default_backend() == "tpu":
         print(f"attn x{L} Pallas:       {timed_chain(attn_loop(True), (q1, None)):8.3f} ms")
 
-    # --- 4: KV scatter alone ------------------------------------------------
-    k_all = jnp.asarray(rng.standard_normal((L, B, nkv, hd)), cfg.jnp_dtype)
+    # --- 4: KV write alone: the XLA loop, and on the chip the DMA kernel ---
+    k_all = jnp.asarray(rng.standard_normal((L, B, nkv * hd)), cfg.jnp_dtype)
 
-    @functools.partial(jax.jit, donate_argnums=0)
-    def scatter(state):
-        kvc, t = state
-        return attn.write_kv_pages_all(kvc[0], kvc[1], k_all, k_all,
-                                       slot_mapping), t
+    def kv_write(use_pallas):
+        @functools.partial(jax.jit, donate_argnums=0)
+        def write(pools):
+            return attn.write_kv_pages_all(*pools, k_all, k_all, slot_mapping,
+                                           use_pallas=use_pallas)
+        return write
 
-    kv_s = mk_kv()
-    print(f"kv scatter:            {timed_chain(scatter, ((kv_s.k, kv_s.v), tokens0)):8.3f} ms")
+    for name, use_pallas in (("loop", False), ("kernel", True)):
+        if use_pallas and jax.default_backend() != "tpu":
+            continue
+        kv_s = mk_kv()
+        ms = timed_chain(kv_write(use_pallas), (kv_s.k, kv_s.v))
+        print(f"kv write ({name}):{' ' * (13 - len(name))}{ms:8.3f} ms")
 
 
 if __name__ == "__main__":

@@ -132,38 +132,74 @@ def check_prefill_history(nh, n_kv, hd, pps, T) -> None:
 
 
 def check_kv_write(L, n_kv, hd, T) -> None:
-    """The post-scan KV write on a donated pool must not copy the pool: a
-    served pool takes ~0.9 of free HBM, so one pool-sized temporary is an
-    OOM at the first prefill. Asserts XLA's temp bytes stay far under the
-    pool's, checks the rows landed, and prints what one T-token flush costs
-    (host clock around block_until_ready, steady state)."""
-    P = 65
+    """The post-scan KV write on a donated pool, XLA loop and Pallas kernel
+    side by side: neither may copy the pool (a served pool takes ~0.9 of
+    free HBM, so one pool-sized temporary is an OOM at the first prefill:
+    XLA's temp bytes must stay far under the pool's), the kernel must leave
+    bitwise the loop's pool (sentinel rows included: the ref bitcast behind
+    its 32-bit view of a 16-bit pool exists only on the chip), and what one
+    T-token flush costs (host clock around block_until_ready, steady
+    state). Slots as the scheduler lays them out: T <= 64 is a decode step
+    (one token a page, offsets odd and even), above that packed prompts of
+    200 tokens, their pages scattered."""
+    P = 65 if T <= 64 else 2 + (T // 200 + 1) * 2
     kd = n_kv * hd
     rng = np.random.default_rng(5)
-    k_pool = jnp.zeros((L, P, PS, kd), jnp.bfloat16)
-    v_pool = jnp.zeros((L, P, PS, kd), jnp.bfloat16)
-    rows = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
-    slots = jnp.asarray(PS + rng.permutation((P - 1) * PS)[:T], jnp.int32)
+    pages = 1 + rng.permutation(P - 1)
+    if T <= 64:
+        slots = pages[:T] * PS + rng.integers(0, PS, T)
+    else:
+        off = 17 + np.arange(200)          # a prompt starts mid-page
+        slots = np.concatenate([
+            np.where(off < PS, pages[2 * s] * PS + off,
+                     pages[2 * s + 1] * PS + off - PS)
+            for s in range(T // 200 + 1)])[:T]
+    assert len(set(slots.tolist())) == T
+    slots = jnp.asarray(slots, jnp.int32)
+    rows_k = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
+    rows_v = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
     pool_bytes = 2 * L * P * PS * kd * 2
-    fn = jax.jit(write_kv_pages_all, donate_argnums=(0, 1))
-    temp = fn.lower(k_pool, v_pool, rows, rows,
-                    slots).compile().memory_analysis().temp_size_in_bytes
-    print(f"kv_write T={T}: temp {temp / 2**20:.1f} MiB "
-          f"(k+v pool {pool_bytes / 2**20:.1f} MiB)")
-    assert temp < pool_bytes // 8, (
-        f"KV write keeps {temp} temp bytes against a {pool_bytes}-byte "
-        "pool: it copies the pool")
-    k_pool, v_pool = jax.block_until_ready(
-        fn(k_pool, v_pool, rows, rows, slots))           # compile + warm
-    got = k_pool.reshape(L, P * PS, kd)[:, slots]
-    assert bool(jnp.all(got == rows)), "rows did not land in their slots"
-    n = 5
-    t0 = time.perf_counter()
-    for _ in range(n):
-        k_pool, v_pool = fn(k_pool, v_pool, rows, rows, slots)
-    jax.block_until_ready((k_pool, v_pool))
-    print(f"kv_write T={T} L={L} kd={kd}: "
-          f"{(time.perf_counter() - t0) / n * 1e3:.2f} ms per flush")
+    pools = {}
+    n = 20      # flushes chained in ONE program: a sub-millisecond program's
+                # host-clock time is its dispatch, not its device time
+    for name, use_pallas in (("loop", False), ("kernel", True)):
+        def write(kk, vv, ka, va, sl, up=use_pallas):
+            return write_kv_pages_all(kk, vv, ka, va, sl, use_pallas=up)
+
+        def chain(kk, vv, ka, va, sl):
+            return jax.lax.fori_loop(
+                0, n, lambda _, kv: write(*kv, ka, va, sl), (kk, vv))
+        fn = jax.jit(write, donate_argnums=(0, 1))
+        fn_n = jax.jit(chain, donate_argnums=(0, 1))
+        k_pool = jnp.full((L, P, PS, kd), 7.0, jnp.bfloat16)   # sentinel
+        v_pool = jnp.full((L, P, PS, kd), -3.0, jnp.bfloat16)
+        args = (rows_k, rows_v, slots)
+        for f in (fn, fn_n):    # neither alone nor as a loop's carry
+            temp = f.lower(k_pool, v_pool, *args
+                           ).compile().memory_analysis().temp_size_in_bytes
+            assert temp < pool_bytes // 8, (
+                f"KV write ({name}) keeps {temp} temp bytes against a "
+                f"{pool_bytes}-byte pool: it copies the pool")
+        k_pool, v_pool = jax.block_until_ready(fn(k_pool, v_pool, *args))
+        pools[name] = (np.asarray(k_pool).view(np.uint16),
+                       np.asarray(v_pool).view(np.uint16))
+        k_pool, v_pool = jax.block_until_ready(fn_n(k_pool, v_pool, *args))
+        t0 = time.perf_counter()
+        for _ in range(3):
+            k_pool, v_pool = fn_n(k_pool, v_pool, *args)
+        jax.block_until_ready((k_pool, v_pool))
+        print(f"kv_write {name} T={T} L={L} kd={kd}: "
+              f"{(time.perf_counter() - t0) / (3 * n) * 1e3:.3f} ms per flush, "
+              f"temp {temp / 2**20:.1f} MiB "
+              f"(k+v pool {pool_bytes / 2**20:.1f} MiB)")
+    got = pools["loop"][0].reshape(L, P * PS, kd)[:, np.asarray(slots)]
+    assert (got == np.asarray(rows_k).view(np.uint16)).all(), (
+        "loop: rows did not land in their slots")
+    for loop, kernel, which in zip(pools["loop"], pools["kernel"], "kv"):
+        assert (loop == kernel).all(), (
+            f"kv_write kernel's {which} pool differs from the loop's in "
+            f"{int((loop != kernel).sum())} elements")
+    print(f"kv_write T={T}: kernel pool == loop pool, bitwise")
 
 
 def check_int4_matmul() -> None:
@@ -212,7 +248,8 @@ def main() -> None:
         "decode": lambda: check_decode(nh, n_kv, hd, pps, B),
         "prefill": lambda: check_prefill(nh, n_kv, hd, T),
         "hist": lambda: check_prefill_history(nh, n_kv, hd, pps, T),
-        "kvwrite": lambda: check_kv_write(cfg.num_layers, n_kv, hd, T),
+        "kvwrite": lambda: [check_kv_write(cfg.num_layers, n_kv, hd, n)
+                            for n in (B, T)],
         "int4": check_int4_matmul,
     }
     for name in args.kernels.split(","):

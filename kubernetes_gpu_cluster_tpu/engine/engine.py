@@ -504,6 +504,7 @@ class LLMEngine:
         allocated. Raises with the compiler's message on failure."""
         from ..ops.pallas.flash_prefill import flash_ragged_prefill
         from ..ops.pallas.flash_prefill_hist import flash_prefill_history
+        from ..ops.pallas.kv_write import kv_write
         from ..ops.pallas.paged_decode import pallas_paged_decode
 
         cfg = self.model_config
@@ -554,6 +555,14 @@ class LLMEngine:
                   arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hd)),
                   arr((T,), i32), arr((T,), i32), pool, pool,
                   arr((pps,), i32), arr((), i32), arr((), i32))
+        # The post-scan KV write, at the pool's real depth (L shapes its
+        # VMEM blocks) for the largest decode and prefill flushes.
+        layers = cfg.num_layers // self.pp_size
+        deep_pool = arr((layers, 2, ps, nkv * hd), pool.dtype)
+        for n in (B, T):
+            rows = arr((layers, n, nkv * hd))
+            probe(f"kv_write[T={n}]", kv_write, deep_pool, deep_pool,
+                  rows, rows, arr((n,), i32))
         logger.info("Pallas kernels compiled at the served geometry: %s",
                     ", ".join(compiled))
 
@@ -622,7 +631,8 @@ class LLMEngine:
                 attn_impl = build_ring_prefill(
                     self.mesh, cfg.num_kv_heads,
                     cfg.num_heads // cfg.num_kv_heads, cfg.head_dim ** -0.5)
-                attn_mesh = None
+                # attn_impl replaces the attention attn_mesh would shard;
+                # the mesh stays for the post-scan KV write kernel.
 
             def fwd(params, kv, int_t, logits_indices):
                 meta = PrefillMeta(seg_ids=int_t[1], positions=int_t[2],
@@ -798,6 +808,7 @@ class LLMEngine:
         accepted token, matching the decode window's per-substep bump."""
         cfg = self.model_config
         use_pallas = self.use_pallas
+        attn_mesh = self._gspmd_attn_mesh()
         V = cfg.vocab_size
 
         def spec_step(params, kv: KVCache, int_t, int_b, float_b,
@@ -810,7 +821,8 @@ class LLMEngine:
                             slot_mapping=int_t[3], page_tables=page_tables,
                             context_lens=context_lens)
             hidden, kv, _ = model_lib.forward_spec_verify(
-                params, cfg, int_t[0], meta, kv, use_pallas=use_pallas)
+                params, cfg, int_t[0], meta, kv, use_pallas=use_pallas,
+                attn_mesh=attn_mesh)
             # Verification needs logits over EVERY draft position, so the
             # vocab projection runs on all R_pad*S rows (the one place the
             # engine pays more than B logit rows; amortized by acceptance).

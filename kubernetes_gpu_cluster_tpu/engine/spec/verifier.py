@@ -13,7 +13,7 @@ and ``R_pad`` is the decode-bucketed row count:
     slot_mapping  KV write slot per token    (multi-token append: every
                                               slice token's K/V commits to
                                               the paged pool in the one
-                                              post-scan scatter)
+                                              post-scan write)
     page_tables   [R_pad, pages_bucket]      per-row history pages
     context_lens  [R_pad]                    committed tokens incl. x_{n-1}
 

@@ -460,9 +460,11 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     trace); the Pallas kernel addresses the stacked pool with a dynamic layer
     index instead, moving zero pool bytes. Each layer's freshly projected
     K/V come out as scan ys, and the caller commits them to the pool in ONE
-    donated scatter after the scan (ops.attention.write_kv_pages_all).
-    Threading the pool through the scan as carry/ys would force a full pool
-    copy per step.
+    in-place write of the donated pool after the scan
+    (ops.attention.write_kv_pages_all: on the chip a Pallas kernel that
+    read-modify-writes the touched pool tiles by DMA, all in flight at
+    once; elsewhere a loop of row updates). Threading the pool through the
+    scan as carry/ys would force a full pool copy per step.
 
     attn_fn(lp, q, k, v, layer_idx) -> attn_out, where the pool holds tokens
     written in PREVIOUS steps only (attention folds the current step's k/v in
@@ -546,7 +548,9 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
         kv = KVCache(k=kv.k[layer_slice[0]:layer_slice[1]],
                      v=kv.v[layer_slice[0]:layer_slice[1]])
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping))
+                                         meta.slot_mapping,
+                                         use_pallas=use_pallas,
+                                         mesh=attn_mesh))
     selected = h[meta.logits_indices]
     return _norm(cfg, selected, params, "final_norm"), new_kv, h
 
@@ -582,7 +586,9 @@ def forward_prefill_hist(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                   tp_axis=tp_axis, ep_axis=ep_axis,
                                   use_pallas=use_pallas)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping))
+                                         meta.slot_mapping,
+                                         use_pallas=use_pallas,
+                                         mesh=attn_mesh))
     selected = h[meta.logits_indices]
     return _norm(cfg, selected, params, "final_norm"), new_kv, h
 
@@ -618,7 +624,9 @@ def forward_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
                                   use_pallas=use_pallas)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping))
+                                         meta.slot_mapping,
+                                         use_pallas=use_pallas,
+                                         mesh=attn_mesh))
     selected = h[meta.logits_indices]
     return _norm(cfg, selected, params, "final_norm"), new_kv, h
 
@@ -653,13 +661,16 @@ def forward_spec_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
                                   use_pallas=use_pallas)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping))
+                                         meta.slot_mapping,
+                                         use_pallas=use_pallas,
+                                         mesh=attn_mesh))
     selected = h[meta.logits_indices]
     return _norm(cfg, selected, params, "final_norm"), new_kv, h
 
 
 def forward_spec_verify(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                        meta: SpecMeta, kv: KVCache, use_pallas=None):
+                        meta: SpecMeta, kv: KVCache, use_pallas=None,
+                        attn_mesh=None):
     """Speculative-verification forward: ONE program scores every running
     sequence's k drafted tokens. Embedding, QKV/MLP matmuls and norms run
     over the flat ``[R_pad * S]`` token axis (the weight streaming a decode
@@ -669,9 +680,11 @@ def forward_spec_verify(params: Params, cfg: ModelConfig, tokens: jax.Array,
     S x S causal block per row). Returns (normed_hidden [T, d] over EVERY
     slot — the verifier needs logits at all draft positions, not one
     sampled row — new_kv, raw_hidden [T, d]). All new K/V (including
-    drafts that will be rejected) commit in the one post-scan scatter;
+    drafts that will be rejected) commit in the one post-scan write;
     rejected slots sit past the sequence's committed length and are
-    overwritten before any later step reads them."""
+    overwritten before any later step reads them. ``attn_mesh``: under a
+    GSPMD mesh the KV write kernel runs per shard (attention here is XLA
+    on every backend)."""
     scale = cfg.head_dim ** -0.5
     h = _embed(params, cfg, tokens, meta.positions)
 
@@ -683,7 +696,9 @@ def forward_spec_verify(params: Params, cfg: ModelConfig, tokens: jax.Array,
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
                                   use_pallas=use_pallas)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping))
+                                         meta.slot_mapping,
+                                         use_pallas=use_pallas,
+                                         mesh=attn_mesh))
     return _norm(cfg, h, params, "final_norm"), new_kv, h
 
 
@@ -708,7 +723,7 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     def attn_fn(lp, q, k, v, layer_idx):
         # Pool holds positions 0..ctx-2; this step's k/v fold in directly and
-        # are committed to the pool in one post-scan scatter. The STACKED pool
+        # are committed to the pool in one post-scan write. The STACKED pool
         # + dynamic layer index go straight to the kernel — no per-layer pool
         # slice is ever materialized (see _layer_scan docstring).
         if attn_mesh is not None:
@@ -723,7 +738,9 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
                                   layer_slice, tp_axis=tp_axis, ep_axis=ep_axis)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping))
+                                         meta.slot_mapping,
+                                         use_pallas=use_pallas,
+                                         mesh=attn_mesh))
     return _norm(cfg, h, params, "final_norm"), new_kv, h
 
 

@@ -35,7 +35,9 @@ def _on_tpu() -> bool:
 
 def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
                        k_all: jax.Array, v_all: jax.Array,
-                       slot_mapping: jax.Array) -> tuple[jax.Array, jax.Array]:
+                       slot_mapping: jax.Array, *,
+                       use_pallas: Optional[bool] = None,
+                       mesh=None) -> tuple[jax.Array, jax.Array]:
     """Write every layer's new K/V vectors into the page pool at once.
 
     kv_k/kv_v:    [L, P, page_size, n_kv*hd] (the whole pool, heads flattened)
@@ -51,17 +53,34 @@ def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
     pool copy per step; attention instead reads the pool pre-write and takes
     the current token's K/V separately (see paged_decode_attention).
 
-    One formulation for every T: a fori_loop of per-token
+    Dispatch, as for the attention kernels: ``use_pallas=True`` means the
+    Pallas DMA kernel (``ops.pallas.kv_write``: every tile's
+    read-modify-write of a block in flight at once, the pool aliased to the
+    result) or its exception, never a fallback; None = the kernel on TPU.
+    ``mesh``: under a GSPMD tp mesh the pool is sharded on its lane dim and
+    the kernel runs per shard (:func:`write_kv_pages_all_tp`). False (CPU,
+    tests) is the XLA loop below, the plain reference the kernel is held to
+    bitwise.
+
+    The loop is one formulation for every T: a fori_loop of per-token
     dynamic_update_slices, which XLA performs in place in the pool's own
-    layout. The batched row-scatter (``.at[:, slots].set``) is NOT in place
-    on TPU: the scatter wants the slot axis major-most, so XLA transposes
-    the whole pool to that layout and back, and pinning the pool's layout
-    with ``with_layout_constraint`` still leaves one pool-sized pre-copy at
-    kd=1024 (PR 21, v5e: ``copy.44 = bf16[36,54016,1024]{2,0,1}``, 4.12 GB,
-    compile-time OOM in the first chunked-prefill step of qwen3-4b). What
-    the loop costs per 2048-token flush is in PERF.md; a page-granular DMA
-    write is the known faster design.
+    layout, each waiting for the one before (5.5 us apiece on the v5e at
+    L=36, kd=1024; PERF.md section 6, PR 25). The batched row-scatter
+    (``.at[:, slots].set``) is NOT in place on TPU: the scatter wants the
+    slot axis major-most, so XLA transposes the whole pool to that layout
+    and back, and pinning the pool's layout with ``with_layout_constraint``
+    still leaves one pool-sized pre-copy at kd=1024 (PR 21, v5e:
+    ``copy.44 = bf16[36,54016,1024]{2,0,1}``, 4.12 GB, compile-time OOM in
+    the first chunked-prefill step of qwen3-4b).
     """
+    if mesh is not None:
+        return write_kv_pages_all_tp(mesh, kv_k, kv_v, k_all, v_all,
+                                     slot_mapping)
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    if use_pallas:
+        from .pallas.kv_write import kv_write
+        return kv_write(kv_k, kv_v, k_all, v_all, slot_mapping)
     L, P, ps, kd = kv_k.shape
     T = k_all.shape[1]
     fk = kv_k.reshape(L, P * ps, kd)
@@ -190,8 +209,9 @@ def paged_decode_attention_xla(
     """Gather-then-attend reference implementation.
 
     The pool holds positions 0..context_len-2; the current token's K/V arrive
-    separately because pool writes are deferred to one post-scan scatter
-    (write_kv_pages_all). The gather materializes [B, pages_per_seq*page_size]
+    separately because pool writes are deferred to one post-scan write
+    (write_kv_pages_all: a DMA kernel on the chip, a loop of row updates
+    here). The gather materializes [B, pages_per_seq*page_size]
     worth of K/V — HBM-bandwidth-bound, which is what the Pallas kernel
     (pallas_paged_decode) avoids by streaming only valid pages through VMEM
     with online softmax."""
@@ -246,7 +266,7 @@ def spec_verify_attention_xla(
     S queries/row — the pool gather is identical; the "current token" term
     becomes an S x S causal block. The pool holds positions
     0..context_len-2 (the slice's own K/V arrive in-batch and are committed
-    by the caller's post-scan scatter, the same pre-write contract as every
+    by the caller's post-scan write, the same pre-write contract as every
     other path). Draft slots past the model cap were routed to the scrap
     page by the scheduler; their outputs are garbage the host discards.
 
@@ -383,7 +403,7 @@ def mixed_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
 
     Both halves read the pool PRE-write (this step's K/V fold in directly:
     the chunk's in-batch, each decode row's as k_cur/v_cur) and the caller
-    commits all new K/V in the one post-scan scatter — the same contract as
+    commits all new K/V in the one post-scan write — the same contract as
     the pure paths, so no new kernel is needed: prefill segments route
     through the flash-prefill-history kernel and decode rows through paged
     decode within one dispatched step. Chunk and decode sequences are
@@ -439,7 +459,7 @@ def spec_mixed_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
       identical semantics to the pure spec step).
 
     Both halves read the pool PRE-write and the caller commits all new K/V
-    (chunk AND draft slots) in the one post-scan scatter — the same
+    (chunk AND draft slots) in the one post-scan write — the same
     contract as every other path, so the composition needs no new kernel:
     it routes each half through the op the pure paths already use. Chunk
     and verify sequences are disjoint and each half addresses only its own
@@ -508,6 +528,28 @@ def paged_decode_attention_tp(mesh, q, k_cache_l, v_cache_l, page_tables,
 
     return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=head_spec, check_vma=False)(*args)
+
+
+def write_kv_pages_all_tp(mesh, kv_k, kv_v, k_all, v_all, slot_mapping, *,
+                          interpret=False):
+    """shard_map-wrapped kv_write over ``mesh``'s tp axis: pool and new rows
+    split on the flattened kv-head lane dim, slots replicated. Each device
+    updates its own shard of the donated pool in place; no collective."""
+    from jax.sharding import PartitionSpec as P
+
+    from .pallas.kv_write import kv_write
+
+    pool_spec = P(None, None, None, "tp")
+    rows_spec = P(None, None, "tp")
+
+    def body(kk, vv, ka, va, slots):
+        return kv_write(kk, vv, ka, va, slots, interpret=interpret)
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(pool_spec, pool_spec, rows_spec, rows_spec, P()),
+        out_specs=(pool_spec, pool_spec), check_vma=False)(
+            kv_k, kv_v, k_all, v_all, slot_mapping)
 
 
 def prefill_history_attention_tp(mesh, q, k, v, seg_ids, positions, k_pool,

@@ -10,8 +10,13 @@ reference ``values-01-minimal-example*.yaml`` deploy):
   gathers the full padded page table instead).
 - flash_prefill.py — ragged (segment-causal) flash attention for prefill,
   O(T) memory (the XLA fallback materializes the O(T^2) score matrix).
+- flash_prefill_hist.py — chunked prefill: the chunk against its own
+  history pages in the pool.
+- kv_write.py — the post-scan KV page write: read-modify-write of the
+  touched pool tiles by DMA, in place (the XLA reference is a loop of
+  dynamic_update_slices).
 
-Both are numerically validated against the XLA reference implementations in
+They are numerically validated against the XLA reference implementations in
 tests/test_pallas.py (interpret mode on CPU; compiled on real TPU).
 """
 

@@ -238,6 +238,7 @@ def test_kv_write_is_one_formulation_for_every_flush_size(T):
      "flash_ragged_prefill", 6),
     (attention.prefill_history_attention, "flash_prefill_hist",
      "flash_prefill_history", 10),
+    (attention.write_kv_pages_all, "kv_write", "kv_write", 5),
 ])
 def test_use_pallas_true_means_the_kernel_or_its_exception(
         monkeypatch, dispatcher, kernel_module, kernel, n_args):

@@ -230,29 +230,42 @@ class TestPallasUnderMesh:
             context_lens=jnp.asarray([6, 4], jnp.int32))
         tokens = jnp.asarray([7, 11], jnp.int32)
 
-        ref, _, _ = model_lib.forward_decode(params, cfg, tokens, meta, kv,
-                                             use_pallas=False)
+        ref, ref_kv, _ = model_lib.forward_decode(params, cfg, tokens, meta,
+                                                  kv, use_pallas=False)
 
-        # Route the tp wrapper's kernel through interpret mode (CPU mesh).
+        # Route the tp wrappers' kernels (attention and the post-scan KV
+        # write) through interpret mode (CPU mesh).
         orig = attn.paged_decode_attention_tp
+        orig_write = attn.write_kv_pages_all_tp
         def tp_interp(mesh_, *a, **kw):
             return orig(mesh_, *a, **{**kw, "interpret": True})
         attn.paged_decode_attention_tp = tp_interp
         model_lib.paged_decode_attention_tp = tp_interp
+        attn.write_kv_pages_all_tp = functools.partial(orig_write,
+                                                       interpret=True)
         try:
             sharded_params = jax.device_put(params, param_shardings(mesh, cfg))
             sharded_kv = jax.tree.map(
                 functools.partial(jax.device_put,
                                   device=kv_cache_sharding(mesh, cfg)), kv)
-            got, _, _ = jax.jit(
+            got, got_kv, _ = jax.jit(
                 lambda p, k: model_lib.forward_decode(p, cfg, tokens, meta, k,
                                                       attn_mesh=mesh)
             )(sharded_params, sharded_kv)
         finally:
             attn.paged_decode_attention_tp = orig
             model_lib.paged_decode_attention_tp = orig
+            attn.write_kv_pages_all_tp = orig_write
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+        # The sharded matmuls sum in another order, so the rows written are
+        # close, not equal; every row not addressed is untouched (zeros).
+        for g, r in zip(got_kv, ref_kv):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=2e-5, atol=2e-5)
+            flat = np.asarray(g).reshape(g.shape[0], -1, g.shape[-1])
+            rest = np.delete(flat, np.asarray(meta.slot_mapping), axis=1)
+            assert not rest.any() and flat[:, 13].any()
 
 
 class TestFlashPrefillHistory:
@@ -371,3 +384,140 @@ def test_prefill_history_tp_matches_oracle():
         np.testing.assert_allclose(np.asarray(got)[mask],
                                    np.asarray(ref)[mask],
                                    rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# KV page write kernel (ops/pallas/kv_write.py) against the XLA loop
+# ---------------------------------------------------------------------------
+
+_KVW_PS = 16
+
+
+def _run_slots(pages, start, n):
+    """Slots of n consecutive tokens of one sequence whose page table is
+    ``pages``, the first at offset ``start`` of its first page."""
+    return [pages[(start + j) // _KVW_PS] * _KVW_PS + (start + j) % _KVW_PS
+            for j in range(n)]
+
+
+# name -> (slots, VMEM budget override forcing 16-token blocks or None).
+_KVW_CASES = {
+    # decode-like: one token per page, odd and even offsets
+    "decode_T1": ([_KVW_PS * 4 + 3], None),
+    "decode_T5": ([_KVW_PS * (p + 1) + o
+                   for p, o in enumerate([0, 7, 8, 15, 2])], None),
+    "decode_T64": ([_KVW_PS * (1 + (p * 7) % 64) + (p * 5) % _KVW_PS
+                    for p in range(64)], None),
+    # prefill-like: two sequences packed back to back, the second at token
+    # offset 27 (no multiple of a tile), runs crossing tile and page edges
+    # over non-adjacent pages, then padding rows on the scrap page
+    "prefill_two_seqs": (_run_slots([9, 2, 5], 5, 27)
+                         + _run_slots([7, 3], 9, 20) + [0] * 3, None),
+    # spec-like: k+1 = 4 adjacent tokens a row, some straddling a tile
+    "spec_rows": (sum((_run_slots([p], o, 4) for p, o in
+                       [(1, 0), (2, 5), (3, 6), (4, 7), (5, 12)]), [])
+                  + [0] * 4, None),
+    # padding only: every row hits scrap slot 0
+    "all_padding": ([0] * 8, None),
+    # several grid steps: a tile shared across a block edge, a padding run
+    # across one, a block that is only partly there
+    "blocks_prefill_T300": (_run_slots(list(range(40, 20, -1)), 3, 293)
+                            + [0] * 7, 1),
+    "blocks_decode_T40": ([_KVW_PS * (1 + p) + (p * 3) % _KVW_PS
+                           for p in range(29)] + [0] * 11, 1),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", sorted(_KVW_CASES))
+def test_kv_write_kernel_is_bitwise_the_loop(monkeypatch, case, dtype):
+    """Interpret mode against the XLA loop AND a NumPy row assignment, the
+    whole pool bitwise: addressed rows hold the new rows, every other row
+    keeps its sentinel. (The ref bitcast that gives the kernel its 32-bit
+    view of a 16-bit pool exists only on the chip: there
+    benchmarks/tpu_kernel_check.py makes the same comparison.)"""
+    from kubernetes_gpu_cluster_tpu.ops.attention import write_kv_pages_all
+    from kubernetes_gpu_cluster_tpu.ops.pallas import kv_write as kvw
+
+    slots, budget = _KVW_CASES[case]
+    if budget is not None:
+        monkeypatch.setattr(kvw, "_VMEM_BUDGET", budget)
+    L, P, kd, T = 3, 66, 128, len(slots)
+    rng = np.random.default_rng(T)
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    pool_k = jnp.asarray(rng.standard_normal((L, P, _KVW_PS, kd)), dtype)
+    pool_v = jnp.asarray(rng.standard_normal((L, P, _KVW_PS, kd)), dtype)
+    # New rows arrive in the model's dtype; the write casts to the pool's.
+    k_all = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
+    v_all = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
+    slots = jnp.asarray(slots, jnp.int32)
+
+    want = []
+    for pool, rows in ((pool_k, k_all), (pool_v, v_all)):
+        flat = np.asarray(pool).view(bits).reshape(L, P * _KVW_PS, kd).copy()
+        new = np.asarray(rows.astype(dtype)).view(bits)
+        for t, s in enumerate(np.asarray(slots)):     # last write wins
+            flat[:, s] = new[:, t]
+        want.append(flat.reshape(L, P, _KVW_PS, kd))
+    loop = write_kv_pages_all(pool_k, pool_v, k_all, v_all, slots,
+                              use_pallas=False)
+    got = jax.jit(lambda *a: kvw.kv_write(*a, interpret=True))(
+        pool_k, pool_v, k_all, v_all, slots)
+    for w, ref, out in zip(want, loop, got):
+        np.testing.assert_array_equal(np.asarray(ref).view(bits), w)
+        np.testing.assert_array_equal(np.asarray(out).view(bits), w)
+
+
+def test_kv_write_blocks_follow_from_the_shapes():
+    """Tokens per grid step come from T, the pool's depth and width and its
+    element size: whole VMEM tiles of the new rows, never more than T needs,
+    never under one tile however deep the pool, never over what the core
+    has read semaphores for."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kv_write import _block_tokens
+    assert _block_tokens(64, 36, 1024, 2) == 32        # qwen3-4b, bf16
+    assert _block_tokens(2048, 36, 1024, 2) == 32
+    assert _block_tokens(1, 36, 1024, 2) == 16
+    assert _block_tokens(64, 36, 1024, 4) == 16        # float32 pool
+    assert _block_tokens(5, 2, 128, 4) == 8
+    assert _block_tokens(2048, 2, 128, 2) == 128       # read semaphores
+    assert _block_tokens(2048, 400, 8192, 2) == 16
+
+
+@pytest.mark.parametrize("shape, dtype, match", [
+    ((2, 4, 16, 96), jnp.bfloat16, "multiple of 128"),
+    ((2, 4, 16, 128), jnp.int8, "16- and 32-bit"),
+    ((2, 4, 12, 128), jnp.bfloat16, "page_size 12"),
+])
+def test_kv_write_refuses_at_trace_time(shape, dtype, match):
+    """What Mosaic would refuse with a layout error is said in words."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kv_write import kv_write
+    pool = jnp.zeros(shape, dtype)
+    rows = jnp.zeros((shape[0], 4, shape[-1]), jnp.bfloat16)
+    with pytest.raises(ValueError, match=match):
+        kv_write(pool, pool, rows, rows, jnp.zeros((4,), jnp.int32))
+
+
+def test_kv_write_tp_matches_loop():
+    """The shard_map wrapper (pool and new rows split on the lane dim under
+    a GSPMD tp mesh): interpret parity on the CPU tp=2 mesh, bitwise."""
+    from kubernetes_gpu_cluster_tpu.ops.attention import (
+        write_kv_pages_all, write_kv_pages_all_tp)
+    from kubernetes_gpu_cluster_tpu.parallel import make_mesh
+
+    mesh = make_mesh(tp=2, dp=4)
+    L, P, kd = 2, 12, 256
+    slots = jnp.asarray(_KVW_CASES["prefill_two_seqs"][0], jnp.int32)
+    T = slots.shape[0]
+    rng = np.random.default_rng(21)
+    pool_k = jnp.asarray(rng.standard_normal((L, P, _KVW_PS, kd)), jnp.bfloat16)
+    pool_v = jnp.asarray(rng.standard_normal((L, P, _KVW_PS, kd)), jnp.bfloat16)
+    k_all = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
+    v_all = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
+    want = write_kv_pages_all(pool_k, pool_v, k_all, v_all, slots,
+                              use_pallas=False)
+    got = write_kv_pages_all_tp(mesh, pool_k, pool_v, k_all, v_all, slots,
+                                interpret=True)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint16),
+                                      np.asarray(w).view(np.uint16))
