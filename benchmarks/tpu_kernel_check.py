@@ -19,6 +19,7 @@ opt-in int4 matmul kernel is ``int4``).
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 import time
@@ -29,7 +30,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubernetes_gpu_cluster_tpu.config import SchedulerConfig, get_model_config
+from kubernetes_gpu_cluster_tpu.config import (SchedulerConfig,
+                                               apply_hf_overrides,
+                                               get_model_config)
 from kubernetes_gpu_cluster_tpu.ops.attention import (
     paged_decode_attention_xla, prefill_history_attention_xla,
     ragged_prefill_attention_xla, write_kv_pages_all)
@@ -202,6 +205,232 @@ def check_kv_write(L, n_kv, hd, T) -> None:
     print(f"kv_write T={T}: kernel pool == loop pool, bitwise")
 
 
+def _timed(fn, *args, n=20) -> float:
+    """Seconds a call, n calls chained in one dispatch loop."""
+    out = jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / n
+
+
+def check_latent(cfg, pps, B, T) -> None:
+    """A latent-attention model's kernels at its geometry: one pool of
+    shared rows (the row is key and value) of ``kv_row_padded`` lanes.
+    Numerics against the XLA twins; the decode kernel timed at the cell's
+    contexts (1-2.7 k tokens a row) against the bytes it must read."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill_hist import (
+        flash_prefill_history_shared)
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kv_write import kv_write
+    from kubernetes_gpu_cluster_tpu.ops.pallas.latent_decode import (
+        latent_paged_decode)
+    nh, R = cfg.num_heads, cfg.kv_row_padded
+    scale = cfg.head_dim ** -0.5
+    rng = np.random.default_rng(7)
+
+    def bf(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    # decode: B rows, contexts as in the batch-decode-2k cell
+    ctx = rng.integers(1024, 2688, B).astype(np.int32)
+    ctx[0] = 1
+    tables = np.zeros((B, pps), np.int32)
+    page = 1
+    for b in range(B):
+        for j in range(cdiv(int(ctx[b]) - 1, PS)):
+            tables[b, j] = page
+            page += 1
+    pool = bf(2, page, PS, R)
+    q, cur = bf(B, nh, R), bf(B, 1, R)
+    tables, ctx_d = jnp.asarray(tables), jnp.asarray(ctx)
+    lyr = jnp.asarray(1, jnp.int32)
+    ref = paged_decode_attention_xla(q, pool, None, tables, ctx_d, cur, None,
+                                     scale, layer=lyr)
+    for C in (2, 4, 8):
+        fn = jax.jit(lambda *a, C=C: latent_paged_decode(
+            *a, scale, layer=lyr, chunk_pages=C))
+        err = _err(fn(q, pool, tables, ctx_d, cur), ref)
+        dt = _timed(fn, q, pool, tables, ctx_d, cur)
+        need = int(ctx.sum()) * cfg.kv_row_dim * 2
+        print(f"latent_paged_decode B={B} chunk_pages={C}: max|pallas-xla| "
+              f"= {err:.4f}; {dt * 1e6:.0f} us a call for {int(ctx.sum())} "
+              f"cached tokens = {need / dt / 1e9:.0f} GB/s of rows "
+              f"({need / dt / 819e9 * 100:.1f} % of 819 GB/s)")
+        assert err < TOL, err
+
+    # chunk with history, shared rows
+    hist_len = 3 * PS + 70
+    pad = 32
+    seg = jnp.asarray(np.where(np.arange(T) < T - pad, 0, -1), jnp.int32)
+    pos = jnp.asarray(np.where(np.arange(T) < T - pad,
+                               hist_len + np.arange(T), 0), jnp.int32)
+    qh, rows = bf(T, nh, R), bf(T, 1, R)
+    hpool = bf(2, 1 + pps, PS, R)
+    pt = jnp.asarray(1 + np.arange(pps), jnp.int32)
+    for hl in (0, hist_len):
+        hl_d = jnp.asarray(hl, jnp.int32)
+        ref = prefill_history_attention_xla(qh, rows, None, seg, pos, hpool,
+                                            None, pt, hl_d, scale, layer=lyr)
+        fn = jax.jit(lambda *a: flash_prefill_history_shared(
+            *a, scale, layer=lyr))
+        err = _err(fn(qh, rows, seg, pos, hpool, pt, hl_d), ref,
+                   np.asarray(seg) >= 0)
+        dt = _timed(fn, qh, rows, seg, pos, hpool, pt, hl_d, n=5)
+        print(f"latent_prefill_hist T={T} hist={hl}: max|pallas-xla| = "
+              f"{err:.4f}; {dt * 1e3:.2f} ms a call")
+        assert err < TOL, err
+
+    # materialised prefill: q/k of head_dim, v of v_head_dim
+    qp, kp = bf(T, nh, cfg.head_dim), bf(T, nh, cfg.head_dim)
+    vp = bf(T, nh, cfg.v_head_dim)
+    seg1 = jnp.asarray(np.where(np.arange(T) < T - pad, 0, -1), jnp.int32)
+    pos1 = jnp.arange(T, dtype=jnp.int32)
+    ref = ragged_prefill_attention_xla(qp, kp, vp, seg1, pos1, scale)
+    fn = jax.jit(lambda *a: flash_ragged_prefill(*a, scale))
+    err = _err(fn(qp, kp, vp, seg1, pos1), ref, np.asarray(seg1) >= 0)
+    dt = _timed(fn, qp, kp, vp, seg1, pos1, n=5)
+    print(f"flash_prefill T={T} qk={cfg.head_dim} v={cfg.v_head_dim}: "
+          f"max|pallas-xla| = {err:.4f}; {dt * 1e3:.2f} ms a call")
+    assert err < TOL, err
+
+    # the one-pool page write, bitwise against the loop
+    L = 9
+    for n in (B, T):
+        wpool = bf(L, 1 + cdiv(n, PS) + 2, PS, R)
+        new = bf(L, n, R)
+        slots = jnp.asarray(PS + np.arange(n), jnp.int32)
+        want, _ = jax.jit(lambda p, r, s: write_kv_pages_all(
+            p, None, r, None, s, use_pallas=False))(wpool, new, slots)
+        got, _ = jax.jit(lambda p, r, s: kv_write(p, None, r, None, s))(
+            wpool, new, slots)
+        same = bool(jnp.array_equal(want, got))
+        print(f"kv_write one pool L={L} T={n} R={R}: bitwise the loop's: "
+              f"{same}")
+        assert same
+
+
+def check_experts(cfg) -> None:
+    """The expert layer's dispatches at the model's widths, bf16, on a
+    stack of two layers: the grouped path (the ``grouped_matmul`` kernel
+    reading layer 1's experts in place among the stack's groups) against
+    its XLA twin (``jax.lax.ragged_dot``) and against dense dispatch, and
+    the time of each at a decode step's, the switch-over's and a mixed
+    step's token counts (what ``models.llama.dense_dispatch_pays`` decides
+    between)."""
+    import kubernetes_gpu_cluster_tpu.engine  # noqa: F401 (before models)
+    from kubernetes_gpu_cluster_tpu.models import llama
+    E, d, ff = cfg.num_experts, cfg.hidden_size, cfg.expert_width
+    ks = jax.random.split(jax.random.key(11), 6)
+
+    def w(key, *shape):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * shape[-2] ** -0.5).astype(jnp.bfloat16)
+    stack = {"w_gate": w(ks[0], 2, E, d, ff), "w_up": w(ks[1], 2, E, d, ff),
+             "w_down": w(ks[2], 2, E, ff, d)}
+    lp = {"router": jax.random.normal(ks[3], (d, E)) * d ** -0.5,
+          "router_bias": 0.01 * jax.random.normal(ks[4], (E,))}
+    layer = jnp.asarray(1, jnp.int32)
+
+    def one_layer(stack):
+        return {k: jax.lax.dynamic_index_in_dim(a, 1, 0, keepdims=False)
+                for k, a in stack.items()}
+
+    def grouped_with(use_pallas):
+        def grouped(stack, x):
+            idx, wts = llama.moe_route(lp, x, cfg)
+            sizes = jnp.sum(jax.nn.one_hot(idx.reshape(-1), E,
+                                           dtype=jnp.int32), axis=0)
+            return llama.experts_grouped(stack, x, idx, wts, sizes, layer,
+                                         use_pallas)
+        return jax.jit(grouped)
+    grouped, twin = grouped_with(True), grouped_with(False)
+
+    @jax.jit
+    def dense(stack, x):
+        idx, wts = llama.moe_route(lp, x, cfg)
+        return llama.experts_dense(one_layer(stack), x, idx, wts, cfg)
+
+    for T in (64, 256, 1088, 2112):
+        x = jax.random.normal(ks[5], (T, d), jnp.float32).astype(jnp.bfloat16)
+        g, tw, dn = grouped(stack, x), twin(stack, x), dense(stack, x)
+        err, err_twin = _err(g, dn), _err(g, tw)
+        scale = float(jnp.max(jnp.abs(dn)))
+        tg, tt, td = (_timed(f, stack, x, n=10)
+                      for f in (grouped, twin, dense))
+        print(f"experts T={T}: max|grouped-dense| = {err:.4f}, "
+              f"|grouped-ragged_dot| = {err_twin:.4f} of outputs up to "
+              f"{scale:.2f}; grouped {tg * 1e3:.2f} ms, ragged_dot "
+              f"{tt * 1e3:.2f} ms, dense {td * 1e3:.2f} ms a layer")
+        assert err < 0.03 * max(scale, 1.0), (err, scale)
+        assert err_twin < 0.03 * max(scale, 1.0), (err_twin, scale)
+
+
+def check_decode_program(cfg, rows=(8, 16, 32, 64, 128),
+                         ctx=(1024, 2688)) -> None:
+    """The expert dispatches IN the decode program: the whole
+    ``forward_decode`` (the layer stack over the model's real weights,
+    latent or paged attention against a pool at the cell's contexts, the
+    page write; no head), once with dense and once with grouped dispatch,
+    at decode row counts. A kernel alone says little here: the step waits
+    for the experts' weights either way, and XLA overlaps the dense path's
+    batched matmuls with what surrounds them. This is the reading
+    ``models.llama.dense_dispatch_pays`` rests on."""
+    import kubernetes_gpu_cluster_tpu.engine  # noqa: F401 (before models)
+    from kubernetes_gpu_cluster_tpu.config.engine_config import CacheConfig
+    from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
+    from kubernetes_gpu_cluster_tpu.models import llama
+    t0 = time.perf_counter()
+    params = jax.block_until_ready(llama.init_params(cfg, jax.random.key(0)))
+    print(f"decode program: {cfg.num_layers} layers of {cfg.name} built in "
+          f"{time.perf_counter() - t0:.0f} s")
+    rng = np.random.default_rng(5)
+    switch = llama.DENSE_DISPATCH_MAX_TOKENS
+    for B in rows:
+        lens = rng.integers(ctx[0], ctx[1], B)
+        pps = int(cdiv(int(lens.max()) + 1, PS))
+        # Beyond the seats a server has, rows share pages (a pool for 256
+        # rows would not fit beside the weights): the same bytes are read.
+        n_pages = min(B * pps, 64 * pps)
+        tables = (np.arange(B * pps, dtype=np.int32) % n_pages).reshape(
+            B, pps)
+        meta = llama.DecodeMeta(
+            positions=jnp.asarray(lens - 1, jnp.int32),
+            slot_mapping=jnp.asarray(
+                tables[np.arange(B), (lens - 1) // PS] * PS + (lens - 1) % PS,
+                jnp.int32),
+            page_tables=jnp.asarray(tables),
+            context_lens=jnp.asarray(lens, jnp.int32))
+        tokens = jnp.asarray(rng.integers(3, cfg.vocab_size, B), jnp.int32)
+        times = {}
+        for name, grouped in (("dense", False), ("grouped", True)):
+            # Read when the program is traced: with 0 no step is small
+            # enough for dense dispatch, so a caller that vouches for whole
+            # experts gets the grouped path at every size; one that does
+            # not gets dense dispatch at every size.
+            llama.DENSE_DISPATCH_MAX_TOKENS = 0 if grouped else switch
+            step = jax.jit(
+                lambda p, t, m, kv, g=grouped: llama.forward_decode(
+                    p, cfg, t, m, kv, grouped_experts=g)[:2],
+                donate_argnums=3)
+            kv = allocate_kv_cache(cfg, CacheConfig(page_size=PS), n_pages)
+            h, kv = step(params, tokens, meta, kv)
+            times[name] = (np.asarray(h, np.float32), None)
+            jax.block_until_ready(kv)
+            t0 = time.perf_counter()
+            for _ in range(20):
+                h, kv = step(params, tokens, meta, kv)
+            jax.block_until_ready(h)
+            times[name] = (times[name][0], (time.perf_counter() - t0) / 20)
+            del kv
+        llama.DENSE_DISPATCH_MAX_TOKENS = switch
+        (hd_, td), (hg, tg) = times["dense"], times["grouped"]
+        print(f"decode program B={B}, {int(lens.sum())} cached tokens: "
+              f"dense {td * 1e3:.3f} ms, grouped {tg * 1e3:.3f} ms a step; "
+              f"max|hidden gap| {np.abs(hd_ - hg).max():.4f} of up to "
+              f"{np.abs(hd_).max():.2f}")
+
+
 def check_int4_matmul() -> None:
     """W4A16 dequant-fused matmul (ops/pallas/int4_matmul.py): packed tiles
     dequantized in VMEM vs the XLA fusion path, at an 8B-decode-like shape
@@ -229,6 +458,9 @@ def main() -> None:
     ap.add_argument("--model", default="qwen3-4b")
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--kernels", default="decode,prefill,hist,kvwrite")
+    ap.add_argument("--hf-overrides", default=None,
+                    help="the server's flag: shape keys as JSON, e.g. the "
+                         "benchmark's depth cut")
     args = ap.parse_args()
     configure_compile_cache()
     dev = jax.devices()[0]
@@ -238,6 +470,8 @@ def main() -> None:
         sys.exit("tpu_kernel_check needs a TPU: Mosaic does not compile here")
 
     cfg = get_model_config(args.model)
+    if args.hf_overrides:
+        cfg = apply_hf_overrides(cfg, json.loads(args.hf_overrides))
     sc = SchedulerConfig()
     nh, n_kv, hd = cfg.num_heads // args.tp, cfg.num_kv_heads // args.tp, cfg.head_dim
     pps = cdiv(cfg.max_model_len, PS)
@@ -251,7 +485,12 @@ def main() -> None:
         "kvwrite": lambda: [check_kv_write(cfg.num_layers, n_kv, hd, n)
                             for n in (B, T)],
         "int4": check_int4_matmul,
+        "latent": lambda: check_latent(cfg, pps, B, T),
+        "experts": lambda: check_experts(cfg),
+        "decode-program": lambda: check_decode_program(cfg),
     }
+    if cfg.is_mla and args.kernels == ap.get_default("kernels"):
+        args.kernels = "latent,experts"
     for name in args.kernels.split(","):
         checks[name]()
     print("OK")

@@ -59,9 +59,15 @@ LOG_DIR = HERE / "chiprun_out"
 # gen is the background generation a short prompt is mixed into.
 MODELS = {
     "qwen3-4b": dict(vocab=151936, long_prompt=3000, gen=256),
+    # ``--model kimi-vl-a3b``: the language model at the benchmark's cut,
+    # one pipeline stage's 9 of 27 layers (latent pages, grouped experts).
+    "kimi-vl-a3b": dict(vocab=163840, long_prompt=3000, gen=256,
+                        flags=["--hf-overrides", '{"num_hidden_layers": 9}']),
     # Rehearsal only: max_model_len 512 cannot hold a chunking prompt.
     "debug-tiny": dict(vocab=512, long_prompt=400, gen=96),
+    "debug-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
 }
+REHEARSAL_OF = {"qwen3-4b": "debug-tiny", "kimi-vl-a3b": "debug-mla-moe"}
 HEALTH_TIMEOUT_S = 600
 REQUEST_TIMEOUT_S = 600
 DRAIN_TIMEOUT_S = 150
@@ -85,7 +91,7 @@ def check(cond: bool, why: str) -> None:
 class Server:
     """The one child: the CLI server, its log file, its HTTP endpoint."""
 
-    def __init__(self, model: str, tp: int, rehearsal: bool):
+    def __init__(self, model: str, tp: int, rehearsal: bool, flags=()):
         LOG_DIR.mkdir(exist_ok=True)
         n = 0
         while (LOG_DIR / f"chip_smoke_server.{n}.log").exists():
@@ -98,7 +104,7 @@ class Server:
         cmd = [sys.executable, "-m",
                "kubernetes_gpu_cluster_tpu.serving.api_server",
                "--model", model, "--host", "127.0.0.1",
-               "--port", str(self.port)]
+               "--port", str(self.port), *flags]
         if tp > 1:
             cmd += ["--tensor-parallel-size", str(tp)]
         # The environment passes through unchanged; the rehearsal alone
@@ -235,7 +241,7 @@ def metric(text: str, name: str) -> float:
 
 def run(args) -> tuple[dict, dict]:
     """(report, verdict) when every phase passed; SmokeFailure otherwise."""
-    model = "debug-tiny" if args.cpu_rehearsal else "qwen3-4b"
+    model = REHEARSAL_OF[args.model] if args.cpu_rehearsal else args.model
     want_platform = "cpu" if args.cpu_rehearsal else "tpu"
     geom = MODELS[model]
     tp = args.tensor_parallel_size
@@ -249,7 +255,7 @@ def run(args) -> tuple[dict, dict]:
     def prompt(n: int) -> list[int]:
         return [rng.randrange(3, geom["vocab"]) for _ in range(n)]
 
-    server = Server(model, tp, args.cpu_rehearsal)
+    server = Server(model, tp, args.cpu_rehearsal, geom.get("flags", ()))
 
     def on_deadline(signum, frame):
         raise SmokeFailure(f"chip_smoke exceeded its {DEADLINE_S}s deadline; "
@@ -425,9 +431,11 @@ def main() -> int:
     ap.add_argument("--expect-greedy-tokens", default=None,
                     help="comma-separated ids the greedy completion must "
                     "START with (the one-chip run's, for the tp run)")
+    ap.add_argument("--model", default="qwen3-4b", choices=list(REHEARSAL_OF),
+                    help="the preset to start (default: the chip check's)")
     ap.add_argument("--cpu-rehearsal", action="store_true",
-                    help="debug this script on the CPU with debug-tiny; "
-                    "never a chip result")
+                    help="debug this script on the CPU with the model's "
+                    "debug preset; never a chip result")
     args = ap.parse_args()
     pinned = os.environ.get("JAX_PLATFORMS", "")
     if not args.cpu_rehearsal and pinned and "tpu" not in pinned.split(","):

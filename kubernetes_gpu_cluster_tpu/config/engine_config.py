@@ -228,3 +228,54 @@ class EngineConfig:
     @staticmethod
     def from_model_name(name: str, **kw) -> "EngineConfig":
         return EngineConfig(model=get_model_config(name), **kw)
+
+
+def latent_model_refusal(config: EngineConfig, mesh_shape=None, *,
+                         role: str = "both", fleet_prefix_cache: bool = False,
+                         peer_pool=None) -> Optional[str]:
+    """What a latent-attention model (one pool of [c | k_pe] rows, no V;
+    grouped expert matmuls) is not carried through yet: one line naming the
+    flag and the mechanism, or None. Each of these either works (tested) or
+    refuses at start; none degrades in silence. ``mesh_shape``: the engine's
+    actual mesh axes, where it was handed a mesh and not flags."""
+    m = config.model
+    if not m.is_mla:
+        return None
+    axes = dict(mesh_shape or {})
+    par = config.parallel
+    for flag, axis, n, why in (
+            ("--tensor-parallel-size", "tp", par.tp,
+             "the latent row is one shared head: there is no kv-head axis "
+             "to shard the pool over, and the kernels run unsharded"),
+            ("--pipeline-parallel-size", "pp", par.pp,
+             "the pipeline stages one homogeneous layer stack, not leading "
+             "dense layers beside expert layers"),
+            ("--sequence-parallel-size", "sp", par.sp,
+             "ring attention is written for K and V per head, not for the "
+             "latent row"),
+            ("--expert-parallel-size", "ep", par.ep,
+             "the grouped expert matmuls run over every expert on one "
+             "device; no all-to-all dispatch exists")):
+        n = max(n, axes.get(axis, 1))
+        if n > 1:
+            return (f"{flag} {n} with {m.name}: {why}")
+    if config.scheduler.spec_decode_enabled:
+        return (f"--enable-spec-decode with {m.name}: the verify step's "
+                "attention reads K and V pools; no latent-page variant")
+    if config.cache.kv_swap_enabled:
+        return (f"--swap-space-gb with {m.name}: the host tier and its "
+                "gather/scatter move K and V page pairs, not latent pages")
+    if m.quantization is not None:
+        return (f"--quantization {m.quantization} with {m.name}: the "
+                "absorbed projections and the grouped expert matmuls have "
+                "no int8/int4 path")
+    if role != "both":
+        return (f"--role {role} with {m.name}: the prefill-to-decode "
+                "handoff frames K and V page pairs, not latent pages")
+    if fleet_prefix_cache:
+        return (f"--fleet-prefix-cache with {m.name}: prefix export and "
+                "spill frame K and V page pairs, not latent pages")
+    if peer_pool:
+        return (f"--peer-pool with {m.name}: live migration frames K and V "
+                "page pairs, not latent pages")
+    return None

@@ -39,9 +39,31 @@ class ModelConfig:
     attention_bias: bool = False
     # Qwen3 applies RMSNorm to q and k per-head before RoPE.
     qk_norm: bool = False
-    # MoE (mixtral-class). num_experts == 0 means dense MLP.
+    # MoE. num_experts == 0 means dense MLP. Mixtral class: softmax over the
+    # top-k logits, every layer an expert layer of width intermediate_size.
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    # DeepSeek-V3 class (kimi_vl's language model): routed experts of width
+    # ``moe_intermediate_size`` beside ``num_shared_experts`` always-on ones
+    # (one SwiGLU of their summed width), after ``first_k_dense_replace``
+    # leading dense layers of width intermediate_size. The router scores by
+    # ``scoring_func`` over ALL experts, chooses by score + a per-expert
+    # correction bias, weighs by the raw score, normalises
+    # (``norm_topk_prob``) and scales (``routed_scaling_factor``).
+    moe_intermediate_size: int = 0
+    num_shared_experts: int = 0
+    first_k_dense_replace: int = 0
+    scoring_func: str = "softmax"     # | "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # Latent attention (MLA), on when kv_lora_rank > 0: the cache holds one
+    # row [c (kv_lora_rank) | k_pe (qk_rope_head_dim)] a token a layer and no
+    # V; q/k heads are qk_nope_head_dim + qk_rope_head_dim wide, v heads
+    # v_head_dim. ``head_dim`` is then the q/k width (softmax scale).
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # OPT-class decoder knobs (reference values-01-minimal-example.yaml:4-8
     # serves facebook/opt-125m). Defaults describe the llama class.
     norm_type: str = "rmsnorm"        # "rmsnorm" | "layernorm" (w/ bias)
@@ -77,6 +99,39 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def num_dense_layers(self) -> int:
+        """Leading dense layers, run before the scan over the expert layers."""
+        return self.first_k_dense_replace if self.is_moe else 0
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def kv_row_dim(self) -> int:
+        """Elements one token holds in one layer of ONE pool: the latent row
+        [c | k_pe], or the flattened K (= V) heads."""
+        if self.is_mla:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return self.num_kv_heads * self.head_dim
+
+    @property
+    def kv_row_padded(self) -> int:
+        """The row as the pool stores it. A latent row is padded to whole
+        128-lane tiles (a DMA slice must be whole HBM tiles: PERF.md, PR 25);
+        K|V rows are stored as they are."""
+        return -(-self.kv_row_dim // 128) * 128 if self.is_mla else self.kv_row_dim
+
+    @property
+    def kv_pools(self) -> int:
+        """Pools of rows the cache keeps: K and V, or the one latent pool."""
+        return 1 if self.is_mla else 2
+
+    @property
     def rope_scaling_dict(self) -> Optional[dict]:
         return dict(self.rope_scaling) if self.rope_scaling else None
 
@@ -100,6 +155,19 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         "debug-moe", vocab_size=512, hidden_size=128, intermediate_size=256,
         num_layers=2, num_heads=4, num_kv_heads=2, head_dim=32, max_model_len=512,
         num_experts=4, num_experts_per_tok=2, dtype="float32",
+    ),
+    # kimi-vl-a3b's block at a size the CPU tests can afford: latent
+    # attention, 1 dense + 3 expert layers, sigmoid router with a choice
+    # bias, 2 shared experts.
+    "debug-mla-moe": _p(
+        "debug-mla-moe", vocab_size=512, hidden_size=128, intermediate_size=256,
+        num_layers=4, num_heads=4, num_kv_heads=4, head_dim=48,
+        kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16,
+        v_head_dim=32, num_experts=8, num_experts_per_tok=3,
+        moe_intermediate_size=64, num_shared_experts=2,
+        first_k_dense_replace=1, scoring_func="sigmoid",
+        routed_scaling_factor=2.446, rope_theta=800000.0,
+        max_model_len=512, dtype="float32",
     ),
     # The reference's minimal-example model (values-01-minimal-example.yaml:8).
     "opt-125m": _p(
@@ -153,7 +221,69 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         rope_theta=1000000.0, max_model_len=8192,
         num_experts=8, num_experts_per_tok=2,
     ),
+    # The language model of moonshotai/Kimi-VL-A3B-Instruct (config.json's
+    # text_config, a deepseek_v3 decoder): MLA, 64 routed experts top-6 with
+    # sigmoid scores and a noaux_tc choice bias, 2 shared experts, one
+    # leading dense layer. Text only: the vision tower is not served.
+    "kimi-vl-a3b": _p(
+        "kimi-vl-a3b", vocab_size=163840, hidden_size=2048,
+        intermediate_size=11264, num_layers=27, num_heads=16,
+        num_kv_heads=16, head_dim=192, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_experts=64, num_experts_per_tok=6, moe_intermediate_size=1408,
+        num_shared_experts=2, first_k_dense_replace=1,
+        scoring_func="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.446, rope_theta=800000.0,
+        rms_norm_eps=1e-5, max_model_len=4096,
+    ),
 }
+
+
+# Shape keys of an HF ``config.json`` and the ModelConfig field each sets:
+# read by engine/weights.config_from_hf and by ``--hf-overrides`` (vLLM's
+# name), which changes SHAPE only, e.g. the depth one pipeline stage holds.
+HF_SHAPE_KEYS: dict[str, str] = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "intermediate_size": "intermediate_size",
+    "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "max_position_embeddings": "max_model_len",
+    "num_experts_per_tok": "num_experts_per_tok",
+    "n_routed_experts": "num_experts",
+    "n_shared_experts": "num_shared_experts",
+    "moe_intermediate_size": "moe_intermediate_size",
+    "first_k_dense_replace": "first_k_dense_replace",
+    "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim",
+    "v_head_dim": "v_head_dim",
+}
+
+
+def apply_hf_overrides(cfg: ModelConfig, overrides: dict) -> ModelConfig:
+    """``--hf-overrides '{"num_hidden_layers": 9}'``: HF shape keys laid
+    over a config. Anything that is not a whole-number shape key is refused
+    by name."""
+    fields = {}
+    for key, val in overrides.items():
+        if key not in HF_SHAPE_KEYS:
+            raise ValueError(
+                f"--hf-overrides: {key!r} is not a shape key "
+                f"(one of {sorted(HF_SHAPE_KEYS)})")
+        if isinstance(val, bool) or not isinstance(val, int) or val < 0:
+            raise ValueError(
+                f"--hf-overrides: {key} must be a whole number, not {val!r}")
+        fields[HF_SHAPE_KEYS[key]] = val
+    cfg = cfg.replace(**fields)
+    if cfg.is_mla:
+        cfg = cfg.replace(head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if cfg.num_dense_layers >= cfg.num_layers > 0 and cfg.is_moe:
+        raise ValueError(
+            f"--hf-overrides: {cfg.num_layers} layers leave no expert layer "
+            f"after {cfg.num_dense_layers} leading dense ones")
+    return cfg
 
 
 def get_model_config(name: str, **overrides) -> ModelConfig:

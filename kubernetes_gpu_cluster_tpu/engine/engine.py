@@ -20,6 +20,7 @@ as in reference values-01-minimal-example2.yaml), PP in parallel/pp.py.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Optional
 
@@ -28,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.sanitize import build_step_sanitizer
-from ..config import EngineConfig
+from ..config import EngineConfig, latent_model_refusal
 from ..models import llama as model_lib
 from ..observability import Observability
 from ..models.llama import DecodeMeta, MixedMeta, PrefillMeta, SpecMeta
@@ -40,7 +41,8 @@ from ..resilience.faults import inject as _inject_fault
 from ..utils import cdiv, get_logger
 from .kv_cache import (KVCache, KVPageIO, KVTransferPrograms,
                        allocate_kv_cache, build_kv_swapper, derive_num_pages,
-                       kv_cache_dtype)
+                       kv_cache_bytes_per_token, kv_cache_dtype,
+                       kv_row_padding_share)
 from .sampling_params import LOGIT_BIAS_CAP, SamplingParams
 from .scheduler import ScheduledBatch, Scheduler
 from .sequence import FinishReason, Sequence, SequenceStatus
@@ -140,6 +142,10 @@ class LLMEngine:
                 config, cache=dataclasses.replace(config.cache, page_size=ps))
         self.config = config
         self.model_config = config.model
+        refusal = latent_model_refusal(
+            config, mesh.shape if mesh is not None else None)
+        if refusal is not None:
+            raise ValueError(refusal)
         self.eos_token_id = eos_token_id
         self.mesh = mesh
         self.pp_size = mesh.shape.get("pp", 1) if mesh is not None else 1
@@ -183,7 +189,7 @@ class LLMEngine:
         # of the weights, against the UNSHARDED bytes per page — each chip
         # stores only 1/tp of a page when kv heads divide tp, so a tp mesh's
         # pool is sized conservatively (about 1/tp of what would fit).
-        hbm_free = _device_free_memory()
+        hbm_free = _device_free_memory(resident=_resident_bytes(self.params))
         if hbm_free is not None:
             # ... and once the largest step program's own workspace is set
             # aside: hbm_utilization applies to what the POOL can have.
@@ -195,8 +201,11 @@ class LLMEngine:
         cap = (config.scheduler.max_num_seqs *
                cdiv(config.effective_max_len, config.cache.page_size) + 1)
         num_pages = min(num_pages, cap)
-        logger.info("KV cache: %d pages x %d tokens (page pool)",
-                    num_pages, config.cache.page_size)
+        logger.info("KV cache: %d pages x %d tokens (page pool; %s bytes "
+                    "were free for it once the weights were resident and "
+                    "%d were set aside for a step's workspace)",
+                    num_pages, config.cache.page_size, hbm_free,
+                    step_workspace_bytes(config))
 
         # One Observability per engine, shared with the scheduler: lifecycle
         # trace events, step-phase attribution, and the /metrics histograms
@@ -390,6 +399,15 @@ class LLMEngine:
             "use_pallas_hist": self.use_pallas_hist,
             "num_pages": self.scheduler.allocator.num_pages,
             "page_size": self.config.cache.page_size,
+            # The bytes a cached token really holds (all layers, padding of
+            # a latent row included) and the share of them that is padding.
+            "weight_bytes": sum(x.size * x.dtype.itemsize
+                                for x in jax.tree.leaves(self.params)),
+            "kv_layout": "latent" if self.model_config.is_mla else "k|v",
+            "kv_bytes_per_token": kv_cache_bytes_per_token(
+                self.model_config, self.config.cache),
+            "kv_row_padding_share": round(
+                kv_row_padding_share(self.model_config), 4),
         }
         if self.pallas_disabled_reason is not None:
             info["pallas_disabled_reason"] = self.pallas_disabled_reason
@@ -464,7 +482,7 @@ class LLMEngine:
             return False
         cfg = self.model_config
         tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
-        lane = (cfg.num_kv_heads * cfg.head_dim) // tp
+        lane = cfg.kv_row_padded // tp
         if cfg.num_heads % tp or cfg.num_kv_heads % tp:
             self.pallas_disabled_reason = (
                 f"heads ({cfg.num_heads} q / {cfg.num_kv_heads} kv) not "
@@ -521,7 +539,7 @@ class LLMEngine:
         i32 = jnp.int32
         # Stacked [L, P, ps, kd] pool with a dynamic layer index — the
         # variant serving runs; P does not shape the kernel.
-        pool = arr((2, 2, ps, nkv * hd),
+        pool = arr((2, 2, ps, cfg.kv_row_padded // tp),
                    kv_cache_dtype(cfg, self.config.cache))
         compiled = []
 
@@ -535,6 +553,23 @@ class LLMEngine:
                     f"pages/seq {pps}, B={B}, T={T}): {e}") from e
             compiled.append(name)
 
+        if self._grouped_experts:
+            # The grouped expert matmuls of the largest step (a full prefill
+            # bucket beside a full decode bucket), over the whole stack's
+            # groups as models.llama.experts_grouped hands them over.
+            from ..ops.pallas.grouped_matmul import grouped_matmul
+            groups = (cfg.num_layers - cfg.num_dense_layers) * cfg.num_experts
+            rows = (T + B) * cfg.num_experts_per_tok
+            d, ff = cfg.hidden_size, cfg.expert_width
+            for name, k, n in (("up", d, ff), ("down", ff, d)):
+                probe(f"grouped_matmul[{name}]", grouped_matmul,
+                      arr((rows, k)), arr((groups, k, n)),
+                      arr((groups,), i32))
+        if cfg.is_mla:
+            self._probe_latent_kernels(probe, arr, pool, B, T, pps)
+            logger.info("Pallas kernels compiled at the served geometry: %s",
+                        ", ".join(compiled))
+            return
         probe("paged_decode",
               lambda q, kp, vp, tb, ctx, kc, vc, lyr: pallas_paged_decode(
                   q, kp, vp, tb, ctx, kc, vc, scale, layer=lyr),
@@ -565,6 +600,65 @@ class LLMEngine:
                   rows, rows, arr((n,), i32))
         logger.info("Pallas kernels compiled at the served geometry: %s",
                     ", ".join(compiled))
+
+    def _probe_latent_kernels(self, probe, arr, pool, B, T, pps) -> None:
+        """The kernels a latent-attention model runs, at its geometry: the
+        shared-row decode and chunk-with-history kernels over the one pool,
+        the materialised prefill (192-wide q/k against 128-wide v), and the
+        one-pool page write at the pool's real depth."""
+        from ..ops.pallas.flash_prefill import flash_ragged_prefill
+        from ..ops.pallas.flash_prefill_hist import (
+            flash_prefill_history_shared)
+        from ..ops.pallas.kv_write import kv_write
+        from ..ops.pallas.latent_decode import latent_paged_decode
+
+        cfg = self.model_config
+        nh, R, i32 = cfg.num_heads, cfg.kv_row_padded, jnp.int32
+        scale = cfg.head_dim ** -0.5
+        probe("latent_paged_decode",
+              lambda q, kp, tb, ctx, cur, lyr: latent_paged_decode(
+                  q, kp, tb, ctx, cur, scale, layer=lyr),
+              arr((B, nh, R)), pool, arr((B, pps), i32), arr((B,), i32),
+              arr((B, 1, R)), arr((1,), i32))
+        probe("flash_prefill",
+              lambda q, k, v, seg, pos: flash_ragged_prefill(
+                  q, k, v, seg, pos, scale),
+              arr((T, nh, cfg.head_dim)), arr((T, nh, cfg.head_dim)),
+              arr((T, nh, cfg.v_head_dim)), arr((T,), i32), arr((T,), i32))
+        probe("latent_prefill_hist",
+              lambda q, rows, seg, pos, kp, pt, hl, lyr:
+              flash_prefill_history_shared(q, rows, seg, pos, kp, pt, hl,
+                                           scale, layer=lyr),
+              arr((T, nh, R)), arr((T, 1, R)), arr((T,), i32),
+              arr((T,), i32), pool, arr((pps,), i32), arr((), i32),
+              arr((), i32))
+        deep_pool = arr((cfg.num_layers, 2, pool.shape[2], R), pool.dtype)
+        for n in (B, T):
+            probe(f"kv_write[T={n}]",
+                  lambda p, rows, slots: kv_write(p, None, rows, None, slots),
+                  deep_pool, arr((cfg.num_layers, n, R)), arr((n,), i32))
+
+    @property
+    def _grouped_experts(self) -> bool:
+        """Whether the step programs may run their experts by grouped
+        dispatch (``models.llama._moe_mlp``'s ``grouped``): an expert model
+        whose expert tensors lie whole on ONE device and are not quantized.
+        Under any mesh (GSPMD shards them P(None, 'ep', None, 'tp'); the pp
+        shard_map slices them) the grouped kernel, a custom call with no
+        partitioning rule, would be handed an all-gathered stack: those keep
+        dense dispatch. The one test for the kernel's start-up probe AND its
+        use, so that what was not probed is not run."""
+        return (self.model_config.is_moe and self.mesh is None
+                and self.model_config.quantization is None)
+
+    @property
+    def _reports_expert_load(self) -> bool:
+        """Prefill, chunk and mixed steps of an expert model on one device
+        return the routed pairs of each expert beside their tokens (a step
+        of thousands of tokens says how even the routing is; a decode
+        window's few hundred pairs say little and its scan would have to
+        carry the count)."""
+        return self.model_config.is_moe and self.mesh is None
 
     def _gspmd_attn_mesh(self):
         """The mesh to run Pallas attention under (shard_map tp wrappers) in
@@ -597,13 +691,14 @@ class LLMEngine:
         is oblivious to pp."""
         cfg = self.model_config
         use_pallas = self.use_pallas
+        grouped = self._grouped_experts
 
         if self.pp_size > 1:
             from ..parallel.pp import build_pp_mapped, pp_logits
             mapped = build_pp_mapped(self.mesh, cfg, "prefill",
                                      use_pallas=use_pallas)
 
-            def fwd(params, kv, int_t, logits_indices):
+            def fwd(params, kv, int_t, logits_indices, moe_load=None):
                 # The whole ragged prefill batch rides the pipeline as ONE
                 # microbatch (M=1): the scheduler packs sequences into a
                 # single flat [T] buffer, and splitting it would let a
@@ -634,21 +729,25 @@ class LLMEngine:
                 # attn_impl replaces the attention attn_mesh would shard;
                 # the mesh stays for the post-scan KV write kernel.
 
-            def fwd(params, kv, int_t, logits_indices):
+            def fwd(params, kv, int_t, logits_indices, moe_load=None):
                 meta = PrefillMeta(seg_ids=int_t[1], positions=int_t[2],
                                    slot_mapping=int_t[3],
                                    logits_indices=logits_indices)
                 hidden, kv, _ = model_lib.forward_prefill(
                     params, cfg, int_t[0], meta, kv, use_pallas=use_pallas,
-                    attn_mesh=attn_mesh, attn_impl=attn_impl)
+                    attn_mesh=attn_mesh, attn_impl=attn_impl,
+                    moe_load=moe_load, grouped_experts=grouped)
                 return model_lib.compute_logits(params, cfg, hidden,
                                                  use_pallas=use_pallas), kv
+
+        reports_load = self._reports_expert_load
 
         def prefill_step(params, kv: KVCache, int_t, int_b, float_b,
                          bias_ids, bias_vals, key):
             # int_b: [B, 5] = (logits_indices, top_k, seed, prompt_len,
             # top_n)
-            logits, kv = fwd(params, kv, int_t, int_b[:, 0])
+            load = [] if reports_load else None
+            logits, kv = fwd(params, kv, int_t, int_b[:, 0], load)
             logits = _maybe_bias(logits, bias_ids, bias_vals)
             logits = _prefill_penalties(cfg, logits, int_t, int_b[:, 3],
                                         float_b[:, 2], float_b[:, 3])
@@ -657,7 +756,7 @@ class LLMEngine:
             next_tokens, lps, tids, tlps = sample_and_logprobs(
                 logits, keys, float_b[:, 0], int_b[:, 1], float_b[:, 1],
                 row_keys=True, with_top=jnp.any(int_b[:, 4] > 0))
-            return next_tokens, lps, tids, tlps, kv
+            return (next_tokens, lps, tids, tlps, kv, *(load or ()))
 
         return self._maybe_jit(prefill_step, donate_argnums=(1,))
 
@@ -676,6 +775,7 @@ class LLMEngine:
         and XLA gathered the whole stack per chunk)."""
         cfg = self.model_config
         use_pallas = self.use_pallas_hist
+        grouped = self._grouped_experts
         # use_pallas_hist already encodes kernel eligibility (pp/sp
         # exclusions, probe result); the helper adds the mesh/pp gating the
         # other builders share.
@@ -687,7 +787,8 @@ class LLMEngine:
             mapped = build_pp_mapped(self.mesh, cfg, "prefill_hist",
                                      use_pallas=False)
 
-            def hist_fwd(params, kv, int_t, int_b, page_table, hist_len):
+            def hist_fwd(params, kv, int_t, int_b, page_table, hist_len,
+                         moe_load=None):
                 T = int_t.shape[1]
                 M = S if T % S == 0 else 1
                 sub = T // M
@@ -705,22 +806,27 @@ class LLMEngine:
                                    logits_indices=int_b[:, 0])
                 return logits, KVCache(k=kvk, v=kvv)
         else:
-            def hist_fwd(params, kv, int_t, int_b, page_table, hist_len):
+            def hist_fwd(params, kv, int_t, int_b, page_table, hist_len,
+                         moe_load=None):
                 meta = PrefillMeta(seg_ids=int_t[1], positions=int_t[2],
                                    slot_mapping=int_t[3],
                                    logits_indices=int_b[:, 0])
                 hidden, kv, _ = model_lib.forward_prefill_hist(
                     params, cfg, int_t[0], meta, kv, page_table[0], hist_len,
                     use_pallas=use_pallas and attn_mesh is None,
-                    attn_mesh=attn_mesh)
+                    attn_mesh=attn_mesh, moe_load=moe_load,
+                    grouped_experts=grouped)
                 return model_lib.compute_logits(params, cfg, hidden,
                                                  use_pallas=use_pallas), kv
+
+        reports_load = self._reports_expert_load
 
         def prefill_hist_step(params, kv: KVCache, int_t, int_b, float_b,
                               page_table, hist_len, out_tokens,
                               bias_ids, bias_vals, key):
+            load = [] if reports_load else None
             logits, kv = hist_fwd(params, kv, int_t, int_b, page_table,
-                                  hist_len)
+                                  hist_len, load)
             logits = _maybe_bias(logits, bias_ids, bias_vals)
             # EXACT penalties on the chunked path: earlier chunks' token ids
             # live in the pool as vectors, not ids, so the histogram comes
@@ -740,7 +846,7 @@ class LLMEngine:
             next_tokens, lps, tids, tlps = sample_and_logprobs(
                 logits, keys, float_b[:, 0], int_b[:, 1], float_b[:, 1],
                 row_keys=True, with_top=jnp.any(int_b[:, 4] > 0))
-            return next_tokens, lps, tids, tlps, kv
+            return (next_tokens, lps, tids, tlps, kv, *(load or ()))
 
         return self._maybe_jit(prefill_hist_step, donate_argnums=(1,))
 
@@ -757,8 +863,10 @@ class LLMEngine:
         partial (KV committed, prompt unfinished)."""
         cfg = self.model_config
         use_pallas = self.use_pallas
+        grouped = self._grouped_experts
         use_pallas_hist = self.use_pallas_hist
         attn_mesh = self._gspmd_attn_mesh()
+        reports_load = self._reports_expert_load
 
         def mixed_step(params, kv: KVCache, int_t, int_b, float_b,
                        chunk_page_table, hist_len, page_tables, context_lens,
@@ -770,9 +878,11 @@ class LLMEngine:
                 logits_indices=int_b[:, 0], chunk_page_table=chunk_page_table,
                 hist_len=hist_len, page_tables=page_tables,
                 context_lens=context_lens)
+            load = [] if reports_load else None
             hidden, kv, _ = model_lib.forward_mixed(
                 params, cfg, int_t[0], meta, kv, use_pallas=use_pallas,
-                use_pallas_hist=use_pallas_hist, attn_mesh=attn_mesh)
+                use_pallas_hist=use_pallas_hist, attn_mesh=attn_mesh,
+                moe_load=load, grouped_experts=grouped)
             logits = model_lib.compute_logits(params, cfg, hidden,
                                               use_pallas=use_pallas)
             logits = _maybe_bias(logits, bias_ids, bias_vals)
@@ -788,7 +898,7 @@ class LLMEngine:
             next_tokens, lps, tids, tlps = sample_and_logprobs(
                 logits, keys, float_b[:, 0], int_b[:, 1], float_b[:, 1],
                 row_keys=True, with_top=jnp.any(int_b[:, 4] > 0))
-            return next_tokens, lps, tids, tlps, kv
+            return (next_tokens, lps, tids, tlps, kv, *(load or ()))
 
         return self._maybe_jit(mixed_step, donate_argnums=(1,))
 
@@ -808,6 +918,7 @@ class LLMEngine:
         accepted token, matching the decode window's per-substep bump."""
         cfg = self.model_config
         use_pallas = self.use_pallas
+        grouped = self._grouped_experts
         attn_mesh = self._gspmd_attn_mesh()
         V = cfg.vocab_size
 
@@ -822,7 +933,7 @@ class LLMEngine:
                             context_lens=context_lens)
             hidden, kv, _ = model_lib.forward_spec_verify(
                 params, cfg, int_t[0], meta, kv, use_pallas=use_pallas,
-                attn_mesh=attn_mesh)
+                attn_mesh=attn_mesh, grouped_experts=grouped)
             # Verification needs logits over EVERY draft position, so the
             # vocab projection runs on all R_pad*S rows (the one place the
             # engine pays more than B logit rows; amortized by acceptance).
@@ -859,6 +970,7 @@ class LLMEngine:
         other grid (tests/test_compile_guard.py)."""
         cfg = self.model_config
         use_pallas = self.use_pallas
+        grouped = self._grouped_experts
         use_pallas_hist = self.use_pallas_hist
         attn_mesh = self._gspmd_attn_mesh()
         V = cfg.vocab_size
@@ -877,7 +989,8 @@ class LLMEngine:
                 context_lens=context_lens)
             hidden, kv, _ = model_lib.forward_spec_mixed(
                 params, cfg, int_t[0], meta, kv, S, use_pallas=use_pallas,
-                use_pallas_hist=use_pallas_hist, attn_mesh=attn_mesh)
+                use_pallas_hist=use_pallas_hist, attn_mesh=attn_mesh,
+                grouped_experts=grouped)
             logits = model_lib.compute_logits(params, cfg, hidden,
                                               use_pallas=use_pallas)
             logits = _maybe_bias(
@@ -949,6 +1062,7 @@ class LLMEngine:
         ``greedy=True`` compiles the argmax-only variant (see __init__)."""
         cfg = self.model_config
         use_pallas = self.use_pallas
+        grouped = self._grouped_experts
         W = self.config.scheduler.decode_window
         ps = self.config.cache.page_size
         max_len = self.config.effective_max_len
@@ -983,7 +1097,7 @@ class LLMEngine:
             def fwd(params, kv, tokens, meta):
                 hidden, kv, _ = model_lib.forward_decode(
                     params, cfg, tokens, meta, kv, use_pallas=use_pallas,
-                    attn_mesh=attn_mesh)
+                    attn_mesh=attn_mesh, grouped_experts=grouped)
                 return model_lib.compute_logits(params, cfg, hidden,
                                                  use_pallas=use_pallas), kv
 
@@ -1188,6 +1302,15 @@ class LLMEngine:
 
     # -- disaggregated prefill/decode (KV handoff seam) ----------------------
 
+    def _require_kv_wire(self, what: str) -> None:
+        """The KV wire paths (handoff, migration, prefix export/import,
+        spill) frame K and V page pairs; a latent-page model has one pool
+        and is refused by name, here and at start (latent_model_refusal)."""
+        if self.model_config.is_mla:
+            raise ValueError(
+                f"{what} with {self.model_config.name}: the KV wire paths "
+                "frame K and V page pairs, not latent pages")
+
     def _export_state(self, seq: Sequence, k_np, v_np) -> dict:
         """The serialized cross-replica sequence state, built from
         COMMITTED quantities only: the sequence's host-known token/logprob
@@ -1218,6 +1341,7 @@ class LLMEngine:
         byte-identically. Pages are released here; raises KeyError when
         nothing is held under ``request_id`` (capacity-terminated or
         already exported) — the caller degrades to local recompute."""
+        self._require_kv_wire("KV export (handoff)")
         seq = self.scheduler.held.pop(request_id, None)
         if seq is None:
             raise KeyError(f"no held KV for request {request_id!r}")
@@ -1253,6 +1377,7 @@ class LLMEngine:
         Raises KeyError when no RUNNING sequence owns ``request_id`` and
         RuntimeError when nothing is committed yet — the caller degrades
         to the wait-it-out drain path."""
+        self._require_kv_wire("KV export (migration)")
         seq = self.scheduler.find_running(request_id)
         if seq is None:
             raise KeyError(f"no running sequence {request_id!r}")
@@ -1301,6 +1426,7 @@ class LLMEngine:
         so the serving layer streams them to the client. Raises on any
         mismatch or capacity shortfall — the caller falls back to local
         recompute (``add_request``), which is byte-identical, just slower."""
+        self._require_kv_wire("KV import")
         # Serving-layer stamp of when the decode replica began the handoff
         # (pull start): now - t0 is the replica-observed TTFT — remote
         # prefill + transfer + import — the client-facing span.
@@ -1439,6 +1565,7 @@ class LLMEngine:
         recomputes locally. Capped at ``len(token_ids) - 1`` like
         admission reuse, so the importer always keeps >= 1 token to
         prefill."""
+        self._require_kv_wire("prefix export")
         pc = self.scheduler.prefix_cache
         if pc is None:
             raise KeyError("prefix caching is off on this replica")
@@ -1528,6 +1655,7 @@ class LLMEngine:
         and finally registers the chain (:meth:`commit_prefix_import`).
         This begin/chunk/commit seam is the ONLY sanctioned way remote
         prefix bytes enter the KV pool (KGCT016)."""
+        self._require_kv_wire("prefix import")
         pc = self.scheduler.prefix_cache
         if pc is None:
             raise ValueError("prefix caching is off on this replica")
@@ -1603,6 +1731,7 @@ class LLMEngine:
         peer's cold prefix never takes device pages until a local lookup
         actually second-chances it. False when the host tier is off/full
         or the frame does not match this pool's geometry."""
+        self._require_kv_wire("remote spill")
         pc = self.scheduler.prefix_cache
         if pc is None:
             return False
@@ -1626,6 +1755,7 @@ class LLMEngine:
         to peers asynchronously). The gather runs through the KVPageIO
         seam and completes before the eviction frees the page (KGCT010).
         False when prefix caching is off."""
+        self._require_kv_wire("fleet spill")
         pc = self.scheduler.prefix_cache
         if pc is None:
             return False
@@ -1729,22 +1859,23 @@ class LLMEngine:
                         out_tokens = self._penalty_out_tokens(batch)
                     with ph("device_dispatch"):
                         (next_tokens, lps, tids, tlps,
-                         self.kv_cache) = self._prefill_hist_fn(
+                         self.kv_cache, *load) = self._prefill_hist_fn(
                             self.params, self.kv_cache, int_t, int_b, float_b,
                             page_tables, jnp.int32(batch.hist_len),
                             out_tokens, bias_ids, bias_vals, step_key)
                     if batch.partial:
                         # Prompt not complete: KV is committed, the sampled
                         # token is meaningless — nothing to report yet.
-                        self._last_step_info = ("prefill", batch.num_seqs,
-                                                None)
+                        self._last_step_info = (
+                            "prefill", batch.num_seqs, None,
+                            self._routed(int(np.sum(batch.seg_ids >= 0))))
                         return drained
                 else:
                     self.stats.prefill_tokens += sum(
                         s.num_tokens for s in batch.seqs)
                     with ph("device_dispatch"):
                         (next_tokens, lps, tids, tlps,
-                         self.kv_cache) = self._prefill_fn(
+                         self.kv_cache, *load) = self._prefill_fn(
                             self.params, self.kv_cache, int_t, int_b, float_b,
                             bias_ids, bias_vals, step_key)
                 with ph("device_fetch"):
@@ -1759,6 +1890,7 @@ class LLMEngine:
                     compute_s = time.perf_counter() - t0f
                     toks_np = np.asarray(next_tokens)[:, None]
                     lps_np = np.asarray(lps)[:, None]
+                    self.obs.on_expert_load(load)
                     top_i = top_l = None
                     if any(s.params.top_logprobs for s in batch.seqs):
                         top_i = np.asarray(tids)[:, None]
@@ -1770,7 +1902,9 @@ class LLMEngine:
                     outs = self._process_window(
                         batch, toks_np, lps_np, set(), defer=False,
                         top_ids=top_i, top_lps=top_l)
-                self._last_step_info = ("prefill", batch.num_seqs, None)
+                self._last_step_info = (
+                    "prefill", batch.num_seqs, None,
+                    self._routed(int(np.sum(batch.seg_ids >= 0))))
                 return drained + outs
             inflight = self._dispatch_window(
                 batch, jnp.asarray(batch.tokens), batch.positions, float_b)
@@ -1812,7 +1946,9 @@ class LLMEngine:
                 self._drain_deferred()
         self._last_step_info = (
             "decode", inflight["batch"].num_seqs,
-            "greedy" if inflight.get("greedy") else "sampled")
+            "greedy" if inflight.get("greedy") else "sampled",
+            self._routed(inflight["batch"].num_seqs
+                         * self.config.scheduler.decode_window))
         return outputs
 
     def _step_mixed(self, batch: ScheduledBatch, float_b,
@@ -1842,7 +1978,8 @@ class LLMEngine:
             bias_ids, bias_vals = self._bias_arrays(batch)
         self.stats.prefill_tokens += batch.prefill_token_count
         with ph("device_dispatch"):
-            (next_tokens, lps, tids, tlps, self.kv_cache) = self._mixed_fn(
+            (next_tokens, lps, tids, tlps, self.kv_cache,
+             *load) = self._mixed_fn(
                 self.params, self.kv_cache, int_t, int_b, float_b, chunk_pt,
                 jnp.int32(batch.hist_len), page_tables, context_lens,
                 out_tokens, bias_ids, bias_vals, step_key)
@@ -1855,6 +1992,7 @@ class LLMEngine:
             compute_s = time.perf_counter() - t0f
             toks_np = np.asarray(next_tokens)[:, None]
             lps_np = np.asarray(lps)[:, None]
+            self.obs.on_expert_load(load)
             top_i = top_l = None
             if any(s.params.top_logprobs for s in batch.seqs):
                 top_i = np.asarray(tids)[:, None]
@@ -1873,8 +2011,19 @@ class LLMEngine:
         self._last_step_info = (
             "mixed", batch.num_seqs, None,
             {"prefill_tokens": batch.prefill_token_count,
-             "decode_tokens": batch.num_seqs - 1})
+             "decode_tokens": batch.num_seqs - 1,
+             **self._routed(batch.prefill_token_count + batch.num_seqs - 1)})
         return outs
+
+    def _routed(self, tokens: int) -> dict:
+        """``on_step``'s count of (token, expert) pairs a step of ``tokens``
+        real tokens sent through the expert layers; empty for a dense
+        model."""
+        m = self.model_config
+        if not m.is_moe:
+            return {}
+        return {"routed_pairs": tokens * m.num_experts_per_tok
+                * (m.num_layers - m.num_dense_layers)}
 
     def _step_spec(self, batch: ScheduledBatch, float_b,
                    step_key) -> list[RequestOutput]:
@@ -2344,15 +2493,55 @@ def _stomp_committed_slot(batch, page_size: int, S: int,
         seq.pages[0] * page_size
 
 
-def _device_free_memory() -> Optional[int]:
+def _resident_bytes(tree) -> int:
+    """Bytes of ``tree``'s arrays that lie on the first addressable device
+    (all of them on one device, a shard of each under a mesh)."""
+    dev = jax.local_devices()[0]
+    return sum(s.data.nbytes for x in jax.tree.leaves(tree)
+               for s in getattr(x, "addressable_shards", ())
+               if s.device == dev)
+
+
+SETTLE_SLACK_BYTES = 64 << 20   # probes' programs and such: 20 MB on a v5e
+SETTLE_TIMEOUT_S = 3.0
+SETTLE_PERIOD_S = 0.05
+
+
+def _device_free_memory(resident: Optional[int] = None) -> Optional[int]:
     """Free HBM bytes on the first addressable device. The CPU backend
     keeps no memory statistics -> None -> test-sized pool
     (kv_cache.derive_num_pages). On an accelerator a missing statistic is
-    an error: guessing there would size a 38 GB pool on a 16 GB chip."""
+    an error: guessing there would size a 38 GB pool on a 16 GB chip.
+
+    ``resident``: the bytes the caller knows to be on the device for good
+    (the weights). In six server starts of fifteen on the v5e the float32
+    draw of a 163,840 x 2048 head, 1.34 GB that the weight init had dropped,
+    was still counted when the last weight was ready, and the pool, a
+    static shape of every step program, came out at 1999 or 2049 pages by
+    chance: a warm compile cache then missed every program. So while the
+    bytes in use stand more than a slack over ``resident``, the read is
+    repeated for up to SETTLE_TIMEOUT_S, each time after a collection of
+    Python's reference cycles and a trivial transfer (for a runtime that
+    frees when it next touches the device); what still stands then is
+    taken to be resident too (another model on the device), and said."""
     dev = jax.local_devices()[0]
     stats = dev.memory_stats()
     if stats and "bytes_limit" in stats:
-        return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+        in_use = int(stats.get("bytes_in_use", 0))
+        t_end = time.monotonic() + SETTLE_TIMEOUT_S
+        while (resident is not None
+               and in_use > resident + SETTLE_SLACK_BYTES):
+            if time.monotonic() > t_end:
+                logger.warning(
+                    "%d bytes in use beside %d of weights did not go within "
+                    "%.0f s: sized around them", in_use - resident, resident,
+                    SETTLE_TIMEOUT_S)
+                break
+            gc.collect()
+            time.sleep(SETTLE_PERIOD_S)
+            jax.device_put(np.int32(0), dev).block_until_ready()
+            in_use = int(dev.memory_stats().get("bytes_in_use", 0))
+        return int(stats["bytes_limit"]) - in_use
     if dev.platform == "cpu":
         return None
     raise RuntimeError(
@@ -2368,10 +2557,12 @@ def step_workspace_bytes(config: EngineConfig) -> int:
     program needs more). Counted at the largest shapes the scheduler
     dispatches, unsharded (conservative under a mesh):
 
-    - the K/V rows the layer scan hands the post-scan pool write;
+    - the K/V (or latent) rows the layer scan hands the post-scan pool write;
     - the widest layer intermediates in f32 plus their model-dtype copy
-      (MLP gate/up/act over ``ff`` — dense-dispatch MoE runs EVERY expert
-      over every token — and q/k/v/attention-out over the heads);
+      (MLP gate/up/act over ``ff`` — a Mixtral-class model is counted at
+      dense dispatch, EVERY expert over every token, which bounds its
+      grouped path too; a latent-attention model at the grouped layout's
+      real rows — and q/k/v/attention-out over the heads);
     - residual-stream copies; and
     - the ``[rows, vocab]`` f32 sampling buffers (logits, penalties
       histogram, sort/top-k scratch) at the top decode bucket."""
@@ -2379,11 +2570,24 @@ def step_workspace_bytes(config: EngineConfig) -> int:
     T = sc.prefill_buckets[-1] + sc.decode_buckets[-1]   # mixed step width
     B = sc.decode_buckets[-1]
     it = m.jnp_dtype.itemsize
-    kd = m.num_kv_heads * m.head_dim
-    kv_rows = (2 * m.num_layers * T * kd
+    kd = m.kv_row_padded
+    kv_rows = (m.kv_pools * m.num_layers * T * kd
                * kv_cache_dtype(m, config.cache).itemsize)
-    mlp = max(m.num_experts, 1) * T * m.intermediate_size * (4 + 4 + it)
-    attn = T * (m.num_heads * m.head_dim + kd) * 2 * (4 + it)
+    if m.is_mla:
+        # Grouped dispatch (models.llama.experts_grouped): the T*k routed
+        # pairs' rows and their gate/up/act over the expert width, the
+        # float32 down-projection before the combine; then the shared
+        # experts and a leading dense layer over T tokens.
+        pairs = T * m.num_experts_per_tok
+        mlp = (pairs * (m.expert_width * (4 + 4 + it)
+                        + m.hidden_size * (it + 4 + 4))
+               + T * max(m.intermediate_size,
+                         m.num_shared_experts * m.expert_width) * (4 + 4 + it))
+        # q, the absorbed q and the latent output at the padded row width.
+        attn = T * m.num_heads * (m.head_dim + 2 * kd) * (4 + it)
+    else:
+        mlp = max(m.num_experts, 1) * T * m.intermediate_size * (4 + 4 + it)
+        attn = T * (m.num_heads * m.head_dim + kd) * 2 * (4 + it)
     resid = 4 * T * m.hidden_size * 4
     sampling = 8 * B * m.vocab_size * 4
     return kv_rows + mlp + attn + resid + sampling

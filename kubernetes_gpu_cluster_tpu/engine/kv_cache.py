@@ -57,9 +57,12 @@ SCRAP_PAGE = 0
 
 
 class KVCache(NamedTuple):
-    """Device-side paged KV pool. k/v: [L, P, page_size, n_kv * head_dim]."""
+    """Device-side paged KV pool. The layout comes from the model: K and V,
+    each [L, P, page_size, n_kv * head_dim], or, for a latent-attention
+    model, ONE pool ``k`` of rows [c | k_pe | pad] ([L, P, page_size,
+    kv_row_padded]) and ``v`` None: there is no V to hold."""
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
 
     @property
     def num_pages(self) -> int:
@@ -83,19 +86,32 @@ def allocate_kv_cache(
 ) -> KVCache:
     dtype = kv_cache_dtype(model, cache)
     shape = (model.num_layers, num_pages, cache.page_size,
-             model.num_kv_heads * model.head_dim)
+             model.kv_row_padded)
     def mk():
         return jnp.zeros(shape, dtype=dtype)
+    if model.kv_pools == 1:
+        return KVCache(k=mk(), v=None)
     if sharding is not None:
         mk_sharded = jax.jit(mk, out_shardings=sharding)
         return KVCache(k=mk_sharded(), v=mk_sharded())
     return KVCache(k=mk(), v=mk())
 
 
+def kv_cache_bytes_per_token(model: ModelConfig, cache: CacheConfig) -> int:
+    """Bytes one cached token really holds over all layers, padding of a
+    latent row included (``kv_row_padding_share`` says how much of it)."""
+    return (model.kv_pools * model.num_layers * model.kv_row_padded
+            * kv_cache_dtype(model, cache).itemsize)
+
+
+def kv_row_padding_share(model: ModelConfig) -> float:
+    """Share of a stored row that is padding: 0 for K|V rows, 64/640 for a
+    576-wide latent row in five 128-lane tiles. Reported, not hidden."""
+    return 1.0 - model.kv_row_dim / model.kv_row_padded
+
+
 def kv_cache_bytes_per_page(model: ModelConfig, cache: CacheConfig) -> int:
-    per_tok = (model.num_kv_heads * model.head_dim
-               * kv_cache_dtype(model, cache).itemsize)
-    return 2 * model.num_layers * cache.page_size * per_tok
+    return cache.page_size * kv_cache_bytes_per_token(model, cache)
 
 
 def derive_num_pages(

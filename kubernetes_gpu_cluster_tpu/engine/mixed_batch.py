@@ -111,6 +111,21 @@ def plan_chunk_tokens(remaining: int, n_decode: int, budget: Optional[int],
     return max(0, min(remaining, room))
 
 
+def mixed_row_bucket(rows: int, chunk_bucket: int,
+                     decode_buckets: tuple[int, ...]) -> int:
+    """The sampled-row bucket of a mixed step: the decode bucket of ``rows``,
+    but never under a sixteenth of the chunk's bucket, so padding rows stay
+    under 1/16 of the step's tokens (their attention meets no page). Each
+    (chunk bucket x row bucket) is a step program of its own to compile, to
+    keep in the compile cache and to load at every start: 35 on the default
+    grid, 11 with this floor, and a server whose seats fill behind prompts of
+    1-2 k tokens meets one program a chunk bucket where it met seven."""
+    from .scheduler import _bucket
+
+    floor = min(chunk_bucket // 16, decode_buckets[-1])
+    return _bucket(max(rows, floor), decode_buckets)
+
+
 def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     """Assemble one mixed step from the scheduler's live state, or return
     None when mixing is not possible this step (caller falls through to the
@@ -211,7 +226,7 @@ def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
 
     D = len(decode_seqs)
     Tp = _bucket(chunk, sc.prefill_buckets)
-    R_pad = _bucket(D + 1, sc.decode_buckets)
+    R_pad = mixed_row_bucket(D + 1, Tp, sc.decode_buckets)
     T_pad = Tp + R_pad
 
     tokens = np.zeros(T_pad, np.int32)

@@ -43,10 +43,20 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
     llama/qwen2/qwen3/mixtral-architecture model works without a preset."""
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
+    if "text_config" in hf:
+        # A kimi_vl checkpoint: the language model's settings are nested,
+        # beside a vision tower this server does not run (text only).
+        logger.info("%s config: serving text_config (%s); vision_config "
+                    "is not served", hf.get("model_type", "multimodal"),
+                    hf["text_config"].get("model_type"))
+        hf = hf["text_config"]
     arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0]
     if arch == "OPTForCausalLM":
         return _opt_config_from_hf(hf, name or
                                    os.path.basename(os.path.normpath(path)))
+    if hf.get("kv_lora_rank"):
+        return _deepseek_config_from_hf(
+            hf, name or os.path.basename(os.path.normpath(path)))
     num_heads = hf["num_attention_heads"]
     head_dim = hf.get("head_dim") or hf["hidden_size"] // num_heads
     rope_scaling = None
@@ -78,6 +88,38 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
         num_experts_per_tok=hf.get("num_experts_per_tok", 2),
         max_model_len=min(int(hf.get("max_position_embeddings", 4096)), 8192),
     )
+
+
+def _deepseek_config_from_hf(hf: dict, name: str) -> ModelConfig:
+    """deepseek_v3-class language model (kimi_vl's ``text_config``): latent
+    attention, sigmoid/noaux_tc routing, shared experts, leading dense
+    layers. What the decoder does not implement refuses the load by name."""
+    from ..config.model_config import HF_SHAPE_KEYS
+    for key, ok in (("q_lora_rank", hf.get("q_lora_rank") is None),
+                    ("rope_scaling", not hf.get("rope_scaling")),
+                    ("n_group", hf.get("n_group", 1) == 1),
+                    ("topk_group", hf.get("topk_group", 1) == 1),
+                    ("moe_layer_freq", hf.get("moe_layer_freq", 1) == 1),
+                    ("hidden_act", hf.get("hidden_act", "silu") == "silu"),
+                    ("attention_bias", not hf.get("attention_bias"))):
+        if not ok:
+            raise ValueError(
+                f"{name}: config.json {key}={hf.get(key)!r} is not "
+                "implemented by the latent-attention decoder")
+    fields = {ours: hf[theirs] for theirs, ours in HF_SHAPE_KEYS.items()
+              if theirs in hf}
+    fields["max_model_len"] = min(int(fields.get("max_model_len", 4096)), 8192)
+    fields.setdefault("num_kv_heads", fields["num_heads"])
+    return ModelConfig(
+        name=name,
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        scoring_func=hf.get("scoring_func", "softmax"),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        **fields)
 
 
 def _validate_act(act: str) -> str:
@@ -579,6 +621,11 @@ def load_weights(path: str, cfg: ModelConfig,
         # full host load; sharded placement still works via device_put with
         # the matching shardings pytree.
         return _place(_load_opt_host(ckpt, cfg), cfg, dtype, shardings)
+    if cfg.is_mla:
+        if shardings is not None:
+            raise ValueError(f"{cfg.name}: a latent-attention model loads "
+                             "onto one device (no sharded placement)")
+        return _place(_load_deepseek_host(ckpt, cfg), cfg, dtype, None)
     if shardings is not None:
         return _load_streamed(ckpt, cfg, shardings, dtype)
     L = cfg.num_layers
@@ -669,8 +716,10 @@ def _place(params: Params, cfg: ModelConfig, dtype,
         # device's shard, instead of committing the full tensor to device 0
         # first and resharding device-to-device.
         name = path_[-1].key if hasattr(path_[-1], "key") else str(path_[-1])
-        if x.dtype == np.int8 or name.endswith("_scale"):
-            return np.ascontiguousarray(x)  # int8 weights / f32 scales as-is
+        if (x.dtype == np.int8 or name.endswith("_scale")
+                or name == "router_bias"):
+            # int8 weights, f32 scales and the f32 choice bias as they are
+            return np.ascontiguousarray(x)
         return np.ascontiguousarray(np.asarray(x, dtype=dtype))
 
     params = jax.tree_util.tree_map_with_path(put, params)
@@ -679,6 +728,92 @@ def _place(params: Params, cfg: ModelConfig, dtype,
     n_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(out))
     logger.info("loaded %s: %.2f GB as %s", cfg.name, n_bytes / 1e9, dtype)
     return out
+
+
+def _load_deepseek_host(ckpt: _Checkpoint, cfg: ModelConfig) -> Params:
+    """deepseek_v3 / kimi_vl HF checkpoint -> the ``dense_layers`` +
+    ``layers`` tree of models/llama.py (host numpy). A kimi_vl checkpoint
+    keeps the decoder under ``language_model.``; its ``vision_tower.*`` and
+    ``multi_modal_projector.*`` tensors are skipped (text only), with one
+    log line.
+
+    The rope columns of ``q_proj`` and ``kv_a_proj_with_mqa`` are
+    DE-INTERLEAVED here ([0, 2, 4, ..., 1, 3, 5, ...]): the published
+    modeling code rotates interleaved pairs (x[2i], x[2i+1]) by doing this
+    same permutation on the activations at run time; on the weights it is
+    free, and the decoder's half-split RoPE then turns the same pairs.
+    ``kv_b_proj`` [nh * (nope + v), r] is split per head into ``w_uk``
+    [nh, r, nope] and ``w_uv`` [nh, r, v]."""
+    names = list(ckpt._index)
+    root = "language_model." if any(
+        n.startswith("language_model.") for n in names) else ""
+    keep = (root,) if root else ("model.", "lm_head.")
+    skipped = [n for n in names if not n.startswith(keep)]
+    if skipped:
+        logger.info("%s: skipped %d tensors outside the language model "
+                    "(vision tower, projector: text only), e.g. %s",
+                    cfg.name, len(skipped), skipped[0])
+    nh, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    deint = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+    pre = root + "model.layers.{}."
+
+    def attn(l: int) -> Params:
+        p = pre.format(l)
+        wq = ckpt.get_t(p + "self_attn.q_proj.weight")         # [d, nh*(n+r)]
+        wq = wq.reshape(wq.shape[0], nh, nope + rope)
+        wq = np.concatenate([wq[..., :nope], wq[..., nope:][..., deint]], -1)
+        w_kva = ckpt.get_t(p + "self_attn.kv_a_proj_with_mqa.weight")
+        w_kva = np.concatenate([w_kva[:, :r], w_kva[:, r:][:, deint]], -1)
+        kv_b = ckpt.get(p + "self_attn.kv_b_proj.weight").reshape(
+            nh, nope + vd, r)
+        return {
+            "input_norm": ckpt.get(p + "input_layernorm.weight"),
+            "post_attn_norm": ckpt.get(p + "post_attention_layernorm.weight"),
+            "wq": wq.reshape(wq.shape[0], -1),
+            "w_kva": w_kva,
+            "kv_norm": ckpt.get(p + "self_attn.kv_a_layernorm.weight"),
+            "w_uk": np.ascontiguousarray(kv_b[:, :nope].transpose(0, 2, 1)),
+            "w_uv": np.ascontiguousarray(kv_b[:, nope:].transpose(0, 2, 1)),
+            "wo": ckpt.get_t(p + "self_attn.o_proj.weight"),
+        }
+
+    def swiglu(prefix: str, names=("w_gate", "w_up", "w_down")) -> Params:
+        return {ours: ckpt.get_t(f"{prefix}{theirs}.weight")
+                for ours, theirs in zip(names, ("gate_proj", "up_proj",
+                                                "down_proj"))}
+
+    def layer(l: int) -> Params:
+        p = pre.format(l) + "mlp."
+        out = attn(l)
+        if l < cfg.num_dense_layers:
+            return {**out, **swiglu(p)}
+        out["router"] = ckpt.get_t(p + "gate.weight").astype(np.float32)
+        out["router_bias"] = np.asarray(
+            ckpt.get(p + "gate.e_score_correction_bias"), np.float32)
+        experts = [swiglu(f"{p}experts.{e}.") for e in range(cfg.num_experts)]
+        for k in ("w_gate", "w_up", "w_down"):
+            out[k] = np.stack([e[k] for e in experts])
+        if cfg.num_shared_experts:
+            out.update(swiglu(p + "shared_experts.",
+                              ("ws_gate", "ws_up", "ws_down")))
+        return out
+
+    def stacked(ls) -> Params:
+        per = [layer(l) for l in ls]
+        return {k: np.stack([p[k] for p in per]) for k in per[0]}
+
+    Ld = cfg.num_dense_layers
+    params: Params = {
+        "embed": ckpt.get(root + "model.embed_tokens.weight"),
+        "final_norm": ckpt.get(root + "model.norm.weight"),
+        "layers": stacked(range(Ld, cfg.num_layers)),
+    }
+    if Ld:
+        params["dense_layers"] = stacked(range(Ld))
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = ckpt.get_t(root + "lm_head.weight")
+    return params
 
 
 def _load_opt_host(ckpt: _Checkpoint, cfg: ModelConfig) -> Params:

@@ -116,6 +116,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = N
                 f"(one of {quant_ops.QUANT_METHODS})")
         return _init_params_quant(cfg, key, dtype, w)
 
+    if cfg.is_mla or cfg.num_dense_layers or cfg.num_shared_experts:
+        return _init_params_deepseek(cfg, key, dtype, w)
+
     d, L = cfg.hidden_size, cfg.num_layers
     nh, nkv, hd, ff = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.intermediate_size
     E = cfg.num_experts
@@ -157,6 +160,90 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = N
         params["final_norm_b"] = jnp.zeros((d,), dtype)
     if cfg.pos_embedding == "learned":
         params["pos_embed"] = w(next(keys), (cfg.max_model_len + 2, d), d)
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w(next(keys), (d, cfg.vocab_size), d)
+    return params
+
+
+def _stacked_normal(key, shape, fan_in: int, dtype) -> jax.Array:
+    """``w(key, shape, fan_in)`` for a stacked ``[L, ...]`` tensor too large
+    to draw at once: the float32 draw of all 64 experts of 8 layers is 5.9 GB
+    beside 11 GB of weights on a 16 GB chip. One layer at a time into a
+    donated buffer."""
+    buf = jnp.zeros(shape, dtype)
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def put(buf, k, l):
+        x = jax.random.normal(k, shape[1:], jnp.float32) * (fan_in ** -0.5)
+        return jax.lax.dynamic_update_index_in_dim(buf, x.astype(dtype), l, 0)
+
+    for l, k in enumerate(jax.random.split(key, shape[0])):
+        buf = put(buf, k, l)
+    return buf
+
+
+def _init_params_deepseek(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
+    """Random init of the DeepSeek-V3-class tree (kimi-vl-a3b's language
+    model): ``dense_layers`` (the ``first_k_dense_replace`` leading layers,
+    stacked) beside ``layers`` (the expert layers, stacked), each with the
+    latent-attention tensors. ``w_uk``/``w_uv`` are ``kv_b_proj`` split per
+    head into its key and value halves ([nh, r, nope] and [nh, r, v]): the
+    absorbed form contracts them with q and with the output per head. The
+    choice bias is drawn small and NOT zero (N(0, 0.01)), so that choosing
+    by score + bias and weighing by the raw score differ."""
+    if not (cfg.is_mla and cfg.is_moe):
+        raise ValueError(
+            f"{cfg.name}: latent attention, leading dense layers and shared "
+            "experts are served together (the deepseek_v3 block) or not at all")
+    if cfg.quantization is not None:
+        raise ValueError(
+            f"--quantization {cfg.quantization} with a latent-attention "
+            "model: the absorbed projections and the grouped expert matmuls "
+            "have no int8/int4 path")
+    d, nh = cfg.hidden_size, cfg.num_heads
+    r, nope, rope, vd = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+    E, ffe = cfg.num_experts, cfg.expert_width
+    ffs = cfg.num_shared_experts * ffe
+    Ld, Lm = cfg.num_dense_layers, cfg.num_layers - cfg.num_dense_layers
+    keys = iter(jax.random.split(key, 32))
+
+    def attn(L):
+        return {
+            "input_norm": jnp.ones((L, d), dtype),
+            "post_attn_norm": jnp.ones((L, d), dtype),
+            "wq": w(next(keys), (L, d, nh * (nope + rope)), d),
+            "w_kva": w(next(keys), (L, d, r + rope), d),
+            "kv_norm": jnp.ones((L, r), dtype),
+            "w_uk": w(next(keys), (L, nh, r, nope), r),
+            "w_uv": w(next(keys), (L, nh, r, vd), r),
+            "wo": w(next(keys), (L, nh * vd, d), nh * vd),
+        }
+
+    params: Params = {
+        "embed": w(next(keys), (cfg.vocab_size, d), d),
+        "final_norm": jnp.ones((d,), dtype),
+    }
+    if Ld:
+        ff = cfg.intermediate_size
+        params["dense_layers"] = {
+            **attn(Ld),
+            "w_gate": w(next(keys), (Ld, d, ff), d),
+            "w_up": w(next(keys), (Ld, d, ff), d),
+            "w_down": w(next(keys), (Ld, ff, d), ff),
+        }
+    layers = attn(Lm)
+    layers["router"] = w(next(keys), (Lm, d, E), d).astype(jnp.float32)
+    layers["router_bias"] = 0.01 * jax.random.normal(next(keys), (Lm, E),
+                                                     jnp.float32)
+    layers["w_gate"] = _stacked_normal(next(keys), (Lm, E, d, ffe), d, dtype)
+    layers["w_up"] = _stacked_normal(next(keys), (Lm, E, d, ffe), d, dtype)
+    layers["w_down"] = _stacked_normal(next(keys), (Lm, E, ffe, d), ffe, dtype)
+    if ffs:
+        layers["ws_gate"] = w(next(keys), (Lm, d, ffs), d)
+        layers["ws_up"] = w(next(keys), (Lm, d, ffs), d)
+        layers["ws_down"] = w(next(keys), (Lm, ffs, d), ffs)
+    params["layers"] = layers
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (d, cfg.vocab_size), d)
     return params
@@ -359,26 +446,121 @@ def _dense_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
     return out.astype(x.dtype)
 
 
-def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
-             tp_axis: Optional[str] = None,
-             ep_axis: Optional[str] = None,
-             use_pallas: Optional[bool] = None) -> jax.Array:
-    """Mixtral-style sparse MoE, dense-dispatch formulation: every expert runs
-    over all tokens; combine weights zero out non-routed pairs. Exact (no
-    capacity drops) and shard-friendly: under expert parallelism each device
-    evaluates its local experts and the combine reduces over the expert axis —
-    a psum over ``ep`` (automatic under GSPMD since the combine einsum
-    contracts E; explicit when ``ep_axis`` names a manual shard_map axis).
-    T is small in the serving hot loop, so the extra FLOPs stay MXU-bound
-    rather than latency-critical."""
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
-    # Router always sees the full expert set (router weights replicated).
-    router_logits = jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32))
-    topk_vals, topk_idx = jax.lax.top_k(router_logits, k)           # [T, k]
-    topk_w = jax.nn.softmax(topk_vals, axis=-1)                      # [T, k]
-    # [T, k, E] one-hot routing -> [T, E] combine weights.
-    combine = jnp.sum(jax.nn.one_hot(topk_idx, E, dtype=jnp.float32)
-                      * topk_w[..., None], axis=1)
+def moe_route(lp: Params, x: jax.Array, cfg: ModelConfig
+              ) -> tuple[jax.Array, jax.Array]:
+    """THE router, for both expert classes: scores over all experts in
+    float32 (softmax: Mixtral; sigmoid: DeepSeek-V3/kimi), the top-k CHOSEN
+    by score + ``router_bias`` where the model has one (the noaux_tc
+    correction bias, which never enters the weights), weights = the raw
+    scores of the chosen, normalised over the k and scaled. Softmax over all
+    then normalising the chosen equals Mixtral's softmax over the top-k
+    logits. ``n_group`` = ``topk_group`` = 1 in every served config, so the
+    group-limited step is the identity and is not written.
+    Returns (idx [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32))
+    scores = (jax.nn.sigmoid(logits) if cfg.scoring_func == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    choice = scores + lp["router_bias"] if "router_bias" in lp else scores
+    _, idx = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.routed_scaling_factor
+
+
+# When a step whose experts lie whole on the device runs them by DENSE
+# dispatch (every expert over every token) and not by the grouped path: when
+# dense wastes nothing. (a) The step routes enough pairs that every expert
+# is hit anyway, so grouped dispatch could not skip an expert's weights:
+# T * k >= 4 * E (kimi-vl-a3b, 6 of 64: from 43 tokens; 95.7 % of the experts
+# are hit at 32, 99.8 % at 64). (b) Its E/k times the routed FLOPs cost
+# nothing while the step waits for those weights: T x 2 FLOP a weight against
+# one 2-byte read of it, so up to the chip's FLOP-to-byte balance (197
+# TFLOP/s / 819 GB/s = 240 tokens on a v5e). Then the batched matmul that
+# streams the stacked weights in place beats a sort, a gather and three
+# kernels a layer. Measured on the v5e IN the decode program (9 layers of
+# kimi-vl-a3b, 1-2.7 k cached tokens a row, ms a step, dense / grouped;
+# PERF.md, PR 26): 8 rows 13.18 / 8.32, 16 rows 13.47 / 11.19, 32 rows
+# 14.04 / 14.15, 64 rows 15.49 / 16.33, 128 rows 18.01 / 19.29; a layer's
+# experts alone at 256 tokens 1.97 / 1.83, at 1088 tokens 7.68 / 2.84.
+DENSE_DISPATCH_MAX_TOKENS = 128
+DENSE_DISPATCH_MIN_PAIRS_PER_EXPERT = 4
+
+
+def dense_dispatch_pays(T: int, cfg: ModelConfig) -> bool:
+    """Whether a step of T tokens should run whole, dense-precision experts
+    by dense dispatch (the comment above: every expert is hit anyway, and the
+    extra FLOPs hide under the weight stream)."""
+    return (T <= DENSE_DISPATCH_MAX_TOKENS
+            and T * cfg.num_experts_per_tok
+            >= DENSE_DISPATCH_MIN_PAIRS_PER_EXPERT * cfg.num_experts)
+
+
+_EXPERT_KEYS = ("w_gate", "w_up", "w_down",
+                "w_gate_scale", "w_up_scale", "w_down_scale")
+
+
+def experts_grouped(lp: Params, x: jax.Array, idx: jax.Array,
+                    w: jax.Array, sizes: jax.Array,
+                    layer: Optional[jax.Array] = None,
+                    use_pallas: Optional[bool] = None) -> jax.Array:
+    """Token-sorted grouped expert matmuls: the T*k routed (token, expert)
+    pairs are sorted by expert and each expert's SwiGLU runs over its own
+    contiguous rows, so the work is the pairs' and not experts x tokens
+    (on the chip ``ops.pallas.grouped_matmul``, the kernel or its exception;
+    elsewhere its XLA twin ``jax.lax.ragged_dot``). Every pair is computed
+    whatever the imbalance: there is no capacity and nothing is dropped.
+    x: [T, d]; idx/w: [T, k]; sizes: [E] int32, the pairs of each expert.
+    Returns [T, d] float32.
+
+    ``lp``'s expert tensors are one layer's [E, ...] or, with ``layer``, the
+    whole stack's [n, E, ...]: the kernel is then handed the stack as n*E
+    groups of which only this layer's are not empty. Indexing the layer out
+    first would COPY its experts (1.1 GB a layer at kimi-vl-a3b's widths,
+    a third of the step's device time when it was done so): a custom call
+    cannot read through a dynamic slice. The rows past the groups' end (the
+    kernel's padding to whole tiles) are cut off before the combine."""
+    T, k = idx.shape
+    order = jnp.argsort(idx.reshape(-1), stable=True)       # pairs by expert
+    xs = x[order // k]                                      # [T*k, d]
+    # Dense-precision experts only: _moe_mlp keeps int8/int4 ones on _dot.
+    w_gate, w_up, w_down = (lp[n] for n in ("w_gate", "w_up", "w_down"))
+    if layer is not None:
+        n_layers, E = w_gate.shape[:2]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * E,), sizes.dtype), sizes, (layer * E,))
+        w_gate, w_up, w_down = (a.reshape((n_layers * E,) + a.shape[2:])
+                                for a in (w_gate, w_up, w_down))
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    if use_pallas:
+        from ..ops.pallas.grouped_matmul import grouped_matmul as matmul
+    else:
+        matmul = functools.partial(jax.lax.ragged_dot,
+                                   preferred_element_type=jnp.float32)
+    gate = matmul(xs, w_gate, sizes)
+    up = matmul(xs, w_up, sizes)
+    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    y = matmul(h, w_down, sizes)                                # [T*k, d]
+    y = y * w.reshape(-1)[order][:, None]
+    # Back to token order by a gather (a scatter-add serialises on the chip).
+    return y[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+
+
+def experts_dense(lp: Params, x: jax.Array, idx: jax.Array, w: jax.Array,
+                  cfg: ModelConfig, ep_axis: Optional[str] = None,
+                  use_pallas: Optional[bool] = None) -> jax.Array:
+    """Dense dispatch: every (local) expert runs over all tokens and combine
+    weights zero the pairs that were not routed. Exact, E/k times the
+    grouped path's FLOPs: the path of steps that hit every expert under the
+    FLOP-to-byte balance (``dense_dispatch_pays``), and kept where experts are sharded (under expert parallelism each device
+    evaluates its local experts and the combine reduces over ``ep``) and
+    for int8/int4 experts (``_dot``); the oracle the grouped path is tested
+    against. ``lp``'s expert tensors are ONE layer's. Returns [T, d]
+    float32."""
+    E = cfg.num_experts
+    combine = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32)
+                      * w[..., None], axis=1)                # [T, E]
     E_local = lp["w_gate"].shape[0]  # E under GSPMD; E/ep inside shard_map
     if ep_axis is not None and E_local != E:
         start = jax.lax.axis_index(ep_axis) * E_local
@@ -390,16 +572,122 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
         h = (jax.nn.silu(gate) * up).astype(x.dtype)
         return _dot(h, ep_params, "w_down", use_pallas)              # [T, d]
 
-    expert_params = {k: lp[k] for k in
-                     ("w_gate", "w_up", "w_down",
-                      "w_gate_scale", "w_up_scale", "w_down_scale")
-                     if k in lp}
+    expert_params = {k: lp[k] for k in _EXPERT_KEYS if k in lp}
     expert_outs = jax.vmap(expert_fn)(expert_params)  # [E_local, T, d]
-    out = jnp.einsum("te,etd->td", combine, expert_outs)
+    return jnp.einsum("te,etd->td", combine, expert_outs)
+
+
+def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
+             tp_axis: Optional[str] = None,
+             ep_axis: Optional[str] = None,
+             use_pallas: Optional[bool] = None,
+             load_out: Optional[list] = None,
+             stacked: Optional[tuple] = None,
+             grouped: bool = False) -> jax.Array:
+    """Sparse expert layer: route (``moe_route``), run the routed experts,
+    add the shared experts where the model has them. Expert compute is
+    dense dispatch unless the caller says ``grouped``: that the expert
+    tensors lie WHOLE on the device that runs this (no GSPMD mesh shards
+    them, no manual ``tp``/``ep`` axis), so the grouped kernel, a custom
+    call with no partitioning rule, may be handed the stack as it is. Then
+    it is chosen from the step's size (``dense_dispatch_pays``): dense where
+    every expert is hit anyway and the step is under the chip's FLOP-to-byte
+    balance, else the token-sorted grouped path.
+    Sharded and quantized experts are always dense dispatch (PERF.md:
+    debt). ``load_out``: a list that is
+    given the routed pairs each expert was sent, [E] int32 (``_layer_scan``'s
+    ``moe_load``). ``stacked``: (the whole stack's expert tensors
+    [n, E, ...], this layer's index in it) where the caller kept them out
+    of ``lp`` (``_layer_scan``: so that no layer's experts are copied)."""
+    with jax.named_scope("kgct.moe.route"):
+        idx, w = moe_route(lp, x, cfg)
+        # Pairs of each expert, as a sum of one-hot rows: a bincount is a
+        # scatter-add, which the chip performs one update after the other.
+        load = jnp.sum(jax.nn.one_hot(idx.reshape(-1), cfg.num_experts,
+                                      dtype=jnp.int32), axis=0)
+    experts, layer = stacked if stacked is not None else (lp, None)
+    with jax.named_scope("kgct.moe.experts"):
+        if grouped and (tp_axis is not None or ep_axis is not None):
+            raise ValueError("grouped expert dispatch inside a manual "
+                             "tp/ep shard_map: its experts are sharded")
+        if (grouped and experts["w_gate"].dtype != jnp.int8
+                and not dense_dispatch_pays(x.shape[0], cfg)):
+            out = experts_grouped(experts, x, idx, w, load, layer,
+                                  use_pallas)
+        else:
+            if layer is not None:   # one layer's, read in place by the dots
+                experts = {k: jax.lax.dynamic_index_in_dim(
+                    a, layer, 0, keepdims=False) for k, a in experts.items()}
+            out = experts_dense(experts, x, idx, w, cfg, ep_axis, use_pallas)
     reduce_axes = tuple(a for a in (ep_axis, tp_axis) if a is not None)
     if reduce_axes:
         out = jax.lax.psum(out, reduce_axes)
-    return out.astype(x.dtype)
+    out = out.astype(x.dtype)
+    if "ws_gate" in lp:
+        with jax.named_scope("kgct.moe.shared"):
+            out = out + _dense_mlp(
+                {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
+                 "w_down": lp["ws_down"]}, x, cfg, use_pallas=use_pallas)
+    if load_out is not None:
+        load_out.append(load)
+    return out
+
+
+def _mla_qkv(lp: Params, cfg: ModelConfig, x: jax.Array,
+             positions: jax.Array, use_pallas: Optional[bool] = None):
+    """Latent attention's projections. x: [T, d] -> q [T, nh, nope + rope]
+    (RoPE on its last ``rope`` dims) and the cache row [T, kv_row_padded] =
+    [c (its own RMSNorm) | k_pe (RoPE, one head shared by all) | zeros].
+    RoPE is half-split over the rope dims: the loader de-interleaves those
+    columns of a checkpoint (engine/weights.py), which rotates the same
+    pairs the published code does."""
+    T = x.shape[0]
+    r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    q = _dot(x, lp, "wq", use_pallas).astype(x.dtype)
+    q = q.reshape(T, q.shape[-1] // cfg.head_dim, cfg.head_dim)
+    a = _dot(x, lp, "w_kva", use_pallas).astype(x.dtype)      # [T, r + rope]
+    c = rms_norm(a[:, :r], lp["kv_norm"], cfg.rms_norm_eps)
+    cos, sin = rope_cos_sin(positions, rope, cfg.rope_theta,
+                            scaling=cfg.rope_scaling_dict)
+    q = jnp.concatenate(
+        [q[..., :-rope], apply_rope(q[..., -rope:], cos, sin)], axis=-1)
+    k_pe = apply_rope(a[:, None, r:], cos, sin)[:, 0]
+    pad = jnp.zeros((T, cfg.kv_row_padded - cfg.kv_row_dim), x.dtype)
+    return q, jnp.concatenate([c, k_pe, pad], axis=-1)
+
+
+def mla_materialise(lp: Params, cfg: ModelConfig, row: jax.Array):
+    """Cache rows [T, R] -> per-head k [T, nh, nope + rope] and v
+    [T, nh, v]: the form fresh tokens attend each other in."""
+    r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    c = row[:, :r]
+    k_nope = jnp.einsum("tc,hcn->thn", c, lp["w_uk"],
+                        preferred_element_type=jnp.float32).astype(row.dtype)
+    v = jnp.einsum("tc,hcv->thv", c, lp["w_uv"],
+                   preferred_element_type=jnp.float32).astype(row.dtype)
+    k_pe = jnp.broadcast_to(row[:, None, r:r + rope],
+                            k_nope.shape[:2] + (rope,))
+    return jnp.concatenate([k_nope, k_pe], axis=-1), v
+
+
+def mla_absorbed(lp: Params, cfg: ModelConfig, q: jax.Array, row: jax.Array,
+                 attend) -> jax.Array:
+    """The form cached tokens are met in: q's nope part is carried into the
+    latent space (``q_lat = q_nope W_uk[head]``), so a score is one dot
+    product of [q_lat | q_pe] with a cache row and every head reads the SAME
+    row (multi-query attention whose value is the key row's first r lanes);
+    the latent output leaves through ``W_uv[head]``. ``attend(q_abs
+    [T, nh, R], rows [T, 1, R]) -> [T, nh, R]`` is any shared-row attention
+    of ops.attention (``v_pool=None``). Same function as the materialised
+    form (tests/test_mla_moe.py)."""
+    r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    q_lat = jnp.einsum("thn,hcn->thc", q[..., :-rope], lp["w_uk"],
+                       preferred_element_type=jnp.float32).astype(q.dtype)
+    pad = jnp.zeros(q.shape[:2] + (row.shape[-1] - r - rope,), q.dtype)
+    q_abs = jnp.concatenate([q_lat, q[..., -rope:], pad], axis=-1)
+    o_lat = attend(q_abs, row[:, None, :])[..., :r]
+    return jnp.einsum("thc,hcv->thv", o_lat, lp["w_uv"],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
 
 
 def _qkv(lp: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
@@ -429,14 +717,35 @@ def _qkv(lp: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     return q, k, v
 
 
-def _mlp_block(lp: Params, cfg: ModelConfig, x: jax.Array,
-               tp_axis: Optional[str] = None,
-               ep_axis: Optional[str] = None,
-               use_pallas: Optional[bool] = None) -> jax.Array:
-    if cfg.is_moe:
-        return _moe_mlp(lp, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
-                        use_pallas=use_pallas)
-    return _dense_mlp(lp, x, cfg, tp_axis=tp_axis, use_pallas=use_pallas)
+def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
+                        row: jax.Array, seg_ids: jax.Array,
+                        positions: jax.Array, pool: jax.Array,
+                        page_table: jax.Array, hist_len: jax.Array,
+                        layer_idx: jax.Array, use_pallas, use_pallas_hist
+                        ) -> jax.Array:
+    """One sequence's prompt chunk under latent attention. With nothing of
+    the sequence in the pool yet (``hist_len == 0``: a whole prompt in one
+    step, the first chunk of a long one) the chunk's tokens attend each
+    other in the MATERIALISED form; with history (a later chunk, a
+    prefix-cache hit) the pool is met in the absorbed form, and the chunk's
+    own rows with it in the same shared-row sweep (3.4x the attention FLOPs
+    for that part; merging the two forms' softmaxes would spare it). The
+    choice is the device's, from ``hist_len``: both are in the program."""
+    scale = cfg.head_dim ** -0.5
+
+    def fresh(_):
+        k, v = mla_materialise(lp, cfg, row)
+        return ragged_prefill_attention(q, k, v, seg_ids, positions, scale,
+                                        use_pallas=use_pallas)
+
+    def with_history(_):
+        return mla_absorbed(
+            lp, cfg, q, row, lambda qa, rows: prefill_history_attention(
+                qa, rows, None, seg_ids, positions, pool, None, page_table,
+                hist_len, scale, layer=layer_idx,
+                use_pallas=use_pallas_hist))
+
+    return jax.lax.cond(hist_len == 0, fresh, with_history, None)
 
 
 # ---------------------------------------------------------------------------
@@ -449,6 +758,8 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                 tp_axis: Optional[str] = None,
                 ep_axis: Optional[str] = None,
                 use_pallas: Optional[bool] = None,
+                moe_load: Optional[list] = None,
+                grouped_experts: bool = False,
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Scan the layer body over stacked weights.
 
@@ -480,18 +791,30 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     write consumes the scan's output buffers as they are (flattening the
     stacked [L, T, n_kv, hd] afterwards is a relayout: a second full copy
     of both, 2 x 144 MB at qwen3-4b T=2048, while the pool leaves no room).
-    """
-    layers = params["layers"]
-    if layer_slice is not None:
-        start, stop = layer_slice
-        layers = jax.tree.map(lambda a: a[start:stop], layers)
+    A latent-attention model (``cfg.is_mla``) has one pool: k_all is its
+    rows [L, T, kv_row_padded], v_all is None, and ``attn_fn`` is handed
+    (lp, q [T, nh, nope + rope], row [T, kv_row_padded], None, layer_idx).
 
+    ``moe_load``: a list the caller owns; where the stack has expert layers
+    the step's routed pairs of each expert, [E] int32 summed over the
+    layers, are appended to it (a traced value of the caller's own trace:
+    the step program returns it beside its tokens, so the host reads the
+    routing balance from a fetch it makes anyway).
+
+    ``grouped_experts``: the engine's word that no mesh shards the expert
+    tensors (``_moe_mlp``'s ``grouped``); every ``forward_*`` hands it on.
+    """
     def body(h, xs):
         lp, layer_idx = xs
         resid = h
         x = _norm(cfg, h, lp, "input_norm")
-        q, k, v = _qkv(lp, cfg, x, positions, use_pallas)
-        attn_out = attn_fn(lp, q, k, v, layer_idx)
+        if cfg.is_mla:
+            with jax.named_scope("kgct.mla"):
+                q, row = _mla_qkv(lp, cfg, x, positions, use_pallas)
+                attn_out = attn_fn(lp, q, row, None, layer_idx)
+        else:
+            q, k, v = _qkv(lp, cfg, x, positions, use_pallas)
+            attn_out = attn_fn(lp, q, k, v, layer_idx)
         attn_out = attn_out.reshape(x.shape[0], -1)
         o = _dot(attn_out, lp, "wo", use_pallas)
         if tp_axis is not None:  # row-sharded wo: partial sums over local heads
@@ -501,14 +824,53 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         h = resid + o.astype(h.dtype)
         resid = h
         x = _norm(cfg, h, lp, "post_attn_norm")
-        h = resid + _mlp_block(lp, cfg, x, tp_axis=tp_axis, ep_axis=ep_axis,
-                               use_pallas=use_pallas)
-        return h, (k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1))
+        # A layer is an expert layer if it holds a router: the leading dense
+        # layers of a deepseek_v3 stack do not.
+        load = [] if moe_load is not None else None
+        if "router" in lp:
+            mlp = _moe_mlp(lp, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
+                           use_pallas=use_pallas, load_out=load,
+                           stacked=(experts, layer_idx - first),
+                           grouped=grouped_experts)
+        else:
+            mlp = _dense_mlp(lp, x, cfg, tp_axis=tp_axis,
+                             use_pallas=use_pallas)
+        h = resid + mlp
+        load = tuple(load or ())
+        if cfg.is_mla:
+            return h, ((row,), load)
+        return h, ((k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1)),
+                   load)
 
-    n_layers = jax.tree.leaves(layers)[0].shape[0]
-    h, (k_all, v_all) = jax.lax.scan(
-        body, h, (layers, jnp.arange(n_layers, dtype=jnp.int32)))
-    return h, k_all, v_all
+    # The stack: the leading dense layers (``dense_layers``, where the model
+    # has them) and then the scanned main layers, pool layer indices in
+    # order. Each group is one scan over its own stacked weights.
+    stacks = [params[name] for name in ("dense_layers", "layers")
+              if name in params]
+    if layer_slice is not None:
+        if len(stacks) > 1:
+            raise ValueError("layer_slice (pipeline stages) over a stack "
+                             "with leading dense layers")
+        start, stop = layer_slice
+        stacks = [jax.tree.map(lambda a: a[start:stop], stacks[0])]
+    first, outs = 0, []
+    for layers in stacks:
+        # An expert stack's expert tensors stay OUT of the scanned xs: the
+        # body reads them in place, by layer index (see experts_grouped).
+        experts = {k: layers[k] for k in _EXPERT_KEYS
+                   if k in layers and "router" in layers}
+        layers = {k: a for k, a in layers.items() if k not in experts}
+        n_layers = jax.tree.leaves(layers)[0].shape[0]
+        h, (rows, load) = jax.lax.scan(
+            body, h,
+            (layers, jnp.arange(first, first + n_layers, dtype=jnp.int32)))
+        first += n_layers
+        outs.append(rows)
+        if load:    # [n_layers, E] -> the step's pairs of each expert
+            moe_load.append(jnp.sum(load[0], axis=0))
+    rows = (outs[0] if len(outs) == 1 else
+            tuple(jnp.concatenate(r, axis=0) for r in zip(*outs)))
+    return (h, rows[0], rows[1]) if len(rows) == 2 else (h, rows[0], None)
 
 
 def forward_prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -517,7 +879,8 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
                     hidden_in: Optional[jax.Array] = None,
                     tp_axis: Optional[str] = None,
                     ep_axis: Optional[str] = None,
-                    attn_mesh=None, attn_impl=None):
+                    attn_mesh=None, attn_impl=None, moe_load=None,
+                    grouped_experts=False):
     """Ragged prefill over T flattened tokens. Returns (selected_hidden [B, d],
     new_kv, raw_hidden [T, d]). ``hidden_in`` replaces the embedding lookup for
     non-first pipeline stages; ``raw_hidden`` is what rotates stage-to-stage.
@@ -538,12 +901,17 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
             return ragged_prefill_attention_tp(attn_mesh, q, k, v,
                                                meta.seg_ids, meta.positions,
                                                scale)
+        if cfg.is_mla:
+            # Fresh tokens attend each other in the materialised form.
+            k, v = mla_materialise(lp, cfg, k)
         return ragged_prefill_attention(q, k, v, meta.seg_ids, meta.positions,
                                         scale, use_pallas=use_pallas)
 
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
                                   layer_slice, tp_axis=tp_axis,
-                                  ep_axis=ep_axis, use_pallas=use_pallas)
+                                  ep_axis=ep_axis, use_pallas=use_pallas,
+                                  moe_load=moe_load,
+                                  grouped_experts=grouped_experts)
     if layer_slice is not None:
         kv = KVCache(k=kv.k[layer_slice[0]:layer_slice[1]],
                      v=kv.v[layer_slice[0]:layer_slice[1]])
@@ -561,7 +929,8 @@ def forward_prefill_hist(params: Params, cfg: ModelConfig, tokens: jax.Array,
                          use_pallas=None, attn_mesh=None,
                          hidden_in: Optional[jax.Array] = None,
                          tp_axis: Optional[str] = None,
-                         ep_axis: Optional[str] = None):
+                         ep_axis: Optional[str] = None, moe_load=None,
+                         grouped_experts=False):
     """Chunked prefill: one sequence's chunk attending to its pool history +
     itself causally (ops.attention.prefill_history_attention). Returns
     (normed_selected [1, d], new_kv, raw_hidden [T, d]). ``attn_mesh``: under
@@ -573,6 +942,10 @@ def forward_prefill_hist(params: Params, cfg: ModelConfig, tokens: jax.Array,
          if hidden_in is None else hidden_in)
 
     def attn_fn(lp, q, k, v, layer_idx):
+        if cfg.is_mla:
+            return mla_chunk_attention(
+                lp, cfg, q, k, meta.seg_ids, meta.positions, kv.k,
+                page_table, hist_len, layer_idx, use_pallas, use_pallas)
         if attn_mesh is not None:
             return prefill_history_attention_tp(
                 attn_mesh, q, k, v, meta.seg_ids, meta.positions, kv.k, kv.v,
@@ -584,7 +957,8 @@ def forward_prefill_hist(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
                                   tp_axis=tp_axis, ep_axis=ep_axis,
-                                  use_pallas=use_pallas)
+                                  use_pallas=use_pallas, moe_load=moe_load,
+                                  grouped_experts=grouped_experts)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
                                          meta.slot_mapping,
                                          use_pallas=use_pallas,
@@ -595,7 +969,8 @@ def forward_prefill_hist(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
 def forward_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
                   meta: MixedMeta, kv: KVCache,
-                  use_pallas=None, use_pallas_hist=None, attn_mesh=None):
+                  use_pallas=None, use_pallas_hist=None, attn_mesh=None,
+                  moe_load=None, grouped_experts=False):
     """Mixed prefill/decode step (stall-free batching): ONE forward over the
     combined token axis — embedding, QKV/MLP matmuls and norms run once for
     chunk and decode tokens together, so the weight streaming a decode step
@@ -614,6 +989,21 @@ def forward_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
     n_prefill = tokens.shape[0] - meta.page_tables.shape[0]
 
     def attn_fn(lp, q, k, v, layer_idx):
+        if cfg.is_mla:
+            # The chunk half as a chunk alone; each decode row against its
+            # latent pages in the absorbed form, every page read once.
+            out_p = mla_chunk_attention(
+                lp, cfg, q[:n_prefill], k[:n_prefill],
+                meta.seg_ids[:n_prefill], meta.positions[:n_prefill], kv.k,
+                meta.chunk_page_table[0], meta.hist_len, layer_idx,
+                use_pallas, use_pallas_hist)
+            out_d = mla_absorbed(
+                lp, cfg, q[n_prefill:], k[n_prefill:],
+                lambda qa, rows: paged_decode_attention(
+                    qa, kv.k, None, meta.page_tables, meta.context_lens,
+                    rows, None, scale, layer=layer_idx,
+                    use_pallas=use_pallas))
+            return jnp.concatenate([out_p, out_d], axis=0)
         return mixed_attention(
             q, k, v, meta.seg_ids, meta.positions, kv.k, kv.v,
             meta.chunk_page_table, meta.hist_len, meta.page_tables,
@@ -622,7 +1012,8 @@ def forward_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
             attn_mesh=attn_mesh)
 
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  use_pallas=use_pallas)
+                                  use_pallas=use_pallas, moe_load=moe_load,
+                                  grouped_experts=grouped_experts)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
                                          meta.slot_mapping,
                                          use_pallas=use_pallas,
@@ -634,7 +1025,7 @@ def forward_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
 def forward_spec_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
                        meta: MixedMeta, kv: KVCache, S: int,
                        use_pallas=None, use_pallas_hist=None,
-                       attn_mesh=None):
+                       attn_mesh=None, grouped_experts=False):
     """Spec×mixed step: ONE forward over the combined
     ``[prefill chunk | verify slices]`` token axis — embedding, QKV/MLP
     matmuls and norms run once for chunk and verify tokens together (the
@@ -659,7 +1050,8 @@ def forward_spec_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
             attn_mesh=attn_mesh)
 
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  use_pallas=use_pallas)
+                                  use_pallas=use_pallas,
+                                  grouped_experts=grouped_experts)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
                                          meta.slot_mapping,
                                          use_pallas=use_pallas,
@@ -670,7 +1062,7 @@ def forward_spec_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
 def forward_spec_verify(params: Params, cfg: ModelConfig, tokens: jax.Array,
                         meta: SpecMeta, kv: KVCache, use_pallas=None,
-                        attn_mesh=None):
+                        attn_mesh=None, grouped_experts=False):
     """Speculative-verification forward: ONE program scores every running
     sequence's k drafted tokens. Embedding, QKV/MLP matmuls and norms run
     over the flat ``[R_pad * S]`` token axis (the weight streaming a decode
@@ -694,7 +1086,8 @@ def forward_spec_verify(params: Params, cfg: ModelConfig, tokens: jax.Array,
             layer=layer_idx, use_pallas=use_pallas)
 
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  use_pallas=use_pallas)
+                                  use_pallas=use_pallas,
+                                  grouped_experts=grouped_experts)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
                                          meta.slot_mapping,
                                          use_pallas=use_pallas,
@@ -708,7 +1101,7 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
                    hidden_in: Optional[jax.Array] = None,
                    tp_axis: Optional[str] = None,
                    ep_axis: Optional[str] = None,
-                   attn_mesh=None):
+                   attn_mesh=None, grouped_experts=False):
     """Decode step: B sequences, one new token each, against the paged pool.
     Returns (normed_hidden [B, d], new_kv, raw_hidden [B, d]).
     ``attn_mesh``: under a GSPMD mesh, run the Pallas attention per-shard via
@@ -726,6 +1119,13 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
         # are committed to the pool in one post-scan write. The STACKED pool
         # + dynamic layer index go straight to the kernel — no per-layer pool
         # slice is ever materialized (see _layer_scan docstring).
+        if cfg.is_mla:
+            # Each latent page is read ONCE, as key and as value.
+            return mla_absorbed(
+                lp, cfg, q, k, lambda qa, rows: paged_decode_attention(
+                    qa, kv.k, None, meta.page_tables, meta.context_lens,
+                    rows, None, scale, layer=layer_idx,
+                    use_pallas=use_pallas))
         if attn_mesh is not None:
             return paged_decode_attention_tp(attn_mesh, q, kv.k, kv.v,
                                              meta.page_tables,
@@ -736,7 +1136,8 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                       layer=layer_idx, use_pallas=use_pallas)
 
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  layer_slice, tp_axis=tp_axis, ep_axis=ep_axis)
+                                  layer_slice, tp_axis=tp_axis, ep_axis=ep_axis,
+                                  grouped_experts=grouped_experts)
     new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
                                          meta.slot_mapping,
                                          use_pallas=use_pallas,

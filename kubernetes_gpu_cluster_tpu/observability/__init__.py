@@ -20,6 +20,8 @@ from __future__ import annotations
 import time
 from collections import deque
 
+import numpy as np
+
 from .flightrecorder import FlightRecorder
 from .phases import PHASES, StepPhaseStats
 from .prometheus import (BATCH_BUCKETS, LATENCY_BUCKETS_S, Histogram, fmt,
@@ -193,6 +195,11 @@ class Observability:
                                  "spec": 0, "spec_mixed": 0}
         self.mixed_prefill_tokens = 0
         self.mixed_decode_tokens = 0
+        # Expert models: (token, expert) pairs sent through the expert
+        # layers, by step kind; and the routing balance of the last prefill,
+        # chunk or mixed step (busiest expert's pairs over the mean).
+        self.moe_routed_pairs: dict[str, int] = {}
+        self.moe_expert_load_max_ratio = 0.0
         # Speculative decoding: cumulative drafted vs accepted draft tokens
         # (bonus tokens excluded from both) — feeds the
         # kgct_spec_acceptance_ratio gauge, the kgct_spec_*_tokens_total
@@ -426,12 +433,23 @@ class Observability:
         self.tracer.emit("abort" if outcome == "aborted" else "finish",
                          seq.request_id, outcome=outcome, output_tokens=n)
 
+    def on_expert_load(self, load) -> None:
+        """``load``: () or one [E] device array, the pairs each expert was
+        sent in the step whose tokens were just fetched (the device is done:
+        this read waits for nothing)."""
+        for pairs in load:
+            pairs = np.asarray(pairs)
+            if pairs.sum() > 0:
+                self.moe_expert_load_max_ratio = float(
+                    pairs.max() / pairs.mean())
+
     # -- step accounting (engine.step) ---------------------------------------
 
     def on_step(self, step: int, kind: str, batch: int, duration_s: float,
                 new_tokens: int, mode: str = None, prefill_tokens: int = 0,
                 decode_tokens: int = 0, drafted_tokens: int = 0,
-                accepted_tokens: int = 0, draft_s: float = 0.0) -> None:
+                accepted_tokens: int = 0, draft_s: float = 0.0,
+                routed_pairs: int = 0) -> None:
         # Flight-recorder state snapshot, at most once per interval: one
         # monotonic read per step when nothing is due.
         self.flight.maybe_snapshot()
@@ -441,6 +459,9 @@ class Observability:
                              duration_s=duration_s)
         if kind in self.step_kind_counts:
             self.step_kind_counts[kind] += 1
+        if routed_pairs:
+            self.moe_routed_pairs[kind] = (
+                self.moe_routed_pairs.get(kind, 0) + routed_pairs)
         if kind == "decode":
             self.tracer.emit("decode", "", batch=batch, tokens=new_tokens,
                              mode=mode or "greedy")
@@ -594,6 +615,20 @@ class Observability:
                                   self.sampled_decode_ratio()))
         lines.extend(render_gauge("kgct_mixed_step_ratio",
                                   self.mixed_step_ratio()))
+        if self.moe_routed_pairs:
+            lines.append("# HELP kgct_moe_routed_pairs_total (token, expert) "
+                         "pairs sent through the expert layers, by step kind")
+            lines.append("# TYPE kgct_moe_routed_pairs_total counter")
+            for kind, n in sorted(self.moe_routed_pairs.items()):
+                lines.append(
+                    'kgct_moe_routed_pairs_total{step_kind="%s"} %d'
+                    % (kind, n))
+            lines.append("# HELP kgct_moe_expert_load_max_ratio busiest "
+                         "expert's pairs over the mean, last prefill, chunk "
+                         "or mixed step")
+            lines.append("# TYPE kgct_moe_expert_load_max_ratio gauge")
+            lines.append("kgct_moe_expert_load_max_ratio %.4f"
+                         % self.moe_expert_load_max_ratio)
         lines.append("# TYPE kgct_mixed_prefill_tokens_total counter")
         lines.append("kgct_mixed_prefill_tokens_total %d"
                      % self.mixed_prefill_tokens)

@@ -43,6 +43,9 @@ def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
     kv_k/kv_v:    [L, P, page_size, n_kv*hd] (the whole pool, heads flattened)
     k_all/v_all:  [L, T, n_kv*hd] (stacked per-layer new entries, heads
                   flattened: the ys of the layer scan)
+                  kv_v and v_all are None for a latent-attention model,
+                  whose one pool holds rows [c | k_pe | pad]; the result's
+                  second element is then None too.
     slot_mapping: [T] int32 flat slot = page_id * page_size + offset.
                   Padding tokens carry slots inside the scrap page 0.
 
@@ -81,23 +84,26 @@ def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
     if use_pallas:
         from .pallas.kv_write import kv_write
         return kv_write(kv_k, kv_v, k_all, v_all, slot_mapping)
+    # One loop over the pools there are: K and V, or a latent-attention
+    # model's one pool of rows [c | k_pe | pad].
+    pools = (kv_k,) if kv_v is None else (kv_k, kv_v)
+    news = (k_all,) if kv_v is None else (k_all, v_all)
     L, P, ps, kd = kv_k.shape
     T = k_all.shape[1]
-    fk = kv_k.reshape(L, P * ps, kd)
-    fv = kv_v.reshape(L, P * ps, kd)
-    k_rows = k_all.reshape(L, T, kd).astype(kv_k.dtype)
-    v_rows = v_all.reshape(L, T, kd).astype(kv_v.dtype)
+    flat = tuple(p.reshape(L, P * ps, kd) for p in pools)
+    rows = tuple(a.reshape(L, T, kd).astype(p.dtype)
+                 for a, p in zip(news, pools))
 
-    def body(i, kv):
-        fk, fv = kv
-        kr = jax.lax.dynamic_slice_in_dim(k_rows, i, 1, axis=1)
-        vr = jax.lax.dynamic_slice_in_dim(v_rows, i, 1, axis=1)
-        fk = jax.lax.dynamic_update_slice(fk, kr, (0, slot_mapping[i], 0))
-        fv = jax.lax.dynamic_update_slice(fv, vr, (0, slot_mapping[i], 0))
-        return fk, fv
+    def body(i, flat):
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                f, jax.lax.dynamic_slice_in_dim(r, i, 1, axis=1),
+                (0, slot_mapping[i], 0))
+            for f, r in zip(flat, rows))
 
-    fk, fv = jax.lax.fori_loop(0, T, body, (fk, fv))
-    return fk.reshape(kv_k.shape), fv.reshape(kv_v.shape)
+    flat = jax.lax.fori_loop(0, T, body, flat)
+    out = tuple(f.reshape(kv_k.shape) for f in flat)
+    return out if kv_v is not None else (out[0], None)
 
 
 # ---------------------------------------------------------------------------
@@ -133,7 +139,8 @@ def ragged_prefill_attention_xla(
     probs = jax.nn.softmax(scores, axis=-1)
     probs = jnp.where(jnp.isnan(probs), 0.0, probs)           # fully-masked rows
     out = jnp.einsum("kgts,skh->tkgh", probs, vf)             # [T, n_kv, g, hd]
-    return out.reshape(T, n_heads, hd).astype(q.dtype)
+    # v may be narrower than q/k (latent attention: 128 against 192).
+    return out.reshape(T, n_heads, v.shape[-1]).astype(q.dtype)
 
 
 def prefill_history_attention_xla(
@@ -143,7 +150,7 @@ def prefill_history_attention_xla(
     seg_ids: jax.Array,      # [T] int32: 0 for chunk tokens, -1 padding
     positions: jax.Array,    # [T] int32 GLOBAL positions (offset by history)
     k_pool: jax.Array,       # [P, ps, n_kv*hd] or [L, P, ps, n_kv*hd]
-    v_pool: jax.Array,
+    v_pool: Optional[jax.Array],  # None: values are the key rows (latent)
     page_table: jax.Array,   # [pages_per_seq] int32 (this sequence's pages)
     hist_len: jax.Array,     # [] int32 tokens already committed to the pool
     scale: float,
@@ -158,6 +165,8 @@ def prefill_history_attention_xla(
     chunked prefills solo — so the history gather is [H, kd], not [T, H, kd].
     XLA implementation; the flash-kernel variant is a planned upgrade.
     """
+    if v_pool is None:        # shared rows: the value is the key row
+        v, v_pool = k, k_pool
     if layer is not None and k_pool.ndim == 4:
         k_pool = jax.lax.dynamic_index_in_dim(k_pool, layer, 0, keepdims=False)
         v_pool = jax.lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
@@ -198,7 +207,7 @@ def prefill_history_attention_xla(
 def paged_decode_attention_xla(
     q: jax.Array,            # [B, n_heads, hd] (post-RoPE)
     k_cache_l: jax.Array,    # [P, page_size, n_kv*hd] (heads flattened)
-    v_cache_l: jax.Array,    # [P, page_size, n_kv*hd]
+    v_cache_l: Optional[jax.Array],  # same; None: values are the key rows
     page_tables: jax.Array,  # [B, pages_per_seq] int32 page ids (pad = 0/scrap)
     context_lens: jax.Array, # [B] int32 number of valid tokens (incl. current)
     k_cur: jax.Array,        # [B, n_kv, hd] current token's K (not yet in pool)
@@ -215,6 +224,8 @@ def paged_decode_attention_xla(
     worth of K/V — HBM-bandwidth-bound, which is what the Pallas kernel
     (pallas_paged_decode) avoids by streaming only valid pages through VMEM
     with online softmax."""
+    if v_cache_l is None:     # shared rows: the value is the key row
+        v_cache_l, v_cur = k_cache_l, k_cur
     if layer is not None and k_cache_l.ndim == 4:
         k_cache_l = jax.lax.dynamic_index_in_dim(k_cache_l, layer, 0,
                                                  keepdims=False)
@@ -353,6 +364,11 @@ def prefill_history_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
     (the pool's layer axis is pp-sharded, outside the tp wrapper's specs)."""
     if use_pallas is None:
         use_pallas = _on_tpu()
+    if use_pallas and v_pool is None:
+        from .pallas.flash_prefill_hist import flash_prefill_history_shared
+        return flash_prefill_history_shared(q, k, seg_ids, positions, k_pool,
+                                            page_table, hist_len, scale,
+                                            layer=layer)
     if use_pallas:
         from .pallas.flash_prefill_hist import flash_prefill_history
         return flash_prefill_history(q, k, v, seg_ids, positions,
@@ -371,6 +387,12 @@ def paged_decode_attention(q, k_cache_l, v_cache_l, page_tables, context_lens,
     slicing a per-layer copy out — the zero-copy path the decode scan uses."""
     if use_pallas is None:
         use_pallas = _on_tpu()
+    if use_pallas and v_cache_l is None:
+        # One pool whose row is key AND value (latent attention as
+        # multi-query attention): a kernel of its own, each page read once.
+        from .pallas.latent_decode import latent_paged_decode
+        return latent_paged_decode(q, k_cache_l, page_tables, context_lens,
+                                   k_cur, scale, layer=layer)
     if use_pallas:
         from .pallas.paged_decode import pallas_paged_decode
         return pallas_paged_decode(q, k_cache_l, v_cache_l, page_tables,

@@ -47,13 +47,14 @@ def _prefill_kernel(
     q_ref,        # [1, BQ, hd] VMEM (one head; arrays are head-major so the
                   #  trailing block dims satisfy Mosaic's (8, 128) tiling)
     k_ref,        # [1, BK, hd] VMEM (matching kv head, absolute block kb)
-    v_ref,        # [1, BK, hd]
+    v_ref,        # [1, BK, hv] (hv = hd, or narrower: latent attention's
+                  #  materialised form has 192-wide q/k and 128-wide v)
     qseg_ref,     # [BQ, 1] int32
     kseg_ref,     # [BK, 1] int32 (absolute block kb)
-    out_ref,      # [1, BQ, hd]
+    out_ref,      # [1, BQ, hv]
     m_scr,        # [BQ, 1] f32
     l_scr,        # [BQ, 1] f32
-    acc_scr,      # [BQ, hd] f32
+    acc_scr,      # [BQ, hv] f32
     *,
     scale: float,
     block_q: int,
@@ -119,10 +120,12 @@ def _prefill_kernel(
 def flash_ragged_prefill(q, k, v, seg_ids, positions, scale, *,
                          block_q: int = 128, block_k: int = 128,
                          interpret: bool = False):
-    """q: [T, nh, hd]; k/v: [T, n_kv, hd]; seg_ids: [T] (-1 = padding).
-    positions are implied by the flat order (causal within segment) and are
-    accepted only for dispatcher signature parity. Returns [T, nh, hd]."""
+    """q: [T, nh, hd]; k: [T, n_kv, hd]; v: [T, n_kv, hv]; seg_ids: [T]
+    (-1 = padding). positions are implied by the flat order (causal within
+    segment) and are accepted only for dispatcher signature parity. Returns
+    [T, nh, hv]."""
     T, nh, hd = q.shape
+    hv = v.shape[-1]
     n_kv = k.shape[1]
     g = nh // n_kv
     block_q = min(block_q, T)
@@ -170,23 +173,23 @@ def flash_ragged_prefill(q, k, v, seg_ids, positions, scale, *,
             pl.BlockSpec((1, block_q, hd), lambda h, i, j, kb: (h, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, hd), kmap, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, hd), kmap, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, hv), kmap, memory_space=pltpu.VMEM),
             pl.BlockSpec((block_q, 1), lambda h, i, j, kb: (i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((block_k, 1), ksegmap, memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, block_q, hd),
+        out_specs=pl.BlockSpec((1, block_q, hv),
                                lambda h, i, j, kb: (h, i, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, hv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((nh, T, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((nh, T, hv), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         name="flash_prefill",

@@ -264,3 +264,166 @@ def flash_prefill_history(q, k, v, seg_ids, positions, k_pool, v_pool,
         name="flash_prefill_hist",
     )(page_table.astype(jnp.int32), meta, q, k_pool, v_pool, kc, vc)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Shared rows: one pool whose row is key AND value (latent attention)
+# ---------------------------------------------------------------------------
+
+def _shared_hist_kernel(
+    # scalar prefetch
+    pt_ref,       # [pps] int32 page table
+    meta_ref,     # [3] int32: (hist_len, layer, n_valid)
+    # blocked inputs
+    q_ref,        # [BQ, nh, R] VMEM, pool dtype
+    page_ref,     # [1, 1, ps, R] VMEM: one pool page, read ONCE
+    rows_ref,     # [BK, R] VMEM: the chunk's own rows
+    out_ref,      # [BQ, nh, R]
+    # scratch
+    m_scr,        # [BQ*nh, 1] f32
+    l_scr,        # [BQ*nh, 1] f32
+    acc_scr,      # [BQ*nh, R] f32
+    *,
+    scale: float,
+    block_q: int,
+    block_k: int,
+    page_size: int,
+    pps: int,
+):
+    """``_hist_kernel`` for one kv head whose value is its key row: the same
+    two-phase sweep (pool pages, then the chunk causally) with no
+    block-diagonal embedding, each page and each chunk block loaded once and
+    used for the scores and for the output, matmuls in the pool's dtype with
+    float32 accumulation, softmax statistics in float32."""
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    hist_len = meta_ref[0]
+    n_valid = meta_ref[2]
+    ps = page_size
+    nh, R = q_ref.shape[1], q_ref.shape[2]
+    rows = block_q * nh
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[:] = jnp.full_like(m_scr, jnp.float32(NEG))
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    q2 = q_ref[...].reshape(rows, R)
+    row_tok = (i * block_q
+               + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // nh)
+    qvalid = row_tok < n_valid
+
+    def online_update(kv, mask):
+        s = jax.lax.dot_general(q2, kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, NEG)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(kv.dtype), kv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(j < pps, j < pl.cdiv(hist_len, ps)))
+    def _():
+        cols = j * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+        online_update(page_ref[0, 0], (cols < hist_len) & qvalid)
+
+    jj = j - pps
+
+    @pl.when(jnp.logical_and(j >= pps,
+                             jj * block_k <= i * block_q + block_q - 1))
+    def _():
+        cols = (jj * block_k
+                + jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 1))
+        kv = rows_ref[...]
+        # A partial final block carries undefined rows past T: 0 * NaN in
+        # p @ kv would poison every real row.
+        krow = (jj * block_k
+                + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0))
+        kv = jnp.where(krow < n_valid, kv, jnp.zeros_like(kv))
+        online_update(kv, (cols <= row_tok) & (cols < n_valid) & qvalid)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_scr[:]
+        safe = jnp.where(l > 0, l, 1.0)   # fully-masked (padding) rows -> 0
+        out_ref[...] = (acc_scr[:] / safe).reshape(
+            block_q, nh, R).astype(out_ref.dtype)
+
+
+def flash_prefill_history_shared(q, rows, seg_ids, positions, pool,
+                                 page_table, hist_len, scale, *, layer=None,
+                                 block_q: int = None, block_k: int = 128,
+                                 interpret: bool = False):
+    """``flash_prefill_history`` over ONE pool of shared rows. q: [T, nh, R];
+    rows: [T, 1, R] (this chunk's rows); pool: [P, ps, R] or [L, P, ps, R]
+    with ``layer``. Returns [T, nh, R] (the caller keeps the lanes that are
+    the value)."""
+    T, nh, R = q.shape
+    if R % 128 != 0 and not interpret:
+        raise ValueError(
+            f"latent pool row {R} must be a multiple of 128 lanes for the "
+            f"Pallas history-prefill kernel")
+    if pool.ndim == 3:
+        pool = pool[None]
+        layer = jnp.zeros((), jnp.int32)
+    elif layer is None:
+        raise ValueError("layer index required for stacked pool")
+    ps = pool.shape[2]
+    pps = page_table.shape[0]
+    if block_q is None:
+        # As above: the float32 accumulator [BQ*nh, R] at ~2 MB.
+        block_q = max(8, min(128, (2 * 1024 * 1024 // (4 * R * nh)) & ~7))
+    block_q = min(block_q, T)
+    block_k = min(block_k, T)
+    nq = pl.cdiv(T, block_q)
+    nk = pl.cdiv(T, block_k)
+    n_valid = jnp.sum(seg_ids >= 0).astype(jnp.int32)
+    meta = jnp.stack([jnp.asarray(hist_len, jnp.int32).reshape(()),
+                      jnp.asarray(layer, jnp.int32).reshape(()),
+                      n_valid])
+
+    def page_idx(j, pt_ref, meta_ref):
+        n_pages = pl.cdiv(meta_ref[0], ps)
+        return pt_ref[jnp.clip(jnp.minimum(j, n_pages - 1), 0, pps - 1)]
+
+    kernel = functools.partial(_shared_hist_kernel, scale=float(scale),
+                               block_q=block_q, block_k=block_k,
+                               page_size=ps, pps=pps)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nq, pps + nk),
+        in_specs=[
+            pl.BlockSpec((block_q, nh, R), lambda i, j, pt, meta: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, ps, R),
+                         lambda i, j, pt, meta:
+                         (meta[1], page_idx(j, pt, meta), 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((block_k, R),
+                         lambda i, j, pt, meta:
+                         (jnp.clip(j - pps, 0, nk - 1), 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((block_q, nh, R),
+                               lambda i, j, pt, meta: (i, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((block_q * nh, 1), jnp.float32),
+            pltpu.VMEM((block_q * nh, 1), jnp.float32),
+            pltpu.VMEM((block_q * nh, R), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((T, nh, R), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name="latent_prefill_hist",
+    )(page_table.astype(jnp.int32), meta, q.astype(pool.dtype), pool,
+      rows.reshape(T, R).astype(pool.dtype))

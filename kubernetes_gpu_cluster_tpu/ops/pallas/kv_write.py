@@ -48,6 +48,7 @@ the decode window's scan carry, where they are.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,38 +66,33 @@ _VMEM_LIMIT = 64 * 2**20    # that and Mosaic's own; a v5e core has 128 MiB
 def _kv_write_kernel(
     # scalar prefetch
     slots_ref,      # [T] int32 flat slot = page * page_size + offset
-    # inputs
-    k_new_ref,      # [L, block_t, kd] VMEM, pool dtype: this block's new rows
-    v_new_ref,
-    k_pool_in,      # aliased to the outputs; never touched
-    v_pool_in,
-    # outputs
-    k_pool,         # [L, P, ps, kd] ANY/HBM
-    v_pool,
-    # scratch
-    k_tiles,        # [block_t, L, 8/pack, max(kd, 256)] uint32 VMEM: one
-                    # pool tile a job, in the first kd lanes
-    v_tiles,
-    read_sems,      # DMA [2 (k, v), block_t]
-    write_sems,     # DMA [2]
-    *,
+    # then, for n pools (K and V, or a latent model's one):
+    #   n inputs   [L, block_t, kd] VMEM, pool dtype: this block's new rows
+    #   n inputs   the pools, aliased to the outputs; never touched
+    #   n outputs  [L, P, ps, kd] ANY/HBM: the pools
+    #   n scratch  [block_t, L, 8/pack, max(kd, 256)] uint32 VMEM: one pool
+    #              tile a job, in the first kd lanes
+    #   read_sems  DMA [n, block_t]
+    #   write_sems DMA [n]
+    *refs,
     num_tokens: int,
     block_t: int,
     page_size: int,
     pack: int,
 ):
-    del k_pool_in, v_pool_in
-    L, lanes = k_tiles.shape[1], k_tiles.shape[-1]
-    kd = k_new_ref.shape[-1]
+    n = (len(refs) - 2) // 4
+    new_refs, out_pools, tiles = refs[:n], refs[2 * n:3 * n], refs[3 * n:4 * n]
+    read_sems, write_sems = refs[4 * n:]
+    L, lanes = tiles[0].shape[1], tiles[0].shape[-1]
+    kd = new_refs[0].shape[-1]
     tile_words = _TILE_ROWS // pack          # word rows of a tile
     t0 = pl.program_id(0) * block_t
     n_here = jnp.minimum(block_t, num_tokens - t0)
     # Interpret mode cannot write through a bitcast ref (JAX 0.9.0): there
     # the caller hands the pools over as words already (``_as_words``).
     pools = tuple(p if p.dtype == jnp.uint32 else p.bitcast(jnp.uint32)
-                  for p in (k_pool, v_pool))
-    news = (k_new_ref.bitcast(jnp.uint32), v_new_ref.bitcast(jnp.uint32))
-    tiles = (k_tiles, v_tiles)
+                  for p in out_pools)
+    news = tuple(r.bitcast(jnp.uint32) for r in new_refs)
 
     def tile_of(i):
         return slots_ref[t0 + i] // _TILE_ROWS
@@ -106,14 +102,14 @@ def _kv_write_kernel(
         return (i == 0) | (tile_of(i) != tile_of(jnp.maximum(i - 1, 0)))
 
     def copies(i, write):
-        """The two DMAs (k, v) of the job token i leads: its pool tile, as
+        """The DMAs (one a pool) of the job token i leads: its pool tile, as
         [L, 8/pack, kd] words, into scratch slot i or back."""
         tile = tile_of(i)
         tiles_per_page = page_size // _TILE_ROWS
         page = tile // tiles_per_page
         w0 = pl.multiple_of((tile % tiles_per_page) * tile_words, tile_words)
         out = []
-        for kv in range(2):
+        for kv in range(n):
             hbm = pools[kv].at[:, page, pl.ds(w0, tile_words), :]
             vmem = tiles[kv].at[i, :, :, pl.ds(0, kd)]
             out.append(pltpu.make_async_copy(vmem, hbm, write_sems.at[kv])
@@ -149,7 +145,7 @@ def _kv_write_kernel(
         keep = ~(jnp.uint32(0xFFFF) << dst_shift)
 
         def layer(l, _):
-            for kv in range(2):
+            for kv in range(n):
                 x = news[kv][l, pl.ds(i // pack, 1), :]          # [1, kd]
                 if lanes != kd:     # scratch rows are wider (see kv_write)
                     x = jnp.concatenate(
@@ -185,12 +181,13 @@ def _kv_write_kernel(
     for_leaders(lambda i: wait(i, True))
 
 
-def _block_tokens(T: int, L: int, kd: int, itemsize: int) -> int:
+def _block_tokens(T: int, L: int, kd: int, itemsize: int,
+                  pools: int = 2) -> int:
     """Tokens per grid step: as many as the VMEM budget holds of one scratch
-    tile (k and v) and the double-buffered new rows (k and v) per token, in
-    whole VMEM tiles of the new rows (16 rows of 16-bit, 8 of 32-bit)."""
+    tile and the double-buffered new rows per token and pool, in whole VMEM
+    tiles of the new rows (16 rows of 16-bit, 8 of 32-bit)."""
     rows = 32 // itemsize
-    per_token = L * kd * itemsize * (2 * _TILE_ROWS + 2 * 2)
+    per_token = L * kd * itemsize * pools * (_TILE_ROWS + 2)
     fit = max(rows, _VMEM_BUDGET // per_token // rows * rows)
     return min(fit, _MAX_BLOCK_T, pl.cdiv(T, rows) * rows)
 
@@ -215,16 +212,22 @@ def _as_rows(words: jax.Array, dtype, pack: int) -> jax.Array:
     return rows.swapaxes(-1, -2).reshape(L, P, wr * pack, kd)
 
 
-def kv_write(k_pool: jax.Array, v_pool: jax.Array, k_all: jax.Array,
-             v_all: jax.Array, slot_mapping: jax.Array, *,
-             interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+def kv_write(k_pool: jax.Array, v_pool: Optional[jax.Array],
+             k_all: jax.Array, v_all: Optional[jax.Array],
+             slot_mapping: jax.Array, *, interpret: bool = False
+             ) -> tuple[jax.Array, Optional[jax.Array]]:
     """k_pool/v_pool: [L, P, ps, kd] (donate them: the result aliases them);
     k_all/v_all: [L, T, kd] as the layer scan hands them over; slot_mapping:
     [T] int32. Returns the two pools with row ``slot_mapping[t]`` of every
     layer holding ``k_all[:, t].astype(pool.dtype)`` and every other row as
     it was: bitwise what the XLA loop leaves (module docstring for equal
-    slots). ``interpret=True`` (CPU tests) hands the pools over as words:
+    slots). A latent-attention model has ONE pool (``v_pool`` and ``v_all``
+    None; the second result is None): same jobs, one DMA each instead of
+    two. ``interpret=True`` (CPU tests) hands the pools over as words:
     interpret mode cannot write through a bitcast ref."""
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    news = (k_all,) if v_pool is None else (k_all, v_all)
+    n = len(pools)
     L, P, ps, kd = k_pool.shape
     T = k_all.shape[1]
     dtype = k_pool.dtype
@@ -234,16 +237,16 @@ def kv_write(k_pool: jax.Array, v_pool: jax.Array, k_all: jax.Array,
         raise ValueError(
             f"paged pool lane dim {kd} (n_kv*head_dim) must be a multiple of "
             f"128 for the Pallas KV write kernel")
-    if itemsize not in (2, 4) or v_pool.dtype != dtype:
+    if itemsize not in (2, 4) or pools[-1].dtype != dtype:
         raise ValueError(
             f"kv_write handles 16- and 32-bit pools of one dtype, not "
-            f"{dtype}/{v_pool.dtype}")
+            f"{dtype}/{pools[-1].dtype}")
     pack = 4 // itemsize
     if ps % _TILE_ROWS:
         raise ValueError(
             f"page_size {ps} must be a multiple of {_TILE_ROWS}, the rows of "
             f"a pool tile in HBM")
-    block_t = _block_tokens(T, L, kd, itemsize)
+    block_t = _block_tokens(T, L, kd, itemsize, n)
 
     kernel = functools.partial(_kv_write_kernel, num_tokens=T,
                                block_t=block_t, page_size=ps, pack=pack)
@@ -258,29 +261,27 @@ def kv_write(k_pool: jax.Array, v_pool: jax.Array, k_all: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(pl.cdiv(T, block_t),),
-        in_specs=[new_spec, new_spec, any_spec, any_spec],
-        out_specs=[any_spec, any_spec],
-        scratch_shapes=[pltpu.VMEM(tile_shape, jnp.uint32),
-                        pltpu.VMEM(tile_shape, jnp.uint32),
-                        pltpu.SemaphoreType.DMA((2, block_t)),
-                        pltpu.SemaphoreType.DMA((2,))],
+        in_specs=[new_spec] * n + [any_spec] * n,
+        out_specs=[any_spec] * n,
+        scratch_shapes=[pltpu.VMEM(tile_shape, jnp.uint32)] * n
+        + [pltpu.SemaphoreType.DMA((n, block_t)),
+           pltpu.SemaphoreType.DMA((n,))],
     )
-    k_new, v_new = k_all.astype(dtype), v_all.astype(dtype)
+    news = [a.astype(dtype) for a in news]
     if interpret:
-        k_pool, v_pool = _as_words(k_pool, pack), _as_words(v_pool, pack)
-    pools = pl.pallas_call(
+        pools = [_as_words(p, pack) for p in pools]
+    out = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
         grid_spec=grid_spec,
         # Operand numbering counts the scalar-prefetch argument.
-        input_output_aliases={3: 0, 4: 1},
+        input_output_aliases={1 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="kv_write",
-    )(slot_mapping.astype(jnp.int32), k_new, v_new, k_pool, v_pool)
+    )(slot_mapping.astype(jnp.int32), *news, *pools)
     if interpret:
-        pools = [_as_rows(p, dtype, pack) for p in pools]
-    return tuple(pools)
+        out = [_as_rows(p, dtype, pack) for p in out]
+    return (out[0], None) if n == 1 else tuple(out)

@@ -309,7 +309,8 @@ class APIServer:
         # exceed the local pool's own byte size (plus header slack) — one
         # misbehaving prefill replica must not balloon this process.
         kv = engine.engine.kv_cache
-        self._handoff_max_bytes = int(kv.k.nbytes + kv.v.nbytes) + (1 << 20)
+        self._handoff_max_bytes = int(
+            kv.k.nbytes + (kv.v.nbytes if kv.v is not None else 0)) + (1 << 20)
         # Spill frames carry ONE page of K and V: bound the /internal/
         # fleet_spill body to that plus header slack — same derive-from-
         # the-local-pool discipline as the handoff bound, checked on
@@ -1121,6 +1122,11 @@ class APIServer:
             messages = body.get("messages")
             if not messages:
                 return None, _error(400, "missing 'messages'")
+            try:
+                messages = _text_only_messages(
+                    messages, self.engine.engine.model_config.name)
+            except ValueError as e:
+                return None, _error(400, str(e))
             return self.tokenizer.encode(
                 apply_chat_template(self.tokenizer, messages)), None
         prompt = body.get("prompt")
@@ -2216,6 +2222,30 @@ def _error(status: int, message: str) -> web.Response:
 
 # -- entry point -------------------------------------------------------------
 
+def _text_only_messages(messages: list, model_name: str) -> list:
+    """Chat messages whose ``content`` is a list of typed parts (the OpenAI
+    multimodal form) as plain-text messages. No model here has a vision or
+    audio tower (kimi-vl-a3b is served as its language model alone), so a
+    part that is not text is refused by name: dropping it would answer a
+    question about a picture nobody looked at."""
+    out = []
+    for m in messages:
+        content = m.get("content") if isinstance(m, dict) else None
+        if isinstance(content, list):
+            texts = []
+            for part in content:
+                kind = part.get("type") if isinstance(part, dict) else None
+                if kind != "text":
+                    raise ValueError(
+                        f"content part of type {kind!r} is not served: "
+                        f"{model_name} runs as a language model on text "
+                        "only (no vision or audio tower); send text parts")
+                texts.append(str(part.get("text", "")))
+            m = {**m, "content": "".join(texts)}
+        out.append(m)
+    return out
+
+
 def build_server(config: EngineConfig, tokenizer_path: Optional[str] = None,
                  model_name: Optional[str] = None, params=None,
                  mesh=None, leader=None, role: str = "both",
@@ -2266,6 +2296,12 @@ def main(argv: Optional[list[str]] = None) -> None:
     p.add_argument("--host", default="0.0.0.0")
     p.add_argument("--port", type=int, default=8000)
     p.add_argument("--max-model-len", type=int, default=None)
+    p.add_argument("--hf-overrides", default=None,
+                   help="JSON object of HF config.json SHAPE keys laid over "
+                        "the model's config (vLLM parity), e.g. "
+                        "'{\"num_hidden_layers\": 9}' to hold one pipeline "
+                        "stage's layers; echoed in the start-up log and on "
+                        "/health")
     p.add_argument("--tensor-parallel-size", type=int, default=1)
     p.add_argument("--pipeline-parallel-size", type=int, default=1)
     p.add_argument("--sequence-parallel-size", type=int, default=1,
@@ -2459,6 +2495,21 @@ def main(argv: Optional[list[str]] = None) -> None:
                 "compile_cache=%s", dev0.platform, dev0.device_kind,
                 jax.device_count(), cache_dir)
     model_cfg = get_model_config(args.model)
+    hf_overrides = None
+    if args.hf_overrides:
+        import json as _json
+
+        from ..config import apply_hf_overrides
+        try:
+            hf_overrides = _json.loads(args.hf_overrides)
+            if not isinstance(hf_overrides, dict):
+                raise ValueError("--hf-overrides: not a JSON object")
+            model_cfg = apply_hf_overrides(model_cfg, hf_overrides)
+        except ValueError as e:
+            p.error(str(e))
+        logger.info("hf overrides %s -> %s: %d layers, max_model_len %d",
+                    hf_overrides, model_cfg.name, model_cfg.num_layers,
+                    model_cfg.max_model_len)
     if args.dtype:
         dtype = {"float16": "bfloat16", "half": "bfloat16",
                  "bf16": "bfloat16"}.get(args.dtype, args.dtype)
@@ -2538,6 +2589,12 @@ def main(argv: Optional[list[str]] = None) -> None:
         # N chips for ~1 chip of throughput. Refuse the misconfiguration.
         p.error(f"--expert-parallel-size {args.expert_parallel_size} "
                 f"requires an MoE model; {model_cfg.name} is dense")
+    from ..config import latent_model_refusal
+    refusal = latent_model_refusal(
+        config, role=args.role, fleet_prefix_cache=args.fleet_prefix_cache,
+        peer_pool=args.peer_pool)
+    if refusal is not None:
+        p.error(refusal)
     mesh = mesh_from_config(config.parallel)
     params = None
     if args.weights:
@@ -2604,6 +2661,8 @@ def main(argv: Optional[list[str]] = None) -> None:
                           integrity_checks=not args.no_integrity_checks,
                           draft_params=draft_params,
                           profile_dir=args.profile_dir)
+    if hf_overrides:
+        server._runtime_info["hf_overrides"] = hf_overrides
     app = server.build_app()
 
     async def _arm_sigterm(app_):

@@ -21,6 +21,7 @@ from __future__ import annotations
 import time
 
 from ..engine.engine import device_memory_stats
+from ..engine.kv_cache import kv_cache_bytes_per_token, kv_row_padding_share
 from ..utils.compile_cache import COMPILE_COUNTERS
 
 
@@ -87,6 +88,15 @@ class Metrics:
             f"kgct_kv_pages_total {alloc.num_pages}",
             "# TYPE kgct_kv_pages_free gauge",
             f"kgct_kv_pages_free {alloc.num_free}",
+            # What a cached token really holds over all layers (K and V
+            # rows, or one latent row with its padding to whole lane
+            # tiles), and the share of a stored row that is padding.
+            "# TYPE kgct_kv_bytes_per_token gauge",
+            "kgct_kv_bytes_per_token %d" % kv_cache_bytes_per_token(
+                eng.model_config, eng.config.cache),
+            "# TYPE kgct_kv_row_padding_share gauge",
+            "kgct_kv_row_padding_share %.4f"
+            % kv_row_padding_share(eng.model_config),
             "# TYPE kgct_uptime_seconds gauge",
             f"kgct_uptime_seconds {time.monotonic() - self._started:.1f}",
         ]

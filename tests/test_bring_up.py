@@ -135,9 +135,9 @@ def built():
         events.append("weights")
         return real_init(*a, **k)
 
-    def free():
+    def free(**k):
         events.append("free_memory")
-        return real_free()
+        return real_free(**k)
 
     mp = pytest.MonkeyPatch()
     mp.setattr(engine_mod.model_lib, "init_params", init)
@@ -191,6 +191,35 @@ class TestEngineBringUp:
         fake.memory_stats = lambda: {"bytes_limit": 100, "bytes_in_use": 40}
         assert engine_mod._device_free_memory() == 60
 
+    def test_free_memory_waits_for_what_the_init_dropped(self, monkeypatch):
+        """The bytes in use fall to the resident weights a moment after the
+        last weight is ready (a dropped float32 draw): the read settles
+        there; bytes that stay (another model on the device) are waited for
+        no longer than the timeout, and with nothing said to be resident
+        there is one read."""
+        n = [0]
+
+        def stats():
+            n[0] += 1
+            return {"bytes_limit": 16 << 30, "bytes_in_use": next(reads)}
+
+        fake = SimpleNamespace(platform="tpu", device_kind="fake v0",
+                               memory_stats=stats)
+        monkeypatch.setattr(jax, "local_devices", lambda: [fake])
+        monkeypatch.setattr(jax, "device_put",
+                            lambda x, d: SimpleNamespace(
+                                block_until_ready=lambda: None))
+        monkeypatch.setattr(engine_mod, "SETTLE_PERIOD_S", 0.0)
+        reads = iter([5 << 30, 5 << 30, (3 << 30) + (20 << 20)])
+        assert engine_mod._device_free_memory(resident=3 << 30) == \
+            (13 << 30) - (20 << 20)
+        monkeypatch.setattr(engine_mod, "SETTLE_TIMEOUT_S", 0.05)
+        reads = iter(lambda: 5 << 30, None)
+        assert engine_mod._device_free_memory(resident=3 << 30) == 11 << 30
+        n[0] = 0
+        assert engine_mod._device_free_memory() == 11 << 30
+        assert n[0] == 1
+
 
 def test_pool_sizing_sets_a_step_workspace_aside():
     """0.90 of everything the weights leave free starves the first full
@@ -231,17 +260,29 @@ def test_kv_write_is_one_formulation_for_every_flush_size(T):
     np.testing.assert_array_equal(np.asarray(v).reshape(want_v.shape), want_v)
 
 
-@pytest.mark.parametrize("dispatcher, kernel_module, kernel, n_args", [
+_X = object()   # an operand the dispatcher only passes on
+
+
+@pytest.mark.parametrize("dispatcher, kernel_module, kernel, args", [
     (attention.paged_decode_attention, "paged_decode",
-     "pallas_paged_decode", 8),
+     "pallas_paged_decode", [_X] * 8),
     (attention.ragged_prefill_attention, "flash_prefill",
-     "flash_ragged_prefill", 6),
+     "flash_ragged_prefill", [_X] * 6),
     (attention.prefill_history_attention, "flash_prefill_hist",
-     "flash_prefill_history", 10),
-    (attention.write_kv_pages_all, "kv_write", "kv_write", 5),
+     "flash_prefill_history", [_X] * 10),
+    (attention.write_kv_pages_all, "kv_write", "kv_write", [_X] * 5),
+    # One pool of shared rows (latent attention: no V pool, no V rows): the
+    # kernels of their own, by the same rule.
+    (attention.paged_decode_attention, "latent_decode",
+     "latent_paged_decode", [_X, _X, None, _X, _X, _X, None, _X]),
+    (attention.prefill_history_attention, "flash_prefill_hist",
+     "flash_prefill_history_shared",
+     [_X, _X, None, _X, _X, _X, None, _X, _X, _X]),
+    (attention.write_kv_pages_all, "kv_write", "kv_write",
+     [_X, None, _X, None, _X]),
 ])
 def test_use_pallas_true_means_the_kernel_or_its_exception(
-        monkeypatch, dispatcher, kernel_module, kernel, n_args):
+        monkeypatch, dispatcher, kernel_module, kernel, args):
     import importlib
     mod = importlib.import_module(
         f"kubernetes_gpu_cluster_tpu.ops.pallas.{kernel_module}")
@@ -250,4 +291,4 @@ def test_use_pallas_true_means_the_kernel_or_its_exception(
         raise NameError("name 'NBUF' is not defined")
     monkeypatch.setattr(mod, kernel, boom)
     with pytest.raises(NameError, match="NBUF"):
-        dispatcher(*([None] * n_args), use_pallas=True)
+        dispatcher(*args, use_pallas=True)

@@ -869,8 +869,11 @@ class TestRouterChaos:
             stall_runner, stall_url = await _mini_replica(
                 response_delay_s=30.0)
             live_runner, live_url = await _mini_replica()
+            # 1.5 s, not 0.3: the deadline also judges the LIVE replica, and
+            # beside five other workers compiling (tier-1 under xdist) its
+            # answer took longer than 0.3 s in three whole runs of three.
             router = Router([stall_url, live_url], health_interval_s=9999,
-                            fail_threshold=1, response_timeout_s=0.3)
+                            fail_threshold=1, response_timeout_s=1.5)
             client = await _start_router(router)
             try:
                 # First request lands on the wedged replica (rr tie-break

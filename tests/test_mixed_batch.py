@@ -18,6 +18,7 @@ from kubernetes_gpu_cluster_tpu.config import (CacheConfig, EngineConfig,
                                                get_model_config)
 from kubernetes_gpu_cluster_tpu.engine import LLMEngine, SamplingParams
 from kubernetes_gpu_cluster_tpu.engine.mixed_batch import (build_mixed_batch,
+                                                           mixed_row_bucket,
                                                            plan_chunk_tokens)
 from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
 from kubernetes_gpu_cluster_tpu.engine.sequence import (Sequence,
@@ -52,6 +53,31 @@ class TestPolicy:
         assert plan_chunk_tokens(100, 4, 64, 16) == 16
         # explicit smaller budget wins
         assert plan_chunk_tokens(100, 1, 8, 16) == 7
+
+    @pytest.mark.parametrize("rows,chunk_bucket,want", [
+        (1, 2048, 64),    # a long prompt's padding rows: 3 % of the step
+        (33, 1024, 64),
+        (2, 512, 32),
+        (40, 512, 64),    # more rows than the floor: their own bucket
+        (2, 128, 8),
+        (9, 128, 16),
+        (2, 16, 2),       # a sixteenth of the chunk under every row count
+    ])
+    def test_row_bucket_floor_is_a_sixteenth_of_the_chunk(self, rows,
+                                                          chunk_bucket, want):
+        grid = (1, 2, 4, 8, 16, 32, 64)
+        assert mixed_row_bucket(rows, chunk_bucket, grid) == want
+
+    def test_row_bucket_floor_bounds_the_program_family(self):
+        """(chunk bucket x row bucket) on the default grid: 11 step programs
+        where every row bucket beside every chunk bucket made 35."""
+        sc = SchedulerConfig()
+        met = {(t, mixed_row_bucket(r, t, sc.decode_buckets))
+               for t in sc.prefill_buckets
+               for r in range(1, sc.decode_buckets[-1] + 1)}
+        assert len(met) == 11
+        assert all(16 * rows >= min(t, 16 * sc.decode_buckets[-1])
+                   for t, rows in met)
 
     def test_mixed_only_when_decode_and_prefill_coexist(self):
         sched = Scheduler(_cfg(), 65)

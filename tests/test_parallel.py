@@ -147,6 +147,33 @@ class TestExpertParallel:
         ep_tokens = _generate_tokens(ep)
         assert ref_tokens == ep_tokens
 
+    @pytest.mark.parametrize("axes", [None, dict(tp=2, ep=2, dp=2),
+                                      dict(tp=4, dp=2), dict(pp=2, tp=2, dp=2)],
+                             ids=["one-device", "gspmd-ep-tp", "gspmd-tp",
+                                  "pp-shard-map"])
+    def test_grouped_dispatch_only_where_experts_are_whole(self, axes,
+                                                           monkeypatch):
+        """The grouped expert kernel is a custom call with no partitioning
+        rule: under ANY mesh (GSPMD shards the expert tensors, the pp
+        shard_map slices them) a step too large for dense dispatch to pay
+        must keep dense dispatch all the same, as the engine's start-up probe (same test,
+        ``_grouped_experts``) assumes; on one device it takes the grouped
+        path. The path is read off the trace of a 300-token prefill."""
+        calls = []
+        real = model_lib.experts_grouped
+        monkeypatch.setattr(
+            model_lib, "experts_grouped",
+            lambda *a, **k: calls.append(a[1].shape[0]) or real(*a, **k))
+        mesh = make_mesh(**axes) if axes else None
+        eng = _greedy_engine("debug-moe", mesh=mesh)
+        assert eng._grouped_experts is (mesh is None)
+        prompt = list(range(3, 303))
+        assert not model_lib.dense_dispatch_pays(len(prompt), eng.model_config)
+        out = eng.generate([prompt], SamplingParams(temperature=0.0,
+                                                    max_tokens=2))
+        assert len(out[0].output_token_ids) == 2
+        assert bool(calls) is (mesh is None), calls
+
     def test_moe_block_shard_map_matches_dense(self):
         cfg = get_model_config("debug-moe")
         key = jax.random.key(2)
