@@ -29,6 +29,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from kubernetes_gpu_cluster_tpu.config import (SchedulerConfig,
                                                apply_hf_overrides,
@@ -39,7 +41,8 @@ from kubernetes_gpu_cluster_tpu.ops.attention import (
 from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill import flash_ragged_prefill
 from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill_hist import (
     flash_prefill_history)
-from kubernetes_gpu_cluster_tpu.ops.pallas.paged_decode import pallas_paged_decode
+from kubernetes_gpu_cluster_tpu.ops.pallas.paged_decode import (
+    _NUM_BUFS, chunk_tokens, pallas_paged_decode)
 from kubernetes_gpu_cluster_tpu.utils import cdiv
 from kubernetes_gpu_cluster_tpu.utils.compile_cache import (
     configure_compile_cache)
@@ -53,21 +56,28 @@ def _err(out, ref, mask=None) -> float:
     return float(jnp.max(d[mask] if mask is not None else d))
 
 
+def _page_tables(ctx, pps):
+    """Distinct pages for every sequence's pool tokens (``ctx`` counts the
+    current token too), padding entries -> scrap page 0. Returns the
+    [B, pps] table and the pool's page count."""
+    tables = np.zeros((len(ctx), pps), np.int32)
+    page = 1
+    for b, n in enumerate(ctx):
+        used = cdiv(int(n) - 1, PS)
+        tables[b, :used] = np.arange(page, page + used)
+        page += used
+    return tables, page
+
+
 def check_decode(nh, n_kv, hd, pps, B) -> None:
     P = 1 + B * 6
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.bfloat16)
     k_pool = jnp.asarray(rng.standard_normal((P, PS, n_kv * hd)), jnp.bfloat16)
     v_pool = jnp.asarray(rng.standard_normal((P, PS, n_kv * hd)), jnp.bfloat16)
-    # Distinct pages per sequence, padding entries -> scrap page 0.
-    tables = np.zeros((B, pps), np.int32)
     ctx = rng.integers(2, 6 * PS, B).astype(np.int32)
     ctx[0] = 1  # empty-pool path: n_chunks == 0, no DMA ever starts
-    next_page = 1
-    for b in range(B):
-        for j in range(cdiv(int(ctx[b]) - 1, PS)):
-            tables[b, j] = next_page
-            next_page += 1
+    tables, next_page = _page_tables(ctx, pps)
     assert next_page <= P, f"pool too small: need {next_page} pages"
     tables, ctx = jnp.asarray(tables), jnp.asarray(ctx)
     k_cur = jnp.asarray(rng.standard_normal((B, n_kv, hd)), jnp.bfloat16)
@@ -81,6 +91,163 @@ def check_decode(nh, n_kv, hd, pps, B) -> None:
     err = _err(out, ref)
     print(f"paged_decode B={B} pps={pps}: max|pallas-xla| = {err:.4f}")
     assert err < TOL, err
+
+
+def cell_contexts(rng, B):
+    """Contexts of B decode rows met at a random instant of the
+    ``batch-decode`` cell's traffic (prompts uniform 64-256, outputs uniform
+    192-640, every seat taken): a seat holds a request for as long as its
+    output is, so the output length met is length-biased, and the row is
+    uniformly far through it. 160 + 228 = ~388 tokens a row."""
+    lens = np.arange(192, 641)
+    out_len = rng.choice(lens, size=B, p=lens / lens.sum())
+    done = np.floor(rng.random(B) * out_len)
+    return (rng.integers(64, 257, B) + done + 1).astype(np.int32)
+
+
+def stream_only_decode(k_pool, v_pool, tables, ctx, layer, chunk_pages,
+                       num_bufs):
+    """``paged_decode``'s page stream with nothing attended to (ISSUE 27's
+    E1): the same chunks, slots, DMA issues and look-ahead across sequences
+    as ``ops/pallas/paged_decode.py`` starts and waits for, and of what
+    lands only eight rows of the chunk's first page are touched. Its time is
+    what the page DMAs alone allow under one grid step a sequence; it has
+    neither the served kernel's prologue nor its epilogue. A copy kept here
+    so that the served kernel carries no instrument: if the stream there
+    changes, change it here."""
+    L, P, ps, kd = k_pool.shape
+    B, pps = tables.shape
+    C, NBUF = chunk_pages, num_bufs
+
+    def kernel(tables_ref, ctx_ref, layer_ref, offsets_ref, k_hbm, v_hbm,
+               out_ref, k_buf, v_buf, sems):
+        b = pl.program_id(0)
+        n_chunks = pl.cdiv(pl.cdiv(jnp.maximum(ctx_ref[b] - 1, 0), ps), C)
+
+        def copies(s, lc, slot):
+            out = []
+            for j in range(C):
+                idx = jnp.minimum(lc * C + j, pps - 1)
+                page = tables_ref[s * pps + idx]
+                for hbm, buf, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    out.append(pltpu.make_async_copy(
+                        hbm.at[layer_ref[0], page], buf.at[slot, j],
+                        sems.at[slot, which, j]))
+            return out
+
+        def start_global(gid):
+            @pl.when(gid < offsets_ref[B])
+            def _():
+                s = jax.lax.while_loop(
+                    lambda s: offsets_ref[s + 1] <= gid, lambda s: s + 1, b)
+                for cp in copies(s, gid - offsets_ref[s],
+                                 jax.lax.rem(gid, NBUF)):
+                    cp.start()
+
+        @pl.when(b == 0)
+        def _():
+            for d in range(NBUF - 1):
+                start_global(jnp.int32(d))
+
+        def body(c, acc):
+            gid = offsets_ref[b] + c
+            slot = jax.lax.rem(gid, NBUF)
+            start_global(gid + NBUF - 1)
+            for cp in copies(b, c, slot):
+                cp.wait()
+            return (acc + k_buf[slot, 0, :8].astype(jnp.float32)
+                    + v_buf[slot, 0, :8].astype(jnp.float32))
+
+        out_ref[0] = jax.lax.fori_loop(
+            0, n_chunks, body, jnp.zeros((8, kd), jnp.float32))
+
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(
+        jnp.ceil(jnp.maximum(ctx - 1, 0) / (C * ps)).astype(jnp.int32))])
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, 8, kd), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 8, kd), lambda b, *_: (b, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((NBUF, C, ps, kd), k_pool.dtype),
+                            pltpu.VMEM((NBUF, C, ps, kd), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((NBUF, 2, C))]),
+        name="paged_decode_stream_only",
+    )(tables.reshape(-1), ctx, jnp.asarray(layer, jnp.int32).reshape(1),
+      offsets, k_pool, v_pool)
+
+
+def time_decode(nh, n_kv, hd, pps, B, L) -> None:
+    """The decode kernel ALONE as a decode window runs it: a stacked L-layer
+    bf16 pool addressed by a dynamic layer index, contexts as the
+    ``batch-decode`` cell draws them, 20 calls chained in one program (each
+    call's output is the next one's query, the layer index walks the stack).
+    Printed against the bytes the call must bring in, in whole pages (what
+    the kernel reads) and in the tokens that exist; then the same stream
+    with no arithmetic (``stream_only_decode``), and the stream's depth and
+    the chunk's size either side of the served ones. The value check is
+    against a float32 reference of the same bf16 inputs."""
+    rng = np.random.default_rng(27)
+    kd = n_kv * hd
+    ctx = cell_contexts(rng, B)
+    tables, page = _page_tables(ctx, pps)
+    keys = jax.random.split(jax.random.key(27), 5)
+    k_pool = jax.random.normal(keys[0], (L, page, PS, kd), jnp.bfloat16)
+    v_pool = jax.random.normal(keys[1], (L, page, PS, kd), jnp.bfloat16)
+    q = jax.random.normal(keys[2], (B, nh, hd), jnp.bfloat16)
+    k_cur = jax.random.normal(keys[3], (B, n_kv, hd), jnp.bfloat16)
+    v_cur = jax.random.normal(keys[4], (B, n_kv, hd), jnp.bfloat16)
+    tables, ctx_d = jnp.asarray(tables), jnp.asarray(ctx)
+    scale = hd ** -0.5
+    n = 20
+    page_bytes = int(np.ceil((ctx - 1) / PS).sum()) * 2 * PS * kd * 2
+    token_bytes = int((ctx - 1).sum()) * 2 * kd * 2
+    print(f"paged_decode alone: B={B}, {nh}q/{n_kv}kv x {hd}, L={L}, "
+          f"{ctx.mean():.0f} tokens a row, {page_bytes / 1e6:.1f} MB of "
+          f"pages = {page_bytes / 819e3:.0f} us at 819 GB/s, "
+          f"{token_bytes / 1e6:.1f} MB of tokens that exist")
+
+    used = int(np.ceil((ctx.max() - 1) / PS))
+    f32 = jnp.float32
+    ref = jax.jit(lambda q, kp, vp, kc, vc: paged_decode_attention_xla(
+        q.astype(f32), kp[1].astype(f32), vp[1].astype(f32),
+        tables[:, :used], ctx_d, kc.astype(f32), vc.astype(f32), scale))(
+            q, k_pool, v_pool, k_cur, v_cur)
+    out = jax.jit(lambda q, kp, vp: pallas_paged_decode(
+        q, kp, vp, tables, ctx_d, k_cur, v_cur, scale, layer=1))(
+            q, k_pool, v_pool)
+    err = _err(out, ref)
+    print(f"paged_decode alone: max|pallas - xla(float32)| = {err:.4f}")
+    assert err < TOL, err
+
+    def us_a_call(call, carry):
+        def chain(carry, kp, vp):
+            return jax.lax.fori_loop(
+                0, n, lambda i, c: call(c, kp, vp, jax.lax.rem(i, L)), carry)
+        return _timed(jax.jit(chain), carry, k_pool, v_pool, n=10) / n * 1e6
+
+    def kernel(**kw):
+        return lambda q, kp, vp, layer: pallas_paged_decode(
+            q, kp, vp, tables, ctx_d, k_cur, v_cur, scale, layer=layer, **kw)
+
+    C = max(1, chunk_tokens(kd, 2) // PS)
+    other_C = max(1, (384 - chunk_tokens(kd, 2)) // PS)
+    timings = [(f"as served ({C * PS}-token chunks, {_NUM_BUFS} slots)",
+                kernel(), q),
+               ("stream only", lambda acc, kp, vp, layer: acc
+                + stream_only_decode(kp, vp, tables, ctx_d, layer, C,
+                                     _NUM_BUFS),
+                jnp.zeros((B, 8, kd), f32))]
+    timings += [(f"num_bufs={nb}", kernel(num_bufs=nb), q) for nb in (2, 3, 5)]
+    timings.append((f"chunk_pages={other_C}", kernel(chunk_pages=other_C), q))
+    for label, call, carry in timings:
+        us = us_a_call(call, carry)
+        print(f"paged_decode alone, {label}: {us:.1f} us a call = "
+              f"{page_bytes / us / 819e3 * 100:.1f} % of 819 GB/s in pages, "
+              f"{token_bytes / us / 819e3 * 100:.1f} % in tokens that exist")
 
 
 def check_prefill(nh, n_kv, hd, T) -> None:
@@ -235,12 +402,7 @@ def check_latent(cfg, pps, B, T) -> None:
     # decode: B rows, contexts as in the batch-decode-2k cell
     ctx = rng.integers(1024, 2688, B).astype(np.int32)
     ctx[0] = 1
-    tables = np.zeros((B, pps), np.int32)
-    page = 1
-    for b in range(B):
-        for j in range(cdiv(int(ctx[b]) - 1, PS)):
-            tables[b, j] = page
-            page += 1
+    tables, page = _page_tables(ctx, pps)
     pool = bf(2, page, PS, R)
     q, cur = bf(B, nh, R), bf(B, 1, R)
     tables, ctx_d = jnp.asarray(tables), jnp.asarray(ctx)
@@ -479,7 +641,8 @@ def main() -> None:
     print(f"{cfg.name} tp={args.tp}: {nh}q/{n_kv}kv x {hd}, kd={n_kv * hd}, "
           f"page {PS}, pages/seq {pps}, B={B}, T={T}")
     checks = {
-        "decode": lambda: check_decode(nh, n_kv, hd, pps, B),
+        "decode": lambda: (check_decode(nh, n_kv, hd, pps, B),
+                           time_decode(nh, n_kv, hd, pps, B, cfg.num_layers)),
         "prefill": lambda: check_prefill(nh, n_kv, hd, T),
         "hist": lambda: check_prefill_history(nh, n_kv, hd, pps, T),
         "kvwrite": lambda: [check_kv_write(cfg.num_layers, n_kv, hd, n)

@@ -384,7 +384,12 @@ def paged_decode_attention(q, k_cache_l, v_cache_l, page_tables, context_lens,
                            use_pallas=None):
     """``layer`` (with a stacked [L, P, ps, n_kv*hd] pool) lets the Pallas
     kernel address the pool with a dynamic layer index instead of the caller
-    slicing a per-layer copy out — the zero-copy path the decode scan uses."""
+    slicing a per-layer copy out — the zero-copy path the decode scan uses.
+
+    The K-and-V kernel takes no setting: its chunk's size comes from the
+    pool's lane width and dtype and its stream's depth is a constant
+    (``ops.pallas.paged_decode``: what was measured, and at which
+    geometry)."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas and v_cache_l is None:
@@ -531,7 +536,9 @@ def paged_decode_attention_tp(mesh, q, k_cache_l, v_cache_l, page_tables,
                               layer=None, interpret=False):
     """shard_map-wrapped pallas_paged_decode over ``mesh``'s tp axis.
     Shapes/semantics match paged_decode_attention; ``interpret=True`` runs
-    the kernel in interpret mode (CPU-mesh parity tests)."""
+    the kernel in interpret mode (CPU-mesh parity tests). Each shard's
+    kernel sizes its chunks from ITS lane width (a quarter of the pool's
+    under tp=4: 256 tokens a chunk where one chip takes 128)."""
     from jax.sharding import PartitionSpec as P
 
     from .pallas.paged_decode import pallas_paged_decode

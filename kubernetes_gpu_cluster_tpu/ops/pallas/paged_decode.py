@@ -2,18 +2,43 @@
 
 One grid step per sequence: stream that sequence's valid KV pages HBM->VMEM
 in CHUNKS of ``chunk_pages`` pages — all pages of a chunk DMA concurrently,
-chunks double-buffer against compute — and accumulate flash-style online
-softmax in fp32 over one matmul per chunk.
+chunks of the whole batch form one stream that runs ``_NUM_BUFS - 1`` chunks
+ahead of compute — and accumulate flash-style online softmax in fp32 over one
+matmul pair per chunk.
 
 The per-chunk matmul uses a BLOCK-DIAGONAL query layout: q [nh, hd] is
 embedded into Qbd [nh, n_kv*hd] with head h's vector placed in its kv-head's
 block, so scores for ALL kv heads come out of a single
 [nh, n_kv*hd] x [n_kv*hd, C*ps] contraction (the off-block products are zero
 by construction). The P@V matmul runs full-width and the output's diagonal
-blocks are extracted at the end. This wastes n_kv x FLOPs — irrelevant, the
-kernel is DMA-bound — and replaces the per-(page, kv-head) tiny-matmul
-structure that made round 1's kernel latency-bound (VERDICT weak #3: grid
-``(B,)`` with [g, hd] matmuls per page).
+blocks are extracted at the end. This wastes n_kv x FLOPs (hidden under the
+page stream where the pool is wide: below) and replaces the
+per-(page, kv-head) tiny-matmul structure that made round 1's kernel
+latency-bound (VERDICT weak #3: grid ``(B,)`` with [g, hd] matmuls per page).
+
+What bounds it, measured (2026-09-28, one v5e, the kernel alone: 64 rows of
+32 q / 8 kv heads x 128, 128-token pages, a 36-layer bf16 pool under a
+dynamic layer index, ~400 tokens a row as the ``batch-decode`` cell draws
+them, 124 MB of pages = 151 us at 819 GB/s; PERF.md section 6, PR 27): the
+STREAM's depth, not the arithmetic. The page DMAs alone, nothing attended
+to, take 169 us a call (the ceiling of one grid step a sequence: 89 % of
+819 GB/s in whole pages); this arithmetic alone (float32 operands, selector
+matmuls), every buffer resident, 131. One chunk ahead, as the kernel ran
+until then, the two did not overlap: 211-218 us, because one 512 KiB chunk
+in flight (0.64 us of bus) does not cover a sequence's turnover (epilogue,
+grid step, prologue, a selector matmul in each) and the bus idled once a
+sequence. Two ahead 173, three ahead 171: the kernel then sits on its
+stream's ceiling, 88 % of 819 GB/s in whole pages, 75 % in the tokens that
+exist (the last page's round-up). A rewrite of the arithmetic (bf16 MXU
+operands, embed and extract without the MXU) read 171-172 at those depths
+and was not kept. Three ahead was never behind two at seven geometries and
+ahead of it where slots are small (a tp=4 shard at 1-2.7 k-token rows 192
+against 212 us, opt-125m 111 against 119), four ahead never ahead of three:
+hence the constant ``_NUM_BUFS``; no setting chooses it. Narrow pools are
+bound by a chunk's fixed work instead (a tp=4 shard of 8 q / 2 kv heads:
+stream alone 52 us, kernel 104 at 128 tokens a chunk, 70-73 at 256), wide
+ones lose to the round-up to whole chunks at 256 (194 against 171 us):
+hence ``_CHUNK_BYTES``.
 
 Mosaic constraint (round-2 failure): lane-splitting/merging shape casts like
 ``[nh, n_kv, hd] -> [nh, n_kv*hd]`` are unsupported on TPU ("infer-vector-
@@ -40,6 +65,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+# Slots of the chunk stream: one computed on, three in flight ahead of it
+# (module docstring: one ahead left the bus idle once a sequence, two ahead
+# still did where slots are small, more than three gained nothing at any
+# geometry timed).
+_NUM_BUFS = 4
+# A chunk's K and V together: 128 tokens of a 1024-lane bf16 pool. A pool
+# narrow enough to fit 256 tokens in as many bytes takes 256 (module
+# docstring).
+_CHUNK_BYTES = 512 << 10
+_VMEM_BUDGET = 8 << 20
+
+
+def chunk_tokens(kd: int, itemsize: int) -> int:
+    """Tokens a streamed chunk spans, from the pool's lane width and dtype."""
+    return 256 if 2 * 256 * kd * itemsize <= _CHUNK_BYTES else 128
 
 
 def _decode_kernel(
@@ -84,11 +126,10 @@ def _decode_kernel(
 
     # Chunks form ONE GLOBAL STREAM across the whole batch (gid in
     # [0, offsets[B])), prefetched NBUF-1 ahead with slots keyed by gid —
-    # so a sequence's first page DMA is issued during the PREVIOUS
-    # sequence's compute instead of stalling its own grid step (the
-    # measured bottleneck: at 128-token pages most sequences are 1-2
-    # chunks, so per-sequence warmup exposed a full DMA latency per grid
-    # step; cross-sequence lookahead hides it).
+    # so a sequence's first pages are in flight during the PREVIOUS
+    # sequences' turns instead of stalling its own grid step (at 128-token
+    # pages most sequences are 1-7 chunks, so a per-sequence warmup would
+    # expose a full DMA latency per grid step).
 
     def _start(s, lc, slot):
         # DMA all C pages of sequence s's chunk lc. Pages past that
@@ -233,13 +274,14 @@ def pallas_paged_decode(q, k_pool, v_pool, page_tables, context_lens,
     pps = page_tables.shape[1]
     g = nh // n_kv
     if chunk_pages is None:
-        # Target ~128 tokens per streamed chunk regardless of page size: the
+        # 128-256 tokens per streamed chunk regardless of page size: the
         # kernel reads whole chunks (tail pages masked), so the chunk span
         # sets the over-read granularity, while the PAGE count per chunk sets
-        # the DMA-issue count — the measured bottleneck (~45 ns/issue on the
-        # sparse core). Big pages with one page per chunk move the same bytes
-        # with 8x fewer issues than 16-token pages.
-        chunk_pages = max(1, 128 // ps)
+        # the DMA-issue count (~45 ns/issue on the sparse core). Big pages
+        # with one page per chunk move the same bytes with 8x fewer issues
+        # than 16-token pages.
+        chunk_pages = max(
+            1, chunk_tokens(n_kv * hd, k_pool.dtype.itemsize) // ps)
     C = max(1, min(chunk_pages, pps))
     # Flatten current-token heads on the host (free in XLA); inside the kernel
     # a [n_kv, hd] -> [1, n_kv*hd] cast would be a Mosaic-unsupported
@@ -250,19 +292,14 @@ def pallas_paged_decode(q, k_pool, v_pool, page_tables, context_lens,
     # Prefetch depth: NBUF slots keep up to NBUF-1 chunks of the GLOBAL
     # cross-sequence stream in flight ahead of compute (do NOT clamp to one
     # sequence's chunk count — the lookahead deliberately crosses sequence
-    # boundaries). num_bufs=1 is the serial baseline; KGCT_DECODE_NBUF
-    # overrides for A/B (bench-measured: 2 best; 4/8 slower — each slot
-    # costs 2*C*ps*n_kv*hd bytes of VMEM, capped below so an env override
-    # fails loudly here rather than as an opaque Mosaic error).
-    if num_bufs is None:
-        import os
-        num_bufs = int(os.environ.get("KGCT_DECODE_NBUF", "2"))
-    NBUF = max(1, int(num_bufs))
+    # boundaries). num_bufs=1 is the serial baseline; tests and
+    # benchmarks/tpu_kernel_check.py pass it to compare.
+    NBUF = _NUM_BUFS if num_bufs is None else max(1, int(num_bufs))
     slot_bytes = 2 * C * ps * n_kv * hd * k_pool.dtype.itemsize
-    if NBUF * slot_bytes > 8 * 1024 * 1024:
+    if NBUF * slot_bytes > _VMEM_BUDGET:
         raise ValueError(
-            f"num_bufs={NBUF} needs {NBUF * slot_bytes} bytes of VMEM "
-            f"scratch (> 8 MiB budget); lower KGCT_DECODE_NBUF")
+            f"num_bufs={NBUF} slots of {slot_bytes} bytes need more than the "
+            f"{_VMEM_BUDGET}-byte VMEM scratch budget")
     # Global chunk stream: cumulative per-sequence chunk counts, so the
     # kernel prefetches ACROSS sequence boundaries (gid -> (seq, chunk)).
     n_chunks_per_seq = jnp.ceil(

@@ -62,6 +62,126 @@ class TestPagedDecodeKernel:
                                        rtol=2e-5, atol=2e-5)
 
 
+def _bf16_case(nh, nkv, hd, seed, L=3, ps=128, q_gain=1.0):
+    """A stacked bf16 pool and one batch holding every context kind: an
+    empty pool, exactly one page, one token past a page, several chunks,
+    and a padding row. q is float32 and holds no bf16 numbers: the output
+    is float32, so the comparison sees past a bf16 output's rounding, and a
+    kernel that rounded q to the pool's dtype would show."""
+    ctx = np.asarray([1, ps + 1, ps + 2, 3 * ps + 5, 0], np.int32)
+    B, pps = len(ctx), 5
+    tables = np.zeros((B, pps), np.int32)
+    page = 1
+    for b in range(B):
+        for j in range(-(-max(int(ctx[b]) - 1, 0) // ps)):
+            tables[b, j] = page
+            page += 1
+    rng = np.random.default_rng(seed)
+
+    def bf(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    kd = nkv * hd
+    return dict(q=jnp.asarray(q_gain * rng.standard_normal((B, nh, hd)),
+                              jnp.float32),
+                k_pool=bf(L, page, ps, kd), v_pool=bf(L, page, ps, kd),
+                tables=jnp.asarray(tables), ctx=jnp.asarray(ctx),
+                k_cur=bf(B, nkv, hd), v_cur=bf(B, nkv, hd))
+
+
+def _f32_reference(c, scale, layer, q=None):
+    f32 = jnp.float32
+    return np.asarray(paged_decode_attention_xla(
+        c["q"] if q is None else q, c["k_pool"][layer].astype(f32),
+        c["v_pool"][layer].astype(f32), c["tables"], c["ctx"],
+        c["k_cur"].astype(f32), c["v_cur"].astype(f32), scale))
+
+
+def _pallas(c, scale, layer, **kw):
+    return np.asarray(pallas_paged_decode(
+        c["q"], c["k_pool"], c["v_pool"], c["tables"], c["ctx"], c["k_cur"],
+        c["v_cur"], scale, layer=layer, interpret=True, **kw))
+
+
+class TestPagedDecodeBf16Pool:
+    """The served dtype: a bf16 pool under the float32 arithmetic the kernel
+    ships with, against a float32 reference of the same bf16 inputs. The
+    tolerance is float32's, as for the float32 pools above: a kernel that
+    handed the MXU bf16 probabilities would miss it by two orders (2^-9
+    against values up to ~4.5), one that split them into two bf16 terms
+    (2^-17) by a hair."""
+
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    @pytest.mark.parametrize("nh,nkv,hd", [
+        (8, 2, 128),    # g = 4 (qwen3-4b's shard under tp=4)
+        (7, 1, 128),    # g = 7 (qwen2.5-7b's)
+        (4, 4, 128),    # g = 1
+        (8, 2, 64),     # head_dim 64 (OPT, TinyLlama): half-tile blocks
+        (4, 4, 64),
+    ])
+    def test_matches_float32_reference(self, nh, nkv, hd, layer):
+        c = _bf16_case(nh, nkv, hd, seed=10 * nh + layer)
+        scale = hd ** -0.5
+        ref = _f32_reference(c, scale, layer)
+        got = _pallas(c, scale, layer)
+        assert got.dtype == np.float32
+        # The padding row (ctx = 0) is garbage in both paths.
+        np.testing.assert_allclose(got[:4], ref[:4], rtol=2e-5, atol=2e-5)
+
+    def test_score_product_does_not_round_q(self):
+        """Scores of magnitude ~25 and a scale that is no power of two: a
+        kernel that scaled q and THEN rounded it to the pool's dtype would
+        move logits by ~0.05 and the output far past the tolerance."""
+        nh, nkv, hd, scale, layer = 8, 2, 128, 0.3, 1
+        c = _bf16_case(nh, nkv, hd, seed=5, q_gain=8.0)
+        ref = _f32_reference(c, scale, layer)
+        rounded_q = ((c["q"] * scale).astype(jnp.bfloat16)
+                     .astype(jnp.float32) / scale)
+        assert np.abs(_f32_reference(c, scale, layer, q=rounded_q)[:4]
+                      - ref[:4]).max() > 50 * 2e-5
+        np.testing.assert_allclose(_pallas(c, scale, layer)[:4], ref[:4],
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_bf16_query_as_served(self):
+        """q in bf16 as the model hands it over: a bf16 output, within its
+        own rounding of the reference."""
+        c = _bf16_case(8, 2, 128, seed=6)
+        c["q"] = c["q"].astype(jnp.bfloat16)
+        got = _pallas(c, 0.125, 2)
+        assert got.dtype == jnp.bfloat16
+        ref = _f32_reference(c, 0.125, 2, q=c["q"].astype(jnp.float32))
+        np.testing.assert_allclose(got[:4].astype(np.float32), ref[:4],
+                                   rtol=2.0 ** -8, atol=2.0 ** -8)
+
+    def test_output_does_not_depend_on_stream_depth(self):
+        from kubernetes_gpu_cluster_tpu.ops.pallas import paged_decode
+        c = _bf16_case(8, 2, 128, seed=7)
+        assert paged_decode._NUM_BUFS not in (1, 2)
+        want = _pallas(c, 0.125, 1)
+        for nb in (1, 2, paged_decode._NUM_BUFS):
+            np.testing.assert_array_equal(_pallas(c, 0.125, 1, num_bufs=nb),
+                                          want)
+        with pytest.raises(ValueError, match="VMEM"):
+            _pallas(c, 0.125, 1, num_bufs=64)
+
+    def test_kernel_reads_no_setting_from_the_environment(self, monkeypatch):
+        """The stream's depth and the chunk's size are the module's own: a
+        call looks up none of this package's variables (the parent read the
+        depth from one on every call)."""
+        import os
+        looked_up = []
+        getitem = os._Environ.__getitem__
+
+        def recording(env, key):
+            looked_up.append(key)
+            return getitem(env, key)
+        monkeypatch.setattr(os._Environ, "__getitem__", recording)
+        os.environ.get("KGCT_PROBE")          # the recorder sees a lookup
+        c = _bf16_case(4, 4, 128, seed=8)
+        _pallas(c, 0.125, 0)
+        assert [k for k in looked_up if k.startswith("KGCT_")] == [
+            "KGCT_PROBE"]
+
+
 class TestFlashPrefillKernel:
     @pytest.mark.parametrize("T,block", [(64, 16), (128, 128)])
     def test_matches_xla(self, T, block):
