@@ -37,7 +37,7 @@ from kubernetes_gpu_cluster_tpu.config import (SchedulerConfig,
                                                get_model_config)
 from kubernetes_gpu_cluster_tpu.ops.attention import (
     paged_decode_attention_xla, prefill_history_attention_xla,
-    ragged_prefill_attention_xla, write_kv_pages_all)
+    ragged_prefill_attention_xla, write_kv_pages_all_xla)
 from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill import flash_ragged_prefill
 from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill_hist import (
     flash_prefill_history)
@@ -332,10 +332,9 @@ def check_kv_write(L, n_kv, hd, T) -> None:
     pools = {}
     n = 20      # flushes chained in ONE program: a sub-millisecond program's
                 # host-clock time is its dispatch, not its device time
-    for name, use_pallas in (("loop", False), ("kernel", True)):
-        def write(kk, vv, ka, va, sl, up=use_pallas):
-            return write_kv_pages_all(kk, vv, ka, va, sl, use_pallas=up)
-
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kv_write import kv_write
+    for name, write in (("loop", write_kv_pages_all_xla),
+                        ("kernel", kv_write)):
         def chain(kk, vv, ka, va, sl):
             return jax.lax.fori_loop(
                 0, n, lambda _, kv: write(*kv, ka, va, sl), (kk, vv))
@@ -462,8 +461,8 @@ def check_latent(cfg, pps, B, T) -> None:
         wpool = bf(L, 1 + cdiv(n, PS) + 2, PS, R)
         new = bf(L, n, R)
         slots = jnp.asarray(PS + np.arange(n), jnp.int32)
-        want, _ = jax.jit(lambda p, r, s: write_kv_pages_all(
-            p, None, r, None, s, use_pallas=False))(wpool, new, slots)
+        want, _ = jax.jit(lambda p, r, s: write_kv_pages_all_xla(
+            p, None, r, None, s))(wpool, new, slots)
         got, _ = jax.jit(lambda p, r, s: kv_write(p, None, r, None, s))(
             wpool, new, slots)
         same = bool(jnp.array_equal(want, got))
@@ -531,7 +530,7 @@ def check_experts(cfg) -> None:
 def check_decode_program(cfg, rows=(8, 16, 32, 64, 128),
                          ctx=(1024, 2688)) -> None:
     """The expert dispatches IN the decode program: the whole
-    ``forward_decode`` (the layer stack over the model's real weights,
+    decode ``forward`` (the layer stack over the model's real weights,
     latent or paged attention against a pool at the cell's contexts, the
     page write; no head), once with dense and once with grouped dispatch,
     at decode row counts. A kernel alone says little here: the step waits
@@ -542,6 +541,7 @@ def check_decode_program(cfg, rows=(8, 16, 32, 64, 128),
     from kubernetes_gpu_cluster_tpu.config.engine_config import CacheConfig
     from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
     from kubernetes_gpu_cluster_tpu.models import llama
+    from kubernetes_gpu_cluster_tpu.ops.attention import Kernels
     t0 = time.perf_counter()
     params = jax.block_until_ready(llama.init_params(cfg, jax.random.key(0)))
     print(f"decode program: {cfg.num_layers} layers of {cfg.name} built in "
@@ -556,7 +556,7 @@ def check_decode_program(cfg, rows=(8, 16, 32, 64, 128),
         n_pages = min(B * pps, 64 * pps)
         tables = (np.arange(B * pps, dtype=np.int32) % n_pages).reshape(
             B, pps)
-        meta = llama.DecodeMeta(
+        meta = llama.StepMeta(
             positions=jnp.asarray(lens - 1, jnp.int32),
             slot_mapping=jnp.asarray(
                 tables[np.arange(B), (lens - 1) // PS] * PS + (lens - 1) % PS,
@@ -572,8 +572,9 @@ def check_decode_program(cfg, rows=(8, 16, 32, 64, 128),
             # not gets dense dispatch at every size.
             llama.DENSE_DISPATCH_MAX_TOKENS = 0 if grouped else switch
             step = jax.jit(
-                lambda p, t, m, kv, g=grouped: llama.forward_decode(
-                    p, cfg, t, m, kv, grouped_experts=g)[:2],
+                lambda p, t, m, kv, g=grouped: llama.forward(
+                    p, cfg, t, m, kv,
+                    Kernels(use_pallas=True, grouped_experts=g))[:2],
                 donate_argnums=3)
             kv = allocate_kv_cache(cfg, CacheConfig(page_size=PS), n_pages)
             h, kv = step(params, tokens, meta, kv)

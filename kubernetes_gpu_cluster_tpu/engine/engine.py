@@ -32,7 +32,8 @@ from ..analysis.sanitize import build_step_sanitizer
 from ..config import EngineConfig, latent_model_refusal
 from ..models import llama as model_lib
 from ..observability import Observability
-from ..models.llama import DecodeMeta, MixedMeta, PrefillMeta, SpecMeta
+from ..models.llama import StepMeta
+from ..ops.attention import Kernels
 from ..ops.sampling import (apply_logit_bias, apply_penalties, build_counts,
                             bump_counts, gated_top_logprobs, row_sample_keys,
                             sample_and_logprobs, spec_verify_sample,
@@ -168,7 +169,7 @@ class LLMEngine:
                 raise ValueError(
                     f"prefill buckets {bad} not divisible by sp={self.sp_size}"
                     " (ring attention shards the token axis)")
-        self.use_pallas = self._resolve_use_pallas(use_pallas)
+        self.kernels = self._resolve_use_pallas(use_pallas)
         self._key = jax.random.key(config.seed)
 
         params_sharding, kv_sharding = resolve_shardings(mesh, config.model)
@@ -280,7 +281,7 @@ class LLMEngine:
         # Speculative decoding: pure-decode steps become batched draft
         # verification (engine/spec/). Single-mesh and GSPMD-tp regimes
         # only, like the mixed path — under pp the layer stack is sharded
-        # outside forward_spec_verify and under sp ring attention replaces
+        # outside the flat forward and under sp ring attention replaces
         # the paged layout it splits on.
         if self.scheduler.spec_enabled and (self.pp_size > 1
                                             or self.sp_size > 1):
@@ -395,8 +396,8 @@ class LLMEngine:
             "mesh": dict(self.mesh.shape) if self.mesh is not None else None,
             "dtype": self.model_config.dtype,
             "quantization": self.model_config.quantization,
-            "use_pallas": self.use_pallas,
-            "use_pallas_hist": self.use_pallas_hist,
+            "use_pallas": self.kernels.use_pallas,
+            "use_pallas_hist": self.kernels.use_pallas_hist,
             "num_pages": self.scheduler.allocator.num_pages,
             "page_size": self.config.cache.page_size,
             # The bytes a cached token really holds (all layers, padding of
@@ -458,7 +459,7 @@ class LLMEngine:
         the same discipline every step program follows (KGCT004)."""
         self.kv_cache = kv
 
-    def _resolve_use_pallas(self, use_pallas: Optional[bool]) -> bool:
+    def _resolve_use_pallas(self, use_pallas: Optional[bool]) -> Kernels:
         """Decide the kernel path ONCE, at init, from static facts — backend,
         mesh sharding, lane alignment — and PROVE it: on a TPU every kernel
         the engine is eligible to use is compiled here at the geometry it
@@ -470,38 +471,57 @@ class LLMEngine:
         their reason is kept in ``pallas_disabled_reason`` and reported on
         /health.
 
-        The history-prefill kernel has its OWN flag (use_pallas_hist): it
-        is ineligible under pp/sp meshes, where chunked prefill keeps the
-        XLA path while decode keeps its kernel."""
-        self.use_pallas_hist = False
+        The result is the ONE value (``ops.attention.Kernels``) every step
+        program hands to the forward pass; nothing below decides again. It
+        also says where the kernels run per shard (a GSPMD mesh; under pp
+        they already run inside the pipeline's own shard_map), what replaces
+        fresh-prompt attention under sp, and whether the experts lie whole
+        on one device."""
         self.pallas_disabled_reason: Optional[str] = None
-        if use_pallas is not None:
-            self.use_pallas_hist = use_pallas and self._hist_kernel_eligible()
-            return use_pallas
-        if jax.default_backend() != "tpu":
-            return False
         cfg = self.model_config
-        tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
-        lane = cfg.kv_row_padded // tp
-        if cfg.num_heads % tp or cfg.num_kv_heads % tp:
-            self.pallas_disabled_reason = (
-                f"heads ({cfg.num_heads} q / {cfg.num_kv_heads} kv) not "
-                f"divisible by tp={tp}")
-        elif lane % 128:
-            self.pallas_disabled_reason = (
-                f"per-shard KV lane dim {lane} (n_kv*hd/tp) is not "
-                "128-aligned")
-        if self.pallas_disabled_reason is not None:
-            logger.warning("Pallas kernels disabled: %s; using XLA attention",
-                           self.pallas_disabled_reason)
-            return False
-        # Under a mesh the kernels run per-shard inside shard_map — the tp
-        # wrappers (ops.attention.*_tp) for GSPMD serving, or the pipeline's
-        # own shard_map body for pp>1 — so the probes compile the kernels at
-        # the PER-SHARD head geometry each device will actually build.
-        self._probe_pallas_compile(tp)
-        self.use_pallas_hist = self._hist_kernel_eligible()
-        return True
+        if use_pallas is None and jax.default_backend() == "tpu":
+            tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
+            lane = cfg.kv_row_padded // tp
+            if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+                self.pallas_disabled_reason = (
+                    f"heads ({cfg.num_heads} q / {cfg.num_kv_heads} kv) not "
+                    f"divisible by tp={tp}")
+            elif lane % 128:
+                self.pallas_disabled_reason = (
+                    f"per-shard KV lane dim {lane} (n_kv*hd/tp) is not "
+                    "128-aligned")
+            use_pallas = self.pallas_disabled_reason is None
+            if use_pallas:
+                # Under a mesh the kernels run per-shard inside shard_map —
+                # the tp wrappers (ops.attention.*_tp) for GSPMD serving, or
+                # the pipeline's own shard_map body for pp>1 — so the probes
+                # compile the kernels at the PER-SHARD head geometry each
+                # device will actually build.
+                self._probe_pallas_compile(tp)
+            else:
+                logger.warning(
+                    "Pallas kernels disabled: %s; using XLA attention",
+                    self.pallas_disabled_reason)
+        use_pallas = bool(use_pallas)   # None: not asked for, not on a TPU
+        ring = None
+        if self.sp_size > 1:
+            # Ring attention over the sp axis (parallel/sp.py): each device
+            # holds T/sp tokens and K/V blocks rotate by ppermute. Heads
+            # stay replicated inside the ring body — sp is the long-context
+            # axis, tp the weight axis; they compose at the GSPMD level
+            # (matmuls), not inside attention. The mesh stays for the
+            # post-scan KV write kernel.
+            from ..parallel.sp import build_ring_prefill
+            ring = build_ring_prefill(
+                self.mesh, cfg.num_kv_heads,
+                cfg.num_heads // cfg.num_kv_heads, cfg.head_dim ** -0.5)
+        gspmd = self.mesh is not None and self.pp_size == 1
+        return Kernels(
+            use_pallas=use_pallas,
+            use_pallas_hist=use_pallas and self._hist_kernel_eligible(),
+            tp_mesh=self.mesh if use_pallas and gspmd else None,
+            ring_prefill=ring,
+            grouped_experts=self._grouped_experts)
 
     def _hist_kernel_eligible(self) -> bool:
         """Where the Pallas history-prefill kernel can serve: meshless
@@ -641,7 +661,7 @@ class LLMEngine:
     @property
     def _grouped_experts(self) -> bool:
         """Whether the step programs may run their experts by grouped
-        dispatch (``models.llama._moe_mlp``'s ``grouped``): an expert model
+        dispatch (``Kernels.grouped_experts``): an expert model
         whose expert tensors lie whole on ONE device and are not quantized.
         Under any mesh (GSPMD shards them P(None, 'ep', None, 'tp'); the pp
         shard_map slices them) the grouped kernel, a custom call with no
@@ -660,14 +680,6 @@ class LLMEngine:
         carry the count)."""
         return self.model_config.is_moe and self.mesh is None
 
-    def _gspmd_attn_mesh(self):
-        """The mesh to run Pallas attention under (shard_map tp wrappers) in
-        GSPMD serving — None when the engine resolved to XLA attention or the
-        forward already runs inside the pipeline's shard_map."""
-        if self.mesh is not None and self.pp_size == 1 and self.use_pallas:
-            return self.mesh
-        return None
-
     # -- jitted step programs ----------------------------------------------
 
     def _maybe_jit(self, fn, donate_argnums=()):
@@ -682,21 +694,19 @@ class LLMEngine:
         """Inputs arrive as TWO packed buffers (one int, one float) — each
         host->device upload is a round trip on remote-attached TPUs, so the
         step interface is packed tight: int_t [4, T] (tokens, seg_ids,
-        positions, slot_mapping), int_b [B, 4] (logits_indices, top_k, seed,
-        prompt_len), float_b [B, 4] (temperature, top_p, presence,
+        positions, slot_mapping), int_b [B, 5] (logits_indices, top_k, seed,
+        prompt_len, top_n), float_b [B, 4] (temperature, top_p, presence,
         frequency).
 
         Under a pp mesh the same interface runs the circular pipeline of
         parallel/pp.py instead of the flat forward — the scheduler/step loop
         is oblivious to pp."""
         cfg = self.model_config
-        use_pallas = self.use_pallas
-        grouped = self._grouped_experts
+        kernels = self.kernels
 
         if self.pp_size > 1:
             from ..parallel.pp import build_pp_mapped, pp_logits
-            mapped = build_pp_mapped(self.mesh, cfg, "prefill",
-                                     use_pallas=use_pallas)
+            mapped = build_pp_mapped(self.mesh, cfg, "prefill", kernels)
 
             def fwd(params, kv, int_t, logits_indices, moe_load=None):
                 # The whole ragged prefill batch rides the pipeline as ONE
@@ -705,7 +715,7 @@ class LLMEngine:
                 # sequence straddle microbatches, breaking in-batch
                 # attention. S-1 bubble ticks per prefill is the cost;
                 # decode — the steady state — microbatches properly.
-                meta_mb = PrefillMeta(
+                meta_mb = StepMeta(
                     seg_ids=int_t[1][None], positions=int_t[2][None],
                     slot_mapping=int_t[3][None],
                     logits_indices=logits_indices[None])
@@ -714,31 +724,15 @@ class LLMEngine:
                 return (pp_logits(params, cfg, hidden_mb[0], logits_indices),
                         KVCache(k=kvk, v=kvv))
         else:
-            attn_mesh = self._gspmd_attn_mesh()
-            attn_impl = None
-            if self.sp_size > 1:
-                # Ring attention over the sp axis (parallel/sp.py): each
-                # device holds T/sp tokens and K/V blocks rotate by ppermute.
-                # Heads stay replicated inside the ring body — sp is the
-                # long-context axis, tp the weight axis; they compose at the
-                # GSPMD level (matmuls), not inside attention.
-                from ..parallel.sp import build_ring_prefill
-                attn_impl = build_ring_prefill(
-                    self.mesh, cfg.num_kv_heads,
-                    cfg.num_heads // cfg.num_kv_heads, cfg.head_dim ** -0.5)
-                # attn_impl replaces the attention attn_mesh would shard;
-                # the mesh stays for the post-scan KV write kernel.
-
             def fwd(params, kv, int_t, logits_indices, moe_load=None):
-                meta = PrefillMeta(seg_ids=int_t[1], positions=int_t[2],
-                                   slot_mapping=int_t[3],
-                                   logits_indices=logits_indices)
-                hidden, kv, _ = model_lib.forward_prefill(
-                    params, cfg, int_t[0], meta, kv, use_pallas=use_pallas,
-                    attn_mesh=attn_mesh, attn_impl=attn_impl,
-                    moe_load=moe_load, grouped_experts=grouped)
+                meta = StepMeta(seg_ids=int_t[1], positions=int_t[2],
+                                slot_mapping=int_t[3],
+                                logits_indices=logits_indices)
+                hidden, kv, _ = model_lib.forward(
+                    params, cfg, int_t[0], meta, kv, kernels,
+                    moe_load=moe_load)
                 return model_lib.compute_logits(params, cfg, hidden,
-                                                 use_pallas=use_pallas), kv
+                                                 kernels), kv
 
         reports_load = self._reports_expert_load
 
@@ -762,37 +756,36 @@ class LLMEngine:
 
     def _build_prefill_hist_fn(self):
         """Chunked-prefill step: one sequence's chunk attending to its pool
-        history (models.forward_prefill_hist). Extra inputs vs prefill:
-        page_table [1, pages_bucket] and hist_len scalar. Compiled lazily —
-        engines that never see a long prompt never pay for it. Gated by its
-        own per-kernel flag (use_pallas_hist); GSPMD meshes route the kernel
-        through the tp shard_map wrapper
-        (ops.attention.prefill_history_attention_tp). pp meshes run the
-        PIPELINED history path (parallel/pp._build_pp_hist_mapped): the
-        chunk is microbatched into sub-chunks with per-sub-chunk history
-        lengths, keeping the layer stack sharded — no all-gather of the
-        pp-sharded params (VERDICT r4 #6; previously this ran as plain GSPMD
-        and XLA gathered the whole stack per chunk)."""
+        history (``StepMeta.chunk_page_table``/``hist_len``). Extra inputs
+        vs prefill: page_table [1, pages_bucket] and hist_len scalar.
+        Compiled lazily — engines that never see a long prompt never pay for
+        it. pp meshes run the PIPELINED history path
+        (parallel/pp._build_pp_hist_mapped): the chunk is microbatched into
+        sub-chunks with per-sub-chunk history lengths, keeping the layer
+        stack sharded — no all-gather of the pp-sharded params (VERDICT r4
+        #6; previously this ran as plain GSPMD and XLA gathered the whole
+        stack per chunk)."""
         cfg = self.model_config
-        use_pallas = self.use_pallas_hist
-        grouped = self._grouped_experts
-        # use_pallas_hist already encodes kernel eligibility (pp/sp
-        # exclusions, probe result); the helper adds the mesh/pp gating the
-        # other builders share.
-        attn_mesh = self._gspmd_attn_mesh() if use_pallas else None
+        kernels = self.kernels
+        if not kernels.use_pallas_hist:
+            # Where the history kernel is ineligible (sp meshes, and the
+            # pipeline's chunked path below) the WHOLE chunked program runs
+            # the XLA references, page write included. No chip has run
+            # either regime, so whether their write may take the kernel is
+            # open (ROADMAP D5g).
+            kernels = kernels.xla_only()
 
         if self.pp_size > 1:
             from ..parallel.pp import build_pp_mapped, pp_logits
             S = self.pp_size
-            mapped = build_pp_mapped(self.mesh, cfg, "prefill_hist",
-                                     use_pallas=False)
+            mapped = build_pp_mapped(self.mesh, cfg, "prefill_hist", kernels)
 
             def hist_fwd(params, kv, int_t, int_b, page_table, hist_len,
                          moe_load=None):
                 T = int_t.shape[1]
                 M = S if T % S == 0 else 1
                 sub = T // M
-                meta_mb = PrefillMeta(
+                meta_mb = StepMeta(
                     seg_ids=int_t[1].reshape(M, sub),
                     positions=int_t[2].reshape(M, sub),
                     slot_mapping=int_t[3].reshape(M, sub),
@@ -808,16 +801,16 @@ class LLMEngine:
         else:
             def hist_fwd(params, kv, int_t, int_b, page_table, hist_len,
                          moe_load=None):
-                meta = PrefillMeta(seg_ids=int_t[1], positions=int_t[2],
-                                   slot_mapping=int_t[3],
-                                   logits_indices=int_b[:, 0])
-                hidden, kv, _ = model_lib.forward_prefill_hist(
-                    params, cfg, int_t[0], meta, kv, page_table[0], hist_len,
-                    use_pallas=use_pallas and attn_mesh is None,
-                    attn_mesh=attn_mesh, moe_load=moe_load,
-                    grouped_experts=grouped)
+                meta = StepMeta(seg_ids=int_t[1], positions=int_t[2],
+                                slot_mapping=int_t[3],
+                                logits_indices=int_b[:, 0],
+                                chunk_page_table=page_table[0],
+                                hist_len=hist_len)
+                hidden, kv, _ = model_lib.forward(
+                    params, cfg, int_t[0], meta, kv, kernels,
+                    moe_load=moe_load)
                 return model_lib.compute_logits(params, cfg, hidden,
-                                                 use_pallas=use_pallas), kv
+                                                 kernels), kv
 
         reports_load = self._reports_expert_load
 
@@ -851,7 +844,7 @@ class LLMEngine:
         return self._maybe_jit(prefill_hist_step, donate_argnums=(1,))
 
     def _build_mixed_fn(self):
-        """Mixed prefill/decode step (models.forward_mixed): ONE program
+        """Mixed prefill/decode step (stall-free batching): ONE program
         runs a budgeted chunk of the queue-head prompt AND every running
         sequence's decode token. Compiled per (prefill bucket, row bucket,
         history width) — the same bounded bucket grid as the pure paths
@@ -862,10 +855,7 @@ class LLMEngine:
         token; the engine discards the chunk row's sample when the chunk is
         partial (KV committed, prompt unfinished)."""
         cfg = self.model_config
-        use_pallas = self.use_pallas
-        grouped = self._grouped_experts
-        use_pallas_hist = self.use_pallas_hist
-        attn_mesh = self._gspmd_attn_mesh()
+        kernels = self.kernels
         reports_load = self._reports_expert_load
 
         def mixed_step(params, kv: KVCache, int_t, int_b, float_b,
@@ -873,18 +863,15 @@ class LLMEngine:
                        out_tokens, bias_ids, bias_vals, key):
             # int_t: [4, Tp_bucket + R_pad]; int_b: [R_pad, 5] =
             # (logits_indices, top_k, seed, prompt_len, top_n).
-            meta = MixedMeta(
+            meta = StepMeta(
                 seg_ids=int_t[1], positions=int_t[2], slot_mapping=int_t[3],
-                logits_indices=int_b[:, 0], chunk_page_table=chunk_page_table,
-                hist_len=hist_len, page_tables=page_tables,
-                context_lens=context_lens)
+                logits_indices=int_b[:, 0],
+                chunk_page_table=chunk_page_table[0], hist_len=hist_len,
+                page_tables=page_tables, context_lens=context_lens)
             load = [] if reports_load else None
-            hidden, kv, _ = model_lib.forward_mixed(
-                params, cfg, int_t[0], meta, kv, use_pallas=use_pallas,
-                use_pallas_hist=use_pallas_hist, attn_mesh=attn_mesh,
-                moe_load=load, grouped_experts=grouped)
-            logits = model_lib.compute_logits(params, cfg, hidden,
-                                              use_pallas=use_pallas)
+            hidden, kv, _ = model_lib.forward(
+                params, cfg, int_t[0], meta, kv, kernels, moe_load=load)
+            logits = model_lib.compute_logits(params, cfg, hidden, kernels)
             logits = _maybe_bias(logits, bias_ids, bias_vals)
             presence, frequency = float_b[:, 2], float_b[:, 3]
             logits = jax.lax.cond(
@@ -903,8 +890,8 @@ class LLMEngine:
         return self._maybe_jit(mixed_step, donate_argnums=(1,))
 
     def _build_spec_verify_fn(self):
-        """Speculative-verification step (models.forward_spec_verify): ONE
-        program runs every running sequence's [last token, k drafts] slice —
+        """Speculative-verification step: ONE program runs every running
+        sequence's [last token, k drafts] slice (a row part S tokens wide) —
         history attention against the paged pool, an S x S causal block per
         row, multi-token KV append — and applies the lossless accept/
         resample rule over the per-position logits
@@ -917,9 +904,7 @@ class LLMEngine:
         output history — and the verifier advances the counts with each
         accepted token, matching the decode window's per-substep bump."""
         cfg = self.model_config
-        use_pallas = self.use_pallas
-        grouped = self._grouped_experts
-        attn_mesh = self._gspmd_attn_mesh()
+        kernels = self.kernels
         V = cfg.vocab_size
 
         def spec_step(params, kv: KVCache, int_t, int_b, float_b,
@@ -928,17 +913,16 @@ class LLMEngine:
             # int_t: [4, R_pad*S]; int_b: [R_pad, 3] = (top_k, seed, top_n).
             R_pad = page_tables.shape[0]
             S = int_t.shape[1] // R_pad
-            meta = SpecMeta(seg_ids=int_t[1], positions=int_t[2],
-                            slot_mapping=int_t[3], page_tables=page_tables,
+            # No segment part: int_t[1] (row ids) is the sanitizer's.
+            meta = StepMeta(positions=int_t[2], slot_mapping=int_t[3],
+                            page_tables=page_tables,
                             context_lens=context_lens)
-            hidden, kv, _ = model_lib.forward_spec_verify(
-                params, cfg, int_t[0], meta, kv, use_pallas=use_pallas,
-                attn_mesh=attn_mesh, grouped_experts=grouped)
+            hidden, kv, _ = model_lib.forward(
+                params, cfg, int_t[0], meta, kv, kernels, row_width=S)
             # Verification needs logits over EVERY draft position, so the
             # vocab projection runs on all R_pad*S rows (the one place the
             # engine pays more than B logit rows; amortized by acceptance).
-            logits = model_lib.compute_logits(params, cfg, hidden,
-                                              use_pallas=use_pallas)
+            logits = model_lib.compute_logits(params, cfg, hidden, kernels)
             logits = _maybe_bias(logits, jnp.repeat(bias_ids, S, axis=0),
                                  jnp.repeat(bias_vals, S, axis=0))
             logits = logits.reshape(R_pad, S, V)
@@ -958,7 +942,7 @@ class LLMEngine:
         return self._maybe_jit(spec_step, donate_argnums=(1,))
 
     def _build_spec_mixed_fn(self):
-        """Spec×mixed step (models.forward_spec_mixed): ONE program runs a
+        """Spec×mixed step: ONE program runs a
         budgeted chunk of the queue-head prompt AND every running
         sequence's verify slice. The verify half follows the spec program
         exactly (lossless accept/resample over all draft positions, counts
@@ -969,10 +953,7 @@ class LLMEngine:
         bucket, row bucket, history width) family, bounded like every
         other grid (tests/test_compile_guard.py)."""
         cfg = self.model_config
-        use_pallas = self.use_pallas
-        grouped = self._grouped_experts
-        use_pallas_hist = self.use_pallas_hist
-        attn_mesh = self._gspmd_attn_mesh()
+        kernels = self.kernels
         V = cfg.vocab_size
 
         def spec_mixed_step(params, kv: KVCache, S, int_t, logits_idx,
@@ -982,17 +963,16 @@ class LLMEngine:
             # int_t: [4, Tp + R_pad*S]; int_b: [R_pad+1, 3] =
             # (top_k, seed, top_n); logits_idx: [R_pad*S + 1].
             R_pad = page_tables.shape[0]
-            meta = MixedMeta(
+            # The verify slices' entries of int_t[1] carry row ids for the
+            # sanitizer's slot map; the forward reads the chunk's only.
+            meta = StepMeta(
                 seg_ids=int_t[1], positions=int_t[2], slot_mapping=int_t[3],
-                logits_indices=logits_idx, chunk_page_table=chunk_page_table,
-                hist_len=hist_len, page_tables=page_tables,
-                context_lens=context_lens)
-            hidden, kv, _ = model_lib.forward_spec_mixed(
-                params, cfg, int_t[0], meta, kv, S, use_pallas=use_pallas,
-                use_pallas_hist=use_pallas_hist, attn_mesh=attn_mesh,
-                grouped_experts=grouped)
-            logits = model_lib.compute_logits(params, cfg, hidden,
-                                              use_pallas=use_pallas)
+                logits_indices=logits_idx,
+                chunk_page_table=chunk_page_table[0], hist_len=hist_len,
+                page_tables=page_tables, context_lens=context_lens)
+            hidden, kv, _ = model_lib.forward(
+                params, cfg, int_t[0], meta, kv, kernels, row_width=S)
+            logits = model_lib.compute_logits(params, cfg, hidden, kernels)
             logits = _maybe_bias(
                 logits,
                 jnp.concatenate([jnp.repeat(bias_ids[:R_pad], S, axis=0),
@@ -1061,8 +1041,7 @@ class LLMEngine:
 
         ``greedy=True`` compiles the argmax-only variant (see __init__)."""
         cfg = self.model_config
-        use_pallas = self.use_pallas
-        grouped = self._grouped_experts
+        kernels = self.kernels
         W = self.config.scheduler.decode_window
         ps = self.config.cache.page_size
         max_len = self.config.effective_max_len
@@ -1070,8 +1049,7 @@ class LLMEngine:
         if self.pp_size > 1:
             from ..parallel.pp import build_pp_mapped, pp_logits
             S = self.pp_size
-            mapped = build_pp_mapped(self.mesh, cfg, "decode",
-                                     use_pallas=use_pallas)
+            mapped = build_pp_mapped(self.mesh, cfg, "decode", kernels)
 
             def fwd(params, kv, tokens, meta):
                 # Split the batch into M microbatches (M = pp when the padded
@@ -1081,7 +1059,7 @@ class LLMEngine:
                 # the shard_map on the reassembled [B] hidden states.
                 B = tokens.shape[0]
                 M = S if B % S == 0 else 1
-                meta_mb = DecodeMeta(
+                meta_mb = StepMeta(
                     positions=meta.positions.reshape(M, B // M),
                     slot_mapping=meta.slot_mapping.reshape(M, B // M),
                     page_tables=meta.page_tables.reshape(M, B // M, -1),
@@ -1092,14 +1070,11 @@ class LLMEngine:
                 return (pp_logits(params, cfg, hidden_mb.reshape(B, -1)),
                         KVCache(k=kvk, v=kvv))
         else:
-            attn_mesh = self._gspmd_attn_mesh()
-
             def fwd(params, kv, tokens, meta):
-                hidden, kv, _ = model_lib.forward_decode(
-                    params, cfg, tokens, meta, kv, use_pallas=use_pallas,
-                    attn_mesh=attn_mesh, grouped_experts=grouped)
+                hidden, kv, _ = model_lib.forward(params, cfg, tokens, meta,
+                                                  kv, kernels)
                 return model_lib.compute_logits(params, cfg, hidden,
-                                                 use_pallas=use_pallas), kv
+                                                 kernels), kv
 
         V = cfg.vocab_size
 
@@ -1115,8 +1090,8 @@ class LLMEngine:
                                        axis=1)[:, 0]
             in_range = pos < max_len
             slot = jnp.where(in_range, page * ps + pos_c % ps, pos % ps)
-            return DecodeMeta(positions=pos_c, slot_mapping=slot,
-                              page_tables=page_tables, context_lens=pos_c + 1)
+            return StepMeta(positions=pos_c, slot_mapping=slot,
+                            page_tables=page_tables, context_lens=pos_c + 1)
 
         def decode_window_greedy(params, kv: KVCache, tokens0, int_b,
                                  float_b, key):

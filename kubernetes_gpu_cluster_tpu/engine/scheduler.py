@@ -44,7 +44,7 @@ logger = get_logger("scheduler")
 @dataclasses.dataclass
 class ScheduledBatch:
     """One device step's worth of work, already laid out as padded numpy
-    arrays matching models.PrefillMeta / models.DecodeMeta / models.MixedMeta."""
+    arrays matching the fields of models.StepMeta."""
     kind: str                      # "prefill" | "decode" | "mixed"
     seqs: list[Sequence]           # the B real sequences (unpadded count);
                                    # mixed: decode seqs then the chunk seq last

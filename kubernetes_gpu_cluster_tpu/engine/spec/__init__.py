@@ -30,8 +30,8 @@ Pieces:
   attention and multi-token KV append into the paged pool.
 
 The device program lives in ``engine.LLMEngine._build_spec_verify_fn``
-(forward: ``models.forward_spec_verify`` over
-``ops.attention.spec_verify_attention``; acceptance:
+(forward: ``models.forward`` with a row part ``k + 1`` tokens wide, over
+``ops.attention.Kernels.verify_attention``; acceptance:
 ``ops.sampling.spec_verify_sample``).
 """
 

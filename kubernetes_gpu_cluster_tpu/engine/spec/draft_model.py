@@ -63,7 +63,7 @@ import numpy as np
 from ...analysis.sanitize import SanitizerError, sanitize_enabled
 from ...config import CacheConfig, EngineConfig, ModelConfig, get_model_config
 from ...models import llama as model_lib
-from ...models.llama import DecodeMeta, PrefillMeta
+from ...models.llama import StepMeta
 from ...utils import cdiv, get_logger
 from ...utils.math import next_power_of_2
 from ..kv_cache import (PageAllocator, allocate_kv_cache,
@@ -221,13 +221,14 @@ class DraftModelRunner(DraftProposer):
 
         def draft_decode(params, kv, tokens, int_b, context_lens):
             # int_b: [B, 2 + pages_bucket] = (position, slot, page_table...)
-            meta = DecodeMeta(positions=int_b[:, 0], slot_mapping=int_b[:, 1],
-                              page_tables=int_b[:, 2:],
-                              context_lens=context_lens)
-            hidden, kv, _ = model_lib.forward_decode(
-                params, cfg, tokens, meta, kv, use_pallas=False)
-            logits = model_lib.compute_logits(params, cfg, hidden,
-                                              use_pallas=False)
+            meta = StepMeta(positions=int_b[:, 0], slot_mapping=int_b[:, 1],
+                            page_tables=int_b[:, 2:],
+                            context_lens=context_lens)
+            # The draft programs run the XLA references (NO_KERNELS, the
+            # forward's default): no chip has run a draft model, so whether
+            # they may take the target's kernels is open (ROADMAP D5g).
+            hidden, kv, _ = model_lib.forward(params, cfg, tokens, meta, kv)
+            logits = model_lib.compute_logits(params, cfg, hidden)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv
 
         return self._maybe_jit(draft_decode, donate_argnums=(1,))
@@ -241,12 +242,12 @@ class DraftModelRunner(DraftProposer):
         cfg = self.draft_config
 
         def draft_prefill(params, kv, int_t, page_table, hist_len):
-            meta = PrefillMeta(seg_ids=int_t[1], positions=int_t[2],
-                               slot_mapping=int_t[3],
-                               logits_indices=jnp.zeros((1,), jnp.int32))
-            _, kv, _ = model_lib.forward_prefill_hist(
-                params, cfg, int_t[0], meta, kv, page_table[0], hist_len,
-                use_pallas=False)
+            meta = StepMeta(seg_ids=int_t[1], positions=int_t[2],
+                            slot_mapping=int_t[3],
+                            logits_indices=jnp.zeros((1,), jnp.int32),
+                            chunk_page_table=page_table[0],
+                            hist_len=hist_len)
+            _, kv, _ = model_lib.forward(params, cfg, int_t[0], meta, kv)
             return kv
 
         return self._maybe_jit(draft_prefill, donate_argnums=(1,))

@@ -1,9 +1,7 @@
 from .llama import (  # noqa: F401
-    PrefillMeta,
-    DecodeMeta,
+    StepMeta,
     init_params,
-    forward_prefill,
-    forward_decode,
+    forward,
     compute_logits,
 )
 from .registry import get_model_config  # noqa: F401

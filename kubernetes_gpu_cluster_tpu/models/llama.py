@@ -11,10 +11,12 @@ TPU-first design decisions:
   leading ``[L, ...]`` axis and the layer loop is a ``lax.scan`` — one traced
   layer body regardless of depth (compile time O(1) in L), and the paged KV
   pool's ``[L, ...]`` leading axis threads through the scan as xs/ys.
-- Two entry points matching the serving hot loop: ``forward_prefill`` (ragged
-  flattened prompt tokens, causal-within-segment) and ``forward_decode`` (one
-  token per sequence against the paged cache). Both scatter K/V into the page
-  pool via precomputed slot mappings (padding slots land in the scrap page).
+- ONE entry point, ``forward``, over one flattened token axis
+  ``[segment tokens | row tokens]`` (``StepMeta``): prompt tokens that are
+  causal within their segment, and running sequences' tokens against the
+  paged cache. Every step program of the serving loop is a case of it. New
+  K/V are scattered into the page pool via precomputed slot mappings
+  (padding slots land in the scrap page).
 - Matmuls stay in model dtype (bf16) with fp32 accumulation on the MXU
   (``preferred_element_type``); norms/softmax in fp32.
 - Only the hidden states that feed sampling are projected to logits
@@ -33,68 +35,46 @@ from ..config import ModelConfig
 from ..engine.kv_cache import KVCache
 from ..ops import quant as quant_ops
 from ..ops.rope import apply_rope, rope_cos_sin
-from ..ops.attention import (
-    write_kv_pages_all,
-    ragged_prefill_attention,
-    ragged_prefill_attention_tp,
-    prefill_history_attention,
-    prefill_history_attention_tp,
-    paged_decode_attention,
-    paged_decode_attention_tp,
-    mixed_attention,
-    spec_mixed_attention,
-    spec_verify_attention,
-)
+from ..ops.attention import NO_KERNELS, Kernels
 
 Params = dict[str, Any]
 
 
-class PrefillMeta(NamedTuple):
-    """Metadata for a ragged prefill step over T flattened prompt tokens."""
-    seg_ids: jax.Array        # [T] int32 sequence id per token; padding = -1
-    positions: jax.Array      # [T] int32 position within its sequence
-    slot_mapping: jax.Array   # [T] int32 flat KV slot (scrap page for padding)
-    logits_indices: jax.Array # [B] int32 index into T of each seq's last token
+class StepMeta(NamedTuple):
+    """Metadata of one step over ONE padded token axis
+    ``T = [segment tokens | row tokens]``; either part may be absent.
 
+    - The **segment part** (``seg_ids`` given) is prompt tokens, causal
+      within their segment: whole prompts packed side by side, or, with
+      ``chunk_page_table``/``hist_len``, ONE sequence's chunk that also
+      attends to what it already has in the pool.
+    - The **row part** (``page_tables`` given) is the running sequences'
+      tokens against their pages: ``forward``'s static ``row_width`` tokens
+      a sequence (1: decode; ``S = k + 1``: a slice of draft verification).
 
-class DecodeMeta(NamedTuple):
-    """Metadata for a decode step: one new token per sequence."""
-    positions: jax.Array      # [B] int32 position of the new token
-    slot_mapping: jax.Array   # [B] int32 flat KV slot for the new token
-    page_tables: jax.Array    # [B, pages_per_seq] int32 page ids (pad = scrap)
-    context_lens: jax.Array   # [B] int32 valid tokens incl. the new one
-
-
-class SpecMeta(NamedTuple):
-    """Metadata for a speculative-verification step over one padded token
-    axis ``T = R_pad * S``: every running sequence contributes S = k+1
-    contiguous slots (its last committed token + k drafts), attending to
-    its own paged-pool history plus the earlier slice tokens causally.
-    The per-row slot count S is static per compiled shape
-    (``S = T // page_tables.shape[0]``)."""
-    seg_ids: jax.Array          # [T] int32: row id on real slots, -1 padding
-    positions: jax.Array        # [T] int32 global positions (RoPE input)
-    slot_mapping: jax.Array     # [T] int32 KV write slot (overflow -> scrap)
-    page_tables: jax.Array      # [R_pad, pages_bucket] per-row history pages
-    context_lens: jax.Array     # [R_pad] committed tokens incl. slot 0's
-
-
-class MixedMeta(NamedTuple):
-    """Metadata for a mixed step over one padded token axis
-    ``T = Tp_bucket + R_pad``: a prefill chunk (tokens [0:Tp_bucket), one
-    sequence, attending to its pool history) followed by decode rows
-    (tokens [Tp_bucket:T), one per running sequence, against the paged
-    pool). The split point is static per compiled shape:
-    ``Tp_bucket = T - page_tables.shape[0]``."""
-    seg_ids: jax.Array          # [T] int32: 0 on chunk tokens, -1 elsewhere
-    positions: jax.Array        # [T] int32 global positions (RoPE)
-    slot_mapping: jax.Array     # [T] int32 KV write slot (pad -> scrap page)
-    logits_indices: jax.Array   # [R_pad] rows to sample: decode rows then
-                                # the chunk's last token
-    chunk_page_table: jax.Array # [1, hist_width] the chunk seq's pages
-    hist_len: jax.Array         # [] int32 chunk history already in the pool
-    page_tables: jax.Array      # [R_pad, pages_bucket] decode page tables
-    context_lens: jax.Array     # [R_pad] decode valid tokens incl. current
+    The split is static per compiled shape: the row part is the last
+    ``page_tables.shape[0] * row_width`` tokens. The two parts' sequences
+    are disjoint and each addresses only its own pages, so no attention
+    crosses the split."""
+    # In the order of the step programs' packed int buffer. ``positions`` and
+    # ``slot_mapping`` are always given; the rest say which parts exist.
+    # [T] int32 segment id per token, -1 = padding (a chunk's tokens carry
+    # 0); only the segment part's entries are read.
+    seg_ids: Optional[jax.Array] = None
+    # [T] int32 global positions (RoPE input).
+    positions: Optional[jax.Array] = None
+    # [T] int32 flat KV slot of each token (padding -> the scrap page).
+    slot_mapping: Optional[jax.Array] = None
+    # [B] int32 the tokens whose hidden state is returned; None: every one.
+    logits_indices: Optional[jax.Array] = None
+    # A chunk with history: [hist_width] int32 its sequence's pages, and
+    # [] int32 how many of its tokens the pool already holds.
+    chunk_page_table: Optional[jax.Array] = None
+    hist_len: Optional[jax.Array] = None
+    # The rows: [R, pages] int32 page ids (pad = scrap page), and [R] int32
+    # committed tokens including the row's first.
+    page_tables: Optional[jax.Array] = None
+    context_lens: Optional[jax.Array] = None
 
 
 # ---------------------------------------------------------------------------
@@ -395,7 +375,9 @@ def _dot(x: jax.Array, lp: Params, name: str,
     - int4 (packed nibbles + group scales, ``scale.ndim == w.ndim``): the
       dequant-fused matmul contracts per input group and folds the scales
       into the f32 partials — no dequantized weight copy in HBM
-      (ops.quant.int4_matmul; Pallas kernel on TPU).
+      (ops.quant.int4_matmul; ``use_pallas`` is ``Kernels.int4_pallas``:
+      False forces the XLA fusion, None leaves it to that function's own
+      opt-in).
     - dense-precision weights take the plain path.
     """
     w = lp[name]
@@ -503,12 +485,12 @@ _EXPERT_KEYS = ("w_gate", "w_up", "w_down",
 def experts_grouped(lp: Params, x: jax.Array, idx: jax.Array,
                     w: jax.Array, sizes: jax.Array,
                     layer: Optional[jax.Array] = None,
-                    use_pallas: Optional[bool] = None) -> jax.Array:
+                    use_pallas: bool = False) -> jax.Array:
     """Token-sorted grouped expert matmuls: the T*k routed (token, expert)
     pairs are sorted by expert and each expert's SwiGLU runs over its own
     contiguous rows, so the work is the pairs' and not experts x tokens
-    (on the chip ``ops.pallas.grouped_matmul``, the kernel or its exception;
-    elsewhere its XLA twin ``jax.lax.ragged_dot``). Every pair is computed
+    (``use_pallas``: ``ops.pallas.grouped_matmul``, the kernel or its
+    exception; else its XLA twin ``jax.lax.ragged_dot``). Every pair is computed
     whatever the imbalance: there is no capacity and nothing is dropped.
     x: [T, d]; idx/w: [T, k]; sizes: [E] int32, the pairs of each expert.
     Returns [T, d] float32.
@@ -531,8 +513,6 @@ def experts_grouped(lp: Params, x: jax.Array, idx: jax.Array,
             jnp.zeros((n_layers * E,), sizes.dtype), sizes, (layer * E,))
         w_gate, w_up, w_down = (a.reshape((n_layers * E,) + a.shape[2:])
                                 for a in (w_gate, w_up, w_down))
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
         from ..ops.pallas.grouped_matmul import grouped_matmul as matmul
     else:
@@ -580,23 +560,21 @@ def experts_dense(lp: Params, x: jax.Array, idx: jax.Array, w: jax.Array,
 def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
              tp_axis: Optional[str] = None,
              ep_axis: Optional[str] = None,
-             use_pallas: Optional[bool] = None,
+             kernels: Kernels = NO_KERNELS,
              load_out: Optional[list] = None,
-             stacked: Optional[tuple] = None,
-             grouped: bool = False) -> jax.Array:
+             stacked: Optional[tuple] = None) -> jax.Array:
     """Sparse expert layer: route (``moe_route``), run the routed experts,
     add the shared experts where the model has them. Expert compute is
-    dense dispatch unless the caller says ``grouped``: that the expert
-    tensors lie WHOLE on the device that runs this (no GSPMD mesh shards
-    them, no manual ``tp``/``ep`` axis), so the grouped kernel, a custom
-    call with no partitioning rule, may be handed the stack as it is. Then
-    it is chosen from the step's size (``dense_dispatch_pays``): dense where
-    every expert is hit anyway and the step is under the chip's FLOP-to-byte
-    balance, else the token-sorted grouped path.
-    Sharded and quantized experts are always dense dispatch (PERF.md:
-    debt). ``load_out``: a list that is
-    given the routed pairs each expert was sent, [E] int32 (``_layer_scan``'s
-    ``moe_load``). ``stacked``: (the whole stack's expert tensors
+    dense dispatch unless ``kernels.grouped_experts``: the engine's word
+    that the expert tensors lie WHOLE on the device that runs this (no GSPMD
+    mesh shards them, no manual ``tp``/``ep`` axis), so the grouped kernel, a
+    custom call with no partitioning rule, may be handed the stack as it is.
+    Then it is chosen from the step's size (``dense_dispatch_pays``): dense
+    where every expert is hit anyway and the step is under the chip's
+    FLOP-to-byte balance, else the token-sorted grouped path. Sharded and
+    quantized experts are always dense dispatch (PERF.md: debt).
+    ``load_out``: a list that is given the routed pairs each expert was
+    sent, [E] int32 (``_layer_scan``'s ``moe_load``). ``stacked``: (the whole stack's expert tensors
     [n, E, ...], this layer's index in it) where the caller kept them out
     of ``lp`` (``_layer_scan``: so that no layer's experts are copied)."""
     with jax.named_scope("kgct.moe.route"):
@@ -606,6 +584,7 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
         load = jnp.sum(jax.nn.one_hot(idx.reshape(-1), cfg.num_experts,
                                       dtype=jnp.int32), axis=0)
     experts, layer = stacked if stacked is not None else (lp, None)
+    grouped, int4 = kernels.grouped_experts, kernels.int4_pallas
     with jax.named_scope("kgct.moe.experts"):
         if grouped and (tp_axis is not None or ep_axis is not None):
             raise ValueError("grouped expert dispatch inside a manual "
@@ -613,12 +592,12 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
         if (grouped and experts["w_gate"].dtype != jnp.int8
                 and not dense_dispatch_pays(x.shape[0], cfg)):
             out = experts_grouped(experts, x, idx, w, load, layer,
-                                  use_pallas)
+                                  kernels.use_pallas)
         else:
             if layer is not None:   # one layer's, read in place by the dots
                 experts = {k: jax.lax.dynamic_index_in_dim(
                     a, layer, 0, keepdims=False) for k, a in experts.items()}
-            out = experts_dense(experts, x, idx, w, cfg, ep_axis, use_pallas)
+            out = experts_dense(experts, x, idx, w, cfg, ep_axis, int4)
     reduce_axes = tuple(a for a in (ep_axis, tp_axis) if a is not None)
     if reduce_axes:
         out = jax.lax.psum(out, reduce_axes)
@@ -627,7 +606,7 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
         with jax.named_scope("kgct.moe.shared"):
             out = out + _dense_mlp(
                 {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
-                 "w_down": lp["ws_down"]}, x, cfg, use_pallas=use_pallas)
+                 "w_down": lp["ws_down"]}, x, cfg, use_pallas=int4)
     if load_out is not None:
         load_out.append(load)
     return out
@@ -721,8 +700,7 @@ def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
                         row: jax.Array, seg_ids: jax.Array,
                         positions: jax.Array, pool: jax.Array,
                         page_table: jax.Array, hist_len: jax.Array,
-                        layer_idx: jax.Array, use_pallas, use_pallas_hist
-                        ) -> jax.Array:
+                        layer_idx: jax.Array, kernels: Kernels) -> jax.Array:
     """One sequence's prompt chunk under latent attention. With nothing of
     the sequence in the pool yet (``hist_len == 0``: a whole prompt in one
     step, the first chunk of a long one) the chunk's tokens attend each
@@ -735,15 +713,13 @@ def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
 
     def fresh(_):
         k, v = mla_materialise(lp, cfg, row)
-        return ragged_prefill_attention(q, k, v, seg_ids, positions, scale,
-                                        use_pallas=use_pallas)
+        return kernels.prefill_attention(q, k, v, seg_ids, positions, scale)
 
     def with_history(_):
         return mla_absorbed(
-            lp, cfg, q, row, lambda qa, rows: prefill_history_attention(
+            lp, cfg, q, row, lambda qa, rows: kernels.chunk_attention(
                 qa, rows, None, seg_ids, positions, pool, None, page_table,
-                hist_len, scale, layer=layer_idx,
-                use_pallas=use_pallas_hist))
+                hist_len, scale, layer=layer_idx))
 
     return jax.lax.cond(hist_len == 0, fresh, with_history, None)
 
@@ -754,12 +730,10 @@ def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
 
 def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                 positions: jax.Array, attn_fn,
-                layer_slice=None,
+                kernels: Kernels = NO_KERNELS,
                 tp_axis: Optional[str] = None,
                 ep_axis: Optional[str] = None,
-                use_pallas: Optional[bool] = None,
                 moe_load: Optional[list] = None,
-                grouped_experts: bool = False,
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Scan the layer body over stacked weights.
 
@@ -772,7 +746,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     index instead, moving zero pool bytes. Each layer's freshly projected
     K/V come out as scan ys, and the caller commits them to the pool in ONE
     in-place write of the donated pool after the scan
-    (ops.attention.write_kv_pages_all: on the chip a Pallas kernel that
+    (ops.attention.Kernels.write_pages: on the chip a Pallas kernel that
     read-modify-writes the touched pool tiles by DMA, all in flight at
     once; elsewhere a loop of row updates). Threading the pool through the
     scan as carry/ys would force a full pool copy per step.
@@ -781,10 +755,12 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     written in PREVIOUS steps only (attention folds the current step's k/v in
     directly).
 
-    ``layer_slice`` restricts to a contiguous [start, stop) layer range.
-    ``tp_axis``/``ep_axis`` name manual mesh axes when running inside
-    shard_map (parallel/pp.py); under GSPMD they stay None and the SPMD
-    partitioner inserts the equivalent collectives.
+    ``kernels`` reaches the matmuls only as the int4 consumer's rule
+    (``Kernels.int4_pallas``) and the expert layers' dispatch (``_moe_mlp``);
+    attention's choice is ``attn_fn``'s. ``tp_axis``/``ep_axis`` name manual
+    mesh axes when running inside shard_map (parallel/pp.py); under GSPMD
+    they stay None and the SPMD partitioner inserts the equivalent
+    collectives.
 
     Returns (h, k_all, v_all) with k_all/v_all: [L, T, n_kv_local * hd] —
     heads flattened per layer, the pool's own row layout, so the post-scan
@@ -800,23 +776,22 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     layers, are appended to it (a traced value of the caller's own trace:
     the step program returns it beside its tokens, so the host reads the
     routing balance from a fetch it makes anyway).
-
-    ``grouped_experts``: the engine's word that no mesh shards the expert
-    tensors (``_moe_mlp``'s ``grouped``); every ``forward_*`` hands it on.
     """
+    int4 = kernels.int4_pallas
+
     def body(h, xs):
         lp, layer_idx = xs
         resid = h
         x = _norm(cfg, h, lp, "input_norm")
         if cfg.is_mla:
             with jax.named_scope("kgct.mla"):
-                q, row = _mla_qkv(lp, cfg, x, positions, use_pallas)
+                q, row = _mla_qkv(lp, cfg, x, positions, int4)
                 attn_out = attn_fn(lp, q, row, None, layer_idx)
         else:
-            q, k, v = _qkv(lp, cfg, x, positions, use_pallas)
+            q, k, v = _qkv(lp, cfg, x, positions, int4)
             attn_out = attn_fn(lp, q, k, v, layer_idx)
         attn_out = attn_out.reshape(x.shape[0], -1)
-        o = _dot(attn_out, lp, "wo", use_pallas)
+        o = _dot(attn_out, lp, "wo", int4)
         if tp_axis is not None:  # row-sharded wo: partial sums over local heads
             o = jax.lax.psum(o, tp_axis)
         if "bo" in lp:           # after the reduce: applied exactly once
@@ -829,12 +804,11 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         load = [] if moe_load is not None else None
         if "router" in lp:
             mlp = _moe_mlp(lp, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
-                           use_pallas=use_pallas, load_out=load,
-                           stacked=(experts, layer_idx - first),
-                           grouped=grouped_experts)
+                           kernels=kernels, load_out=load,
+                           stacked=(experts, layer_idx - first))
         else:
             mlp = _dense_mlp(lp, x, cfg, tp_axis=tp_axis,
-                             use_pallas=use_pallas)
+                             use_pallas=int4)
         h = resid + mlp
         load = tuple(load or ())
         if cfg.is_mla:
@@ -847,12 +821,6 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     # order. Each group is one scan over its own stacked weights.
     stacks = [params[name] for name in ("dense_layers", "layers")
               if name in params]
-    if layer_slice is not None:
-        if len(stacks) > 1:
-            raise ValueError("layer_slice (pipeline stages) over a stack "
-                             "with leading dense layers")
-        start, stop = layer_slice
-        stacks = [jax.tree.map(lambda a: a[start:stop], stacks[0])]
     first, outs = 0, []
     for layers in stacks:
         # An expert stack's expert tensors stay OUT of the scanned xs: the
@@ -873,284 +841,118 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     return (h, rows[0], rows[1]) if len(rows) == 2 else (h, rows[0], None)
 
 
-def forward_prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                    meta: PrefillMeta, kv: KVCache,
-                    layer_slice=None, use_pallas=None,
-                    hidden_in: Optional[jax.Array] = None,
-                    tp_axis: Optional[str] = None,
-                    ep_axis: Optional[str] = None,
-                    attn_mesh=None, attn_impl=None, moe_load=None,
-                    grouped_experts=False):
-    """Ragged prefill over T flattened tokens. Returns (selected_hidden [B, d],
-    new_kv, raw_hidden [T, d]). ``hidden_in`` replaces the embedding lookup for
-    non-first pipeline stages; ``raw_hidden`` is what rotates stage-to-stage.
-    ``attn_mesh``: under a GSPMD mesh, run the Pallas attention per-shard via
-    shard_map over the tp axis (ops.attention.ragged_prefill_attention_tp).
-    ``attn_impl``: full override ``fn(q, k, v, seg_ids, positions) -> out``
-    (the engine passes ring attention here for sp>1 meshes)."""
+def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+            meta: StepMeta, kv: KVCache, kernels: Kernels = NO_KERNELS, *,
+            row_width: int = 1,
+            hidden_in: Optional[jax.Array] = None,
+            tp_axis: Optional[str] = None,
+            ep_axis: Optional[str] = None,
+            moe_load: Optional[list] = None):
+    """THE forward pass: one program over the token axis
+    ``[segment tokens | row tokens]`` that ``meta`` describes. Embedding,
+    QKV/MLP matmuls and norms run once over all T tokens (the weight
+    streaming a decode step pays is amortised over whatever rides along);
+    only attention splits, at the static boundary, and each part routes
+    through the operation the pure steps use:
+
+    ============================  =======================================
+    segment part, fresh           ``kernels.prefill_attention``
+    segment part, with history    ``kernels.chunk_attention``
+    row part, ``row_width == 1``  ``kernels.decode_attention``
+    row part, ``row_width > 1``   ``kernels.verify_attention``
+    ============================  =======================================
+
+    A part that is absent adds no operation: a pure decode or a pure
+    prefill traces to exactly its own program. A latent-attention model
+    (``cfg.is_mla``) meets fresh tokens in the materialised form and cached
+    ones in the absorbed form (``mla_materialise``/``mla_absorbed``).
+
+    Every part reads the pool PRE-write (this step's K/V fold in directly)
+    and all new K/V, rejected drafts' included, commit in the one post-scan
+    write; a rejected slot sits past its sequence's committed length and is
+    overwritten before any later step reads it.
+
+    ``hidden_in`` replaces the embedding lookup and ``tp_axis``/``ep_axis``
+    name the manual mesh axes for a pipeline stage (parallel/pp.py's
+    shard_map body); ``moe_load``: see ``_layer_scan``.
+
+    Returns (normed hidden of ``meta.logits_indices``' tokens, or of every
+    token where that is None [*, d]; new_kv; raw_hidden [T, d], which is
+    what rotates stage to stage)."""
     scale = cfg.head_dim ** -0.5
     h = (_embed(params, cfg, tokens, meta.positions)
          if hidden_in is None else hidden_in)
+    n_rows = (0 if meta.page_tables is None
+              else meta.page_tables.shape[0] * row_width)
+    n_seg = 0 if meta.seg_ids is None else tokens.shape[0] - n_rows
+    if n_seg < 0 or n_seg + n_rows != tokens.shape[0]:
+        raise ValueError(
+            f"{tokens.shape[0]} tokens are not {n_seg} segment tokens + "
+            f"{n_rows} row tokens")
 
-    def attn_fn(lp, q, k, v, layer_idx):
-        # Prefill attends within the in-batch k/v only (each sequence's whole
-        # prompt is in this batch); the pool is written post-scan for decode.
-        if attn_impl is not None:
-            return attn_impl(q, k, v, meta.seg_ids, meta.positions)
-        if attn_mesh is not None:
-            return ragged_prefill_attention_tp(attn_mesh, q, k, v,
-                                               meta.seg_ids, meta.positions,
-                                               scale)
-        if cfg.is_mla:
-            # Fresh tokens attend each other in the materialised form.
-            k, v = mla_materialise(lp, cfg, k)
-        return ragged_prefill_attention(q, k, v, meta.seg_ids, meta.positions,
-                                        scale, use_pallas=use_pallas)
-
-    h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  layer_slice, tp_axis=tp_axis,
-                                  ep_axis=ep_axis, use_pallas=use_pallas,
-                                  moe_load=moe_load,
-                                  grouped_experts=grouped_experts)
-    if layer_slice is not None:
-        kv = KVCache(k=kv.k[layer_slice[0]:layer_slice[1]],
-                     v=kv.v[layer_slice[0]:layer_slice[1]])
-    new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping,
-                                         use_pallas=use_pallas,
-                                         mesh=attn_mesh))
-    selected = h[meta.logits_indices]
-    return _norm(cfg, selected, params, "final_norm"), new_kv, h
-
-
-def forward_prefill_hist(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                         meta: PrefillMeta, kv: KVCache,
-                         page_table: jax.Array, hist_len: jax.Array,
-                         use_pallas=None, attn_mesh=None,
-                         hidden_in: Optional[jax.Array] = None,
-                         tp_axis: Optional[str] = None,
-                         ep_axis: Optional[str] = None, moe_load=None,
-                         grouped_experts=False):
-    """Chunked prefill: one sequence's chunk attending to its pool history +
-    itself causally (ops.attention.prefill_history_attention). Returns
-    (normed_selected [1, d], new_kv, raw_hidden [T, d]). ``attn_mesh``: under
-    a GSPMD mesh, run the Pallas history kernel per-shard via shard_map over
-    the tp axis. ``hidden_in``/``tp_axis``/``ep_axis``: manual-mesh entry for
-    non-first pipeline stages (parallel/pp.py's pipelined chunked prefill)."""
-    scale = cfg.head_dim ** -0.5
-    h = (_embed(params, cfg, tokens, meta.positions)
-         if hidden_in is None else hidden_in)
-
-    def attn_fn(lp, q, k, v, layer_idx):
+    def segment_attn(lp, q, k, v, seg_ids, positions, layer_idx):
+        if meta.chunk_page_table is None:
+            # Each sequence's whole prompt is in this batch: tokens attend
+            # within the in-batch k/v only (a latent model's in the
+            # materialised form).
+            if cfg.is_mla:
+                k, v = mla_materialise(lp, cfg, k)
+            return kernels.prefill_attention(q, k, v, seg_ids, positions,
+                                             scale)
         if cfg.is_mla:
             return mla_chunk_attention(
-                lp, cfg, q, k, meta.seg_ids, meta.positions, kv.k,
-                page_table, hist_len, layer_idx, use_pallas, use_pallas)
-        if attn_mesh is not None:
-            return prefill_history_attention_tp(
-                attn_mesh, q, k, v, meta.seg_ids, meta.positions, kv.k, kv.v,
-                page_table, hist_len, scale, layer=layer_idx)
-        return prefill_history_attention(
-            q, k, v, meta.seg_ids, meta.positions, kv.k, kv.v,
-            page_table, hist_len, scale, layer=layer_idx,
-            use_pallas=use_pallas)
+                lp, cfg, q, k, seg_ids, positions, kv.k,
+                meta.chunk_page_table, meta.hist_len, layer_idx, kernels)
+        return kernels.chunk_attention(
+            q, k, v, seg_ids, positions, kv.k, kv.v, meta.chunk_page_table,
+            meta.hist_len, scale, layer=layer_idx)
 
-    h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  tp_axis=tp_axis, ep_axis=ep_axis,
-                                  use_pallas=use_pallas, moe_load=moe_load,
-                                  grouped_experts=grouped_experts)
-    new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping,
-                                         use_pallas=use_pallas,
-                                         mesh=attn_mesh))
-    selected = h[meta.logits_indices]
-    return _norm(cfg, selected, params, "final_norm"), new_kv, h
-
-
-def forward_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                  meta: MixedMeta, kv: KVCache,
-                  use_pallas=None, use_pallas_hist=None, attn_mesh=None,
-                  moe_load=None, grouped_experts=False):
-    """Mixed prefill/decode step (stall-free batching): ONE forward over the
-    combined token axis — embedding, QKV/MLP matmuls and norms run once for
-    chunk and decode tokens together, so the weight streaming a decode step
-    pays is amortized over the prefill chunk riding along — with attention
-    split at the static chunk/decode boundary: chunk tokens run history
-    attention against their own pool pages, decode rows run paged decode
-    (ops.attention.mixed_attention). Returns (normed_selected [R_pad, d],
-    new_kv, raw_hidden [T, d]).
-
-    Single-mesh and GSPMD-tp regimes only — under pp the layer stack is
-    sharded outside this path and under sp ring attention replaces the
-    ragged kernels; the engine falls back to the legacy scheduler policy
-    there."""
-    scale = cfg.head_dim ** -0.5
-    h = _embed(params, cfg, tokens, meta.positions)
-    n_prefill = tokens.shape[0] - meta.page_tables.shape[0]
-
-    def attn_fn(lp, q, k, v, layer_idx):
+    def row_attn(lp, q, k, v, layer_idx):
+        # The pool holds positions 0..ctx-2. The STACKED pool + dynamic
+        # layer index go straight to the kernel: no per-layer pool slice is
+        # ever materialised (see _layer_scan).
+        if row_width > 1:
+            return kernels.verify_attention(
+                q, k, v, kv.k, kv.v, meta.page_tables, meta.context_lens,
+                scale, layer=layer_idx)
         if cfg.is_mla:
-            # The chunk half as a chunk alone; each decode row against its
-            # latent pages in the absorbed form, every page read once.
-            out_p = mla_chunk_attention(
-                lp, cfg, q[:n_prefill], k[:n_prefill],
-                meta.seg_ids[:n_prefill], meta.positions[:n_prefill], kv.k,
-                meta.chunk_page_table[0], meta.hist_len, layer_idx,
-                use_pallas, use_pallas_hist)
-            out_d = mla_absorbed(
-                lp, cfg, q[n_prefill:], k[n_prefill:],
-                lambda qa, rows: paged_decode_attention(
-                    qa, kv.k, None, meta.page_tables, meta.context_lens,
-                    rows, None, scale, layer=layer_idx,
-                    use_pallas=use_pallas))
-            return jnp.concatenate([out_p, out_d], axis=0)
-        return mixed_attention(
-            q, k, v, meta.seg_ids, meta.positions, kv.k, kv.v,
-            meta.chunk_page_table, meta.hist_len, meta.page_tables,
-            meta.context_lens, scale, n_prefill=n_prefill, layer=layer_idx,
-            use_pallas=use_pallas, use_pallas_hist=use_pallas_hist,
-            attn_mesh=attn_mesh)
-
-    h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  use_pallas=use_pallas, moe_load=moe_load,
-                                  grouped_experts=grouped_experts)
-    new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping,
-                                         use_pallas=use_pallas,
-                                         mesh=attn_mesh))
-    selected = h[meta.logits_indices]
-    return _norm(cfg, selected, params, "final_norm"), new_kv, h
-
-
-def forward_spec_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                       meta: MixedMeta, kv: KVCache, S: int,
-                       use_pallas=None, use_pallas_hist=None,
-                       attn_mesh=None, grouped_experts=False):
-    """Spec×mixed step: ONE forward over the combined
-    ``[prefill chunk | verify slices]`` token axis — embedding, QKV/MLP
-    matmuls and norms run once for chunk and verify tokens together (the
-    weight streaming a verify step pays is amortized over the chunk riding
-    along, the same economics that motivated mixed batching) — with
-    attention split at the static chunk/verify boundary
-    (ops.attention.spec_mixed_attention). ``S = k+1`` is config-static per
-    compiled shape (the engine passes it as a jit static arg):
-    ``n_prefill = T - R_pad * S``. Returns (normed_selected
-    [R_pad*S + 1, d] — every verify slot plus the chunk's last token —
-    new_kv, raw_hidden [T, d])."""
-    scale = cfg.head_dim ** -0.5
-    h = _embed(params, cfg, tokens, meta.positions)
-    n_prefill = tokens.shape[0] - meta.page_tables.shape[0] * S
-
-    def attn_fn(lp, q, k, v, layer_idx):
-        return spec_mixed_attention(
-            q, k, v, meta.seg_ids, meta.positions, kv.k, kv.v,
-            meta.chunk_page_table, meta.hist_len, meta.page_tables,
-            meta.context_lens, scale, n_prefill=n_prefill, layer=layer_idx,
-            use_pallas=use_pallas, use_pallas_hist=use_pallas_hist,
-            attn_mesh=attn_mesh)
-
-    h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  use_pallas=use_pallas,
-                                  grouped_experts=grouped_experts)
-    new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping,
-                                         use_pallas=use_pallas,
-                                         mesh=attn_mesh))
-    selected = h[meta.logits_indices]
-    return _norm(cfg, selected, params, "final_norm"), new_kv, h
-
-
-def forward_spec_verify(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                        meta: SpecMeta, kv: KVCache, use_pallas=None,
-                        attn_mesh=None, grouped_experts=False):
-    """Speculative-verification forward: ONE program scores every running
-    sequence's k drafted tokens. Embedding, QKV/MLP matmuls and norms run
-    over the flat ``[R_pad * S]`` token axis (the weight streaming a decode
-    step pays is amortized over all draft positions — the same economics
-    as mixed batching); attention runs the batched draft-verification
-    shape (ops.attention.spec_verify_attention: paged-pool history + an
-    S x S causal block per row). Returns (normed_hidden [T, d] over EVERY
-    slot — the verifier needs logits at all draft positions, not one
-    sampled row — new_kv, raw_hidden [T, d]). All new K/V (including
-    drafts that will be rejected) commit in the one post-scan write;
-    rejected slots sit past the sequence's committed length and are
-    overwritten before any later step reads them. ``attn_mesh``: under a
-    GSPMD mesh the KV write kernel runs per shard (attention here is XLA
-    on every backend)."""
-    scale = cfg.head_dim ** -0.5
-    h = _embed(params, cfg, tokens, meta.positions)
-
-    def attn_fn(lp, q, k, v, layer_idx):
-        return spec_verify_attention(
-            q, k, v, kv.k, kv.v, meta.page_tables, meta.context_lens, scale,
-            layer=layer_idx, use_pallas=use_pallas)
-
-    h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  use_pallas=use_pallas,
-                                  grouped_experts=grouped_experts)
-    new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping,
-                                         use_pallas=use_pallas,
-                                         mesh=attn_mesh))
-    return _norm(cfg, h, params, "final_norm"), new_kv, h
-
-
-def forward_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                   meta: DecodeMeta, kv: KVCache,
-                   layer_slice=None, use_pallas=None,
-                   hidden_in: Optional[jax.Array] = None,
-                   tp_axis: Optional[str] = None,
-                   ep_axis: Optional[str] = None,
-                   attn_mesh=None, grouped_experts=False):
-    """Decode step: B sequences, one new token each, against the paged pool.
-    Returns (normed_hidden [B, d], new_kv, raw_hidden [B, d]).
-    ``attn_mesh``: under a GSPMD mesh, run the Pallas attention per-shard via
-    shard_map over the tp axis (ops.attention.paged_decode_attention_tp)."""
-    scale = cfg.head_dim ** -0.5
-    h = (_embed(params, cfg, tokens, meta.positions)
-         if hidden_in is None else hidden_in)
-
-    if layer_slice is not None:
-        kv = KVCache(k=kv.k[layer_slice[0]:layer_slice[1]],
-                     v=kv.v[layer_slice[0]:layer_slice[1]])
-
-    def attn_fn(lp, q, k, v, layer_idx):
-        # Pool holds positions 0..ctx-2; this step's k/v fold in directly and
-        # are committed to the pool in one post-scan write. The STACKED pool
-        # + dynamic layer index go straight to the kernel — no per-layer pool
-        # slice is ever materialized (see _layer_scan docstring).
-        if cfg.is_mla:
-            # Each latent page is read ONCE, as key and as value.
+            # Absorbed form: each latent page is read ONCE, as key and value.
             return mla_absorbed(
-                lp, cfg, q, k, lambda qa, rows: paged_decode_attention(
+                lp, cfg, q, k, lambda qa, rows: kernels.decode_attention(
                     qa, kv.k, None, meta.page_tables, meta.context_lens,
-                    rows, None, scale, layer=layer_idx,
-                    use_pallas=use_pallas))
-        if attn_mesh is not None:
-            return paged_decode_attention_tp(attn_mesh, q, kv.k, kv.v,
-                                             meta.page_tables,
-                                             meta.context_lens, k, v, scale,
-                                             layer=layer_idx)
-        return paged_decode_attention(q, kv.k, kv.v, meta.page_tables,
-                                      meta.context_lens, k, v, scale,
-                                      layer=layer_idx, use_pallas=use_pallas)
+                    rows, None, scale, layer=layer_idx))
+        return kernels.decode_attention(
+            q, kv.k, kv.v, meta.page_tables, meta.context_lens, k, v, scale,
+            layer=layer_idx)
+
+    def attn_fn(lp, q, k, v, layer_idx):
+        if not n_rows:
+            return segment_attn(lp, q, k, v, meta.seg_ids, meta.positions,
+                                layer_idx)
+        if not n_seg:
+            return row_attn(lp, q, k, v, layer_idx)
+        # v is None for a latent model (k is its cache row).
+        qs, ks, vs = (a if a is None else a[:n_seg] for a in (q, k, v))
+        qr, kr, vr = (a if a is None else a[n_seg:] for a in (q, k, v))
+        return jnp.concatenate(
+            [segment_attn(lp, qs, ks, vs, meta.seg_ids[:n_seg],
+                          meta.positions[:n_seg], layer_idx),
+             row_attn(lp, qr, kr, vr, layer_idx)], axis=0)
 
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  layer_slice, tp_axis=tp_axis, ep_axis=ep_axis,
-                                  grouped_experts=grouped_experts)
-    new_kv = KVCache(*write_kv_pages_all(kv.k, kv.v, k_all, v_all,
-                                         meta.slot_mapping,
-                                         use_pallas=use_pallas,
-                                         mesh=attn_mesh))
-    return _norm(cfg, h, params, "final_norm"), new_kv, h
+                                  kernels, tp_axis=tp_axis, ep_axis=ep_axis,
+                                  moe_load=moe_load)
+    new_kv = KVCache(*kernels.write_pages(kv.k, kv.v, k_all, v_all,
+                                          meta.slot_mapping))
+    selected = h if meta.logits_indices is None else h[meta.logits_indices]
+    return _norm(cfg, selected, params, "final_norm"), new_kv, h
 
 
 def compute_logits(params: Params, cfg: ModelConfig, hidden: jax.Array,
-                   use_pallas: Optional[bool] = None) -> jax.Array:
-    """hidden [B, d] -> logits [B, V] in fp32. ``use_pallas`` reaches the
-    dequant-fused int4 head matmul (same tri-state as the attention
-    kernels: None = auto by backend, False = the XLA kill-switch)."""
+                   kernels: Kernels = NO_KERNELS) -> jax.Array:
+    """hidden [B, d] -> logits [B, V] in fp32. ``kernels`` reaches the int4
+    head matmul by the one rule of ``Kernels.int4_pallas``."""
     if cfg.tie_word_embeddings:
         return jnp.dot(hidden, params["embed"].T,
                        preferred_element_type=jnp.float32)
-    return _dot(hidden, params, "lm_head", use_pallas)
+    return _dot(hidden, params, "lm_head", kernels.int4_pallas)

@@ -1,7 +1,3 @@
 from .rope import apply_rope, rope_cos_sin  # noqa: F401
-from .attention import (  # noqa: F401
-    write_kv_pages_all,
-    paged_decode_attention,
-    ragged_prefill_attention,
-)
+from .attention import NO_KERNELS, Kernels  # noqa: F401
 from .sampling import sample_tokens  # noqa: F401

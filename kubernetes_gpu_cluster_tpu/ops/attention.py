@@ -12,33 +12,33 @@ Pallas/XLA custom-calls"):
   and are addressed through per-sequence page tables.
 
 This module holds the pure-XLA reference implementations (correct everywhere,
-used on CPU meshes and as the numerical oracle in tests) and the dispatchers
-that select the Pallas TPU kernels from ``ops.pallas`` when running on TPU.
+used on CPU meshes and as the numerical oracle in tests), the shard_map
+wrappers that run a Pallas kernel per shard under a tp mesh, and
+:class:`Kernels`: the ONE place where reference, kernel or per-shard kernel
+is chosen, from a value the engine decides at start-up.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional
+import dataclasses
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
 # KV page writes
 # ---------------------------------------------------------------------------
 
-def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
-                       k_all: jax.Array, v_all: jax.Array,
-                       slot_mapping: jax.Array, *,
-                       use_pallas: Optional[bool] = None,
-                       mesh=None) -> tuple[jax.Array, jax.Array]:
-    """Write every layer's new K/V vectors into the page pool at once.
+def write_kv_pages_all_xla(kv_k: jax.Array, kv_v: Optional[jax.Array],
+                           k_all: jax.Array, v_all: Optional[jax.Array],
+                           slot_mapping: jax.Array
+                           ) -> tuple[jax.Array, Optional[jax.Array]]:
+    """Write every layer's new K/V vectors into the page pool at once: the
+    plain reference (CPU, tests) that the Pallas DMA kernel
+    (``ops.pallas.kv_write``) is held to bitwise. :meth:`Kernels.write_pages`
+    chooses between them.
 
     kv_k/kv_v:    [L, P, page_size, n_kv*hd] (the whole pool, heads flattened)
     k_all/v_all:  [L, T, n_kv*hd] (stacked per-layer new entries, heads
@@ -54,16 +54,7 @@ def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
     weights leave free, so a single pool-sized temporary is an OOM, not a
     slowdown. Threading the pool through the scan as carry/ys forces a full
     pool copy per step; attention instead reads the pool pre-write and takes
-    the current token's K/V separately (see paged_decode_attention).
-
-    Dispatch, as for the attention kernels: ``use_pallas=True`` means the
-    Pallas DMA kernel (``ops.pallas.kv_write``: every tile's
-    read-modify-write of a block in flight at once, the pool aliased to the
-    result) or its exception, never a fallback; None = the kernel on TPU.
-    ``mesh``: under a GSPMD tp mesh the pool is sharded on its lane dim and
-    the kernel runs per shard (:func:`write_kv_pages_all_tp`). False (CPU,
-    tests) is the XLA loop below, the plain reference the kernel is held to
-    bitwise.
+    the current token's K/V separately (see paged_decode_attention_xla).
 
     The loop is one formulation for every T: a fori_loop of per-token
     dynamic_update_slices, which XLA performs in place in the pool's own
@@ -76,14 +67,6 @@ def write_kv_pages_all(kv_k: jax.Array, kv_v: jax.Array,
     ``copy.44 = bf16[36,54016,1024]{2,0,1}``, 4.12 GB, compile-time OOM in
     the first chunked-prefill step of qwen3-4b).
     """
-    if mesh is not None:
-        return write_kv_pages_all_tp(mesh, kv_k, kv_v, k_all, v_all,
-                                     slot_mapping)
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
-        from .pallas.kv_write import kv_write
-        return kv_write(kv_k, kv_v, k_all, v_all, slot_mapping)
     # One loop over the pools there are: K and V, or a latent-attention
     # model's one pool of rows [c | k_pe | pad].
     pools = (kv_k,) if kv_v is None else (kv_k, kv_v)
@@ -219,7 +202,7 @@ def paged_decode_attention_xla(
 
     The pool holds positions 0..context_len-2; the current token's K/V arrive
     separately because pool writes are deferred to one post-scan write
-    (write_kv_pages_all: a DMA kernel on the chip, a loop of row updates
+    (Kernels.write_pages: a DMA kernel on the chip, a loop of row updates
     here). The gather materializes [B, pages_per_seq*page_size]
     worth of K/V — HBM-bandwidth-bound, which is what the Pallas kernel
     (pallas_paged_decode) avoids by streaming only valid pages through VMEM
@@ -284,8 +267,8 @@ def spec_verify_attention_xla(
     XLA implementation — correct everywhere, GSPMD-partitionable under tp
     meshes (heads shard like the other reference paths). A Pallas kernel
     (streaming only valid pages, S queries per DMA block) is the natural
-    upgrade once spec decode is TPU-bench-proven; the dispatcher below
-    keeps the seam.
+    upgrade once spec decode is TPU-bench-proven
+    (:meth:`Kernels.verify_attention` is its seam).
     """
     if layer is not None and k_pool.ndim == 4:
         k_pool = jax.lax.dynamic_index_in_dim(k_pool, layer, 0, keepdims=False)
@@ -324,199 +307,6 @@ def spec_verify_attention_xla(
     return out.reshape(T, n_heads, hd).astype(q.dtype)
 
 
-def spec_verify_attention(q, k, v, k_pool, v_pool, page_tables, context_lens,
-                          scale, *, layer=None, use_pallas=None):
-    """Spec-verify dispatcher. No Pallas kernel exists yet — every backend
-    takes the XLA path (on TPU it runs as plain XLA inside the jitted step,
-    exactly like chunked-prefill history attention did before its kernel
-    landed; under a GSPMD tp mesh the partitioner shards it over heads).
-    ``use_pallas`` is accepted so the call sites are already wired for the
-    kernel when it lands."""
-    del use_pallas
-    return spec_verify_attention_xla(q, k, v, k_pool, v_pool, page_tables,
-                                     context_lens, scale, layer=layer)
-
-
-# ---------------------------------------------------------------------------
-# Dispatchers (Pallas on TPU, XLA elsewhere)
-# ---------------------------------------------------------------------------
-
-def ragged_prefill_attention(q, k, v, seg_ids, positions, scale, *,
-                             use_pallas=None):
-    """``use_pallas=True`` means the kernel or its exception — there is no
-    XLA fallback behind it (a swallowed kernel failure once put XLA gather
-    attention into the record as the system). None = Pallas on TPU."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
-        from .pallas.flash_prefill import flash_ragged_prefill
-        return flash_ragged_prefill(q, k, v, seg_ids, positions, scale)
-    return ragged_prefill_attention_xla(q, k, v, seg_ids, positions, scale)
-
-
-def prefill_history_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
-                              page_table, hist_len, scale, *, layer=None,
-                              use_pallas=None):
-    """Chunked-prefill dispatcher: Pallas flash kernel on TPU (streams only
-    the valid history pages), XLA gather elsewhere. Single-device /
-    shard_map-manual paths only — GSPMD tp meshes use
-    :func:`prefill_history_attention_tp`; pp meshes keep the XLA path
-    (the pool's layer axis is pp-sharded, outside the tp wrapper's specs)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas and v_pool is None:
-        from .pallas.flash_prefill_hist import flash_prefill_history_shared
-        return flash_prefill_history_shared(q, k, seg_ids, positions, k_pool,
-                                            page_table, hist_len, scale,
-                                            layer=layer)
-    if use_pallas:
-        from .pallas.flash_prefill_hist import flash_prefill_history
-        return flash_prefill_history(q, k, v, seg_ids, positions,
-                                     k_pool, v_pool, page_table,
-                                     hist_len, scale, layer=layer)
-    return prefill_history_attention_xla(q, k, v, seg_ids, positions,
-                                         k_pool, v_pool, page_table,
-                                         hist_len, scale, layer=layer)
-
-
-def paged_decode_attention(q, k_cache_l, v_cache_l, page_tables, context_lens,
-                           k_cur, v_cur, scale, *, layer=None,
-                           use_pallas=None):
-    """``layer`` (with a stacked [L, P, ps, n_kv*hd] pool) lets the Pallas
-    kernel address the pool with a dynamic layer index instead of the caller
-    slicing a per-layer copy out — the zero-copy path the decode scan uses.
-
-    The K-and-V kernel takes no setting: its chunk's size comes from the
-    pool's lane width and dtype and its stream's depth is a constant
-    (``ops.pallas.paged_decode``: what was measured, and at which
-    geometry)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas and v_cache_l is None:
-        # One pool whose row is key AND value (latent attention as
-        # multi-query attention): a kernel of its own, each page read once.
-        from .pallas.latent_decode import latent_paged_decode
-        return latent_paged_decode(q, k_cache_l, page_tables, context_lens,
-                                   k_cur, scale, layer=layer)
-    if use_pallas:
-        from .pallas.paged_decode import pallas_paged_decode
-        return pallas_paged_decode(q, k_cache_l, v_cache_l, page_tables,
-                                   context_lens, k_cur, v_cur, scale,
-                                   layer=layer)
-    return paged_decode_attention_xla(q, k_cache_l, v_cache_l, page_tables,
-                                      context_lens, k_cur, v_cur, scale,
-                                      layer=layer)
-
-
-# ---------------------------------------------------------------------------
-# Mixed prefill/decode attention (stall-free batching)
-# ---------------------------------------------------------------------------
-
-def mixed_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
-                    chunk_page_table, hist_len, page_tables, context_lens,
-                    scale, *, n_prefill, layer=None, use_pallas=None,
-                    use_pallas_hist=None, attn_mesh=None):
-    """Attention for one MIXED step: the token axis is
-    ``[prefill chunk | decode rows]`` with a STATIC split at ``n_prefill``
-    (derived from padded bucket shapes, so it resolves at trace time and the
-    compile count stays bounded by the bucket grid).
-
-    - tokens [0:n_prefill): one sequence's prompt chunk — causal within the
-      chunk plus full attention to its committed pool history
-      (``prefill_history_attention``; Pallas flash-history kernel on TPU).
-    - tokens [n_prefill:): one decode token per running sequence against the
-      paged pool (``paged_decode_attention``; Pallas paged-decode kernel on
-      TPU).
-
-    Both halves read the pool PRE-write (this step's K/V fold in directly:
-    the chunk's in-batch, each decode row's as k_cur/v_cur) and the caller
-    commits all new K/V in the one post-scan write — the same contract as
-    the pure paths, so no new kernel is needed: prefill segments route
-    through the flash-prefill-history kernel and decode rows through paged
-    decode within one dispatched step. Chunk and decode sequences are
-    disjoint and each half only addresses its own page tables, so no
-    cross-attention between the halves is possible by construction.
-
-    ``attn_mesh``: under a GSPMD tp mesh both halves run per-shard through
-    the existing shard_map wrappers. ``use_pallas_hist`` gates the history
-    kernel independently (mirrors LLMEngine.use_pallas_hist).
-    """
-    qp, kp, vp = q[:n_prefill], k[:n_prefill], v[:n_prefill]
-    qd, kd, vd = q[n_prefill:], k[n_prefill:], v[n_prefill:]
-    segp, posp = seg_ids[:n_prefill], positions[:n_prefill]
-    # The two halves gate their kernels INDEPENDENTLY, mirroring the pure
-    # paths: where the history kernel is ineligible (use_pallas_hist False
-    # while use_pallas stays True) the chunk half runs plain XLA —
-    # GSPMD-partitionable under a tp mesh — while decode keeps its kernel.
-    if attn_mesh is not None and use_pallas_hist:
-        out_p = prefill_history_attention_tp(
-            attn_mesh, qp, kp, vp, segp, posp, k_pool, v_pool,
-            chunk_page_table[0], hist_len, scale, layer=layer)
-    else:
-        out_p = prefill_history_attention(
-            qp, kp, vp, segp, posp, k_pool, v_pool, chunk_page_table[0],
-            hist_len, scale, layer=layer,
-            use_pallas=use_pallas_hist if attn_mesh is None else False)
-    if attn_mesh is not None:
-        out_d = paged_decode_attention_tp(
-            attn_mesh, qd, k_pool, v_pool, page_tables, context_lens,
-            kd, vd, scale, layer=layer)
-    else:
-        out_d = paged_decode_attention(
-            qd, k_pool, v_pool, page_tables, context_lens, kd, vd, scale,
-            layer=layer, use_pallas=use_pallas)
-    return jnp.concatenate([out_p, out_d], axis=0)
-
-
-def spec_mixed_attention(q, k, v, seg_ids, positions, k_pool, v_pool,
-                         chunk_page_table, hist_len, page_tables,
-                         context_lens, scale, *, n_prefill, layer=None,
-                         use_pallas=None, use_pallas_hist=None,
-                         attn_mesh=None):
-    """Attention for one SPEC×MIXED step: the token axis is
-    ``[prefill chunk | verify slices]`` with a STATIC split at
-    ``n_prefill`` (derived from padded bucket shapes plus the
-    config-static slice width S, so it resolves at trace time).
-
-    - tokens [0:n_prefill): one sequence's prompt chunk — exactly the
-      mixed path's chunk half (``prefill_history_attention``; chunk tokens
-      carry seg 0, padding -1).
-    - tokens [n_prefill:): every running sequence's ``[last, d_1..d_k]``
-      verify slice against the paged pool (``spec_verify_attention``:
-      identical semantics to the pure spec step).
-
-    Both halves read the pool PRE-write and the caller commits all new K/V
-    (chunk AND draft slots) in the one post-scan write — the same
-    contract as every other path, so the composition needs no new kernel:
-    it routes each half through the op the pure paths already use. Chunk
-    and verify sequences are disjoint and each half addresses only its own
-    page tables, so cross-attention between the halves is impossible by
-    construction."""
-    qp, kp, vp = q[:n_prefill], k[:n_prefill], v[:n_prefill]
-    qs, ks, vs = q[n_prefill:], k[n_prefill:], v[n_prefill:]
-    # The chunk half's segment view: seg 0 on chunk tokens, -1 elsewhere
-    # (the flat batch carries row ids on the verify slices for the
-    # sanitizer's slot map — the chunk kernel must not see them).
-    segp = jnp.where(seg_ids[:n_prefill] >= 0, 0, -1)
-    posp = positions[:n_prefill]
-    if attn_mesh is not None and use_pallas_hist:
-        out_p = prefill_history_attention_tp(
-            attn_mesh, qp, kp, vp, segp, posp, k_pool, v_pool,
-            chunk_page_table[0], hist_len, scale, layer=layer)
-    else:
-        out_p = prefill_history_attention(
-            qp, kp, vp, segp, posp, k_pool, v_pool, chunk_page_table[0],
-            hist_len, scale, layer=layer,
-            use_pallas=use_pallas_hist if attn_mesh is None else False)
-    # Verify half: XLA path everywhere today (GSPMD-partitionable over
-    # heads under a tp mesh), the same dispatcher seam as the pure spec
-    # step — a Pallas kernel lands behind it without touching this split.
-    out_s = spec_verify_attention(
-        qs, ks, vs, k_pool, v_pool, page_tables, context_lens, scale,
-        layer=layer, use_pallas=use_pallas)
-    return jnp.concatenate([out_p, out_s], axis=0)
-
-
 # ---------------------------------------------------------------------------
 # Tensor-parallel wrappers: Pallas kernels under a GSPMD mesh via shard_map
 # ---------------------------------------------------------------------------
@@ -535,7 +325,7 @@ def paged_decode_attention_tp(mesh, q, k_cache_l, v_cache_l, page_tables,
                               context_lens, k_cur, v_cur, scale, *,
                               layer=None, interpret=False):
     """shard_map-wrapped pallas_paged_decode over ``mesh``'s tp axis.
-    Shapes/semantics match paged_decode_attention; ``interpret=True`` runs
+    Shapes/semantics match paged_decode_attention_xla; ``interpret=True`` runs
     the kernel in interpret mode (CPU-mesh parity tests). Each shard's
     kernel sizes its chunks from ITS lane width (a quarter of the pool's
     under tp=4: 256 tokens a chunk where one chip takes 128)."""
@@ -628,3 +418,149 @@ def ragged_prefill_attention_tp(mesh, q, k, v, seg_ids, positions, scale, *,
         body, mesh=mesh,
         in_specs=(head_spec, head_spec, head_spec, P(), P()),
         out_specs=head_spec, check_vma=False)(q, k, v, seg_ids, positions)
+
+
+# ---------------------------------------------------------------------------
+# The choice of kernel: one value, decided once, routing five operations
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Kernels:
+    """What the engine decides ONCE, at construction, about how the five
+    operations a forward pass needs are carried out
+    (``LLMEngine._resolve_use_pallas`` builds it, proves every kernel it
+    names by compiling it, and hands it to every step program); nothing
+    below the engine decides again. The default is the XLA references
+    everywhere: what a CPU run, a test oracle and the draft model use.
+
+    ``use_pallas=True`` means the Pallas kernel or its exception, never a
+    fallback: a swallowed kernel failure once put XLA gather attention into
+    the record as the system. A latent-attention pool (``v_pool is None``:
+    one pool whose row is key AND value) takes the kernels of its own; its
+    row is one shared head with no kv-head axis to shard, so it never takes
+    a per-shard wrapper.
+    """
+    use_pallas: bool = False
+    # The chunk-with-history kernel's own eligibility: under pp the pool's
+    # layer axis is sharded outside the tp wrapper's specs, under sp the
+    # wrapper would replicate the chunk's attention across the sp group.
+    use_pallas_hist: bool = False
+    # The GSPMD mesh whose ``tp`` axis the kernels run per shard under
+    # (the ``*_tp`` shard_map wrappers above). None on one device, inside
+    # the pipeline's own shard_map, and wherever the kernels are off.
+    tp_mesh: Any = None
+    # ``fn(q, k, v, seg_ids, positions) -> out`` that replaces fresh-prompt
+    # attention: ring attention over an sp mesh (parallel/sp.py).
+    ring_prefill: Optional[Callable] = None
+    # The expert tensors lie whole on one device, so the grouped expert
+    # matmuls (a custom call with no partitioning rule) may be handed the
+    # stack as it is (``models.llama._moe_mlp``).
+    grouped_experts: bool = False
+
+    def xla_only(self) -> "Kernels":
+        """The same deployment with every kernel off."""
+        return dataclasses.replace(self, use_pallas=False,
+                                   use_pallas_hist=False, tp_mesh=None)
+
+    @property
+    def int4_pallas(self) -> Optional[bool]:
+        """What the int4 consumer (``models.llama._dot`` ->
+        ``ops.quant.int4_matmul``) is told: False where the kernels are off
+        (the engine's kill-switch forces XLA), else None, which leaves the
+        decision to ``int4_matmul``'s own documented opt-in
+        (``KGCT_INT4_PALLAS=1`` on a TPU). Never True: that kernel has no
+        per-shard wrapper and no chip run behind it."""
+        return None if self.use_pallas else False
+
+    def prefill_attention(self, q, k, v, seg_ids, positions, scale):
+        """Fresh prompt tokens attending each other, causal within a
+        segment: ring attention under sp, else the flash kernel (per shard
+        under a tp mesh) or the dense reference."""
+        if self.ring_prefill is not None:
+            return self.ring_prefill(q, k, v, seg_ids, positions)
+        if not self.use_pallas:
+            return ragged_prefill_attention_xla(q, k, v, seg_ids, positions,
+                                                scale)
+        if self.tp_mesh is not None:
+            return ragged_prefill_attention_tp(self.tp_mesh, q, k, v, seg_ids,
+                                               positions, scale)
+        from .pallas.flash_prefill import flash_ragged_prefill
+        return flash_ragged_prefill(q, k, v, seg_ids, positions, scale)
+
+    def chunk_attention(self, q, k, v, seg_ids, positions, k_pool, v_pool,
+                        page_table, hist_len, scale, *, layer=None):
+        """One sequence's prompt chunk attending to its history in the pool
+        plus itself causally. Gated by ``use_pallas_hist`` alone: where the
+        history kernel is ineligible the chunk runs the XLA reference
+        (which GSPMD partitions under any mesh) while the other operations
+        keep their kernels."""
+        if not self.use_pallas_hist:
+            return prefill_history_attention_xla(
+                q, k, v, seg_ids, positions, k_pool, v_pool, page_table,
+                hist_len, scale, layer=layer)
+        if v_pool is None:
+            from .pallas.flash_prefill_hist import (
+                flash_prefill_history_shared)
+            return flash_prefill_history_shared(
+                q, k, seg_ids, positions, k_pool, page_table, hist_len,
+                scale, layer=layer)
+        if self.tp_mesh is not None:
+            return prefill_history_attention_tp(
+                self.tp_mesh, q, k, v, seg_ids, positions, k_pool, v_pool,
+                page_table, hist_len, scale, layer=layer)
+        from .pallas.flash_prefill_hist import flash_prefill_history
+        return flash_prefill_history(q, k, v, seg_ids, positions, k_pool,
+                                     v_pool, page_table, hist_len, scale,
+                                     layer=layer)
+
+    def decode_attention(self, q, k_pool, v_pool, page_tables, context_lens,
+                         k_cur, v_cur, scale, *, layer=None):
+        """One query token a sequence against its pages. ``layer`` (with a
+        stacked [L, P, ps, n_kv*hd] pool) lets the kernel address the pool
+        with a dynamic layer index instead of the caller slicing a
+        per-layer copy out: the zero-copy path the decode scan uses. The
+        K-and-V kernel takes no setting: its chunk's size comes from the
+        pool's lane width and dtype and its stream's depth is a constant
+        (``ops.pallas.paged_decode``: what was measured, and at which
+        geometry)."""
+        if not self.use_pallas:
+            return paged_decode_attention_xla(
+                q, k_pool, v_pool, page_tables, context_lens, k_cur, v_cur,
+                scale, layer=layer)
+        if v_pool is None:      # each latent page read once, as key and value
+            from .pallas.latent_decode import latent_paged_decode
+            return latent_paged_decode(q, k_pool, page_tables, context_lens,
+                                       k_cur, scale, layer=layer)
+        if self.tp_mesh is not None:
+            return paged_decode_attention_tp(
+                self.tp_mesh, q, k_pool, v_pool, page_tables, context_lens,
+                k_cur, v_cur, scale, layer=layer)
+        from .pallas.paged_decode import pallas_paged_decode
+        return pallas_paged_decode(q, k_pool, v_pool, page_tables,
+                                   context_lens, k_cur, v_cur, scale,
+                                   layer=layer)
+
+    def verify_attention(self, q, k, v, k_pool, v_pool, page_tables,
+                         context_lens, scale, *, layer=None):
+        """S = k+1 query tokens a sequence (draft verification). No kernel
+        exists: every backend takes the XLA reference (under a tp mesh the
+        partitioner shards it over heads)."""
+        return spec_verify_attention_xla(q, k, v, k_pool, v_pool,
+                                         page_tables, context_lens, scale,
+                                         layer=layer)
+
+    def write_pages(self, kv_k, kv_v, k_all, v_all, slot_mapping):
+        """The step's new rows into the donated pool, in place: the DMA
+        kernel (per shard under a tp mesh, the pool sharded on its lane
+        dim) or the XLA loop."""
+        if not self.use_pallas:
+            return write_kv_pages_all_xla(kv_k, kv_v, k_all, v_all,
+                                          slot_mapping)
+        if self.tp_mesh is not None and kv_v is not None:
+            return write_kv_pages_all_tp(self.tp_mesh, kv_k, kv_v, k_all,
+                                         v_all, slot_mapping)
+        from .pallas.kv_write import kv_write
+        return kv_write(kv_k, kv_v, k_all, v_all, slot_mapping)
+
+
+NO_KERNELS = Kernels()

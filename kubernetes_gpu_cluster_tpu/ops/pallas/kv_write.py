@@ -1,7 +1,7 @@
 """The post-scan KV page write as a Pallas TPU kernel: the donated pool is
 updated in place by DMA, every touched tile of a block in flight at once.
 
-What it replaces on the chip: ``ops.attention.write_kv_pages_all``'s XLA
+What it replaces on the chip: ``ops.attention.write_kv_pages_all_xla``'s XLA
 loop, T ``dynamic_update_slice``s of ``[L, 1, kd]`` each waiting for the one
 before (5.5 us apiece on the v5e at L=36, kd=1024: 0.70 ms of an 18.6 ms
 decode step at T=64 to move 9.4 MB; PERF.md section 6, PR 25). The loop

@@ -22,7 +22,6 @@ end with a psum over ``pp``.
 from __future__ import annotations
 
 from functools import partial
-from typing import Union
 
 import jax
 import jax.numpy as jnp
@@ -31,9 +30,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..config import ModelConfig
 from ..engine.kv_cache import KVCache
 from ..models import llama as model_lib
-from ..models.llama import DecodeMeta, PrefillMeta
-
-Meta = Union[PrefillMeta, DecodeMeta]
+from ..models.llama import StepMeta
+from ..ops.attention import NO_KERNELS, Kernels
 
 
 def _layer_specs(cfg: ModelConfig) -> dict:
@@ -163,20 +161,22 @@ def validate_pp_mesh(mesh: Mesh, cfg: ModelConfig) -> None:
         raise ValueError(f"num_experts={cfg.num_experts} not divisible by ep={ep}")
 
 
-def build_pp_mapped(mesh: Mesh, cfg: ModelConfig, kind: str, use_pallas=None):
+def build_pp_mapped(mesh: Mesh, cfg: ModelConfig, kind: str,
+                    kernels: Kernels = NO_KERNELS):
     """The un-jitted shard_map pipeline: ``mapped(params, kv_k, kv_v,
     tokens_mb, meta_mb) -> (hidden_mb [M, N, d], kv_k, kv_v)``. Composable
     inside a larger jitted program — the engine's decode window wraps it in
     its substep scan (sampling stays outside the shard_map, where params'
-    replicated final_norm/lm_head make logits a plain GSPMD matmul)."""
+    replicated final_norm/lm_head make logits a plain GSPMD matmul).
+    ``kernels``: the engine's choice; inside the shard_map body a kernel
+    runs at the stage's per-shard geometry (``tp_mesh`` is None under pp)."""
     assert kind in ("prefill", "decode", "prefill_hist")
     validate_pp_mesh(mesh, cfg)
     S = mesh.shape["pp"]
     perm = [(i, (i + 1) % S) for i in range(S)]
-    fwd = model_lib.forward_prefill if kind == "prefill" else model_lib.forward_decode
 
     if kind == "prefill_hist":
-        return _build_pp_hist_mapped(mesh, cfg, S, perm, use_pallas)
+        return _build_pp_hist_mapped(mesh, cfg, S, perm, kernels)
 
     def local_fn(params, kv_k, kv_v, tokens_mb, meta_mb):
         rank = jax.lax.axis_index("pp")
@@ -192,11 +192,11 @@ def build_pp_mapped(mesh: Mesh, cfg: ModelConfig, kind: str, use_pallas=None):
             # Inactive ticks write their K/V into the scrap page (slot 0).
             slots = jnp.where(active, meta_mb.slot_mapping[mb], 0)
             if kind == "prefill":
-                meta = PrefillMeta(
+                meta = StepMeta(
                     seg_ids=meta_mb.seg_ids[mb], positions=meta_mb.positions[mb],
                     slot_mapping=slots, logits_indices=meta_mb.logits_indices[mb])
             else:
-                meta = DecodeMeta(
+                meta = StepMeta(
                     positions=meta_mb.positions[mb], slot_mapping=slots,
                     page_tables=meta_mb.page_tables[mb],
                     context_lens=meta_mb.context_lens[mb])
@@ -204,10 +204,9 @@ def build_pp_mapped(mesh: Mesh, cfg: ModelConfig, kind: str, use_pallas=None):
                 rank == 0,
                 model_lib._embed(params, cfg, tokens,
                                  meta.positions).astype(dtype), buf)
-            _, kv_new, h_out = fwd(
-                params, cfg, tokens, meta, KVCache(k=kvk, v=kvv),
-                use_pallas=use_pallas, hidden_in=h_in,
-                tp_axis="tp", ep_axis="ep")
+            _, kv_new, h_out = model_lib.forward(
+                params, cfg, tokens, meta, KVCache(k=kvk, v=kvv), kernels,
+                hidden_in=h_in, tp_axis="tp", ep_axis="ep")
             contrib = jnp.where(jnp.logical_and(rank == S - 1, active),
                                 h_out, jnp.zeros_like(h_out))
             outputs = outputs.at[mb].add(contrib)
@@ -222,24 +221,18 @@ def build_pp_mapped(mesh: Mesh, cfg: ModelConfig, kind: str, use_pallas=None):
         outputs = jax.lax.psum(outputs, "pp")
         return outputs, kvk, kvv
 
-    if kind == "prefill":
-        meta_specs = PrefillMeta(seg_ids=P(), positions=P(),
-                                 slot_mapping=P(), logits_indices=P())
-    else:
-        meta_specs = DecodeMeta(positions=P(), slot_mapping=P(),
-                                page_tables=P(), context_lens=P())
-
+    # P() for the whole meta: every field it carries is replicated.
     return jax.shard_map(
         local_fn,
         mesh=mesh,
-        in_specs=(param_pp_specs(cfg), KV_PP_SPEC, KV_PP_SPEC, P(), meta_specs),
+        in_specs=(param_pp_specs(cfg), KV_PP_SPEC, KV_PP_SPEC, P(), P()),
         out_specs=(P(), KV_PP_SPEC, KV_PP_SPEC),
         check_vma=False,
     )
 
 
 def _build_pp_hist_mapped(mesh: Mesh, cfg: ModelConfig, S: int, perm,
-                          use_pallas):
+                          kernels: Kernels):
     """Pipelined CHUNKED prefill (VERDICT r4 #6: the history path used to
     run as plain GSPMD, making XLA all-gather the pp-sharded layer stack on
     every long-prompt chunk). The chunk is split into M sub-chunk
@@ -265,16 +258,16 @@ def _build_pp_hist_mapped(mesh: Mesh, cfg: ModelConfig, S: int, perm,
             active = jnp.logical_and(t - rank >= 0, t - rank < M)
             tokens = tokens_mb[mb]
             slots = jnp.where(active, meta_mb.slot_mapping[mb], 0)
-            meta = PrefillMeta(
+            meta = StepMeta(
                 seg_ids=meta_mb.seg_ids[mb], positions=meta_mb.positions[mb],
-                slot_mapping=slots, logits_indices=meta_mb.logits_indices[mb])
+                slot_mapping=slots, logits_indices=meta_mb.logits_indices[mb],
+                chunk_page_table=page_table, hist_len=hist_lens[mb])
             h_in = jnp.where(
                 rank == 0,
                 model_lib._embed(params, cfg, tokens,
                                  meta.positions).astype(dtype), buf)
-            _, kv_new, h_out = model_lib.forward_prefill_hist(
-                params, cfg, tokens, meta, KVCache(k=kvk, v=kvv),
-                page_table, hist_lens[mb], use_pallas=use_pallas,
+            _, kv_new, h_out = model_lib.forward(
+                params, cfg, tokens, meta, KVCache(k=kvk, v=kvv), kernels,
                 hidden_in=h_in, tp_axis="tp", ep_axis="ep")
             contrib = jnp.where(jnp.logical_and(rank == S - 1, active),
                                 h_out, jnp.zeros_like(h_out))
@@ -290,19 +283,18 @@ def _build_pp_hist_mapped(mesh: Mesh, cfg: ModelConfig, S: int, perm,
         outputs = jax.lax.psum(outputs, "pp")
         return outputs, kvk, kvv
 
-    meta_specs = PrefillMeta(seg_ids=P(), positions=P(),
-                             slot_mapping=P(), logits_indices=P())
     return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(param_pp_specs(cfg), KV_PP_SPEC, KV_PP_SPEC, P(),
-                  meta_specs, P(), P()),
+                  P(), P(), P()),
         out_specs=(P(), KV_PP_SPEC, KV_PP_SPEC),
         check_vma=False,
     )
 
 
-def build_pp_forward(mesh: Mesh, cfg: ModelConfig, kind: str, use_pallas=None):
+def build_pp_forward(mesh: Mesh, cfg: ModelConfig, kind: str,
+                     kernels: Kernels = NO_KERNELS):
     """Jitted standalone pipelined forward: ``fn(params, kv, tokens_mb,
     meta_mb) -> (hidden_mb, new_kv)`` where every meta field carries a leading
     microbatch axis ``[M, ...]`` and ``hidden_mb`` is the raw last-stage
@@ -310,7 +302,7 @@ def build_pp_forward(mesh: Mesh, cfg: ModelConfig, kind: str, use_pallas=None):
     for decode). The caller applies final-norm/logits/sampling (see
     :func:`pp_logits`). The serving engine uses :func:`build_pp_mapped`
     directly instead, fusing sampling into its step program."""
-    mapped = build_pp_mapped(mesh, cfg, kind, use_pallas=use_pallas)
+    mapped = build_pp_mapped(mesh, cfg, kind, kernels)
 
     @partial(jax.jit, donate_argnums=(1,))
     def fn(params, kv: KVCache, tokens_mb, meta_mb):
@@ -330,5 +322,4 @@ def pp_logits(params, cfg: ModelConfig, hidden: jax.Array,
     if logits_indices is not None:
         hidden = hidden[logits_indices]
     normed = model_lib._norm(cfg, hidden, params, "final_norm")
-    return model_lib.compute_logits(params, cfg, normed,
-                                    use_pallas=False)
+    return model_lib.compute_logits(params, cfg, normed)

@@ -18,7 +18,7 @@ framework-over-reference capability, TPU-first by construction:
   O((T/sp)^2) scores + O(T/sp) KV;
 - causal + segment masking works on GLOBAL positions/segment ids, which
   travel with their K/V block, so ragged multi-sequence prefill batches work
-  exactly like ops/attention.ragged_prefill_attention.
+  exactly like ops/attention.ragged_prefill_attention_xla.
 
 This is the blockwise/ring formulation of Liu et al.'s Ring Attention
 (arXiv:2310.01889) specialized to causal ragged serving prefill.

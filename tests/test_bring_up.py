@@ -177,7 +177,7 @@ class TestEngineBringUp:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(eng, "model_config",
                             get_model_config("debug-tiny"))   # kd = 64
-        assert eng._resolve_use_pallas(None) is False
+        assert eng._resolve_use_pallas(None).use_pallas is False
         assert "not 128-aligned" in eng.pallas_disabled_reason
         assert "pallas_disabled_reason" in eng.runtime_info()
 
@@ -253,36 +253,32 @@ def test_kv_write_is_one_formulation_for_every_flush_size(T):
     want_v = np.zeros_like(want_k)
     want_k[:, slots], want_v[:, slots] = k_rows, v_rows
     pool = jnp.zeros((L, P, ps, kd), jnp.float32)
-    k, v = jax.jit(attention.write_kv_pages_all, donate_argnums=(0, 1))(
+    k, v = jax.jit(attention.write_kv_pages_all_xla, donate_argnums=(0, 1))(
         pool, pool + 0, jnp.asarray(k_rows), jnp.asarray(v_rows),
         jnp.asarray(slots))
     np.testing.assert_array_equal(np.asarray(k).reshape(want_k.shape), want_k)
     np.testing.assert_array_equal(np.asarray(v).reshape(want_v.shape), want_v)
 
 
-_X = object()   # an operand the dispatcher only passes on
+_X = object()   # an operand the operation only passes on
 
 
-@pytest.mark.parametrize("dispatcher, kernel_module, kernel, args", [
-    (attention.paged_decode_attention, "paged_decode",
-     "pallas_paged_decode", [_X] * 8),
-    (attention.ragged_prefill_attention, "flash_prefill",
-     "flash_ragged_prefill", [_X] * 6),
-    (attention.prefill_history_attention, "flash_prefill_hist",
-     "flash_prefill_history", [_X] * 10),
-    (attention.write_kv_pages_all, "kv_write", "kv_write", [_X] * 5),
+@pytest.mark.parametrize("operation, kernel_module, kernel, args", [
+    ("decode_attention", "paged_decode", "pallas_paged_decode", [_X] * 8),
+    ("prefill_attention", "flash_prefill", "flash_ragged_prefill", [_X] * 6),
+    ("chunk_attention", "flash_prefill_hist", "flash_prefill_history",
+     [_X] * 10),
+    ("write_pages", "kv_write", "kv_write", [_X] * 5),
     # One pool of shared rows (latent attention: no V pool, no V rows): the
     # kernels of their own, by the same rule.
-    (attention.paged_decode_attention, "latent_decode",
-     "latent_paged_decode", [_X, _X, None, _X, _X, _X, None, _X]),
-    (attention.prefill_history_attention, "flash_prefill_hist",
-     "flash_prefill_history_shared",
+    ("decode_attention", "latent_decode", "latent_paged_decode",
+     [_X, _X, None, _X, _X, _X, None, _X]),
+    ("chunk_attention", "flash_prefill_hist", "flash_prefill_history_shared",
      [_X, _X, None, _X, _X, _X, None, _X, _X, _X]),
-    (attention.write_kv_pages_all, "kv_write", "kv_write",
-     [_X, None, _X, None, _X]),
+    ("write_pages", "kv_write", "kv_write", [_X, None, _X, None, _X]),
 ])
 def test_use_pallas_true_means_the_kernel_or_its_exception(
-        monkeypatch, dispatcher, kernel_module, kernel, args):
+        monkeypatch, operation, kernel_module, kernel, args):
     import importlib
     mod = importlib.import_module(
         f"kubernetes_gpu_cluster_tpu.ops.pallas.{kernel_module}")
@@ -290,5 +286,84 @@ def test_use_pallas_true_means_the_kernel_or_its_exception(
     def boom(*a, **k):
         raise NameError("name 'NBUF' is not defined")
     monkeypatch.setattr(mod, kernel, boom)
+    kernels = attention.Kernels(use_pallas=True, use_pallas_hist=True)
     with pytest.raises(NameError, match="NBUF"):
-        dispatcher(*args, use_pallas=True)
+        getattr(kernels, operation)(*args)
+
+
+# What each of the five operations reaches, K|V pool / latent pool, for
+# (kernels on, history kernel eligible, tp mesh) as the engine combines them
+# (LLMEngine._resolve_use_pallas). "xla": the reference; "kernel": the Pallas
+# kernel called directly; "tp": its shard_map wrapper; "shared"/"latent": the
+# one-pool kernels, which no wrapper shards (a latent row is one shared head).
+# Verify attention has no kernel. Fresh prompt tokens are met in the
+# materialised form, so their operation never sees a latent pool.
+_ALL_XLA = dict(prefill="xla", chunk="xla xla", decode="xla xla",
+                verify="xla", write="xla xla")
+_SELECTION = {
+    (True, True, False): dict(prefill="kernel", chunk="kernel shared",
+                              decode="kernel latent", verify="xla",
+                              write="kernel kernel"),
+    (True, False, False): dict(prefill="kernel", chunk="xla xla",
+                               decode="kernel latent", verify="xla",
+                               write="kernel kernel"),
+    (True, True, True): dict(prefill="tp", chunk="tp shared",
+                             decode="tp latent", verify="xla",
+                             write="tp kernel"),
+    (True, False, True): dict(prefill="tp", chunk="xla xla",
+                              decode="tp latent", verify="xla",
+                              write="tp kernel"),
+}
+
+
+@pytest.mark.parametrize("latent", [False, True], ids=["k|v", "latent"])
+@pytest.mark.parametrize("mesh", [False, True], ids=["one-device", "tp-mesh"])
+@pytest.mark.parametrize("eligible", [True, False],
+                         ids=["hist-eligible", "hist-ineligible"])
+@pytest.mark.parametrize("on", [True, False], ids=["kernels", "xla"])
+def test_kernel_selection_table(monkeypatch, on, eligible, mesh, latent):
+    """The ONE place that chooses reference, kernel or per-shard kernel
+    (ops.attention.Kernels), with every implementation replaced by a
+    sentinel: nothing compiles. With the kernels off every operation is the
+    reference whatever else holds."""
+    import importlib
+    for name, tag in (("ragged_prefill_attention_xla", "xla"),
+                      ("prefill_history_attention_xla", "xla"),
+                      ("paged_decode_attention_xla", "xla"),
+                      ("spec_verify_attention_xla", "xla"),
+                      ("write_kv_pages_all_xla", "xla"),
+                      ("ragged_prefill_attention_tp", "tp"),
+                      ("prefill_history_attention_tp", "tp"),
+                      ("paged_decode_attention_tp", "tp"),
+                      ("write_kv_pages_all_tp", "tp")):
+        monkeypatch.setattr(attention, name, lambda *a, _t=tag, **k: _t)
+    for module, name, tag in (
+            ("flash_prefill", "flash_ragged_prefill", "kernel"),
+            ("flash_prefill_hist", "flash_prefill_history", "kernel"),
+            ("flash_prefill_hist", "flash_prefill_history_shared", "shared"),
+            ("paged_decode", "pallas_paged_decode", "kernel"),
+            ("latent_decode", "latent_paged_decode", "latent"),
+            ("kv_write", "kv_write", "kernel")):
+        monkeypatch.setattr(
+            importlib.import_module(
+                f"kubernetes_gpu_cluster_tpu.ops.pallas.{module}"),
+            name, lambda *a, _t=tag, **k: _t)
+    kernels = attention.Kernels(
+        use_pallas=on, use_pallas_hist=on and eligible,
+        tp_mesh="the mesh" if on and mesh else None)
+    v = None if latent else _X      # the V pool, the V rows
+    got = dict(
+        prefill=kernels.prefill_attention(_X, _X, _X, _X, _X, _X),
+        chunk=kernels.chunk_attention(_X, _X, v, _X, _X, _X, v, _X, _X, _X),
+        decode=kernels.decode_attention(_X, _X, v, _X, _X, _X, v, _X),
+        verify=kernels.verify_attention(_X, _X, _X, _X, _X, _X, _X, _X),
+        write=kernels.write_pages(_X, v, _X, v, _X))
+    want = _SELECTION[(on, eligible, mesh)] if on else _ALL_XLA
+    assert got == {op: impl.split()[-1 if latent else 0]
+                   for op, impl in want.items()}
+    assert kernels.int4_pallas is (None if on else False)
+    # sp: ring attention replaces the fresh-prompt operation alone.
+    ring = attention.Kernels(use_pallas=on, ring_prefill=lambda *a: "ring")
+    assert ring.prefill_attention(_X, _X, _X, _X, _X, _X) == "ring"
+    assert ring.xla_only() == attention.Kernels(
+        ring_prefill=ring.ring_prefill)

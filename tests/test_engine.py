@@ -367,13 +367,13 @@ class TestLogprobs:
                        np.arange(T), 0).astype(np.int32)
         slots = np.where(np.arange(T) < len(prompt),
                          16 + np.arange(T), np.arange(T) % 16).astype(np.int32)
-        meta = model_lib.PrefillMeta(
+        meta = model_lib.StepMeta(
             seg_ids=jnp.asarray(seg), positions=jnp.asarray(pos),
             slot_mapping=jnp.asarray(slots),
             logits_indices=jnp.asarray([len(prompt) - 1], jnp.int32))
         kv = allocate_kv_cache(cfg.model, cfg.cache, 33)
-        hidden, _, _ = model_lib.forward_prefill(params, cfg.model,
-                                                 jnp.asarray(toks), meta, kv)
+        hidden, _, _ = model_lib.forward(params, cfg.model,
+                                         jnp.asarray(toks), meta, kv)
         logits = model_lib.compute_logits(params, cfg.model, hidden)[0]
         assert out.output_token_ids[0] == int(jnp.argmax(logits))
         ref_lp = float(jax.nn.log_softmax(logits)[out.output_token_ids[0]])

@@ -79,22 +79,21 @@ class TestServedAgainstReference:
         toks = _prompt(40, 1)
         T = 48
         ar = jnp.arange(T)
-        meta = llama.PrefillMeta(
+        meta = llama.StepMeta(
             seg_ids=jnp.where(ar < 40, 0, -1), positions=ar % 40,
             slot_mapping=jnp.where(ar < 40, 16 + ar, 0),
             logits_indices=jnp.array([39]))
-        hid, kv, _ = llama.forward_prefill(
+        hid, kv, _ = llama.forward(
             params, CFG, jnp.asarray(toks + [0] * 8), meta, kv)
         want = ref.forward(params, CFG, toks)
         np.testing.assert_allclose(llama.compute_logits(params, CFG, hid)[0],
                                    want[-1], atol=LOGIT_TOL)
         nxt = int(jnp.argmax(want[-1]))
-        dm = llama.DecodeMeta(
+        dm = llama.StepMeta(
             positions=jnp.array([40]), slot_mapping=jnp.array([16 + 40]),
             page_tables=jnp.array([[1, 2, 3, 4]]),
             context_lens=jnp.array([41]))
-        hid, kv, _ = llama.forward_decode(params, CFG, jnp.array([nxt]), dm,
-                                          kv)
+        hid, kv, _ = llama.forward(params, CFG, jnp.array([nxt]), dm, kv)
         np.testing.assert_allclose(
             llama.compute_logits(params, CFG, hid)[0],
             ref.forward(params, CFG, toks + [nxt])[-1], atol=LOGIT_TOL)
@@ -246,7 +245,8 @@ class TestRouterAndDispatch:
         T = llama.DENSE_DISPATCH_MAX_TOKENS + 44     # the grouped path
         x = jax.random.normal(jax.random.key(7), (T, CFG.hidden_size))
         load = []
-        got = llama._moe_mlp(lp, x, CFG, load_out=load, grouped=True)
+        got = llama._moe_mlp(lp, x, CFG, load_out=load,
+                             kernels=attention.Kernels(grouped_experts=True))
         assert np.asarray(load[0]).tolist() == [T] * k + [0] * (
             CFG.num_experts - k)
         idx, w = llama.moe_route(lp, x, CFG)
@@ -292,8 +292,8 @@ class TestLatentPages:
         rows = jax.random.normal(jax.random.key(2), (L, 11, R)).astype(dtype)
         slots = jnp.array([16, 17, 18, 40, 41, 0, 0, 0, 90, 91, 95],
                           jnp.int32)
-        want, none = attention.write_kv_pages_all(pool, None, rows, None,
-                                                  slots, use_pallas=False)
+        want, none = attention.write_kv_pages_all_xla(pool, None, rows, None,
+                                                      slots)
         got, none2 = kv_write(pool, None, rows, None, slots, interpret=True)
         assert none is None and none2 is None
         assert bool((want == got).all())        # bitwise

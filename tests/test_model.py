@@ -20,13 +20,13 @@ def _prefill_whole(cfg, params, token_ids, num_pages=64):
     pages = list(range(1, 1 + (n + PAGE - 1) // PAGE))
     pos = np.arange(n)
     slots = np.array([pages[p // PAGE] * PAGE + p % PAGE for p in pos], np.int32)
-    meta = M.PrefillMeta(
+    meta = M.StepMeta(
         seg_ids=jnp.zeros(n, jnp.int32),
         positions=jnp.asarray(pos, jnp.int32),
         slot_mapping=jnp.asarray(slots),
         logits_indices=jnp.array([n - 1], jnp.int32))
-    hidden, kv, _ = M.forward_prefill(params, cfg, jnp.asarray(token_ids, jnp.int32),
-                                      meta, kv, use_pallas=False)
+    hidden, kv, _ = M.forward(params, cfg, jnp.asarray(token_ids, jnp.int32),
+                              meta, kv)
     return M.compute_logits(params, cfg, hidden)[0], kv, pages
 
 
@@ -49,13 +49,13 @@ def test_decode_matches_prefill(model_name):
     n = len(prefix)
     if n % PAGE == 0:
         pages = pages + [max(pages) + 1]
-    dmeta = M.DecodeMeta(
+    dmeta = M.StepMeta(
         positions=jnp.array([n], jnp.int32),
         slot_mapping=jnp.array([pages[n // PAGE] * PAGE + n % PAGE], jnp.int32),
         page_tables=jnp.asarray([pages], jnp.int32),
         context_lens=jnp.array([n + 1], jnp.int32))
-    hidden, kv, _ = M.forward_decode(params, cfg, jnp.array([seq[-1]], jnp.int32),
-                                     dmeta, kv, use_pallas=False)
+    hidden, kv, _ = M.forward(params, cfg, jnp.array([seq[-1]], jnp.int32),
+                              dmeta, kv)
     decode_logits = M.compute_logits(params, cfg, hidden)[0]
 
     np.testing.assert_allclose(np.asarray(decode_logits), np.asarray(oracle_logits),
@@ -89,10 +89,9 @@ def test_ragged_prefill_isolation():
             slots[i] = (1 + s * 4 + p // PAGE) * PAGE + p % PAGE
             i += 1
         logits_idx.append(i - 1)
-    meta = M.PrefillMeta(jnp.asarray(seg), jnp.asarray(pos), jnp.asarray(slots),
+    meta = M.StepMeta(jnp.asarray(seg), jnp.asarray(pos), jnp.asarray(slots),
                          jnp.asarray(logits_idx, jnp.int32))
-    hidden, _, _ = M.forward_prefill(params, cfg, jnp.asarray(toks), meta, kv,
-                                     use_pallas=False)
+    hidden, _, _ = M.forward(params, cfg, jnp.asarray(toks), meta, kv)
     logits = M.compute_logits(params, cfg, hidden)
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(solo0), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(logits[1]), np.asarray(solo1), rtol=2e-4, atol=2e-4)

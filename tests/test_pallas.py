@@ -323,8 +323,8 @@ class TestPallasUnderMesh:
                                    rtol=2e-5, atol=2e-5)
 
     def test_engine_decode_via_attn_mesh(self):
-        """Full forward_decode with attn_mesh set (the engine's GSPMD + Pallas
-        path) must match the plain XLA forward. interpret-mode Pallas inside
+        """A full decode forward with the kernels on under a tp mesh (the
+        engine's GSPMD + Pallas path) must match the plain XLA forward. interpret-mode Pallas inside
         the real model forward, under jit, on the tp=2 mesh."""
         import functools
 
@@ -343,15 +343,14 @@ class TestPallasUnderMesh:
         kv = allocate_kv_cache(cfg, CacheConfig(page_size=8, num_pages=17), 17)
 
         B, pps = 2, 2
-        meta = model_lib.DecodeMeta(
+        meta = model_lib.StepMeta(
             positions=jnp.asarray([5, 3], jnp.int32),
             slot_mapping=jnp.asarray([1 * 8 + 5, 3 * 8 + 3], jnp.int32),
             page_tables=jnp.asarray([[1, 2], [3, 4]], jnp.int32),
             context_lens=jnp.asarray([6, 4], jnp.int32))
         tokens = jnp.asarray([7, 11], jnp.int32)
 
-        ref, ref_kv, _ = model_lib.forward_decode(params, cfg, tokens, meta,
-                                                  kv, use_pallas=False)
+        ref, ref_kv, _ = model_lib.forward(params, cfg, tokens, meta, kv)
 
         # Route the tp wrappers' kernels (attention and the post-scan KV
         # write) through interpret mode (CPU mesh).
@@ -360,7 +359,6 @@ class TestPallasUnderMesh:
         def tp_interp(mesh_, *a, **kw):
             return orig(mesh_, *a, **{**kw, "interpret": True})
         attn.paged_decode_attention_tp = tp_interp
-        model_lib.paged_decode_attention_tp = tp_interp
         attn.write_kv_pages_all_tp = functools.partial(orig_write,
                                                        interpret=True)
         try:
@@ -369,12 +367,12 @@ class TestPallasUnderMesh:
                 functools.partial(jax.device_put,
                                   device=kv_cache_sharding(mesh, cfg)), kv)
             got, got_kv, _ = jax.jit(
-                lambda p, k: model_lib.forward_decode(p, cfg, tokens, meta, k,
-                                                      attn_mesh=mesh)
+                lambda p, k: model_lib.forward(
+                    p, cfg, tokens, meta, k,
+                    attn.Kernels(use_pallas=True, tp_mesh=mesh))
             )(sharded_params, sharded_kv)
         finally:
             attn.paged_decode_attention_tp = orig
-            model_lib.paged_decode_attention_tp = orig
             attn.write_kv_pages_all_tp = orig_write
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -557,7 +555,8 @@ def test_kv_write_kernel_is_bitwise_the_loop(monkeypatch, case, dtype):
     keeps its sentinel. (The ref bitcast that gives the kernel its 32-bit
     view of a 16-bit pool exists only on the chip: there
     benchmarks/tpu_kernel_check.py makes the same comparison.)"""
-    from kubernetes_gpu_cluster_tpu.ops.attention import write_kv_pages_all
+    from kubernetes_gpu_cluster_tpu.ops.attention import (
+        write_kv_pages_all_xla)
     from kubernetes_gpu_cluster_tpu.ops.pallas import kv_write as kvw
 
     slots, budget = _KVW_CASES[case]
@@ -580,8 +579,7 @@ def test_kv_write_kernel_is_bitwise_the_loop(monkeypatch, case, dtype):
         for t, s in enumerate(np.asarray(slots)):     # last write wins
             flat[:, s] = new[:, t]
         want.append(flat.reshape(L, P, _KVW_PS, kd))
-    loop = write_kv_pages_all(pool_k, pool_v, k_all, v_all, slots,
-                              use_pallas=False)
+    loop = write_kv_pages_all_xla(pool_k, pool_v, k_all, v_all, slots)
     got = jax.jit(lambda *a: kvw.kv_write(*a, interpret=True))(
         pool_k, pool_v, k_all, v_all, slots)
     for w, ref, out in zip(want, loop, got):
@@ -622,7 +620,7 @@ def test_kv_write_tp_matches_loop():
     """The shard_map wrapper (pool and new rows split on the lane dim under
     a GSPMD tp mesh): interpret parity on the CPU tp=2 mesh, bitwise."""
     from kubernetes_gpu_cluster_tpu.ops.attention import (
-        write_kv_pages_all, write_kv_pages_all_tp)
+        write_kv_pages_all_tp, write_kv_pages_all_xla)
     from kubernetes_gpu_cluster_tpu.parallel import make_mesh
 
     mesh = make_mesh(tp=2, dp=4)
@@ -634,8 +632,7 @@ def test_kv_write_tp_matches_loop():
     pool_v = jnp.asarray(rng.standard_normal((L, P, _KVW_PS, kd)), jnp.bfloat16)
     k_all = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
     v_all = jnp.asarray(rng.standard_normal((L, T, kd)), jnp.bfloat16)
-    want = write_kv_pages_all(pool_k, pool_v, k_all, v_all, slots,
-                              use_pallas=False)
+    want = write_kv_pages_all_xla(pool_k, pool_v, k_all, v_all, slots)
     got = write_kv_pages_all_tp(mesh, pool_k, pool_v, k_all, v_all, slots,
                                 interpret=True)
     for w, g in zip(want, got):

@@ -156,7 +156,7 @@ def test_logits_close_to_full_precision(model, method):
     cfg = get_model_config(model).replace(quant_group_size=GROUP)
     T = 6
     tokens = jnp.arange(T, dtype=jnp.int32) + 3
-    meta = model_lib.PrefillMeta(
+    meta = model_lib.StepMeta(
         seg_ids=jnp.zeros((T,), jnp.int32),
         positions=jnp.arange(T, dtype=jnp.int32),
         slot_mapping=jnp.arange(T, dtype=jnp.int32) + 8,
@@ -166,8 +166,7 @@ def test_logits_close_to_full_precision(model, method):
 
     def logits_of(p):
         kv = allocate_kv_cache(cfg, cache, 9)
-        h, _, _ = model_lib.forward_prefill(p, cfg, tokens, meta, kv,
-                                            use_pallas=False)
+        h, _, _ = model_lib.forward(p, cfg, tokens, meta, kv)
         return np.asarray(model_lib.compute_logits(p, cfg, h))[0]
 
     params, ref = _ref_logits(model, cfg, logits_of)
@@ -182,6 +181,69 @@ def test_logits_close_to_full_precision(model, method):
     got = logits_of(qparams)
     cos = np.dot(ref, got) / (np.linalg.norm(ref) * np.linalg.norm(got))
     assert cos > COSINE_GATE[method], (method, cos)
+
+
+# The six shapes of the one forward: (segment tokens, has history, rows,
+# row width). Tokens = segment + rows * width.
+_FORWARD_SHAPES = {
+    "prefill": (16, False, 0, 1),
+    "prefill_hist": (16, True, 0, 1),
+    "mixed": (16, True, 2, 1),
+    "spec_mixed": (16, True, 2, 4),
+    "spec_verify": (0, False, 2, 4),
+    "decode": (0, False, 2, 1),
+}
+
+
+@pytest.mark.parametrize("kernels_on", [True, False], ids=["kernels", "xla"])
+@pytest.mark.parametrize("shape", list(_FORWARD_SHAPES))
+def test_int4_consumer_follows_one_rule(monkeypatch, shape, kernels_on):
+    """Every int4 matmul of every step shape, and the head, is told the
+    SAME thing: ``False`` where the engine resolved to no kernels, ``None``
+    (ops.quant.int4_matmul's own KGCT_INT4_PALLAS opt-in) otherwise, never
+    ``True``. At the parent only decode layers followed the opt-in; prefill,
+    mixed and spec layers and the head were forced onto the Pallas int4
+    kernel whenever the engine ran kernels. Traced only (a width whose
+    kernels trace: kd = 256); nothing compiles."""
+    from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
+    from kubernetes_gpu_cluster_tpu.ops import quant as quant_ops
+    from kubernetes_gpu_cluster_tpu.ops.attention import Kernels
+
+    seen = []
+    monkeypatch.setattr(
+        quant_ops, "int4_matmul",
+        lambda x, w, scale, use_pallas=None: (
+            seen.append(use_pallas) or int4_matmul_xla(x, w, scale)))
+    cfg = get_model_config("debug-tiny").replace(
+        num_heads=8, num_kv_heads=4, head_dim=64, quantization="int4",
+        quant_group_size=GROUP)
+    params = jax.eval_shape(
+        lambda: model_lib.init_params(cfg, jax.random.key(0)))
+    kv = jax.eval_shape(lambda: allocate_kv_cache(
+        cfg, CacheConfig(page_size=8, num_pages=9), 9))
+    n_seg, hist, rows, width = _FORWARD_SHAPES[shape]
+    T = n_seg + rows * width
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    meta = model_lib.StepMeta(
+        seg_ids=i32(T) if n_seg else None, positions=i32(T),
+        slot_mapping=i32(T), logits_indices=i32(1) if n_seg else None,
+        chunk_page_table=i32(4) if hist else None,
+        hist_len=i32() if hist else None,
+        page_tables=i32(rows, 4) if rows else None,
+        context_lens=i32(rows) if rows else None)
+    kernels = Kernels(use_pallas=kernels_on, use_pallas_hist=kernels_on)
+
+    def step(params, kv, tokens, meta):
+        hidden, kv, _ = model_lib.forward(params, cfg, tokens, meta, kv,
+                                          kernels, row_width=width)
+        return model_lib.compute_logits(params, cfg, hidden, kernels), kv
+
+    jax.eval_shape(step, params, kv, i32(T), meta)
+    # One traced layer body (q, k, v, o, gate, up, down) and the head.
+    assert seen == [None if kernels_on else False] * 8
 
 
 def test_engine_serves_quantized_int8():

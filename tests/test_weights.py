@@ -44,7 +44,7 @@ def _our_logits(path, prompt):
     cfg = config_from_hf(path).replace(dtype="float32")
     params = load_weights(path, cfg)
     T = len(prompt)
-    meta = model_lib.PrefillMeta(
+    meta = model_lib.StepMeta(
         seg_ids=jnp.zeros((T,), jnp.int32),
         positions=jnp.arange(T, dtype=jnp.int32),
         slot_mapping=jnp.arange(T, dtype=jnp.int32),  # scratch pool below
@@ -52,8 +52,7 @@ def _our_logits(path, prompt):
     from kubernetes_gpu_cluster_tpu.config import CacheConfig
     from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
     kv = allocate_kv_cache(cfg, CacheConfig(page_size=16, num_pages=4), 4)
-    _, _, h = model_lib.forward_prefill(params, cfg, jnp.asarray(prompt), meta,
-                                        kv, use_pallas=False)
+    _, _, h = model_lib.forward(params, cfg, jnp.asarray(prompt), meta, kv)
     h = model_lib._norm(cfg, h, params, "final_norm")
     return np.asarray(model_lib.compute_logits(params, cfg, h))   # [T, V]
 
