@@ -527,6 +527,89 @@ def check_experts(cfg) -> None:
         assert err_twin < 0.03 * max(scale, 1.0), (err_twin, scale)
 
 
+def time_grouped_matmul(cfg) -> None:
+    """``grouped_matmul`` alone beside the kernel it replaced (megablox
+    ``gmm`` at the parent's tiling: 128 rows, K whole; imported HERE only,
+    for this comparison) and ``jax.lax.ragged_dot``'s values: the model's
+    up-projection (d -> expert width) over the stack's groups with one
+    layer's not empty, at the `batch-decode-2k` cell's mixed step (2048 + 64
+    tokens of which a 1472-token prompt and 64 rows are real) with the
+    padding tokens' pairs in the groups (the parent's handing) and out of
+    them, at 2, 4 and 8 such prompts packed, and at Mixtral's shape (8
+    experts, top-2, 4096 x 14336, where N does not fit VMEM whole). The
+    sizes are drawn with the imbalance the cell's gauge reads (busiest
+    expert ~1.8x the mean). Each kernel gets the rows in its own layout
+    (the parent's: groups back to back; this one's: ``group_starts``), and
+    on the rows inside groups all three must agree bitwise."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm as megablox_gmm
+    from kubernetes_gpu_cluster_tpu.ops.pallas import grouped_matmul as gm
+    rng = np.random.default_rng(30)
+    k_tok, layers = cfg.num_experts_per_tok, 8
+    real, padded = (1472 + 64) * k_tok, (2048 + 64) * k_tok
+    shapes = [(f"{cfg.name} mixed step, padding routed", padded, padded,
+               layers, cfg.num_experts, cfg.hidden_size, cfg.expert_width),
+              (f"{cfg.name} mixed step, real pairs only", real, padded,
+               layers, cfg.num_experts, cfg.hidden_size, cfg.expert_width)]
+    shapes += [(f"{cfg.name} {n} prompts packed", n * 1472 * k_tok,
+                n * 1472 * k_tok, layers, cfg.num_experts, cfg.hidden_size,
+                cfg.expert_width) for n in (2, 4, 8)]
+    shapes += [("mixtral-8x7b shape, 2112 tokens", 2112 * 2, 2112 * 2, 1, 8,
+                4096, 14336)]
+    weights, differ = {}, []
+    for name, pairs, handed, n_layers, E, K, N in shapes:
+        p = np.exp(0.35 * rng.standard_normal(E))
+        sizes = rng.multinomial(pairs, p / p.sum()).astype(np.int32)
+        stack = np.zeros(n_layers * E, np.int32)
+        stack[(n_layers // 2) * E:][:E] = sizes       # a middle layer's
+        if (n_layers * E, K, N) not in weights:
+            weights.clear()     # one stack at a time on the device
+            weights[n_layers * E, K, N] = (
+                jax.random.normal(jax.random.key(1), (n_layers * E, K, N),
+                                  jnp.bfloat16) * K ** -0.5)
+        rhs = weights[n_layers * E, K, N]
+        # The pairs' rows once, then in each kernel's layout.
+        ends = np.cumsum(sizes)
+        m_old = -(-handed // 128) * 128
+        m_new = gm.padded_rows(handed, E)
+        pair_rows = jax.random.normal(jax.random.key(2), (m_old, K),
+                                      jnp.bfloat16)
+        at = np.concatenate([s + np.arange(n) for s, n in zip(
+            gm.group_starts(sizes), sizes)])          # pair -> its new row
+        src = np.zeros(m_new, np.int64)
+        src[at] = np.arange(pairs)
+        lhs_new = pair_rows[jnp.asarray(src)]
+        old = jax.jit(lambda a, b, c: megablox_gmm(
+            a, b, c, preferred_element_type=jnp.float32,
+            tiling=gm.tiling(K, N, 2)))
+        new = jax.jit(gm.grouped_matmul)
+        twin = jax.jit(lambda a, b, c: jax.lax.ragged_dot(
+            a, b, gm.aligned_sizes(c), preferred_element_type=jnp.float32))
+        sz = jnp.asarray(stack)
+        out_new = np.asarray(new(lhs_new, rhs, sz))[at]
+        gap_twin = np.abs(out_new - np.asarray(twin(lhs_new, rhs, sz))[at])
+        gap_old = np.abs(out_new - np.asarray(old(pair_rows, rhs, sz))[:pairs])
+        t_old = _timed(old, pair_rows, rhs, sz)
+        t_new = _timed(new, lhs_new, rhs, sz)
+        # The parent's visits: every 128-row tile a group's rows touch.
+        v_old = int(sum(-(-e // 128) - (e - n) // 128
+                        for e, n in zip(ends, sizes) if n))
+        v_new = int(gm.group_visits(sizes).sum())
+        print(f"grouped_matmul {name}: {pairs} pairs in {int((sizes > 0).sum())} "
+              f"groups of {n_layers * E}, {K} x {N}, busiest {sizes.max()} "
+              f"({sizes.max() / sizes.mean():.2f}x the mean); megablox "
+              f"{t_old * 1e6:.0f} us ({v_old} visits, fill "
+              f"{100 * pairs / (v_old * 128):.1f} %, result {m_old} rows), "
+              f"this kernel {t_new * 1e6:.0f} us ({v_new} visits, fill "
+              f"{100 * gm.tile_fill_share(sizes):.1f} %, result {m_new} "
+              f"rows): x{t_old / t_new:.2f}; weights alone "
+              f"{int((sizes > 0).sum()) * K * N * 2 / 819e9 * 1e6:.0f} us at "
+              f"819 GB/s; max|this - ragged_dot| {gap_twin.max():.3g}, "
+              f"max|this - megablox| {gap_old.max():.3g}")
+        if gap_twin.max() or gap_old.max():
+            differ.append(name)
+    assert not differ, f"not bitwise equal: {differ}"
+
+
 def check_decode_program(cfg, rows=(8, 16, 32, 64, 128),
                          ctx=(1024, 2688)) -> None:
     """The expert dispatches IN the decode program: the whole
@@ -650,7 +733,8 @@ def main() -> None:
                             for n in (B, T)],
         "int4": check_int4_matmul,
         "latent": lambda: check_latent(cfg, pps, B, T),
-        "experts": lambda: check_experts(cfg),
+        "experts": lambda: (check_experts(cfg), time_grouped_matmul(cfg)),
+        "expert-kernel": lambda: time_grouped_matmul(cfg),
         "decode-program": lambda: check_decode_program(cfg),
     }
     if cfg.is_mla and args.kernels == ap.get_default("kernels"):

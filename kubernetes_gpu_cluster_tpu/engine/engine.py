@@ -576,10 +576,12 @@ class LLMEngine:
         if self._grouped_experts:
             # The grouped expert matmuls of the largest step (a full prefill
             # bucket beside a full decode bucket), over the whole stack's
-            # groups as models.llama.experts_grouped hands them over.
-            from ..ops.pallas.grouped_matmul import grouped_matmul
+            # groups and in the rows models.llama.experts_grouped lays out.
+            from ..ops.pallas.grouped_matmul import (grouped_matmul,
+                                                     padded_rows)
             groups = (cfg.num_layers - cfg.num_dense_layers) * cfg.num_experts
-            rows = (T + B) * cfg.num_experts_per_tok
+            rows = padded_rows((T + B) * cfg.num_experts_per_tok,
+                               cfg.num_experts)
             d, ff = cfg.hidden_size, cfg.expert_width
             for name, k, n in (("up", d, ff), ("down", ff, d)):
                 probe(f"grouped_matmul[{name}]", grouped_matmul,
@@ -674,10 +676,10 @@ class LLMEngine:
     @property
     def _reports_expert_load(self) -> bool:
         """Prefill, chunk and mixed steps of an expert model on one device
-        return the routed pairs of each expert beside their tokens (a step
-        of thousands of tokens says how even the routing is; a decode
-        window's few hundred pairs say little and its scan would have to
-        carry the count)."""
+        return the real routed pairs of each expert of each layer beside
+        their tokens (a step of thousands of tokens says how even the
+        routing is; a decode window's few hundred pairs say little and its
+        scan would have to carry the count)."""
         return self.model_config.is_moe and self.mesh is None
 
     # -- jitted step programs ----------------------------------------------
@@ -1865,7 +1867,10 @@ class LLMEngine:
                     compute_s = time.perf_counter() - t0f
                     toks_np = np.asarray(next_tokens)[:, None]
                     lps_np = np.asarray(lps)[:, None]
-                    self.obs.on_expert_load(load)
+                    self.obs.on_expert_load(
+                        load, model_lib.grouped_dispatch(
+                            len(batch.tokens), self.model_config,
+                            self.kernels))
                     top_i = top_l = None
                     if any(s.params.top_logprobs for s in batch.seqs):
                         top_i = np.asarray(tids)[:, None]
@@ -1967,7 +1972,9 @@ class LLMEngine:
             compute_s = time.perf_counter() - t0f
             toks_np = np.asarray(next_tokens)[:, None]
             lps_np = np.asarray(lps)[:, None]
-            self.obs.on_expert_load(load)
+            self.obs.on_expert_load(
+                load, model_lib.grouped_dispatch(
+                    len(batch.tokens), self.model_config, self.kernels))
             top_i = top_l = None
             if any(s.params.top_logprobs for s in batch.seqs):
                 top_i = np.asarray(tids)[:, None]

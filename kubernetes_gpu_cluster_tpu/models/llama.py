@@ -36,6 +36,7 @@ from ..engine.kv_cache import KVCache
 from ..ops import quant as quant_ops
 from ..ops.rope import apply_rope, rope_cos_sin
 from ..ops.attention import NO_KERNELS, Kernels
+from ..ops.pallas import grouped_matmul as gm
 
 Params = dict[str, Any]
 
@@ -478,6 +479,13 @@ def dense_dispatch_pays(T: int, cfg: ModelConfig) -> bool:
             >= DENSE_DISPATCH_MIN_PAIRS_PER_EXPERT * cfg.num_experts)
 
 
+def grouped_dispatch(T: int, cfg: ModelConfig, kernels: Kernels) -> bool:
+    """Whether a step program of T tokens (padding included) runs its experts
+    by grouped dispatch: the one test, ``_moe_mlp``'s and that of the host's
+    gauge of what the grouped kernel computed."""
+    return kernels.grouped_experts and not dense_dispatch_pays(T, cfg)
+
+
 _EXPERT_KEYS = ("w_gate", "w_up", "w_down",
                 "w_gate_scale", "w_up_scale", "w_down_scale")
 
@@ -485,46 +493,72 @@ _EXPERT_KEYS = ("w_gate", "w_up", "w_down",
 def experts_grouped(lp: Params, x: jax.Array, idx: jax.Array,
                     w: jax.Array, sizes: jax.Array,
                     layer: Optional[jax.Array] = None,
-                    use_pallas: bool = False) -> jax.Array:
-    """Token-sorted grouped expert matmuls: the T*k routed (token, expert)
+                    use_pallas: bool = False,
+                    valid: Optional[jax.Array] = None) -> jax.Array:
+    """Token-sorted grouped expert matmuls: the routed (token, expert)
     pairs are sorted by expert and each expert's SwiGLU runs over its own
     contiguous rows, so the work is the pairs' and not experts x tokens
     (``use_pallas``: ``ops.pallas.grouped_matmul``, the kernel or its
-    exception; else its XLA twin ``jax.lax.ragged_dot``). Every pair is computed
-    whatever the imbalance: there is no capacity and nothing is dropped.
-    x: [T, d]; idx/w: [T, k]; sizes: [E] int32, the pairs of each expert.
+    exception; else its XLA twin ``jax.lax.ragged_dot``). Every pair of a
+    real token is computed whatever the imbalance: there is no capacity and
+    nothing is dropped. A padding token's pairs (``valid`` False) are in no
+    group: they sort behind every expert's, get no row, and the token's
+    result is zero.
+    x: [T, d]; idx/w: [T, k]; sizes: [E] int32, the real pairs of each
+    expert; valid: [T] bool or None (every token is real).
     Returns [T, d] float32.
+
+    The rows are laid out as the kernel walks them (``grouped_matmul``'s
+    ``group_starts``: every expert's rows start on a whole HBM tile, so a
+    row tile belongs to one expert), for the twin as well: it is handed the
+    sizes rounded up likewise, and computes the few rows in between for
+    nobody. Rows outside the groups hold nothing meaningful and are never
+    gathered back.
 
     ``lp``'s expert tensors are one layer's [E, ...] or, with ``layer``, the
     whole stack's [n, E, ...]: the kernel is then handed the stack as n*E
     groups of which only this layer's are not empty. Indexing the layer out
     first would COPY its experts (1.1 GB a layer at kimi-vl-a3b's widths,
     a third of the step's device time when it was done so): a custom call
-    cannot read through a dynamic slice. The rows past the groups' end (the
-    kernel's padding to whole tiles) are cut off before the combine."""
+    cannot read through a dynamic slice."""
     T, k = idx.shape
-    order = jnp.argsort(idx.reshape(-1), stable=True)       # pairs by expert
-    xs = x[order // k]                                      # [T*k, d]
+    E = sizes.shape[0]
+    expert = idx if valid is None else jnp.where(valid[:, None], idx, E)
+    # One stable sort lays the rows out: behind the pairs come ROW_ALIGN - 1
+    # fillers an expert, of which each expert owns what rounds its rows up
+    # (the rest, like a padding token's pairs, sort behind every expert: E).
+    fill = jnp.arange(gm.ROW_ALIGN - 1)[None, :] < (
+        gm.aligned_sizes(sizes) - sizes)[:, None]
+    keys = jnp.concatenate([
+        expert.reshape(-1),
+        jnp.where(fill, jnp.arange(E)[:, None], E).reshape(-1)])
+    order = jnp.argsort(keys, stable=True)       # row -> pair (or filler)
+    # ... and a tile more, for the last visit's spill (gm.padded_rows).
+    src = jnp.minimum(order, T * k - 1) // k
+    xs = x[jnp.pad(src, (0, gm.padded_rows(T * k, E) - src.shape[0]))]
     # Dense-precision experts only: _moe_mlp keeps int8/int4 ones on _dot.
     w_gate, w_up, w_down = (lp[n] for n in ("w_gate", "w_up", "w_down"))
     if layer is not None:
-        n_layers, E = w_gate.shape[:2]
+        n_layers = w_gate.shape[0]
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((n_layers * E,), sizes.dtype), sizes, (layer * E,))
         w_gate, w_up, w_down = (a.reshape((n_layers * E,) + a.shape[2:])
                                 for a in (w_gate, w_up, w_down))
     if use_pallas:
-        from ..ops.pallas.grouped_matmul import grouped_matmul as matmul
+        matmul = gm.grouped_matmul
     else:
+        sizes = gm.aligned_sizes(sizes)
         matmul = functools.partial(jax.lax.ragged_dot,
                                    preferred_element_type=jnp.float32)
     gate = matmul(xs, w_gate, sizes)
     up = matmul(xs, w_up, sizes)
     h = (jax.nn.silu(gate) * up).astype(x.dtype)
-    y = matmul(h, w_down, sizes)                                # [T*k, d]
-    y = y * w.reshape(-1)[order][:, None]
+    y = matmul(h, w_down, sizes)                                # [rows, d]
     # Back to token order by a gather (a scatter-add serialises on the chip).
-    return y[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+    y = y[jnp.argsort(order)[:T * k]] * w.reshape(-1, 1)    # pair -> row
+    if valid is not None:
+        y = jnp.where((expert < E).reshape(-1, 1), y, 0.0)
+    return y.reshape(T, k, -1).sum(axis=1)
 
 
 def experts_dense(lp: Params, x: jax.Array, idx: jax.Array, w: jax.Array,
@@ -562,7 +596,8 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
              ep_axis: Optional[str] = None,
              kernels: Kernels = NO_KERNELS,
              load_out: Optional[list] = None,
-             stacked: Optional[tuple] = None) -> jax.Array:
+             stacked: Optional[tuple] = None,
+             valid: Optional[jax.Array] = None) -> jax.Array:
     """Sparse expert layer: route (``moe_route``), run the routed experts,
     add the shared experts where the model has them. Expert compute is
     dense dispatch unless ``kernels.grouped_experts``: the engine's word
@@ -571,9 +606,14 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
     custom call with no partitioning rule, may be handed the stack as it is.
     Then it is chosen from the step's size (``dense_dispatch_pays``): dense
     where every expert is hit anyway and the step is under the chip's
-    FLOP-to-byte balance, else the token-sorted grouped path. Sharded and
-    quantized experts are always dense dispatch (PERF.md: debt).
-    ``load_out``: a list that is given the routed pairs each expert was
+    FLOP-to-byte balance, else the token-sorted grouped path
+    (``grouped_dispatch``). Sharded and quantized experts are always dense
+    dispatch (PERF.md: debt): the engine gives no such word for them, and it
+    is refused here.
+    ``valid``: [T] bool, False on the step's padding tokens (None: there
+    are none). Padding is not load: its pairs are in no expert's count, and
+    the grouped path computes nothing for them.
+    ``load_out``: a list that is given the real routed pairs each expert was
     sent, [E] int32 (``_layer_scan``'s ``moe_load``). ``stacked``: (the whole stack's expert tensors
     [n, E, ...], this layer's index in it) where the caller kept them out
     of ``lp`` (``_layer_scan``: so that no layer's experts are copied)."""
@@ -581,7 +621,9 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
         idx, w = moe_route(lp, x, cfg)
         # Pairs of each expert, as a sum of one-hot rows: a bincount is a
         # scatter-add, which the chip performs one update after the other.
-        load = jnp.sum(jax.nn.one_hot(idx.reshape(-1), cfg.num_experts,
+        # (A padding token's row is all zeros: its expert is out of range.)
+        sent = idx if valid is None else jnp.where(valid[:, None], idx, -1)
+        load = jnp.sum(jax.nn.one_hot(sent.reshape(-1), cfg.num_experts,
                                       dtype=jnp.int32), axis=0)
     experts, layer = stacked if stacked is not None else (lp, None)
     grouped, int4 = kernels.grouped_experts, kernels.int4_pallas
@@ -589,10 +631,12 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
         if grouped and (tp_axis is not None or ep_axis is not None):
             raise ValueError("grouped expert dispatch inside a manual "
                              "tp/ep shard_map: its experts are sharded")
-        if (grouped and experts["w_gate"].dtype != jnp.int8
-                and not dense_dispatch_pays(x.shape[0], cfg)):
+        if grouped and experts["w_gate"].dtype == jnp.int8:
+            raise ValueError("grouped expert dispatch over quantized "
+                             "experts: the kernel takes whole precision")
+        if grouped_dispatch(x.shape[0], cfg, kernels):
             out = experts_grouped(experts, x, idx, w, load, layer,
-                                  kernels.use_pallas)
+                                  kernels.use_pallas, valid)
         else:
             if layer is not None:   # one layer's, read in place by the dots
                 experts = {k: jax.lax.dynamic_index_in_dim(
@@ -734,6 +778,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                 tp_axis: Optional[str] = None,
                 ep_axis: Optional[str] = None,
                 moe_load: Optional[list] = None,
+                valid: Optional[jax.Array] = None,
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Scan the layer body over stacked weights.
 
@@ -772,10 +817,11 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     (lp, q [T, nh, nope + rope], row [T, kv_row_padded], None, layer_idx).
 
     ``moe_load``: a list the caller owns; where the stack has expert layers
-    the step's routed pairs of each expert, [E] int32 summed over the
-    layers, are appended to it (a traced value of the caller's own trace:
+    the step's real routed pairs of each expert of each layer, [n_layers, E]
+    int32, are appended to it (a traced value of the caller's own trace:
     the step program returns it beside its tokens, so the host reads the
-    routing balance from a fetch it makes anyway).
+    routing balance from a fetch it makes anyway). ``valid``: [T] bool, the
+    step's real tokens, for the expert layers (``_moe_mlp``).
     """
     int4 = kernels.int4_pallas
 
@@ -805,7 +851,8 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         if "router" in lp:
             mlp = _moe_mlp(lp, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
                            kernels=kernels, load_out=load,
-                           stacked=(experts, layer_idx - first))
+                           stacked=(experts, layer_idx - first),
+                           valid=valid)
         else:
             mlp = _dense_mlp(lp, x, cfg, tp_axis=tp_axis,
                              use_pallas=int4)
@@ -834,8 +881,8 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
             (layers, jnp.arange(first, first + n_layers, dtype=jnp.int32)))
         first += n_layers
         outs.append(rows)
-        if load:    # [n_layers, E] -> the step's pairs of each expert
-            moe_load.append(jnp.sum(load[0], axis=0))
+        if load:    # [n_layers, E]
+            moe_load.append(load[0])
     rows = (outs[0] if len(outs) == 1 else
             tuple(jnp.concatenate(r, axis=0) for r in zip(*outs)))
     return (h, rows[0], rows[1]) if len(rows) == 2 else (h, rows[0], None)
@@ -939,9 +986,19 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                           meta.positions[:n_seg], layer_idx),
              row_attn(lp, qr, kr, vr, layer_idx)], axis=0)
 
+    # The step's real tokens, for the expert layers: a prompt token of a
+    # segment, a row that holds a sequence. (The same tokens whose
+    # ``slot_mapping`` is not the scrap page's; a decode window's padding
+    # rows count a context of 1 and pass for real, as before.)
+    real = None
+    if cfg.is_moe:
+        real = jnp.concatenate(
+            ([meta.seg_ids[:n_seg] >= 0] if n_seg else [])
+            + ([jnp.repeat(meta.context_lens > 0, row_width)]
+               if n_rows else []))
     h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
                                   kernels, tp_axis=tp_axis, ep_axis=ep_axis,
-                                  moe_load=moe_load)
+                                  moe_load=moe_load, valid=real)
     new_kv = KVCache(*kernels.write_pages(kv.k, kv.v, k_all, v_all,
                                           meta.slot_mapping))
     selected = h if meta.logits_indices is None else h[meta.logits_indices]

@@ -196,10 +196,13 @@ class Observability:
         self.mixed_prefill_tokens = 0
         self.mixed_decode_tokens = 0
         # Expert models: (token, expert) pairs sent through the expert
-        # layers, by step kind; and the routing balance of the last prefill,
-        # chunk or mixed step (busiest expert's pairs over the mean).
+        # layers, by step kind; the routing balance of the last prefill,
+        # chunk or mixed step (busiest expert's pairs over the mean); and,
+        # of the last such step that took the grouped path, the share of the
+        # rows its expert matmuls computed that were pairs (percent).
         self.moe_routed_pairs: dict[str, int] = {}
         self.moe_expert_load_max_ratio = 0.0
+        self.moe_grouped_tile_fill_share = 0.0
         # Speculative decoding: cumulative drafted vs accepted draft tokens
         # (bonus tokens excluded from both) — feeds the
         # kgct_spec_acceptance_ratio gauge, the kgct_spec_*_tokens_total
@@ -433,15 +436,23 @@ class Observability:
         self.tracer.emit("abort" if outcome == "aborted" else "finish",
                          seq.request_id, outcome=outcome, output_tokens=n)
 
-    def on_expert_load(self, load) -> None:
-        """``load``: () or one [E] device array, the pairs each expert was
-        sent in the step whose tokens were just fetched (the device is done:
-        this read waits for nothing)."""
-        for pairs in load:
-            pairs = np.asarray(pairs)
+    def on_expert_load(self, load, grouped: bool = False) -> None:
+        """``load``: () or one [layers, E] device array, the real pairs each
+        expert of each layer was sent in the step whose tokens were just
+        fetched (the device is done: this read waits for nothing).
+        ``grouped``: the step's experts ran by grouped dispatch, one
+        ``grouped_matmul`` group an expert; its visit rule says how full
+        the row tiles it computed were."""
+        for layers in load:
+            layers = np.asarray(layers)
+            pairs = layers.sum(axis=0)
             if pairs.sum() > 0:
                 self.moe_expert_load_max_ratio = float(
                     pairs.max() / pairs.mean())
+                if grouped:
+                    from ..ops.pallas.grouped_matmul import tile_fill_share
+                    self.moe_grouped_tile_fill_share = (
+                        100.0 * tile_fill_share(layers))
 
     # -- step accounting (engine.step) ---------------------------------------
 
@@ -629,6 +640,13 @@ class Observability:
             lines.append("# TYPE kgct_moe_expert_load_max_ratio gauge")
             lines.append("kgct_moe_expert_load_max_ratio %.4f"
                          % self.moe_expert_load_max_ratio)
+            lines.append("# HELP kgct_moe_grouped_tile_fill_share percent "
+                         "of the rows the grouped expert matmuls computed "
+                         "that were routed pairs, last prefill, chunk or "
+                         "mixed step on the grouped path")
+            lines.append("# TYPE kgct_moe_grouped_tile_fill_share gauge")
+            lines.append("kgct_moe_grouped_tile_fill_share %.4f"
+                         % self.moe_grouped_tile_fill_share)
         lines.append("# TYPE kgct_mixed_prefill_tokens_total counter")
         lines.append("kgct_mixed_prefill_tokens_total %d"
                      % self.mixed_prefill_tokens)

@@ -111,6 +111,29 @@ class TestServedAgainstReference:
         assert eng.obs.moe_routed_pairs["decode"] > 0
         assert eng.obs.moe_expert_load_max_ratio >= 1.0
 
+    def test_device_and_host_count_the_same_pairs(self, params):
+        """Steps wide enough for the grouped path (192 tokens > the dense
+        dispatch's 128), padding in both parts: the load the device returns
+        sums to what the host has always reckoned from the real tokens, the
+        logits stand, and the grouped path's tile fill is reported."""
+        eng = _engine(params, prefill_buckets=(192,), max_prefill_tokens=192)
+        device, host = [], []
+        on_load, on_step = eng.obs.on_expert_load, eng.obs.on_step
+        eng.obs.on_expert_load = lambda load, grouped: (
+            device.append((sum(int(np.asarray(a).sum()) for a in load),
+                           grouped)), on_load(load, grouped))[1]
+        eng.obs.on_step = lambda **kw: (
+            host.append((kw["routed_pairs"], True))
+            if kw["kind"] in ("prefill", "mixed") else None, on_step(**kw))[1]
+        # 40 tokens in a 192-token prefill; 250 in two chunks, beside the
+        # first prompt's decode row in a 4-row bucket.
+        _served_vs_reference(eng, params, [_prompt(40, 8), _prompt(250, 9)])
+        assert len(device) >= 3 and device == host
+        assert 0 < eng.obs.moe_grouped_tile_fill_share <= 100
+        assert ("kgct_moe_grouped_tile_fill_share %.4f"
+                % eng.obs.moe_grouped_tile_fill_share
+                ) in eng.obs.render_prometheus()
+
     def test_engine_chunked_without_mixed_batching(self, params):
         eng = _engine(params, mixed_batch_enabled=False)
         _served_vs_reference(eng, params, [_prompt(100, 4)])
@@ -204,20 +227,151 @@ class TestRouterAndDispatch:
             llama.experts_grouped(stack, x, idx, w, sizes, jnp.int32(1)),
             llama.experts_grouped(lp1, x, idx, w, sizes), atol=2e-5)
 
-    def test_grouped_matmul_kernel_equals_ragged_dot(self):
-        from kubernetes_gpu_cluster_tpu.ops.pallas.grouped_matmul import (
-            grouped_matmul, tiling)
+    # sizes of the groups, rows handed beyond the pairs, (K, N), and the
+    # bytes a weight tile may take (None: the module's)
+    GROUPED_MATMUL_CASES = {
+        "empty groups between full ones":
+            ([0, 0, 100, 0, 0, 150, 0, 50], 0, (128, 256), None),
+        "one group holds every row": ([0, 300, 0], 0, (128, 256), None),
+        "sizes 1, tile - 1, tile, tile + 1":
+            ([1, 127, 128, 129], 0, (128, 256), None),
+        "a long tail of padding pairs behind the groups":
+            ([40, 0, 7, 130], 600, (128, 256), None),
+        "a stack of 4 x 8 groups, one layer's not empty":
+            ([0] * 16 + [9, 0, 140, 33, 0, 128, 1, 60] + [0] * 8, 0,
+             (128, 256), None),
+        "mixtral's class: N in more than one tile":
+            ([200, 0, 90, 260], 0, (256, 512), 256 * 128 * 4 * 2),
+        "bfloat16 rows and weights":
+            ([33, 190, 0, 16], 50, (128, 256), None),
+        "real-valued float32": ([0, 100, 0, 150, 50], 40, (128, 256), None),
+        "real-valued bfloat16": ([33, 190, 0, 16], 50, (128, 256), None),
+    }
+
+    @pytest.mark.parametrize("case", list(GROUPED_MATMUL_CASES))
+    def test_grouped_matmul_kernel_equals_ragged_dot(self, case,
+                                                     monkeypatch):
+        """The kernel (interpret mode) against ``ragged_dot`` over the same
+        layout on the rows inside groups: BITWISE on whole numbers, where no
+        order of the float32 sums differs, and on normal values to 1e-4
+        (float32 operands; bfloat16 ones to their own products' rounding),
+        which a kernel that rounded its operands or its sums would miss."""
+        from kubernetes_gpu_cluster_tpu.ops.pallas import (
+            grouped_matmul as gm)
         # whole K; the widest N that divides and fits (kimi; mixtral)
-        assert tiling(2048, 1408, 2) == (128, 2048, 1408)
-        assert tiling(4096, 14336, 2) == (128, 4096, 512)
-        x = jax.random.normal(jax.random.key(0), (300, 128))
-        w = jax.random.normal(jax.random.key(1), (8, 128, 256))
-        # a stack's groups with one layer's not empty; 300 rows: padded
-        sizes = jnp.array([0, 0, 0, 0, 100, 0, 150, 50], jnp.int32)
-        got = grouped_matmul(x, w, sizes, interpret=True)
-        want = jax.lax.ragged_dot(x, w, sizes)
-        assert got.shape == (300, 256)
-        np.testing.assert_allclose(got, want, atol=1e-4)   # f32, sum order
+        assert gm.tiling(2048, 1408, 2) == (128, 2048, 1408)
+        assert gm.tiling(4096, 14336, 2) == (128, 4096, 512)
+        sizes, tail, (K, N), tile_bytes = self.GROUPED_MATMUL_CASES[case]
+        dtype = jnp.bfloat16 if "bfloat16" in case else jnp.float32
+        if tile_bytes is not None:
+            monkeypatch.setattr(gm, "_RHS_TILE_BYTES", tile_bytes)
+            assert gm.tiling(K, N, 4)[2] < N
+        sizes = np.asarray(sizes, np.int32)
+        m = gm.padded_rows(int(sizes.sum()) + tail, int((sizes > 0).sum()))
+        if "real-valued" in case:
+            x = jax.random.normal(jax.random.key(0), (m, K), dtype)
+            w = jax.random.normal(jax.random.key(1), (len(sizes), K, N),
+                                  dtype)
+        else:
+            x = jax.random.randint(jax.random.key(0), (m, K), -4,
+                                   5).astype(dtype)
+            w = jax.random.randint(jax.random.key(1), (len(sizes), K, N), -4,
+                                   5).astype(dtype)
+        got = np.asarray(gm.grouped_matmul(x, w, jnp.asarray(sizes),
+                                           interpret=True))
+        want = np.asarray(jax.lax.ragged_dot(
+            x, w, jnp.asarray(gm.aligned_sizes(sizes)),
+            preferred_element_type=jnp.float32))
+        assert got.shape == (m, N) and got.dtype == np.float32
+        inside = np.zeros(m, bool)
+        for start, size in zip(gm.group_starts(sizes), sizes):
+            assert start % gm.ROW_ALIGN == 0
+            inside[start:start + size] = True
+        assert inside.sum() == sizes.sum() and np.abs(want[inside]).max() > 0
+        if "real-valued" in case:   # f32 accumulation, the sums' order
+            np.testing.assert_allclose(got[inside], want[inside], atol=1e-4)
+        else:
+            np.testing.assert_array_equal(got[inside], want[inside])
+
+    @pytest.mark.parametrize("sizes", [
+        [0, 0, 100, 0, 0, 150, 0, 50], [1, 127, 128, 129], [0, 0, 0],
+        [198] * 64, [144, 80, 266, 0, 129] * 12])
+    def test_host_visit_count_is_the_kernels_grid(self, sizes):
+        """The gauge's rule (NumPy, on the host) and the kernel's grid (JAX,
+        on the device) are one function of the sizes: the same count, and
+        every visit a tile of its own group's rows."""
+        from kubernetes_gpu_cluster_tpu.ops.pallas import (
+            grouped_matmul as gm)
+        sizes = np.asarray(sizes, np.int32)
+        m = gm.padded_rows(int(sizes.sum()), len(sizes))
+        group, row, _, count = (np.asarray(a) for a in gm.visit_list(
+            jnp.asarray(sizes), m))
+        assert count.tolist() == [gm.group_visits(sizes).sum()]
+        want = [(g, start + t * gm.TILE_ROWS)
+                for g, (start, size) in enumerate(
+                    zip(gm.group_starts(sizes), sizes))
+                for t in range(-(-size // gm.TILE_ROWS))]
+        assert list(zip(group[:count[0]], row[:count[0]])) == want
+        assert len(group) >= count[0] and (row + gm.TILE_ROWS <= m).all()
+        fill = gm.tile_fill_share(sizes)
+        assert fill == (sizes.sum() / (len(want) * gm.TILE_ROWS)
+                        if want else 0.0)
+
+    def test_grouped_dispatch_is_one_test_and_refuses_int8_experts(self):
+        """``grouped_dispatch`` is the engine's word and the step's size,
+        nothing else; experts the kernel cannot take are refused, not
+        quietly sent another way than the host's gauge would think."""
+        cfg = get_model_config("debug-moe")
+        on = attention.Kernels(grouped_experts=True)
+        assert [llama.grouped_dispatch(T, cfg, on) for T in (4, 64, 192)] \
+            == [not llama.dense_dispatch_pays(T, cfg) for T in (4, 64, 192)]
+        assert not llama.grouped_dispatch(192, cfg, attention.Kernels())
+        lp = jax.tree.map(lambda a: a[0],
+                          llama.init_params(cfg, jax.random.key(5))["layers"])
+        lp = {**lp, "w_gate": lp["w_gate"].astype(jnp.int8)}
+        x = jnp.zeros((192, cfg.hidden_size))
+        with pytest.raises(ValueError, match="quantized"):
+            llama._moe_mlp(lp, x, cfg, kernels=on)
+
+    @pytest.mark.parametrize("name", ["debug-moe", "debug-mla-moe"])
+    def test_padding_is_not_routed_and_rows_outside_groups_are_not_read(
+            self, name, monkeypatch):
+        """A step with padding tokens: their pairs are in no group, the real
+        tokens' results are bit for bit what they are when the padding is
+        routed too (the parent's), and nothing the matmuls leave in the rows
+        outside the groups reaches the result."""
+        from kubernetes_gpu_cluster_tpu.ops.pallas import (
+            grouped_matmul as gm)
+        cfg = get_model_config(name)
+        lp = jax.tree.map(lambda a: a[0],
+                          llama.init_params(cfg, jax.random.key(5))["layers"])
+        T, k, E = 150, cfg.num_experts_per_tok, cfg.num_experts
+        x = jax.random.normal(jax.random.key(6), (T, cfg.hidden_size))
+        valid = jnp.arange(T) % 5 != 3            # padding in between
+        load = []
+        llama._moe_mlp(lp, x, cfg, load_out=load, valid=valid,
+                       kernels=attention.Kernels(grouped_experts=True))
+        assert int(load[0].sum()) == k * int(valid.sum())
+        idx, w = llama.moe_route(lp, x, cfg)
+        all_routed = jnp.sum(jax.nn.one_hot(idx.reshape(-1), E,
+                                            dtype=jnp.int32), axis=0)
+        parents = llama.experts_grouped(lp, x, idx, w, all_routed)
+        mine = llama.experts_grouped(lp, x, idx, w, load[0], valid=valid)
+        np.testing.assert_array_equal(mine[valid], parents[valid])
+        assert not np.asarray(mine[~valid]).any()
+        assert np.abs(np.asarray(parents[~valid])).min() > 0
+
+        def poisoned(lhs, rhs, sizes):      # the kernel's contract, harshly
+            out = jax.lax.ragged_dot(lhs, rhs, gm.aligned_sizes(sizes),
+                                     preferred_element_type=jnp.float32)
+            rows = jnp.arange(lhs.shape[0])[:, None]
+            starts = gm.group_starts(sizes)
+            inside = ((rows >= starts) & (rows < starts + sizes)).any(axis=1)
+            return jnp.where(inside[:, None], out, jnp.nan)
+        monkeypatch.setattr(gm, "grouped_matmul", poisoned)
+        np.testing.assert_array_equal(
+            llama.experts_grouped(lp, x, idx, w, load[0], use_pallas=True,
+                                  valid=valid), mine)
 
     @pytest.mark.parametrize("name,T,dense", [
         # kimi-vl-a3b, 6 of 64: every expert is hit from ~43 tokens on
