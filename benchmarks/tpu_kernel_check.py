@@ -19,6 +19,7 @@ opt-in int4 matmul kernel is ``int4``).
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -36,7 +37,7 @@ from kubernetes_gpu_cluster_tpu.config import (SchedulerConfig,
                                                apply_hf_overrides,
                                                get_model_config)
 from kubernetes_gpu_cluster_tpu.ops.attention import (
-    paged_decode_attention_xla, prefill_history_attention_xla,
+    Kernels, paged_decode_attention_xla, prefill_history_attention_xla,
     ragged_prefill_attention_xla, write_kv_pages_all_xla)
 from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill import flash_ragged_prefill
 from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill_hist import (
@@ -677,6 +678,208 @@ def check_decode_program(cfg, rows=(8, 16, 32, 64, 128),
               f"{np.abs(hd_).max():.2f}")
 
 
+def check_ssm(cfg, B, T) -> None:
+    """A state model's two operations at its geometry. The one-token update:
+    the Pallas kernel against the XLA reference (values, the untouched slots
+    bitwise), then each timed alone over the SERVED pool (every state layer,
+    B + 1 slots, float32), a call a layer chained in one program, against
+    the bytes of the rows' slots. The chunked scan (XLA einsums, no kernel):
+    timed alone over one prompt of the cell's mean length in the top prefill
+    bucket, against its FLOPs."""
+    from kubernetes_gpu_cluster_tpu.ops import ssm as ssm_ops
+    from kubernetes_gpu_cluster_tpu.ops.pallas.ssm_update import ssm_update
+    Ls, N, di = cfg.num_state_layers, cfg.mamba_d_state, cfg.mamba_d_inner
+    H, P, Q = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_chunk_size
+    f32 = jnp.float32
+    k = jax.random.split(jax.random.key(11), 8)
+    slots = jnp.concatenate([jax.random.permutation(k[0], B)[:B - 3] + 1,
+                             jnp.zeros(3, jnp.int32)]).astype(jnp.int32)
+    decay = jax.random.uniform(k[1], (B, di), f32, 0.5, 1.0)
+    dtx = jax.random.normal(k[2], (B, di), f32) * 0.1
+    Bm, Cm = (jax.random.normal(k[i], (B, N), f32) for i in (3, 4))
+
+    small = jax.random.normal(k[5], (3, B + 1, N, di), f32)
+    want_pool, want_y = jax.jit(ssm_ops.ssm_update_xla)(
+        small, jnp.int32(1), slots, decay, dtx, Bm, Cm)
+    got_pool, got_y = jax.jit(ssm_update)(
+        small, jnp.int32(1), slots, decay, dtx, Bm, Cm)
+    real = np.asarray(slots[:B - 3])
+    e_y = _err(got_y[:B - 3], want_y[:B - 3])
+    e_s = _err(got_pool[1, real], want_pool[1, real])
+    untouched = np.ones(small.shape[:2], bool)
+    untouched[1, np.asarray(slots)] = False
+    same = bool(np.array_equal(np.asarray(got_pool)[untouched],
+                               np.asarray(small)[untouched]))
+    print(f"ssm_update B={B} [{N}, {di}] f32: max|pallas-xla| y={e_y:.2e} "
+          f"state={e_s:.2e}; other slots and layers bitwise: {same}")
+    assert e_y < 1e-3 and e_s < 1e-4 and same
+    del small, want_pool, got_pool
+
+    def chained(update):
+        def run(pool):
+            def layer(l, carry):
+                pool, acc = carry
+                pool, y = update(pool, l, slots, decay, dtx, Bm, Cm)
+                return pool, acc + y
+            return jax.lax.fori_loop(0, Ls, layer,
+                                     (pool, jnp.zeros((B, di), f32)))
+        return jax.jit(run, donate_argnums=0)
+
+    least = (B - 3) * 2 * N * di * 4
+    variants = [("pallas, as served", ssm_update)] + [
+        (f"pallas, lane_block={lane}",
+         functools.partial(ssm_update, lane_block=lane))
+        for lane in (512, 2048, 4096) if lane <= di]
+    for name, update in variants + [("xla", ssm_ops.ssm_update_xla)]:
+        run = chained(update)
+        pool = jnp.zeros((Ls, B + 1, N, di), f32)
+        pool, _ = jax.block_until_ready(run(pool))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            pool, acc = run(pool)
+        jax.block_until_ready(acc)
+        us = (time.perf_counter() - t0) / (5 * Ls) * 1e6
+        print(f"ssm_update[{name}] alone, pool [{Ls}, {B + 1}, {N}, {di}] "
+              f"({pool.nbytes / 1e9:.2f} GB), {B - 3} real rows: {us:.1f} us "
+              f"a call; {least / 1e6:.0f} MB of slots there and back = "
+              f"{least / 819e9 * 1e6:.1f} us at 819 GB/s "
+              f"({least / 819e9 * 1e6 / us:.1%})")
+        del pool, acc
+
+    n = 1472        # the cell's mean prompt, alone in the 2048 bucket
+    dt = cfg.jnp_dtype
+    x = jax.random.normal(k[6], (T, H, P), f32).astype(dt)
+    dtv = jax.nn.softplus(jax.random.normal(k[7], (T, H), f32) - 2.0)
+    dA = dtv * -jnp.exp(jax.random.uniform(k[0], (H,), f32, 0.0, 2.5))
+    Bs, Cs = (jax.random.normal(k[i], (T, N), f32).astype(dt) for i in (1, 2))
+    seg = jnp.where(jnp.arange(T) < n, 0, -1).astype(jnp.int32)
+    ends = jnp.asarray([n - 1], jnp.int32)
+    init = jnp.zeros((N, di), f32)
+    scan = jax.jit(lambda *a: ssm_ops.ssm_chunk_scan_xla(*a, -2, Q))
+    s = _timed(scan, x, dtv, dA, Bs, Cs, seg, ends, init)
+    y, final = scan(x, dtv, dA, Bs, Cs, seg, ends, init)
+    y_ref, f_ref = jax.jit(ssm_ops.ssm_recurrence)(
+        x[:n], dtv[:n], dA[:n], Bs[:n], Cs[:n], init)
+    flops = T * (2 * Q * N + 2 * Q * di + 4 * N * di)
+    print(f"ssm_chunk_scan_xla alone, T={T} ({n} real), {H} x {P}, N={N}, "
+          f"chunk {Q}: {s * 1e3:.2f} ms a layer; {flops / 1e9:.1f} GFLOP = "
+          f"{flops / 197e12 * 1e3:.3f} ms at 197 TFLOP/s; max|chunked - "
+          f"token by token| y={_err(y[:n], y_ref):.2e} (max|y| "
+          f"{float(jnp.max(jnp.abs(y_ref))):.1f}; the products that feed y "
+          f"run in {dt.__name__}) state={_err(final[0], f_ref):.2e}")
+
+
+# The chained state's error against the float64 recurrence, as a share of
+# the largest value: the limit is the geometric mean of the largest reading
+# of the update as served (2.26e-7) and the smallest of a planted fault
+# (6.03e-3: y with the state rounded to bfloat16 a token), both on a v5e at
+# granite-4.0-h-micro's widths (PERF.md section 2).
+STATE_CHAIN_LIMIT = 4e-5
+
+
+def check_ssm_chain(cfg, kernels, steps: int = 512, rows: int = 4) -> dict:
+    """The gate of the recurrent state's precision: ``steps`` tokens of
+    ``rows`` sequences through the one-token update as the engine routes it
+    (``kernels.ssm_update``), over a slot pool the engine's own allocation
+    made (so the pool's dtype is the program's, not this check's), against
+    the same recurrence in float64 on the host: the final state of every
+    row's slot and y at every token, as max |error| over max |value|.
+
+    The inputs are drawn as the model's are (A = -U(1, 16), dt = logU(1e-3,
+    1e-1) as the init draws them; x, B, C unit normals rounded to the
+    model's dtype); decay and dt * x are float32 for both sides, so what is
+    compared is the recurrence's arithmetic and what the slot keeps of it.
+
+    Two planted faults run through the same chain and MUST read over the
+    limit, or the gate is blind and fails too: the state rounded to bfloat16
+    after every token (what the configuration forbids), and one row's slot
+    left unchanged by one update 16 tokens before the end (a stale slot)."""
+    from kubernetes_gpu_cluster_tpu.config import CacheConfig
+    from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
+    Ls, N, di = cfg.num_state_layers, cfg.mamba_d_state, cfg.mamba_d_inner
+    H, P = cfg.mamba_n_heads, cfg.mamba_d_head
+    layer = Ls // 2
+    f32 = np.float32
+    rng = np.random.default_rng(17)
+    dtype = cfg.jnp_dtype
+
+    def activations(*shape):        # as the model's: rounded to its dtype
+        return np.asarray(jnp.asarray(rng.standard_normal(shape, f32), dtype)
+                          .astype(jnp.float32))
+
+    A = -rng.uniform(1.0, 16.0, H).astype(f32)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                            (steps, rows, H))).astype(f32)
+    decay = np.repeat(np.exp(dt * A), P, axis=-1)               # [t, r, di]
+    dtx = np.repeat(dt, P, axis=-1) * activations(steps, rows, di)
+    Bm, Cm = activations(steps, rows, N), activations(steps, rows, N)
+    real = rng.permutation(rows) + 1
+    pad = 2                         # padding rows name the scrap slot 0
+    slots = jnp.asarray(np.concatenate([real, np.zeros(pad)]), jnp.int32)
+
+    def padded(a):
+        return jnp.asarray(np.pad(a, ((0, 0), (0, pad), (0, 0))))
+
+    S = np.zeros((rows, N, di), np.float64)
+    y_ref = np.empty((steps, rows, di), np.float64)
+    for t in range(steps):
+        S *= decay[t][:, None, :]
+        S += Bm[t].astype(np.float64)[:, :, None] * dtx[t][:, None, :]
+        y_ref[t] = np.einsum("rnc,rn->rc", S, Cm[t].astype(np.float64))
+
+    stale_at = steps - 16
+
+    @functools.partial(jax.jit, static_argnames="fault", donate_argnums=0)
+    def chain(pool, decay, dtx, Bm, Cm, fault=None):
+        def token(pool, xs):
+            t, d, u, b, c = xs
+            before = pool[layer, slots[0]]
+            pool, y = kernels.ssm_update(pool, jnp.int32(layer), slots,
+                                         d, u, b, c)
+            if fault == "bf16":
+                # (reduce_precision, not astype there and back: XLA on the
+                # TPU elides that round trip as excess precision allowed.)
+                pool = pool.at[layer].set(jax.lax.reduce_precision(
+                    pool[layer], exponent_bits=8, mantissa_bits=7))
+            if fault == "stale":
+                pool = pool.at[layer, slots[0]].set(jnp.where(
+                    t == stale_at, before, pool[layer, slots[0]]))
+            return pool, y
+        return jax.lax.scan(token, pool,
+                            (jnp.arange(steps), decay, dtx, Bm, Cm))
+
+    args = tuple(padded(a) for a in (decay, dtx, Bm, Cm))
+    readings = {}
+    for fault in (None, "bf16", "stale"):
+        pool = allocate_kv_cache(cfg, CacheConfig(page_size=PS), 2,
+                                 num_state_slots=rows + 1).ssm
+        pool, y = jax.block_until_ready(chain(pool, *args, fault=fault))
+        got = np.asarray(pool.astype(jnp.float32))
+        e_s = np.abs(got[layer, real] - S).max() / np.abs(S).max()
+        e_y = (np.abs(np.asarray(y, np.float64)[:, :rows] - y_ref).max()
+               / np.abs(y_ref).max())
+        others = np.ones(got.shape[:2], bool)
+        others[layer] = False
+        readings[fault or "served"] = (float(e_s), float(e_y))
+        print(f"ssm_update chained {steps} tokens x {rows} rows, pool "
+              f"{pool.dtype} [{Ls}, {rows + 1}, {N}, {di}], "
+              f"{fault or 'as served'}: against the float64 recurrence "
+              f"state {e_s:.2e}, y {e_y:.2e} of max |S| {np.abs(S).max():.1f}"
+              f", max |y| {np.abs(y_ref).max():.1f}; the other layers "
+              f"untouched: {not got[others].any()}")
+        assert not got[others].any()
+        del pool, got
+    e_s, e_y = readings["served"]
+    assert max(e_s, e_y) < STATE_CHAIN_LIMIT, (
+        f"the state as served is {max(e_s, e_y):.2e} from the float64 "
+        f"recurrence (limit {STATE_CHAIN_LIMIT})")
+    for fault in ("bf16", "stale"):
+        assert min(readings[fault]) > STATE_CHAIN_LIMIT, (
+            f"the planted fault {fault!r} reads {min(readings[fault]):.2e}, "
+            f"under the limit {STATE_CHAIN_LIMIT}: this gate is blind")
+    return readings
+
+
 def check_int4_matmul() -> None:
     """W4A16 dequant-fused matmul (ops/pallas/int4_matmul.py): packed tiles
     dequantized in VMEM vs the XLA fusion path, at an 8B-decode-like shape
@@ -726,19 +929,25 @@ def main() -> None:
           f"page {PS}, pages/seq {pps}, B={B}, T={T}")
     checks = {
         "decode": lambda: (check_decode(nh, n_kv, hd, pps, B),
-                           time_decode(nh, n_kv, hd, pps, B, cfg.num_layers)),
+                           time_decode(nh, n_kv, hd, pps, B,
+                                       cfg.num_kv_layers)),
         "prefill": lambda: check_prefill(nh, n_kv, hd, T),
         "hist": lambda: check_prefill_history(nh, n_kv, hd, pps, T),
-        "kvwrite": lambda: [check_kv_write(cfg.num_layers, n_kv, hd, n)
+        "kvwrite": lambda: [check_kv_write(cfg.num_kv_layers, n_kv, hd, n)
                             for n in (B, T)],
         "int4": check_int4_matmul,
         "latent": lambda: check_latent(cfg, pps, B, T),
         "experts": lambda: (check_experts(cfg), time_grouped_matmul(cfg)),
         "expert-kernel": lambda: time_grouped_matmul(cfg),
         "decode-program": lambda: check_decode_program(cfg),
+        "ssm": lambda: check_ssm(cfg, B, T),
+        "ssm-chain": lambda: check_ssm_chain(cfg,
+                                             Kernels(use_pallas=True)),
     }
     if cfg.is_mla and args.kernels == ap.get_default("kernels"):
         args.kernels = "latent,experts"
+    if cfg.has_state and args.kernels == ap.get_default("kernels"):
+        args.kernels += ",ssm,ssm-chain"
     for name in args.kernels.split(","):
         checks[name]()
     print("OK")

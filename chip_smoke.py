@@ -63,11 +63,16 @@ MODELS = {
     # one pipeline stage's 9 of 27 layers (latent pages, grouped experts).
     "kimi-vl-a3b": dict(vocab=163840, long_prompt=3000, gen=256,
                         flags=["--hf-overrides", '{"num_hidden_layers": 9}']),
+    # ``--model granite-4.0-h-micro``: the whole model, 36 state layers'
+    # slots beside the pages of 4 attention layers.
+    "granite-4.0-h-micro": dict(vocab=100352, long_prompt=3000, gen=256),
     # Rehearsal only: max_model_len 512 cannot hold a chunking prompt.
     "debug-tiny": dict(vocab=512, long_prompt=400, gen=96),
     "debug-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
+    "debug-ssm-hybrid": dict(vocab=512, long_prompt=400, gen=96),
 }
-REHEARSAL_OF = {"qwen3-4b": "debug-tiny", "kimi-vl-a3b": "debug-mla-moe"}
+REHEARSAL_OF = {"qwen3-4b": "debug-tiny", "kimi-vl-a3b": "debug-mla-moe",
+                "granite-4.0-h-micro": "debug-ssm-hybrid"}
 HEALTH_TIMEOUT_S = 600
 REQUEST_TIMEOUT_S = 600
 DRAIN_TIMEOUT_S = 150
@@ -376,6 +381,37 @@ def run(args) -> tuple[dict, dict]:
             raise bg["error"]
         statuses["background_stream"] = statuses["mixed_short"] = 200
 
+        if "state_bytes" in health:
+            # 7. a state model: three short prompts at once on an idle
+            #    server ride ONE packed prefill, whose segment boundaries
+            #    fall inside the scan's chunks; each must start as it does
+            #    alone (a slot found as another sequence left it, or a
+            #    state carried over a boundary, would not).
+            packed = [dict(prompt=prompt(n), max_tokens=8, temperature=0,
+                           logprobs=1, return_tokens_as_token_ids=True)
+                      for n in (40, 300, 17)]
+            alone = [complete(server, body) for body in packed]
+            together: dict = {}
+
+            def one(i, body):
+                try:
+                    together[i] = complete(server, body)
+                except Exception as e:   # re-raised on the main thread
+                    together[i] = e
+            threads = [threading.Thread(target=one, args=(i, b), daemon=True)
+                       for i, b in enumerate(packed)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(REQUEST_TIMEOUT_S)
+            for i, ids in enumerate(alone):
+                if isinstance(together.get(i), Exception):
+                    raise together[i]
+                check(i in together and together[i][0] == ids[0],
+                      f"prompt {i} starts {together.get(i)} beside others, "
+                      f"{ids} alone")
+            statuses["packed_prefill"] = 200
+
         status, metrics = server.get("/metrics", timeout=30)
         check(status == 200, f"/metrics -> {status}")
         mixed_ratio = metric(metrics, "kgct_mixed_step_ratio")
@@ -407,6 +443,9 @@ def run(args) -> tuple[dict, dict]:
         "model": model, "dtype": health["dtype"],
         "tensor_parallel_size": tp,
         "pages": health["num_pages"], "page_size": health["page_size"],
+        "kv_layout": health.get("kv_layout"),
+        "weight_bytes": health.get("weight_bytes"),
+        "state_bytes": health.get("state_bytes"),
         "kernels": {"use_pallas": health["use_pallas"],
                     "use_pallas_hist": health["use_pallas_hist"]},
         "seconds_to_healthy": round(t_healthy, 1),

@@ -230,52 +230,78 @@ class EngineConfig:
         return EngineConfig(model=get_model_config(name), **kw)
 
 
-def latent_model_refusal(config: EngineConfig, mesh_shape=None, *,
-                         role: str = "both", fleet_prefix_cache: bool = False,
-                         peer_pool=None) -> Optional[str]:
-    """What a latent-attention model (one pool of [c | k_pe] rows, no V;
-    grouped expert matmuls) is not carried through yet: one line naming the
-    flag and the mechanism, or None. Each of these either works (tested) or
-    refuses at start; none degrades in silence. ``mesh_shape``: the engine's
-    actual mesh axes, where it was handed a mesh and not flags."""
+def cache_kind_refusal(config: EngineConfig, mesh_shape=None, *,
+                       role: str = "both", fleet_prefix_cache: bool = False,
+                       peer_pool=None) -> Optional[str]:
+    """What this model's KINDS of per-sequence memory cannot be carried
+    through yet: one line naming the flag and the mechanism, or None. Pages
+    of K and V go everywhere; a latent-attention model holds ONE pool of [c
+    | k_pe] rows (and runs grouped expert matmuls), and a state model holds
+    a slot of recurrent state beside the pages of its attention layers.
+    Each flag either works with the model (tested) or refuses at start; none
+    degrades in silence. ``mesh_shape``: the engine's actual mesh axes,
+    where it was handed a mesh and not flags."""
     m = config.model
-    if not m.is_mla:
+    if not (m.is_mla or m.has_state):
         return None
     axes = dict(mesh_shape or {})
     par = config.parallel
-    for flag, axis, n, why in (
-            ("--tensor-parallel-size", "tp", par.tp,
-             "the latent row is one shared head: there is no kv-head axis "
-             "to shard the pool over, and the kernels run unsharded"),
-            ("--pipeline-parallel-size", "pp", par.pp,
-             "the pipeline stages one homogeneous layer stack, not leading "
-             "dense layers beside expert layers"),
-            ("--sequence-parallel-size", "sp", par.sp,
-             "ring attention is written for K and V per head, not for the "
-             "latent row"),
-            ("--expert-parallel-size", "ep", par.ep,
-             "the grouped expert matmuls run over every expert on one "
-             "device; no all-to-all dispatch exists")):
+    if m.is_mla:
+        why_axis = {
+            "tp": "the latent row is one shared head: there is no kv-head "
+                  "axis to shard the pool over, and the kernels run "
+                  "unsharded",
+            "pp": "the pipeline stages one homogeneous layer stack, not "
+                  "leading dense layers beside expert layers",
+            "sp": "ring attention is written for K and V per head, not for "
+                  "the latent row",
+            "ep": "the grouped expert matmuls run over every expert on one "
+                  "device; no all-to-all dispatch exists"}
+        moves, kind = "K and V page pairs, not latent pages", "latent pages"
+    else:
+        why_axis = {
+            "tp": "the state slots and the in-place state update have no "
+                  "sharded form (heads of a state layer are not split)",
+            "pp": "the pipeline stages one homogeneous layer stack, not a "
+                  "period of typed layers with state slots",
+            "sp": "ring attention splits the prompt over devices; the state "
+                  "scan runs a segment's tokens in order on one",
+            "ep": "the model has no experts to spread"}
+        moves, kind = ("pages of K and V, not a sequence's recurrent state",
+                       "state slots")
+    for flag, axis, n in (
+            ("--tensor-parallel-size", "tp", par.tp),
+            ("--pipeline-parallel-size", "pp", par.pp),
+            ("--sequence-parallel-size", "sp", par.sp),
+            ("--expert-parallel-size", "ep", par.ep)):
         n = max(n, axes.get(axis, 1))
         if n > 1:
-            return (f"{flag} {n} with {m.name}: {why}")
+            return f"{flag} {n} with {m.name}: {why_axis[axis]}"
+    if m.has_state and config.scheduler.enable_prefix_caching:
+        return (f"--enable-prefix-caching with {m.name}: a cached prefix is "
+                "pages of K and V; the recurrent state at the prefix's end "
+                "is not kept, so the tail cannot continue from it")
     if config.scheduler.spec_decode_enabled:
-        return (f"--enable-spec-decode with {m.name}: the verify step's "
-                "attention reads K and V pools; no latent-page variant")
+        return (f"--enable-spec-decode with {m.name}: " + (
+            "the verify step's attention reads K and V pools; no "
+            "latent-page variant" if m.is_mla else
+            "a rejected draft has already advanced the recurrent state; "
+            "no snapshot exists to roll it back to"))
     if config.cache.kv_swap_enabled:
         return (f"--swap-space-gb with {m.name}: the host tier and its "
-                "gather/scatter move K and V page pairs, not latent pages")
+                f"gather/scatter move {moves}")
     if m.quantization is not None:
-        return (f"--quantization {m.quantization} with {m.name}: the "
-                "absorbed projections and the grouped expert matmuls have "
-                "no int8/int4 path")
+        return (f"--quantization {m.quantization} with {m.name}: " + (
+            "the absorbed projections and the grouped expert matmuls have "
+            "no int8/int4 path" if m.is_mla else
+            "the state layers' projections and conv have no int8/int4 "
+            "layout"))
     if role != "both":
         return (f"--role {role} with {m.name}: the prefill-to-decode "
-                "handoff frames K and V page pairs, not latent pages")
+                f"handoff frames {moves}")
     if fleet_prefix_cache:
         return (f"--fleet-prefix-cache with {m.name}: prefix export and "
-                "spill frame K and V page pairs, not latent pages")
+                f"spill frame {moves}")
     if peer_pool:
-        return (f"--peer-pool with {m.name}: live migration frames K and V "
-                "page pairs, not latent pages")
+        return (f"--peer-pool with {m.name}: live migration frames {moves}")
     return None

@@ -67,7 +67,9 @@ class ModelConfig:
     # OPT-class decoder knobs (reference values-01-minimal-example.yaml:4-8
     # serves facebook/opt-125m). Defaults describe the llama class.
     norm_type: str = "rmsnorm"        # "rmsnorm" | "layernorm" (w/ bias)
-    pos_embedding: str = "rope"       # "rope" | "learned" (+2 OPT offset)
+    # "rope" | "learned" (+2 OPT offset) | "none" (granitemoehybrid's
+    # "nope": the state layers carry the order)
+    pos_embedding: str = "rope"
     mlp_type: str = "swiglu"          # "swiglu" | "mlp" (fc1/act/fc2, biased)
     mlp_act: str = "silu"             # "mlp" type only: "relu" | "gelu"
     # OPT puts biases on the attention out-projection and the MLP.
@@ -84,6 +86,88 @@ class ModelConfig:
     # (hidden/ff/nh*hd) and align with row-shard boundaries under tp.
     quant_group_size: int = 128
     max_model_len: int = 4096
+    # Typed layers (granitemoehybrid class): one entry a layer, "attention"
+    # or "mamba" (a Mamba-2 state mixer); None: every layer is attention.
+    # The pattern is whole repetitions of ``layer_period``, which is what
+    # the layer scan scans. An attention layer holds pages of K|V, a state
+    # layer one fixed slot of recurrent state a sequence
+    # (engine/kv_cache.py); every layer has the dense MLP.
+    layer_types: Optional[tuple] = None
+    # The state mixer's sizes (``mamba_*`` of the HF config): heads x head
+    # width = d_inner; one B and one C of ``mamba_d_state`` a group; a
+    # causal depthwise conv of ``mamba_d_conv`` taps over [x | B | C]; the
+    # segment scan's chunk.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    # Granite's four scalars: h0 = embed * embedding_multiplier; every
+    # residual add takes residual_multiplier * branch; the softmax scale is
+    # attention_multiplier (None: head_dim ** -0.5); logits / logits_scaling.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            return
+        if len(self.layer_types) != self.num_layers:
+            raise ValueError(
+                f"{self.name}: layer_types names {len(self.layer_types)} "
+                f"layers, num_layers is {self.num_layers}")
+        bad = set(self.layer_types) - {"attention", "mamba"}
+        if bad:
+            raise ValueError(f"{self.name}: layer_types {sorted(bad)} are "
+                             "neither 'attention' nor 'mamba'")
+        if self.has_state and self.mamba_n_groups != 1:
+            raise ValueError(
+                f"{self.name}: mamba_n_groups {self.mamba_n_groups}: the "
+                "state mixer shares one B and one C among all heads")
+
+    @property
+    def has_state(self) -> bool:
+        """Whether some layer is a state layer (recurrent state slots beside
+        the pages)."""
+        return self.layer_types is not None and "mamba" in self.layer_types
+
+    @property
+    def layer_period(self) -> tuple:
+        """The shortest run of layer types whose repetition is the whole
+        stack: ("attention",) for a homogeneous model."""
+        types = self.layer_types or ("attention",) * max(self.num_layers, 1)
+        for n in range(1, len(types) + 1):
+            if len(types) % n == 0 and types[:n] * (len(types) // n) == types:
+                return tuple(types[:n])
+        return tuple(types)
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that hold pages: the depth of the K|V pools."""
+        if self.layer_types is None:
+            return self.num_layers
+        return self.layer_types.count("attention")
+
+    @property
+    def num_state_layers(self) -> int:
+        return self.num_layers - self.num_kv_layers
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the depthwise conv: [x | B | C]."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def attn_scale(self) -> float:
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return self.head_dim ** -0.5
 
     @property
     def jnp_dtype(self):
@@ -169,6 +253,20 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         routed_scaling_factor=2.446, rope_theta=800000.0,
         max_model_len=512, dtype="float32",
     ),
+    # granite-4.0-h-micro's block at a size the CPU tests can afford: 2
+    # periods of [2 state, 1 attention, 1 state] (the attention layer INSIDE
+    # the period), a scan chunk of 8, no positional encoding, the four
+    # multipliers all away from 1.
+    "debug-ssm-hybrid": _p(
+        "debug-ssm-hybrid", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=8, num_heads=4, num_kv_heads=2,
+        head_dim=32, pos_embedding="none", tie_word_embeddings=True,
+        layer_types=("mamba", "mamba", "attention", "mamba") * 2,
+        mamba_n_heads=8, mamba_d_head=32, mamba_d_state=16,
+        mamba_chunk_size=8, embedding_multiplier=3.0,
+        residual_multiplier=0.5, attention_multiplier=0.25,
+        logits_scaling=2.0, max_model_len=512, dtype="float32",
+    ),
     # The reference's minimal-example model (values-01-minimal-example.yaml:8).
     "opt-125m": _p(
         "opt-125m", vocab_size=50272, hidden_size=768, intermediate_size=3072,
@@ -236,6 +334,23 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         routed_scaling_factor=2.446, rope_theta=800000.0,
         rms_norm_eps=1e-5, max_model_len=4096,
     ),
+    # ibm-granite/granite-4.0-h-micro (granitemoehybrid): 4 periods of
+    # [5 state, 1 attention, 4 state]; Mamba-2 mixers of 64 heads x 64 with
+    # state 128; GQA attention with no positional encoding and a softmax
+    # scale of 1/64; one SwiGLU of 8192 after every mixer (num_local_experts
+    # 0: the "shared" MLP is the only one); tied head.
+    "granite-4.0-h-micro": _p(
+        "granite-4.0-h-micro", vocab_size=100352, hidden_size=2048,
+        intermediate_size=8192, num_layers=40, num_heads=32, num_kv_heads=8,
+        head_dim=64, pos_embedding="none", tie_word_embeddings=True,
+        rms_norm_eps=1e-5,
+        layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4,
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+        mamba_n_groups=1, mamba_d_conv=4, mamba_chunk_size=256,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.015625, logits_scaling=8.0,
+        max_model_len=4096,
+    ),
 }
 
 
@@ -276,6 +391,15 @@ def apply_hf_overrides(cfg: ModelConfig, overrides: dict) -> ModelConfig:
             raise ValueError(
                 f"--hf-overrides: {key} must be a whole number, not {val!r}")
         fields[HF_SHAPE_KEYS[key]] = val
+    depth = fields.get("num_layers")
+    if cfg.layer_types is not None and depth is not None:
+        period = cfg.layer_period
+        if depth % len(period):
+            raise ValueError(
+                f"--hf-overrides: num_hidden_layers {depth} is not whole "
+                f"periods of {cfg.name}'s layer pattern ({len(period)} "
+                f"layers: {', '.join(period)})")
+        fields["layer_types"] = period * (depth // len(period))
     cfg = cfg.replace(**fields)
     if cfg.is_mla:
         cfg = cfg.replace(head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.sanitize import build_step_sanitizer
-from ..config import EngineConfig, latent_model_refusal
+from ..config import EngineConfig, cache_kind_refusal
 from ..models import llama as model_lib
 from ..observability import Observability
 from ..models.llama import StepMeta
@@ -41,9 +41,10 @@ from ..ops.sampling import (apply_logit_bias, apply_penalties, build_counts,
 from ..resilience.faults import inject as _inject_fault
 from ..utils import cdiv, get_logger
 from .kv_cache import (KVCache, KVPageIO, KVTransferPrograms,
-                       allocate_kv_cache, build_kv_swapper, derive_num_pages,
+                       allocate_kv_cache, build_kv_swapper,
+                       default_state_slots, derive_num_pages,
                        kv_cache_bytes_per_token, kv_cache_dtype,
-                       kv_row_padding_share)
+                       kv_row_padding_share, state_bytes_per_seq)
 from .sampling_params import LOGIT_BIAS_CAP, SamplingParams
 from .scheduler import ScheduledBatch, Scheduler
 from .sequence import FinishReason, Sequence, SequenceStatus
@@ -111,6 +112,31 @@ def _prefill_penalties(cfg, logits, int_t, prompt_lens, presence, frequency):
     return jax.lax.cond(any_pen, penalize, lambda l: l, logits)
 
 
+def _pack_int_b(batch: ScheduledBatch) -> np.ndarray:
+    """The per-row int buffer of the prefill, chunk and mixed programs:
+    [B, 5] = (logits_indices, top_k, seed, prompt_len, top_n), and for a
+    state model the slots after them, one column a kind the batch has
+    (segments', then rows'), so that they ride the upload that is made
+    anyway."""
+    cols = [batch.logits_indices, batch.top_k, batch.seed, batch.prompt_lens,
+            batch.top_n]
+    cols += [c for c in (batch.seg_slots, batch.row_slots) if c is not None]
+    return np.stack(cols, axis=1)
+
+
+def _slot_columns(cfg, int_b, *names, chunk: bool = False) -> dict:
+    """``_pack_int_b``'s slot columns back as StepMeta fields, in its order;
+    nothing for a model without state layers. ``chunk``: the segment part
+    is ONE sequence's chunk, so only the column's first entry is a segment
+    (the forward computes a final state for every entry it is handed)."""
+    if not cfg.has_state:
+        return {}
+    cols = {name: int_b[:, 5 + i] for i, name in enumerate(names)}
+    if chunk:
+        cols["seg_slots"] = cols["seg_slots"][:1]
+    return cols
+
+
 def resolve_shardings(mesh, model_cfg):
     """(params_sharding, kv_sharding) for a serving mesh — the one place
     that picks between GSPMD Megatron layouts (parallel/sharding.py) and the
@@ -143,7 +169,7 @@ class LLMEngine:
                 config, cache=dataclasses.replace(config.cache, page_size=ps))
         self.config = config
         self.model_config = config.model
-        refusal = latent_model_refusal(
+        refusal = cache_kind_refusal(
             config, mesh.shape if mesh is not None else None)
         if refusal is not None:
             raise ValueError(refusal)
@@ -191,10 +217,16 @@ class LLMEngine:
         # stores only 1/tp of a page when kv heads divide tp, so a tp mesh's
         # pool is sized conservatively (about 1/tp of what would fit).
         hbm_free = _device_free_memory(resident=_resident_bytes(self.params))
+        # A state model's slots come before the pages: a seat each and the
+        # scrap slot, a fixed size whatever the contexts (64 seats of
+        # granite-4.0-h-micro: 4.97 GB, beside 6.38 GB of weights).
+        num_state_slots = default_state_slots(
+            config.model, config.scheduler.max_num_seqs)
+        state_bytes = num_state_slots * state_bytes_per_seq(config.model)
         if hbm_free is not None:
             # ... and once the largest step program's own workspace is set
             # aside: hbm_utilization applies to what the POOL can have.
-            hbm_free -= step_workspace_bytes(config)
+            hbm_free -= step_workspace_bytes(config) + state_bytes
         num_pages = derive_num_pages(
             config.model, config.cache, config.effective_max_len,
             config.scheduler.max_num_seqs, hbm_free)
@@ -213,7 +245,8 @@ class LLMEngine:
         # all accumulate here (serving.metrics renders it; /debug/trace
         # exports it; bench.py reads the TTFT decomposition).
         self.obs = Observability()
-        self.scheduler = Scheduler(config, num_pages, obs=self.obs)
+        self.scheduler = Scheduler(config, num_pages, obs=self.obs,
+                                   num_state_slots=num_state_slots)
         if self.scheduler.qos is not None:
             # Per-tier SLO trackers + served counters (bounded label set:
             # the configured tier names). Tiers without their own budget
@@ -227,7 +260,12 @@ class LLMEngine:
                 fallback_budget_ms=config.resilience.default_ttft_budget_ms)
 
         self.kv_cache = allocate_kv_cache(config.model, config.cache, num_pages,
-                                          kv_sharding)
+                                          kv_sharding, num_state_slots)
+        if state_bytes:
+            logger.info("state slots: %d x %d bytes (%d state layers; "
+                        "slot 0 is scrap)", num_state_slots,
+                        state_bytes_per_seq(config.model),
+                        config.model.num_state_layers)
 
         self._prefill_fn = self._build_prefill_fn()
         # Two compiled window programs: all-greedy batches (the common
@@ -404,12 +442,25 @@ class LLMEngine:
             # a latent row included) and the share of them that is padding.
             "weight_bytes": sum(x.size * x.dtype.itemsize
                                 for x in jax.tree.leaves(self.params)),
-            "kv_layout": "latent" if self.model_config.is_mla else "k|v",
+            "kv_layout": ("latent" if self.model_config.is_mla else
+                          "k|v+state" if self.model_config.has_state
+                          else "k|v"),
             "kv_bytes_per_token": kv_cache_bytes_per_token(
                 self.model_config, self.config.cache),
             "kv_row_padding_share": round(
                 kv_row_padding_share(self.model_config), 4),
         }
+        if self.model_config.has_state:
+            # The second kind of memory: layers of each kind, the slots and
+            # what they hold (scrap slot included), a sequence's share.
+            alloc = self.scheduler.allocator
+            info.update(
+                kv_layers=self.model_config.num_kv_layers,
+                state_layers=self.model_config.num_state_layers,
+                state_slots=alloc.num_state_slots,
+                state_bytes_per_seq=state_bytes_per_seq(self.model_config),
+                state_bytes=alloc.num_state_slots
+                * state_bytes_per_seq(self.model_config))
         if self.pallas_disabled_reason is not None:
             info["pallas_disabled_reason"] = self.pallas_disabled_reason
         return info
@@ -421,6 +472,9 @@ class LLMEngine:
                 "swapped": len(sched.swapped), "step": self.step_count,
                 "kv_pages_free": alloc.num_free,
                 "kv_pages_total": alloc.num_pages}
+        if alloc.num_state_slots:
+            snap["state_slots_free"] = alloc.num_free_slots
+            snap["state_slots_total"] = alloc.num_state_slots
         if self.swapper is not None:
             snap["host_pages_in_use"] = self.swapper.host.num_in_use
             snap["host_pages_total"] = self.swapper.host.num_pages
@@ -614,12 +668,22 @@ class LLMEngine:
                   arr((pps,), i32), arr((), i32), arr((), i32))
         # The post-scan KV write, at the pool's real depth (L shapes its
         # VMEM blocks) for the largest decode and prefill flushes.
-        layers = cfg.num_layers // self.pp_size
+        layers = cfg.num_kv_layers // self.pp_size
         deep_pool = arr((layers, 2, ps, nkv * hd), pool.dtype)
         for n in (B, T):
             rows = arr((layers, n, nkv * hd))
             probe(f"kv_write[T={n}]", kv_write, deep_pool, deep_pool,
                   rows, rows, arr((n,), i32))
+        if cfg.has_state:
+            # The state update of a full decode bucket, in place in a pool
+            # of the served slot shape (its depth and slot count shape no
+            # block).
+            from ..ops.pallas.ssm_update import ssm_update
+            f32, N, di = jnp.float32, cfg.mamba_d_state, cfg.mamba_d_inner
+            probe("ssm_update", ssm_update,
+                  arr((cfg.num_state_layers, 2, N, di), f32), arr((), i32),
+                  arr((B,), i32), arr((B, di), f32), arr((B, di), f32),
+                  arr((B, N), f32), arr((B, N), f32))
         logger.info("Pallas kernels compiled at the served geometry: %s",
                     ", ".join(compiled))
 
@@ -726,10 +790,12 @@ class LLMEngine:
                 return (pp_logits(params, cfg, hidden_mb[0], logits_indices),
                         KVCache(k=kvk, v=kvv))
         else:
-            def fwd(params, kv, int_t, logits_indices, moe_load=None):
+            def fwd(params, kv, int_t, logits_indices, moe_load=None,
+                    seg_slots=None):
                 meta = StepMeta(seg_ids=int_t[1], positions=int_t[2],
                                 slot_mapping=int_t[3],
-                                logits_indices=logits_indices)
+                                logits_indices=logits_indices,
+                                seg_slots=seg_slots)
                 hidden, kv, _ = model_lib.forward(
                     params, cfg, int_t[0], meta, kv, kernels,
                     moe_load=moe_load)
@@ -741,9 +807,10 @@ class LLMEngine:
         def prefill_step(params, kv: KVCache, int_t, int_b, float_b,
                          bias_ids, bias_vals, key):
             # int_b: [B, 5] = (logits_indices, top_k, seed, prompt_len,
-            # top_n)
+            # top_n); a state model's has a 6th column, each segment's slot.
             load = [] if reports_load else None
-            logits, kv = fwd(params, kv, int_t, int_b[:, 0], load)
+            logits, kv = fwd(params, kv, int_t, int_b[:, 0], load,
+                             **_slot_columns(cfg, int_b, "seg_slots"))
             logits = _maybe_bias(logits, bias_ids, bias_vals)
             logits = _prefill_penalties(cfg, logits, int_t, int_b[:, 3],
                                         float_b[:, 2], float_b[:, 3])
@@ -807,7 +874,9 @@ class LLMEngine:
                                 slot_mapping=int_t[3],
                                 logits_indices=int_b[:, 0],
                                 chunk_page_table=page_table[0],
-                                hist_len=hist_len)
+                                hist_len=hist_len,
+                                **_slot_columns(cfg, int_b, "seg_slots",
+                                                chunk=True))
                 hidden, kv, _ = model_lib.forward(
                     params, cfg, int_t[0], meta, kv, kernels,
                     moe_load=moe_load)
@@ -869,7 +938,9 @@ class LLMEngine:
                 seg_ids=int_t[1], positions=int_t[2], slot_mapping=int_t[3],
                 logits_indices=int_b[:, 0],
                 chunk_page_table=chunk_page_table[0], hist_len=hist_len,
-                page_tables=page_tables, context_lens=context_lens)
+                page_tables=page_tables, context_lens=context_lens,
+                **_slot_columns(cfg, int_b, "seg_slots", "row_slots",
+                                chunk=True))
             load = [] if reports_load else None
             hidden, kv, _ = model_lib.forward(
                 params, cfg, int_t[0], meta, kv, kernels, moe_load=load)
@@ -1080,7 +1151,14 @@ class LLMEngine:
 
         V = cfg.vocab_size
 
-        def substep_meta(page_tables, pos):
+        def window_tables(int_b):
+            # A state model's int_b ends with one more column: each row's
+            # state slot, which every substep of the window updates.
+            if cfg.has_state:
+                return int_b[:, 4:-1], int_b[:, -1]
+            return int_b[:, 4:], None
+
+        def substep_meta(page_tables, pos, row_slots=None):
             # Window substeps past the model length cap produce tokens the
             # host discards — but their KV writes still happen on device.
             # Route them to the scrap page (page 0) instead of clamping
@@ -1093,26 +1171,27 @@ class LLMEngine:
             in_range = pos < max_len
             slot = jnp.where(in_range, page * ps + pos_c % ps, pos % ps)
             return StepMeta(positions=pos_c, slot_mapping=slot,
-                            page_tables=page_tables, context_lens=pos_c + 1)
+                            page_tables=page_tables, context_lens=pos_c + 1,
+                            row_slots=row_slots)
 
         def decode_window_greedy(params, kv: KVCache, tokens0, int_b,
                                  float_b, key):
             # tokens0: [B] — separate so chained windows can feed the previous
             # window's device-resident output column without a host roundtrip.
             # int_b: [B, pps+4] = (positions, top_k, seed, top_n,
-            # page_table...), float_b: [B, 4] = (temperature, top_p,
+            # page_table...[, state slot]), float_b: [B, 4] = (temperature, top_p,
             # presence, frequency). Slots/context lens are recomputed per
             # sub-step from positions + page tables. The greedy program
             # ignores the sampling columns — it is only dispatched for
             # all-greedy, penalty-free, bias-free batches.
             positions0 = int_b[:, 0]
             any_top = jnp.any(int_b[:, 3] > 0)
-            page_tables = int_b[:, 4:]
+            page_tables, row_slots = window_tables(int_b)
 
             def substep(carry, i):
                 kv, tokens, pos = carry
                 logits, kv = fwd(params, kv, tokens,
-                                 substep_meta(page_tables, pos))
+                                 substep_meta(page_tables, pos, row_slots))
                 next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 lps = token_logprobs(logits, next_tokens)
                 tids, tlps = gated_top_logprobs(logits, any_top)
@@ -1140,7 +1219,7 @@ class LLMEngine:
             top_k = int_b[:, 1]
             seed = int_b[:, 2]
             any_top = jnp.any(int_b[:, 3] > 0)
-            page_tables = int_b[:, 4:]
+            page_tables, row_slots = window_tables(int_b)
             temperature = float_b[:, 0]
             top_p = float_b[:, 1]
             presence = float_b[:, 2]
@@ -1153,7 +1232,7 @@ class LLMEngine:
             def substep(carry, i):
                 kv, counts, tokens, pos = carry
                 logits, kv = fwd(params, kv, tokens,
-                                 substep_meta(page_tables, pos))
+                                 substep_meta(page_tables, pos, row_slots))
                 logits = _maybe_bias(logits, bias_ids, bias_vals)
                 logits = jax.lax.cond(
                     any_pen,
@@ -1281,12 +1360,17 @@ class LLMEngine:
 
     def _require_kv_wire(self, what: str) -> None:
         """The KV wire paths (handoff, migration, prefix export/import,
-        spill) frame K and V page pairs; a latent-page model has one pool
-        and is refused by name, here and at start (latent_model_refusal)."""
+        spill) frame K and V page pairs; a latent-page model has one pool,
+        a state model a slot of recurrent state beside its pages: both are
+        refused by name, here and at start (cache_kind_refusal)."""
         if self.model_config.is_mla:
             raise ValueError(
                 f"{what} with {self.model_config.name}: the KV wire paths "
                 "frame K and V page pairs, not latent pages")
+        if self.model_config.has_state:
+            raise ValueError(
+                f"{what} with {self.model_config.name}: the KV wire paths "
+                "frame pages of K and V, not a sequence's recurrent state")
 
     def _export_state(self, seq: Sequence, k_np, v_np) -> dict:
         """The serialized cross-replica sequence state, built from
@@ -1823,9 +1907,7 @@ class LLMEngine:
                     int_t = jnp.asarray(np.stack(
                         [batch.tokens, batch.seg_ids, batch.positions,
                          batch.slot_mapping]))
-                    int_b = jnp.asarray(np.stack(
-                        [batch.logits_indices, batch.top_k, batch.seed,
-                         batch.prompt_lens, batch.top_n], axis=1))
+                    int_b = jnp.asarray(_pack_int_b(batch))
                     bias_ids, bias_vals = self._bias_arrays(batch)
                 if batch.hist_len is not None:
                     # Chunked prefill (solo): chunk attends to pool history.
@@ -1948,9 +2030,7 @@ class LLMEngine:
             int_t = jnp.asarray(np.stack(
                 [batch.tokens, batch.seg_ids, batch.positions,
                  batch.slot_mapping]))
-            int_b = jnp.asarray(np.stack(
-                [batch.logits_indices, batch.top_k, batch.seed,
-                 batch.prompt_lens, batch.top_n], axis=1))
+            int_b = jnp.asarray(_pack_int_b(batch))
             chunk_pt = jnp.asarray(batch.chunk_page_table)
             page_tables = jnp.asarray(batch.page_tables)
             context_lens = jnp.asarray(batch.context_lens)
@@ -2226,7 +2306,9 @@ class LLMEngine:
         with ph("host_prep"):
             int_b = jnp.asarray(np.concatenate(
                 [np.stack([positions, batch.top_k, batch.seed, batch.top_n],
-                          axis=1), batch.page_tables], axis=1))
+                          axis=1), batch.page_tables]
+                + ([] if batch.row_slots is None
+                   else [batch.row_slots[:, None]]), axis=1))
         self._key, step_key = jax.random.split(self._key)
         greedy = (bool(np.all(batch.temperature <= 0))
                   and not np.any(batch.presence)
@@ -2430,9 +2512,7 @@ class LLMEngine:
                 # release, exactly like the scheduler.finish hold path.
                 self.scheduler.held[seq.request_id] = seq
                 continue
-            if seq.pages:
-                self.scheduler.allocator.free(seq.pages)
-                seq.pages = []
+            self.scheduler._release(seq)    # pages, and a state slot
         self._deferred_release.clear()
 
     # -- convenience --------------------------------------------------------
@@ -2553,7 +2633,7 @@ def step_workspace_bytes(config: EngineConfig) -> int:
     B = sc.decode_buckets[-1]
     it = m.jnp_dtype.itemsize
     kd = m.kv_row_padded
-    kv_rows = (m.kv_pools * m.num_layers * T * kd
+    kv_rows = (m.kv_pools * m.num_kv_layers * T * kd
                * kv_cache_dtype(m, config.cache).itemsize)
     if m.is_mla:
         # Grouped dispatch (models.llama.experts_grouped): the T*k routed
@@ -2572,7 +2652,20 @@ def step_workspace_bytes(config: EngineConfig) -> int:
         attn = T * (m.num_heads * m.head_dim + kd) * 2 * (4 + it)
     resid = 4 * T * m.hidden_size * 4
     sampling = 8 * B * m.vocab_size * 4
-    return kv_rows + mlp + attn + resid + sampling
+    state = 0
+    if m.has_state:
+        # One state layer's chunked scan at a time: the [chunks, heads, Q, Q]
+        # decay-masked products (mask, decay, product), the projection and
+        # the conv over T, and, for every segment a packed prefill may hold
+        # (a decode bucket's worth), its final state with the chunk it was
+        # gathered from; then all layers' conv rows.
+        Q, di = m.mamba_chunk_size, m.mamba_d_inner
+        state = (3 * T * m.mamba_n_heads * Q * 4
+                 + 2 * T * (di + m.mamba_conv_dim) * (4 + it)
+                 + B * (3 * m.mamba_d_state + 2 * Q) * di * 4
+                 + m.num_state_layers * 2 * B * (m.mamba_d_conv - 1)
+                 * m.mamba_conv_dim * it)
+    return kv_rows + mlp + attn + resid + sampling + state
 
 
 def device_memory_stats() -> list[tuple[int, int]]:

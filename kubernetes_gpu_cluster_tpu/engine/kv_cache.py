@@ -54,15 +54,29 @@ logger = get_logger("kv_cache")
 
 # Page 0 never backs real tokens; padding slots scatter into it.
 SCRAP_PAGE = 0
+# State slot 0 belongs to no sequence: padding rows and absent segments
+# update it.
+SCRAP_SLOT = 0
 
 
 class KVCache(NamedTuple):
     """Device-side paged KV pool. The layout comes from the model: K and V,
     each [L, P, page_size, n_kv * head_dim], or, for a latent-attention
     model, ONE pool ``k`` of rows [c | k_pe | pad] ([L, P, page_size,
-    kv_row_padded]) and ``v`` None: there is no V to hold."""
+    kv_row_padded]) and ``v`` None: there is no V to hold. L counts the
+    layers that hold pages (``ModelConfig.num_kv_layers``).
+
+    A state model's state layers keep a fixed SLOT a sequence beside the
+    pages, in two more pools that ride with the page pools wherever those
+    go (donated into every step program, returned by it): ``ssm`` [Ls,
+    slots, N, d_inner] float32, the recurrent state (``ops/ssm.py`` has the
+    layout), and ``conv`` [Ls, slots, d_conv - 1, channels], the conv's
+    last inputs, in the model's dtype. Slot 0 is scrap, as page 0 is. Both
+    None for every other model."""
     k: jax.Array
     v: Optional[jax.Array]
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -83,12 +97,24 @@ def allocate_kv_cache(
     cache: CacheConfig,
     num_pages: int,
     sharding: Optional[jax.sharding.Sharding] = None,
+    num_state_slots: int = 0,
 ) -> KVCache:
+    """The page pools over the layers that hold pages and, for a state
+    model, the two slot pools over its state layers (``num_state_slots``
+    slots, the scrap slot among them)."""
     dtype = kv_cache_dtype(model, cache)
-    shape = (model.num_layers, num_pages, cache.page_size,
+    shape = (model.num_kv_layers, num_pages, cache.page_size,
              model.kv_row_padded)
     def mk():
         return jnp.zeros(shape, dtype=dtype)
+    if model.has_state:
+        Ls = model.num_state_layers
+        return KVCache(
+            k=mk(), v=mk(),
+            ssm=jnp.zeros((Ls, num_state_slots, model.mamba_d_state,
+                           model.mamba_d_inner), STATE_DTYPE),
+            conv=jnp.zeros((Ls, num_state_slots, model.mamba_d_conv - 1,
+                            model.mamba_conv_dim), model.jnp_dtype))
     if model.kv_pools == 1:
         return KVCache(k=mk(), v=None)
     if sharding is not None:
@@ -97,10 +123,36 @@ def allocate_kv_cache(
     return KVCache(k=mk(), v=mk())
 
 
+# The recurrent state is held and updated in float32: rounded to bfloat16 at
+# every token of a 2 k-token context it is a different result (PERF.md
+# section 4: part of the configuration, not a setting). The conv rows are
+# copies of the model's activations and keep its dtype.
+STATE_DTYPE = jnp.float32
+
+
+def default_state_slots(model: ModelConfig, max_num_seqs: int) -> int:
+    """The slots a state model's engine holds: a seat each and the scrap
+    slot 0; none for a model without state layers."""
+    return max_num_seqs + 1 if model.has_state else 0
+
+
+def state_bytes_per_seq(model: ModelConfig) -> int:
+    """Bytes one sequence's slot holds over all state layers: the state and
+    the conv rows. 0 for a model without state layers."""
+    if not model.has_state:
+        return 0
+    return model.num_state_layers * (
+        model.mamba_d_state * model.mamba_d_inner
+        * jnp.dtype(STATE_DTYPE).itemsize
+        + (model.mamba_d_conv - 1) * model.mamba_conv_dim
+        * model.jnp_dtype.itemsize)
+
+
 def kv_cache_bytes_per_token(model: ModelConfig, cache: CacheConfig) -> int:
-    """Bytes one cached token really holds over all layers, padding of a
-    latent row included (``kv_row_padding_share`` says how much of it)."""
-    return (model.kv_pools * model.num_layers * model.kv_row_padded
+    """Bytes one cached token really holds over the layers that hold pages,
+    padding of a latent row included (``kv_row_padding_share`` says how much
+    of it)."""
+    return (model.kv_pools * model.num_kv_layers * model.kv_row_padded
             * kv_cache_dtype(model, cache).itemsize)
 
 
@@ -142,19 +194,54 @@ def derive_num_pages(
 class PageAllocator:
     """Free-list page allocator with refcounts (enables future copy-on-write
     prefix sharing). All operations O(1) amortized. Host-side only — the device
-    never sees this object, just the block tables it produces."""
+    never sees this object, just the block tables it produces.
 
-    def __init__(self, num_pages: int, page_size: int):
+    The SAME manager hands out a state model's second kind of per-sequence
+    memory: ``num_state_slots`` fixed slots of recurrent state (slot 0 is
+    scrap, as page 0 is), one a sequence from admission to finish or
+    preemption. A sequence is admitted only if its pages AND a slot fit
+    (``can_admit``); a slot never grows and is never shared."""
+
+    def __init__(self, num_pages: int, page_size: int,
+                 num_state_slots: int = 0):
         assert num_pages >= 2, "need at least scrap page + 1 usable page"
         self.num_pages = num_pages
         self.page_size = page_size
         # Page 0 is the scrap page and never allocatable.
         self._free: list[int] = list(range(num_pages - 1, 0, -1))
         self._refcount: dict[int, int] = {}
+        self.num_state_slots = num_state_slots
+        self._free_slots: list[int] = list(range(num_state_slots - 1, 0, -1))
 
     @property
     def num_free(self) -> int:
         return len(self._free)
+
+    @property
+    def num_free_slots(self) -> int:
+        return len(self._free_slots)
+
+    def can_admit(self, n_pages: int) -> bool:
+        """Whether a NEW sequence fits: its pages and, for a state model, a
+        slot. The slot is asked first: ``can_allocate`` may evict."""
+        if self.num_state_slots and not self._free_slots:
+            return False
+        return self.can_allocate(n_pages)
+
+    def allocate_slot(self) -> Optional[int]:
+        """A state slot for a sequence being admitted; None for a model
+        that has none."""
+        if not self.num_state_slots:
+            return None
+        if not self._free_slots:
+            raise RuntimeError("state slots exhausted")
+        return self._free_slots.pop()
+
+    def free_slot(self, slot: Optional[int]) -> None:
+        if slot is not None:
+            if slot in self._free_slots or not 0 < slot < self.num_state_slots:
+                raise RuntimeError(f"double free of state slot {slot}")
+            self._free_slots.append(slot)
 
     def can_allocate(self, n: int) -> bool:
         return len(self._free) >= n
@@ -769,8 +856,9 @@ class CachingPageAllocator(PageAllocator):
     pressure, so every existing can_allocate/allocate call site (scheduler
     admission, decode window growth, chunk growth) gets eviction for free."""
 
-    def __init__(self, num_pages: int, page_size: int):
-        super().__init__(num_pages, page_size)
+    def __init__(self, num_pages: int, page_size: int,
+                 num_state_slots: int = 0):
+        super().__init__(num_pages, page_size, num_state_slots)
         self.prefix_cache = PrefixCache(self)
 
     def can_allocate(self, n: int) -> bool:

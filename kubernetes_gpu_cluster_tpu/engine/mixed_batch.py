@@ -216,6 +216,8 @@ def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     end = head.num_prefilled + chunk
     final = end >= head.num_tokens
     need = cdiv(end, sched.page_size) - len(head.pages)
+    if sched.needs_slot(head) and not sched.allocator.num_free_slots:
+        return None     # a state model's head waits for a slot as for pages
     if need > 0:
         if not sched.allocator.can_allocate(need):
             # Never preempt running decodes to feed a prefill chunk; the
@@ -223,6 +225,8 @@ def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
             # admission, capacity termination when the pool drains).
             return None
         head.pages.extend(sched.allocator.allocate(need))
+    if sched.needs_slot(head):
+        head.state_slot = sched.allocator.allocate_slot()
 
     D = len(decode_seqs)
     Tp = _bucket(chunk, sc.prefill_buckets)
@@ -270,6 +274,8 @@ def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
         logits_indices=logits_indices, page_tables=page_tables,
         context_lens=context_lens, chunk_page_table=chunk_page_table,
         hist_len=hist_len, partial=not final, prefill_token_count=chunk,
+        seg_slots=sched._state_slots([head], R_pad),
+        row_slots=sched._state_slots(decode_seqs, R_pad),
         **sched._sampling_arrays(seqs, R_pad))
 
 

@@ -34,7 +34,8 @@ from ..config import EngineConfig
 from ..observability import Observability
 from ..utils import cdiv, get_logger
 from ..utils.math import next_power_of_2
-from .kv_cache import CachingPageAllocator, PageAllocator
+from .kv_cache import (CachingPageAllocator, PageAllocator,
+                       default_state_slots)
 from .qos import build_qos
 from .sequence import FinishReason, Sequence, SequenceStatus
 
@@ -58,6 +59,11 @@ class ScheduledBatch:
     # decode + mixed (decode rows)
     page_tables: Optional[np.ndarray] = None      # [B_pad, pages_bucket]
     context_lens: Optional[np.ndarray] = None     # [B_pad]
+    # a state model only: the state slot of each segment [B_pad] (prefill,
+    # chunk, mixed: the chunk's first) and of each decode row [B_pad];
+    # padding names the scrap slot 0
+    seg_slots: Optional[np.ndarray] = None
+    row_slots: Optional[np.ndarray] = None
     # chunked prefill only (solo batch): history length + this seq's pages
     # (in page_tables [1, pages_bucket]); partial = prompt not yet complete
     # after this chunk (the sampled token is discarded).
@@ -115,7 +121,8 @@ def _bucket(value: int, buckets: tuple[int, ...]) -> int:
 
 class Scheduler:
     def __init__(self, config: EngineConfig, num_pages: int,
-                 obs: Optional[Observability] = None):
+                 obs: Optional[Observability] = None,
+                 num_state_slots: Optional[int] = None):
         # The engine shares its Observability so scheduler-side lifecycle
         # events (queued/scheduled/chunk/preempt/terminal) land in the same
         # trace ring as the step loop's; standalone construction (tests)
@@ -151,11 +158,21 @@ class Scheduler:
         self.decode_buckets = sc.decode_buckets
         self.prefill_buckets = sc.prefill_buckets
         self.page_size = config.cache.page_size
+        # A state model's slots, the second resource of the same manager: a
+        # seat each and the scrap slot, unless the caller holds fewer. (A
+        # mid-chunk queue head holds one beside the running sequences', so
+        # admission can find pages and seats free and still have to wait.)
+        self.has_state = config.model.has_state
+        if num_state_slots is None:
+            num_state_slots = default_state_slots(config.model,
+                                                  sc.max_num_seqs)
         if sc.enable_prefix_caching:
-            self.allocator = CachingPageAllocator(num_pages, self.page_size)
+            self.allocator = CachingPageAllocator(num_pages, self.page_size,
+                                                  num_state_slots)
             self.prefix_cache = self.allocator.prefix_cache
         else:
-            self.allocator = PageAllocator(num_pages, self.page_size)
+            self.allocator = PageAllocator(num_pages, self.page_size,
+                                           num_state_slots)
             self.prefix_cache = None
         self.waiting: deque[Sequence] = deque()
         self.running: list[Sequence] = []
@@ -249,6 +266,8 @@ class Scheduler:
         if seq.pages:
             self.allocator.free(seq.pages)
             seq.pages = []
+        self.allocator.free_slot(seq.state_slot)
+        seq.state_slot = None
         if seq.host_pages and self.swapper is not None:
             self.swapper.free_host(seq.host_pages)
             seq.host_pages = []
@@ -750,7 +769,7 @@ class Scheduler:
             # Budget first: can_allocate may EVICT prefix-cache entries to
             # satisfy the probe, which must not happen for candidates the
             # token budget rejects anyway.
-            fits_pages = fits_budget and self.allocator.can_allocate(need)
+            fits_pages = fits_budget and self.allocator.can_admit(need)
             if not fits_pages and i == 0 and not self.running and not admitted:
                 # Pool is empty and the head still doesn't fit: it has grown
                 # (via preempt-recompute) past total capacity and can never be
@@ -775,6 +794,7 @@ class Scheduler:
                 i += 1
                 continue
             seq.pages = self.allocator.allocate(need)
+            seq.state_slot = self.allocator.allocate_slot()
             del self.waiting[i]
             admitted.append(seq)
             total_tokens += seq.num_tokens
@@ -808,7 +828,9 @@ class Scheduler:
         return ScheduledBatch(
             kind="prefill", seqs=admitted, tokens=tokens, positions=positions,
             slot_mapping=slot_mapping, seg_ids=seg_ids,
-            logits_indices=logits_indices, **self._sampling_arrays(admitted, B))
+            logits_indices=logits_indices,
+            seg_slots=self._state_slots(admitted, B),
+            **self._sampling_arrays(admitted, B))
 
     def _schedule_chunk(self, seq: Sequence) -> Optional[ScheduledBatch]:
         """One chunk of a long prompt, admitted solo: tokens
@@ -822,6 +844,8 @@ class Scheduler:
             return None
         end = seq.num_prefilled + chunk
         need = cdiv(end, self.page_size) - len(seq.pages)
+        if self.needs_slot(seq) and not self.allocator.num_free_slots:
+            return None        # wait for a finish to free a state slot
         if need > 0 and not self.allocator.can_allocate(need):
             usable = self.allocator.num_pages - 1
             if not self.running and cdiv(end, self.page_size) > usable:
@@ -838,6 +862,8 @@ class Scheduler:
             return None        # wait for decode finishes to free pages
         if need > 0:
             seq.pages.extend(self.allocator.allocate(need))
+        if self.needs_slot(seq):
+            seq.state_slot = self.allocator.allocate_slot()
 
         partial = end < seq.num_tokens
         T = _bucket(chunk, self.prefill_buckets)
@@ -881,7 +907,22 @@ class Scheduler:
             slot_mapping=slot_mapping, seg_ids=seg_ids,
             logits_indices=logits_indices, page_tables=page_table,
             hist_len=hist_len, partial=partial,
+            seg_slots=self._state_slots([seq], B),
             **self._sampling_arrays([seq], B))
+
+    def needs_slot(self, seq: Sequence) -> bool:
+        """Whether a state model's sequence is still without its slot (its
+        first chunk has not been scheduled)."""
+        return self.has_state and seq.state_slot is None
+
+    def _state_slots(self, seqs: list[Sequence], n: int
+                     ) -> Optional[np.ndarray]:
+        """[n] int32: the state slots of ``seqs``, padded with the scrap
+        slot; None for a model without state layers."""
+        if not self.has_state:
+            return None
+        held = [s.state_slot for s in seqs]
+        return np.asarray(held + [0] * (n - len(held)), np.int32)
 
     def _chunk_page_table(self, seq: Sequence) -> np.ndarray:
         """[1, width] page table for a chunk's history attention. Width
@@ -1011,7 +1052,9 @@ class Scheduler:
         return ScheduledBatch(
             kind="decode", seqs=scheduled, tokens=tokens, positions=positions,
             slot_mapping=slot_mapping, page_tables=page_tables,
-            context_lens=context_lens, **self._sampling_arrays(scheduled, B))
+            context_lens=context_lens,
+            row_slots=self._state_slots(scheduled, B),
+            **self._sampling_arrays(scheduled, B))
 
     def _sampling_arrays(self, seqs: list[Sequence], B: int,
                          rows: Optional[list[int]] = None) -> dict:

@@ -43,6 +43,11 @@ class Sequence:
         self.status = SequenceStatus.WAITING
         self.finish_reason: Optional[FinishReason] = None
         self.pages: list[int] = []
+        # A state model's second kind of memory: the slot of recurrent
+        # state the sequence holds from admission to finish or preemption
+        # (engine/kv_cache.PageAllocator); None before, after, and always
+        # for a model without state layers.
+        self.state_slot: Optional[int] = None
         # Two-tier KV cache: host-pool page ids holding this sequence's
         # committed KV while it is preempted-by-swap (engine/kv_cache).
         self.host_pages: list[int] = []

@@ -57,6 +57,9 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
     if hf.get("kv_lora_rank"):
         return _deepseek_config_from_hf(
             hf, name or os.path.basename(os.path.normpath(path)))
+    if hf.get("model_type") == "granitemoehybrid":
+        return _granite_hybrid_config_from_hf(
+            hf, name or os.path.basename(os.path.normpath(path)))
     num_heads = hf["num_attention_heads"]
     head_dim = hf.get("head_dim") or hf["hidden_size"] // num_heads
     rope_scaling = None
@@ -120,6 +123,50 @@ def _deepseek_config_from_hf(hf: dict, name: str) -> ModelConfig:
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         **fields)
+
+
+def _granite_hybrid_config_from_hf(hf: dict, name: str) -> ModelConfig:
+    """granitemoehybrid (granite-4.0-h): Mamba-2 state layers and GQA
+    attention layers by ``layer_types``, one SwiGLU a layer, four scalar
+    multipliers. What the decoder does not implement refuses the load by
+    name (the family's expert variants among it)."""
+    for key, ok in (
+            ("num_local_experts", not hf.get("num_local_experts")),
+            ("position_embedding_type",
+             hf.get("position_embedding_type", "nope") in ("nope", None)),
+            ("mamba_n_groups", hf.get("mamba_n_groups", 1) == 1),
+            ("mamba_proj_bias", not hf.get("mamba_proj_bias")),
+            ("mamba_conv_bias", hf.get("mamba_conv_bias", True)),
+            ("mamba_expand", hf.get("mamba_expand", 2) * hf["hidden_size"]
+             == hf["mamba_n_heads"] * hf["mamba_d_head"]),
+            ("time_step_limit", "time_step_limit" not in hf),
+            ("hidden_act", hf.get("hidden_act", "silu") == "silu"),
+            ("attention_bias", not hf.get("attention_bias"))):
+        if not ok:
+            raise ValueError(
+                f"{name}: config.json {key}={hf.get(key)!r} is not "
+                "implemented by the state-layer decoder")
+    num_heads = hf["num_attention_heads"]
+    return ModelConfig(
+        name=name, vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["shared_intermediate_size"],
+        num_layers=hf["num_hidden_layers"], num_heads=num_heads,
+        num_kv_heads=hf.get("num_key_value_heads", num_heads),
+        head_dim=hf["hidden_size"] // num_heads, pos_embedding="none",
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        layer_types=tuple(hf["layer_types"]),
+        mamba_n_heads=hf["mamba_n_heads"], mamba_d_head=hf["mamba_d_head"],
+        mamba_d_state=hf["mamba_d_state"], mamba_n_groups=1,
+        mamba_d_conv=hf["mamba_d_conv"],
+        mamba_chunk_size=hf.get("mamba_chunk_size", 256),
+        embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+        attention_multiplier=float(hf["attention_multiplier"]),
+        logits_scaling=float(hf.get("logits_scaling", 1.0)),
+        max_model_len=min(int(hf.get("max_position_embeddings", 4096)), 8192),
+    )
 
 
 def _validate_act(act: str) -> str:
@@ -626,6 +673,11 @@ def load_weights(path: str, cfg: ModelConfig,
             raise ValueError(f"{cfg.name}: a latent-attention model loads "
                              "onto one device (no sharded placement)")
         return _place(_load_deepseek_host(ckpt, cfg), cfg, dtype, None)
+    if cfg.has_state:
+        if shardings is not None:
+            raise ValueError(f"{cfg.name}: a state model loads onto one "
+                             "device (no sharded placement)")
+        return _place(_load_granite_hybrid_host(ckpt, cfg), cfg, dtype, None)
     if shardings is not None:
         return _load_streamed(ckpt, cfg, shardings, dtype)
     L = cfg.num_layers
@@ -720,6 +772,9 @@ def _place(params: Params, cfg: ModelConfig, dtype,
                 or name == "router_bias"):
             # int8 weights, f32 scales and the f32 choice bias as they are
             return np.ascontiguousarray(x)
+        if name in ("A_log", "dt_bias", "D"):
+            # a state layer's recurrence vectors stay float32
+            return np.ascontiguousarray(np.asarray(x, np.float32))
         return np.ascontiguousarray(np.asarray(x, dtype=dtype))
 
     params = jax.tree_util.tree_map_with_path(put, params)
@@ -813,6 +868,66 @@ def _load_deepseek_host(ckpt: _Checkpoint, cfg: ModelConfig) -> Params:
         params["dense_layers"] = stacked(range(Ld))
     if not cfg.tie_word_embeddings:
         params["lm_head"] = ckpt.get_t(root + "lm_head.weight")
+    return params
+
+
+def _load_granite_hybrid_host(ckpt: _Checkpoint, cfg: ModelConfig) -> Params:
+    """granitemoehybrid HF checkpoint -> the ``layers`` (attention) +
+    ``ssm_layers`` (state) tree of models/llama.py (host numpy), each stack
+    in the order its kind appears in ``cfg.layer_types``. Every layer's
+    ``shared_mlp.input_linear`` [2 ff, d] is split into ``w_gate`` | ``w_up``
+    (the published code chunks it in that order); the conv's weight
+    [channels, 1, K] is stored [K, channels]; ``mamba.in_proj``'s columns [z
+    | x B C | dt], in the published order, are split into ``w_z``, ``w_xbc``
+    and ``w_dt`` (models.llama._init_state_layers says why)."""
+    ff = cfg.intermediate_size
+    pre = "model.layers.{}."
+
+    def mlp(p: str) -> Params:
+        w_in = ckpt.get_t(p + "shared_mlp.input_linear.weight")  # [d, 2 ff]
+        return {
+            "input_norm": ckpt.get(p + "input_layernorm.weight"),
+            "post_attn_norm": ckpt.get(p + "post_attention_layernorm.weight"),
+            "w_gate": w_in[:, :ff], "w_up": w_in[:, ff:],
+            "w_down": ckpt.get_t(p + "shared_mlp.output_linear.weight"),
+        }
+
+    def layer(l: int) -> Params:
+        p = pre.format(l)
+        if cfg.layer_types[l] == "attention":
+            return {**mlp(p), **{
+                ours: ckpt.get_t(f"{p}self_attn.{theirs}_proj.weight")
+                for ours, theirs in (("wq", "q"), ("wk", "k"), ("wv", "v"),
+                                     ("wo", "o"))}}
+        m = p + "mamba."
+        in_proj = ckpt.get_t(m + "in_proj.weight")       # [d, di + C + H]
+        return {
+            **mlp(p),
+            "w_z": in_proj[:, :cfg.mamba_d_inner],
+            "w_xbc": in_proj[:, cfg.mamba_d_inner:-cfg.mamba_n_heads],
+            "w_dt": in_proj[:, -cfg.mamba_n_heads:],
+            "conv_w": np.ascontiguousarray(
+                ckpt.get(m + "conv1d.weight")[:, 0, :].T),
+            "conv_b": ckpt.get(m + "conv1d.bias"),
+            "dt_bias": ckpt.get(m + "dt_bias"),
+            "A_log": ckpt.get(m + "A_log"),
+            "D": ckpt.get(m + "D"),
+            "ssm_norm": ckpt.get(m + "norm.weight"),
+            "w_out": ckpt.get_t(m + "out_proj.weight"),
+        }
+
+    def stacked(kind: str) -> Params:
+        per = [layer(l) for l, t in enumerate(cfg.layer_types) if t == kind]
+        return {k: np.stack([p[k] for p in per]) for k in per[0]}
+
+    params: Params = {
+        "embed": ckpt.get("model.embed_tokens.weight"),
+        "final_norm": ckpt.get("model.norm.weight"),
+        "layers": stacked("attention"),
+        "ssm_layers": stacked("mamba"),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = ckpt.get_t("lm_head.weight")
     return params
 
 

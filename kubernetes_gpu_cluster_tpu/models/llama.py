@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from ..config import ModelConfig
 from ..engine.kv_cache import KVCache
 from ..ops import quant as quant_ops
+from ..ops import ssm as ssm_ops
 from ..ops.rope import apply_rope, rope_cos_sin
 from ..ops.attention import NO_KERNELS, Kernels
 from ..ops.pallas import grouped_matmul as gm
@@ -76,6 +77,12 @@ class StepMeta(NamedTuple):
     # committed tokens including the row's first.
     page_tables: Optional[jax.Array] = None
     context_lens: Optional[jax.Array] = None
+    # A state model's slots (engine/kv_cache.py): [S] int32 the slot of
+    # segment s (S is static: the most segments the step can hold; absent
+    # ones name the scrap slot 0), and [R] int32 the slot of each row
+    # (padding rows: the scrap slot).
+    seg_slots: Optional[jax.Array] = None
+    row_slots: Optional[jax.Array] = None
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +107,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = N
     if cfg.is_mla or cfg.num_dense_layers or cfg.num_shared_experts:
         return _init_params_deepseek(cfg, key, dtype, w)
 
-    d, L = cfg.hidden_size, cfg.num_layers
+    d, L = cfg.hidden_size, cfg.num_kv_layers
     nh, nkv, hd, ff = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.intermediate_size
     E = cfg.num_experts
     keys = iter(jax.random.split(key, 16))
@@ -143,7 +150,56 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = N
         params["pos_embed"] = w(next(keys), (cfg.max_model_len + 2, d), d)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (d, cfg.vocab_size), d)
+    if cfg.has_state:
+        params["ssm_layers"] = _init_state_layers(cfg, next(keys), dtype, w)
     return params
+
+
+def _init_state_layers(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
+    """Random init of the state layers' stack ``[num_state_layers, ...]``
+    (the attention layers of a typed model are ``layers``, as everywhere).
+    The mixer's in-projection is stored in three pieces, ``w_z`` [d,
+    d_inner], ``w_xbc`` [d, d_inner + 2 N] (columns [x | B | C]) and
+    ``w_dt`` [d, heads]: the checkpoint's ``in_proj`` is their
+    concatenation. (Whole, its 8512 columns are not whole 128-lane tiles:
+    the chip keeps it transposed and every step program copies 1.26 GB
+    back. And z is consumed at the gate, xBC at once: as one product of a
+    2 k-token step XLA computed it twice rather than keep it.)
+    ``conv_w`` is [K, channels], tap K-1 on the token itself. The recurrence's own parameters are drawn as
+    ``mamba_ssm`` initialises them, so that the state neither dies nor
+    explodes over thousands of tokens: ``A_log = log U(1, 16)``, ``dt_bias =
+    softplus^-1(log-uniform(1e-3, 1e-1))``, ``D = 1``, the conv (weight and
+    bias) uniform in +-1/2 (torch's conv1d default at a fan-in of 4). Those
+    three vectors a head stay float32, whatever the model's dtype."""
+    if cfg.is_moe or cfg.is_mla or cfg.quantization is not None:
+        raise ValueError(
+            f"{cfg.name}: state layers are served beside dense-precision "
+            "GQA attention layers and dense MLPs only")
+    d, ff, S = cfg.hidden_size, cfg.intermediate_size, cfg.num_state_layers
+    H, di, C = cfg.mamba_n_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
+    K = cfg.mamba_d_conv
+    keys = iter(jax.random.split(key, 12))
+    u = jax.random.uniform
+    dt = jnp.exp(u(next(keys), (S, H), jnp.float32,
+                   jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "input_norm": jnp.ones((S, d), dtype),
+        "post_attn_norm": jnp.ones((S, d), dtype),
+        "w_z": w(next(keys), (S, d, di), d),
+        "w_xbc": w(next(keys), (S, d, C), d),
+        "w_dt": w(next(keys), (S, d, H), d),
+        "conv_w": u(next(keys), (S, K, C), jnp.float32, -0.5, 0.5
+                    ).astype(dtype),
+        "conv_b": u(next(keys), (S, C), jnp.float32, -0.5, 0.5).astype(dtype),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(u(next(keys), (S, H), jnp.float32, 1.0, 16.0)),
+        "D": jnp.ones((S, H), jnp.float32),
+        "ssm_norm": jnp.ones((S, di), dtype),
+        "w_out": w(next(keys), (S, di, d), di),
+        "w_gate": w(next(keys), (S, d, ff), d),
+        "w_up": w(next(keys), (S, d, ff), d),
+        "w_down": w(next(keys), (S, ff, d), ff),
+    }
 
 
 def _stacked_normal(key, shape, fan_in: int, dtype) -> jax.Array:
@@ -359,6 +415,8 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array,
     """Token embedding lookup, plus OPT-class learned positional embeddings
     (HF OPTLearnedPositionalEmbedding keeps a +2 offset into the table)."""
     h = params["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
     if cfg.pos_embedding == "learned":
         h = h + params["pos_embed"][positions + 2]
     return h
@@ -768,6 +826,95 @@ def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
     return jax.lax.cond(hist_len == 0, fresh, with_history, None)
 
 
+def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
+                n_seg: int, ssm: jax.Array, conv: jax.Array,
+                layer: jax.Array, kernels: Kernels = NO_KERNELS):
+    """The Mamba-2 mixer of one state layer over the step's token axis
+    ``[segment tokens | row tokens]``. x: [T, d], the layer's normed input.
+
+        z = x W_z;   xBC = x W_xbc;   dt = x W_dt      (in_proj, in three pieces)
+        xBC = silu(causal_depthwise_conv(xBC) + b);   [x | B | C] = xBC
+        dt = softplus(dt + dt_bias);   A = -exp(A_log)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;   y_t = S_t C_t + D x_t
+        out = (RMSNorm(y * silu(z)) * w_norm) W_out
+
+    ``time_step_limit`` is (0, inf) in every served config: the clamp of dt
+    is the identity and is not written. One group: B and C are shared by
+    all heads, and the gated norm runs over all of d_inner.
+
+    The segment part runs the conv and the chunked scan
+    (``ops.ssm.ssm_chunk_scan_xla``) within its segments; a chunk with
+    history (``meta.hist_len`` > 0) starts from its slot, anything else from
+    zero WHATEVER the slot held; each segment's final state goes to its
+    slot. The row part is the one-token update of each row's slot
+    (``kernels.ssm_update``: in place). ``ssm`` [Ls, slots, N, d_inner]
+    float32 is threaded (the scans carry it); ``conv`` [Ls, slots, K-1,
+    channels] is read as the step found it, and this layer's new rows come
+    back for the one write behind the scan, as new K/V do.
+    Returns (out [T, d] float32, ssm, new conv rows [S + R, K-1, channels]
+    for ``meta.seg_slots`` then ``meta.row_slots``)."""
+    H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    di, C = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    f32 = jnp.float32
+    with jax.named_scope("kgct.ssm.proj"):
+        xbc = _dot(x, lp, "w_xbc").astype(x.dtype)                   # [T, C]
+        dt = jax.nn.softplus(_dot(x, lp, "w_dt") + lp["dt_bias"])    # [T, H]
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        D = jnp.repeat(lp["D"].astype(f32), P)
+    ys, conv_new = [], []
+    if n_seg:
+        seg = meta.seg_ids[:n_seg]
+        n_segs = meta.seg_slots.shape[0]
+        seg_ends = jnp.max(jnp.where(
+            seg[None, :] == jnp.arange(n_segs)[:, None],
+            jnp.arange(n_seg)[None, :], -1), axis=1)
+        # A chunk with history continues from its slot; the step programs
+        # without one (packed whole prompts) have no such read at all.
+        resumes = None if meta.hist_len is None else meta.hist_len > 0
+        slot0 = meta.seg_slots[0]
+
+        def start(pool):
+            first = jnp.zeros(pool.shape[2:], pool.dtype)
+            if resumes is None:
+                return first
+            return jnp.where(resumes, pool[layer, slot0], first)
+
+        with jax.named_scope("kgct.ssm.conv"):
+            c_out, rows = ssm_ops.conv_segments(
+                xbc[:n_seg], seg, seg_ends, start(conv), lp["conv_w"],
+                lp["conv_b"])
+            xs = jax.nn.silu(c_out).astype(x.dtype)
+            conv_new.append(rows)
+        with jax.named_scope("kgct.ssm.scan"):
+            y, final = ssm_ops.ssm_chunk_scan_xla(
+                xs[:, :di].reshape(n_seg, H, P), dt[:n_seg],
+                dt[:n_seg] * A, xs[:, di:di + N], xs[:, di + N:], seg,
+                seg_ends, start(ssm), 0 if resumes is not None else -2,
+                cfg.mamba_chunk_size)
+            ssm = ssm_ops.write_slots(ssm, final, meta.seg_slots, layer)
+            ys.append(y.reshape(n_seg, di) + D * xs[:, :di].astype(f32))
+    if x.shape[0] > n_seg:
+        slots = meta.row_slots
+        with jax.named_scope("kgct.ssm.conv"):
+            c_out, rows = ssm_ops.conv_rows(
+                xbc[n_seg:], conv[layer, slots], lp["conv_w"], lp["conv_b"])
+            xr = jax.nn.silu(c_out).astype(x.dtype).astype(f32)
+            conv_new.append(rows)
+        with jax.named_scope("kgct.ssm.update"):
+            dt_r = dt[n_seg:]
+            ssm, y = kernels.ssm_update(
+                ssm, layer, slots, jnp.repeat(jnp.exp(dt_r * A), P, axis=-1),
+                jnp.repeat(dt_r, P, axis=-1) * xr[:, :di],
+                xr[:, di:di + N], xr[:, di + N:])
+            ys.append(y + D * xr[:, :di])
+    with jax.named_scope("kgct.ssm.gate"):
+        g = jnp.concatenate(ys, axis=0) * jax.nn.silu(_dot(x, lp, "w_z"))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        out = _dot(g.astype(x.dtype) * lp["ssm_norm"], lp, "w_out")
+    return out, ssm, jnp.concatenate(conv_new, axis=0)
+
+
 # ---------------------------------------------------------------------------
 # Forward passes (scan over stacked layers; attn addresses the pool by index)
 # ---------------------------------------------------------------------------
@@ -779,8 +926,16 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                 ep_axis: Optional[str] = None,
                 moe_load: Optional[list] = None,
                 valid: Optional[jax.Array] = None,
-                ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Scan the layer body over stacked weights.
+                state_fn=None, ssm: Optional[jax.Array] = None):
+    """Scan ONE PERIOD of typed layers over the stacked weights: the
+    shortest run of layer types whose repetition is the stack
+    (``cfg.layer_period``). A homogeneous model's period is one attention
+    layer, and its scan is the scan over its layers; granite-4.0-h-micro's
+    is [5 state, 1 attention, 4 state], scanned 4 times, with an inner scan
+    over each run of state layers, so the program holds one attention body
+    and two state bodies whatever the depth. Pool layer indices count per
+    kind: an attention layer addresses layer ``i`` of the K|V pools, a state
+    layer layer ``j`` of the slot pools.
 
     The KV pool does NOT travel through the scan: it is closed over whole and
     ``attn_fn`` receives the LAYER INDEX (scanned as xs) to address it.
@@ -807,7 +962,8 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     they stay None and the SPMD partitioner inserts the equivalent
     collectives.
 
-    Returns (h, k_all, v_all) with k_all/v_all: [L, T, n_kv_local * hd] —
+    Returns (h, k_all, v_all, ssm, conv_rows) with k_all/v_all:
+    [attention layers, T, n_kv_local * hd] —
     heads flattened per layer, the pool's own row layout, so the post-scan
     write consumes the scan's output buffers as they are (flattening the
     stacked [L, T, n_kv, hd] afterwards is a relayout: a second full copy
@@ -815,6 +971,15 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     A latent-attention model (``cfg.is_mla``) has one pool: k_all is its
     rows [L, T, kv_row_padded], v_all is None, and ``attn_fn`` is handed
     (lp, q [T, nh, nope + rope], row [T, kv_row_padded], None, layer_idx).
+
+    ``state_fn(lp, x, ssm, layer_idx) -> (out, ssm, conv_rows)`` is the
+    state layers' mixer (``state_mixer`` under the step's meta). ``ssm``,
+    the recurrent-state pool, IS carried through the scans: its update is a
+    read-modify-write of the rows' own slots, in place (a Pallas call that
+    aliases the pool, or dynamic_update_slices), and a layer's output needs
+    the updated state, so it cannot wait for the end of the scan as the
+    page write does. The conv rows do wait: they come back stacked
+    [state layers, S + R, ...].
 
     ``moe_load``: a list the caller owns; where the stack has expert layers
     the step's real routed pairs of each expert of each layer, [n_layers, E]
@@ -824,9 +989,31 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     step's real tokens, for the expert layers (``_moe_mlp``).
     """
     int4 = kernels.int4_pallas
+    r = cfg.residual_multiplier
 
-    def body(h, xs):
-        lp, layer_idx = xs
+    def scaled(branch):     # granite: h += r * branch (r == 1: nothing)
+        return branch if r == 1.0 else branch * r
+
+    def at(stack, i):
+        """Layer ``i`` of a stack, read where it lies. (A slice of a scan's
+        xs handed to an inner scan is a COPY of those layers' weights every
+        step: 1.4 GB a period at granite-4.0-h-micro's widths.)"""
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, i, 0, keepdims=False), stack)
+
+    def state_layer(carry, layer_idx):
+        h, ssm = carry
+        lp = at(params["ssm_layers"], layer_idx)
+        x = _norm(cfg, h, lp, "input_norm")
+        with jax.named_scope("kgct.ssm"):
+            out, ssm, conv_rows = state_fn(lp, x, ssm, layer_idx)
+        h = h + scaled(out).astype(h.dtype)
+        x = _norm(cfg, h, lp, "post_attn_norm")
+        h = h + scaled(_dense_mlp(lp, x, cfg, tp_axis=tp_axis,
+                                  use_pallas=int4))
+        return (h, ssm), conv_rows
+
+    def attn_layer(h, lp, layer_idx):
         resid = h
         x = _norm(cfg, h, lp, "input_norm")
         if cfg.is_mla:
@@ -842,7 +1029,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
             o = jax.lax.psum(o, tp_axis)
         if "bo" in lp:           # after the reduce: applied exactly once
             o = o + lp["bo"]
-        h = resid + o.astype(h.dtype)
+        h = resid + scaled(o).astype(h.dtype)
         resid = h
         x = _norm(cfg, h, lp, "post_attn_norm")
         # A layer is an expert layer if it holds a router: the leading dense
@@ -856,19 +1043,63 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         else:
             mlp = _dense_mlp(lp, x, cfg, tp_axis=tp_axis,
                              use_pallas=int4)
-        h = resid + mlp
+        h = resid + scaled(mlp)
         load = tuple(load or ())
         if cfg.is_mla:
             return h, ((row,), load)
         return h, ((k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1)),
                    load)
 
+    # One period: its attention layers one after the other, each run of
+    # state layers an inner scan over pool layer indices. ``attn_p``: the
+    # period's attention layer, scanned as xs where the period holds one
+    # (every model of today); ``attn_idx``/``ssm_idx``: the pool layer index
+    # of the period's first layer of each kind.
+    period = cfg.layer_period
+    n_attn, n_state = period.count("attention"), period.count("mamba")
+    runs, seen = [], {"attention": 0, "mamba": 0}
+    for kind in period:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen[kind], 1])
+        seen[kind] += 1
+
+    def period_body(layers, carry, xs):
+        (h, ssm), (attn_p, attn_idx, ssm_idx) = carry, xs
+        rows, loads, conv_rows = [], [], []
+        for kind, start, count in runs:
+            if kind == "mamba":
+                (h, ssm), conv = jax.lax.scan(
+                    state_layer, (h, ssm),
+                    ssm_idx + jnp.arange(start, start + count,
+                                         dtype=jnp.int32))
+                conv_rows.append(conv)
+                continue
+            for j in range(start, start + count):
+                idx = attn_idx + j if j else attn_idx
+                h, (row, load) = attn_layer(
+                    h, attn_p if n_attn == 1 else at(layers, idx), idx)
+                rows.append(row)
+                loads.append(load)
+        if n_attn == 1:
+            rows, loads = rows[0], loads[0]
+        else:   # [n_attn, T, ...]
+            rows, loads = (tuple(jnp.stack(a) for a in zip(*b))
+                           for b in (rows, loads))
+        if conv_rows:   # [n_state, S + R, ...]
+            conv_rows = jnp.concatenate(conv_rows, axis=0)
+        return (h, ssm), (rows, loads, conv_rows)
+
+    def whole(a, n):    # the scan's [periods, n, ...] ys back to [layers, ...]
+        return a if n == 1 else a.reshape((-1,) + a.shape[2:])
+
     # The stack: the leading dense layers (``dense_layers``, where the model
     # has them) and then the scanned main layers, pool layer indices in
     # order. Each group is one scan over its own stacked weights.
     stacks = [params[name] for name in ("dense_layers", "layers")
               if name in params]
-    first, outs = 0, []
+    first, outs, conv_rows = 0, [], None
     for layers in stacks:
         # An expert stack's expert tensors stay OUT of the scanned xs: the
         # body reads them in place, by layer index (see experts_grouped).
@@ -876,16 +1107,25 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                    if k in layers and "router" in layers}
         layers = {k: a for k, a in layers.items() if k not in experts}
         n_layers = jax.tree.leaves(layers)[0].shape[0]
-        h, (rows, load) = jax.lax.scan(
-            body, h,
-            (layers, jnp.arange(first, first + n_layers, dtype=jnp.int32)))
+        if n_attn == 1:
+            attn_idx = jnp.arange(first, first + n_layers, dtype=jnp.int32)
+        else:
+            attn_idx = jnp.arange(first, first + n_layers, n_attn,
+                                  dtype=jnp.int32)
+        ssm_idx = (jnp.arange(0, cfg.num_state_layers, n_state,
+                              dtype=jnp.int32) if n_state else None)
+        (h, ssm), (rows, load, conv) = jax.lax.scan(
+            functools.partial(period_body, layers), (h, ssm),
+            (layers if n_attn == 1 else None, attn_idx, ssm_idx))
         first += n_layers
-        outs.append(rows)
+        outs.append(tuple(whole(a, n_attn) for a in rows))
         if load:    # [n_layers, E]
-            moe_load.append(load[0])
+            moe_load.append(whole(load[0], n_attn))
+        if n_state:
+            conv_rows = conv.reshape((-1,) + conv.shape[2:])
     rows = (outs[0] if len(outs) == 1 else
             tuple(jnp.concatenate(r, axis=0) for r in zip(*outs)))
-    return (h, rows[0], rows[1]) if len(rows) == 2 else (h, rows[0], None)
+    return (h, rows[0], rows[1] if len(rows) == 2 else None, ssm, conv_rows)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -923,10 +1163,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     name the manual mesh axes for a pipeline stage (parallel/pp.py's
     shard_map body); ``moe_load``: see ``_layer_scan``.
 
+    A state model's state layers (``cfg.has_state``) run ``state_mixer``
+    over the same token axis: the chunked scan on the segment part, the
+    one-token update on the row part, against the slots ``meta.seg_slots``
+    and ``meta.row_slots`` name in ``kv.ssm``/``kv.conv``.
+
     Returns (normed hidden of ``meta.logits_indices``' tokens, or of every
-    token where that is None [*, d]; new_kv; raw_hidden [T, d], which is
-    what rotates stage to stage)."""
-    scale = cfg.head_dim ** -0.5
+    token where that is None [*, d]; new_kv, the new state slots in it;
+    raw_hidden [T, d], which is what rotates stage to stage)."""
+    scale = cfg.attn_scale
     h = (_embed(params, cfg, tokens, meta.positions)
          if hidden_in is None else hidden_in)
     n_rows = (0 if meta.page_tables is None
@@ -996,11 +1241,24 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             ([meta.seg_ids[:n_seg] >= 0] if n_seg else [])
             + ([jnp.repeat(meta.context_lens > 0, row_width)]
                if n_rows else []))
-    h, k_all, v_all = _layer_scan(params, cfg, h, meta.positions, attn_fn,
-                                  kernels, tp_axis=tp_axis, ep_axis=ep_axis,
-                                  moe_load=moe_load, valid=real)
+    state_fn = None
+    if cfg.has_state:
+        def state_fn(lp, x, ssm, layer_idx):
+            return state_mixer(lp, cfg, x, meta, n_seg, ssm, kv.conv,
+                               layer_idx, kernels)
+    h, k_all, v_all, ssm, conv_rows = _layer_scan(
+        params, cfg, h, meta.positions, attn_fn, kernels, tp_axis=tp_axis,
+        ep_axis=ep_axis, moe_load=moe_load, valid=real, state_fn=state_fn,
+        ssm=kv.ssm)
+    conv = kv.conv
+    if cfg.has_state:
+        # The state layers' new conv rows, every layer's at once, as the
+        # pages are written: segments' slots, then rows'.
+        conv = ssm_ops.write_slots(conv, conv_rows, jnp.concatenate(
+            ([meta.seg_slots] if n_seg else [])
+            + ([meta.row_slots] if n_rows else [])))
     new_kv = KVCache(*kernels.write_pages(kv.k, kv.v, k_all, v_all,
-                                          meta.slot_mapping))
+                                          meta.slot_mapping), ssm, conv)
     selected = h if meta.logits_indices is None else h[meta.logits_indices]
     return _norm(cfg, selected, params, "final_norm"), new_kv, h
 
@@ -1010,6 +1268,10 @@ def compute_logits(params: Params, cfg: ModelConfig, hidden: jax.Array,
     """hidden [B, d] -> logits [B, V] in fp32. ``kernels`` reaches the int4
     head matmul by the one rule of ``Kernels.int4_pallas``."""
     if cfg.tie_word_embeddings:
-        return jnp.dot(hidden, params["embed"].T,
-                       preferred_element_type=jnp.float32)
-    return _dot(hidden, params, "lm_head", kernels.int4_pallas)
+        logits = jnp.dot(hidden, params["embed"].T,
+                         preferred_element_type=jnp.float32)
+    else:
+        logits = _dot(hidden, params, "lm_head", kernels.int4_pallas)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits

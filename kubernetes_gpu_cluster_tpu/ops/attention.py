@@ -426,8 +426,9 @@ def ragged_prefill_attention_tp(mesh, q, k, v, seg_ids, positions, scale, *,
 
 @dataclasses.dataclass(frozen=True)
 class Kernels:
-    """What the engine decides ONCE, at construction, about how the five
-    operations a forward pass needs are carried out
+    """What the engine decides ONCE, at construction, about how the
+    operations a forward pass needs (five of attention and the page pool,
+    one of the state slots) are carried out
     (``LLMEngine._resolve_use_pallas`` builds it, proves every kernel it
     names by compiling it, and hands it to every step program); nothing
     below the engine decides again. The default is the XLA references
@@ -561,6 +562,17 @@ class Kernels:
                                          v_all, slot_mapping)
         from .pallas.kv_write import kv_write
         return kv_write(kv_k, kv_v, k_all, v_all, slot_mapping)
+
+    def ssm_update(self, pool, layer, slots, decay, dtx, B, C):
+        """A state layer's one-token update of the rows' slots, in place in
+        the carried pool (``ops/ssm.py``): the Pallas kernel or the XLA
+        reference. A state model runs on one device (refused under a mesh
+        at start), so there is no per-shard form."""
+        if not self.use_pallas:
+            from .ssm import ssm_update_xla
+            return ssm_update_xla(pool, layer, slots, decay, dtx, B, C)
+        from .pallas.ssm_update import ssm_update
+        return ssm_update(pool, layer, slots, decay, dtx, B, C)
 
 
 NO_KERNELS = Kernels()

@@ -2589,8 +2589,8 @@ def main(argv: Optional[list[str]] = None) -> None:
         # N chips for ~1 chip of throughput. Refuse the misconfiguration.
         p.error(f"--expert-parallel-size {args.expert_parallel_size} "
                 f"requires an MoE model; {model_cfg.name} is dense")
-    from ..config import latent_model_refusal
-    refusal = latent_model_refusal(
+    from ..config import cache_kind_refusal
+    refusal = cache_kind_refusal(
         config, role=args.role, fleet_prefix_cache=args.fleet_prefix_cache,
         peer_pool=args.peer_pool)
     if refusal is not None:

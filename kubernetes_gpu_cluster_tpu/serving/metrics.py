@@ -21,7 +21,8 @@ from __future__ import annotations
 import time
 
 from ..engine.engine import device_memory_stats
-from ..engine.kv_cache import kv_cache_bytes_per_token, kv_row_padding_share
+from ..engine.kv_cache import (kv_cache_bytes_per_token, kv_row_padding_share,
+                               state_bytes_per_seq)
 from ..utils.compile_cache import COMPILE_COUNTERS
 
 
@@ -99,6 +100,17 @@ class Metrics:
             % kv_row_padding_share(eng.model_config),
             "# TYPE kgct_uptime_seconds gauge",
             f"kgct_uptime_seconds {time.monotonic() - self._started:.1f}",
+        ]
+        # A state model's second kind of per-sequence memory: its slots
+        # (the scrap slot is neither counted nor free) and what one holds.
+        # Zeros for every other model, so the series always exist.
+        lines += [
+            "# TYPE kgct_state_slots_total gauge",
+            f"kgct_state_slots_total {max(alloc.num_state_slots - 1, 0)}",
+            "# TYPE kgct_state_slots_free gauge",
+            f"kgct_state_slots_free {alloc.num_free_slots}",
+            "# TYPE kgct_state_bytes_per_seq gauge",
+            f"kgct_state_bytes_per_seq {state_bytes_per_seq(eng.model_config)}",
         ]
         # Prefix-cache reuse (engine/kv_cache.PrefixCache counts lookups;
         # nothing scraped them until now). Emitted unconditionally — zeros

@@ -63,3 +63,21 @@ def test_grouped_matmul_compiles_for_a_v5e(one_chip, no_compile_cache, pairs,
     assert "tpu_custom_call" in compiled.as_text()
     # the rows and the weights are read where they lie: nothing is copied
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_ssm_update_compiles_for_a_v5e_in_place(one_chip, no_compile_cache):
+    """granite-4.0-h-micro's decode step: 64 rows against 65 slots of
+    [128, 4096] float32 in 36 state layers (4.9 GB). The pool is aliased,
+    nothing of it is copied."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.ssm_update import ssm_update
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = arr((36, 65, 128, 4096))
+    compiled = jax.jit(ssm_update, donate_argnums=0).lower(
+        pool, arr((), jnp.int32), arr((64,), jnp.int32), arr((64, 4096)),
+        arr((64, 4096)), arr((64, 128)), arr((64, 128))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**24
+    assert mem.alias_size_in_bytes >= 36 * 65 * 128 * 4096 * 4

@@ -23,7 +23,7 @@ from kubernetes_gpu_cluster_tpu.config import (CacheConfig, EngineConfig,
                                                SchedulerConfig,
                                                apply_hf_overrides,
                                                get_model_config,
-                                               latent_model_refusal)
+                                               cache_kind_refusal)
 from kubernetes_gpu_cluster_tpu.engine import LLMEngine, SamplingParams
 from kubernetes_gpu_cluster_tpu.engine import kv_cache as kvc
 from kubernetes_gpu_cluster_tpu.models import llama
@@ -531,7 +531,7 @@ def _cfg(**kw):
     ("--peer-pool", _cfg(), {"peer_pool": "http://peer:8000"}),
 ])
 def test_refused_flag_is_named_with_its_mechanism(flag, config, extra):
-    msg = latent_model_refusal(config, **extra)
+    msg = cache_kind_refusal(config, **extra)
     assert msg is not None and msg.startswith(flag) and CFG.name in msg
     assert "\n" not in msg and len(msg.split(": ", 1)[1]) > 20
     if not extra:   # the engine refuses too, before it builds anything
@@ -541,7 +541,7 @@ def test_refused_flag_is_named_with_its_mechanism(flag, config, extra):
 
 def test_dense_models_are_refused_nothing():
     tiny = get_model_config("debug-tiny")
-    assert latent_model_refusal(EngineConfig(
+    assert cache_kind_refusal(EngineConfig(
         model=tiny, parallel=ParallelConfig(tp=2),
         scheduler=SchedulerConfig(spec_decode_enabled=True)),
         role="prefill", fleet_prefix_cache=True) is None

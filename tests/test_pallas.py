@@ -378,7 +378,7 @@ class TestPallasUnderMesh:
                                    rtol=2e-5, atol=2e-5)
         # The sharded matmuls sum in another order, so the rows written are
         # close, not equal; every row not addressed is untouched (zeros).
-        for g, r in zip(got_kv, ref_kv):
+        for g, r in zip(got_kv[:2], ref_kv[:2]):     # K and V pools
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                        rtol=2e-5, atol=2e-5)
             flat = np.asarray(g).reshape(g.shape[0], -1, g.shape[-1])
