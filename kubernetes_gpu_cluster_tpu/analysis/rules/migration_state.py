@@ -13,7 +13,9 @@ committed.
 
 Statically this rule scans export-seam functions in the engine modules and
 flags any UNCOMMITTED-source reference — the in-flight window dict
-(``_inflight``), window scratch (``float_b``, ``window_*``), zombie sets,
+(``_inflight``), a sequence's count of tokens in flight
+(``inflight_tokens``, ``sched_tokens``), window scratch (``float_b``,
+``window_*``), zombie sets,
 or draft/pending buffers — flowing into the serialized state: a value in a
 returned dict literal, a store into the state mapping, or an ``update()``
 of it. Window BOOKKEEPING in the same function (zombie registration,
@@ -35,7 +37,8 @@ _EXPORT_FN = re.compile(r"^(_export_state$|export_)")
 # the ast dump of VALUE expressions only, so bookkeeping reads elsewhere in
 # the function never fire.
 _FORBIDDEN = re.compile(
-    r"_inflight|float_b|zombies|window_toks|window_lps|in_window"
+    r"_inflight|inflight_|sched_tokens|float_b|zombies|window_toks"
+    r"|window_lps|in_window"
     r"|_pending|uncommitted|draft_")
 
 

@@ -133,6 +133,9 @@ class StepSanitizer:
         # restarted client may resend one): shadow state must die with its
         # sequence, not haunt the next request wearing the same id.
         self._owner: dict = {}
+        # The device queue: per step program dispatched and not yet
+        # fetched, oldest first, the ids of the sequences it has rows for.
+        self._dispatched: list = []
         self.checks = 0           # observability: hooks that ran
 
     # -- step-output guard ---------------------------------------------------
@@ -271,6 +274,33 @@ class StepSanitizer:
             for pos, slot in self._spec_writes.pop(rid, ()):
                 if pos >= bound:
                     self._stale.setdefault(rid, {})[pos] = slot
+
+    # -- device-queue shadow ---------------------------------------------------
+
+    def on_step_dispatch(self, seqs) -> None:
+        """A step program was queued on the device with a row for each of
+        ``seqs``: until it is fetched it may write their pages and slots."""
+        self._dispatched.append({id(s) for s in seqs})
+
+    def on_step_retire(self) -> None:
+        """The oldest dispatched program was fetched: its writes are done."""
+        if self._dispatched:
+            self._dispatched.pop(0)
+
+    def on_release(self, seq) -> None:
+        """Pages and slot of ``seq`` are about to return to the allocator.
+        The engine's contract (``_drain_deferred``): not while a program
+        that has a row for the sequence is dispatched and unfetched, for
+        that program writes them and the next owner's step may be queued
+        right behind the release."""
+        self.checks += 1
+        if not (seq.pages or seq.state_slot is not None):
+            return
+        if any(id(seq) in step for step in self._dispatched):
+            raise SanitizerError(
+                f"device-queue shadow: pages/slot of {seq.request_id} "
+                f"released while a dispatched, unfetched step program still "
+                "has a row for it (it can still write them)")
 
     def on_swap_restore(self, seq) -> None:
         """Two-tier KV cache: a sequence restored from the host tier holds

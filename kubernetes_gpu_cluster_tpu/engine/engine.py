@@ -46,7 +46,7 @@ from .kv_cache import (KVCache, KVPageIO, KVTransferPrograms,
                        kv_cache_bytes_per_token, kv_cache_dtype,
                        kv_row_padding_share, state_bytes_per_seq)
 from .sampling_params import LOGIT_BIAS_CAP, SamplingParams
-from .scheduler import ScheduledBatch, Scheduler
+from .scheduler import CannotChain, ScheduledBatch, Scheduler, _bucket
 from .sequence import FinishReason, Sequence, SequenceStatus
 
 logger = get_logger("engine")
@@ -117,11 +117,27 @@ def _pack_int_b(batch: ScheduledBatch) -> np.ndarray:
     [B, 5] = (logits_indices, top_k, seed, prompt_len, top_n), and for a
     state model the slots after them, one column a kind the batch has
     (segments', then rows'), so that they ride the upload that is made
-    anyway."""
+    anyway; last, where the batch has decode rows (mixed), ``tok_src``."""
     cols = [batch.logits_indices, batch.top_k, batch.seed, batch.prompt_lens,
             batch.top_n]
-    cols += [c for c in (batch.seg_slots, batch.row_slots) if c is not None]
+    cols += [c for c in (batch.seg_slots, batch.row_slots, batch.tok_src)
+             if c is not None]
     return np.stack(cols, axis=1)
+
+
+def _chained_tokens(tokens, prev, src):
+    """Decode rows' input tokens: where ``src`` names a row, the token the
+    step dispatched before this one sampled for it (``prev``, that step's
+    last-token output, still on the device); the host's elsewhere. A step
+    with no predecessor passes zeros and -1 everywhere."""
+    return jnp.where(src >= 0, jnp.take(prev, jnp.maximum(src, 0)), tokens)
+
+
+def _last_tokens(tokens, width: int):
+    """A step's newest token a row as ``[width]``, whatever its row bucket:
+    what ``_chained_tokens`` of the next step reads, one shape for every
+    predecessor, so that a step kind stays one program."""
+    return jnp.zeros(width, jnp.int32).at[:tokens.shape[0]].set(tokens)
 
 
 def _slot_columns(cfg, int_b, *names, chunk: bool = False) -> dict:
@@ -267,6 +283,16 @@ class LLMEngine:
                         state_bytes_per_seq(config.model),
                         config.model.num_state_layers)
 
+        # The one-deep device queue: every step program also returns its
+        # newest token a row as [_last_width], and takes its predecessor's
+        # such output (or these zeros), so that a step dispatched behind an
+        # unfetched one reads its decode rows' input tokens on the device.
+        # One width, the largest row bucket there is, keeps each step kind
+        # ONE program whatever precedes it.
+        sc = config.scheduler
+        self._last_width = max(sc.decode_buckets[-1],
+                               _bucket(sc.max_num_seqs, sc.decode_buckets))
+        self._no_pred = jnp.zeros(self._last_width, jnp.int32)
         self._prefill_fn = self._build_prefill_fn()
         # Two compiled window programs: all-greedy batches (the common
         # serving case) never trace sampling at all — argmax only. Selection
@@ -355,12 +381,13 @@ class LLMEngine:
                                        else sc.effective_spec_k_max)
         self.stats = EngineStats()
         self.step_count = 0
-        # Speculative decode-window chain state (see step()).
+        # The step in flight: the record of the one program dispatched and
+        # not yet fetched, of whatever kind (see _step()).
         self._inflight: Optional[dict] = None
-        # Set by import_request: a sequence joined ``running`` outside
-        # schedule(), so a chained decode window's batch no longer covers
-        # all running work — the chain must break at the next step or the
-        # import would starve until some chained sequence finishes.
+        # Set by import_request: a sequence joined ``running`` (and pages
+        # were written) outside schedule(), between two steps. The next
+        # step is then scheduled with nothing in flight (chain break
+        # "stale"), as the loop always did after an import.
         self._batch_stale = False
         self._deferred_release: list[Sequence] = []
         # Streamed fleet-prefix imports in flight (begin_prefix_import):
@@ -374,8 +401,9 @@ class LLMEngine:
         # penalty histogram (outputs are bounded by the model length).
         self._out_cap = config.effective_max_len
         # Recycled device buffers for the sampled decode program, per padded
-        # batch size: counts cycle donated through windows and return to the
-        # pool when a chain drains (contents only read under rebuild/penalty
+        # batch size: counts cycle donated through windows over the same rows
+        # and return to the pool when a window's successor takes none
+        # (contents only read under rebuild/penalty
         # conds, so staleness is harmless); the -1-filled out_tokens dummy is
         # not donated and lives forever.
         self._counts_pool: dict[int, Any] = {}
@@ -386,6 +414,8 @@ class LLMEngine:
         # rollback contract. None when off — every hook is one is-None
         # test and outputs are byte-identical with the sanitizer absent.
         self._sanitizer = build_step_sanitizer(config.cache.page_size)
+        if self._sanitizer is not None:
+            self.scheduler.release_guard = self._sanitizer.on_release
         # Two-tier KV cache (CacheConfig.swap_space_gb > 0): host-DRAM page
         # pool + batched jitted gather/scatter. The scheduler preempts by
         # swap instead of recompute, and the prefix cache spills evicted
@@ -803,6 +833,7 @@ class LLMEngine:
                                                  kernels), kv
 
         reports_load = self._reports_expert_load
+        last_width = self._last_width
 
         def prefill_step(params, kv: KVCache, int_t, int_b, float_b,
                          bias_ids, bias_vals, key):
@@ -819,7 +850,8 @@ class LLMEngine:
             next_tokens, lps, tids, tlps = sample_and_logprobs(
                 logits, keys, float_b[:, 0], int_b[:, 1], float_b[:, 1],
                 row_keys=True, with_top=jnp.any(int_b[:, 4] > 0))
-            return (next_tokens, lps, tids, tlps, kv, *(load or ()))
+            return (next_tokens, lps, tids, tlps,
+                    _last_tokens(next_tokens, last_width), kv, *(load or ()))
 
         return self._maybe_jit(prefill_step, donate_argnums=(1,))
 
@@ -884,6 +916,7 @@ class LLMEngine:
                                                  kernels), kv
 
         reports_load = self._reports_expert_load
+        last_width = self._last_width
 
         def prefill_hist_step(params, kv: KVCache, int_t, int_b, float_b,
                               page_table, hist_len, out_tokens,
@@ -910,7 +943,8 @@ class LLMEngine:
             next_tokens, lps, tids, tlps = sample_and_logprobs(
                 logits, keys, float_b[:, 0], int_b[:, 1], float_b[:, 1],
                 row_keys=True, with_top=jnp.any(int_b[:, 4] > 0))
-            return (next_tokens, lps, tids, tlps, kv, *(load or ()))
+            return (next_tokens, lps, tids, tlps,
+                    _last_tokens(next_tokens, last_width), kv, *(load or ()))
 
         return self._maybe_jit(prefill_hist_step, donate_argnums=(1,))
 
@@ -920,20 +954,28 @@ class LLMEngine:
         sequence's decode token. Compiled per (prefill bucket, row bucket,
         history width) — the same bounded bucket grid as the pure paths
         (tests/test_compile_guard.py pins the bound). Penalties use the
-        host-resync histogram (out_tokens) like the chunked path: mixed
-        steps sync every step, so the host always knows the full output
-        history. Sampling rows cover the decode rows plus the chunk's last
+        host-resync histogram (out_tokens) like the chunked path: a mixed
+        step rides behind an unfetched step only when no penalised row has
+        tokens in flight (_chain_break). Sampling rows cover the decode rows plus the chunk's last
         token; the engine discards the chunk row's sample when the chunk is
         partial (KV committed, prompt unfinished)."""
         cfg = self.model_config
         kernels = self.kernels
         reports_load = self._reports_expert_load
+        last_width = self._last_width
 
-        def mixed_step(params, kv: KVCache, int_t, int_b, float_b,
+        def mixed_step(params, kv: KVCache, prev, int_t, int_b, float_b,
                        chunk_page_table, hist_len, page_tables, context_lens,
                        out_tokens, bias_ids, bias_vals, key):
-            # int_t: [4, Tp_bucket + R_pad]; int_b: [R_pad, 5] =
-            # (logits_indices, top_k, seed, prompt_len, top_n).
+            # int_t: [4, Tp_bucket + R_pad]; int_b: [R_pad, 5..] =
+            # (logits_indices, top_k, seed, prompt_len, top_n[, slots],
+            # tok_src). prev: [last_width], the last-token output of the
+            # step dispatched before this one (zeros when there is none):
+            # the decode rows whose newest token the host has not fetched
+            # take it from there.
+            Tp = int_t.shape[1] - int_b.shape[0]
+            tokens = int_t[0].at[Tp:].set(
+                _chained_tokens(int_t[0, Tp:], prev, int_b[:, -1]))
             meta = StepMeta(
                 seg_ids=int_t[1], positions=int_t[2], slot_mapping=int_t[3],
                 logits_indices=int_b[:, 0],
@@ -943,7 +985,7 @@ class LLMEngine:
                                 chunk=True))
             load = [] if reports_load else None
             hidden, kv, _ = model_lib.forward(
-                params, cfg, int_t[0], meta, kv, kernels, moe_load=load)
+                params, cfg, tokens, meta, kv, kernels, moe_load=load)
             logits = model_lib.compute_logits(params, cfg, hidden, kernels)
             logits = _maybe_bias(logits, bias_ids, bias_vals)
             presence, frequency = float_b[:, 2], float_b[:, 3]
@@ -958,7 +1000,8 @@ class LLMEngine:
             next_tokens, lps, tids, tlps = sample_and_logprobs(
                 logits, keys, float_b[:, 0], int_b[:, 1], float_b[:, 1],
                 row_keys=True, with_top=jnp.any(int_b[:, 4] > 0))
-            return (next_tokens, lps, tids, tlps, kv, *(load or ()))
+            return (next_tokens, lps, tids, tlps,
+                    _last_tokens(next_tokens, last_width), kv, *(load or ()))
 
         return self._maybe_jit(mixed_step, donate_argnums=(1,))
 
@@ -1150,13 +1193,14 @@ class LLMEngine:
                                                  kernels), kv
 
         V = cfg.vocab_size
+        last_width = self._last_width
 
         def window_tables(int_b):
             # A state model's int_b ends with one more column: each row's
             # state slot, which every substep of the window updates.
             if cfg.has_state:
-                return int_b[:, 4:-1], int_b[:, -1]
-            return int_b[:, 4:], None
+                return int_b[:, 6:-1], int_b[:, -1]
+            return int_b[:, 6:], None
 
         def substep_meta(page_tables, pos, row_slots=None):
             # Window substeps past the model length cap produce tokens the
@@ -1174,16 +1218,20 @@ class LLMEngine:
                             page_tables=page_tables, context_lens=pos_c + 1,
                             row_slots=row_slots)
 
-        def decode_window_greedy(params, kv: KVCache, tokens0, int_b,
+        def decode_window_greedy(params, kv: KVCache, prev, int_b,
                                  float_b, key):
-            # tokens0: [B] — separate so chained windows can feed the previous
-            # window's device-resident output column without a host roundtrip.
-            # int_b: [B, pps+4] = (positions, top_k, seed, top_n,
-            # page_table...[, state slot]), float_b: [B, 4] = (temperature, top_p,
+            # prev: [last_width], the last-token output of the step
+            # dispatched before this one, still on the device: a row whose
+            # newest token the host has not fetched takes it from there
+            # (tok_src), with no host round trip.
+            # int_b: [B, pps+6] = (positions, top_k, seed, top_n, token,
+            # tok_src, page_table...[, state slot]), float_b: [B, 4] =
+            # (temperature, top_p,
             # presence, frequency). Slots/context lens are recomputed per
             # sub-step from positions + page tables. The greedy program
             # ignores the sampling columns — it is only dispatched for
             # all-greedy, penalty-free, bias-free batches.
+            tokens0 = _chained_tokens(int_b[:, 4], prev, int_b[:, 5])
             positions0 = int_b[:, 0]
             any_top = jnp.any(int_b[:, 3] > 0)
             page_tables, row_slots = window_tables(int_b)
@@ -1202,9 +1250,10 @@ class LLMEngine:
                 substep, (kv, tokens0, positions0), jnp.arange(W))
             # [B, W] / [B, W, K]
             return (toks.T, lps.T, tids.transpose(1, 0, 2),
-                    tlps.transpose(1, 0, 2), kv)
+                    tlps.transpose(1, 0, 2),
+                    _last_tokens(toks[-1], last_width), kv)
 
-        def decode_window_sampled(params, kv: KVCache, tokens0, int_b,
+        def decode_window_sampled(params, kv: KVCache, prev, int_b,
                                   float_b, key, counts, out_tokens, rebuild,
                                   bias_ids, bias_vals):
             # Sampled variant adds per-request seed + presence/frequency
@@ -1215,6 +1264,7 @@ class LLMEngine:
             # through the chain) across speculatively chained windows — so
             # penalties see the in-flight window's tokens the host hasn't
             # downloaded yet.
+            tokens0 = _chained_tokens(int_b[:, 4], prev, int_b[:, 5])
             positions0 = int_b[:, 0]
             top_k = int_b[:, 1]
             seed = int_b[:, 2]
@@ -1251,7 +1301,8 @@ class LLMEngine:
             (kv, counts, _, _), (toks, lps, tids, tlps) = jax.lax.scan(
                 substep, (kv, counts, tokens0, positions0), jnp.arange(W))
             return (toks.T, lps.T, tids.transpose(1, 0, 2),
-                    tlps.transpose(1, 0, 2), kv, counts)
+                    tlps.transpose(1, 0, 2),
+                    _last_tokens(toks[-1], last_width), kv, counts)
 
         if greedy:
             return self._maybe_jit(decode_window_greedy, donate_argnums=(1,))
@@ -1320,16 +1371,19 @@ class LLMEngine:
             raise
 
     def abort_request(self, request_id: str) -> bool:
-        # A sequence in the in-flight window still has device KV writes
-        # pending against its pages: finish it but defer the page release
-        # until the chain drains.
+        # A sequence in the step in flight still has device KV writes
+        # pending against its pages: finish it but defer the release of
+        # pages and slot until that step is fetched. (A prompt between two
+        # chunks is still in ``waiting``.)
         if self._inflight is not None:
             for seq in self._inflight["batch"].seqs:
                 if seq.request_id == request_id and not seq.is_finished:
                     seq.status = SequenceStatus.FINISHED
                     seq.finish_reason = FinishReason.ABORT
-                    if seq in self.scheduler.running:
-                        self.scheduler.running.remove(seq)
+                    for queue in (self.scheduler.running,
+                                  self.scheduler.waiting):
+                        if seq in queue:
+                            queue.remove(seq)
                     self._inflight["zombies"].add(request_id)
                     self._deferred_release.append(seq)
                     self.stats.requests_finished += 1
@@ -1352,8 +1406,8 @@ class LLMEngine:
         return False
 
     def has_unfinished_requests(self) -> bool:
-        # An in-flight window must be drained even if every sequence finished
-        # (its deferred page releases happen at drain time).
+        # A step in flight must be fetched even if every sequence finished
+        # (what they hold is released then).
         return self.scheduler.has_work() or self._inflight is not None
 
     # -- disaggregated prefill/decode (KV handoff seam) ----------------------
@@ -1426,11 +1480,11 @@ class LLMEngine:
         sampling the imported continuation is byte-identical to the
         uninterrupted run.
 
-        Safe against the speculative decode-window chain: a sequence in
-        the in-flight window becomes a ZOMBIE (its already-sampled,
-        not-yet-fetched window tokens are discarded — the peer regenerates
-        them deterministically) and its pages are released only when the
-        chain drains, since the dispatched window still writes into them.
+        Safe against the device queue: a sequence in the step in flight
+        becomes a ZOMBIE (its already-sampled, not-yet-fetched tokens are
+        discarded — the peer regenerates them deterministically) and its
+        pages are released only when that step has been fetched, since the
+        dispatched program still writes into them.
         The gather itself serializes after the in-flight program on the
         device stream and reads only committed positions' pages, which the
         window never touches below position num_tokens-1.
@@ -1451,8 +1505,8 @@ class LLMEngine:
         state = self._export_state(seq, k_np, v_np)
         state["mid_stream"] = True
         # Retire locally. Only now (gather fetched) may pages be released
-        # (KGCT010); a sequence in the in-flight window defers the release
-        # to the chain drain (pending device writes target its pages).
+        # (KGCT010); a sequence in the step in flight defers the release
+        # to that step's fetch (pending device writes target its pages).
         self.scheduler.running.remove(seq)
         seq.status = SequenceStatus.FINISHED
         seq.finish_reason = FinishReason.MIGRATE
@@ -1576,12 +1630,12 @@ class LLMEngine:
             sched.finish(seq, reason)
             self.stats.requests_finished += 1
         else:
-            # A chained decode window's batch predates this sequence —
-            # break the chain at the next step so the import is not
-            # starved. A sequence that finished AT import left ``running``
-            # net-unchanged: the live window still covers every runner, so
-            # no break (a prefill-heavy max_tokens=1 storm would otherwise
-            # pay a schedule round-trip per import on the decode replica).
+            # The step in flight predates this sequence: the next one is
+            # scheduled once it is fetched (see ``_batch_stale``). A
+            # sequence that finished AT import left ``running``
+            # net-unchanged, so no break (a prefill-heavy max_tokens=1
+            # storm would otherwise pay a schedule round-trip per import
+            # on the decode replica).
             self._batch_stale = True
         return [RequestOutput(
             request_id=request_id,
@@ -1869,213 +1923,255 @@ class LLMEngine:
         return outs
 
     def _step(self) -> list[RequestOutput]:
-        """Run one engine iteration and return outputs for sequences that
-        advanced.
+        """Run one engine iteration: dispatch the NEXT step program, then
+        fetch and post-process the one in flight.
 
-        Decode windows are SPECULATIVELY CHAINED: before downloading window
-        w's tokens, window w+1 is dispatched with its input tokens taken from
-        w's device-resident output column — so the (expensive) device->host
-        download of w overlaps w+1's execution, and the device never idles
-        between windows. The chain breaks when a prefill is waiting or any
-        sequence finished (the already-dispatched successor then runs with
-        the finished rows as zombies; their pages are only released once the
-        chain drains, so in-flight KV writes never touch reused pages)."""
-        ph = self.obs.phases.phase
+        The device queue is one deep for every step kind whose inputs the
+        host can derive without its predecessor's tokens: a decode window,
+        a mixed step, a prefill or a chunk is scheduled from "tokens in
+        flight a sequence" (``Sequence.sched_tokens``) and dispatched
+        BEHIND the step in flight; its decode rows read their input token
+        from that step's device-resident last-token output
+        (``_chained_tokens``). So the device-to-host download of step n,
+        its post-processing, the hand-over of its tokens and the scheduling
+        of step n+2 all run under step n+1, and an admission or a finish no
+        longer empties the queue. A row found finished in step n rides
+        n+1, already dispatched, as a zombie and no further; a row whose
+        ``max_tokens`` falls inside n is left out of n+1. What a finished
+        sequence holds is released when the last dispatched program that
+        carries it has been fetched (``_drain_deferred``).
+
+        The chain breaks, and the next step is scheduled only once this one
+        is fetched, for speculation (the next drafts depend on the accepted
+        tokens), a batch that needs a preemption, an import since the last
+        schedule, and penalties whose histogram the host must rebuild: each
+        counted by reason (``kgct_chain_breaks_total``)."""
         inflight = self._inflight
+        outs: list[RequestOutput] = []
         if inflight is None:
-            with ph("schedule"):
-                batch = self.scheduler.schedule()
-            self._batch_stale = False
-            drained = self._drain_terminally_finished()
-            if batch is None:
-                return drained
-            self.step_count += 1
-            self._key, step_key = jax.random.split(self._key)
-            with ph("host_prep"):
-                float_b = jnp.asarray(np.stack(
-                    [batch.temperature, batch.top_p, batch.presence,
-                     batch.frequency], axis=1))
-            if batch.kind == "mixed":
-                return drained + self._step_mixed(batch, float_b, step_key)
-            if batch.kind == "spec":
-                return drained + self._step_spec(batch, float_b, step_key)
-            if batch.kind == "spec_mixed":
-                return drained + self._step_spec_mixed(batch, float_b,
-                                                       step_key)
-            if batch.kind == "prefill":
-                with ph("host_prep"):
-                    int_t = jnp.asarray(np.stack(
-                        [batch.tokens, batch.seg_ids, batch.positions,
-                         batch.slot_mapping]))
-                    int_b = jnp.asarray(_pack_int_b(batch))
-                    bias_ids, bias_vals = self._bias_arrays(batch)
-                if batch.hist_len is not None:
-                    # Chunked prefill (solo): chunk attends to pool history.
-                    self.stats.prefill_tokens += int(
-                        np.sum(batch.seg_ids >= 0))
-                    with ph("host_prep"):
-                        page_tables = jnp.asarray(batch.page_tables)
-                        out_tokens = self._penalty_out_tokens(batch)
-                    with ph("device_dispatch"):
-                        (next_tokens, lps, tids, tlps,
-                         self.kv_cache, *load) = self._prefill_hist_fn(
-                            self.params, self.kv_cache, int_t, int_b, float_b,
-                            page_tables, jnp.int32(batch.hist_len),
-                            out_tokens, bias_ids, bias_vals, step_key)
-                    if batch.partial:
-                        # Prompt not complete: KV is committed, the sampled
-                        # token is meaningless — nothing to report yet.
-                        self._last_step_info = (
-                            "prefill", batch.num_seqs, None,
-                            self._routed(int(np.sum(batch.seg_ids >= 0))))
-                        return drained
-                else:
-                    self.stats.prefill_tokens += sum(
-                        s.num_tokens for s in batch.seqs)
-                    with ph("device_dispatch"):
-                        (next_tokens, lps, tids, tlps,
-                         self.kv_cache, *load) = self._prefill_fn(
-                            self.params, self.kv_cache, int_t, int_b, float_b,
-                            bias_ids, bias_vals, step_key)
-                with ph("device_fetch"):
-                    # Async dispatch means the device prefill COMPUTE
-                    # completes inside this sync; split it from the
-                    # device->host transfer so the TTFT decomposition's
-                    # "prefill" carries the compute and "first_fetch" only
-                    # the copy (else prefill reads ~0 and the fetch looks
-                    # like a phantom bottleneck).
-                    t0f = time.perf_counter()
-                    next_tokens.block_until_ready()
-                    compute_s = time.perf_counter() - t0f
-                    toks_np = np.asarray(next_tokens)[:, None]
-                    lps_np = np.asarray(lps)[:, None]
-                    self.obs.on_expert_load(
-                        load, model_lib.grouped_dispatch(
-                            len(batch.tokens), self.model_config,
-                            self.kernels))
-                    top_i = top_l = None
-                    if any(s.params.top_logprobs for s in batch.seqs):
-                        top_i = np.asarray(tids)[:, None]
-                        top_l = np.asarray(tlps)[:, None]
-                self._ttft_transfer_s = max(
-                    self.obs.phases.current_durs.get("device_fetch", 0.0)
-                    - compute_s, 0.0)
-                with ph("postproc"):
-                    outs = self._process_window(
-                        batch, toks_np, lps_np, set(), defer=False,
-                        top_ids=top_i, top_lps=top_l)
-                self._last_step_info = (
-                    "prefill", batch.num_seqs, None,
-                    self._routed(int(np.sum(batch.seg_ids >= 0))))
-                return drained + outs
-            inflight = self._dispatch_window(
-                batch, jnp.asarray(batch.tokens), batch.positions, float_b)
-            inflight["drained"] = drained
-
-        successor = None
-        # With spec decode enabled, decode windows never speculatively
-        # chain: draft verification IS the speculation mechanism, and a
-        # chained successor would pin the engine in legacy decode even
-        # after n-gram matches appear in the generated text (schedule()
-        # only re-evaluates spec eligibility between chains).
-        if (not self.scheduler.waiting and not inflight["zombies"]
-                and not self._batch_stale
-                and not self.scheduler.spec_enabled):
-            successor = self._advance_window(inflight)
-
-        with ph("device_fetch"):
-            toks = np.asarray(inflight["dev_out"])  # syncs; overlaps successor
-            lps = np.asarray(inflight["dev_lp"])
-            top_i = top_l = None
-            if any(s.params.top_logprobs for s in inflight["batch"].seqs):
-                # Alternatives ride the device outputs unconditionally; the
-                # device->host TRANSFER happens only when someone asked.
-                top_i = np.asarray(inflight["dev_tid"])
-                top_l = np.asarray(inflight["dev_tlp"])
+            inflight, outs = self._launch(None)
+            if inflight is None:
+                return outs
+            self._mark_in_flight(inflight)
+        successor, drained = self._launch(inflight)
         self._inflight = successor
-        with ph("postproc"):
-            outputs = inflight.pop("drained", []) + self._process_window(
-                inflight["batch"], toks, lps, inflight["zombies"],
-                defer=successor is not None, top_ids=top_i, top_lps=top_l)
-            if successor is not None:
-                successor["zombies"].update(
-                    s.request_id for s in inflight["batch"].seqs
-                    if s.is_finished)
-            else:
-                counts = inflight.get("counts")
-                if counts is not None:
-                    self._counts_pool[counts.shape[0]] = counts
-                self._drain_deferred()
-        self._last_step_info = (
-            "decode", inflight["batch"].num_seqs,
-            "greedy" if inflight.get("greedy") else "sampled",
-            self._routed(inflight["batch"].num_seqs
-                         * self.config.scheduler.decode_window))
-        return outputs
+        return outs + drained + self._retire(inflight, successor)
 
-    def _step_mixed(self, batch: ScheduledBatch, float_b,
-                    step_key) -> list[RequestOutput]:
-        """Execute one mixed step and commit its results: every decode row's
-        sampled token appends (with stop checks), the chunk's KV is
-        committed by the program itself, and the chunk row's sampled token
-        is the sequence's first generated token on a FINAL chunk — or
-        discarded (zombie row) when the prompt is still partial, exactly
-        like the solo chunked-prefill path. Mixed steps are synchronous
-        (no speculative chaining: the next step's batch composition depends
-        on this one's chunk progress), so finished rows release pages
-        immediately."""
+    def _chain_break(self, pred: dict) -> Optional[str]:
+        """Why no step may be scheduled while ``pred`` is in flight, read
+        from what the engine holds; None when one may."""
+        sched = self.scheduler
+        if sched.spec_enabled:
+            # Draft verification IS the speculation, and a chained window
+            # would pin the engine in legacy decode after n-gram matches
+            # appear (schedule() re-checks eligibility between steps).
+            return "spec"
+        if self._batch_stale:
+            return "stale"
+        if any(s.inflight_tokens and (s.params.presence_penalty
+                                      or s.params.frequency_penalty)
+               for s in sched.running):
+            # A penalised row's newest tokens are on the chip. Only the
+            # window over the very same rows carries their histogram there
+            # (``counts``, donated along); any other batch rebuilds it from
+            # the output tokens the host knows.
+            same_rows = (pred["kind"] == "decode"
+                         and pred["counts"] is not None
+                         and not pred["zombies"]
+                         and not sched.waiting and not sched.swapped
+                         and pred["batch"].seqs == sched.running
+                         and not any(
+                             s.finishes_in_flight(
+                                 self.config.effective_max_len)
+                             for s in sched.running))
+            if not same_rows:
+                return "penalties"
+        return None
+
+    def _launch(self, pred: Optional[dict]
+                ) -> tuple[Optional[dict], list[RequestOutput]]:
+        """Schedule the next batch and dispatch its program, behind
+        ``pred`` when a step is in flight. Returns the record of the step
+        now in flight (None: nothing to run, a chain break, or a
+        speculative step, which runs to its end here) and the outputs that
+        are due at once."""
         ph = self.obs.phases.phase
-        chunk_seq = batch.seqs[-1]
+        behind = pred is not None
+        reason = self._chain_break(pred) if behind else None
+        batch = None
+        if reason is None:
+            try:
+                with ph("schedule"):
+                    batch = self.scheduler.schedule(behind=behind)
+            except CannotChain as e:
+                reason = e.reason
+        if reason is not None:
+            self.obs.on_chain_break(reason)
+            return None, []
+        if not behind:
+            self._batch_stale = False
+        outs = self._drain_terminally_finished()
+        if batch is None:
+            return None, outs
+        self.step_count += 1
+        self._key, step_key = jax.random.split(self._key)
+        with ph("host_prep"):
+            float_b = jnp.asarray(np.stack(
+                [batch.temperature, batch.top_p, batch.presence,
+                 batch.frequency], axis=1))
+        self.obs.on_step_dispatched(batch.kind, behind)
+        if batch.kind in ("spec", "spec_mixed"):
+            self.obs.on_chain_break("spec")
+            step = (self._step_spec if batch.kind == "spec"
+                    else self._step_spec_mixed)
+            return None, outs + step(batch, float_b, step_key)
+        if self._sanitizer is not None:
+            self._sanitizer.on_step_dispatch(batch.seqs)
+        prev = self._no_pred if pred is None else pred["last"]
+        if batch.kind == "decode":
+            rec = self._dispatch_window(batch, prev, float_b, step_key, pred)
+        else:
+            rec = self._dispatch_prefill(batch, prev, float_b, step_key)
+        rec.update(batch=batch, kind=batch.kind)
+        return rec, outs
+
+    def _dispatch_prefill(self, batch: ScheduledBatch, prev, float_b,
+                          step_key) -> dict:
+        """Dispatch a prefill, a chunk with history or a mixed step; what
+        its fetch needs comes back as the step's record. A partial chunk's
+        sampled row is meaningless (KV committed, prompt unfinished): it
+        goes through the zombie set, so ``_process_window`` skips it with
+        no output, no stats, no stop checks."""
+        ph = self.obs.phases.phase
+        mixed = batch.kind == "mixed"
         with ph("host_prep"):
             int_t = jnp.asarray(np.stack(
                 [batch.tokens, batch.seg_ids, batch.positions,
                  batch.slot_mapping]))
             int_b = jnp.asarray(_pack_int_b(batch))
-            chunk_pt = jnp.asarray(batch.chunk_page_table)
-            page_tables = jnp.asarray(batch.page_tables)
-            context_lens = jnp.asarray(batch.context_lens)
-            out_tokens = self._penalty_out_tokens(batch)
             bias_ids, bias_vals = self._bias_arrays(batch)
-        self.stats.prefill_tokens += batch.prefill_token_count
-        with ph("device_dispatch"):
-            (next_tokens, lps, tids, tlps, self.kv_cache,
-             *load) = self._mixed_fn(
-                self.params, self.kv_cache, int_t, int_b, float_b, chunk_pt,
-                jnp.int32(batch.hist_len), page_tables, context_lens,
-                out_tokens, bias_ids, bias_vals, step_key)
+            if batch.hist_len is not None:
+                page_tables = jnp.asarray(batch.page_tables)
+                out_tokens = self._penalty_out_tokens(batch)
+            if mixed:
+                chunk_pt = jnp.asarray(batch.chunk_page_table)
+                context_lens = jnp.asarray(batch.context_lens)
+        if mixed:
+            self.stats.prefill_tokens += batch.prefill_token_count
+            with ph("device_dispatch"):
+                (toks, lps, tids, tlps, last, self.kv_cache,
+                 *load) = self._mixed_fn(
+                    self.params, self.kv_cache, prev, int_t, int_b, float_b,
+                    chunk_pt, jnp.int32(batch.hist_len), page_tables,
+                    context_lens, out_tokens, bias_ids, bias_vals, step_key)
+        elif batch.hist_len is not None:
+            # Chunked prefill (solo): the chunk attends to pool history.
+            self.stats.prefill_tokens += int(np.sum(batch.seg_ids >= 0))
+            with ph("device_dispatch"):
+                (toks, lps, tids, tlps, last, self.kv_cache,
+                 *load) = self._prefill_hist_fn(
+                    self.params, self.kv_cache, int_t, int_b, float_b,
+                    page_tables, jnp.int32(batch.hist_len), out_tokens,
+                    bias_ids, bias_vals, step_key)
+        else:
+            self.stats.prefill_tokens += sum(
+                s.num_tokens for s in batch.seqs)
+            with ph("device_dispatch"):
+                (toks, lps, tids, tlps, last, self.kv_cache,
+                 *load) = self._prefill_fn(
+                    self.params, self.kv_cache, int_t, int_b, float_b,
+                    bias_ids, bias_vals, step_key)
+        zombies = {batch.seqs[-1].request_id} if batch.partial else set()
+        return {"toks": toks, "lps": lps, "tids": tids, "tlps": tlps,
+                "last": last, "load": load, "zombies": zombies,
+                "counts": None}
+
+    def _retire(self, step: dict,
+                successor: Optional[dict]) -> list[RequestOutput]:
+        """Fetch the tokens of ``step`` (the wait for the device, under its
+        ``successor`` when one was dispatched), commit them, and release
+        what no dispatched program can write any more."""
+        ph = self.obs.phases.phase
+        batch = step["batch"]
+        window = step["kind"] == "decode"
         with ph("device_fetch"):
-            # Same compute/transfer split as the prefill path: the TTFT
-            # decomposition's "prefill" carries the device compute, and
-            # "first_fetch" only the device->host copy.
+            # Async dispatch means the device COMPUTE completes inside this
+            # sync; split it from the device->host transfer so the TTFT
+            # decomposition's "prefill" carries the compute and
+            # "first_fetch" only the copy.
             t0f = time.perf_counter()
-            next_tokens.block_until_ready()
+            step["toks"].block_until_ready()
             compute_s = time.perf_counter() - t0f
-            toks_np = np.asarray(next_tokens)[:, None]
-            lps_np = np.asarray(lps)[:, None]
-            self.obs.on_expert_load(
-                load, model_lib.grouped_dispatch(
-                    len(batch.tokens), self.model_config, self.kernels))
+            toks = np.asarray(step["toks"])
+            lps = np.asarray(step["lps"])
             top_i = top_l = None
             if any(s.params.top_logprobs for s in batch.seqs):
-                top_i = np.asarray(tids)[:, None]
-                top_l = np.asarray(tlps)[:, None]
-        self._ttft_transfer_s = max(
-            self.obs.phases.current_durs.get("device_fetch", 0.0)
-            - compute_s, 0.0)
-        # A partial chunk's sampled row is meaningless (prompt unfinished):
-        # route it through the zombie set so _process_window skips it with
-        # no output, no stats, no stop checks.
-        zombies = {chunk_seq.request_id} if batch.partial else set()
+                # Alternatives ride the device outputs unconditionally; the
+                # device->host TRANSFER happens only when someone asked.
+                top_i = np.asarray(step["tids"])
+                top_l = np.asarray(step["tlps"])
+            if not window:
+                toks, lps = toks[:, None], lps[:, None]
+                if top_i is not None:
+                    top_i, top_l = top_i[:, None], top_l[:, None]
+                self.obs.on_expert_load(
+                    step["load"], model_lib.grouped_dispatch(
+                        len(batch.tokens), self.model_config, self.kernels))
+        if not window:
+            self._ttft_transfer_s = max(
+                self.obs.phases.current_durs.get("device_fetch", 0.0)
+                - compute_s, 0.0)
+        if self._sanitizer is not None:
+            self._sanitizer.on_step_retire()
+        for seq in batch.seqs:
+            seq.inflight_tokens, seq.inflight_row = 0, -1
+        carried = (frozenset() if successor is None
+                   else frozenset(map(id, successor["batch"].seqs)))
         with ph("postproc"):
-            outs = self._process_window(batch, toks_np, lps_np, zombies,
-                                        defer=False, top_ids=top_i,
-                                        top_lps=top_l)
-        self._last_step_info = (
-            "mixed", batch.num_seqs, None,
-            {"prefill_tokens": batch.prefill_token_count,
-             "decode_tokens": batch.num_seqs - 1,
-             **self._routed(batch.prefill_token_count + batch.num_seqs - 1)})
-        return outs
+            outputs = self._process_window(
+                batch, toks, lps, step["zombies"], carried,
+                top_ids=top_i, top_lps=top_l)
+            if successor is not None:
+                successor["zombies"].update(
+                    s.request_id for s in successor["batch"].seqs
+                    if s.is_finished)
+                self._mark_in_flight(successor)
+            counts = step["counts"]
+            if counts is not None:
+                # (None too where a successor over the same rows took the
+                # histogram along, donated: ``_dispatch_window``)
+                self._counts_pool[counts.shape[0]] = counts
+            self._drain_deferred(carried)
+        if window:
+            self._last_step_info = (
+                "decode", batch.num_seqs,
+                "greedy" if step["greedy"] else "sampled",
+                self._routed(batch.num_seqs
+                             * self.config.scheduler.decode_window))
+        elif step["kind"] == "mixed":
+            self._last_step_info = (
+                "mixed", batch.num_seqs, None,
+                {"prefill_tokens": batch.prefill_token_count,
+                 "decode_tokens": batch.num_seqs - 1,
+                 **self._routed(batch.prefill_token_count
+                                + batch.num_seqs - 1)})
+        else:
+            self._last_step_info = (
+                "prefill", batch.num_seqs, None,
+                self._routed(int(np.sum(batch.seg_ids >= 0))))
+        return outputs
+
+    def _mark_in_flight(self, step: dict) -> None:
+        """Tell the sequences of ``step``, now the only unfetched program,
+        what it holds for them: how many tokens, and the row of its
+        last-token output with the newest. Rows of finished sequences and
+        a partial chunk's (the zombies) are sampled nothing that counts."""
+        batch = step["batch"]
+        n = (self.config.scheduler.decode_window
+             if step["kind"] == "decode" else 1)
+        for row, seq in batch.device_seq_rows():
+            if seq.request_id not in step["zombies"]:
+                seq.inflight_tokens, seq.inflight_row = n, row
 
     def _routed(self, tokens: int) -> dict:
         """``on_step``'s count of (token, expert) pairs a step of ``tokens``
@@ -2151,8 +2247,8 @@ class LLMEngine:
             self._sanitizer.on_spec_commit(batch, emit)
         with ph("postproc"):
             outs = self._process_window(batch, toks_np, lps_np, set(),
-                                        defer=False, top_ids=top_i,
-                                        top_lps=top_l, emit_counts=emit)
+                                        top_ids=top_i, top_lps=top_l,
+                                        emit_counts=emit)
         self._last_step_info = (
             "spec", B, "greedy" if greedy else "sampled",
             {"drafted_tokens": drafted, "accepted_tokens": accepted,
@@ -2249,8 +2345,8 @@ class LLMEngine:
         zombies = {chunk_seq.request_id} if batch.partial else set()
         with ph("postproc"):
             outs = self._process_window(batch, toks_np, lps_np, zombies,
-                                        defer=False, top_ids=top_i,
-                                        top_lps=top_l, emit_counts=emit)
+                                        top_ids=top_i, top_lps=top_l,
+                                        emit_counts=emit)
         self._last_step_info = (
             "spec_mixed", batch.num_seqs, "greedy" if greedy else "sampled",
             {"prefill_tokens": batch.prefill_token_count,
@@ -2296,34 +2392,43 @@ class LLMEngine:
             out[s, :len(ids)] = ids
         return jnp.asarray(out)
 
-    def _dispatch_window(self, batch: ScheduledBatch, tokens_dev,
-                         positions: np.ndarray, float_b,
-                         counts=None) -> dict:
+    def _dispatch_window(self, batch: ScheduledBatch, prev, float_b,
+                         step_key, pred: Optional[dict]) -> dict:
+        """Dispatch a decode window; what its fetch needs comes back as
+        the step's record. Behind a window over the very same rows the
+        penalty histogram rides along on the device (``counts``, donated:
+        it already holds the tokens in flight)."""
         ph = self.obs.phases.phase
         if self._sanitizer is not None:
             self._sanitizer.on_decode_dispatch(
-                batch.seqs, positions, self.config.scheduler.decode_window)
+                batch.seqs, batch.positions,
+                self.config.scheduler.decode_window)
         with ph("host_prep"):
             int_b = jnp.asarray(np.concatenate(
-                [np.stack([positions, batch.top_k, batch.seed, batch.top_n],
+                [np.stack([batch.positions, batch.top_k, batch.seed,
+                           batch.top_n, batch.tokens, batch.tok_src],
                           axis=1), batch.page_tables]
                 + ([] if batch.row_slots is None
                    else [batch.row_slots[:, None]]), axis=1))
-        self._key, step_key = jax.random.split(self._key)
         greedy = (bool(np.all(batch.temperature <= 0))
                   and not np.any(batch.presence)
                   and not np.any(batch.frequency)
                   and not any(s.params.logit_bias for s in batch.seqs))
         if greedy:
             with ph("device_dispatch"):
-                (dev_out, dev_lp, dev_tid, dev_tlp,
+                (dev_out, dev_lp, dev_tid, dev_tlp, last,
                  self.kv_cache) = self._decode_fn_greedy(
-                    self.params, self.kv_cache, tokens_dev, int_b, float_b,
+                    self.params, self.kv_cache, prev, int_b, float_b,
                     step_key)
             counts = None
         else:
             B = len(batch.temperature)
             any_pen = bool(np.any(batch.presence) or np.any(batch.frequency))
+            counts = None
+            if (pred is not None and pred["counts"] is not None
+                    and pred["counts"].shape[0] == B
+                    and pred["batch"].seqs == batch.seqs):
+                counts, pred["counts"] = pred["counts"], None
             rebuild = counts is None and any_pen
             if counts is None:
                 counts = self._counts_pool.pop(B, None)
@@ -2331,10 +2436,12 @@ class LLMEngine:
                     counts = jnp.zeros((B, self.model_config.vocab_size),
                                        jnp.int32)
             if rebuild:
-                # Fresh (non-chained) window with penalties active: re-sync
-                # the histogram from host-known output tokens. Chained
-                # successors carry the device-resident counts instead (they
-                # already include the in-flight window's tokens), and
+                # A window over other rows than its predecessor's, with
+                # penalties active: re-sync the histogram from host-known
+                # output tokens (``_chain_break`` saw to it that none of a
+                # penalised row's are still in flight). A successor over
+                # the same rows carries the device-resident counts instead
+                # (they already include the in-flight window's tokens), and
                 # penalty-free sampled batches (the common case) skip the
                 # host assembly + upload + scatter entirely — counts stay a
                 # device zero-fill that apply_penalties never reads.
@@ -2347,56 +2454,30 @@ class LLMEngine:
             with ph("host_prep"):
                 bias_ids, bias_vals = self._bias_arrays(batch)
             with ph("device_dispatch"):
-                (dev_out, dev_lp, dev_tid, dev_tlp, self.kv_cache,
+                (dev_out, dev_lp, dev_tid, dev_tlp, last, self.kv_cache,
                  counts) = self._decode_fn(
-                    self.params, self.kv_cache, tokens_dev, int_b, float_b,
+                    self.params, self.kv_cache, prev, int_b, float_b,
                     step_key, counts, out_tokens, jnp.asarray(rebuild),
                     bias_ids, bias_vals)
-        return {"batch": batch, "dev_out": dev_out, "dev_lp": dev_lp,
-                "dev_tid": dev_tid, "dev_tlp": dev_tlp,
-                "positions": positions, "float_b": float_b, "zombies": set(),
+        return {"toks": dev_out, "lps": dev_lp, "tids": dev_tid,
+                "tlps": dev_tlp, "last": last, "load": (), "zombies": set(),
                 "counts": counts, "greedy": greedy}
-
-    def _advance_window(self, inflight: dict) -> Optional[dict]:
-        """Build + dispatch the speculative successor window: same batch
-        composition, positions advanced by W, pages grown to cover the new
-        window. Returns None (chain breaks) if pages can't be grown."""
-        W = self.config.scheduler.decode_window
-        ps = self.config.cache.page_size
-        batch = inflight["batch"]
-        new_positions = inflight["positions"] + W
-        # Grow page lists to cover the successor window's KV writes.
-        grows = []
-        total = 0
-        for s, seq in enumerate(batch.seqs):
-            last_pos = seq.last_window_pos(
-                int(new_positions[s]), W, self.config.effective_max_len)
-            need = cdiv(last_pos + 1, ps) - len(seq.pages)
-            if need > 0:
-                grows.append((s, seq, need))
-                total += need
-        if not self.scheduler.allocator.can_allocate(total):
-            return None
-        for s, seq, need in grows:
-            seq.pages.extend(self.scheduler.allocator.allocate(need))
-            batch.page_tables[s, :len(seq.pages)] = seq.pages
-        self.step_count += 1
-        return self._dispatch_window(batch, inflight["dev_out"][:, -1],
-                                     new_positions, inflight["float_b"],
-                                     counts=inflight.get("counts"))
 
     def _process_window(self, batch: ScheduledBatch, next_tokens: np.ndarray,
                         logprobs: np.ndarray, zombies: set,
-                        defer: bool, top_ids: Optional[np.ndarray] = None,
+                        carried: frozenset = frozenset(),
+                        top_ids: Optional[np.ndarray] = None,
                         top_lps: Optional[np.ndarray] = None,
                         emit_counts: Optional[np.ndarray] = None,
                         ) -> list[RequestOutput]:
         """next_tokens/logprobs: [B_pad, W]. Append window tokens per sequence
         until a stop condition fires; tokens generated past the stop are
         discarded.
-        ``zombies`` (request ids finished in an earlier chained window) are
-        skipped; with ``defer`` the pages of newly finished sequences are held
-        until the chain drains (an in-flight window may still write to them).
+        ``zombies`` (request ids finished in an earlier step of the chain,
+        and a partial chunk's row) are skipped. ``carried``: the ``id`` of
+        every sequence that the successor, already dispatched, has a row
+        for: one of them that finishes here keeps its pages and slot until
+        that program is fetched (it still writes them).
         ``emit_counts`` [B_pad] caps the usable columns per row (spec steps:
         accepted drafts + 1; slots past the first rejection are garbage).
         """
@@ -2443,7 +2524,7 @@ class LLMEngine:
                     new_lps.append(float(lp))
                 reason = seq.check_stop(self.config.effective_max_len)
                 if reason is not None:
-                    if defer:
+                    if id(seq) in carried:
                         seq.status = SequenceStatus.FINISHED
                         seq.finish_reason = reason
                         if seq in self.scheduler.running:
@@ -2503,17 +2584,23 @@ class LLMEngine:
         self.scheduler.terminally_finished.clear()
         return outs
 
-    def _drain_deferred(self) -> None:
+    def _drain_deferred(self, carried: frozenset = frozenset()) -> None:
+        """Release what finished sequences still hold, but for those that
+        the program in flight has a row for (``carried``: their turn comes
+        when that one is fetched)."""
+        held_back = [s for s in self._deferred_release if id(s) in carried]
         for seq in self._deferred_release:
+            if id(seq) in carried:
+                continue
             if (seq.hold_kv and seq.pages
                     and seq.finish_reason != FinishReason.ABORT):
-                # Disaggregated prefill finishing inside a chained decode
-                # window (max_tokens > 1 holds): the export seam owns the
+                # Disaggregated prefill finishing in a step that had a
+                # successor queued (max_tokens > 1 holds): the export seam owns the
                 # release, exactly like the scheduler.finish hold path.
                 self.scheduler.held[seq.request_id] = seq
                 continue
             self.scheduler._release(seq)    # pages, and a state slot
-        self._deferred_release.clear()
+        self._deferred_release[:] = held_back
 
     # -- convenience --------------------------------------------------------
 

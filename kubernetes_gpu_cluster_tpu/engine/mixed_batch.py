@@ -126,7 +126,8 @@ def mixed_row_bucket(rows: int, chunk_bucket: int,
     return _bucket(max(rows, floor), decode_buckets)
 
 
-def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
+def build_mixed_batch(sched: "Scheduler", behind: bool = False
+                      ) -> Optional["ScheduledBatch"]:
     """Assemble one mixed step from the scheduler's live state, or return
     None when mixing is not possible this step (caller falls through to the
     legacy prefill-else-decode policy).
@@ -200,7 +201,7 @@ def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     # waiting[0]. If the chunk cannot get pages
     # after this, the growth is not wasted: the fall-through decode step
     # needs exactly these pages.
-    decode_seqs = sched._grow_decode_pages(window=1)
+    decode_seqs = sched._grow_decode_pages(window=1, behind=behind)
     if not decode_seqs or not sched.waiting or sched.waiting[0] is not head:
         # Preemption displaced the (fresh, pageless) head — let the legacy
         # path deal with the victim-headed queue this step.
@@ -254,9 +255,10 @@ def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     pages_bucket = cdiv(sched.config.effective_max_len, sched.page_size)
     page_tables = np.zeros((R_pad, pages_bucket), np.int32)
     context_lens = np.zeros(R_pad, np.int32)
+    tok_src = np.full(R_pad, -1, np.int32)
     for s, seq in enumerate(decode_seqs):
         sched._fill_decode_row(seq, s, Tp, tokens, positions, slot_mapping,
-                               page_tables, context_lens)
+                               page_tables, context_lens, tok_src)
 
     # -- sampled rows -------------------------------------------------------
     logits_indices = np.zeros(R_pad, np.int32)
@@ -272,7 +274,8 @@ def build_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
         kind="mixed", seqs=seqs, tokens=tokens, positions=positions,
         slot_mapping=slot_mapping, seg_ids=seg_ids,
         logits_indices=logits_indices, page_tables=page_tables,
-        context_lens=context_lens, chunk_page_table=chunk_page_table,
+        context_lens=context_lens, tok_src=tok_src,
+        chunk_page_table=chunk_page_table,
         hist_len=hist_len, partial=not final, prefill_token_count=chunk,
         seg_slots=sched._state_slots([head], R_pad),
         row_slots=sched._state_slots(decode_seqs, R_pad),
@@ -308,10 +311,11 @@ def build_spec_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     of a verify slice) plus the spec bow-outs (k throttled to 0, rows
     outside the bucket grid, nothing proposed). Every bow-out returns None
     and the caller falls through to the PLAIN mixed step, so spec×mixed
-    never costs a composition the engine already had. Window chaining is
+    never costs a composition the engine already had. The device queue is
     not in play at this seam: spec steps are synchronous by construction
-    (the next step's drafts depend on this one's accepted tokens), exactly
-    like mixed steps (the next batch depends on chunk progress).
+    (the next step's drafts depend on this one's accepted tokens), unlike
+    plain mixed steps, whose successor depends on chunk progress alone,
+    which the host knows.
     """
     from .scheduler import ScheduledBatch, _bucket
     from .spec.verifier import collect_proposals, resolve_spec_k

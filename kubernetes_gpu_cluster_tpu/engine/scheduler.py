@@ -42,6 +42,17 @@ from .sequence import FinishReason, Sequence, SequenceStatus
 logger = get_logger("scheduler")
 
 
+class CannotChain(Exception):
+    """Raised by ``schedule(behind=True)``: the next batch cannot be built
+    while a step is still in flight (it would take a preemption, and the
+    victim holds tokens the host has not seen). ``reason`` names the chain
+    break; the engine schedules again once that step is fetched."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
+
+
 @dataclasses.dataclass
 class ScheduledBatch:
     """One device step's worth of work, already laid out as padded numpy
@@ -59,6 +70,10 @@ class ScheduledBatch:
     # decode + mixed (decode rows)
     page_tables: Optional[np.ndarray] = None      # [B_pad, pages_bucket]
     context_lens: Optional[np.ndarray] = None     # [B_pad]
+    # decode + mixed: where each decode row's input token comes from: the
+    # row of the step in flight's last-token output that holds it, or -1
+    # for the token in ``tokens`` (the host knows it)
+    tok_src: Optional[np.ndarray] = None          # [B_pad]
     # a state model only: the state slot of each segment [B_pad] (prefill,
     # chunk, mixed: the chunk's first) and of each decode row [B_pad];
     # padding names the scrap slot 0
@@ -203,6 +218,9 @@ class Scheduler:
         # Monotone high-water marks for padded shapes (stats/debug).
         self.num_preemptions = 0
         self.num_preemptions_by_kind = {"recompute": 0, "swap": 0}
+        # KGCT_SANITIZE: told of every release of pages and slot before it
+        # happens (analysis/sanitize.py on_release); None when off.
+        self.release_guard = None
 
     def attach_swapper(self, swapper) -> None:
         """Enable preempt-by-swap (engine/kv_cache.KVSwapper)."""
@@ -263,6 +281,8 @@ class Scheduler:
         return None
 
     def _release(self, seq: Sequence) -> None:
+        if self.release_guard is not None:
+            self.release_guard(seq)
         if seq.pages:
             self.allocator.free(seq.pages)
             seq.pages = []
@@ -493,7 +513,7 @@ class Scheduler:
             if seq.num_prefilled == 0 and not seq.pages:
                 yield seq, self.qos.resolve(seq.params.qos_tier)
 
-    def _qos_pass(self) -> None:
+    def _qos_pass(self, behind: bool = False) -> None:
         """Once per schedule() — on EVERY call, waiting-empty included:
         sync the tier activity set first (a tier's departure during a
         pure-decode stretch must be observed, or its later return would
@@ -509,7 +529,7 @@ class Scheduler:
         if not self.waiting:
             return
         self._qos_promote()
-        self._qos_make_room()
+        self._qos_make_room(behind)
 
     def _qos_promote(self) -> None:
         """Weighted-fair admission order: move the first fresh waiting
@@ -531,7 +551,7 @@ class Scheduler:
                 self.waiting.appendleft(seq)
                 return
 
-    def _qos_make_room(self) -> None:
+    def _qos_make_room(self, behind: bool = False) -> None:
         """Priority admission preemption: when the (promoted) fresh head is
         blocked by seats or pages, evict strictly-LOWER-priority running
         sequences (lowest tier first, youngest within it) until it fits or
@@ -563,6 +583,8 @@ class Scheduler:
                     floor = p
             if victim is None:
                 return
+            if behind:
+                raise CannotChain("no_pages")
             self.running.remove(victim)
             self._evict(victim, behind_head=True)
 
@@ -652,13 +674,21 @@ class Scheduler:
 
     # -- scheduling ---------------------------------------------------------
 
-    def schedule(self) -> Optional[ScheduledBatch]:
-        batch = self._schedule_inner()
+    def schedule(self, behind: bool = False) -> Optional[ScheduledBatch]:
+        """The next batch. ``behind``: a step is in flight; rows take
+        positions, pages and slots from ``Sequence.sched_tokens`` and their
+        newest token from that step's output (``tok_src``), rows that
+        finish inside it are left out, a batch that would need a
+        preemption raises :class:`CannotChain` (what was grown before that
+        stays with its sequences: the next call needs it anyway), and no
+        waiting head is finished for want of pool: sequences that ended in
+        the step in flight hold their pages until it is fetched."""
+        batch = self._schedule_inner(behind)
         if self.qos is not None and batch is not None:
             self._qos_charge_batch(batch)
         return batch
 
-    def _schedule_inner(self) -> Optional[ScheduledBatch]:
+    def _schedule_inner(self, behind: bool) -> Optional[ScheduledBatch]:
         # Swap-readmission first: restored sequences rejoin ``running`` and
         # ride whatever batch this very call builds — resumption is a
         # memcpy plus a decode step, never a prefill.
@@ -668,7 +698,7 @@ class Scheduler:
         # fair-share promotion + priority make-room run before any
         # admission path looks at the queue.
         if self.qos is not None:
-            self._qos_pass()
+            self._qos_pass(behind)
         # Acceptance-adaptive speculation at the k=0 floor: tick the idle
         # cooldown ONCE per schedule call (both the spec and spec-mixed
         # builders read current_k; ticking inside them would double-count
@@ -690,10 +720,10 @@ class Scheduler:
                 batch = build_spec_mixed_batch(self)
                 if batch is not None:
                     return batch
-            batch = build_mixed_batch(self)
+            batch = build_mixed_batch(self, behind)
             if batch is not None:
                 return batch
-        batch = self._schedule_prefills()
+        batch = self._schedule_prefills(behind)
         if batch is not None:
             return batch
         # Speculative decoding replaces the pure decode step when enabled:
@@ -708,7 +738,7 @@ class Scheduler:
             batch = build_spec_batch(self)
             if batch is not None:
                 return batch
-        return self._schedule_decode()
+        return self._schedule_decode(behind)
 
     # Bounded lookahead past a blocked queue head: fills the batch with
     # later sequences that DO fit (no reordering — skipped sequences keep
@@ -717,7 +747,8 @@ class Scheduler:
     # behind it, while the bound prevents unbounded queue scans.
     PREFILL_LOOKAHEAD = 8
 
-    def _schedule_prefills(self) -> Optional[ScheduledBatch]:
+    def _schedule_prefills(self, behind: bool = False
+                           ) -> Optional[ScheduledBatch]:
         # A sequence larger than the prefill token budget streams through in
         # chunks, admitted solo (its chunk attends to its pool history).
         # When the chunk is BLOCKED (no pages / batch full), fall through to
@@ -770,10 +801,15 @@ class Scheduler:
             # satisfy the probe, which must not happen for candidates the
             # token budget rejects anyway.
             fits_pages = fits_budget and self.allocator.can_admit(need)
-            if not fits_pages and i == 0 and not self.running and not admitted:
+            if (not fits_pages and i == 0 and not self.running
+                    and not admitted and not behind):
                 # Pool is empty and the head still doesn't fit: it has grown
                 # (via preempt-recompute) past total capacity and can never be
-                # scheduled — terminate it at capacity.
+                # scheduled — terminate it at capacity. Never with a step in
+                # flight: a sequence that ended in it (EOS, a stop string,
+                # an abort) has left ``running`` and still holds its pages
+                # until that step is fetched, so "nothing runs" is not "the
+                # pool is empty" there; the head waits that one step out.
                 self.waiting.popleft()
                 self._release(seq)
                 seq.status = SequenceStatus.FINISHED
@@ -939,18 +975,23 @@ class Scheduler:
 
     def _fill_decode_row(self, seq: Sequence, row: int, offset: int,
                          tokens, positions, slot_mapping,
-                         page_tables, context_lens) -> None:
+                         page_tables, context_lens, tok_src) -> None:
         """One decode row's step inputs (token slot ``offset + row``, table
-        row ``row``): shared by the pure decode and mixed layouts."""
-        pos = seq.num_tokens - 1
-        tokens[offset + row] = (seq.output_token_ids[-1]
-                                if seq.output_token_ids
-                                else seq.prompt_token_ids[-1])
+        row ``row``): shared by the pure decode and mixed layouts. A row
+        with tokens in flight names the row of that step's output that
+        holds its input token; the host does not know it yet."""
+        pos = seq.sched_tokens - 1
+        if seq.inflight_tokens:
+            tok_src[row] = seq.inflight_row
+        else:
+            tokens[offset + row] = (seq.output_token_ids[-1]
+                                    if seq.output_token_ids
+                                    else seq.prompt_token_ids[-1])
         positions[offset + row] = pos
         slot_mapping[offset + row] = (seq.pages[pos // self.page_size] *
                                       self.page_size + pos % self.page_size)
         page_tables[row, :len(seq.pages)] = seq.pages
-        context_lens[row] = seq.num_tokens
+        context_lens[row] = seq.sched_tokens
 
     def _try_prefix_reuse(self, seq: Sequence) -> None:
         """Prefix-cache reuse rides the chunked-prefill machinery: a cached
@@ -994,22 +1035,30 @@ class Scheduler:
             self.prefix_cache.register(seq.prompt_token_ids,
                                        seq.pages[:full])
 
-    def _grow_decode_pages(self, window: int) -> list[Sequence]:
+    def _grow_decode_pages(self, window: int,
+                           behind: bool = False) -> list[Sequence]:
         """Ensure every running seq has pages covering a ``window``-step
         decode (the device writes ``window`` new KV entries before the host
         sees any token); preempt the youngest until the rest fit. Returns
         the sequences whose pages now cover the window — the decode rows of
         this step. Shared by the pure decode path (window = decode_window)
         and the mixed path (window = 1: mixed steps advance decode by one
-        token, since the chunk in the same program runs once)."""
+        token, since the chunk in the same program runs once). ``behind``
+        (a step is in flight): growth that would need a victim raises
+        :class:`CannotChain` instead; the victim's newest tokens are still
+        on the chip."""
         scheduled: list[Sequence] = []
         idx = 0
+        max_len = self.config.effective_max_len
         while idx < len(self.running):
             seq = self.running[idx]
+            if seq.finishes_in_flight(max_len):
+                idx += 1          # done before it is fetched: rides no more
+                continue
             # Window inputs occupy positions num_tokens-1 .. num_tokens+W-2
             # (see Sequence.last_window_pos for the clamp rationale).
             last_pos = seq.last_window_pos(
-                seq.num_tokens - 1, window, self.config.effective_max_len)
+                seq.sched_tokens - 1, window, max_len)
             pages_needed = cdiv(last_pos + 1, self.page_size)
             grow = pages_needed - len(seq.pages)
             if grow > 0:
@@ -1020,6 +1069,8 @@ class Scheduler:
                     # off; tier-aware (lowest-priority-first, never a
                     # higher tier for a lower requester) when on — always
                     # among running[idx:], the not-yet-granted tail.
+                    if behind:
+                        raise CannotChain("no_pages")
                     if not self._preempt_victim(idx):
                         break
                     continue  # retry same index (list shrank behind idx)
@@ -1027,10 +1078,12 @@ class Scheduler:
             idx += 1
         return scheduled
 
-    def _schedule_decode(self) -> Optional[ScheduledBatch]:
+    def _schedule_decode(self, behind: bool = False
+                         ) -> Optional[ScheduledBatch]:
         if not self.running:
             return None
-        scheduled = self._grow_decode_pages(self.config.scheduler.decode_window)
+        scheduled = self._grow_decode_pages(
+            self.config.scheduler.decode_window, behind)
         if not scheduled:
             return None
 
@@ -1045,14 +1098,15 @@ class Scheduler:
         slot_mapping = np.zeros(B, np.int32)
         page_tables = np.zeros((B, pages_bucket), np.int32)
         context_lens = np.zeros(B, np.int32)
+        tok_src = np.full(B, -1, np.int32)
         for s, seq in enumerate(scheduled):
             self._fill_decode_row(seq, s, 0, tokens, positions, slot_mapping,
-                                  page_tables, context_lens)
+                                  page_tables, context_lens, tok_src)
 
         return ScheduledBatch(
             kind="decode", seqs=scheduled, tokens=tokens, positions=positions,
             slot_mapping=slot_mapping, page_tables=page_tables,
-            context_lens=context_lens,
+            context_lens=context_lens, tok_src=tok_src,
             row_slots=self._state_slots(scheduled, B),
             **self._sampling_arrays(scheduled, B))
 

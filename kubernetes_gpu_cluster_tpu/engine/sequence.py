@@ -79,6 +79,14 @@ class Sequence:
         # it to a decode replica (scheduler.finish parks it in
         # ``scheduler.held`` instead of releasing; aborts still release).
         self.hold_kv = False
+        # The device queue: tokens this sequence was sampled by the step
+        # program in flight (dispatched, not fetched: W of a decode window,
+        # 1 of a mixed or prefill step), and the row of that program's
+        # last-token output that holds the newest. The engine sets both
+        # when the step's predecessor retires and clears them when the
+        # step itself does; 0 and -1 whenever nothing is in flight.
+        self.inflight_tokens = 0
+        self.inflight_row = -1
 
     @property
     def all_token_ids(self) -> list[int]:
@@ -98,6 +106,23 @@ class Sequence:
     def num_tokens(self) -> int:
         return self.num_prompt_tokens + self.num_output_tokens
 
+    @property
+    def sched_tokens(self) -> int:
+        """``num_tokens`` as it will read once the step in flight is
+        fetched (a finish inside it aside): what a successor dispatched
+        behind that step takes its positions, context lengths, slots and
+        page growth from."""
+        return self.num_tokens + self.inflight_tokens
+
+    def finishes_in_flight(self, max_len: int) -> bool:
+        """The step in flight reaches this request's ``max_tokens`` or the
+        model's length: it is known to finish before its tokens are
+        fetched and rides no further step."""
+        return self.inflight_tokens > 0 and (
+            self.num_output_tokens + self.inflight_tokens
+            >= self.params.max_tokens
+            or self.sched_tokens >= max_len)
+
     def last_window_pos(self, next_input_pos: int, window: int,
                         max_len: int) -> int:
         """Highest position a decode window starting its inputs at
@@ -105,8 +130,8 @@ class Sequence:
         request's own max_tokens budget. Window-tail tokens past either
         bound route to the scrap page, so page growth sized by this bound
         makes EXACTLY-sized pools safe (no pages a request can never use).
-        The single source of truth for scheduler._schedule_decode and the
-        speculative chain's engine._advance_window."""
+        The single source of truth for every decode-row builder of the
+        scheduler, whether a step is in flight or not."""
         return min(next_input_pos + window - 1, max_len - 1,
                    self.num_prompt_tokens + self.params.max_tokens - 1)
 

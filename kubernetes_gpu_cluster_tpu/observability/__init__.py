@@ -195,6 +195,12 @@ class Observability:
                                  "spec": 0, "spec_mixed": 0}
         self.mixed_prefill_tokens = 0
         self.mixed_decode_tokens = 0
+        # The one-deep device queue (engine._step): step programs
+        # dispatched, by kind and by whether one was still unfetched when
+        # this one was queued behind it; and the times no step could be
+        # scheduled behind the one in flight, by reason.
+        self.steps_dispatched: dict[tuple[str, bool], int] = {}
+        self.chain_breaks: dict[str, int] = {}
         # Expert models: (token, expert) pairs sent through the expert
         # layers, by step kind; the routing balance of the last prefill,
         # chunk or mixed step (busiest expert's pairs over the mean); and,
@@ -456,6 +462,13 @@ class Observability:
 
     # -- step accounting (engine.step) ---------------------------------------
 
+    def on_step_dispatched(self, kind: str, behind: bool) -> None:
+        key = (kind, behind)
+        self.steps_dispatched[key] = self.steps_dispatched.get(key, 0) + 1
+
+    def on_chain_break(self, reason: str) -> None:
+        self.chain_breaks[reason] = self.chain_breaks.get(reason, 0) + 1
+
     def on_step(self, step: int, kind: str, batch: int, duration_s: float,
                 new_tokens: int, mode: str = None, prefill_tokens: int = 0,
                 decode_tokens: int = 0, drafted_tokens: int = 0,
@@ -626,6 +639,20 @@ class Observability:
                                   self.sampled_decode_ratio()))
         lines.extend(render_gauge("kgct_mixed_step_ratio",
                                   self.mixed_step_ratio()))
+        lines.append("# HELP kgct_steps_dispatched_total step programs "
+                     "dispatched, by kind; behind=1: queued while its "
+                     "predecessor was still unfetched")
+        lines.append("# TYPE kgct_steps_dispatched_total counter")
+        for (kind, behind), n in sorted(self.steps_dispatched.items()):
+            lines.append(
+                'kgct_steps_dispatched_total{kind="%s",behind="%d"} %d'
+                % (kind, behind, n))
+        lines.append("# HELP kgct_chain_breaks_total times no step could "
+                     "be scheduled behind the one in flight, by reason")
+        lines.append("# TYPE kgct_chain_breaks_total counter")
+        for reason, n in sorted(self.chain_breaks.items()):
+            lines.append('kgct_chain_breaks_total{reason="%s"} %d'
+                         % (reason, n))
         if self.moe_routed_pairs:
             lines.append("# HELP kgct_moe_routed_pairs_total (token, expert) "
                          "pairs sent through the expert layers, by step kind")

@@ -245,27 +245,58 @@ def _anon(line):
     return _BARE.sub("_", line)
 
 
+def _bag(text):
+    return collections.Counter(
+        _anon(l) for l in text.split("\n")[1:] if l.strip())
+
+
+def _pair_renamed(a, b):
+    """A program whose ARGUMENTS changed is recorded under another key (the
+    key holds its last arguments' shapes). Pair what only one side has, by
+    case and program, each with the entry of the other side whose equations
+    are nearest to its own."""
+    groups = collections.defaultdict(lambda: ([], []))
+    for side, (mine, other) in enumerate(((a, b), (b, a))):
+        for k in sorted(set(mine) - set(other)):
+            groups[tuple(k.split(":")[:2])][side].append(k)
+    pairs, lone = [], []
+    for (case, prog), (ka, kb) in sorted(groups.items()):
+        bags = {k: _bag(b[k]) for k in kb}
+        for k in ka:
+            if not bags:
+                lone.append(k)
+                continue
+            mine = _bag(a[k])
+            near = min(bags, key=lambda o: sum(
+                ((mine - bags[o]) + (bags[o] - mine)).values()))
+            del bags[near]
+            pairs.append((k, near))
+        lone += sorted(bags)
+    return pairs, lone
+
+
 def compare(path_a, path_b):
     a, b = json.load(open(path_a)), json.load(open(path_b))
-    print("entries:", len(a), len(b), "only in one:", sorted(set(a) ^ set(b)))
+    renamed, lone = _pair_renamed(a, b)
+    print("entries:", len(a), len(b), "under another key (arguments "
+          "changed):", len(renamed), "only in one:", lone)
     tiers = collections.defaultdict(collections.Counter)
     shown = {}
-    for k in sorted(set(a) & set(b)):
+    for k, kb in [(k, k) for k in sorted(set(a) & set(b))] + renamed:
         case, prog = k.split(":")[:2]
-        ta, tb = a[k], b[k]
+        ta, tb = a[k], b[kb]
         if ta == tb:
             tiers[case][prog, "text-equal"] += 1
         elif ta.startswith("GRAPH") and ta.split("\n")[0] == tb.split("\n")[0]:
             tiers[case][prog, "graph-equal"] += 1
         else:
             tiers[case][prog, "DIFFERS"] += 1
-            ba, bb = (collections.Counter(
-                _anon(l) for l in t.split("\n")[1:] if l.strip())
-                for t in (ta, tb))
+            ba, bb = _bag(ta), _bag(tb)
             lines = ([f"     - x{n} {l[:230]}" for l, n in sorted((ba - bb).items())]
                      + [f"     + x{n} {l[:230]}" for l, n in sorted((bb - ba).items())])
             sig = "\n".join(lines)
-            print(f"DIFFERS {k[:100]}")
+            print(f"DIFFERS {k[:100]}"
+                  + (f"\n   ~ {kb[:100]}" if kb != k else ""))
             print(f"     (the lines of {shown[sig]})" if sig in shown else sig)
             shown.setdefault(sig, k[:60])
     for case in sorted(tiers):

@@ -379,3 +379,193 @@ class TestLogprobs:
         ref_lp = float(jax.nn.log_softmax(logits)[out.output_token_ids[0]])
         np.testing.assert_allclose(out.output_logprobs[0], ref_lp,
                                    rtol=1e-4, atol=1e-4)
+
+
+# -- the one-deep device queue: every step kind dispatched behind the one in
+# flight must give what the same engine gives when it schedules each step
+# only after the one before it was fetched ----------------------------------
+
+def _queue_engine(model="debug-tiny", eos=None, num_pages=128, **sched):
+    kw = dict(max_num_seqs=4, max_prefill_tokens=32, decode_buckets=(1, 2, 4),
+              prefill_buckets=(16, 32), decode_window=4)
+    kw.update(sched)
+    return LLMEngine(EngineConfig(
+        model=get_model_config(model),
+        cache=CacheConfig(page_size=8, num_pages=num_pages),
+        scheduler=SchedulerConfig(**kw)), eos_token_id=eos)
+
+
+def _drive(eng, arrivals, aborts=()):
+    """Step ``eng`` through a script: ``arrivals`` are (call, request id,
+    prompt, params), ``aborts`` (call, request id); ``call`` counts the
+    calls of ``step()``. Returns request id -> (tokens, finish reason,
+    logprobs, the alternatives' ids, their values)."""
+    arrivals, aborts = list(arrivals), list(aborts)
+    seen, call = {}, 0
+    while arrivals or aborts or eng.has_unfinished_requests():
+        for item in [a for a in arrivals if a[0] <= call]:
+            arrivals.remove(item)
+            eng.add_request(*item[1:])
+        for item in [a for a in aborts if a[0] <= call]:
+            aborts.remove(item)
+            eng.abort_request(item[1])
+        if eng.has_unfinished_requests():
+            for o in eng.step():
+                seen[o.request_id] = (
+                    list(o.output_token_ids), o.finish_reason,
+                    list(o.output_logprobs or []),
+                    [[t for t, _ in row]
+                     for row in o.output_top_logprobs or []],
+                    [[v for _, v in row]
+                     for row in o.output_top_logprobs or []])
+        call += 1
+        assert call < 2000
+    return seen
+
+
+_RNG = np.random.default_rng(7)
+_P = [[int(t) for t in _RNG.integers(1, 200, n)]
+      for n in (5, 12, 9, 80, 20, 7, 30)]
+_GREEDY = SamplingParams(max_tokens=21, temperature=0.0)
+
+
+def _staggered(params, prompts=_P[:3] + _P[4:6], every=2):
+    params = params if isinstance(params, list) else [params] * len(prompts)
+    return [(i * every, f"r{i}", p, sp)
+            for i, (p, sp) in enumerate(zip(prompts, params))]
+
+
+def _eos_of(model="debug-tiny"):
+    """An id the greedy decode of ``_P[0]`` emits a few tokens in: declared
+    EOS it ends that request inside a decode window."""
+    out = _queue_engine(model).generate(
+        [_P[0]], SamplingParams(max_tokens=12, temperature=0.0))[0]
+    return out.output_token_ids[6]
+
+
+QUEUE_CASES = {
+    "greedy": lambda: dict(arrivals=_staggered(_GREEDY)),
+    "seeded_penalties": lambda: dict(arrivals=_staggered(
+        [SamplingParams(max_tokens=18 + i, temperature=0.8, top_k=20,
+                        seed=11 + i, presence_penalty=0.3 * (i % 2),
+                        frequency_penalty=0.2, logprobs=True)
+         for i in range(5)])),
+    "top_logprobs_5": lambda: dict(arrivals=_staggered(
+        SamplingParams(max_tokens=13, temperature=0.0, logprobs=True,
+                       top_logprobs=5))),
+    # 80 tokens at a budget of 32 beside decode rows: three mixed steps
+    "three_chunks_mixed": lambda: dict(
+        arrivals=[(0, "r0", _P[0], _GREEDY), (0, "r1", _P[1], _GREEDY),
+                  (3, "long", _P[3], _GREEDY), (5, "r2", _P[2], _GREEDY)],
+        kinds={"mixed": 3}),
+    "packed_prefill_of_three": lambda: dict(
+        arrivals=[(0, "r0", _P[0], _GREEDY)]
+        + [(2, f"p{i}", p, _GREEDY) for i, p in enumerate(_P[:3])],
+        sched=dict(mixed_batch_enabled=False)),
+    "max_tokens_and_eos_in_window": lambda: dict(
+        arrivals=[(0, "r0", _P[0], SamplingParams(max_tokens=40,
+                                                  temperature=0.0)),
+                  (0, "r1", _P[1], SamplingParams(max_tokens=6,
+                                                  temperature=0.0,
+                                                  ignore_eos=True)),
+                  (1, "r2", _P[2], SamplingParams(max_tokens=15,
+                                                  temperature=0.0,
+                                                  ignore_eos=True)),
+                  (7, "r3", _P[5], _GREEDY)],
+        eos=_eos_of(), reasons={"r0": "stop", "r1": "length"}),
+    "abort_in_flight": lambda: dict(
+        arrivals=_staggered(SamplingParams(max_tokens=30, temperature=0.0)),
+        aborts=[(4, "r1"), (9, "r3")]),
+    "preemption": lambda: dict(
+        arrivals=_staggered(SamplingParams(max_tokens=40, temperature=0.0),
+                            every=1),
+        num_pages=14, preempts=True),
+    # A pool of 5 pages: "a" holds 2-5 of them and "b" (4 to be admitted)
+    # waits; "a" ends in the step in flight and leaves ``running`` with its
+    # pages still held, which is no empty pool: "b" is served a step later,
+    # not finished at capacity.
+    "eos_frees_tight_pool": lambda: dict(
+        arrivals=[(0, "a", _P[0], SamplingParams(max_tokens=30,
+                                                 temperature=0.0)),
+                  (1, "b", _P[6], SamplingParams(max_tokens=8,
+                                                 temperature=0.0,
+                                                 ignore_eos=True))],
+        eos=_eos_of(), num_pages=6, reasons={"a": "stop", "b": "length"},
+        tokens={"b": 8}),
+    "abort_frees_tight_pool": lambda: dict(
+        arrivals=[(0, "a", _P[0], SamplingParams(max_tokens=30,
+                                                 temperature=0.0)),
+                  (1, "b", _P[6], SamplingParams(max_tokens=8,
+                                                 temperature=0.0))],
+        aborts=[(4, "a")], num_pages=6, reasons={"b": "length"},
+        tokens={"b": 8}),
+    # One packed prefill of three, then windows over the same three rows
+    # to the same last token: both loops build the very same batches, so
+    # here the values are held bit for bit.
+    "same_batches_bitwise": lambda: dict(
+        arrivals=[(0, f"r{i}", p, SamplingParams(
+            max_tokens=21, temperature=0.0, logprobs=True, top_logprobs=5))
+            for i, p in enumerate(_P[:3])],
+        bitwise=True),
+    "latent_page_experts": lambda: dict(
+        model="debug-mla-moe",
+        arrivals=_staggered(_GREEDY) + [(4, "long", _P[3], _GREEDY)]),
+    "state_slots": lambda: dict(
+        model="debug-ssm-hybrid",
+        arrivals=_staggered(_GREEDY) + [(4, "long", _P[3], _GREEDY)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(QUEUE_CASES))
+def test_device_queue_matches_chain_broken_every_step(case):
+    """Token ids of every request bit for bit, logprobs to float32
+    rounding (bit for bit where both loops build the same batches): the loop that dispatches each step behind the one in flight
+    against the same engine made to fetch every step before it schedules
+    the next."""
+    spec = QUEUE_CASES[case]()
+    kw = dict(model=spec.get("model", "debug-tiny"), eos=spec.get("eos"),
+              num_pages=spec.get("num_pages", 128), **spec.get("sched", {}))
+    results = {}
+    for mode in ("queued", "broken"):
+        eng = _queue_engine(**kw)
+        if mode == "broken":
+            eng._chain_break = lambda pred: "forced"
+        results[mode] = _drive(eng, spec["arrivals"], spec.get("aborts", ()))
+        alloc = eng.scheduler.allocator
+        assert alloc.num_free == alloc.num_pages - 1, (mode, "pages leaked")
+        assert not eng._deferred_release and eng._inflight is None
+        behind = sum(n for (_, b), n in eng.obs.steps_dispatched.items() if b)
+        if mode == "queued":
+            assert behind > 0 and "forced" not in eng.obs.chain_breaks
+            if spec.get("preempts"):
+                assert eng.scheduler.num_preemptions > 0
+                assert eng.obs.chain_breaks.get("no_pages", 0) > 0
+            for kind, n in spec.get("kinds", {}).items():
+                assert eng.obs.step_kind_counts[kind] >= n
+        else:
+            assert behind == 0 and eng.obs.chain_breaks["forced"] > 0
+    queued, broken = results["queued"], results["broken"]
+    assert queued.keys() == broken.keys()
+    aborted = {rid for _, rid in spec.get("aborts", ())}
+    for rid in queued:
+        if rid in aborted:
+            # cut at another token by the call it fell in: one a prefix
+            a, b = queued[rid][0], broken[rid][0]
+            n = min(len(a), len(b))
+            assert a[:n] == b[:n]
+            continue
+        # ids, finish reason and the alternatives' ids: equal. The values:
+        # to float32 rounding, for a row's logits come from programs of
+        # other row counts where a prompt rides a window later.
+        assert queued[rid][:2] == broken[rid][:2], rid
+        assert queued[rid][3] == broken[rid][3], rid
+        if spec.get("bitwise"):
+            assert queued[rid][2] and queued[rid] == broken[rid], rid
+        np.testing.assert_allclose(queued[rid][2], broken[rid][2],
+                                   rtol=0, atol=2e-5, err_msg=rid)
+        for a, b in zip(queued[rid][4], broken[rid][4]):
+            np.testing.assert_allclose(a, b, rtol=0, atol=2e-5, err_msg=rid)
+    for rid, reason in spec.get("reasons", {}).items():
+        assert queued[rid][1] == reason
+    for rid, n in spec.get("tokens", {}).items():
+        assert len(queued[rid][0]) == n

@@ -788,6 +788,22 @@ class TestMigrationStateSafety:  # KGCT014
         """, "KGCT014", relpath="engine/engine.py")
         assert len(found) == 1
 
+    @pytest.mark.parametrize("source", [
+        "seq.num_tokens + seq.inflight_tokens", "seq.sched_tokens",
+        "seq.inflight_row"])
+    def test_tokens_in_flight_of_a_sequence_fire(self, source):
+        """The device queue's per-sequence bookkeeping: what a sequence
+        WILL hold once the step in flight is fetched is no committed
+        quantity either, wherever it is kept."""
+        found = lint(f"""
+            class Engine:
+                def _export_state(self, seq, k_np, v_np):
+                    state = {{"k": k_np, "v": v_np}}
+                    state["num_tokens"] = {source}
+                    return state
+        """, "KGCT014", relpath="engine/engine.py")
+        assert len(found) == 1 and "committed" in found[0].message
+
     def test_committed_only_export_with_zombie_bookkeeping_silent(self):
         """The idiomatic export: committed host history + fetched buffers
         into the state; the in-flight window touched ONLY for retirement

@@ -448,3 +448,100 @@ class TestBenchOutputContract:
         last = captured.rstrip("\n").splitlines()[-1]
         parsed = json.loads(last)
         assert parsed["unit"] == "tokens/s/chip"
+
+
+# -- the device queue's counters (engine._step) and the metric that reads them
+
+def _queue_counter_engine(spec=False):
+    from kubernetes_gpu_cluster_tpu.config import (
+        CacheConfig, EngineConfig, SchedulerConfig, get_model_config)
+    from kubernetes_gpu_cluster_tpu.engine import LLMEngine
+    return LLMEngine(EngineConfig(
+        model=get_model_config("debug-tiny"),
+        cache=CacheConfig(page_size=8, num_pages=64),
+        scheduler=SchedulerConfig(
+            max_num_seqs=4, max_prefill_tokens=32, decode_buckets=(1, 2, 4),
+            prefill_buckets=(16, 32), decode_window=4,
+            spec_decode_enabled=spec, num_speculative_tokens=3)))
+
+
+def _counter_lines(eng, family):
+    return {l.split(" ")[0][len(family):]: int(l.split(" ")[1])
+            for l in eng.obs.render_prometheus() if l.startswith(family)}
+
+
+class TestDeviceQueueCounters:
+    def test_counts_what_a_scripted_run_did(self):
+        """One prompt, 13 tokens at a window of 4: a prefill with nothing
+        before it, then three windows each queued behind an unfetched step
+        (1 + 3 x 4 tokens: the third is known to reach ``max_tokens``, so
+        nothing rides behind it); a second prompt then arrives between two
+        windows and rides a mixed step behind one."""
+        from kubernetes_gpu_cluster_tpu.engine import SamplingParams
+        eng = _queue_counter_engine()
+        sp = SamplingParams(max_tokens=13, temperature=0.0)
+        eng.generate([[1, 2, 3]], sp)
+        got = _counter_lines(eng, "kgct_steps_dispatched_total")
+        assert got == {'{kind="prefill",behind="0"}': 1,
+                       '{kind="decode",behind="1"}': 3}
+        assert _counter_lines(eng, "kgct_chain_breaks_total") == {}
+        eng.add_request("a", [5, 6, 7], sp)
+        eng.step()
+        eng.add_request("b", [9, 8, 7, 6], sp)
+        while eng.has_unfinished_requests():
+            eng.step()
+        got = _counter_lines(eng, "kgct_steps_dispatched_total")
+        assert got['{kind="mixed",behind="1"}'] == 1
+        assert got['{kind="prefill",behind="0"}'] == 2
+        assert sum(got.values()) == sum(eng.obs.steps_dispatched.values())
+        text = "\n".join(eng.obs.render_prometheus())
+        assert "# TYPE kgct_steps_dispatched_total counter" in text
+        assert "# TYPE kgct_chain_breaks_total counter" in text
+
+    def test_spec_step_is_never_behind_and_says_why(self):
+        from kubernetes_gpu_cluster_tpu.engine import SamplingParams
+        eng = _queue_counter_engine(spec=True)
+        eng.generate([[7, 3, 9, 11] * 4],
+                     SamplingParams(max_tokens=16, temperature=0.0))
+        got = _counter_lines(eng, "kgct_steps_dispatched_total")
+        assert got.get('{kind="spec",behind="0"}', 0) > 0
+        assert not any('behind="1"' in k for k in got)
+        breaks = _counter_lines(eng, "kgct_chain_breaks_total")
+        assert set(breaks) == {'{reason="spec"}'}
+        assert breaks['{reason="spec"}'] >= got['{kind="spec",behind="0"}']
+
+    def test_layer_metric_file_reads_the_share_from_two_scrapes(self):
+        """``steps_dispatched_behind_share`` is a data file over the reader
+        the benchmark has: it loads, names its layer as BENCHMARK.json does,
+        and ``prom_ratio`` reads the share of a canned pair of scrapes; a
+        server without the family (the parent) reads nothing."""
+        from perfbench import stats
+        from perfbench.readers import prom_ratio
+        from perfbench.spec import Benchmark
+        bench = Benchmark()
+        spec = bench.layer_metric("steps_dispatched_behind_share")
+        entry = next(m for m in bench.doc["per_layer"]
+                     if m["name"] == "steps_dispatched_behind_share")
+        assert spec["reader"] == "prom_ratio"
+        assert {k: spec[k] for k in ("unit", "layer", "moves")} == {
+            k: entry[k] for k in ("unit", "layer", "moves")}
+        assert entry["workloads"] == bench.cell_names()
+        for cell in bench.cell_names():
+            assert "steps_dispatched_behind_share" in [
+                m["name"] for m in bench.cell(cell).per_layer]
+        before = stats.parse_prometheus(
+            'kgct_steps_dispatched_total{kind="decode",behind="1"} 100\n'
+            'kgct_steps_dispatched_total{kind="decode",behind="0"} 40\n'
+            'kgct_steps_dispatched_total{kind="mixed",behind="0"} 10\n'
+            'kgct_chain_breaks_total{reason="no_pages"} 3\n')
+        after = stats.parse_prometheus(
+            'kgct_steps_dispatched_total{kind="decode",behind="1"} 280\n'
+            'kgct_steps_dispatched_total{kind="decode",behind="0"} 45\n'
+            'kgct_steps_dispatched_total{kind="mixed",behind="0"} 10\n'
+            'kgct_steps_dispatched_total{kind="mixed",behind="1"} 15\n'
+            'kgct_chain_breaks_total{reason="no_pages"} 8\n')
+        ctx = {"scrape_before": before, "scrape_after": after}
+        assert prom_ratio.read(spec, ctx) == 100.0 * 195 / 200
+        parent = stats.parse_prometheus("kgct_step_duration_seconds_count 5\n")
+        assert prom_ratio.read(
+            spec, {"scrape_before": parent, "scrape_after": parent}) is None

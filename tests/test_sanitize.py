@@ -443,3 +443,138 @@ class TestInterleaveSanitizer:
         assert out3 == out1                    # still race-free
         s3 = _by_site(iz3.trace)
         assert s3["generate.stream"] != s1["generate.stream"]
+
+
+# -- device-queue shadow: nothing a dispatched, unfetched step program can
+# still write goes back to the allocator (engine._step, _drain_deferred) ----
+
+def _queue_cfg(model="debug-ssm-hybrid"):
+    return EngineConfig(
+        model=get_model_config(model),
+        cache=CacheConfig(page_size=8, num_pages=64),
+        scheduler=SchedulerConfig(
+            max_num_seqs=4, max_prefill_tokens=32, decode_buckets=(1, 2, 4),
+            prefill_buckets=(16, 32), decode_window=4))
+
+
+_QUEUE_PROMPTS = [[5, 9, 2, 7, 11], [3, 1, 4, 1, 5, 9, 2, 6], [8, 8, 2]]
+
+
+def _eos_mid_window(cfg):
+    """An id the first prompt's greedy decode emits inside a decode window
+    (not as its prefill's token) and not before: declared EOS it ends the
+    request in a step whose successor is already queued with a row for
+    it. Returns the id and how many tokens the request then has."""
+    out = LLMEngine(cfg).generate(
+        [_QUEUE_PROMPTS[0]], SamplingParams(max_tokens=10, temperature=0.0))
+    ids = out[0].output_token_ids
+    n = next(j for j in range(2, len(ids)) if ids[j] not in ids[:j])
+    return ids[n], n + 1
+
+
+def _staged(eng, abort=None):
+    """Three staggered requests; ``abort`` (call, request id) cuts one
+    while a step that has a row for it is in flight."""
+    sp = SamplingParams(max_tokens=24, temperature=0.0)
+    done, call = {}, 0
+    pending = [(2 * i, f"q{i}", p, sp) for i, p in enumerate(_QUEUE_PROMPTS)]
+    while pending or eng.has_unfinished_requests():
+        for item in [a for a in pending if a[0] <= call]:
+            pending.remove(item)
+            eng.add_request(*item[1:])
+        if abort is not None and abort[0] == call:
+            assert eng._inflight is not None
+            assert eng.abort_request(abort[1])
+        for o in eng.step():
+            done[o.request_id] = (list(o.output_token_ids), o.finish_reason)
+        call += 1
+    return done
+
+
+class TestDeviceQueueShadow:
+    def test_shadow_unit(self):
+        san = StepSanitizer(8)
+        a, b = _FakeSeq("a", 9, [1, 2]), _FakeSeq("b", 9, [3])
+        a.state_slot = b.state_slot = None
+        san.on_step_dispatch([a, b])
+        san.on_step_dispatch([b])
+        with pytest.raises(SanitizerError, match="device-queue shadow"):
+            san.on_release(a)
+        san.on_step_retire()                  # the oldest is fetched
+        san.on_release(a)                     # no program has a row for it
+        with pytest.raises(SanitizerError, match="b released"):
+            san.on_release(b)
+        b.pages, b.state_slot = [], 3         # a slot alone counts too
+        with pytest.raises(SanitizerError, match="device-queue shadow"):
+            san.on_release(b)
+        b.state_slot = None                   # nothing held: nothing to say
+        san.on_release(b)
+        san.on_step_retire()
+        san.on_step_retire()                  # an empty queue stays empty
+
+    def test_clean_run_under_both_sanitizers(self, monkeypatch):
+        """A finish by EOS inside a window whose successor is queued and an
+        abort of a row in flight, on the state model (pages AND slots):
+        identical with the sanitizers off and on, every page and slot back.
+        Then the same engine behind the real worker thread with the
+        interleave sanitizer widening every seam."""
+        monkeypatch.delenv("KGCT_SANITIZE", raising=False)
+        monkeypatch.delenv("KGCT_SANITIZE_INTERLEAVE", raising=False)
+        cfg = _queue_cfg()
+        eos, n_out = _eos_mid_window(cfg)
+        base = _staged(LLMEngine(cfg, eos_token_id=eos), abort=(5, "q1"))
+        assert base["q0"][1] == "stop" and len(base["q0"][0]) == n_out
+        monkeypatch.setenv("KGCT_SANITIZE", "1")
+        monkeypatch.setenv("KGCT_SANITIZE_INTERLEAVE", "1")
+        monkeypatch.setenv("KGCT_INTERLEAVE_SEED", "5")
+        eng = LLMEngine(cfg, eos_token_id=eos)
+        assert eng.scheduler.release_guard is not None
+        assert _staged(eng, abort=(5, "q1")) == base
+        alloc = eng.scheduler.allocator
+        assert alloc.num_free == alloc.num_pages - 1
+        assert alloc.num_free_slots == 4
+        assert not eng._sanitizer._dispatched and eng._sanitizer.checks > 0
+
+        a = AsyncLLMEngine(cfg, eos_token_id=eos)
+        assert a._interleave is not None and a.engine._sanitizer is not None
+        loop = asyncio.new_event_loop()
+        try:
+            async def consume(rid, prompt, cut=None):
+                toks = []
+                async for chunk in a.generate(
+                        rid, prompt,
+                        SamplingParams(max_tokens=24, temperature=0.0)):
+                    toks = list(chunk.output_token_ids)
+                    if cut is not None and len(toks) >= cut:
+                        a.abort(rid)
+                        break
+                return toks
+
+            async def go():
+                a.start(loop)
+                return await asyncio.gather(
+                    consume("q0", _QUEUE_PROMPTS[0]),
+                    consume("q1", _QUEUE_PROMPTS[1], cut=6),
+                    consume("q2", _QUEUE_PROMPTS[2]))
+            served = loop.run_until_complete(go())
+        finally:
+            a.shutdown()
+            loop.close()
+        assert served[0] == base["q0"][0] and served[2] == base["q2"][0]
+        assert any(y for _, _, y in a._interleave.trace)
+
+    def test_planted_early_release_is_caught(self, monkeypatch):
+        """The fault itself: the loop forgets that the successor, already
+        dispatched, has a row for a sequence that finishes now, and hands
+        its pages and slot back at once."""
+        monkeypatch.delenv("KGCT_SANITIZE", raising=False)
+        cfg = _queue_cfg()
+        eos, _ = _eos_mid_window(cfg)
+        monkeypatch.setenv("KGCT_SANITIZE", "1")
+        eng = LLMEngine(cfg, eos_token_id=eos)
+        commit = eng._process_window
+        eng._process_window = (
+            lambda batch, toks, lps, zombies, carried=frozenset(), **kw:
+            commit(batch, toks, lps, zombies, frozenset(), **kw))
+        with pytest.raises(SanitizerError, match="device-queue shadow"):
+            _staged(eng)

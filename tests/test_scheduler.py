@@ -4,7 +4,7 @@ import pytest
 
 from kubernetes_gpu_cluster_tpu.config import (
     CacheConfig, EngineConfig, SchedulerConfig, get_model_config)
-from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
+from kubernetes_gpu_cluster_tpu.engine.scheduler import CannotChain, Scheduler
 from kubernetes_gpu_cluster_tpu.engine.sampling_params import SamplingParams
 from kubernetes_gpu_cluster_tpu.engine.sequence import (
     FinishReason, Sequence, SequenceStatus)
@@ -143,6 +143,62 @@ class TestPreemptionInDecode:
         assert sched.num_preemptions == 1
         assert b.status == SequenceStatus.PREEMPTED
         assert sched.waiting[0] is b
+
+
+class TestScheduleBehind:
+    """``schedule(behind=True)``: a step is in flight, so what the
+    scheduler's queues show is not all there is."""
+
+    def test_no_capacity_termination_while_a_step_is_in_flight(self):
+        """A sequence that ended in the step in flight has left ``running``
+        and still holds its pages until that step is fetched: ``running``
+        empty is then no empty pool, and the waiting head that does not
+        fit yet waits, it is not finished at LENGTH."""
+        cfg = _cfg(num_pages=5, page_size=4)   # 4 usable pages = 16 tokens
+        sched = Scheduler(cfg, 5)
+        a = _seq("a", 10)                      # 3 pages
+        sched.add(a)
+        assert sched.schedule().kind == "prefill"
+        # "a" found finished (EOS, abort) while its successor is in flight:
+        # the engine takes it out of running and defers the release.
+        sched.running.remove(a)
+        a.status = SequenceStatus.FINISHED
+        b = _seq("b", 8, max_tokens=4)         # 2 pages: 1 is free
+        sched.add(b)
+        assert sched.schedule(behind=True) is None
+        assert b.status != SequenceStatus.FINISHED and sched.waiting[0] is b
+        assert not sched.terminally_finished
+        sched._release(a)                      # the step was fetched
+        batch = sched.schedule()
+        assert batch.kind == "prefill" and batch.seqs == [b]
+
+    def test_head_beyond_the_pool_is_still_finished_once_nothing_flies(self):
+        cfg = _cfg(num_pages=3, page_size=4)   # 2 usable pages = 8 tokens
+        sched = Scheduler(cfg, 3)
+        seq = _seq("grown", 6)
+        sched.add(seq)
+        for t in (7, 8, 9):
+            seq.append_token(t)
+        assert sched.schedule(behind=True) is None
+        assert seq.status != SequenceStatus.FINISHED
+        assert sched.schedule() is None
+        assert seq.finish_reason == FinishReason.LENGTH
+
+    def test_growth_that_needs_a_victim_raises_instead_of_preempting(self):
+        cfg = _cfg(num_pages=3, page_size=2, max_num_seqs=4)  # 2 usable pages
+        sched = Scheduler(cfg, 3)
+        a, b = _seq("a", 2), _seq("b", 2)
+        sched.add(a)
+        sched.add(b)
+        assert sched.schedule().kind == "prefill"
+        a.append_token(5)
+        b.append_token(6)
+        with pytest.raises(CannotChain) as e:
+            sched.schedule(behind=True)
+        assert e.value.reason == "no_pages"
+        assert sched.num_preemptions == 0 and sched.running == [a, b]
+        assert sched.schedule().seqs == [a]    # nothing in flight: as ever
+        assert sched.num_preemptions == 1
 
 
 class TestDecodeWindow:
