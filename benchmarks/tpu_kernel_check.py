@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import functools
 import json
+import math
 import os
 import sys
 import time
@@ -769,6 +770,76 @@ def check_ssm(cfg, B, T) -> None:
           f"run in {dt.__name__}) state={_err(final[0], f_ref):.2e}")
 
 
+def _state_chain(cfg, name: str, update, inputs, state_ref, y_ref, real,
+                 slots, limit: float) -> dict:
+    """The common part of the two gates of a recurrent state's precision
+    (``check_ssm_chain``, ``check_kda_chain``): ``inputs`` ([steps, rows +
+    padding, ...] each) through ``update(pool, layer, slots, *inputs[t]) ->
+    (pool, y)`` token by token, over a slot pool the engine's own allocation
+    made (so the pool's dtype is the program's, not this check's), against
+    the float64 recurrence's final state ``state_ref`` [rows, *slot] and
+    outputs ``y_ref`` [steps, rows, width], as max |error| over max
+    |value|.
+
+    Two planted faults run through the same chain and MUST read over
+    ``limit``, or the gate is blind and fails too: the state rounded to
+    bfloat16 after every token (what the configuration forbids), and one
+    row's slot left unchanged by one update 16 tokens before the end (a
+    stale slot)."""
+    from kubernetes_gpu_cluster_tpu.config import CacheConfig
+    from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
+    steps, rows = y_ref.shape[:2]
+    layer = cfg.num_state_layers // 2
+    stale_at = steps - 16
+
+    @functools.partial(jax.jit, static_argnames="fault", donate_argnums=0)
+    def chain(pool, *inputs, fault=None):
+        def token(pool, xs):
+            t, *ins = xs
+            before = pool[layer, slots[0]]
+            pool, y = update(pool, jnp.int32(layer), slots, *ins)
+            if fault == "bf16":
+                # (reduce_precision, not astype there and back: XLA on the
+                # TPU elides that round trip as excess precision allowed.)
+                pool = pool.at[layer].set(jax.lax.reduce_precision(
+                    pool[layer], exponent_bits=8, mantissa_bits=7))
+            if fault == "stale":
+                pool = pool.at[layer, slots[0]].set(jnp.where(
+                    t == stale_at, before, pool[layer, slots[0]]))
+            return pool, y
+        return jax.lax.scan(token, pool, (jnp.arange(steps), *inputs))
+
+    readings = {}
+    for fault in (None, "bf16", "stale"):
+        pool = allocate_kv_cache(cfg, CacheConfig(page_size=PS), 2,
+                                 num_state_slots=rows + 1).ssm
+        pool, y = jax.block_until_ready(chain(pool, *inputs, fault=fault))
+        got = np.asarray(pool.astype(jnp.float32))
+        e_s = (np.abs(got[layer, real] - state_ref).max()
+               / np.abs(state_ref).max())
+        e_y = (np.abs(np.asarray(y, np.float64)[:, :rows] - y_ref).max()
+               / np.abs(y_ref).max())
+        others = np.ones(got.shape[:2], bool)
+        others[layer] = False
+        readings[fault or "served"] = (float(e_s), float(e_y))
+        print(f"{name} chained {steps} tokens x {rows} rows, pool "
+              f"{pool.dtype} {list(pool.shape)}, {fault or 'as served'}: "
+              f"against the float64 recurrence state {e_s:.2e}, y {e_y:.2e} "
+              f"of max |S| {np.abs(state_ref).max():.2f}, max |y| "
+              f"{np.abs(y_ref).max():.2f}; the other layers untouched: "
+              f"{not got[others].any()}")
+        assert not got[others].any()
+        del pool, got
+    assert max(readings["served"]) < limit, (
+        f"the state as served is {max(readings['served']):.2e} from the "
+        f"float64 recurrence (limit {limit})")
+    for fault in ("bf16", "stale"):
+        assert min(readings[fault]) > limit, (
+            f"the planted fault {fault!r} reads {min(readings[fault]):.2e}, "
+            f"under the limit {limit}: this gate is blind")
+    return readings
+
+
 # The chained state's error against the float64 recurrence, as a share of
 # the largest value: the limit is the geometric mean of the largest reading
 # of the update as served (2.26e-7) and the smallest of a planted fault
@@ -790,15 +861,9 @@ def check_ssm_chain(cfg, kernels, steps: int = 512, rows: int = 4) -> dict:
     model's dtype); decay and dt * x are float32 for both sides, so what is
     compared is the recurrence's arithmetic and what the slot keeps of it.
 
-    Two planted faults run through the same chain and MUST read over the
-    limit, or the gate is blind and fails too: the state rounded to bfloat16
-    after every token (what the configuration forbids), and one row's slot
-    left unchanged by one update 16 tokens before the end (a stale slot)."""
-    from kubernetes_gpu_cluster_tpu.config import CacheConfig
-    from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
-    Ls, N, di = cfg.num_state_layers, cfg.mamba_d_state, cfg.mamba_d_inner
+    The planted faults and the limit's test: ``_state_chain``."""
+    N, di = cfg.mamba_d_state, cfg.mamba_d_inner
     H, P = cfg.mamba_n_heads, cfg.mamba_d_head
-    layer = Ls // 2
     f32 = np.float32
     rng = np.random.default_rng(17)
     dtype = cfg.jnp_dtype
@@ -827,57 +892,164 @@ def check_ssm_chain(cfg, kernels, steps: int = 512, rows: int = 4) -> dict:
         S += Bm[t].astype(np.float64)[:, :, None] * dtx[t][:, None, :]
         y_ref[t] = np.einsum("rnc,rn->rc", S, Cm[t].astype(np.float64))
 
-    stale_at = steps - 16
+    return _state_chain(
+        cfg, "ssm_update", kernels.ssm_update,
+        tuple(padded(a) for a in (decay, dtx, Bm, Cm)), S, y_ref, real,
+        slots, STATE_CHAIN_LIMIT)
 
-    @functools.partial(jax.jit, static_argnames="fault", donate_argnums=0)
-    def chain(pool, decay, dtx, Bm, Cm, fault=None):
-        def token(pool, xs):
-            t, d, u, b, c = xs
-            before = pool[layer, slots[0]]
-            pool, y = kernels.ssm_update(pool, jnp.int32(layer), slots,
-                                         d, u, b, c)
-            if fault == "bf16":
-                # (reduce_precision, not astype there and back: XLA on the
-                # TPU elides that round trip as excess precision allowed.)
-                pool = pool.at[layer].set(jax.lax.reduce_precision(
-                    pool[layer], exponent_bits=8, mantissa_bits=7))
-            if fault == "stale":
-                pool = pool.at[layer, slots[0]].set(jnp.where(
-                    t == stale_at, before, pool[layer, slots[0]]))
-            return pool, y
-        return jax.lax.scan(token, pool,
-                            (jnp.arange(steps), decay, dtx, Bm, Cm))
 
-    args = tuple(padded(a) for a in (decay, dtx, Bm, Cm))
-    readings = {}
-    for fault in (None, "bf16", "stale"):
-        pool = allocate_kv_cache(cfg, CacheConfig(page_size=PS), 2,
-                                 num_state_slots=rows + 1).ssm
-        pool, y = jax.block_until_ready(chain(pool, *args, fault=fault))
-        got = np.asarray(pool.astype(jnp.float32))
-        e_s = np.abs(got[layer, real] - S).max() / np.abs(S).max()
-        e_y = (np.abs(np.asarray(y, np.float64)[:, :rows] - y_ref).max()
-               / np.abs(y_ref).max())
-        others = np.ones(got.shape[:2], bool)
-        others[layer] = False
-        readings[fault or "served"] = (float(e_s), float(e_y))
-        print(f"ssm_update chained {steps} tokens x {rows} rows, pool "
-              f"{pool.dtype} [{Ls}, {rows + 1}, {N}, {di}], "
-              f"{fault or 'as served'}: against the float64 recurrence "
-              f"state {e_s:.2e}, y {e_y:.2e} of max |S| {np.abs(S).max():.1f}"
-              f", max |y| {np.abs(y_ref).max():.1f}; the other layers "
-              f"untouched: {not got[others].any()}")
-        assert not got[others].any()
-        del pool, got
-    e_s, e_y = readings["served"]
-    assert max(e_s, e_y) < STATE_CHAIN_LIMIT, (
-        f"the state as served is {max(e_s, e_y):.2e} from the float64 "
-        f"recurrence (limit {STATE_CHAIN_LIMIT})")
-    for fault in ("bf16", "stale"):
-        assert min(readings[fault]) > STATE_CHAIN_LIMIT, (
-            f"the planted fault {fault!r} reads {min(readings[fault]):.2e}, "
-            f"under the limit {STATE_CHAIN_LIMIT}: this gate is blind")
-    return readings
+def _kda_inputs(rng, cfg, *lead):
+    """A KDA layer's per-token inputs, drawn as the model's are: A = U(1,
+    16) a head and dt = logU(1e-3, 1e-1) a channel as the init draws them (g
+    = -A dt), unit keys and scaled unit queries of activations rounded to
+    the model's dtype, v likewise, beta a sigmoid. float32 numpy arrays
+    (g [.., H, d], beta [.., H], q, k, v [.., H, d])."""
+    H, hd = cfg.kda_n_heads, cfg.kda_head_dim
+    f32 = np.float32
+
+    def activations(*shape):        # as the model's: rounded to its dtype
+        return np.asarray(jnp.asarray(rng.standard_normal(shape, f32),
+                                      cfg.jnp_dtype).astype(jnp.float32))
+
+    def unit(a):
+        return a / np.sqrt((a * a).sum(-1, keepdims=True) + 1e-6)
+
+    A = rng.uniform(1.0, 16.0, (H, 1)).astype(f32)
+    g = -A * np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                lead + (H, hd))).astype(f32)
+    beta = 1 / (1 + np.exp(-rng.standard_normal(lead + (H,), f32)))
+    q, k, v = (activations(*lead, H, hd) for _ in range(3))
+    return g, beta.astype(f32), (unit(q) * hd ** -0.5).astype(f32), \
+        unit(k).astype(f32), v
+
+
+def check_kda(cfg, B, T) -> None:
+    """A delta-rule model's two operations at its geometry. The one-token
+    update: the Pallas kernel against the XLA reference (values, the
+    untouched slots bitwise), then each timed alone over the SERVED pool
+    (every KDA layer, B + 1 slots, float32), a call a layer chained in one
+    program, against the bytes of the rows' slots. The chunked form (XLA
+    einsums, no kernel): timed alone over one prompt of the cell's mean
+    length in the top prefill bucket, and held to the token-by-token
+    recurrence."""
+    from kubernetes_gpu_cluster_tpu.ops import kda as kda_ops
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kda_update import kda_update
+    Ls, H, hd = cfg.num_state_layers, cfg.kda_n_heads, cfg.kda_head_dim
+    shape, f32 = cfg.state_shape, jnp.float32
+    rng = np.random.default_rng(11)
+    slots = jnp.asarray(np.concatenate(
+        [rng.permutation(B)[:B - 3] + 1, np.zeros(3)]), jnp.int32)
+    ins = tuple(jnp.asarray(a) for a in _kda_inputs(rng, cfg, B))
+
+    small = jnp.asarray(rng.standard_normal((3, B + 1) + shape, np.float32))
+    want_pool, want_o = jax.jit(kda_ops.kda_update_xla)(
+        small, jnp.int32(1), slots, *ins)
+    got_pool, got_o = jax.jit(kda_update)(small, jnp.int32(1), slots, *ins)
+    real = np.asarray(slots[:B - 3])
+    e_o = _err(got_o[:B - 3], want_o[:B - 3])
+    e_s = _err(got_pool[1, real], want_pool[1, real])
+    untouched = np.ones(small.shape[:2], bool)
+    untouched[1, np.asarray(slots)] = False
+    same = bool(np.array_equal(np.asarray(got_pool)[untouched],
+                               np.asarray(small)[untouched]))
+    print(f"kda_update B={B} {H} x [{hd}, {hd}] f32: max|pallas-xla| "
+          f"o={e_o:.2e} state={e_s:.2e}; other slots and layers bitwise: "
+          f"{same}")
+    assert e_o < 1e-4 and e_s < 1e-4 and same
+    del small, want_pool, got_pool
+
+    def chained(update):
+        def run(pool):
+            def layer(l, carry):
+                pool, acc = carry
+                pool, o = update(pool, l, slots, *ins)
+                return pool, acc + o
+            return jax.lax.fori_loop(0, Ls, layer,
+                                     (pool, jnp.zeros((B, H * hd), f32)))
+        return jax.jit(run, donate_argnums=0)
+
+    least = (B - 3) * 2 * math.prod(shape) * 4
+    variants = [("pallas, as served", kda_update)] + [
+        (f"pallas, head_block={hb}",
+         functools.partial(kda_update, head_block=hb))
+        for hb in (4, 8, 16) if hb <= H]
+    for name, update in variants + [("xla", kda_ops.kda_update_xla)]:
+        run = chained(update)
+        pool = jnp.zeros((Ls, B + 1) + shape, f32)
+        pool, _ = jax.block_until_ready(run(pool))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            pool, acc = run(pool)
+        jax.block_until_ready(acc)
+        us = (time.perf_counter() - t0) / (5 * Ls) * 1e6
+        print(f"kda_update[{name}] alone, pool {list(pool.shape)} "
+              f"({pool.nbytes / 1e9:.2f} GB), {B - 3} real rows: {us:.1f} us "
+              f"a call; {least / 1e6:.0f} MB of slots there and back = "
+              f"{least / 819e9 * 1e6:.1f} us at 819 GB/s "
+              f"({least / 819e9 * 1e6 / us:.1%})")
+        del pool, acc
+
+    n, Q = 1472, cfg.kda_chunk_size     # the cell's mean prompt, alone
+    g, beta, q, k, v = (jnp.asarray(a) for a in _kda_inputs(rng, cfg, T))
+    seg = jnp.where(jnp.arange(T) < n, 0, -1).astype(jnp.int32)
+    ends = jnp.asarray([n - 1], jnp.int32)
+    init = jnp.zeros(shape, f32)
+    scan = jax.jit(lambda *a: kda_ops.kda_chunk_scan_xla(*a, -2, Q))
+    sec = _timed(scan, q, k, v, g, beta, seg, ends, init)
+    o, final = scan(q, k, v, g, beta, seg, ends, init)
+    o_ref, f_ref = jax.jit(kda_ops.kda_recurrence)(
+        q[:n], k[:n], v[:n], g[:n], beta[:n], init)
+    print(f"kda_chunk_scan_xla alone, T={T} ({n} real), {H} x {hd}, chunk "
+          f"{Q}: {sec * 1e3:.2f} ms a layer; max|chunked - token by token| "
+          f"o={_err(o[:n], o_ref):.2e} (max|o| "
+          f"{float(jnp.max(jnp.abs(o_ref))):.2f}; every product at full "
+          f"float32) "
+          f"state={_err(final[0], f_ref):.2e} (max|S| "
+          f"{float(jnp.max(jnp.abs(f_ref))):.2f})")
+
+
+# The chained delta-rule state's error against the float64 recurrence, as a
+# share of the largest value: the limit is the geometric mean of the largest
+# reading of the update as served (3.00e-5: o; the kernel is bitwise its XLA
+# twin, the distance is float32's over 512 tokens of 128-term sums) and the
+# smallest of a planted fault (7.71e-3: o with the state rounded to bfloat16
+# a token), both on a v5e at kimi-linear's widths (PERF.md section 2).
+KDA_CHAIN_LIMIT = 5e-4
+
+
+def check_kda_chain(cfg, kernels, steps: int = 512, rows: int = 4) -> dict:
+    """``check_ssm_chain`` for the delta rule: ``steps`` tokens of ``rows``
+    sequences through ``kernels.kda_update`` over a slot pool the engine's
+    own allocation made, against the same recurrence in float64 on the
+    host: the final state of every row's slot and o at every token. The
+    planted faults and the limit's test: ``_state_chain``."""
+    H, hd = cfg.kda_n_heads, cfg.kda_head_dim
+    rng = np.random.default_rng(17)
+    g, beta, q, k, v = _kda_inputs(rng, cfg, steps, rows)
+    real = rng.permutation(rows) + 1
+    pad = 2                         # padding rows name the scrap slot 0
+    slots = jnp.asarray(np.concatenate([real, np.zeros(pad)]), jnp.int32)
+
+    def padded(a):
+        return jnp.asarray(np.pad(
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)))
+
+    S = np.zeros((rows, H, hd, hd), np.float64)
+    o_ref = np.empty((steps, rows, H, hd), np.float64)
+    for t in range(steps):
+        kt, qt = k[t].astype(np.float64), q[t].astype(np.float64)
+        D = S * np.exp(g[t].astype(np.float64))[..., None]
+        u = beta[t].astype(np.float64)[..., None] * (
+            v[t] - np.einsum("rhkv,rhk->rhv", D, kt))
+        S = D + kt[..., None] * u[:, :, None, :]
+        o_ref[t] = np.einsum("rhkv,rhk->rhv", S, qt)
+    S = S.reshape(rows, H * hd, hd)
+    o_ref = o_ref.reshape(steps, rows, H * hd)
+
+    return _state_chain(
+        cfg, "kda_update", kernels.kda_update,
+        tuple(padded(a) for a in (g, beta, q, k, v)), S, o_ref, real, slots,
+        KDA_CHAIN_LIMIT)
 
 
 def check_int4_matmul() -> None:
@@ -943,10 +1115,15 @@ def main() -> None:
         "ssm": lambda: check_ssm(cfg, B, T),
         "ssm-chain": lambda: check_ssm_chain(cfg,
                                              Kernels(use_pallas=True)),
+        "kda": lambda: check_kda(cfg, B, T),
+        "kda-chain": lambda: check_kda_chain(cfg, Kernels(use_pallas=True)),
     }
     if cfg.is_mla and args.kernels == ap.get_default("kernels"):
         args.kernels = "latent,experts"
-    if cfg.has_state and args.kernels == ap.get_default("kernels"):
+    if cfg.state_kind == "kda" and args.kernels in (
+            ap.get_default("kernels"), "latent,experts"):
+        args.kernels = "latent,kda,kda-chain"
+    elif cfg.has_state and args.kernels == ap.get_default("kernels"):
         args.kernels += ",ssm,ssm-chain"
     for name in args.kernels.split(","):
         checks[name]()

@@ -66,13 +66,23 @@ MODELS = {
     # ``--model granite-4.0-h-micro``: the whole model, 36 state layers'
     # slots beside the pages of 4 attention layers.
     "granite-4.0-h-micro": dict(vocab=100352, long_prompt=3000, gen=256),
+    # ``--model kimi-linear-48b-a3b``: the benchmark's cut, one chip's share
+    # of stage 1: 9 of 27 layers (7 KDA state layers' slots beside 2 NoPE
+    # latent layers' pages), experts 0-63 of 256, ids 0-40,959.
+    "kimi-linear-48b-a3b": dict(
+        vocab=40960, long_prompt=3000, gen=256,
+        flags=["--hf-overrides", '{"num_hidden_layers": 9, '
+               '"experts_held": 64, "vocab_size": 40960}']),
     # Rehearsal only: max_model_len 512 cannot hold a chunking prompt.
     "debug-tiny": dict(vocab=512, long_prompt=400, gen=96),
     "debug-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
     "debug-ssm-hybrid": dict(vocab=512, long_prompt=400, gen=96),
+    "debug-kda-hybrid": dict(vocab=512, long_prompt=400, gen=96,
+                             flags=["--hf-overrides", '{"experts_held": 4}']),
 }
 REHEARSAL_OF = {"qwen3-4b": "debug-tiny", "kimi-vl-a3b": "debug-mla-moe",
-                "granite-4.0-h-micro": "debug-ssm-hybrid"}
+                "granite-4.0-h-micro": "debug-ssm-hybrid",
+                "kimi-linear-48b-a3b": "debug-kda-hybrid"}
 HEALTH_TIMEOUT_S = 600
 REQUEST_TIMEOUT_S = 600
 DRAIN_TIMEOUT_S = 150

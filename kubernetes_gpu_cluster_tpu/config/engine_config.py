@@ -246,29 +246,48 @@ def cache_kind_refusal(config: EngineConfig, mesh_shape=None, *,
         return None
     axes = dict(mesh_shape or {})
     par = config.parallel
-    if m.is_mla:
-        why_axis = {
-            "tp": "the latent row is one shared head: there is no kv-head "
-                  "axis to shard the pool over, and the kernels run "
-                  "unsharded",
-            "pp": "the pipeline stages one homogeneous layer stack, not "
-                  "leading dense layers beside expert layers",
-            "sp": "ring attention is written for K and V per head, not for "
-                  "the latent row",
-            "ep": "the grouped expert matmuls run over every expert on one "
-                  "device; no all-to-all dispatch exists"}
-        moves, kind = "K and V page pairs, not latent pages", "latent pages"
-    else:
-        why_axis = {
-            "tp": "the state slots and the in-place state update have no "
-                  "sharded form (heads of a state layer are not split)",
-            "pp": "the pipeline stages one homogeneous layer stack, not a "
-                  "period of typed layers with state slots",
-            "sp": "ring attention splits the prompt over devices; the state "
-                  "scan runs a segment's tokens in order on one",
-            "ep": "the model has no experts to spread"}
-        moves, kind = ("pages of K and V, not a sequence's recurrent state",
-                       "state slots")
+    # What each kind of memory refuses, by flag; a model of both kinds
+    # (latent pages AND state slots: kimi_linear) is refused a flag by the
+    # first kind that refuses it.
+    latent = {
+        "tp": "the latent row is one shared head: there is no kv-head "
+              "axis to shard the pool over, and the kernels run "
+              "unsharded",
+        "pp": "the pipeline stages one homogeneous layer stack, not "
+              "leading dense layers beside expert layers",
+        "sp": "ring attention is written for K and V per head, not for "
+              "the latent row",
+        "ep": "the grouped expert matmuls run over every expert on one "
+              "device; no all-to-all dispatch exists",
+        "spec": "the verify step's attention reads K and V pools; no "
+                "latent-page variant",
+        "quant": "the absorbed projections and the grouped expert matmuls "
+                 "have no int8/int4 path",
+        "moves": "K and V page pairs, not latent pages"}
+    state = {
+        "tp": "the state slots and the in-place state update have no "
+              "sharded form (heads of a state layer are not split)",
+        "pp": "the pipeline stages one homogeneous layer stack, not a "
+              "period of typed layers with state slots",
+        "sp": "ring attention splits the prompt over devices; the state "
+              "scan runs a segment's tokens in order on one",
+        "ep": "the model has no experts to spread",
+        "prefix": "a cached prefix is pages of K and V; the recurrent "
+                  "state at the prefix's end is not kept, so the tail "
+                  "cannot continue from it",
+        "spec": "a rejected draft has already advanced the recurrent "
+                "state; no snapshot exists to roll it back to",
+        "quant": "the state layers' projections and conv have no int8/int4 "
+                 "layout",
+        "moves": "pages of K and V, not a sequence's recurrent state"}
+    kinds = [k for k, has in ((latent, m.is_mla), (state, m.has_state))
+             if has]
+
+    def why(key: str) -> Optional[str]:
+        return next((k[key] for k in kinds if key in k), None)
+
+    why_axis = {axis: why(axis) for axis in ("tp", "pp", "sp", "ep")}
+    moves = why("moves")
     for flag, axis, n in (
             ("--tensor-parallel-size", "tp", par.tp),
             ("--pipeline-parallel-size", "pp", par.pp),
@@ -277,25 +296,15 @@ def cache_kind_refusal(config: EngineConfig, mesh_shape=None, *,
         n = max(n, axes.get(axis, 1))
         if n > 1:
             return f"{flag} {n} with {m.name}: {why_axis[axis]}"
-    if m.has_state and config.scheduler.enable_prefix_caching:
-        return (f"--enable-prefix-caching with {m.name}: a cached prefix is "
-                "pages of K and V; the recurrent state at the prefix's end "
-                "is not kept, so the tail cannot continue from it")
+    if why("prefix") and config.scheduler.enable_prefix_caching:
+        return f"--enable-prefix-caching with {m.name}: {why('prefix')}"
     if config.scheduler.spec_decode_enabled:
-        return (f"--enable-spec-decode with {m.name}: " + (
-            "the verify step's attention reads K and V pools; no "
-            "latent-page variant" if m.is_mla else
-            "a rejected draft has already advanced the recurrent state; "
-            "no snapshot exists to roll it back to"))
+        return f"--enable-spec-decode with {m.name}: {why('spec')}"
     if config.cache.kv_swap_enabled:
         return (f"--swap-space-gb with {m.name}: the host tier and its "
                 f"gather/scatter move {moves}")
     if m.quantization is not None:
-        return (f"--quantization {m.quantization} with {m.name}: " + (
-            "the absorbed projections and the grouped expert matmuls have "
-            "no int8/int4 path" if m.is_mla else
-            "the state layers' projections and conv have no int8/int4 "
-            "layout"))
+        return f"--quantization {m.quantization} with {m.name}: {why('quant')}"
     if role != "both":
         return (f"--role {role} with {m.name}: the prefill-to-decode "
                 f"handoff frames {moves}")

@@ -19,6 +19,9 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+STATE_KINDS = ("mamba", "kda")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -86,12 +89,16 @@ class ModelConfig:
     # (hidden/ff/nh*hd) and align with row-shard boundaries under tp.
     quant_group_size: int = 128
     max_model_len: int = 4096
-    # Typed layers (granitemoehybrid class): one entry a layer, "attention"
-    # or "mamba" (a Mamba-2 state mixer); None: every layer is attention.
-    # The pattern is whole repetitions of ``layer_period``, which is what
-    # the layer scan scans. An attention layer holds pages of K|V, a state
-    # layer one fixed slot of recurrent state a sequence
-    # (engine/kv_cache.py); every layer has the dense MLP.
+    # Typed layers: one entry a layer, the layer's MIXER: "attention",
+    # "mamba" (a Mamba-2 state mixer: granitemoehybrid) or "kda" (a gated
+    # delta-rule state mixer: kimi_linear); None: every layer is attention.
+    # The layer's MLP is independent of it: dense in the leading
+    # ``num_dense_layers`` and in a model without experts, else experts.
+    # The pattern is the leading dense layers, whole repetitions of
+    # ``layer_period`` (what the layer scan scans) and a tail shorter than
+    # a period (``layer_sections``). An attention layer holds pages (K|V,
+    # or latent rows), a state layer one fixed slot of recurrent state a
+    # sequence (engine/kv_cache.py).
     layer_types: Optional[tuple] = None
     # The state mixer's sizes (``mamba_*`` of the HF config): heads x head
     # width = d_inner; one B and one C of ``mamba_d_state`` a group; a
@@ -103,6 +110,22 @@ class ModelConfig:
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    # The delta-rule mixer's sizes (``linear_attn_config`` of kimi_linear):
+    # heads x head width for q, k and v alike; the two low-rank pairs (the
+    # decay gate's and the output gate's) pass through ``kda_head_dim``; a
+    # causal depthwise conv of ``kda_d_conv`` taps over each of q, k, v;
+    # the segment form's chunk.
+    kda_n_heads: int = 0
+    kda_head_dim: int = 0
+    kda_d_conv: int = 4
+    kda_chunk_size: int = 64
+    # The share of the routed experts this process holds: ``experts_held``
+    # experts from id ``experts_first`` on (0 held: every one). The router
+    # scores all ``num_experts``; the routed sum runs over the chosen
+    # experts that are held, the others' weights are never allocated and
+    # nothing stands in for what they would add (models.llama._moe_mlp).
+    experts_first: int = 0
+    experts_held: int = 0
     # Granite's four scalars: h0 = embed * embedding_multiplier; every
     # residual add takes residual_multiplier * branch; the softmax scale is
     # attention_multiplier (None: head_dim ** -0.5); logits / logits_scaling.
@@ -112,40 +135,93 @@ class ModelConfig:
     logits_scaling: float = 1.0
 
     def __post_init__(self):
+        if not 0 <= self.experts_first <= (
+                self.num_experts - self.num_local_experts):
+            raise ValueError(
+                f"{self.name}: experts {self.experts_first} to "
+                f"{self.experts_first + self.num_local_experts - 1} held, "
+                f"of {self.num_experts}")
         if self.layer_types is None:
             return
         if len(self.layer_types) != self.num_layers:
             raise ValueError(
                 f"{self.name}: layer_types names {len(self.layer_types)} "
                 f"layers, num_layers is {self.num_layers}")
-        bad = set(self.layer_types) - {"attention", "mamba"}
+        bad = set(self.layer_types) - {"attention", *STATE_KINDS}
         if bad:
             raise ValueError(f"{self.name}: layer_types {sorted(bad)} are "
-                             "neither 'attention' nor 'mamba'")
-        if self.has_state and self.mamba_n_groups != 1:
+                             "none of 'attention', 'mamba', 'kda'")
+        if len(set(self.layer_types) & set(STATE_KINDS)) > 1:
+            raise ValueError(
+                f"{self.name}: state layers of two kinds in one model: the "
+                "slot pool has one layout")
+        if self.state_kind == "mamba" and self.mamba_n_groups != 1:
             raise ValueError(
                 f"{self.name}: mamba_n_groups {self.mamba_n_groups}: the "
                 "state mixer shares one B and one C among all heads")
 
     @property
+    def state_kind(self) -> Optional[str]:
+        """The kind of the model's state layers ("mamba", "kda"), or None."""
+        for kind in self.layer_types or ():
+            if kind in STATE_KINDS:
+                return kind
+        return None
+
+    @property
     def has_state(self) -> bool:
         """Whether some layer is a state layer (recurrent state slots beside
         the pages)."""
-        return self.layer_types is not None and "mamba" in self.layer_types
+        return self.state_kind is not None
+
+    @property
+    def layer_sections(self) -> tuple:
+        """The stack as the layer scan runs it: ``(types, repeats, dense)``
+        a section, in order. The leading dense layers first (their own
+        shortest period), then the shortest run of layer types whose whole
+        repetitions cover the layers that follow, then what is left, shorter
+        than that period, once. A homogeneous model: one section of
+        ("attention",); granite-4.0-h-micro: 4 x [5 state, 1 attention, 4
+        state]; kimi-linear: the dense KDA layer, 6 x [KDA, KDA, MLA, KDA],
+        [KDA, MLA]."""
+        types = tuple(self.layer_types
+                      or ("attention",) * max(self.num_layers, 1))
+
+        def period(run):
+            for n in range(1, len(run) + 1):
+                k = len(run) // n
+                if run[:n] * k == run[:n * k]:
+                    return n, k
+            return len(run), 1
+
+        nd = self.num_dense_layers
+        sections = []
+        if nd:
+            n, k = period(types[:nd])
+            sections.append((types[:n], k, True))
+            if types[n * k:nd]:
+                sections.append((types[n * k:nd], 1, True))
+        body = types[nd:]
+        n, k = period(body)
+        dense = not self.is_moe
+        sections.append((body[:n], k, dense))
+        if body[n * k:]:
+            sections.append((body[n * k:], 1, dense))
+        return tuple(sections)
 
     @property
     def layer_period(self) -> tuple:
-        """The shortest run of layer types whose repetition is the whole
-        stack: ("attention",) for a homogeneous model."""
-        types = self.layer_types or ("attention",) * max(self.num_layers, 1)
-        for n in range(1, len(types) + 1):
-            if len(types) % n == 0 and types[:n] * (len(types) // n) == types:
-                return tuple(types[:n])
-        return tuple(types)
+        """The period of the layers behind the leading dense ones."""
+        at = 0
+        for types, repeats, _ in self.layer_sections:
+            if at >= self.num_dense_layers:
+                return types
+            at += len(types) * repeats
+        return ()
 
     @property
     def num_kv_layers(self) -> int:
-        """Layers that hold pages: the depth of the K|V pools."""
+        """Layers that hold pages: the depth of the page pools."""
         if self.layer_types is None:
             return self.num_layers
         return self.layer_types.count("attention")
@@ -162,6 +238,29 @@ class ModelConfig:
     def mamba_conv_dim(self) -> int:
         """Channels of the depthwise conv: [x | B | C]."""
         return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def state_shape(self) -> tuple:
+        """What a slot holds of the recurrent state in one state layer,
+        float32, lane-dense: Mamba-2's [N, d_inner] (``ops/ssm.py``), the
+        delta rule's [heads * d_k, d_v] (``ops/kda.py``)."""
+        if self.state_kind == "kda":
+            return (self.kda_n_heads * self.kda_head_dim, self.kda_head_dim)
+        return (self.mamba_d_state, self.mamba_d_inner)
+
+    @property
+    def state_conv_shape(self) -> tuple:
+        """... and of the conv's last inputs, in the model's dtype:
+        [taps - 1, channels] ([x | B | C]; [q | k | v])."""
+        if self.state_kind == "kda":
+            return (self.kda_d_conv - 1,
+                    3 * self.kda_n_heads * self.kda_head_dim)
+        return (self.mamba_d_conv - 1, self.mamba_conv_dim)
+
+    @property
+    def num_local_experts(self) -> int:
+        """Routed experts whose weights this process holds."""
+        return self.experts_held or self.num_experts
 
     @property
     def attn_scale(self) -> float:
@@ -267,6 +366,23 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         residual_multiplier=0.5, attention_multiplier=0.25,
         logits_scaling=2.0, max_model_len=512, dtype="float32",
     ),
+    # kimi-linear's block at a size the CPU tests can afford, in the shape
+    # of its pattern: one leading dense KDA layer, two periods of [KDA, KDA,
+    # MLA, KDA], a ragged [KDA, MLA] tail; NoPE latent attention; 16 experts
+    # top-4 + 1 shared; a chunk of two sub-chunks.
+    "debug-kda-hybrid": _p(
+        "debug-kda-hybrid", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=11, num_heads=4, num_kv_heads=4,
+        head_dim=48, kv_lora_rank=64, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, pos_embedding="none",
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=64,
+        num_shared_experts=1, first_k_dense_replace=1,
+        scoring_func="sigmoid", routed_scaling_factor=2.446,
+        layer_types=(("kda",) + ("kda", "kda", "attention", "kda") * 2
+                     + ("kda", "attention")),
+        kda_n_heads=4, kda_head_dim=32, kda_chunk_size=32,
+        max_model_len=512, dtype="float32",
+    ),
     # The reference's minimal-example model (values-01-minimal-example.yaml:8).
     "opt-125m": _p(
         "opt-125m", vocab_size=50272, hidden_size=768, intermediate_size=3072,
@@ -351,6 +467,29 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         attention_multiplier=0.015625, logits_scaling=8.0,
         max_model_len=4096,
     ),
+    # moonshotai/Kimi-Linear-48B-A3B-Instruct (kimi_linear, arXiv:2510.26692):
+    # 20 KDA (gated delta-rule) layers of 32 heads x 128 and 7 latent
+    # attention layers (kimi-vl's MLA widths over 32 heads) with NO
+    # positional encoding, 1-based layers 4, 8, ..., 24 and 27; layer 1 has a
+    # dense SwiGLU of 9216, layers 2-27 256 routed experts of 1024 top-8
+    # (sigmoid, renormalised, x 2.446) and 1 shared; untied head. Its 98 GB
+    # of bf16 weights are served as a share: ``--hf-overrides`` names the
+    # depth, the experts held and the vocabulary slice.
+    "kimi-linear-48b-a3b": _p(
+        "kimi-linear-48b-a3b", vocab_size=163840, hidden_size=2304,
+        intermediate_size=9216, num_layers=27, num_heads=32,
+        num_kv_heads=32, head_dim=192, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        pos_embedding="none", num_experts=256, num_experts_per_tok=8,
+        moe_intermediate_size=1024, num_shared_experts=1,
+        first_k_dense_replace=1, scoring_func="sigmoid",
+        norm_topk_prob=True, routed_scaling_factor=2.446,
+        rms_norm_eps=1e-5,
+        layer_types=(("kda",) + ("kda", "kda", "attention", "kda") * 6
+                     + ("kda", "attention")),
+        kda_n_heads=32, kda_head_dim=128, kda_d_conv=4, kda_chunk_size=64,
+        max_model_len=4096,
+    ),
 }
 
 
@@ -374,6 +513,9 @@ HF_SHAPE_KEYS: dict[str, str] = {
     "qk_nope_head_dim": "qk_nope_head_dim",
     "qk_rope_head_dim": "qk_rope_head_dim",
     "v_head_dim": "v_head_dim",
+    # Not HF's: the share of the routed experts this process holds.
+    "experts_first": "experts_first",
+    "experts_held": "experts_held",
 }
 
 
@@ -392,14 +534,17 @@ def apply_hf_overrides(cfg: ModelConfig, overrides: dict) -> ModelConfig:
                 f"--hf-overrides: {key} must be a whole number, not {val!r}")
         fields[HF_SHAPE_KEYS[key]] = val
     depth = fields.get("num_layers")
-    if cfg.layer_types is not None and depth is not None:
-        period = cfg.layer_period
-        if depth % len(period):
+    if (cfg.layer_types is not None and depth is not None
+            and depth != cfg.num_layers):
+        period, nd = cfg.layer_period, cfg.num_dense_layers
+        if depth < nd or (depth - nd) % len(period):
             raise ValueError(
-                f"--hf-overrides: num_hidden_layers {depth} is not whole "
-                f"periods of {cfg.name}'s layer pattern ({len(period)} "
-                f"layers: {', '.join(period)})")
-        fields["layer_types"] = period * (depth // len(period))
+                f"--hf-overrides: num_hidden_layers {depth} is not "
+                + (f"{nd} leading dense layers and " if nd else "")
+                + f"whole periods of {cfg.name}'s layer pattern "
+                f"({len(period)} layers: {', '.join(period)})")
+        fields["layer_types"] = (cfg.layer_types[:nd]
+                                 + period * ((depth - nd) // len(period)))
     cfg = cfg.replace(**fields)
     if cfg.is_mla:
         cfg = cfg.replace(head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)

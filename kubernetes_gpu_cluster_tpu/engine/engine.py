@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import math
 import time
 from typing import Optional
 
@@ -261,6 +262,9 @@ class LLMEngine:
         # all accumulate here (serving.metrics renders it; /debug/trace
         # exports it; bench.py reads the TTFT decomposition).
         self.obs = Observability()
+        if config.model.experts_held:   # the gauges are over this share
+            self.obs.moe_held = (config.model.experts_first,
+                                 config.model.experts_held)
         self.scheduler = Scheduler(config, num_pages, obs=self.obs,
                                    num_state_slots=num_state_slots)
         if self.scheduler.qos is not None:
@@ -472,9 +476,9 @@ class LLMEngine:
             # a latent row included) and the share of them that is padding.
             "weight_bytes": sum(x.size * x.dtype.itemsize
                                 for x in jax.tree.leaves(self.params)),
-            "kv_layout": ("latent" if self.model_config.is_mla else
-                          "k|v+state" if self.model_config.has_state
-                          else "k|v"),
+            "kv_layout": (("latent" if self.model_config.is_mla else "k|v")
+                          + ("+state" if self.model_config.has_state
+                             else "")),
             "kv_bytes_per_token": kv_cache_bytes_per_token(
                 self.model_config, self.config.cache),
             "kv_row_padding_share": round(
@@ -491,6 +495,12 @@ class LLMEngine:
                 state_bytes_per_seq=state_bytes_per_seq(self.model_config),
                 state_bytes=alloc.num_state_slots
                 * state_bytes_per_seq(self.model_config))
+        if self.model_config.experts_held:
+            # A share of the routed experts, by configuration: which ones,
+            # of how many the router scores.
+            info.update(experts_held=self.model_config.experts_held,
+                        experts_first=self.model_config.experts_first,
+                        experts_published=self.model_config.num_experts)
         if self.pallas_disabled_reason is not None:
             info["pallas_disabled_reason"] = self.pallas_disabled_reason
         return info
@@ -663,14 +673,36 @@ class LLMEngine:
             # groups and in the rows models.llama.experts_grouped lays out.
             from ..ops.pallas.grouped_matmul import (grouped_matmul,
                                                      padded_rows)
-            groups = (cfg.num_layers - cfg.num_dense_layers) * cfg.num_experts
-            rows = padded_rows((T + B) * cfg.num_experts_per_tok,
-                               cfg.num_experts)
+            held = cfg.num_local_experts
+            rows = padded_rows((T + B) * cfg.num_experts_per_tok, held)
             d, ff = cfg.hidden_size, cfg.expert_width
-            for name, k, n in (("up", d, ff), ("down", ff, d)):
-                probe(f"grouped_matmul[{name}]", grouped_matmul,
-                      arr((rows, k)), arr((groups, k, n)),
-                      arr((groups,), i32))
+            # One stack's groups a call: a typed model's expert layers lie
+            # in two (its attention layers', its state layers').
+            for stack, n_layers in model_lib.layer_stacks(cfg).items():
+                if stack.startswith("dense_"):
+                    continue
+                groups = n_layers * held
+                for name, k, n in (("up", d, ff), ("down", ff, d)):
+                    probe(f"grouped_matmul[{stack}.{name}]", grouped_matmul,
+                          arr((rows, k)), arr((groups, k, n)),
+                          arr((groups,), i32))
+        if cfg.has_state:
+            # The state update of a full decode bucket, in place in a pool
+            # of the served slot shape (its depth and slot count shape no
+            # block).
+            f32, (N, di) = jnp.float32, cfg.state_shape
+            state_pool = arr((cfg.num_state_layers, 2) + cfg.state_shape, f32)
+            if cfg.state_kind == "kda":
+                from ..ops.pallas.kda_update import kda_update
+                H, hd = cfg.kda_n_heads, cfg.kda_head_dim
+                probe("kda_update", kda_update, state_pool, arr((), i32),
+                      arr((B,), i32), arr((B, H, hd), f32), arr((B, H), f32),
+                      *(arr((B, H, hd), f32),) * 3)
+            else:
+                from ..ops.pallas.ssm_update import ssm_update
+                probe("ssm_update", ssm_update, state_pool, arr((), i32),
+                      arr((B,), i32), arr((B, di), f32), arr((B, di), f32),
+                      arr((B, N), f32), arr((B, N), f32))
         if cfg.is_mla:
             self._probe_latent_kernels(probe, arr, pool, B, T, pps)
             logger.info("Pallas kernels compiled at the served geometry: %s",
@@ -704,16 +736,6 @@ class LLMEngine:
             rows = arr((layers, n, nkv * hd))
             probe(f"kv_write[T={n}]", kv_write, deep_pool, deep_pool,
                   rows, rows, arr((n,), i32))
-        if cfg.has_state:
-            # The state update of a full decode bucket, in place in a pool
-            # of the served slot shape (its depth and slot count shape no
-            # block).
-            from ..ops.pallas.ssm_update import ssm_update
-            f32, N, di = jnp.float32, cfg.mamba_d_state, cfg.mamba_d_inner
-            probe("ssm_update", ssm_update,
-                  arr((cfg.num_state_layers, 2, N, di), f32), arr((), i32),
-                  arr((B,), i32), arr((B, di), f32), arr((B, di), f32),
-                  arr((B, N), f32), arr((B, N), f32))
         logger.info("Pallas kernels compiled at the served geometry: %s",
                     ", ".join(compiled))
 
@@ -748,11 +770,11 @@ class LLMEngine:
               arr((T, nh, R)), arr((T, 1, R)), arr((T,), i32),
               arr((T,), i32), pool, arr((pps,), i32), arr((), i32),
               arr((), i32))
-        deep_pool = arr((cfg.num_layers, 2, pool.shape[2], R), pool.dtype)
+        deep_pool = arr((cfg.num_kv_layers, 2, pool.shape[2], R), pool.dtype)
         for n in (B, T):
             probe(f"kv_write[T={n}]",
                   lambda p, rows, slots: kv_write(p, None, rows, None, slots),
-                  deep_pool, arr((cfg.num_layers, n, R)), arr((n,), i32))
+                  deep_pool, arr((cfg.num_kv_layers, n, R)), arr((n,), i32))
 
     @property
     def _grouped_experts(self) -> bool:
@@ -2740,7 +2762,7 @@ def step_workspace_bytes(config: EngineConfig) -> int:
     resid = 4 * T * m.hidden_size * 4
     sampling = 8 * B * m.vocab_size * 4
     state = 0
-    if m.has_state:
+    if m.state_kind == "mamba":
         # One state layer's chunked scan at a time: the [chunks, heads, Q, Q]
         # decay-masked products (mask, decay, product), the projection and
         # the conv over T, and, for every segment a packed prefill may hold
@@ -2749,9 +2771,23 @@ def step_workspace_bytes(config: EngineConfig) -> int:
         Q, di = m.mamba_chunk_size, m.mamba_d_inner
         state = (3 * T * m.mamba_n_heads * Q * 4
                  + 2 * T * (di + m.mamba_conv_dim) * (4 + it)
-                 + B * (3 * m.mamba_d_state + 2 * Q) * di * 4
-                 + m.num_state_layers * 2 * B * (m.mamba_d_conv - 1)
-                 * m.mamba_conv_dim * it)
+                 + B * (3 * m.mamba_d_state + 2 * Q) * di * 4)
+    elif m.state_kind == "kda":
+        # One KDA layer's chunked form at a time (ops/kda.py): q, k, v, g,
+        # their running sums and decayed copies per token in float32 (a
+        # dozen [T, H, d] arrays), the pairwise decay of the sub-chunks'
+        # diagonal blocks ([T, 16, H, d]), the [T, H, Q] Gram matrices and
+        # the solve, every chunk's incoming state; the conv over T; for
+        # every segment a packed prefill may hold, its final state with the
+        # chunk it was gathered from.
+        Q, HD = m.kda_chunk_size, m.kda_n_heads * m.kda_head_dim
+        state = (T * HD * 4 * (12 + 16) + 4 * T * m.kda_n_heads * Q * 4
+                 + (T // Q + 1) * HD * m.kda_head_dim * 4
+                 + 2 * T * 3 * HD * (4 + it)
+                 + B * (3 * m.kda_head_dim + 4 * Q) * HD * 4)
+    if m.has_state:     # all layers' conv rows
+        state += m.num_state_layers * 2 * B * math.prod(
+            m.state_conv_shape) * it
     return kv_rows + mlp + attn + resid + sampling + state
 
 

@@ -37,6 +37,7 @@ step's dispatch consumes the donated pool (the KGCT004/KGCT010 contracts).
 from __future__ import annotations
 
 import hashlib
+import math
 import time
 from collections import OrderedDict
 from typing import Callable, NamedTuple, Optional
@@ -69,10 +70,12 @@ class KVCache(NamedTuple):
     A state model's state layers keep a fixed SLOT a sequence beside the
     pages, in two more pools that ride with the page pools wherever those
     go (donated into every step program, returned by it): ``ssm`` [Ls,
-    slots, N, d_inner] float32, the recurrent state (``ops/ssm.py`` has the
-    layout), and ``conv`` [Ls, slots, d_conv - 1, channels], the conv's
-    last inputs, in the model's dtype. Slot 0 is scrap, as page 0 is. Both
-    None for every other model."""
+    slots, *model.state_shape] float32, the recurrent state (``ops/ssm.py``
+    and ``ops/kda.py`` have the layouts), and ``conv`` [Ls, slots,
+    *model.state_conv_shape], the conv's last inputs, in the model's dtype.
+    Slot 0 is scrap, as page 0 is. Both None for every other model. The
+    two kinds are independent: a model of latent pages AND state layers
+    holds ``k``, ``ssm`` and ``conv``."""
     k: jax.Array
     v: Optional[jax.Array]
     ssm: Optional[jax.Array] = None
@@ -107,20 +110,15 @@ def allocate_kv_cache(
              model.kv_row_padded)
     def mk():
         return jnp.zeros(shape, dtype=dtype)
+    if sharding is not None and model.kv_pools == 2:
+        mk = jax.jit(mk, out_shardings=sharding)
+    slots = {}
     if model.has_state:
-        Ls = model.num_state_layers
-        return KVCache(
-            k=mk(), v=mk(),
-            ssm=jnp.zeros((Ls, num_state_slots, model.mamba_d_state,
-                           model.mamba_d_inner), STATE_DTYPE),
-            conv=jnp.zeros((Ls, num_state_slots, model.mamba_d_conv - 1,
-                            model.mamba_conv_dim), model.jnp_dtype))
-    if model.kv_pools == 1:
-        return KVCache(k=mk(), v=None)
-    if sharding is not None:
-        mk_sharded = jax.jit(mk, out_shardings=sharding)
-        return KVCache(k=mk_sharded(), v=mk_sharded())
-    return KVCache(k=mk(), v=mk())
+        lead = (model.num_state_layers, num_state_slots)
+        slots = dict(
+            ssm=jnp.zeros(lead + model.state_shape, STATE_DTYPE),
+            conv=jnp.zeros(lead + model.state_conv_shape, model.jnp_dtype))
+    return KVCache(k=mk(), v=mk() if model.kv_pools == 2 else None, **slots)
 
 
 # The recurrent state is held and updated in float32: rounded to bfloat16 at
@@ -142,10 +140,8 @@ def state_bytes_per_seq(model: ModelConfig) -> int:
     if not model.has_state:
         return 0
     return model.num_state_layers * (
-        model.mamba_d_state * model.mamba_d_inner
-        * jnp.dtype(STATE_DTYPE).itemsize
-        + (model.mamba_d_conv - 1) * model.mamba_conv_dim
-        * model.jnp_dtype.itemsize)
+        math.prod(model.state_shape) * jnp.dtype(STATE_DTYPE).itemsize
+        + math.prod(model.state_conv_shape) * model.jnp_dtype.itemsize)
 
 
 def kv_cache_bytes_per_token(model: ModelConfig, cache: CacheConfig) -> int:

@@ -54,6 +54,9 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
     if arch == "OPTForCausalLM":
         return _opt_config_from_hf(hf, name or
                                    os.path.basename(os.path.normpath(path)))
+    if hf.get("model_type") == "kimi_linear":
+        return _kimi_linear_config_from_hf(
+            hf, name or os.path.basename(os.path.normpath(path)))
     if hf.get("kv_lora_rank"):
         return _deepseek_config_from_hf(
             hf, name or os.path.basename(os.path.normpath(path)))
@@ -123,6 +126,72 @@ def _deepseek_config_from_hf(hf: dict, name: str) -> ModelConfig:
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         **fields)
+
+
+def _kimi_linear_config_from_hf(hf: dict, name: str) -> ModelConfig:
+    """kimi_linear (Kimi-Linear-48B-A3B): KDA state layers and latent
+    attention layers by the two 1-based lists of ``linear_attn_config``,
+    kimi_vl's MLA widths (``mla_use_nope``: no rotation), a leading dense
+    layer, sigmoid-routed experts beside a shared one. The family names its
+    expert keys otherwise than deepseek_v3 does. Entries of the lists past
+    ``num_hidden_layers`` are another stage's; a layer neither list names,
+    or both, refuses the load by name, as does what the decoder does not
+    implement."""
+    lin = hf["linear_attn_config"]
+    for key, ok in (
+            ("q_lora_rank", hf.get("q_lora_rank") is None),
+            ("rope_scaling", not hf.get("rope_scaling")),
+            ("num_expert_group", hf.get("num_expert_group", 1) == 1),
+            ("topk_group", hf.get("topk_group", 1) == 1),
+            ("moe_layer_freq", hf.get("moe_layer_freq", 1) == 1),
+            ("num_nextn_predict_layers",
+             not hf.get("num_nextn_predict_layers")),
+            ("moe_router_activation_func",
+             hf.get("moe_router_activation_func", "sigmoid")
+             in ("sigmoid", "softmax")),
+            ("hidden_act", hf.get("hidden_act", "silu") == "silu")):
+        if not ok:
+            raise ValueError(
+                f"{name}: config.json {key}={hf.get(key)!r} is not "
+                "implemented by the delta-rule decoder")
+    kda, full = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+    types = []
+    for layer in range(1, hf["num_hidden_layers"] + 1):     # 1-based
+        if (layer in kda) == (layer in full):
+            raise ValueError(
+                f"{name}: config.json linear_attn_config names layer "
+                f"{layer} in " + ("both" if layer in kda else "neither")
+                + " of kda_layers and full_attn_layers")
+        types.append("kda" if layer in kda else "attention")
+    return ModelConfig(
+        name=name, vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads",
+                            hf["num_attention_heads"]),
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"], v_head_dim=hf["v_head_dim"],
+        pos_embedding="none" if hf.get("mla_use_nope") else "rope",
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_token"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_shared_experts=hf.get("num_shared_experts", 0),
+        first_k_dense_replace=hf.get("first_k_dense_replace", 0),
+        scoring_func=hf.get("moe_router_activation_func", "sigmoid"),
+        norm_topk_prob=bool(hf.get("moe_renormalize", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        layer_types=tuple(types), kda_n_heads=lin["num_heads"],
+        kda_head_dim=lin["head_dim"],
+        kda_d_conv=lin.get("short_conv_kernel_size", 4),
+        max_model_len=min(int(hf.get("max_position_embeddings", 4096)), 8192),
+    )
 
 
 def _granite_hybrid_config_from_hf(hf: dict, name: str) -> ModelConfig:
@@ -668,6 +737,12 @@ def load_weights(path: str, cfg: ModelConfig,
         # full host load; sharded placement still works via device_put with
         # the matching shardings pytree.
         return _place(_load_opt_host(ckpt, cfg), cfg, dtype, shardings)
+    if cfg.state_kind == "kda":
+        raise ValueError(
+            f"{cfg.name}: no loader for a kimi_linear checkpoint: none could "
+            "be fetched to read its tensor names from (they are ASSUMED in "
+            "perfbench/configs/kimi-linear-48b-a3b-bf16.json); it is served "
+            "with random weights")
     if cfg.is_mla:
         if shardings is not None:
             raise ValueError(f"{cfg.name}: a latent-attention model loads "

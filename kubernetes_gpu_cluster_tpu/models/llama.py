@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from ..config import ModelConfig
 from ..engine.kv_cache import KVCache
 from ..ops import quant as quant_ops
+from ..ops import kda as kda_ops
 from ..ops import ssm as ssm_ops
 from ..ops.rope import apply_rope, rope_cos_sin
 from ..ops.attention import NO_KERNELS, Kernels
@@ -155,9 +156,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = N
     return params
 
 
-def _init_state_layers(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
-    """Random init of the state layers' stack ``[num_state_layers, ...]``
-    (the attention layers of a typed model are ``layers``, as everywhere).
+def _init_swiglu(L: int, d: int, ff: int, keys, w) -> Params:
+    return {"w_gate": w(next(keys), (L, d, ff), d),
+            "w_up": w(next(keys), (L, d, ff), d),
+            "w_down": w(next(keys), (L, ff, d), ff)}
+
+
+def _init_mamba_mixer(cfg: ModelConfig, S: int, keys, w, dtype) -> Params:
+    """``S`` stacked Mamba-2 mixers with their layer's two norms.
     The mixer's in-projection is stored in three pieces, ``w_z`` [d,
     d_inner], ``w_xbc`` [d, d_inner + 2 N] (columns [x | B | C]) and
     ``w_dt`` [d, heads]: the checkpoint's ``in_proj`` is their
@@ -171,14 +177,9 @@ def _init_state_layers(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
     softplus^-1(log-uniform(1e-3, 1e-1))``, ``D = 1``, the conv (weight and
     bias) uniform in +-1/2 (torch's conv1d default at a fan-in of 4). Those
     three vectors a head stay float32, whatever the model's dtype."""
-    if cfg.is_moe or cfg.is_mla or cfg.quantization is not None:
-        raise ValueError(
-            f"{cfg.name}: state layers are served beside dense-precision "
-            "GQA attention layers and dense MLPs only")
-    d, ff, S = cfg.hidden_size, cfg.intermediate_size, cfg.num_state_layers
+    d = cfg.hidden_size
     H, di, C = cfg.mamba_n_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
     K = cfg.mamba_d_conv
-    keys = iter(jax.random.split(key, 12))
     u = jax.random.uniform
     dt = jnp.exp(u(next(keys), (S, H), jnp.float32,
                    jnp.log(1e-3), jnp.log(1e-1)))
@@ -196,10 +197,103 @@ def _init_state_layers(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
         "D": jnp.ones((S, H), jnp.float32),
         "ssm_norm": jnp.ones((S, di), dtype),
         "w_out": w(next(keys), (S, di, d), di),
-        "w_gate": w(next(keys), (S, d, ff), d),
-        "w_up": w(next(keys), (S, d, ff), d),
-        "w_down": w(next(keys), (S, ff, d), ff),
     }
+
+
+def _init_kda_mixer(cfg: ModelConfig, S: int, keys, w, dtype) -> Params:
+    """``S`` stacked KDA (gated delta-rule) mixers with their layer's two
+    norms. ``w_qkv`` [d, 3 H d_k] is the checkpoint's q, k and v projections
+    side by side and ``conv_w`` [K, 3 H d_k] its three depthwise convs
+    likewise (tap K-1 on the token itself; no bias): one product and one
+    conv over whole 128-lane tiles. The decay gate and the output gate each
+    pass through a low-rank pair of ``kda_head_dim``. The recurrence's own
+    parameters are drawn as flash-linear-attention initialises them, so that
+    the state neither dies nor explodes over thousands of tokens: ``A_log =
+    log U(1, 16)`` a head, ``dt_bias = softplus^-1(log-uniform(1e-3, 1e-1))``
+    a channel, both float32 whatever the model's dtype; the convs uniform in
+    +-1/2 (torch's conv1d default at a fan-in of 4)."""
+    d, H, hd, K = (cfg.hidden_size, cfg.kda_n_heads, cfg.kda_head_dim,
+                   cfg.kda_d_conv)
+    u = jax.random.uniform
+    dt = jnp.exp(u(next(keys), (S, H * hd), jnp.float32,
+                   jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "input_norm": jnp.ones((S, d), dtype),
+        "post_attn_norm": jnp.ones((S, d), dtype),
+        "w_qkv": w(next(keys), (S, d, 3 * H * hd), d),
+        "conv_w": u(next(keys), (S, K, 3 * H * hd), jnp.float32, -0.5, 0.5
+                    ).astype(dtype),
+        "w_f_down": w(next(keys), (S, d, hd), d),
+        "w_f_up": w(next(keys), (S, hd, H * hd), hd),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(u(next(keys), (S, H), jnp.float32, 1.0, 16.0)),
+        "w_beta": w(next(keys), (S, d, H), d),
+        "w_g_down": w(next(keys), (S, d, hd), d),
+        "w_g_up": w(next(keys), (S, hd, H * hd), hd),
+        "kda_norm": jnp.ones((S, hd), dtype),
+        "w_out": w(next(keys), (S, H * hd, d), H * hd),
+    }
+
+
+def _init_mla_mixer(cfg: ModelConfig, L: int, keys, w, dtype) -> Params:
+    """``L`` stacked latent-attention mixers with their layer's two norms.
+    ``w_uk``/``w_uv`` are ``kv_b_proj`` split per head into its key and
+    value halves ([nh, r, nope] and [nh, r, v]): the absorbed form contracts
+    them with q and with the output per head."""
+    d, nh = cfg.hidden_size, cfg.num_heads
+    r, nope, rope, vd = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+    return {
+        "input_norm": jnp.ones((L, d), dtype),
+        "post_attn_norm": jnp.ones((L, d), dtype),
+        "wq": w(next(keys), (L, d, nh * (nope + rope)), d),
+        "w_kva": w(next(keys), (L, d, r + rope), d),
+        "kv_norm": jnp.ones((L, r), dtype),
+        "w_uk": w(next(keys), (L, nh, r, nope), r),
+        "w_uv": w(next(keys), (L, nh, r, vd), r),
+        "wo": w(next(keys), (L, nh * vd, d), nh * vd),
+    }
+
+
+def _init_experts(cfg: ModelConfig, L: int, keys, w, dtype) -> Params:
+    """``L`` stacked DeepSeek-V3-class expert MLPs: the router over ALL
+    ``num_experts`` (float32) with its choice bias, drawn small and NOT zero
+    (N(0, 0.01)), so that choosing by score + bias and weighing by the raw
+    score differ; the experts this process HOLDS (``num_local_experts``:
+    an absent expert's weights are never allocated); the shared experts as
+    one SwiGLU of their summed width."""
+    d, E, ffe = cfg.hidden_size, cfg.num_experts, cfg.expert_width
+    held, ffs = cfg.num_local_experts, cfg.num_shared_experts * ffe
+    mlp = {
+        "router": w(next(keys), (L, d, E), d).astype(jnp.float32),
+        "router_bias": 0.01 * jax.random.normal(next(keys), (L, E),
+                                                jnp.float32),
+        "w_gate": _stacked_normal(next(keys), (L, held, d, ffe), d, dtype),
+        "w_up": _stacked_normal(next(keys), (L, held, d, ffe), d, dtype),
+        "w_down": _stacked_normal(next(keys), (L, held, ffe, d), ffe, dtype),
+    }
+    if ffs:
+        mlp["ws_gate"] = w(next(keys), (L, d, ffs), d)
+        mlp["ws_up"] = w(next(keys), (L, d, ffs), d)
+        mlp["ws_down"] = w(next(keys), (L, ffs, d), ffs)
+    return mlp
+
+
+_MIXER_INITS = {"mamba": _init_mamba_mixer, "kda": _init_kda_mixer}
+
+
+def _init_state_layers(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
+    """Random init of a dense model's state layers ``[num_state_layers,
+    ...]``, each mixer with its dense MLP (the attention layers of a typed
+    model are ``layers``, as everywhere)."""
+    if cfg.is_moe or cfg.is_mla or cfg.quantization is not None:
+        raise ValueError(
+            f"{cfg.name}: state layers beside dense-precision GQA attention "
+            "layers come with dense MLPs only")
+    keys = iter(jax.random.split(key, 12))
+    S = cfg.num_state_layers
+    return {**_MIXER_INITS[cfg.state_kind](cfg, S, keys, w, dtype),
+            **_init_swiglu(S, cfg.hidden_size, cfg.intermediate_size, keys, w)}
 
 
 def _stacked_normal(key, shape, fan_in: int, dtype) -> jax.Array:
@@ -219,15 +313,29 @@ def _stacked_normal(key, shape, fan_in: int, dtype) -> jax.Array:
     return buf
 
 
+def layer_stacks(cfg: ModelConfig) -> dict:
+    """The weight stacks of a model and how many layers each holds, in the
+    order the layers run: a layer's weights (its mixer's AND its MLP's) lie
+    in ``layers`` (attention) or ``ssm_layers`` (a state mixer), the
+    leading dense layers of an expert model in ``dense_layers`` /
+    ``dense_ssm_layers``. A section's tail continues its period's stacks."""
+    stacks = {}
+    for types, repeats, dense in cfg.layer_sections:
+        pre = "dense_" if dense and cfg.is_moe else ""
+        for kind in types:
+            name = pre + ("layers" if kind == "attention" else "ssm_layers")
+            stacks[name] = stacks.get(name, 0) + repeats
+    return stacks
+
+
 def _init_params_deepseek(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
-    """Random init of the DeepSeek-V3-class tree (kimi-vl-a3b's language
-    model): ``dense_layers`` (the ``first_k_dense_replace`` leading layers,
+    """Random init of the trees whose layers pair a mixer with an MLP of
+    another make: the DeepSeek-V3-class tree (kimi-vl-a3b's language
+    model), ``dense_layers`` (the ``first_k_dense_replace`` leading layers,
     stacked) beside ``layers`` (the expert layers, stacked), each with the
-    latent-attention tensors. ``w_uk``/``w_uv`` are ``kv_b_proj`` split per
-    head into its key and value halves ([nh, r, nope] and [nh, r, v]): the
-    absorbed form contracts them with q and with the output per head. The
-    choice bias is drawn small and NOT zero (N(0, 0.01)), so that choosing
-    by score + bias and weighing by the raw score differ."""
+    latent-attention tensors; and kimi-linear's, where a layer of either
+    group may be a KDA state layer instead (``dense_ssm_layers`` /
+    ``ssm_layers``: ``layer_stacks``)."""
     if not (cfg.is_mla and cfg.is_moe):
         raise ValueError(
             f"{cfg.name}: latent attention, leading dense layers and shared "
@@ -237,50 +345,20 @@ def _init_params_deepseek(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
             f"--quantization {cfg.quantization} with a latent-attention "
             "model: the absorbed projections and the grouped expert matmuls "
             "have no int8/int4 path")
-    d, nh = cfg.hidden_size, cfg.num_heads
-    r, nope, rope, vd = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-                         cfg.qk_rope_head_dim, cfg.v_head_dim)
-    E, ffe = cfg.num_experts, cfg.expert_width
-    ffs = cfg.num_shared_experts * ffe
-    Ld, Lm = cfg.num_dense_layers, cfg.num_layers - cfg.num_dense_layers
-    keys = iter(jax.random.split(key, 32))
-
-    def attn(L):
-        return {
-            "input_norm": jnp.ones((L, d), dtype),
-            "post_attn_norm": jnp.ones((L, d), dtype),
-            "wq": w(next(keys), (L, d, nh * (nope + rope)), d),
-            "w_kva": w(next(keys), (L, d, r + rope), d),
-            "kv_norm": jnp.ones((L, r), dtype),
-            "w_uk": w(next(keys), (L, nh, r, nope), r),
-            "w_uv": w(next(keys), (L, nh, r, vd), r),
-            "wo": w(next(keys), (L, nh * vd, d), nh * vd),
-        }
-
+    d = cfg.hidden_size
+    keys = iter(jax.random.split(key, 64 if cfg.has_state else 32))
     params: Params = {
         "embed": w(next(keys), (cfg.vocab_size, d), d),
         "final_norm": jnp.ones((d,), dtype),
     }
-    if Ld:
-        ff = cfg.intermediate_size
-        params["dense_layers"] = {
-            **attn(Ld),
-            "w_gate": w(next(keys), (Ld, d, ff), d),
-            "w_up": w(next(keys), (Ld, d, ff), d),
-            "w_down": w(next(keys), (Ld, ff, d), ff),
-        }
-    layers = attn(Lm)
-    layers["router"] = w(next(keys), (Lm, d, E), d).astype(jnp.float32)
-    layers["router_bias"] = 0.01 * jax.random.normal(next(keys), (Lm, E),
-                                                     jnp.float32)
-    layers["w_gate"] = _stacked_normal(next(keys), (Lm, E, d, ffe), d, dtype)
-    layers["w_up"] = _stacked_normal(next(keys), (Lm, E, d, ffe), d, dtype)
-    layers["w_down"] = _stacked_normal(next(keys), (Lm, E, ffe, d), ffe, dtype)
-    if ffs:
-        layers["ws_gate"] = w(next(keys), (Lm, d, ffs), d)
-        layers["ws_up"] = w(next(keys), (Lm, d, ffs), d)
-        layers["ws_down"] = w(next(keys), (Lm, ffs, d), ffs)
-    params["layers"] = layers
+    for name, L in layer_stacks(cfg).items():
+        mixer = (_MIXER_INITS[cfg.state_kind] if "ssm" in name
+                 else _init_mla_mixer)
+        params[name] = {
+            **mixer(cfg, L, keys, w, dtype),
+            **(_init_swiglu(L, d, cfg.intermediate_size, keys, w)
+               if name.startswith("dense_")
+               else _init_experts(cfg, L, keys, w, dtype))}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (d, cfg.vocab_size), d)
     return params
@@ -528,13 +606,22 @@ DENSE_DISPATCH_MAX_TOKENS = 128
 DENSE_DISPATCH_MIN_PAIRS_PER_EXPERT = 4
 
 
+def held_pairs(T: int, cfg: ModelConfig) -> float:
+    """The (token, expert) pairs a step of T tokens sends to the experts
+    HELD here, under uniform routing: all T * k where every expert is held,
+    the held share of them (a quarter at 64 of 256) otherwise."""
+    return (T * cfg.num_experts_per_tok * cfg.num_local_experts
+            / cfg.num_experts)
+
+
 def dense_dispatch_pays(T: int, cfg: ModelConfig) -> bool:
-    """Whether a step of T tokens should run whole, dense-precision experts
-    by dense dispatch (the comment above: every expert is hit anyway, and the
-    extra FLOPs hide under the weight stream)."""
+    """Whether a step of T tokens should run the dense-precision experts
+    held here by dense dispatch (the comment above: every HELD expert is
+    hit anyway by the pairs that reach the held ones, and the extra FLOPs
+    hide under the weight stream)."""
     return (T <= DENSE_DISPATCH_MAX_TOKENS
-            and T * cfg.num_experts_per_tok
-            >= DENSE_DISPATCH_MIN_PAIRS_PER_EXPERT * cfg.num_experts)
+            and held_pairs(T, cfg) >= (DENSE_DISPATCH_MIN_PAIRS_PER_EXPERT
+                                       * cfg.num_local_experts))
 
 
 def grouped_dispatch(T: int, cfg: ModelConfig, kernels: Kernels) -> bool:
@@ -561,9 +648,11 @@ def experts_grouped(lp: Params, x: jax.Array, idx: jax.Array,
     real token is computed whatever the imbalance: there is no capacity and
     nothing is dropped. A padding token's pairs (``valid`` False) are in no
     group: they sort behind every expert's, get no row, and the token's
-    result is zero.
-    x: [T, d]; idx/w: [T, k]; sizes: [E] int32, the real pairs of each
-    expert; valid: [T] bool or None (every token is real).
+    result is zero; so are the pairs of an expert this process does not
+    hold (``valid`` a pair: their part of the sum is another process's).
+    x: [T, d]; idx/w: [T, k], idx among the E experts of ``lp``; sizes: [E]
+    int32, the real pairs of each; valid: [T] or [T, k] bool, or None (every
+    pair is real and its expert here).
     Returns [T, d] float32.
 
     The rows are laid out as the kernel walks them (``grouped_matmul``'s
@@ -581,7 +670,8 @@ def experts_grouped(lp: Params, x: jax.Array, idx: jax.Array,
     cannot read through a dynamic slice."""
     T, k = idx.shape
     E = sizes.shape[0]
-    expert = idx if valid is None else jnp.where(valid[:, None], idx, E)
+    expert = idx if valid is None else jnp.where(
+        valid if valid.ndim == 2 else valid[:, None], idx, E)
     # One stable sort lays the rows out: behind the pairs come ROW_ALIGN - 1
     # fillers an expert, of which each expert owns what rounds its rows up
     # (the rest, like a padding token's pairs, sort behind every expert: E).
@@ -637,6 +727,8 @@ def experts_dense(lp: Params, x: jax.Array, idx: jax.Array, w: jax.Array,
     if ep_axis is not None and E_local != E:
         start = jax.lax.axis_index(ep_axis) * E_local
         combine = jax.lax.dynamic_slice_in_dim(combine, start, E_local, axis=1)
+    elif cfg.experts_held:      # this process's share, by configuration
+        combine = combine[:, cfg.experts_first:cfg.experts_first + E_local]
 
     def expert_fn(ep_params):
         gate = _dot(x, ep_params, "w_gate", use_pallas)
@@ -671,8 +763,15 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
     ``valid``: [T] bool, False on the step's padding tokens (None: there
     are none). Padding is not load: its pairs are in no expert's count, and
     the grouped path computes nothing for them.
+    A model that holds a SHARE of its experts (``cfg.experts_held`` from
+    ``cfg.experts_first``) routes over all of them all the same and sums
+    over the chosen experts that are held, on either path; what the absent
+    ones would add is another process's to compute and nothing here stands
+    in for it, nor for the exchange. The shared experts are computed here.
     ``load_out``: a list that is given the real routed pairs each expert was
-    sent, [E] int32 (``_layer_scan``'s ``moe_load``). ``stacked``: (the whole stack's expert tensors
+    sent, [E] int32 over ALL experts (``_layer_scan``'s ``moe_load``; the
+    host reads the held ones' balance and the share of pairs that reached
+    them). ``stacked``: (the whole stack's expert tensors
     [n, E, ...], this layer's index in it) where the caller kept them out
     of ``lp`` (``_layer_scan``: so that no layer's experts are copied)."""
     with jax.named_scope("kgct.moe.route"):
@@ -693,7 +792,13 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
             raise ValueError("grouped expert dispatch over quantized "
                              "experts: the kernel takes whole precision")
         if grouped_dispatch(x.shape[0], cfg, kernels):
-            out = experts_grouped(experts, x, idx, w, load, layer,
+            sizes = load
+            if cfg.experts_held:    # the pairs of an absent expert: no group
+                first, held = cfg.experts_first, cfg.experts_held
+                here = (idx >= first) & (idx < first + held)
+                valid = here if valid is None else here & valid[:, None]
+                idx, sizes = idx - first, load[first:first + held]
+            out = experts_grouped(experts, x, idx, w, sizes, layer,
                                   kernels.use_pallas, valid)
         else:
             if layer is not None:   # one layer's, read in place by the dots
@@ -728,11 +833,14 @@ def _mla_qkv(lp: Params, cfg: ModelConfig, x: jax.Array,
     q = q.reshape(T, q.shape[-1] // cfg.head_dim, cfg.head_dim)
     a = _dot(x, lp, "w_kva", use_pallas).astype(x.dtype)      # [T, r + rope]
     c = rms_norm(a[:, :r], lp["kv_norm"], cfg.rms_norm_eps)
-    cos, sin = rope_cos_sin(positions, rope, cfg.rope_theta,
-                            scaling=cfg.rope_scaling_dict)
-    q = jnp.concatenate(
-        [q[..., :-rope], apply_rope(q[..., -rope:], cos, sin)], axis=-1)
-    k_pe = apply_rope(a[:, None, r:], cos, sin)[:, 0]
+    k_pe = a[:, r:]
+    if cfg.pos_embedding == "rope":   # "none" (kimi_linear's mla_use_nope):
+        # the "rope" dims of q and of the shared key are used unrotated
+        cos, sin = rope_cos_sin(positions, rope, cfg.rope_theta,
+                                scaling=cfg.rope_scaling_dict)
+        q = jnp.concatenate(
+            [q[..., :-rope], apply_rope(q[..., -rope:], cos, sin)], axis=-1)
+        k_pe = apply_rope(k_pe[:, None], cos, sin)[:, 0]
     pad = jnp.zeros((T, cfg.kv_row_padded - cfg.kv_row_dim), x.dtype)
     return q, jnp.concatenate([c, k_pe, pad], axis=-1)
 
@@ -826,11 +934,147 @@ def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
     return jax.lax.cond(hist_len == 0, fresh, with_history, None)
 
 
+class _Stack(NamedTuple):
+    """What ``_layer_scan`` knows of the weight stack a section's layers of
+    one kind lie in: the stack without its expert tensors, those tensors
+    (read in place by layer index), the pool layer index of the stack's
+    first layer, and that of the section's first layer of the kind."""
+    layers: Optional[Params] = None
+    experts: Optional[Params] = None
+    first: int = 0
+    at: int = 0
+
+
+class _StateKind(NamedTuple):
+    """What one kind of state mixer is made of, for ``state_mixer``: the
+    prefix of its named scopes, the scope of its segment form, and
+
+    - ``project(lp, cfg, x) -> (conv_in [T, C], per_token, consts)``: the
+      conv's input, the recurrence's other per-token inputs ([T, ...] each)
+      and its constants;
+    - ``segments(cfg, xs [n, C], per_token, consts, seg, seg_ends, state0,
+      init_seg) -> (y [n, width] float32, final [S, *cfg.state_shape])``;
+    - ``rows(cfg, kernels, pool, layer, slots, xr [R, C] float32,
+      per_token, consts) -> (pool, y [R, width] float32)``;
+    - ``gate(lp, cfg, x, y [T, width]) -> out [T, d] float32``;
+    - ``conv_dtype``: what the conv's activated output is rounded to before
+      the recurrence (None: the model's dtype; the conv ROWS a slot keeps
+      are the model's dtype either way)."""
+    scope: str
+    segment_scope: str
+    project: Any
+    segments: Any
+    rows: Any
+    gate: Any
+    conv_dtype: Any = None
+
+
+def _mamba_project(lp, cfg, x):
+    P = cfg.mamba_d_head
+    xbc = _dot(x, lp, "w_xbc").astype(x.dtype)                   # [T, C]
+    dt = jax.nn.softplus(_dot(x, lp, "w_dt") + lp["dt_bias"])    # [T, H]
+    A = -jnp.exp(lp["A_log"].astype(jnp.float32))
+    D = jnp.repeat(lp["D"].astype(jnp.float32), P)
+    return xbc, (dt,), (A, D)
+
+
+def _mamba_segments(cfg, xs, per_token, consts, seg, seg_ends, state0,
+                    init_seg):
+    H, P, N, di = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
+                   cfg.mamba_d_inner)
+    (dt,), (A, D), n = per_token, consts, xs.shape[0]
+    y, final = ssm_ops.ssm_chunk_scan_xla(
+        xs[:, :di].reshape(n, H, P), dt, dt * A, xs[:, di:di + N],
+        xs[:, di + N:], seg, seg_ends, state0, init_seg,
+        cfg.mamba_chunk_size)
+    return y.reshape(n, di) + D * xs[:, :di].astype(jnp.float32), final
+
+
+def _mamba_rows(cfg, kernels, pool, layer, slots, xr, per_token, consts):
+    P, N, di = cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_d_inner
+    (dt,), (A, D) = per_token, consts
+    pool, y = kernels.ssm_update(
+        pool, layer, slots, jnp.repeat(jnp.exp(dt * A), P, axis=-1),
+        jnp.repeat(dt, P, axis=-1) * xr[:, :di],
+        xr[:, di:di + N], xr[:, di + N:])
+    return pool, y + D * xr[:, :di]
+
+
+def _mamba_gate(lp, cfg, x, y):
+    g = y * jax.nn.silu(_dot(x, lp, "w_z"))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                          + cfg.rms_norm_eps)
+    return _dot(g.astype(x.dtype) * lp["ssm_norm"], lp, "w_out")
+
+
+def _low_rank(x, lp, name):
+    """x through the pair ``<name>_down``, ``<name>_up``: float32. What
+    passes between the two (``kda_head_dim`` wide) is not rounded to the
+    model's dtype: the second product is 128 deep, 2 GFLOP at 2 k tokens."""
+    mid = _dot(x, lp, name + "_down")
+    return jnp.dot(mid, lp[name + "_up"].astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _kda_project(lp, cfg, x):
+    H, hd = cfg.kda_n_heads, cfg.kda_head_dim
+    qkv = _dot(x, lp, "w_qkv").astype(x.dtype)                # [T, 3 H hd]
+    g = -jnp.exp(lp["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+        _low_rank(x, lp, "w_f") + lp["dt_bias"]).reshape(-1, H, hd)
+    return qkv, (g, jax.nn.sigmoid(_dot(x, lp, "w_beta"))), ()
+
+
+def _kda_qkv(cfg, xs):
+    """The conv's output [n, 3 H hd] as unit q (scaled), unit k, v: float32
+    [n, H, hd] each."""
+    H, hd = cfg.kda_n_heads, cfg.kda_head_dim
+    q, k, v = (xs[:, i * H * hd:(i + 1) * H * hd].reshape(-1, H, hd)
+               for i in range(3))
+    return (kda_ops.l2_normalise(q) * hd ** -0.5, kda_ops.l2_normalise(k),
+            v.astype(jnp.float32))
+
+
+def _kda_segments(cfg, xs, per_token, consts, seg, seg_ends, state0,
+                  init_seg):
+    (g, beta), (q, k, v) = per_token, _kda_qkv(cfg, xs)
+    o, final = kda_ops.kda_chunk_scan_xla(
+        q, k, v, g, beta, seg, seg_ends, state0, init_seg,
+        cfg.kda_chunk_size)
+    return o.reshape(xs.shape[0], -1), final
+
+
+def _kda_rows(cfg, kernels, pool, layer, slots, xr, per_token, consts):
+    (g, beta), (q, k, v) = per_token, _kda_qkv(cfg, xr)
+    return kernels.kda_update(pool, layer, slots, g, beta, q, k, v)
+
+
+def _kda_gate(lp, cfg, x, y):
+    hd = cfg.kda_head_dim
+    o = y.reshape(y.shape[0], -1, hd)                          # per head
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.rms_norm_eps) * lp["kda_norm"]
+    o = o.reshape(y.shape) * jax.nn.sigmoid(_low_rank(x, lp, "w_g"))
+    return _dot(o.astype(x.dtype), lp, "w_out")
+
+
+_STATE_MIXERS = {
+    "mamba": _StateKind("kgct.ssm", "scan", _mamba_project, _mamba_segments,
+                        _mamba_rows, _mamba_gate),
+    # q, k and v stay float32 from the conv to the recurrence: k and q are
+    # normalised next, and the state they write is float32.
+    "kda": _StateKind("kgct.kda", "chunk", _kda_project, _kda_segments,
+                      _kda_rows, _kda_gate, jnp.float32),
+}
+
+
 def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
                 n_seg: int, ssm: jax.Array, conv: jax.Array,
                 layer: jax.Array, kernels: Kernels = NO_KERNELS):
-    """The Mamba-2 mixer of one state layer over the step's token axis
-    ``[segment tokens | row tokens]``. x: [T, d], the layer's normed input.
+    """The mixer of one state layer over the step's token axis ``[segment
+    tokens | row tokens]``, whichever kind the model has (``_STATE_MIXERS``).
+    x: [T, d], the layer's normed input.
+
+    Mamba-2 (granitemoehybrid):
 
         z = x W_z;   xBC = x W_xbc;   dt = x W_dt      (in_proj, in three pieces)
         xBC = silu(causal_depthwise_conv(xBC) + b);   [x | B | C] = xBC
@@ -842,25 +1086,30 @@ def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
     is the identity and is not written. One group: B and C are shared by
     all heads, and the gated norm runs over all of d_inner.
 
-    The segment part runs the conv and the chunked scan
-    (``ops.ssm.ssm_chunk_scan_xla``) within its segments; a chunk with
+    KDA (kimi_linear), per head:
+
+        [q | k | v] = silu(causal_depthwise_conv(x W_qkv));   q, k unit, q * d_k^-1/2
+        g = -exp(A_log) softplus((x W_f_down) W_f_up + dt_bias)   per channel
+        beta = sigmoid(x W_beta)
+        D = Diag(exp(g_t)) S_{t-1};   S_t = D + beta_t k_t (v_t - D^T k_t)^T;   o_t = S_t^T q_t
+        out = (RMSNorm_head(o) * w_norm * sigmoid((x W_g_down) W_g_up)) W_out
+
+    What the kinds share is what a slot means. The segment part runs the
+    conv and the kind's chunked form within its segments; a chunk with
     history (``meta.hist_len`` > 0) starts from its slot, anything else from
     zero WHATEVER the slot held; each segment's final state goes to its
-    slot. The row part is the one-token update of each row's slot
-    (``kernels.ssm_update``: in place). ``ssm`` [Ls, slots, N, d_inner]
+    slot. The row part is the one-token update of each row's slot (a kernel
+    of ``kernels``: in place). ``ssm`` [Ls, slots, *cfg.state_shape]
     float32 is threaded (the scans carry it); ``conv`` [Ls, slots, K-1,
     channels] is read as the step found it, and this layer's new rows come
     back for the one write behind the scan, as new K/V do.
     Returns (out [T, d] float32, ssm, new conv rows [S + R, K-1, channels]
     for ``meta.seg_slots`` then ``meta.row_slots``)."""
-    H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
-    di, C = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    kind = _STATE_MIXERS[cfg.state_kind]
+    scope = lambda part: jax.named_scope(f"{kind.scope}.{part}")
     f32 = jnp.float32
-    with jax.named_scope("kgct.ssm.proj"):
-        xbc = _dot(x, lp, "w_xbc").astype(x.dtype)                   # [T, C]
-        dt = jax.nn.softplus(_dot(x, lp, "w_dt") + lp["dt_bias"])    # [T, H]
-        A = -jnp.exp(lp["A_log"].astype(f32))
-        D = jnp.repeat(lp["D"].astype(f32), P)
+    with scope("proj"):
+        xbc, per_token, consts = kind.project(lp, cfg, x)
     ys, conv_new = [], []
     if n_seg:
         seg = meta.seg_ids[:n_seg]
@@ -879,39 +1128,33 @@ def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
                 return first
             return jnp.where(resumes, pool[layer, slot0], first)
 
-        with jax.named_scope("kgct.ssm.conv"):
+        with scope("conv"):
             c_out, rows = ssm_ops.conv_segments(
                 xbc[:n_seg], seg, seg_ends, start(conv), lp["conv_w"],
-                lp["conv_b"])
-            xs = jax.nn.silu(c_out).astype(x.dtype)
+                lp.get("conv_b"))
+            xs = jax.nn.silu(c_out).astype(kind.conv_dtype or x.dtype)
             conv_new.append(rows)
-        with jax.named_scope("kgct.ssm.scan"):
-            y, final = ssm_ops.ssm_chunk_scan_xla(
-                xs[:, :di].reshape(n_seg, H, P), dt[:n_seg],
-                dt[:n_seg] * A, xs[:, di:di + N], xs[:, di + N:], seg,
-                seg_ends, start(ssm), 0 if resumes is not None else -2,
-                cfg.mamba_chunk_size)
+        with scope(kind.segment_scope):
+            y, final = kind.segments(
+                cfg, xs, [a[:n_seg] for a in per_token], consts, seg,
+                seg_ends, start(ssm), 0 if resumes is not None else -2)
             ssm = ssm_ops.write_slots(ssm, final, meta.seg_slots, layer)
-            ys.append(y.reshape(n_seg, di) + D * xs[:, :di].astype(f32))
+            ys.append(y)
     if x.shape[0] > n_seg:
         slots = meta.row_slots
-        with jax.named_scope("kgct.ssm.conv"):
+        with scope("conv"):
             c_out, rows = ssm_ops.conv_rows(
-                xbc[n_seg:], conv[layer, slots], lp["conv_w"], lp["conv_b"])
-            xr = jax.nn.silu(c_out).astype(x.dtype).astype(f32)
+                xbc[n_seg:], conv[layer, slots], lp["conv_w"],
+                lp.get("conv_b"))
+            xr = jax.nn.silu(c_out).astype(
+                kind.conv_dtype or x.dtype).astype(f32)
             conv_new.append(rows)
-        with jax.named_scope("kgct.ssm.update"):
-            dt_r = dt[n_seg:]
-            ssm, y = kernels.ssm_update(
-                ssm, layer, slots, jnp.repeat(jnp.exp(dt_r * A), P, axis=-1),
-                jnp.repeat(dt_r, P, axis=-1) * xr[:, :di],
-                xr[:, di:di + N], xr[:, di + N:])
-            ys.append(y + D * xr[:, :di])
-    with jax.named_scope("kgct.ssm.gate"):
-        g = jnp.concatenate(ys, axis=0) * jax.nn.silu(_dot(x, lp, "w_z"))
-        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                              + cfg.rms_norm_eps)
-        out = _dot(g.astype(x.dtype) * lp["ssm_norm"], lp, "w_out")
+        with scope("update"):
+            ssm, y = kind.rows(cfg, kernels, ssm, layer, slots, xr,
+                               [a[n_seg:] for a in per_token], consts)
+            ys.append(y)
+    with scope("gate"):
+        out = kind.gate(lp, cfg, x, jnp.concatenate(ys, axis=0))
     return out, ssm, jnp.concatenate(conv_new, axis=0)
 
 
@@ -927,15 +1170,21 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                 moe_load: Optional[list] = None,
                 valid: Optional[jax.Array] = None,
                 state_fn=None, ssm: Optional[jax.Array] = None):
-    """Scan ONE PERIOD of typed layers over the stacked weights: the
-    shortest run of layer types whose repetition is the stack
-    (``cfg.layer_period``). A homogeneous model's period is one attention
-    layer, and its scan is the scan over its layers; granite-4.0-h-micro's
-    is [5 state, 1 attention, 4 state], scanned 4 times, with an inner scan
-    over each run of state layers, so the program holds one attention body
-    and two state bodies whatever the depth. Pool layer indices count per
-    kind: an attention layer addresses layer ``i`` of the K|V pools, a state
-    layer layer ``j`` of the slot pools.
+    """Run the stack section by section (``cfg.layer_sections``), each a
+    scan of ONE PERIOD of typed layers over the stacked weights: the
+    leading dense layers, then the shortest run of layer types whose whole
+    repetitions cover the layers behind them, then a tail shorter than a
+    period. A homogeneous model is one section of one attention layer, and
+    its scan is the scan over its layers; granite-4.0-h-micro's period is [5
+    state, 1 attention, 4 state], scanned 4 times, with an inner scan over
+    each run of state layers; kimi-linear's sections are its dense KDA
+    layer, 6 x [KDA, KDA, MLA, KDA] and the tail [KDA, MLA]: the program
+    holds one body a layer kind of each section, whatever the depth. A
+    layer is (a mixer: attention or the model's state mixer) x (an MLP:
+    dense or experts), and its weights lie in its mixer's stack
+    (``layer_stacks``). Pool layer indices count per kind through all the
+    sections: an attention layer addresses layer ``i`` of the page pools, a
+    state layer layer ``j`` of the slot pools.
 
     The KV pool does NOT travel through the scan: it is closed over whole and
     ``attn_fn`` receives the LAYER INDEX (scanned as xs) to address it.
@@ -1001,19 +1250,33 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, i, 0, keepdims=False), stack)
 
-    def state_layer(carry, layer_idx):
+    def mlp_of(lp, h, experts, i):
+        """The layer's MLP over its normed input, whatever its mixer was: an
+        expert layer if it holds a router (the leading dense layers of a
+        deepseek_v3 stack do not), else the dense MLP. ``experts``/``i``:
+        the expert tensors of the layer's stack and its index there.
+        Returns (h + the branch, the layer's expert load or ())."""
+        x = _norm(cfg, h, lp, "post_attn_norm")
+        load = [] if moe_load is not None else None
+        if "router" in lp:
+            mlp = _moe_mlp(lp, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
+                           kernels=kernels, load_out=load,
+                           stacked=(experts, i), valid=valid)
+        else:
+            mlp = _dense_mlp(lp, x, cfg, tp_axis=tp_axis, use_pallas=int4)
+        return h + scaled(mlp), tuple(load or ())
+
+    def state_layer(stack, carry, layer_idx):
         h, ssm = carry
-        lp = at(params["ssm_layers"], layer_idx)
+        lp = at(stack.layers, layer_idx - stack.first)
         x = _norm(cfg, h, lp, "input_norm")
-        with jax.named_scope("kgct.ssm"):
+        with jax.named_scope(_STATE_MIXERS[cfg.state_kind].scope):
             out, ssm, conv_rows = state_fn(lp, x, ssm, layer_idx)
         h = h + scaled(out).astype(h.dtype)
-        x = _norm(cfg, h, lp, "post_attn_norm")
-        h = h + scaled(_dense_mlp(lp, x, cfg, tp_axis=tp_axis,
-                                  use_pallas=int4))
-        return (h, ssm), conv_rows
+        h, load = mlp_of(lp, h, stack.experts, layer_idx - stack.first)
+        return (h, ssm), (conv_rows, load)
 
-    def attn_layer(h, lp, layer_idx):
+    def attn_layer(h, lp, stack, layer_idx):
         resid = h
         x = _norm(cfg, h, lp, "input_norm")
         if cfg.is_mla:
@@ -1030,101 +1293,121 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         if "bo" in lp:           # after the reduce: applied exactly once
             o = o + lp["bo"]
         h = resid + scaled(o).astype(h.dtype)
-        resid = h
-        x = _norm(cfg, h, lp, "post_attn_norm")
-        # A layer is an expert layer if it holds a router: the leading dense
-        # layers of a deepseek_v3 stack do not.
-        load = [] if moe_load is not None else None
-        if "router" in lp:
-            mlp = _moe_mlp(lp, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
-                           kernels=kernels, load_out=load,
-                           stacked=(experts, layer_idx - first),
-                           valid=valid)
-        else:
-            mlp = _dense_mlp(lp, x, cfg, tp_axis=tp_axis,
-                             use_pallas=int4)
-        h = resid + scaled(mlp)
-        load = tuple(load or ())
+        h, load = mlp_of(lp, h, stack.experts, layer_idx - stack.first)
         if cfg.is_mla:
             return h, ((row,), load)
         return h, ((k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1)),
                    load)
 
-    # One period: its attention layers one after the other, each run of
-    # state layers an inner scan over pool layer indices. ``attn_p``: the
-    # period's attention layer, scanned as xs where the period holds one
-    # (every model of today); ``attn_idx``/``ssm_idx``: the pool layer index
-    # of the period's first layer of each kind.
-    period = cfg.layer_period
-    n_attn, n_state = period.count("attention"), period.count("mamba")
-    runs, seen = [], {"attention": 0, "mamba": 0}
-    for kind in period:
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
-        else:
-            runs.append([kind, seen[kind], 1])
-        seen[kind] += 1
+    def split(stack):
+        """A stack's expert tensors stay OUT of what the bodies index or
+        scan: they read them in place, by layer index (see
+        experts_grouped)."""
+        experts = {k: stack[k] for k in _EXPERT_KEYS
+                   if k in stack and "router" in stack}
+        return {k: a for k, a in stack.items() if k not in experts}, experts
 
-    def period_body(layers, carry, xs):
-        (h, ssm), (attn_p, attn_idx, ssm_idx) = carry, xs
-        rows, loads, conv_rows = [], [], []
-        for kind, start, count in runs:
-            if kind == "mamba":
-                (h, ssm), conv = jax.lax.scan(
-                    state_layer, (h, ssm),
-                    ssm_idx + jnp.arange(start, start + count,
-                                         dtype=jnp.int32))
-                conv_rows.append(conv)
-                continue
-            for j in range(start, start + count):
-                idx = attn_idx + j if j else attn_idx
-                h, (row, load) = attn_layer(
-                    h, attn_p if n_attn == 1 else at(layers, idx), idx)
-                rows.append(row)
-                loads.append(load)
-        if n_attn == 1:
-            rows, loads = rows[0], loads[0]
-        else:   # [n_attn, T, ...]
-            rows, loads = (tuple(jnp.stack(a) for a in zip(*b))
-                           for b in (rows, loads))
-        if conv_rows:   # [n_state, S + R, ...]
-            conv_rows = jnp.concatenate(conv_rows, axis=0)
-        return (h, ssm), (rows, loads, conv_rows)
+    def whole(a):    # the scan's [periods, n, ...] ys back to [layers, ...]
+        return a.reshape((-1,) + a.shape[2:])
 
-    def whole(a, n):    # the scan's [periods, n, ...] ys back to [layers, ...]
-        return a if n == 1 else a.reshape((-1,) + a.shape[2:])
+    def run_section(carry, types, repeats, attn, state):
+        """``repeats`` periods of ``types`` as one scan: the period's
+        attention layers one after the other, each run of state layers an
+        inner scan over pool layer indices. ``attn``/``state``: the
+        ``_Stack`` the period's layers of that kind lie in. The period's
+        attention layer is scanned as xs where the period holds one and the
+        section is its whole stack (every model without a tail)."""
+        n_attn, n_state = types.count("attention"), len(types) - types.count(
+            "attention")
+        runs, seen = [], {"attention": 0, "state": 0}
+        for kind in types:
+            kind = kind if kind == "attention" else "state"
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, seen[kind], 1])
+            seen[kind] += 1
+        as_xs = n_attn == 1 and repeats == jax.tree.leaves(
+            attn.layers)[0].shape[0]
 
-    # The stack: the leading dense layers (``dense_layers``, where the model
-    # has them) and then the scanned main layers, pool layer indices in
-    # order. Each group is one scan over its own stacked weights.
-    stacks = [params[name] for name in ("dense_layers", "layers")
-              if name in params]
-    first, outs, conv_rows = 0, [], None
-    for layers in stacks:
-        # An expert stack's expert tensors stay OUT of the scanned xs: the
-        # body reads them in place, by layer index (see experts_grouped).
-        experts = {k: layers[k] for k in _EXPERT_KEYS
-                   if k in layers and "router" in layers}
-        layers = {k: a for k, a in layers.items() if k not in experts}
-        n_layers = jax.tree.leaves(layers)[0].shape[0]
-        if n_attn == 1:
-            attn_idx = jnp.arange(first, first + n_layers, dtype=jnp.int32)
-        else:
-            attn_idx = jnp.arange(first, first + n_layers, n_attn,
-                                  dtype=jnp.int32)
-        ssm_idx = (jnp.arange(0, cfg.num_state_layers, n_state,
-                              dtype=jnp.int32) if n_state else None)
-        (h, ssm), (rows, load, conv) = jax.lax.scan(
-            functools.partial(period_body, layers), (h, ssm),
-            (layers if n_attn == 1 else None, attn_idx, ssm_idx))
-        first += n_layers
-        outs.append(tuple(whole(a, n_attn) for a in rows))
-        if load:    # [n_layers, E]
-            moe_load.append(whole(load[0], n_attn))
-        if n_state:
-            conv_rows = conv.reshape((-1,) + conv.shape[2:])
-    rows = (outs[0] if len(outs) == 1 else
-            tuple(jnp.concatenate(r, axis=0) for r in zip(*outs)))
+        def period_body(carry, xs):
+            (h, ssm), (attn_p, attn_idx, ssm_idx) = carry, xs
+            rows, loads, conv_rows = [], [], []
+            for kind, start, count in runs:
+                if kind == "state":
+                    (h, ssm), (conv, load) = jax.lax.scan(
+                        functools.partial(state_layer, state), (h, ssm),
+                        ssm_idx + jnp.arange(start, start + count,
+                                             dtype=jnp.int32))
+                    conv_rows.append(conv)
+                    loads.extend(load)          # [count, E], or nothing
+                    continue
+                for j in range(start, start + count):
+                    idx = attn_idx + j if j else attn_idx
+                    h, (row, load) = attn_layer(
+                        h, attn_p if as_xs else at(attn.layers,
+                                                   idx - attn.first),
+                        attn, idx)
+                    rows.append(row)
+                    loads.extend(a[None] for a in load)
+            rows = tuple(jnp.stack(a) for a in zip(*rows))  # [n_attn, T, ..]
+            loads = jnp.concatenate(loads) if loads else ()
+            conv_rows = jnp.concatenate(conv_rows) if conv_rows else ()
+            return (h, ssm), (rows, loads, conv_rows)
+
+        idx = lambda stack, n: (jnp.arange(
+            stack.at, stack.at + repeats * n, n, dtype=jnp.int32)
+            if n else None)
+        carry, (rows, loads, conv) = jax.lax.scan(
+            period_body, carry,
+            (attn.layers if as_xs else None, idx(attn, n_attn),
+             idx(state, n_state)), length=repeats)
+        return (carry, tuple(whole(a) for a in rows),
+                loads if isinstance(loads, tuple) else whole(loads),
+                whole(conv) if n_state else None)
+
+    # The stack, section by section (``cfg.layer_sections``): the leading
+    # dense layers, the periods, the tail; pool layer indices count on per
+    # kind through all of them, a stack's own indices from its first layer.
+    carry, rows, loads, conv_rows = (h, ssm), [], [], []
+    pool_at = {"attention": 0, "state": 0}
+    stack_at: dict = {}
+    held = layer_stacks(cfg)
+    for types, repeats, dense in cfg.layer_sections:
+        pre = "dense_" if dense and cfg.is_moe else ""
+        # A pipeline stage (parallel/pp.py) holds its share of the layers:
+        # the periods of a section are those its stacks hold here.
+        first_of = pre + ("layers" if types[0] == "attention"
+                          else "ssm_layers")
+        repeats = repeats * jax.tree.leaves(
+            params[first_of])[0].shape[0] // held[first_of]
+        args = {}
+        for kind, name in (("attention", pre + "layers"),
+                           ("state", pre + "ssm_layers")):
+            n = sum((t == "attention") == (kind == "attention")
+                    for t in types)
+            args[kind] = _Stack()
+            if n:
+                first = stack_at.setdefault(name, pool_at[kind])
+                args[kind] = _Stack(*split(params[name]), first,
+                                    pool_at[kind])
+                pool_at[kind] += n * repeats
+        carry, row, load, conv = run_section(
+            carry, types, repeats, args["attention"], args["state"])
+        if row:
+            rows.append(row)
+        if not isinstance(load, tuple):
+            loads.append(load)
+        if conv is not None:
+            conv_rows.append(conv)
+    h, ssm = carry
+    if loads:    # [expert layers, E], in the sections' order
+        moe_load.append(loads[0] if len(loads) == 1
+                        else jnp.concatenate(loads))
+    rows = (rows[0] if len(rows) == 1 else
+            tuple(jnp.concatenate(r, axis=0) for r in zip(*rows)))
+    conv_rows = (None if not conv_rows else conv_rows[0]
+                 if len(conv_rows) == 1 else jnp.concatenate(conv_rows))
     return (h, rows[0], rows[1] if len(rows) == 2 else None, ssm, conv_rows)
 
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
+from typing import Optional
 
 import numpy as np
 
@@ -208,6 +209,10 @@ class Observability:
         # rows its expert matmuls computed that were pairs (percent).
         self.moe_routed_pairs: dict[str, int] = {}
         self.moe_expert_load_max_ratio = 0.0
+        # None until a step of a model that holds a SHARE of its experts
+        # reports its load: the share of real pairs that reached them.
+        self.moe_pairs_held_share: Optional[float] = None
+        self.moe_held: Optional[tuple] = None
         self.moe_grouped_tile_fill_share = 0.0
         # Speculative decoding: cumulative drafted vs accepted draft tokens
         # (bonus tokens excluded from both) — feeds the
@@ -448,9 +453,20 @@ class Observability:
         fetched (the device is done: this read waits for nothing).
         ``grouped``: the step's experts ran by grouped dispatch, one
         ``grouped_matmul`` group an expert; its visit rule says how full
-        the row tiles it computed were."""
+        the row tiles it computed were. Where this process holds a share of
+        the experts (``moe_held``: (first, count), the engine's word) the
+        balance and the tiles are THEIRS, and the share of the step's real
+        pairs that reached them is kept (a quarter at 64 of 256 under
+        uniform routing: the routing's skew as one share sees it)."""
+        held = self.moe_held
         for layers in load:
             layers = np.asarray(layers)
+            if held is not None:
+                sent = layers.sum()
+                layers = layers[:, held[0]:held[0] + held[1]]
+                if sent > 0:
+                    self.moe_pairs_held_share = float(
+                        100.0 * layers.sum() / sent)
             pairs = layers.sum(axis=0)
             if pairs.sum() > 0:
                 self.moe_expert_load_max_ratio = float(
@@ -674,6 +690,14 @@ class Observability:
             lines.append("# TYPE kgct_moe_grouped_tile_fill_share gauge")
             lines.append("kgct_moe_grouped_tile_fill_share %.4f"
                          % self.moe_grouped_tile_fill_share)
+            if self.moe_pairs_held_share is not None:
+                lines.append("# HELP kgct_moe_pairs_held_share percent of "
+                             "the real routed pairs that reached an expert "
+                             "this process holds, last prefill, chunk or "
+                             "mixed step")
+                lines.append("# TYPE kgct_moe_pairs_held_share gauge")
+                lines.append("kgct_moe_pairs_held_share %.4f"
+                             % self.moe_pairs_held_share)
         lines.append("# TYPE kgct_mixed_prefill_tokens_total counter")
         lines.append("kgct_mixed_prefill_tokens_total %d"
                      % self.mixed_prefill_tokens)

@@ -574,5 +574,15 @@ class Kernels:
         from .pallas.ssm_update import ssm_update
         return ssm_update(pool, layer, slots, decay, dtx, B, C)
 
+    def kda_update(self, pool, layer, slots, g, beta, q, k, v):
+        """A delta-rule layer's one-token update of the rows' slots, in
+        place in the carried pool (``ops/kda.py``): the Pallas kernel or the
+        XLA reference; one device, as ``ssm_update``."""
+        if not self.use_pallas:
+            from .kda import kda_update_xla
+            return kda_update_xla(pool, layer, slots, g, beta, q, k, v)
+        from .pallas.kda_update import kda_update
+        return kda_update(pool, layer, slots, g, beta, q, k, v)
+
 
 NO_KERNELS = Kernels()

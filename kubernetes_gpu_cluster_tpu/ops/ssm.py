@@ -59,7 +59,8 @@ def conv_segments(xbc: jax.Array, seg_ids: jax.Array, seg_ends: jax.Array,
 
     xbc [T, C]; seg_ids [T]; seg_ends [S] the last token of each segment
     (-1: absent); init_rows [K-1, C]; w [K, C] (tap K-1 meets the token
-    itself, as torch's conv1d weight [C, 1, K] does); b [C].
+    itself, as torch's conv1d weight [C, 1, K] does); b [C] or None (a conv
+    without bias).
     Returns (conv + bias [T, C] float32, each segment's new conv rows
     [S, K-1, C] in xbc's dtype: its last K-1 inputs, zeros or ``init_rows``
     where it is shorter)."""
@@ -68,7 +69,8 @@ def conv_segments(xbc: jax.Array, seg_ids: jax.Array, seg_ends: jax.Array,
     ext = jnp.concatenate([init_rows.astype(xbc.dtype), xbc], axis=0)
     seg_ext = jnp.concatenate(
         [jnp.broadcast_to(seg_ids[:1], (K - 1,)), seg_ids])
-    out = jnp.broadcast_to(b.astype(jnp.float32), xbc.shape)
+    out = jnp.broadcast_to(
+        jnp.float32(0) if b is None else b.astype(jnp.float32), xbc.shape)
     wf = w.astype(jnp.float32)
     for k in range(K):
         same = seg_ext[k:k + T] == seg_ids
@@ -81,11 +83,13 @@ def conv_segments(xbc: jax.Array, seg_ids: jax.Array, seg_ends: jax.Array,
 
 def conv_rows(xbc: jax.Array, state: jax.Array, w: jax.Array, b: jax.Array):
     """The conv of one new token a row against the row's slot.
-    xbc [R, C]; state [R, K-1, C] (oldest first). Returns (conv + bias
-    [R, C] float32, the new rows [R, K-1, C])."""
+    xbc [R, C]; state [R, K-1, C] (oldest first); b [C] or None. Returns
+    (conv + bias [R, C] float32, the new rows [R, K-1, C])."""
     full = jnp.concatenate([state.astype(xbc.dtype), xbc[:, None]], axis=1)
     out = jnp.einsum("rkc,kc->rc", full.astype(jnp.float32),
-                     w.astype(jnp.float32)) + b.astype(jnp.float32)
+                     w.astype(jnp.float32))
+    if b is not None:
+        out = out + b.astype(jnp.float32)
     return out, full[:, 1:]
 
 

@@ -81,3 +81,22 @@ def test_ssm_update_compiles_for_a_v5e_in_place(one_chip, no_compile_cache):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2**24
     assert mem.alias_size_in_bytes >= 36 * 65 * 128 * 4096 * 4
+
+
+def test_kda_update_compiles_for_a_v5e_in_place(one_chip, no_compile_cache):
+    """kimi-linear's decode step at the served cut: 64 rows against 65 slots
+    of [32 x 128, 128] float32 in 7 KDA layers (0.95 GB). The pool is
+    aliased, nothing of it is copied."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kda_update import kda_update
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = arr((7, 65, 4096, 128))
+    compiled = jax.jit(kda_update, donate_argnums=0).lower(
+        pool, arr((), jnp.int32), arr((64,), jnp.int32), arr((64, 32, 128)),
+        arr((64, 32)), arr((64, 32, 128)), arr((64, 32, 128)),
+        arr((64, 32, 128))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # ... beside the columns' and rows' small rearrangements
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**23
+    assert compiled.memory_analysis().alias_size_in_bytes >= 7 * 65 * 2**21
