@@ -928,10 +928,8 @@ def check_kda(cfg, B, T) -> None:
     update: the Pallas kernel against the XLA reference (values, the
     untouched slots bitwise), then each timed alone over the SERVED pool
     (every KDA layer, B + 1 slots, float32), a call a layer chained in one
-    program, against the bytes of the rows' slots. The chunked form (XLA
-    einsums, no kernel): timed alone over one prompt of the cell's mean
-    length in the top prefill bucket, and held to the token-by-token
-    recurrence."""
+    program, against the bytes of the rows' slots. (The chunked form:
+    ``check_kda_chunk``.)"""
     from kubernetes_gpu_cluster_tpu.ops import kda as kda_ops
     from kubernetes_gpu_cluster_tpu.ops.pallas.kda_update import kda_update
     Ls, H, hd = cfg.num_state_layers, cfg.kda_n_heads, cfg.kda_head_dim
@@ -989,23 +987,76 @@ def check_kda(cfg, B, T) -> None:
               f"({least / 819e9 * 1e6 / us:.1%})")
         del pool, acc
 
-    n, Q = 1472, cfg.kda_chunk_size     # the cell's mean prompt, alone
-    g, beta, q, k, v = (jnp.asarray(a) for a in _kda_inputs(rng, cfg, T))
-    seg = jnp.where(jnp.arange(T) < n, 0, -1).astype(jnp.int32)
-    ends = jnp.asarray([n - 1], jnp.int32)
-    init = jnp.zeros(shape, f32)
-    scan = jax.jit(lambda *a: kda_ops.kda_chunk_scan_xla(*a, -2, Q))
-    sec = _timed(scan, q, k, v, g, beta, seg, ends, init)
-    o, final = scan(q, k, v, g, beta, seg, ends, init)
-    o_ref, f_ref = jax.jit(kda_ops.kda_recurrence)(
-        q[:n], k[:n], v[:n], g[:n], beta[:n], init)
-    print(f"kda_chunk_scan_xla alone, T={T} ({n} real), {H} x {hd}, chunk "
-          f"{Q}: {sec * 1e3:.2f} ms a layer; max|chunked - token by token| "
-          f"o={_err(o[:n], o_ref):.2e} (max|o| "
-          f"{float(jnp.max(jnp.abs(o_ref))):.2f}; every product at full "
-          f"float32) "
-          f"state={_err(final[0], f_ref):.2e} (max|S| "
-          f"{float(jnp.max(jnp.abs(f_ref))):.2f})")
+
+def check_kda_chunk(cfg, T) -> None:
+    """The chunked delta-rule form at the model's geometry, the Pallas
+    kernel beside the XLA form, each held to the token-by-token recurrence
+    (max |error| of o and of the final state): one prompt of the cell's mean
+    length alone in the top prefill bucket (timed: a call a layer); three
+    prompts packed with boundaries inside chunks and sub-chunks, the first
+    continuing from a state, and three from nothing, the last wholly inside
+    one chunk between another's tail and the padding; a strong gate (a chunk's running sum ~ -4000);
+    a prompt of one repeated token without decay. The kernel may read at
+    most twice the XLA form's error (and a rounding)."""
+    from kubernetes_gpu_cluster_tpu.ops import kda as kda_ops
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kda_chunk import kda_chunk
+    H, hd, Q = cfg.kda_n_heads, cfg.kda_head_dim, cfg.kda_chunk_size
+    f32 = jnp.float32
+    rng = np.random.default_rng(13)
+    forms = (("xla", kda_ops.kda_chunk_scan_xla), ("pallas", kda_chunk))
+    recurrence = jax.jit(kda_ops.kda_recurrence)
+
+    def case(name, ins, bounds, init, init_seg, timed=False):
+        """``bounds``: each segment's [start, end) in the token axis."""
+        g, beta, q, k, v = (jnp.asarray(a) for a in ins)
+        n_tok = g.shape[0]
+        seg = np.full(n_tok, -1, np.int32)
+        for s, (a, b) in enumerate(bounds):
+            seg[a:b] = s
+        ends = jnp.asarray([b - 1 for _, b in bounds] + [-1], jnp.int32)
+        want = [recurrence(q[a:b], k[a:b], v[a:b], g[a:b], beta[a:b],
+                           init if s == init_seg else jnp.zeros_like(init))
+                for s, (a, b) in enumerate(bounds)]
+        errs = {}
+        for form, fn in forms:
+            run = jax.jit(lambda *a, fn=fn: fn(*a, init_seg, Q))
+            args = (q, k, v, g, beta, jnp.asarray(seg), ends, init)
+            o, final = run(*args)
+            finite = bool(jnp.isfinite(o).all() & jnp.isfinite(
+                final[:len(bounds)]).all())
+            e_o = max(_err(o[a:b], w[0]) for (a, b), w in zip(bounds, want))
+            e_s = max(_err(final[s], w[1]) for s, w in enumerate(want))
+            errs[form] = (e_o, e_s)
+            ms = (f"{_timed(run, *args) * 1e3:.2f} ms a layer; "
+                  if timed else "")
+            print(f"kda_chunk[{form}] {name}, T={n_tok}, {H} x {hd}, chunk "
+                  f"{Q}: {ms}max|chunked - token by token| o={e_o:.2e} "
+                  f"state={e_s:.2e} (max|o| "
+                  f"{max(float(jnp.max(jnp.abs(w[0]))) for w in want):.2f}, "
+                  f"max|S| "
+                  f"{max(float(jnp.max(jnp.abs(w[1]))) for w in want):.2f}); "
+                  f"finite: {finite}")
+            assert finite, (name, form)
+        for got, ref in zip(errs["pallas"], errs["xla"]):
+            assert got <= 2 * ref + 1e-6, (name, errs)
+
+    shape = cfg.state_shape
+    n = 1472                            # the cell's mean prompt, alone
+    case(f"one prompt ({n} real)", _kda_inputs(rng, cfg, T), [(0, n)],
+         jnp.zeros(shape, f32), -2, timed=True)
+    case("three packed, the first with history", _kda_inputs(rng, cfg, 600),
+         [(0, 70), (70, 201), (201, 535)],
+         jnp.asarray(rng.standard_normal(shape, np.float32)), 0)
+    case("three packed from nothing (chip_smoke's 40, 300, 17)",
+         _kda_inputs(rng, cfg, 512), [(0, 40), (40, 340), (340, 357)],
+         jnp.zeros(shape, f32), -2)
+    g, beta, q, k, v = _kda_inputs(rng, cfg, 256)
+    case("strong gate", (np.full_like(g, -64.0), beta, q, k, v), [(0, 256)],
+         jnp.asarray(rng.standard_normal(shape, np.float32)), 0)
+    same = lambda a: np.broadcast_to(a[:1], a.shape)
+    case("one repeated token, no decay",
+         (np.zeros_like(g), np.full_like(beta, 0.5), same(q), same(k), v),
+         [(0, 256)], jnp.zeros(shape, f32), -2)
 
 
 # The chained delta-rule state's error against the float64 recurrence, as a
@@ -1116,13 +1167,14 @@ def main() -> None:
         "ssm-chain": lambda: check_ssm_chain(cfg,
                                              Kernels(use_pallas=True)),
         "kda": lambda: check_kda(cfg, B, T),
+        "kda-chunk": lambda: check_kda_chunk(cfg, T),
         "kda-chain": lambda: check_kda_chain(cfg, Kernels(use_pallas=True)),
     }
     if cfg.is_mla and args.kernels == ap.get_default("kernels"):
         args.kernels = "latent,experts"
     if cfg.state_kind == "kda" and args.kernels in (
             ap.get_default("kernels"), "latent,experts"):
-        args.kernels = "latent,kda,kda-chain"
+        args.kernels = "latent,kda,kda-chunk,kda-chain"
     elif cfg.has_state and args.kernels == ap.get_default("kernels"):
         args.kernels += ",ssm,ssm-chain"
     for name in args.kernels.split(","):

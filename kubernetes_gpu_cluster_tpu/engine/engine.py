@@ -126,6 +126,13 @@ def _pack_int_b(batch: ScheduledBatch) -> np.ndarray:
     return np.stack(cols, axis=1)
 
 
+def _pack_float_b(batch: ScheduledBatch):
+    """The per-row float buffer of every step program: [B, 4]."""
+    return jnp.asarray(np.stack(
+        [batch.temperature, batch.top_p, batch.presence, batch.frequency],
+        axis=1))
+
+
 def _chained_tokens(tokens, prev, src):
     """Decode rows' input tokens: where ``src`` names a row, the token the
     step dispatched before this one sampled for it (``prev``, that step's
@@ -693,11 +700,17 @@ class LLMEngine:
             f32, (N, di) = jnp.float32, cfg.state_shape
             state_pool = arr((cfg.num_state_layers, 2) + cfg.state_shape, f32)
             if cfg.state_kind == "kda":
+                from ..ops.pallas.kda_chunk import kda_chunk
                 from ..ops.pallas.kda_update import kda_update
                 H, hd = cfg.kda_n_heads, cfg.kda_head_dim
                 probe("kda_update", kda_update, state_pool, arr((), i32),
                       arr((B,), i32), arr((B, H, hd), f32), arr((B, H), f32),
                       *(arr((B, H, hd), f32),) * 3)
+                # The chunked form over a full prefill bucket.
+                probe("kda_chunk",
+                      lambda *a: kda_chunk(*a, -2, cfg.kda_chunk_size),
+                      *(arr((T, H, hd), f32),) * 4, arr((T, H), f32),
+                      arr((T,), i32), arr((1,), i32), arr((N, di), f32))
             else:
                 from ..ops.pallas.ssm_update import ssm_update
                 probe("ssm_update", ssm_update, state_pool, arr((), i32),
@@ -2038,9 +2051,7 @@ class LLMEngine:
         self.step_count += 1
         self._key, step_key = jax.random.split(self._key)
         with ph("host_prep"):
-            float_b = jnp.asarray(np.stack(
-                [batch.temperature, batch.top_p, batch.presence,
-                 batch.frequency], axis=1))
+            float_b = _pack_float_b(batch)
         self.obs.on_step_dispatched(batch.kind, behind)
         if batch.kind in ("spec", "spec_mixed"):
             self.obs.on_chain_break("spec")
@@ -2056,6 +2067,29 @@ class LLMEngine:
             rec = self._dispatch_prefill(batch, prev, float_b, step_key)
         rec.update(batch=batch, kind=batch.kind)
         return rec, outs
+
+    def warm_full_window(self) -> None:
+        """Dispatch the greedy decode window over padding rows alone at the
+        largest row bucket, and wait for it: the program a server runs from
+        the moment its seats are full is then traced, lowered and loaded (or
+        compiled) before the first request, not under every open stream at
+        once (6 s without a token on 64 streams at kimi-linear: PERF.md
+        section 6, PR 36). Padding rows write the scrap page and the scrap
+        slot; no sequence, counter or random key of the engine is touched.
+        The serving CLI calls it before it listens; a library user may."""
+        sc = self.config.scheduler
+        t0 = time.monotonic()
+        batch = self.scheduler.decode_batch(
+            [], _bucket(sc.max_num_seqs, sc.decode_buckets))
+        prev = self._no_pred
+        for _ in range(2):
+            # the second behind the first: its cache and ``prev`` are a
+            # step's outputs, as every window's under load
+            prev = self._dispatch_window(batch, prev, _pack_float_b(batch),
+                                         jax.random.key(0), None)["last"]
+        jax.block_until_ready(prev)
+        logger.info("the window at %d rows met before the first request: "
+                    "%.1f s", len(batch.tokens), time.monotonic() - t0)
 
     def _dispatch_prefill(self, batch: ScheduledBatch, prev, float_b,
                           step_key) -> dict:

@@ -1086,8 +1086,15 @@ class Scheduler:
             self.config.scheduler.decode_window, behind)
         if not scheduled:
             return None
+        return self.decode_batch(
+            scheduled, _bucket(len(scheduled), self.decode_buckets))
 
-        B = _bucket(len(scheduled), self.decode_buckets)
+    def decode_batch(self, scheduled: list[Sequence], B: int
+                     ) -> ScheduledBatch:
+        """A decode window's batch over ``scheduled`` at ``B`` rows; the
+        rows past them are padding (the scrap page, the scrap slot, greedy).
+        With no sequence at all it is what ``LLMEngine.warm_full_window``
+        dispatches."""
         # Static page-table width: sized for max_model_len once, so the jitted
         # decode program never recompiles as contexts grow. Costless on the
         # device side — the Pallas decode kernel streams only the valid pages;

@@ -952,8 +952,9 @@ class _StateKind(NamedTuple):
     - ``project(lp, cfg, x) -> (conv_in [T, C], per_token, consts)``: the
       conv's input, the recurrence's other per-token inputs ([T, ...] each)
       and its constants;
-    - ``segments(cfg, xs [n, C], per_token, consts, seg, seg_ends, state0,
-      init_seg) -> (y [n, width] float32, final [S, *cfg.state_shape])``;
+    - ``segments(cfg, kernels, xs [n, C], per_token, consts, seg, seg_ends,
+      state0, init_seg) -> (y [n, width] float32, final [S,
+      *cfg.state_shape])``;
     - ``rows(cfg, kernels, pool, layer, slots, xr [R, C] float32,
       per_token, consts) -> (pool, y [R, width] float32)``;
     - ``gate(lp, cfg, x, y [T, width]) -> out [T, d] float32``;
@@ -978,8 +979,8 @@ def _mamba_project(lp, cfg, x):
     return xbc, (dt,), (A, D)
 
 
-def _mamba_segments(cfg, xs, per_token, consts, seg, seg_ends, state0,
-                    init_seg):
+def _mamba_segments(cfg, kernels, xs, per_token, consts, seg, seg_ends,
+                    state0, init_seg):
     H, P, N, di = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
                    cfg.mamba_d_inner)
     (dt,), (A, D), n = per_token, consts, xs.shape[0]
@@ -1034,10 +1035,10 @@ def _kda_qkv(cfg, xs):
             v.astype(jnp.float32))
 
 
-def _kda_segments(cfg, xs, per_token, consts, seg, seg_ends, state0,
-                  init_seg):
+def _kda_segments(cfg, kernels, xs, per_token, consts, seg, seg_ends,
+                  state0, init_seg):
     (g, beta), (q, k, v) = per_token, _kda_qkv(cfg, xs)
-    o, final = kda_ops.kda_chunk_scan_xla(
+    o, final = kernels.kda_chunk(
         q, k, v, g, beta, seg, seg_ends, state0, init_seg,
         cfg.kda_chunk_size)
     return o.reshape(xs.shape[0], -1), final
@@ -1136,8 +1137,8 @@ def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
             conv_new.append(rows)
         with scope(kind.segment_scope):
             y, final = kind.segments(
-                cfg, xs, [a[:n_seg] for a in per_token], consts, seg,
-                seg_ends, start(ssm), 0 if resumes is not None else -2)
+                cfg, kernels, xs, [a[:n_seg] for a in per_token], consts,
+                seg, seg_ends, start(ssm), 0 if resumes is not None else -2)
             ssm = ssm_ops.write_slots(ssm, final, meta.seg_slots, layer)
             ys.append(y)
     if x.shape[0] > n_seg:

@@ -584,5 +584,18 @@ class Kernels:
         from .pallas.kda_update import kda_update
         return kda_update(pool, layer, slots, g, beta, q, k, v)
 
+    def kda_chunk(self, q, k, v, g, beta, seg_ids, seg_ends, init_state,
+                  init_seg, chunk):
+        """A delta-rule layer's segment part, a chunk at a time
+        (``ops/kda.py``): the Pallas kernel or the XLA form; one device, as
+        ``ssm_update``."""
+        if not self.use_pallas:
+            from .kda import kda_chunk_scan_xla
+            return kda_chunk_scan_xla(q, k, v, g, beta, seg_ids, seg_ends,
+                                      init_state, init_seg, chunk)
+        from .pallas.kda_chunk import kda_chunk
+        return kda_chunk(q, k, v, g, beta, seg_ids, seg_ends, init_state,
+                         init_seg, chunk)
+
 
 NO_KERNELS = Kernels()

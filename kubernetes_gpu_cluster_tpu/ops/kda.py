@@ -24,9 +24,11 @@ transpose onto the slice's operand: the whole pool, copied every step that
 resumes a chunk from its slot.)
 
 The references here are what the CPU runs, what ``NO_KERNELS`` names and
-what the Pallas ``kda_update`` (``ops/pallas/kda_update.py``) is held to;
-``ops.attention.Kernels.kda_update`` chooses. The chunked form has no
-kernel: its products are einsums the MXU takes as they are.
+what the two Pallas kernels are held to: ``kda_update``
+(``ops/pallas/kda_update.py``) and, for the chunked form, ``kda_chunk``
+(``ops/pallas/kda_chunk.py``: a chunk's operands stay in VMEM from the
+running decay to o). ``ops.attention.Kernels.kda_update`` and ``.kda_chunk``
+choose.
 """
 
 from __future__ import annotations
@@ -151,6 +153,27 @@ def _solve_unit_lower(A: jax.Array, rhs: jax.Array) -> jax.Array:
     return jnp.stack(xs, axis=-3).reshape(rhs.shape)
 
 
+def segment_finals(k_s: jax.Array, G_s: jax.Array, sc_s: jax.Array,
+                    S_in_s: jax.Array, U_s: jax.Array, seg_in_s: jax.Array,
+                    o_s: jax.Array) -> jax.Array:
+    """Each segment's state at ITS last token: the carry into that token's
+    chunk, decayed, plus what the segment's tokens up to there add. Every
+    operand is of the segment's last chunk: k_s, G_s [S, H, Q, d_k] (G the
+    running sum of the chunk's g), sc_s [S, Q] its seg_ids, S_in_s [S, H,
+    d_k, d_v] the state it was handed, of segment seg_in_s [S], U_s [S, H,
+    Q, d_v], o_s [S] the token's place in the chunk. Returns [S, H, d_k,
+    d_v] float32."""
+    t_idx = jnp.arange(k_s.shape[2])
+    G_o = jnp.take_along_axis(G_s, o_s[:, None, None, None], axis=2)
+    seg_of = jnp.take_along_axis(sc_s, o_s[:, None], axis=1)       # [S, 1]
+    upto = (t_idx[None, :] <= o_s[:, None]) & (sc_s == seg_of)
+    k_fin = k_s * jnp.exp(jnp.where(
+        upto[:, None, :, None], G_o - G_s, -jnp.inf))
+    return (S_in_s * (jnp.exp(G_o[:, :, 0, :])
+                      * (seg_in_s[:, None] == seg_of)[..., None])[..., None]
+            + jnp.einsum("shqd,shqv->shdv", k_fin, U_s, precision=_HI))
+
+
 def kda_chunk_scan_xla(q: jax.Array, k: jax.Array, v: jax.Array,
                        g: jax.Array, beta: jax.Array, seg_ids: jax.Array,
                        seg_ends: jax.Array, init_state: jax.Array, init_seg,
@@ -169,8 +192,10 @@ def kda_chunk_scan_xla(q: jax.Array, k: jax.Array, v: jax.Array,
     once, so the walk over the chunks is two small products a chunk. Every
     product runs at full float32 (six MXU passes): all of them together are
     ~10 GFLOP a layer at 2 k tokens, 0.3 ms of MXU time where a layer takes
-    6 ms, and at the default precision o alone read 4e-3 of its largest
-    value from the recurrence's (v5e, PR 35).
+    6 ms in this form (the rest is traffic XLA makes for itself: the
+    pairwise decays in HBM, the transposes, the scan's carry), and at the
+    default precision o alone read 4e-3 of its largest value from the
+    recurrence's (v5e, PR 35).
 
     q, k [T, H, d_k] (unit keys, scaled unit queries), v [T, H, d_v], g
     [T, H, d_k] (log decay, <= 0), beta [T, H]: float32; seg_ids [T] (-1:
@@ -234,22 +259,11 @@ def kda_chunk_scan_xla(q: jax.Array, k: jax.Array, v: jax.Array,
                         precision=_HI)
              + jnp.einsum("chts,chsv->chtv", P, U, precision=_HI))
 
-    # Each segment's state at ITS last token: the carry into that token's
-    # chunk, decayed, plus what the segment's tokens up to there add.
     with jax.named_scope("kgct.kda.chunk.final"):
         e = jnp.maximum(seg_ends, 0)
-        c_s, o_s = e // Q, e % Q
-        G_s, sc_s = G[c_s], sc[c_s]                    # [S,H,Q,d]  [S,Q]
-        G_o = jnp.take_along_axis(G_s, o_s[:, None, None, None], axis=2)
-        seg_of = jnp.take_along_axis(sc_s, o_s[:, None], axis=1)   # [S, 1]
-        upto = (t_idx[None, :] <= o_s[:, None]) & (sc_s == seg_of)
-        k_fin = kc[c_s] * jnp.exp(jnp.where(
-            upto[:, None, :, None], G_o - G_s, -jnp.inf))
-        final = (S_in[c_s] * (jnp.exp(G_o[:, :, 0, :])
-                              * (seg_in[c_s][:, None] == seg_of)[..., None]
-                              )[..., None]
-                 + jnp.einsum("shqd,shqv->shdv", k_fin, U[c_s],
-                              precision=_HI))
+        c_s = e // Q
+        final = segment_finals(kc[c_s], G[c_s], sc[c_s], S_in[c_s], U[c_s],
+                               seg_in[c_s], e % Q)
     o = o.swapaxes(1, 2).reshape(nc * Q, H, -1)[:T]
     return o, slot_layout(final)
 

@@ -2663,6 +2663,13 @@ def main(argv: Optional[list[str]] = None) -> None:
                           profile_dir=args.profile_dir)
     if hf_overrides:
         server._runtime_info["hf_overrides"] = hf_overrides
+    if (leader is None and args.role != "prefill"
+            and not config.enforce_eager):
+        # Before it listens: the first use of the window at full seats
+        # would otherwise stand every open stream still for its length.
+        # (Under a multi-process mesh every dispatch is a directive: the
+        # followers would have to be told.)
+        server.engine.engine.warm_full_window()
     app = server.build_app()
 
     async def _arm_sigterm(app_):

@@ -10,8 +10,8 @@ compare two dumps. CPU only; nothing here measures time.
         python scripts/step_program_jaxprs.py dump /root/scratch/change.json
     python scripts/step_program_jaxprs.py compare parent.json change.json
 
-``dump`` builds the tiny presets (dense, int4, latent + experts; meshless,
-tp=2, pp=2, sp=2, tp x ep) with the XLA references as the CPU engine resolves
+``dump`` builds the tiny presets (dense, int4, latent + experts, the two
+state kinds; meshless, tp=2, pp=2, sp=2, tp x ep) with the XLA references as the CPU engine resolves
 them, runs a staged load (packed and chunked prompts, mixed steps, greedy and
 sampled windows, speculation) and records each program at each shape it met.
 Then it traces the same shapes through an engine told ``use_pallas=True`` at a
@@ -46,6 +46,8 @@ CASES = [
     ("tiny-pp2", dict(model="debug-tiny", mesh=dict(pp=2))),
     ("tiny-sp2", dict(model="debug-tiny", mesh=dict(sp=2))),
     ("moe-tp2ep2", dict(model="debug-moe", mesh=dict(tp=2, ep=2))),
+    ("ssm-hybrid", dict(model="debug-ssm-hybrid")),
+    ("kda-hybrid", dict(model="debug-kda-hybrid")),
 ]
 
 

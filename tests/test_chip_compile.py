@@ -100,3 +100,24 @@ def test_kda_update_compiles_for_a_v5e_in_place(one_chip, no_compile_cache):
     # ... beside the columns' and rows' small rearrangements
     assert compiled.memory_analysis().temp_size_in_bytes < 2**23
     assert compiled.memory_analysis().alias_size_in_bytes >= 7 * 65 * 2**21
+
+
+def test_kda_chunk_compiles_for_a_v5e_without_copies(one_chip,
+                                                     no_compile_cache):
+    """kimi-linear's chunked delta-rule form over a full prefill bucket: 2048
+    tokens of 32 heads of 128 in chunks of 64, four segments. The operands
+    are read as they are: beside the kernel's own results (o, u and the
+    states handed to each chunk) nothing of their size is written, so no
+    operand was copied to another layout on its way in or out."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kda_chunk import kda_chunk
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    T, H, d = 2048, 32, 128
+    compiled = jax.jit(lambda *a: kda_chunk(*a, 0, 64)).lower(
+        *(arr((T, H, d)),) * 4, arr((T, H)), arr((T,), jnp.int32),
+        arr((4,), jnp.int32), arr((H * d, d))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    operand = T * H * d * 4
+    # u [T, H, d] and the 32 chunks' [H x d, d] states, plus small change
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * operand

@@ -569,3 +569,27 @@ def test_device_queue_matches_chain_broken_every_step(case):
         assert queued[rid][1] == reason
     for rid, n in spec.get("tokens", {}).items():
         assert len(queued[rid][0]) == n
+
+
+def test_warm_full_window_meets_the_full_seat_program_and_nothing_else():
+    """``warm_full_window`` runs the greedy window at the largest row
+    bucket over padding rows alone: one step program more has met its
+    shape, no page, step or key of the engine is used up, and what is
+    served afterwards (greedy, and sampled from the engine's own key) is
+    what an engine that was never warmed serves."""
+    prompts = _P[:3] + _P[4:6]
+    cold, warm = _queue_engine(), _queue_engine()
+    before = warm.compiled_step_variants()
+    warm.warm_full_window()
+    assert warm.compiled_step_variants() == before + 1
+    assert warm.step_count == 0
+    alloc = warm.scheduler.allocator
+    assert alloc.num_free == alloc.num_pages - 1
+    for temperature in (0.0, 0.8):
+        p = SamplingParams(max_tokens=9, temperature=temperature)
+        want = [o.output_token_ids for o in cold.generate(prompts, p)]
+        assert [o.output_token_ids
+                for o in warm.generate(prompts, p)] == want
+    # what was warmed IS the program full seats run: the unwarmed engine
+    # met it in its load, and holds no shape fewer
+    assert warm.compiled_step_variants() == cold.compiled_step_variants()

@@ -11,8 +11,9 @@ whose boundaries fall inside chunks and sub-chunks; a prompt in two chunks
 with history), its row part (a decode through the slots AND the latent
 pages), both in one mixed step; the slots (zero at a sequence's start
 whatever they held); the engine (chained decode windows, preemption by
-recompute); the chunked form against the recurrence under a strong gate;
-the Pallas update in interpret mode; a model that holds a SHARE of its
+recompute); the chunked form (the XLA einsums and the Pallas kernel in interpret mode)
+against the recurrence, under a strong gate and over one repeated token, and
+the kernel against the einsums; the Pallas update in interpret mode; a model that holds a SHARE of its
 experts (four shares add up to the whole layer on both dispatch paths, the
 load and the dispatch rule over the experts held); every flag a
 latent-and-stateful model is refused, by its message; ``config_from_hf``.
@@ -328,8 +329,20 @@ def _kda_inputs(T, H, d, key, gate_bias, A=None):
     return q, kk, v, g, jax.nn.sigmoid(jax.random.normal(k[5], (T, H)))
 
 
+def _pallas_chunk(*args):
+    from kubernetes_gpu_cluster_tpu.ops.pallas.kda_chunk import kda_chunk
+    return kda_chunk(*args, interpret=True)
+
+
+# The chunked form's two implementations: what the CPU and NO_KERNELS run,
+# and the chip's kernel in interpret mode.
+_CHUNK_FORMS = [pytest.param(kda_ops.kda_chunk_scan_xla, id="xla"),
+                pytest.param(_pallas_chunk, id="pallas")]
+
+
+@pytest.mark.parametrize("form", _CHUNK_FORMS)
 @pytest.mark.parametrize("chunk", [16, 64])
-def test_chunked_form_equals_the_recurrence(chunk):
+def test_chunked_form_equals_the_recurrence(chunk, form):
     """Three segments in 300 tokens (boundaries at 70 and 201: inside chunks
     and sub-chunks at both sizes), the first continuing from a state."""
     H, d = 3, 32
@@ -337,8 +350,7 @@ def test_chunked_form_equals_the_recurrence(chunk):
     init = jax.random.normal(jax.random.key(5), (H * d, d))
     seg = jnp.asarray([0] * 70 + [1] * 131 + [2] * 79 + [-1] * 20)
     ends = jnp.asarray([69, 200, 279, -1])
-    o, final = kda_ops.kda_chunk_scan_xla(q, k, v, g, beta, seg, ends, init,
-                                          0, chunk)
+    o, final = form(q, k, v, g, beta, seg, ends, init, 0, chunk)
     for s, (a, b) in enumerate(((0, 70), (70, 201), (201, 280))):
         o_s, f_s = kda_ops.kda_recurrence(
             q[a:b], k[a:b], v[a:b], g[a:b], beta[a:b],
@@ -348,7 +360,8 @@ def test_chunked_form_equals_the_recurrence(chunk):
         np.testing.assert_allclose(final[s], f_s, atol=2e-5)
 
 
-def test_chunked_form_under_a_strong_gate_is_finite_and_right():
+@pytest.mark.parametrize("form", _CHUNK_FORMS)
+def test_chunked_form_under_a_strong_gate_is_finite_and_right(form):
     """A = 16 and a large dt_bias (softplus(x + 4) ~ 4: g ~ -64 a token, a
     chunk's running sum ~ -4000): exp(-G_s) alone overflows float32 after
     two tokens; the decay differences are formed as G_t - G_s and the
@@ -358,8 +371,7 @@ def test_chunked_form_under_a_strong_gate_is_finite_and_right():
     assert float(jnp.min(jnp.cumsum(g[:64], axis=0))) < -3000
     init = jax.random.normal(jax.random.key(6), (H * d, d))
     seg, ends = jnp.zeros(256, jnp.int32), jnp.asarray([255])
-    o, final = kda_ops.kda_chunk_scan_xla(q, k, v, g, beta, seg, ends, init,
-                                          0, 64)
+    o, final = form(q, k, v, g, beta, seg, ends, init, 0, 64)
     o_r, f_r = kda_ops.kda_recurrence(q, k, v, g, beta, init)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(final).all())
     # nothing survives a token under this gate, so o is one rank-one term:
@@ -368,7 +380,8 @@ def test_chunked_form_under_a_strong_gate_is_finite_and_right():
     np.testing.assert_allclose(final[0], f_r, atol=1e-6)
 
 
-def test_the_solve_survives_a_prompt_of_one_repeated_token():
+@pytest.mark.parametrize("form", _CHUNK_FORMS)
+def test_the_solve_survives_a_prompt_of_one_repeated_token(form):
     """Every key the same and no decay: A is beta times the strictly lower
     ones, whose powers grow like binomials; the sub-chunk inverses and the
     forward substitution keep the digits a 64-row series would lose."""
@@ -379,12 +392,49 @@ def test_the_solve_survives_a_prompt_of_one_repeated_token():
     v = jax.random.normal(jax.random.key(2), (T, H, d))
     g, beta = jnp.zeros((T, H, d)), jnp.full((T, H), 0.5)
     init = jnp.zeros((H * d, d))
-    o, final = kda_ops.kda_chunk_scan_xla(
+    o, final = form(
         q, k, v, g, beta, jnp.zeros(T, jnp.int32), jnp.asarray([T - 1]),
         init, -2, 64)
     o_r, f_r = kda_ops.kda_recurrence(q, k, v, g, beta, init)
     np.testing.assert_allclose(o, o_r, atol=1e-5)
     np.testing.assert_allclose(final[0], f_r, atol=1e-5)
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("bounds,history", [
+    pytest.param([(0, 150)], False, id="fresh"),
+    pytest.param([(0, 150)], True, id="history"),
+    pytest.param([(0, 70), (70, 180)], False, id="two-packed"),
+    pytest.param([(0, 37), (37, 42), (42, 150)], True, id="three-packed"),
+])
+def test_the_chunk_kernel_in_interpret_mode_equals_the_xla_form(
+        chunk, bounds, history):
+    """``ops/pallas/kda_chunk.py`` against ``kda_chunk_scan_xla`` over 256
+    tokens of two heads: one prompt from nothing and from a slot's state
+    (``init_seg`` 0); two and three prompts packed, their boundaries (70;
+    37 and 42) inside chunks and sub-chunks at both chunk sizes; behind
+    them a padding tail of whole chunks (from 192 at the latest), where o
+    is zero; absent segments (``seg_ends`` -1) beside the present ones."""
+    T, H, d, S = 256, 2, 32, 3
+    q, k, v, g, beta = _kda_inputs(T, H, d, jax.random.key(7), -3.0)
+    init = jax.random.normal(jax.random.key(8), (H * d, d))
+    seg = np.full(T, -1, np.int32)
+    for s, (a, b) in enumerate(bounds):
+        seg[a:b] = s
+    ends = jnp.asarray([b - 1 for _, b in bounds]
+                       + [-1] * (S - len(bounds)), jnp.int32)
+    args = (q, k, v, g, beta, jnp.asarray(seg), ends, init,
+            0 if history else -2, chunk)
+    want_o, want_f = kda_ops.kda_chunk_scan_xla(*args)
+    got_o, got_f = _pallas_chunk(*args)
+    n = bounds[-1][1]
+    # float32 both: the order of sums only
+    np.testing.assert_allclose(got_o[:n], want_o[:n], atol=1e-5)
+    np.testing.assert_allclose(got_f[:len(bounds)], want_f[:len(bounds)],
+                               atol=1e-5)
+    assert bool(jnp.isfinite(got_o).all()) and bool(jnp.isfinite(got_f).all())
+    whole = -(-n // chunk) * chunk          # the first chunk of padding only
+    assert whole < T and not np.asarray(got_o[whole:]).any()
 
 
 def test_pallas_update_in_interpret_mode_equals_its_xla_twin():
@@ -550,6 +600,19 @@ def test_engine_greedy_equals_the_reference(served):
     kinds = {kind for kind, _ in eng.obs.steps_dispatched}
     assert {"prefill", "mixed", "decode"} <= kinds
     assert any(behind for _, behind in eng.obs.steps_dispatched)
+
+
+def test_a_warmed_full_window_leaves_slots_and_pages_as_they_were(served):
+    """``warm_full_window`` (the serving CLI's, before it listens) runs a
+    window of padding rows: they write the scrap slot and the scrap page,
+    so the same prompts are served as before it."""
+    eng, want = served
+    eng.warm_full_window()
+    alloc = eng.scheduler.allocator
+    assert alloc.num_free_slots == alloc.num_state_slots - 1
+    assert alloc.num_free == alloc.num_pages - 1
+    outs = eng.generate(PROMPTS, GREEDY)
+    assert [o.output_token_ids for o in outs] == want
 
 
 def test_preemption_by_recompute_frees_pages_and_slot(served):
