@@ -19,6 +19,7 @@ as in reference values-01-minimal-example2.yaml), PP in parallel/pp.py.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import math
@@ -46,6 +47,7 @@ from .kv_cache import (KVCache, KVPageIO, KVTransferPrograms,
                        default_state_slots, derive_num_pages,
                        kv_cache_bytes_per_token, kv_cache_dtype,
                        kv_row_padding_share, state_bytes_per_seq)
+from .mixed_batch import mixed_row_bucket, padding_mixed_batch
 from .sampling_params import LOGIT_BIAS_CAP, SamplingParams
 from .scheduler import CannotChain, ScheduledBatch, Scheduler, _bucket
 from .sequence import FinishReason, Sequence, SequenceStatus
@@ -89,6 +91,7 @@ class RequestOutput:
     # of the N most likely tokens (N = SamplingParams.top_logprobs).
     new_top_logprobs: Optional[list[list[tuple[int, float]]]] = None
     output_top_logprobs: Optional[list[list[tuple[int, float]]]] = None
+    t_ready: Optional[float] = None  # monotonic: new_token_ids' program ended
 
 
 def _prefill_penalties(cfg, logits, int_t, prompt_lens, presence, frequency):
@@ -406,8 +409,6 @@ class LLMEngine:
         # commit/abort; the serving layer owns abort-on-failure.
         self._prefix_imports: dict[str, dict] = {}
         self._prefix_import_seq = 0
-        self._last_step_info = None
-        self._ttft_transfer_s: Optional[float] = None
         # Width of the host->device output-token resync buffer for the
         # penalty histogram (outputs are bounded by the model length).
         self._out_cap = config.effective_max_len
@@ -1922,39 +1923,25 @@ class LLMEngine:
         return True
 
     def step(self) -> list[RequestOutput]:
+        """One iteration of the loop: dispatch the next step program, then
+        fetch the one in flight (``_step``). Every program's time is kept on
+        its own record, not here (``_record``, ``_retired``)."""
         # Chaos site: KGCT_FAULT=step_stall:delay=N sleeps here, simulating a
         # hung device dispatch for the watchdog to catch. One is-armed check
         # when no spec is set — free on the hot path.
         _inject_fault("step_stall")
-        self.obs.phases.start_step()
-        # Set by _step when a device program actually ran this iteration:
-        # (kind, batch_size, decode_mode) — None means an idle/drain-only
-        # call whose timing would pollute the step histograms.
-        self._last_step_info = None
-        # Transfer-only share of the prefill fetch sync, when this step's
-        # prefill measured it (TTFT decomposition).
-        self._ttft_transfer_s = None
-        t0 = time.perf_counter()
         # The phases' parent in a profiler capture: what of "kgct.step" no
         # phase covers (the key split, the drains, a window's unphased
-        # parts) is the step's own time there.
-        with self.obs.phases.span("step", step_num=self.stats.steps):
+        # parts) is the step's own time there. ``launched``: the number this
+        # iteration's launch gets if it schedules a program; ``retired``:
+        # the program it fetches.
+        nxt = self.step_count + 1
+        with self.obs.phases.span(
+                "step", launched=nxt,
+                retired=nxt if self._inflight is None
+                else self._inflight["step"]):
             outs = self._step()
-        dt = time.perf_counter() - t0
         self.stats.steps += 1
-        info = self._last_step_info
-        if info is None:
-            self.obs.phases.discard_step()
-        else:
-            # Mixed/spec steps extend the info tuple with kind-specific
-            # extras (mixed: the prefill/decode token split; spec: the
-            # drafted/accepted token counts).
-            kind, bsize, mode = info[:3]
-            extra = info[3] if len(info) > 3 else {}
-            self.obs.on_step(
-                step=self.step_count, kind=kind, batch=bsize, duration_s=dt,
-                new_tokens=sum(len(o.new_token_ids or []) for o in outs),
-                mode=mode, **extra)
         return outs
 
     def _step(self) -> list[RequestOutput]:
@@ -1981,6 +1968,7 @@ class LLMEngine:
         tokens), a batch that needs a preemption, an import since the last
         schedule, and penalties whose histogram the host must rebuild: each
         counted by reason (``kgct_chain_breaks_total``)."""
+        t_iter = time.monotonic()
         inflight = self._inflight
         outs: list[RequestOutput] = []
         if inflight is None:
@@ -1990,6 +1978,7 @@ class LLMEngine:
             self._mark_in_flight(inflight)
         successor, drained = self._launch(inflight)
         self._inflight = successor
+        inflight["t_iter"] = t_iter
         return outs + drained + self._retire(inflight, successor)
 
     def _chain_break(self, pred: dict) -> Optional[str]:
@@ -2030,11 +2019,17 @@ class LLMEngine:
         now in flight (None: nothing to run, a chain break, or a
         speculative step, which runs to its end here) and the outputs that
         are due at once."""
+        t_launch = time.monotonic()
+        # What this launch spends (schedule, host_prep, device_dispatch) is
+        # filed under the program it launches, not under the one the same
+        # iteration fetches.
+        phases = self.obs.phases.start_step()
         ph = self.obs.phases.phase
         behind = pred is not None
         reason = self._chain_break(pred) if behind else None
         batch = None
         if reason is None:
+            self.obs.step_launching = self.step_count + 1
             try:
                 with ph("schedule"):
                     batch = self.scheduler.schedule(behind=behind)
@@ -2053,20 +2048,77 @@ class LLMEngine:
         with ph("host_prep"):
             float_b = _pack_float_b(batch)
         self.obs.on_step_dispatched(batch.kind, behind)
+        rec = self._record(batch, pred, t_launch, phases)
         if batch.kind in ("spec", "spec_mixed"):
             self.obs.on_chain_break("spec")
             step = (self._step_spec if batch.kind == "spec"
                     else self._step_spec_mixed)
-            return None, outs + step(batch, float_b, step_key)
+            return None, outs + step(rec, float_b, step_key)
         if self._sanitizer is not None:
             self._sanitizer.on_step_dispatch(batch.seqs)
         prev = self._no_pred if pred is None else pred["last"]
         if batch.kind == "decode":
-            rec = self._dispatch_window(batch, prev, float_b, step_key, pred)
+            self._dispatch_window(rec, prev, float_b, step_key, pred)
         else:
-            rec = self._dispatch_prefill(batch, prev, float_b, step_key)
-        rec.update(batch=batch, kind=batch.kind)
+            self._dispatch_prefill(rec, prev, float_b, step_key)
         return rec, outs
+
+    def _record(self, batch: ScheduledBatch, pred: Optional[dict],
+                t_launch: float, phases: list) -> dict:
+        """The record of the program about to be dispatched for ``batch``,
+        the ONE place it is made for all five step bodies: its own number
+        (the ``step_count`` it was given), kind, real rows, the tokens it
+        computes and its bucket's size, whether and behind which program
+        it is queued, and the first of its stamps. The dispatchers add
+        what the fetch needs and ``t_dispatched``; ``_fetching`` adds
+        ``t_wait`` and ``t_ready``; ``_retired`` adds ``t_retired`` and
+        hands it to ``Observability.on_step``."""
+        kind, rows, padded = batch.kind, batch.num_seqs, len(batch.tokens)
+        if kind == "decode":
+            window = self.config.scheduler.decode_window
+            tokens, padded = rows * window, padded * window
+        elif kind == "prefill":
+            tokens = int(np.sum(batch.seg_ids >= 0))
+        elif kind == "mixed":
+            tokens = batch.prefill_token_count + rows - 1
+        else:
+            # verify slices of S tokens a row; spec_mixed: behind the chunk
+            S = batch.spec_S or padded // batch.page_tables.shape[0]
+            tokens = ((rows * S) if kind == "spec"
+                      else batch.prefill_token_count + (rows - 1) * S)
+        return {"step": self.step_count, "kind": kind, "batch": batch,
+                "rows": rows, "tokens": tokens, "padded_tokens": padded,
+                "behind": pred is not None,
+                "pred": None if pred is None else pred["step"],
+                "t_launch": t_launch, "t_iter": t_launch, "phases": phases}
+
+    @contextlib.contextmanager
+    def _fetching(self, rec: dict):
+        """The fetch of the program of ``rec``, as the phase
+        ``device_fetch``: block until it is done, stamping both ends of the
+        wait (they are also the worker's turns into and out of
+        ``device_wait``), then let the caller copy what it needs. Async
+        dispatch means the device COMPUTE completes inside this sync, so
+        what follows ``t_ready`` is the device->host copy alone:
+        ``transfer_s``, the TTFT decomposition's "first_fetch" (the compute
+        is its "prefill" share)."""
+        turn = self.obs.phases.worker_turn
+        with self.obs.phases.phase("device_fetch", rec):
+            rec["t_wait"] = t = time.monotonic()
+            turn("device_wait", t)
+            rec["toks"].block_until_ready()
+            rec["t_ready"] = t = time.monotonic()
+            turn("host", t)
+            yield
+        rec["transfer_s"] = time.monotonic() - rec["t_ready"]
+
+    def _retired(self, rec: dict, outs: list[RequestOutput], **extra) -> None:
+        """Post-processing of the program of ``rec`` is done: the last
+        stamp, what it committed, and the record goes to the accounts."""
+        rec["t_retired"] = time.monotonic()
+        rec["new_tokens"] = sum(len(o.new_token_ids or []) for o in outs)
+        rec.update(extra)
+        self.obs.on_step(rec)
 
     def warm_full_window(self) -> None:
         """Dispatch the greedy decode window over padding rows alone at the
@@ -2085,20 +2137,55 @@ class LLMEngine:
         for _ in range(2):
             # the second behind the first: its cache and ``prev`` are a
             # step's outputs, as every window's under load
-            prev = self._dispatch_window(batch, prev, _pack_float_b(batch),
-                                         jax.random.key(0), None)["last"]
+            rec = self._record(batch, None, t0, [])
+            self._dispatch_window(rec, prev, _pack_float_b(batch),
+                                  jax.random.key(0), None)
+            prev = rec["last"]
         jax.block_until_ready(prev)
         logger.info("the window at %d rows met before the first request: "
                     "%.1f s", len(batch.tokens), time.monotonic() - t0)
 
-    def _dispatch_prefill(self, batch: ScheduledBatch, prev, float_b,
-                          step_key) -> dict:
+    def warm_short_mixed(self) -> None:
+        """Dispatch the mixed step of the smallest prefill bucket beside the
+        largest row bucket over padding alone, and wait for it: the program
+        a server with every seat taken runs when a prompt of a few tokens
+        arrives. Its first use stands every open stream still for 2-3 s
+        with a warm compile cache and ~30 s without (PERF.md section 6,
+        PR 37: the benchmark's probe beside the load met it in four runs of
+        six, and the requests alive then carried the stall in their
+        ``tpot_p90_ms``). As ``warm_full_window``: the scrap page and the
+        scrap slot, no sequence, counter or random key of the engine. No
+        mixed step (pp, sp, ``mixed_batch_enabled`` off) or a speculative
+        one in its place: nothing to meet."""
+        sc = self.config.scheduler
+        if (self._mixed_fn is None or not self.scheduler.mixed_enabled
+                or self.scheduler.spec_enabled):
+            return
+        t0 = time.monotonic()
+        Tp = min(sc.prefill_buckets)
+        batch = padding_mixed_batch(
+            self.scheduler, Tp,
+            mixed_row_bucket(sc.max_num_seqs, Tp, sc.decode_buckets))
+        prev = self._no_pred
+        for _ in range(2):
+            # the second behind the first, as in warm_full_window
+            rec = self._record(batch, None, t0, [])
+            self._dispatch_prefill(rec, prev, _pack_float_b(batch),
+                                   jax.random.key(0))
+            prev = rec["last"]
+        jax.block_until_ready(prev)
+        logger.info("the mixed step of %d tokens beside %d rows met before "
+                    "the first request: %.1f s", Tp,
+                    len(batch.context_lens), time.monotonic() - t0)
+
+    def _dispatch_prefill(self, rec: dict, prev, float_b, step_key) -> None:
         """Dispatch a prefill, a chunk with history or a mixed step; what
-        its fetch needs comes back as the step's record. A partial chunk's
-        sampled row is meaningless (KV committed, prompt unfinished): it
-        goes through the zombie set, so ``_process_window`` skips it with
-        no output, no stats, no stop checks."""
+        its fetch needs goes into the step's record ``rec``. A partial
+        chunk's sampled row is meaningless (KV committed, prompt
+        unfinished): it goes through the zombie set, so ``_process_window``
+        skips it with no output, no stats, no stop checks."""
         ph = self.obs.phases.phase
+        batch = rec["batch"]
         mixed = batch.kind == "mixed"
         with ph("host_prep"):
             int_t = jnp.asarray(np.stack(
@@ -2114,7 +2201,7 @@ class LLMEngine:
                 context_lens = jnp.asarray(batch.context_lens)
         if mixed:
             self.stats.prefill_tokens += batch.prefill_token_count
-            with ph("device_dispatch"):
+            with ph("device_dispatch", rec):
                 (toks, lps, tids, tlps, last, self.kv_cache,
                  *load) = self._mixed_fn(
                     self.params, self.kv_cache, prev, int_t, int_b, float_b,
@@ -2122,8 +2209,8 @@ class LLMEngine:
                     context_lens, out_tokens, bias_ids, bias_vals, step_key)
         elif batch.hist_len is not None:
             # Chunked prefill (solo): the chunk attends to pool history.
-            self.stats.prefill_tokens += int(np.sum(batch.seg_ids >= 0))
-            with ph("device_dispatch"):
+            self.stats.prefill_tokens += rec["tokens"]
+            with ph("device_dispatch", rec):
                 (toks, lps, tids, tlps, last, self.kv_cache,
                  *load) = self._prefill_hist_fn(
                     self.params, self.kv_cache, int_t, int_b, float_b,
@@ -2132,15 +2219,15 @@ class LLMEngine:
         else:
             self.stats.prefill_tokens += sum(
                 s.num_tokens for s in batch.seqs)
-            with ph("device_dispatch"):
+            with ph("device_dispatch", rec):
                 (toks, lps, tids, tlps, last, self.kv_cache,
                  *load) = self._prefill_fn(
                     self.params, self.kv_cache, int_t, int_b, float_b,
                     bias_ids, bias_vals, step_key)
-        zombies = {batch.seqs[-1].request_id} if batch.partial else set()
-        return {"toks": toks, "lps": lps, "tids": tids, "tlps": tlps,
-                "last": last, "load": load, "zombies": zombies,
-                "counts": None}
+        rec.update(t_dispatched=time.monotonic(), toks=toks, lps=lps,
+                   tids=tids, tlps=tlps, last=last, load=load, counts=None,
+                   zombies=({batch.seqs[-1].request_id} if batch.partial
+                            else set()))
 
     def _retire(self, step: dict,
                 successor: Optional[dict]) -> list[RequestOutput]:
@@ -2148,16 +2235,12 @@ class LLMEngine:
         ``successor`` when one was dispatched), commit them, and release
         what no dispatched program can write any more."""
         ph = self.obs.phases.phase
+        # The fetch and the post-processing serve THIS program, whatever
+        # the iteration launched before it came here.
+        self.obs.phases.file_under(step["phases"])
         batch = step["batch"]
         window = step["kind"] == "decode"
-        with ph("device_fetch"):
-            # Async dispatch means the device COMPUTE completes inside this
-            # sync; split it from the device->host transfer so the TTFT
-            # decomposition's "prefill" carries the compute and
-            # "first_fetch" only the copy.
-            t0f = time.perf_counter()
-            step["toks"].block_until_ready()
-            compute_s = time.perf_counter() - t0f
+        with self._fetching(step):
             toks = np.asarray(step["toks"])
             lps = np.asarray(step["lps"])
             top_i = top_l = None
@@ -2173,10 +2256,6 @@ class LLMEngine:
                 self.obs.on_expert_load(
                     step["load"], model_lib.grouped_dispatch(
                         len(batch.tokens), self.model_config, self.kernels))
-        if not window:
-            self._ttft_transfer_s = max(
-                self.obs.phases.current_durs.get("device_fetch", 0.0)
-                - compute_s, 0.0)
         if self._sanitizer is not None:
             self._sanitizer.on_step_retire()
         for seq in batch.seqs:
@@ -2185,8 +2264,7 @@ class LLMEngine:
                    else frozenset(map(id, successor["batch"].seqs)))
         with ph("postproc"):
             outputs = self._process_window(
-                batch, toks, lps, step["zombies"], carried,
-                top_ids=top_i, top_lps=top_l)
+                step, toks, lps, carried, top_ids=top_i, top_lps=top_l)
             if successor is not None:
                 successor["zombies"].update(
                     s.request_id for s in successor["batch"].seqs
@@ -2198,23 +2276,13 @@ class LLMEngine:
                 # histogram along, donated: ``_dispatch_window``)
                 self._counts_pool[counts.shape[0]] = counts
             self._drain_deferred(carried)
+        extra = self._routed(step["tokens"])
         if window:
-            self._last_step_info = (
-                "decode", batch.num_seqs,
-                "greedy" if step["greedy"] else "sampled",
-                self._routed(batch.num_seqs
-                             * self.config.scheduler.decode_window))
+            extra["mode"] = "greedy" if step["greedy"] else "sampled"
         elif step["kind"] == "mixed":
-            self._last_step_info = (
-                "mixed", batch.num_seqs, None,
-                {"prefill_tokens": batch.prefill_token_count,
-                 "decode_tokens": batch.num_seqs - 1,
-                 **self._routed(batch.prefill_token_count
-                                + batch.num_seqs - 1)})
-        else:
-            self._last_step_info = (
-                "prefill", batch.num_seqs, None,
-                self._routed(int(np.sum(batch.seg_ids >= 0))))
+            extra.update(prefill_tokens=batch.prefill_token_count,
+                         decode_tokens=batch.num_seqs - 1)
+        self._retired(step, outputs, **extra)
         return outputs
 
     def _mark_in_flight(self, step: dict) -> None:
@@ -2239,8 +2307,7 @@ class LLMEngine:
         return {"routed_pairs": tokens * m.num_experts_per_tok
                 * (m.num_layers - m.num_dense_layers)}
 
-    def _step_spec(self, batch: ScheduledBatch, float_b,
-                   step_key) -> list[RequestOutput]:
+    def _step_spec(self, rec: dict, float_b, step_key) -> list[RequestOutput]:
         """Execute one speculative-verification step and commit its
         results: every row advances by ``accepted + 1`` tokens (the
         accepted draft prefix plus the resample-or-bonus token), appended
@@ -2252,6 +2319,7 @@ class LLMEngine:
         length and the next step's append overwrites them before any read
         (the verifier module documents the invariant; tests pin it)."""
         ph = self.obs.phases.phase
+        batch = rec["batch"]
         R_pad = batch.page_tables.shape[0]
         S = len(batch.tokens) // R_pad
         # Chaos site: KGCT_FAULT=kv_commit_stomp corrupts one KV write slot
@@ -2271,13 +2339,14 @@ class LLMEngine:
             context_lens = jnp.asarray(batch.context_lens)
             out_tokens = self._penalty_out_tokens(batch)
             bias_ids, bias_vals = self._bias_arrays(batch)
-        with ph("device_dispatch"):
+        with ph("device_dispatch", rec):
             (toks, n_acc, lps, tids, tlps,
              self.kv_cache) = self._spec_verify_fn(
                 self.params, self.kv_cache, int_t, int_b, float_b,
                 page_tables, context_lens, out_tokens, bias_ids, bias_vals,
                 step_key)
-        with ph("device_fetch"):
+        rec.update(t_dispatched=time.monotonic(), toks=toks, zombies=set())
+        with self._fetching(rec):
             toks_np = np.asarray(toks)
             n_acc_np = np.asarray(n_acc)
             lps_np = np.asarray(lps)
@@ -2302,13 +2371,12 @@ class LLMEngine:
             # (past each row's accepted prefix) become stale in the shadow.
             self._sanitizer.on_spec_commit(batch, emit)
         with ph("postproc"):
-            outs = self._process_window(batch, toks_np, lps_np, set(),
+            outs = self._process_window(rec, toks_np, lps_np,
                                         top_ids=top_i, top_lps=top_l,
                                         emit_counts=emit)
-        self._last_step_info = (
-            "spec", B, "greedy" if greedy else "sampled",
-            {"drafted_tokens": drafted, "accepted_tokens": accepted,
-             "draft_s": batch.draft_time_s})
+        self._retired(rec, outs, mode="greedy" if greedy else "sampled",
+                      drafted_tokens=drafted, accepted_tokens=accepted,
+                      draft_s=batch.draft_time_s)
         return outs
 
     def _observe_spec_outcome(self, drafted: int, accepted: int) -> None:
@@ -2320,7 +2388,7 @@ class LLMEngine:
         ctrl.observe(drafted, accepted)
         self.obs.spec_current_k = ctrl.current_k
 
-    def _step_spec_mixed(self, batch: ScheduledBatch, float_b,
+    def _step_spec_mixed(self, rec: dict, float_b,
                          step_key) -> list[RequestOutput]:
         """Execute one spec×mixed step: every running row advances by
         ``accepted + 1`` tokens (the spec path's commit) AND the queue-head
@@ -2331,6 +2399,7 @@ class LLMEngine:
         slots roll back by the same overwrite-before-read contract the
         pure spec step pins."""
         ph = self.obs.phases.phase
+        batch = rec["batch"]
         chunk_seq = batch.seqs[-1]
         decode_seqs = batch.seqs[:-1]
         D = len(decode_seqs)
@@ -2359,18 +2428,15 @@ class LLMEngine:
             out_tokens = self._penalty_out_tokens(batch)
             bias_ids, bias_vals = self._bias_arrays(batch)
         self.stats.prefill_tokens += batch.prefill_token_count
-        with ph("device_dispatch"):
+        with ph("device_dispatch", rec):
             (toks, n_acc, lps, tids, tlps,
              self.kv_cache) = self._spec_mixed_fn(
                 self.params, self.kv_cache, S, int_t, logits_idx, int_b,
                 float_b, chunk_pt, jnp.int32(batch.hist_len), page_tables,
                 context_lens, out_tokens, bias_ids, bias_vals, step_key)
-        with ph("device_fetch"):
-            # Compute/transfer split for the TTFT decomposition — the
-            # chunk's first token may land this step, like mixed.
-            t0f = time.perf_counter()
-            toks.block_until_ready()
-            compute_s = time.perf_counter() - t0f
+        zombies = {chunk_seq.request_id} if batch.partial else set()
+        rec.update(t_dispatched=time.monotonic(), toks=toks, zombies=zombies)
+        with self._fetching(rec):
             toks_np = np.asarray(toks)
             n_acc_np = np.asarray(n_acc)
             lps_np = np.asarray(lps)
@@ -2378,9 +2444,6 @@ class LLMEngine:
             if any(s.params.top_logprobs for s in batch.seqs):
                 top_i = np.asarray(tids)
                 top_l = np.asarray(tlps)
-        self._ttft_transfer_s = max(
-            self.obs.phases.current_durs.get("device_fetch", 0.0)
-            - compute_s, 0.0)
         # Host row view: the D real verify rows, then the chunk's device
         # row (R_pad) — matching batch.seqs order for _process_window.
         sel = list(range(D)) + [R_pad]
@@ -2398,17 +2461,15 @@ class LLMEngine:
         self._observe_spec_outcome(drafted, accepted)
         if self._sanitizer is not None:
             self._sanitizer.on_spec_commit(batch, emit)
-        zombies = {chunk_seq.request_id} if batch.partial else set()
         with ph("postproc"):
-            outs = self._process_window(batch, toks_np, lps_np, zombies,
+            outs = self._process_window(rec, toks_np, lps_np,
                                         top_ids=top_i, top_lps=top_l,
                                         emit_counts=emit)
-        self._last_step_info = (
-            "spec_mixed", batch.num_seqs, "greedy" if greedy else "sampled",
-            {"prefill_tokens": batch.prefill_token_count,
-             "decode_tokens": int(emit[:D].sum()),
-             "drafted_tokens": drafted, "accepted_tokens": accepted,
-             "draft_s": batch.draft_time_s})
+        self._retired(rec, outs, mode="greedy" if greedy else "sampled",
+                      prefill_tokens=batch.prefill_token_count,
+                      decode_tokens=int(emit[:D].sum()),
+                      drafted_tokens=drafted, accepted_tokens=accepted,
+                      draft_s=batch.draft_time_s)
         return outs
 
     def _bias_arrays(self, batch: ScheduledBatch):
@@ -2448,13 +2509,14 @@ class LLMEngine:
             out[s, :len(ids)] = ids
         return jnp.asarray(out)
 
-    def _dispatch_window(self, batch: ScheduledBatch, prev, float_b,
-                         step_key, pred: Optional[dict]) -> dict:
-        """Dispatch a decode window; what its fetch needs comes back as
-        the step's record. Behind a window over the very same rows the
+    def _dispatch_window(self, rec: dict, prev, float_b, step_key,
+                         pred: Optional[dict]) -> None:
+        """Dispatch a decode window; what its fetch needs goes into the
+        step's record ``rec``. Behind a window over the very same rows the
         penalty histogram rides along on the device (``counts``, donated:
         it already holds the tokens in flight)."""
         ph = self.obs.phases.phase
+        batch = rec["batch"]
         if self._sanitizer is not None:
             self._sanitizer.on_decode_dispatch(
                 batch.seqs, batch.positions,
@@ -2471,7 +2533,7 @@ class LLMEngine:
                   and not np.any(batch.frequency)
                   and not any(s.params.logit_bias for s in batch.seqs))
         if greedy:
-            with ph("device_dispatch"):
+            with ph("device_dispatch", rec):
                 (dev_out, dev_lp, dev_tid, dev_tlp, last,
                  self.kv_cache) = self._decode_fn_greedy(
                     self.params, self.kv_cache, prev, int_b, float_b,
@@ -2509,27 +2571,30 @@ class LLMEngine:
                     B, jnp.full((B, self._out_cap), -1, jnp.int32))
             with ph("host_prep"):
                 bias_ids, bias_vals = self._bias_arrays(batch)
-            with ph("device_dispatch"):
+            with ph("device_dispatch", rec):
                 (dev_out, dev_lp, dev_tid, dev_tlp, last, self.kv_cache,
                  counts) = self._decode_fn(
                     self.params, self.kv_cache, prev, int_b, float_b,
                     step_key, counts, out_tokens, jnp.asarray(rebuild),
                     bias_ids, bias_vals)
-        return {"toks": dev_out, "lps": dev_lp, "tids": dev_tid,
-                "tlps": dev_tlp, "last": last, "load": (), "zombies": set(),
-                "counts": counts, "greedy": greedy}
+        rec.update(t_dispatched=time.monotonic(), toks=dev_out, lps=dev_lp,
+                   tids=dev_tid, tlps=dev_tlp, last=last, load=(),
+                   zombies=set(), counts=counts, greedy=greedy)
 
-    def _process_window(self, batch: ScheduledBatch, next_tokens: np.ndarray,
-                        logprobs: np.ndarray, zombies: set,
+    def _process_window(self, rec: dict, next_tokens: np.ndarray,
+                        logprobs: np.ndarray,
                         carried: frozenset = frozenset(),
                         top_ids: Optional[np.ndarray] = None,
                         top_lps: Optional[np.ndarray] = None,
                         emit_counts: Optional[np.ndarray] = None,
                         ) -> list[RequestOutput]:
-        """next_tokens/logprobs: [B_pad, W]. Append window tokens per sequence
+        """``rec``: the record of the program whose tokens these are (its
+        batch, its zombies, its number and stamps for the first-token event
+        and the frame delay). next_tokens/logprobs: [B_pad, W]. Append
+        window tokens per sequence
         until a stop condition fires; tokens generated past the stop are
         discarded.
-        ``zombies`` (request ids finished in an earlier step of the chain,
+        Its ``zombies`` (request ids finished in an earlier step of the chain,
         and a partial chunk's row) are skipped. ``carried``: the ``id`` of
         every sequence that the successor, already dispatched, has a row
         for: one of them that finishes here keeps its pages and slot until
@@ -2540,6 +2605,7 @@ class LLMEngine:
         # Chaos site: KGCT_FAULT=nan_step_output poisons the fetched
         # logprobs — the corruption class the KGCT_SANITIZE step-output
         # guard must catch before any client sees it.
+        batch, zombies = rec["batch"], rec["zombies"]
         if _inject_fault("nan_step_output"):
             logprobs = np.full_like(np.asarray(logprobs, np.float32), np.nan)
         if self._sanitizer is not None:
@@ -2593,14 +2659,10 @@ class LLMEngine:
             self.stats.tokens_generated += len(new_tokens)
             if not had_first and seq.first_token_time is not None:
                 # TTFT decomposition: under async dispatch the device
-                # compute completes inside the fetch sync, so the prefill
-                # path measures the transfer-only share separately — falling
-                # back to the whole fetch phase when it did not.
-                fetch_s = self._ttft_transfer_s
-                if fetch_s is None:
-                    fetch_s = self.obs.phases.current_durs.get(
-                        "device_fetch", 0.0)
-                self.obs.on_first_token(seq, fetch_s=fetch_s)
+                # compute completes inside the fetch sync, so "first_fetch"
+                # is the copy alone, from the program's t_ready on.
+                self.obs.on_first_token(seq, fetch_s=rec["transfer_s"],
+                                        step=rec["step"])
             if seq.is_finished:
                 self.stats.requests_finished += 1
             outputs.append(RequestOutput(
@@ -2615,7 +2677,8 @@ class LLMEngine:
                                  if want_lps else None),
                 new_top_logprobs=new_tops if want_top else None,
                 output_top_logprobs=(list(seq.output_top_logprobs)
-                                     if seq.params.top_logprobs else None)))
+                                     if seq.params.top_logprobs else None),
+                t_ready=rec["t_ready"]))
         return outputs
 
     def _drain_terminally_finished(self) -> list[RequestOutput]:

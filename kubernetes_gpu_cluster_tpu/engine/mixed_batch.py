@@ -282,6 +282,33 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
         **sched._sampling_arrays(seqs, R_pad))
 
 
+def padding_mixed_batch(sched: "Scheduler", Tp: int,
+                        R_pad: int) -> "ScheduledBatch":
+    """A mixed step's batch at the chunk bucket ``Tp`` beside ``R_pad`` rows
+    with no sequence at all (what ``LLMEngine.warm_short_mixed``
+    dispatches): a chunk of one token with no history whose page table is
+    one page wide, as a prompt inside a page has it, written like every
+    padding token and row to the scrap page and the scrap slot."""
+    from .scheduler import ScheduledBatch
+
+    T_pad = Tp + R_pad
+    seg_ids = np.full(T_pad, -1, np.int32)
+    seg_ids[0] = 0
+    pages_bucket = cdiv(sched.config.effective_max_len, sched.page_size)
+    return ScheduledBatch(
+        kind="mixed", seqs=[], tokens=np.zeros(T_pad, np.int32),
+        positions=np.zeros(T_pad, np.int32),
+        slot_mapping=np.zeros(T_pad, np.int32), seg_ids=seg_ids,
+        logits_indices=np.zeros(R_pad, np.int32),
+        page_tables=np.zeros((R_pad, pages_bucket), np.int32),
+        context_lens=np.zeros(R_pad, np.int32),
+        tok_src=np.full(R_pad, -1, np.int32),
+        chunk_page_table=np.zeros((1, 1), np.int32), hist_len=0,
+        seg_slots=sched._state_slots([], R_pad),
+        row_slots=sched._state_slots([], R_pad),
+        **sched._sampling_arrays([], R_pad))
+
+
 def build_spec_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     """Spec×mixed composition: one device step carrying every running row's
     ``[last, d_1..d_k]`` VERIFY SLICE plus the budgeted chunk of the

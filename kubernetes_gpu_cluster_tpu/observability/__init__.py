@@ -7,6 +7,19 @@ trace ring via /debug/trace; bench.py reads the TTFT decomposition deques.
 Everything here is bounded (rings + fixed-bucket histograms) and lock-free
 on the hot path — the engine step loop must never block on observability.
 
+One iteration of the step loop (``LLMEngine.step()``) dispatches program n+1
+and then fetches program n (the one-deep device queue, PR 33), so step
+accounting goes by PROGRAM, not by iteration: ``on_step`` takes the record
+of the program just retired (``step`` its own number, ``kind``, ``rows``,
+``tokens``, ``padded_tokens``, ``behind``, and the stamps ``t_launch``,
+``t_dispatched``, ``t_wait``, ``t_ready``, ``t_retired`` on
+``time.monotonic``; phases.py says what is derived from them), files the
+phases that served it under its number in ``/debug/trace`` and fills the
+always-on series ``kgct_step_device_seconds``, ``kgct_steps_retired_total``,
+``kgct_step_slow_*``, ``kgct_step_tokens_total``; the worker's three states
+(``kgct_worker_seconds_total``) and the frame delay
+(``kgct_frame_delay_seconds``) are kept beside them.
+
 Disable entirely with ``KGCT_TRACE=0`` (hooks become cheap early-returns;
 histograms still fill — they are the /metrics contract). The black-box
 flight recorder (flightrecorder.py) mirrors the same events into its own
@@ -24,9 +37,9 @@ from typing import Optional
 import numpy as np
 
 from .flightrecorder import FlightRecorder
-from .phases import PHASES, StepPhaseStats
-from .prometheus import (BATCH_BUCKETS, LATENCY_BUCKETS_S, Histogram, fmt,
-                         render_gauge)
+from .phases import PHASES, STEP_KINDS, WORKER_STATES, StepPhaseStats
+from .prometheus import (BATCH_BUCKETS, LATENCY_BUCKETS_S, STEP_BUCKETS_S,
+                         Histogram, fmt, render_gauge)
 from .trace import EVENT_KINDS, RequestTracer, merge_perfetto
 
 __all__ = ["Observability", "Histogram", "RequestTracer", "StepPhaseStats",
@@ -123,6 +136,10 @@ class SLOTracker:
         self._window_start = time.monotonic()
 
 
+def _ms(seconds: Optional[float]) -> Optional[float]:
+    return None if seconds is None else round(seconds * 1e3, 3)
+
+
 def _outcome(seq, reason) -> str:
     """finished | aborted | preempted — the label the e2e/TTFT-facing series
     carry. A request that was ever preempted finished late through no fault
@@ -173,7 +190,30 @@ class Observability:
         self.prefill_latency = Histogram(
             "kgct_prefill_seconds", "scheduling to first token, minus fetch")
         self.step_duration = Histogram(
-            "kgct_step_seconds", "engine step wall time")
+            "kgct_step_seconds", "one iteration of the step loop (dispatch "
+            "of program n+1, then fetch and post-processing of program n)")
+        # The program's own clock (phases.StepPhaseStats.retire): device
+        # time of the programs whose both ends the host saw, by step kind.
+        self.step_device = Histogram(
+            "kgct_step_device_seconds", "device time of a step program, "
+            "end of its predecessor (or its own dispatch) to its own end; "
+            "only programs that were waited for, behind one that was",
+            buckets=STEP_BUCKETS_S, labels=("kind",))
+        # (kind, waited) -> programs retired; waited False: found ready.
+        self.steps_retired: dict[tuple[str, bool], int] = {}
+        # cause -> [seconds, count] of ready gaps that counted as slow.
+        self.step_slow = {"host": [0.0, 0], "device": [0.0, 0]}
+        # (kind, real) -> tokens the programs computed; real False: the
+        # bucket's padding.
+        self.step_tokens: dict[tuple[str, bool], int] = {}
+        # Per frame the event loop wrote: now - t_ready of the program whose
+        # tokens it carries (the HTTP layer's own share of a token's gap).
+        self.frame_delay = Histogram(
+            "kgct_frame_delay_seconds", "end of a step program to the "
+            "write of the frame that carries its tokens")
+        # The number the program being scheduled will get: the request
+        # events of a schedule() name the step that serves them.
+        self.step_launching = 0
         self.batch_size = Histogram(
             "kgct_batch_size_per_step", "real sequences per engine step",
             buckets=BATCH_BUCKETS)
@@ -192,8 +232,7 @@ class Observability:
         # Mixed (stall-free) batching: device steps by kind plus the
         # cumulative prefill/decode token split of mixed steps — feeds the
         # kgct_mixed_step_ratio gauge and the bench mixed readout.
-        self.step_kind_counts = {"prefill": 0, "decode": 0, "mixed": 0,
-                                 "spec": 0, "spec_mixed": 0}
+        self.step_kind_counts = {kind: 0 for kind in STEP_KINDS}
         self.mixed_prefill_tokens = 0
         self.mixed_decode_tokens = 0
         # The one-deep device queue (engine._step): step programs
@@ -310,11 +349,13 @@ class Observability:
             seq.scheduled_time = time.monotonic()
             self.queue_wait.observe(seq.scheduled_time - seq.arrival_time)
         self.tracer.emit("resume" if resumed else "scheduled",
-                         seq.request_id, batch=n_batch)
+                         seq.request_id, batch=n_batch,
+                         step=self.step_launching)
 
     def on_prefill_chunk(self, seq, start: int, end: int, total: int) -> None:
         self.tracer.emit("prefill_chunk", seq.request_id,
-                         start=start, end=end, total=total)
+                         start=start, end=end, total=total,
+                         step=self.step_launching)
 
     def on_preempt(self, seq, kind: str = "recompute") -> None:
         seq.preempt_count += 1
@@ -378,7 +419,8 @@ class Observability:
         self.spec_draft_tokens += n_tokens
         self.spec_draft_latency.observe(duration_s)
 
-    def on_first_token(self, seq, fetch_s: float = 0.0) -> None:
+    def on_first_token(self, seq, fetch_s: float = 0.0,
+                       step: Optional[int] = None) -> None:
         ttft = seq.first_token_time - seq.arrival_time
         self.ttft.observe(ttft, (_outcome(seq, None),))
         self.slo.on_first_token(ttft)
@@ -394,7 +436,7 @@ class Observability:
         self.ttft_prefill_s.append(prefill)
         self.ttft_fetch_s.append(fetch_s)
         self.tracer.emit("first_token", seq.request_id,
-                         ttft_ms=round(ttft * 1e3, 2))
+                         ttft_ms=round(ttft * 1e3, 2), step=step)
 
     def on_handoff_first_token(self, seq, ttft_s: float) -> None:
         """Disaggregated import: the first token(s) arrived WITH the KV
@@ -485,70 +527,92 @@ class Observability:
     def on_chain_break(self, reason: str) -> None:
         self.chain_breaks[reason] = self.chain_breaks.get(reason, 0) + 1
 
-    def on_step(self, step: int, kind: str, batch: int, duration_s: float,
-                new_tokens: int, mode: str = None, prefill_tokens: int = 0,
-                decode_tokens: int = 0, drafted_tokens: int = 0,
-                accepted_tokens: int = 0, draft_s: float = 0.0,
-                routed_pairs: int = 0) -> None:
+    def on_step(self, rec: dict) -> None:
+        """``rec``: the record of the program just retired (the module
+        docstring names its keys; ``t_iter`` is where the iteration that
+        retired it began, ``new_tokens`` what it committed, and a kind's
+        extras ride along: ``mode``, ``prefill_tokens``, ``decode_tokens``,
+        ``drafted_tokens``, ``accepted_tokens``, ``draft_s``,
+        ``routed_pairs``). Its phases, whichever iteration ran them, go to
+        the trace under ITS number."""
         # Flight-recorder state snapshot, at most once per interval: one
         # monotonic read per step when nothing is due.
         self.flight.maybe_snapshot()
+        self.phases.retire(rec)
+        kind, rows = rec["kind"], rec["rows"]
+        # One iteration of the loop: it dispatched this program's successor
+        # and then fetched this one.
+        duration_s = rec["t_retired"] - rec["t_iter"]
         self.step_duration.observe(duration_s)
-        self.batch_size.observe(batch)
-        self.phases.end_step(step=step, kind=kind, batch=batch,
-                             duration_s=duration_s)
+        self.batch_size.observe(rows)
+        if rec["exact"]:
+            self.step_device.observe(rec["device_s"], (kind,))
+        key = (kind, not rec["found_ready"])
+        self.steps_retired[key] = self.steps_retired.get(key, 0) + 1
+        if rec["slow"] is not None:
+            cell = self.step_slow[rec["slow"]]
+            cell[0] += rec["ready_gap_s"]
+            cell[1] += 1
+        for real, n in ((True, rec["tokens"]),
+                        (False, rec["padded_tokens"] - rec["tokens"])):
+            self.step_tokens[(kind, real)] = (
+                self.step_tokens.get((kind, real), 0) + n)
+        timing = {"device_ms": _ms(rec["device_s"]), "exact": rec["exact"],
+                  "wait_ms": _ms(rec["wait_s"]), "lead_ms": _ms(rec["lead_s"])}
+        self.phases.end_step(step=rec["step"], kind=kind, batch=rows,
+                             duration_s=duration_s, phases=rec["phases"],
+                             **timing)
         if kind in self.step_kind_counts:
             self.step_kind_counts[kind] += 1
+        routed_pairs = rec.get("routed_pairs", 0)
         if routed_pairs:
             self.moe_routed_pairs[kind] = (
                 self.moe_routed_pairs.get(kind, 0) + routed_pairs)
+        new_tokens = rec.get("new_tokens", 0)
+        mode = rec.get("mode") or "greedy"
+        prefill_tokens = rec.get("prefill_tokens", 0)
+        decode_tokens = rec.get("decode_tokens", 0)
+        drafted_tokens = rec.get("drafted_tokens", 0)
+        accepted_tokens = rec.get("accepted_tokens", 0)
+        # The step event (trace ring and flight recorder): the program's
+        # own number, kind, rows and clock, then what its kind adds.
+        event = {"step": rec["step"], "batch": rows, **timing}
         if kind == "decode":
-            self.tracer.emit("decode", "", batch=batch, tokens=new_tokens,
-                             mode=mode or "greedy")
+            event.update(tokens=new_tokens, mode=mode)
             if mode in self.decode_mode_tokens:
                 self.decode_mode_tokens[mode] += new_tokens
                 self.decode_mode_wall_s[mode] += duration_s
-        elif kind == "mixed":
+        if kind in ("mixed", "spec_mixed"):
             # The stall-free batching signal: how this step's token budget
-            # split between the prefill chunk and the decode rows.
+            # split between the prefill chunk and the decode rows (a
+            # spec_mixed step IS a stall-free step and counts both ways).
             self.mixed_prefill_tokens += prefill_tokens
             self.mixed_decode_tokens += decode_tokens
-            self.tracer.emit("mixed", "", batch=batch,
-                             prefill_tokens=prefill_tokens,
-                             decode_tokens=decode_tokens)
-        elif kind == "spec":
+            event.update(prefill_tokens=prefill_tokens)
+            if kind == "mixed":
+                event.update(decode_tokens=decode_tokens)
+        if kind in ("spec", "spec_mixed"):
             # The speculative-decoding signal: of the drafts this step
             # verified, how many committed (emitted tokens = accepted +
             # one bonus per row; new_tokens carries the realized total).
             # draft/verify phase attribution: the draft half is the
             # proposer-seam wall time, the verify half is the rest of the
             # step (dispatch + fetch of the one verify program).
+            draft_s = rec.get("draft_s", 0.0)
             self.spec_drafted_tokens += drafted_tokens
             self.spec_accepted_tokens += accepted_tokens
-            self.tracer.emit("spec", "", batch=batch, tokens=new_tokens,
-                             drafted=drafted_tokens, accepted=accepted_tokens,
-                             mode=mode or "greedy",
-                             draft_ms=round(draft_s * 1e3, 3),
-                             verify_ms=round(
-                                 max(duration_s - draft_s, 0.0) * 1e3, 3))
-        elif kind == "spec_mixed":
-            # The composition step counts BOTH ways: its chunk/verify token
-            # split feeds the mixed-batching counters (a spec_mixed step IS
-            # a stall-free step) and its draft outcome feeds the spec
-            # acceptance counters.
-            self.mixed_prefill_tokens += prefill_tokens
-            self.mixed_decode_tokens += decode_tokens
-            self.spec_drafted_tokens += drafted_tokens
-            self.spec_accepted_tokens += accepted_tokens
-            self.tracer.emit("spec_mixed", "", batch=batch,
-                             tokens=new_tokens,
-                             prefill_tokens=prefill_tokens,
-                             drafted=drafted_tokens,
-                             accepted=accepted_tokens,
-                             mode=mode or "greedy",
-                             draft_ms=round(draft_s * 1e3, 3),
-                             verify_ms=round(
-                                 max(duration_s - draft_s, 0.0) * 1e3, 3))
+            event.update(tokens=new_tokens, drafted=drafted_tokens,
+                         accepted=accepted_tokens, mode=mode,
+                         draft_ms=round(draft_s * 1e3, 3),
+                         verify_ms=round(
+                             max(duration_s - draft_s, 0.0) * 1e3, 3))
+        self.tracer.emit(kind, "", **event)
+
+    def on_frame(self, t_ready: Optional[float]) -> None:
+        """The event loop wrote a frame whose tokens the program that was
+        ready at ``t_ready`` (``time.monotonic``) produced."""
+        if t_ready is not None:
+            self.frame_delay.observe(time.monotonic() - t_ready)
 
     def mixed_step_ratio(self):
         """Fraction of device steps that carried a prefill chunk alongside
@@ -669,6 +733,42 @@ class Observability:
         for reason, n in sorted(self.chain_breaks.items()):
             lines.append('kgct_chain_breaks_total{reason="%s"} %d'
                          % (reason, n))
+        lines.extend(self.step_device.render())
+        lines.append("# HELP kgct_steps_retired_total step programs retired, "
+                     "by kind; waited=0: found ready, the host came after "
+                     "the chip")
+        lines.append("# TYPE kgct_steps_retired_total counter")
+        for (kind, waited), n in sorted(self.steps_retired.items()):
+            lines.append(
+                'kgct_steps_retired_total{kind="%s",waited="%d"} %d'
+                % (kind, waited, n))
+        lines.append("# HELP kgct_step_slow_seconds_total gap between the "
+                     "ends of two step programs where it was over 0.5 s and "
+                     "3x its kind's mean; cause=host: the program was found "
+                     "ready or dispatched late, device: it was waited for")
+        lines.append("# TYPE kgct_step_slow_seconds_total counter")
+        for cause in sorted(self.step_slow):
+            lines.append('kgct_step_slow_seconds_total{cause="%s"} %s'
+                         % (cause, fmt(round(self.step_slow[cause][0], 6))))
+        lines.append("# TYPE kgct_step_slow_total counter")
+        for cause in sorted(self.step_slow):
+            lines.append('kgct_step_slow_total{cause="%s"} %d'
+                         % (cause, self.step_slow[cause][1]))
+        lines.append("# HELP kgct_step_tokens_total tokens the step programs "
+                     "computed, by kind; real=0: the bucket's padding")
+        lines.append("# TYPE kgct_step_tokens_total counter")
+        for (kind, real), n in sorted(self.step_tokens.items()):
+            lines.append('kgct_step_tokens_total{kind="%s",real="%d"} %d'
+                         % (kind, real, n))
+        lines.append("# HELP kgct_worker_seconds_total the step loop "
+                     "thread's wall by state: device_wait (blocked for a "
+                     "program), inbox_wait (idle), host (everything else)")
+        lines.append("# TYPE kgct_worker_seconds_total counter")
+        worker = self.phases.worker_seconds()
+        for state in WORKER_STATES:
+            lines.append('kgct_worker_seconds_total{state="%s"} %s'
+                         % (state, fmt(round(worker[state], 6))))
+        lines.extend(self.frame_delay.render())
         if self.moe_routed_pairs:
             lines.append("# HELP kgct_moe_routed_pairs_total (token, expert) "
                          "pairs sent through the expert layers, by step kind")

@@ -22,6 +22,12 @@ from typing import Optional
 LATENCY_BUCKETS_S = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                      0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 
+# Device time of one step program: 1-2-5 from 1 ms to 10 s (a decode window
+# of 100-220 ms and a mixed step of 50-140 ms fall in different buckets; the
+# mean comes from _sum/_count either way).
+STEP_BUCKETS_S = (0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5,
+                  1.0, 2.0, 5.0, 10.0)
+
 # Batch-size-per-step buckets: powers of two matching the scheduler's padded
 # decode buckets, so the histogram reads as "which compiled shape ran".
 BATCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0, 512.0)

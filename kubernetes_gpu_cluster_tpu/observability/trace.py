@@ -10,7 +10,9 @@ deque append is atomic, and the exporter snapshots with list()).
 Export is Chrome/Perfetto trace-event JSON (``GET /debug/trace``): each
 request becomes an async span (``ph: b/n/e`` keyed by request id) on the
 "requests" track, and each engine step's phase timings (phases.py) become
-complete slices (``ph: X``) on the "engine.step" track — load the file in
+complete slices (``ph: X``) on the "engine.step" track, each under the
+number and kind of the PROGRAM it served (an iteration's dispatch slices
+belong to program n+1, its fetch slices to program n) — load the file in
 https://ui.perfetto.dev and TTFT decomposes visually into queue wait,
 prefill, and fetch.
 """
@@ -22,10 +24,14 @@ import time
 from collections import deque
 from typing import Optional
 
-# Typed event kinds (the request lifecycle, in rough order). "decode",
-# "mixed" and "spec" are engine-wide per-step events (empty request id); a
-# "mixed" event carries the step's prefill/decode token split, a "spec"
-# event the drafted/accepted draft-token counts. "preempt" carries the
+# Typed event kinds (the request lifecycle, in rough order). "prefill",
+# "decode", "mixed", "spec" and "spec_mixed" are engine-wide events, one per
+# step PROGRAM retired (empty request id): each carries the program's own
+# number (``step``), its rows and its clock (device_ms, wait_ms, lead_ms:
+# phases.StepPhaseStats.retire); a "mixed" event adds the step's
+# prefill/decode token split, a "spec" event the drafted/accepted
+# draft-token counts. "scheduled", "resume", "prefill_chunk" and
+# "first_token" name the program that served them (``step``). "preempt" carries the
 # preemption kind (recompute|swap) and "swap" a two-tier KV transfer's
 # direction + page count, "handoff" a disaggregated KV handoff
 # (side=export|import, outcome/bytes/ms). The router's span stream reuses the same
@@ -33,7 +39,8 @@ from typing import Optional
 # hit/overflow/remap), "connect_retry" (connect-phase failover), "ttfb"
 # (upstream headers latency), "relay" (stream relay complete, bytes).
 EVENT_KINDS = ("arrival", "queued", "scheduled", "prefill_chunk",
-               "first_token", "decode", "mixed", "spec", "spec_mixed",
+               "first_token", "prefill", "decode", "mixed", "spec",
+               "spec_mixed",
                "preempt", "swap", "handoff", "migrate", "resume", "finish",
                "abort", "pick", "connect_retry", "ttfb", "relay", "failover")
 
@@ -162,7 +169,8 @@ class RequestTracer:
                     {"name": name, "cat": "step", "ph": "X", "pid": 1,
                      "tid": 2, "ts": us(start), "dur": round(dur * 1e6, 1),
                      "args": {"step": rec["step"], "kind": rec["kind"],
-                              "batch": rec["batch"]}})
+                              "batch": rec["batch"],
+                              **rec.get("args", {})}})
         return {"traceEvents": trace_events, "displayTimeUnit": "ms",
                 "kgctT0Unix": t0_unix}
 

@@ -65,6 +65,7 @@ from ..resilience.drain import drain_and_notify
 from ..resilience.faults import fault_value as _fault_value
 from ..resilience.faults import inject as _inject_fault
 from ..utils import get_logger
+from ..utils.stack import roomy_stack
 from .async_engine import AsyncLLMEngine
 from ..engine.qos import resolve_tier_name, tenant_key_of
 from .errors import (MIGRATE_URL_HEADER, PREFILL_URL_HEADER,
@@ -834,6 +835,15 @@ class APIServer:
         finally:
             phases.record("detokenize", time.perf_counter() - t0)
 
+    async def _write_frame(self, resp, body: dict, chunk) -> None:
+        """Write one SSE frame of a stream (the span ``kgct.http.write`` in
+        a capture), and hold the moment it is written against the end of
+        the step program that produced its tokens."""
+        obs = self.engine.engine.obs
+        with obs.phases.span("http.write"):
+            await resp.write(_sse(body))
+        obs.on_frame(chunk.t_ready)
+
     async def profile(self, request: web.Request) -> web.Response:
         """Capture a jax.profiler trace of live serving traffic.
 
@@ -1310,9 +1320,7 @@ class APIServer:
                                        for t in new_ids],
                             "token_logprobs": lps[-len(new_ids):],
                         }
-                    with self.engine.engine.obs.phases.span(
-                            "http.write"):
-                        await resp.write(_sse(sb))
+                    await self._write_frame(resp, sb, chunk)
                 if finished:
                     complete = True
                     break
@@ -1977,9 +1985,7 @@ class APIServer:
                         if chunk.new_top_logprobs:
                             sb["choices"][0]["logprobs"]["top_logprobs"] = \
                                 _format_tops(lp_tok, chunk.new_top_logprobs)
-                    with self.engine.engine.obs.phases.span(
-                            "http.write"):
-                        await resp.write(_sse(sb))
+                    await self._write_frame(resp, sb, chunk)
                 if finished:
                     complete = True
                     break
@@ -2267,6 +2273,9 @@ def build_server(config: EngineConfig, tokenizer_path: Optional[str] = None,
                      profile_dir=profile_dir)
 
 
+# roomy_stack: the engine's start and the event loop run below main()'s
+# frame; none of their call sites sits on the edge of a frame chunk.
+@roomy_stack
 def main(argv: Optional[list[str]] = None) -> None:
     """CLI: python -m kubernetes_gpu_cluster_tpu.serving.api_server
     --model tinyllama-1.1b --port 8000 [--tokenizer /models/TinyLlama]
@@ -2665,11 +2674,13 @@ def main(argv: Optional[list[str]] = None) -> None:
         server._runtime_info["hf_overrides"] = hf_overrides
     if (leader is None and args.role != "prefill"
             and not config.enforce_eager):
-        # Before it listens: the first use of the window at full seats
-        # would otherwise stand every open stream still for its length.
-        # (Under a multi-process mesh every dispatch is a directive: the
-        # followers would have to be told.)
+        # Before it listens: the first use of the window at full seats,
+        # and of a short prompt's mixed step beside them, would otherwise
+        # stand every open stream still for its length. (Under a
+        # multi-process mesh every dispatch is a directive: the followers
+        # would have to be told.)
         server.engine.engine.warm_full_window()
+        server.engine.engine.warm_short_mixed()
     app = server.build_app()
 
     async def _arm_sigterm(app_):

@@ -24,6 +24,7 @@ from ..analysis.sanitize import build_interleave_sanitizer
 from ..config import EngineConfig
 from ..engine import LLMEngine, RequestOutput, SamplingParams
 from ..utils import get_logger
+from ..utils.stack import roomy_stack
 
 logger = get_logger("serving.async_engine")
 
@@ -38,6 +39,10 @@ class StreamChunk:
     finish_reason: Optional[str]
     new_logprobs: list[float] = dataclasses.field(default_factory=list)
     new_top_logprobs: list = dataclasses.field(default_factory=list)
+    # time.monotonic at which the program that produced these tokens was
+    # ready on the device (None: no program did); the frame that carries
+    # them is held against it (kgct_frame_delay_seconds).
+    t_ready: Optional[float] = None
 
 
 class AsyncLLMEngine:
@@ -295,18 +300,29 @@ class AsyncLLMEngine:
 
     # -- worker thread -------------------------------------------------------
 
+    # roomy_stack: no call site below sits on the edge of a frame chunk
+    # (utils/stack.py: a first use of a program took 3.5 s longer there).
+    @roomy_stack
     def _worker(self) -> None:
         izer = self._interleave
         # Host spans of a profiler capture (observability/phases.py): what
         # the worker does outside engine.step(). Nothing while none runs.
         span = self.engine.obs.phases.span
+        # The thread's wall, split at its own turns (kgct_worker_seconds_
+        # total): ``inbox_wait`` here, ``device_wait`` inside the engine's
+        # fetch, ``host`` everything else (GIL waits included). This first
+        # turn starts the clock.
+        turn = self.engine.obs.phases.worker_turn
+        turn("host")
         while True:
             with self._cv:
                 while not (self._shutdown or self._inbox or self._aborts
                            or self._ops
                            or self.engine.has_unfinished_requests()):
                     with span("worker.wait"):
+                        turn("inbox_wait")
                         self._cv.wait()
+                        turn("host")
                 inbox, self._inbox = self._inbox, []
                 aborts, self._aborts = self._aborts, []
                 ops, self._ops = self._ops, []
@@ -496,4 +512,5 @@ def _chunk_of(out: RequestOutput) -> StreamChunk:
         finished=out.finished,
         finish_reason=out.finish_reason,
         new_logprobs=list(out.new_logprobs or []),
-        new_top_logprobs=list(out.new_top_logprobs or []))
+        new_top_logprobs=list(out.new_top_logprobs or []),
+        t_ready=out.t_ready)

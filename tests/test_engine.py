@@ -593,3 +593,175 @@ def test_warm_full_window_meets_the_full_seat_program_and_nothing_else():
     # what was warmed IS the program full seats run: the unwarmed engine
     # met it in its load, and holds no shape fewer
     assert warm.compiled_step_variants() == cold.compiled_step_variants()
+
+
+def test_warm_short_mixed_meets_a_short_prompts_step_beside_full_seats():
+    """``warm_short_mixed`` runs the mixed step of the smallest prefill
+    bucket at the largest row bucket over padding alone: one step program
+    more has met its shape, no page, step or key of the engine is used up,
+    what is served afterwards is what an engine that was never warmed
+    serves, and what was warmed IS the program that a prompt inside a page
+    runs beside the rows in their seats: the unwarmed engine met it in its
+    load, and holds no shape fewer."""
+    long = SamplingParams(max_tokens=30, temperature=0.0)
+    arrivals = [(0, f"r{i}", _P[i], long) for i in (1, 2, 4)] + [
+        (4, "short", _P[0], SamplingParams(max_tokens=6, temperature=0.8)),
+        (9, "short2", _P[5], _GREEDY)]
+    cold, warm = _queue_engine(), _queue_engine()
+    before = warm.compiled_step_variants()
+    warm.warm_short_mixed()
+    assert warm.compiled_step_variants() == before + 1
+    assert warm.step_count == 0 and warm.stats.prefill_tokens == 0
+    alloc = warm.scheduler.allocator
+    assert alloc.num_free == alloc.num_pages - 1
+    met = warm.compiled_step_variants()
+    want = _drive(cold, arrivals)
+    assert _drive(warm, arrivals) == want
+    assert ("mixed", True) in cold.obs.steps_dispatched
+    assert warm.compiled_step_variants() == cold.compiled_step_variants()
+    assert warm._mixed_fn._cache_size() == cold._mixed_fn._cache_size()
+    assert met < warm.compiled_step_variants()      # the load met others
+
+
+# -- the step record: one a dispatched program, its own number and clock ------
+
+@pytest.fixture(scope="module")
+def recorded_run():
+    """A staged run (a long prompt in three mixed steps beside decode rows,
+    an arrival riding behind a window) with every record ``on_step`` was
+    handed, in order, and what /debug/trace exports afterwards."""
+    eng = _queue_engine()
+    records = []
+    on_step = eng.obs.on_step
+    eng.obs.on_step = lambda rec: (on_step(rec), records.append(rec))[0]
+    _drive(eng, QUEUE_CASES["three_chunks_mixed"]()["arrivals"])
+    return eng, records, eng.obs.export_perfetto()["traceEvents"]
+
+
+def test_every_retired_program_has_one_record_and_the_times_add_up(
+        recorded_run):
+    eng, records, _ = recorded_run
+    steps = [r["step"] for r in records]
+    assert steps == list(range(1, eng.step_count + 1))      # each once
+    assert {r["kind"] for r in records} == {"prefill", "decode", "mixed"}
+    for r in records:
+        assert (r["t_launch"] <= r["t_dispatched"] <= r["t_wait"]
+                <= r["t_ready"] <= r["t_retired"]), r["step"]
+        assert r["wait_s"] == r["t_ready"] - r["t_wait"]
+        assert r["found_ready"] == (r["wait_s"] < 100e-6)
+        assert 0 < r["tokens"] <= r["padded_tokens"]
+        assert r["rows"] == r["batch"].num_seqs
+    # a chain of programs each queued behind the one before it: device time
+    # (exact or not) plus the time the chip stood waiting for a dispatch
+    # tile the span from the first dispatch to the last end
+    chains, by_step = [], {r["step"]: r for r in records}
+    for r in records:
+        if r["pred"] is None:
+            chains.append([r])
+        else:
+            assert r["behind"] and r["pred"] == r["step"] - 1
+            assert r["ready_gap_s"] == r["t_ready"] - by_step[
+                r["pred"]]["t_ready"]
+            chains[-1].append(r)
+    assert max(map(len, chains)) > 3
+    for chain in chains:
+        assert chain[0]["ready_gap_s"] is None and not chain[0]["behind"]
+        busy = sum(r["device_s"] for r in chain)
+        stood = sum(max(-r["lead_s"], 0.0) for r in chain[1:])
+        span = chain[-1]["t_ready"] - chain[0]["t_dispatched"]
+        assert busy + stood == pytest.approx(span, abs=1e-9)
+        for prev, r in zip(chain, chain[1:]):
+            assert r["exact"] == (not r["found_ready"]
+                                  and not prev["found_ready"])
+    # the histogram holds the exact ones and nothing else
+    exact = [r for r in records if r["exact"]]
+    assert eng.obs.step_device.count == len(exact)
+    assert eng.obs.step_device.sum == pytest.approx(
+        sum(r["device_s"] for r in exact))
+    assert sum(eng.obs.steps_retired.values()) == len(records)
+    assert eng.obs.step_tokens[("mixed", True)] == sum(
+        r["tokens"] for r in records if r["kind"] == "mixed")
+
+
+def test_trace_slices_carry_the_step_and_kind_of_the_program_they_served(
+        recorded_run):
+    """An iteration dispatches program n+1 and fetches program n: the
+    dispatch's slices are filed under n+1 and its kind, the fetch's under
+    n and ITS kind (the parent filed both under the number of n+1 and the
+    kind of n)."""
+    _, records, events = recorded_run
+    kinds = {r["step"]: r["kind"] for r in records}
+    slices = [e for e in events if e.get("ph") == "X"]
+    seen: dict = {}
+    for e in slices:
+        a = e["args"]
+        assert a["kind"] == kinds[a["step"]], e
+        assert {"device_ms", "wait_ms", "lead_ms", "exact"} <= set(a)
+        seen.setdefault(a["step"], []).append(e["name"])
+    for step, kind in kinds.items():
+        names = seen[step]
+        assert names.count("device_dispatch") == 1, (step, names)
+        assert names.count("device_fetch") == 1
+        assert names.count("postproc") == 1
+        assert names.index("schedule") < names.index("device_dispatch") \
+            < names.index("device_fetch") < names.index("postproc")
+    # a mixed step queued behind a window: its dispatch lies BEFORE the end
+    # of the window's fetch on the timeline, and is still the mixed step's
+    by = {(e["args"]["step"], e["name"]): e for e in slices}
+    mixed = next(r for r in records
+                 if r["kind"] == "mixed" and kinds[r["pred"]] == "decode")
+    d, f = by[(mixed["step"], "device_dispatch")], \
+        by[(mixed["pred"], "device_fetch")]
+    assert d["ts"] < f["ts"] + f["dur"]
+    assert d["args"]["kind"] == "mixed" and f["args"]["kind"] == "decode"
+    # the step events of the flight recorder carry the same numbers
+    evs = [e for e in events if e.get("cat") == "engine"]
+    assert [e["args"]["step"] for e in evs] == sorted(kinds)
+    assert all(e["name"] == kinds[e["args"]["step"]] for e in evs)
+
+
+def test_request_events_name_the_steps_that_served_them(recorded_run):
+    _, records, events = recorded_run
+    kinds = {r["step"]: r["kind"] for r in records}
+    by_req: dict = {}
+    for e in events:
+        if e.get("cat") == "request" and e.get("ph") == "n":
+            by_req.setdefault(e["id"], {}).setdefault(
+                e["name"], []).append(e["args"])
+    assert set(by_req) == {"r0", "r1", "long", "r2"}
+    for rid, ev in by_req.items():
+        [sched] = ev["scheduled"]
+        [first] = ev["first_token"]
+        assert sched["step"] in kinds and first["step"] in kinds
+        assert sched["step"] <= first["step"]
+    # the long prompt: three chunks in three mixed steps, the first token
+    # out of the last of them
+    chunks = [a["step"] for a in by_req["long"]["prefill_chunk"]]
+    assert len(chunks) == 3 and all(kinds[s] == "mixed" for s in chunks)
+    assert by_req["long"]["scheduled"][0]["step"] == chunks[0]
+    assert by_req["long"]["first_token"][0]["step"] == chunks[-1]
+    # it arrived while a window was in flight: the program that served it
+    # was queued BEHIND that one (two step numbers: S5(iii))
+    served = next(r for r in records if r["step"] == chunks[0])
+    assert served["behind"] and kinds[served["pred"]] == "decode"
+
+
+def test_spec_steps_get_the_same_record_through_the_same_helper():
+    eng = _queue_engine(spec_decode_enabled=True, num_speculative_tokens=3)
+    records = []
+    on_step = eng.obs.on_step
+    eng.obs.on_step = lambda rec: (on_step(rec), records.append(rec))[0]
+    eng.generate([[7, 3, 9, 11] * 4],
+                 SamplingParams(max_tokens=16, temperature=0.0))
+    spec = [r for r in records if r["kind"] == "spec"]
+    assert spec and [r["step"] for r in records] == list(
+        range(1, eng.step_count + 1))
+    for r in spec:
+        assert not r["behind"] and r["pred"] is None
+        assert r["t_launch"] <= r["t_dispatched"] <= r["t_wait"] \
+            <= r["t_ready"] <= r["t_retired"]
+        assert r["device_s"] == r["t_ready"] - r["t_dispatched"]
+        assert r["tokens"] == r["rows"] * 4 <= r["padded_tokens"]
+        assert r["drafted_tokens"] >= r["accepted_tokens"] >= 0
+    assert eng.obs.steps_retired[("spec", True)] \
+        + eng.obs.steps_retired.get(("spec", False), 0) == len(spec)

@@ -119,17 +119,50 @@ class TestStepSpans:
         # a leaf directly under the step: phases do not nest in each other
         assert all(around == ("kgct.step",) for around in hits), hits[:3]
 
-    def test_on_the_step_is_the_parent_and_carries_its_number(
+    def test_on_the_step_is_the_parent_and_names_both_programs(
             self, recorded_steps):
+        """An iteration dispatches program n+1 and fetches program n: its
+        span carries both numbers, not a count of iterations."""
         steps = [(name, kw) for name, kw in recorded_steps.made
                  if name == "kgct.step"]
-        assert steps and all(set(kw) == {"step_num"} for _, kw in steps)
-        nums = [kw["step_num"] for _, kw in steps]
+        assert steps and all(set(kw) == {"launched", "retired"}
+                             for _, kw in steps)
+        # the first iteration finds nothing in flight: it launches the
+        # program it then retires; from then on the one behind it
+        first = steps[0][1]
+        assert first["launched"] == first["retired"]
+        assert all(kw["retired"] == kw["launched"] - 1
+                   for _, kw in steps[1:])
+        nums = [kw["retired"] for _, kw in steps]
         assert nums == list(range(nums[0], nums[0] + len(nums)))
         assert all(around == () for name, _, around
                    in recorded_steps.entered if name == "kgct.step")
         assert recorded_steps.names() == \
             {"kgct.step"} | {f"kgct.{p}" for p in STEP_PHASES}
+
+    def test_dispatch_and_fetch_spans_carry_their_own_program(
+            self, recorded_steps):
+        """``kgct.device_dispatch`` and ``kgct.device_fetch`` name the
+        program THEY serve (step, kind, rows): every program dispatched is
+        fetched once, under the same number and kind, and inside one
+        iteration the dispatch is the successor's."""
+        made = recorded_steps.made
+        disp = [kw for name, kw in made if name == "kgct.device_dispatch"]
+        fetch = [kw for name, kw in made if name == "kgct.device_fetch"]
+        assert disp and all(set(kw) == {"step", "kind", "rows"}
+                            for kw in disp + fetch)
+        key = lambda kw: (kw["step"], kw["kind"], kw["rows"])  # noqa: E731
+        assert sorted(map(key, disp)) == sorted(map(key, fetch))
+        assert len({kw["step"] for kw in disp}) == len(disp)
+        assert {kw["kind"] for kw in disp} >= {"prefill", "decode"}
+        # in the order they were made, a fetch of n follows the dispatch
+        # of n+1 (the queue is one deep), but for the last program
+        order = [(name, kw["step"]) for name, kw in made
+                 if name in ("kgct.device_dispatch", "kgct.device_fetch")]
+        for i, (name, n) in enumerate(order):
+            if name == "kgct.device_fetch" and i + 1 < len(order):
+                assert order[i - 1] in (("kgct.device_dispatch", n + 1),
+                                        ("kgct.device_dispatch", n)), order
 
     @pytest.mark.parametrize("params", [
         SamplingParams(max_tokens=12, temperature=0.0),
@@ -380,6 +413,11 @@ class TestProfileEndpoint:
                         clock.append((ev.start_ns, dict(ev.stats)))
         assert {"kgct.step", "kgct.worker.post", "kgct.http.write",
                 "kgct.clock"} | {f"kgct.{p}" for p in STEP_PHASES} <= names
+        # the dispatch spans carry their program's kind as the benchmark's
+        # reader of the host's lead finds it (perfbench trace_step_lead)
+        from perfbench.readers import trace_step_lead
+        kinds = [k for _, _, k in trace_step_lead.dispatch_spans(files[0])]
+        assert kinds and set(kinds) <= {"prefill", "decode", "mixed"}
         [(clock_ns, stats)] = clock
         assert int(stats["monotonic_ns"]) == reply["started_monotonic_ns"]
         # the stamp was taken just before the annotation: the offset between

@@ -602,12 +602,15 @@ def test_engine_greedy_equals_the_reference(served):
     assert any(behind for _, behind in eng.obs.steps_dispatched)
 
 
-def test_a_warmed_full_window_leaves_slots_and_pages_as_they_were(served):
-    """``warm_full_window`` (the serving CLI's, before it listens) runs a
-    window of padding rows: they write the scrap slot and the scrap page,
-    so the same prompts are served as before it."""
+@pytest.mark.parametrize("warm", ["warm_full_window", "warm_short_mixed"])
+def test_a_warmed_step_program_leaves_slots_and_pages_as_they_were(
+        served, warm):
+    """``warm_full_window`` and ``warm_short_mixed`` (the serving CLI's,
+    before it listens) run a window, a mixed step, of padding alone: they
+    write the scrap slot and the scrap page, so the same prompts are served
+    as before them."""
     eng, want = served
-    eng.warm_full_window()
+    getattr(eng, warm)()
     alloc = eng.scheduler.allocator
     assert alloc.num_free_slots == alloc.num_state_slots - 1
     assert alloc.num_free == alloc.num_pages - 1

@@ -324,6 +324,9 @@ class TestEngineParity:
         params = SamplingParams(max_tokens=8, temperature=temperature,
                                 top_k=40 if temperature else 0, seed=seed)
         outs, kinds = {}, []
+        on_step = eng.obs.on_step
+        eng.obs.on_step = lambda rec: (kinds.append(rec["kind"]),
+                                       on_step(rec))[1]
         eng.add_request(f"{tag}-a", prompts["a"], params)
         for _ in range(2):                      # a prefills, starts decoding
             for o in eng.step():
@@ -335,8 +338,7 @@ class TestEngineParity:
             for o in eng.step():
                 if o.finished:
                     outs[o.request_id] = o.output_token_ids
-            if eng._last_step_info:
-                kinds.append(eng._last_step_info[0])
+        eng.obs.on_step = on_step
         return {k.split("-", 1)[1]: v for k, v in outs.items()}, kinds
 
     def test_outputs_identical_to_legacy(self):

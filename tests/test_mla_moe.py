@@ -52,6 +52,30 @@ def _prompt(n, seed):
     return np.random.default_rng(seed).integers(3, CFG.vocab_size, n).tolist()
 
 
+def test_the_warmed_step_programs_leave_the_latent_pool_as_it_was(params):
+    """``warm_full_window`` and ``warm_short_mixed`` over latent pages and
+    routed experts: padding alone, written to the scrap page, so a warmed
+    engine serves what an unwarmed one serves, and the switches that leave
+    no mixed step to meet (``mixed_batch_enabled`` off) make the second a
+    no-op."""
+    prompts = [_prompt(n, n) for n in (9, 40, 70, 21, 12)]
+    greedy = SamplingParams(max_tokens=12, temperature=0.0)
+    want = [o.output_token_ids
+            for o in _engine(params).generate(prompts, greedy)]
+    eng = _engine(params)
+    before = eng.compiled_step_variants()
+    eng.warm_full_window()
+    eng.warm_short_mixed()
+    assert eng.compiled_step_variants() == before + 2
+    alloc = eng.scheduler.allocator
+    assert alloc.num_free == alloc.num_pages - 1
+    assert [o.output_token_ids for o in eng.generate(prompts, greedy)] == want
+    off = _engine(params, mixed_batch_enabled=False)
+    before = off.compiled_step_variants()
+    off.warm_short_mixed()
+    assert off.compiled_step_variants() == before
+
+
 def _served_vs_reference(eng, params, prompts, max_tokens=5):
     """Greedy generation with logprobs through the engine; every emitted
     token's log-probability and top-1 against the reference's full forward
@@ -102,8 +126,8 @@ class TestServedAgainstReference:
         eng = _engine(params)
         kinds = []
         orig = eng.obs.on_step
-        eng.obs.on_step = lambda **kw: (kinds.append(kw["kind"]),
-                                        orig(**kw))[1]
+        eng.obs.on_step = lambda rec: (kinds.append(rec["kind"]),
+                                       orig(rec))[1]
         # 100 tokens > the 64-token budget: taken in two chunks, the second
         # (and the first) beside the 40-token prompt's decode row.
         _served_vs_reference(eng, params, [_prompt(40, 2), _prompt(100, 3)])
@@ -122,9 +146,10 @@ class TestServedAgainstReference:
         eng.obs.on_expert_load = lambda load, grouped: (
             device.append((sum(int(np.asarray(a).sum()) for a in load),
                            grouped)), on_load(load, grouped))[1]
-        eng.obs.on_step = lambda **kw: (
-            host.append((kw["routed_pairs"], True))
-            if kw["kind"] in ("prefill", "mixed") else None, on_step(**kw))[1]
+        eng.obs.on_step = lambda rec: (
+            host.append((rec["routed_pairs"], True))
+            if rec["kind"] in ("prefill", "mixed") else None,
+            on_step(rec))[1]
         # 40 tokens in a 192-token prefill; 250 in two chunks, beside the
         # first prompt's decode row in a 4-row bucket.
         _served_vs_reference(eng, params, [_prompt(40, 8), _prompt(250, 9)])

@@ -7,6 +7,9 @@ import logging
 import math
 import os
 import sys
+import time
+
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -18,6 +21,21 @@ from kubernetes_gpu_cluster_tpu.observability.phases import (  # noqa: E402
     StepPhaseStats)
 from kubernetes_gpu_cluster_tpu.observability.trace import (  # noqa: E402
     RequestTracer, merge_perfetto)
+
+
+def _rec(step, kind, rows, duration_s, new_tokens, t0=10.0, pred=None,
+         wait_s=0.005, **extra):
+    """A retired program's record as the engine hands it to ``on_step``:
+    launched at ``t0``, dispatched 1 ms later, waited for ``wait_s`` and
+    retired ``duration_s`` after the iteration began."""
+    rec = {"step": step, "kind": kind, "rows": rows, "tokens": rows,
+           "padded_tokens": rows, "behind": pred is not None, "pred": pred,
+           "t_iter": t0, "t_launch": t0, "t_dispatched": t0 + 0.001,
+           "t_wait": t0 + 0.002, "t_ready": t0 + 0.002 + wait_s,
+           "t_retired": t0 + duration_s, "phases": [],
+           "new_tokens": new_tokens}
+    rec.update(extra)
+    return rec
 
 
 class _Seq:
@@ -281,7 +299,8 @@ class TestStepPhaseStats:
         with st.phase("schedule"):
             pass
         total = st.totals["schedule"]
-        st.discard_step()
+        # a launch that scheduled nothing: the next one starts a fresh list
+        assert st.start_step() == []
         assert st.step_records() == []
         assert st.totals["schedule"] == total >= 0.0
 
@@ -293,7 +312,7 @@ class TestStepPhaseStats:
         # Out-of-step slices must not touch the engine thread's step-local
         # state (they arrive from the HTTP event-loop thread mid-step) —
         # they surface through detached_records() instead.
-        assert st._current == [] and st.current_durs == {}
+        assert st._current == []
         [rec] = st.detached_records()
         assert rec["kind"] == "http"
         assert [p[0] for p in rec["phases"]] == ["detokenize"]
@@ -347,9 +366,9 @@ class TestObservabilityLifecycle:
     def test_sampled_decode_ratio_gauge(self):
         obs = Observability(enabled=True)
         assert obs.sampled_decode_ratio() is None     # one mode only
-        obs.on_step(1, "decode", 4, 0.1, 100, mode="greedy")
+        obs.on_step(_rec(1, "decode", 4, 0.1, 100, mode="greedy"))
         assert obs.sampled_decode_ratio() is None
-        obs.on_step(2, "decode", 4, 0.1, 90, mode="sampled")
+        obs.on_step(_rec(2, "decode", 4, 0.1, 90, mode="sampled"))
         assert abs(obs.sampled_decode_ratio() - 0.9) < 1e-9
         text = "\n".join(obs.render_prometheus())
         assert "kgct_sampled_decode_ratio 0.9" in text
@@ -357,10 +376,11 @@ class TestObservabilityLifecycle:
     def test_clear_trace_scopes_capture(self):
         obs = Observability(enabled=True)
         self._run_request(obs)
-        obs.phases.start_step()
+        phases = obs.phases.start_step()
         with obs.phases.phase("device_dispatch"):
             pass
-        obs.on_step(1, "decode", 1, 0.01, 1, mode="greedy")
+        obs.on_step(_rec(1, "decode", 1, 0.01, 1, mode="greedy",
+                         phases=phases))
         obs.phases.record("detokenize", 0.001)     # detached (HTTP thread)
         evs = obs.export_perfetto()["traceEvents"]
         assert {"device_dispatch", "detokenize"} <= {
@@ -545,3 +565,315 @@ class TestDeviceQueueCounters:
         parent = stats.parse_prometheus("kgct_step_duration_seconds_count 5\n")
         assert prom_ratio.read(
             spec, {"scrape_before": parent, "scrape_after": parent}) is None
+
+
+# -- the step loop's own clock (phases.StepPhaseStats.retire, worker_turn) ---
+
+def _stamps(step, kind, pred, t_dispatched, t_wait, t_ready):
+    return {"step": step, "kind": kind, "pred": pred,
+            "t_dispatched": t_dispatched, "t_wait": t_wait,
+            "t_ready": t_ready}
+
+
+class TestStepClock:
+    def test_scripted_stamps_give_device_time_exactness_and_found_ready(self):
+        st = StepPhaseStats()
+        # 1: nothing in flight before it: it starts at its own dispatch
+        a = st.retire(_stamps(1, "prefill", None, 10.000, 10.010, 10.050))
+        assert a["wait_s"] == pytest.approx(0.040) and not a["found_ready"]
+        assert a["device_s"] == pytest.approx(0.050) and a["exact"]
+        assert a["ready_gap_s"] is None and a["lead_s"] is None
+        # 2: queued at 10.020 behind 1, which ended at 10.050: it starts
+        # there; the host was 30 ms ahead of the chip
+        b = st.retire(_stamps(2, "decode", 1, 10.020, 10.060, 10.150))
+        assert b["device_s"] == pytest.approx(0.100) and b["exact"]
+        assert b["ready_gap_s"] == pytest.approx(0.100)
+        assert b["lead_s"] == pytest.approx(0.030)
+        # 3: dispatched AFTER its predecessor was ready (the chip stood 20
+        # ms): it starts at its own dispatch; the lead is negative
+        c = st.retire(_stamps(3, "decode", 2, 10.170, 10.180, 10.260))
+        assert c["device_s"] == pytest.approx(0.090) and c["exact"]
+        assert c["ready_gap_s"] == pytest.approx(0.110)
+        assert c["lead_s"] == pytest.approx(-0.020)
+        # 4: the host asked at 10.400, the chip had long finished: found
+        # ready, so t_ready is the host's arrival and device_s is not exact
+        d = st.retire(_stamps(4, "mixed", 3, 10.200, 10.400, 10.40002))
+        assert d["found_ready"] and not d["exact"]
+        assert d["wait_s"] == pytest.approx(20e-6)
+        # 5: waited for, but behind one that was found ready: its start is
+        # unknown, so it is not exact either; 6 behind 5 is exact again
+        e = st.retire(_stamps(5, "decode", 4, 10.300, 10.410, 10.480))
+        assert not e["found_ready"] and not e["exact"]
+        f = st.retire(_stamps(6, "decode", 5, 10.420, 10.490, 10.580))
+        assert f["exact"] and f["device_s"] == pytest.approx(0.100)
+        # 8: launched with 7 in flight, but 7 is not what was retired last
+        # (it never came back): no predecessor to reckon from
+        g = st.retire(_stamps(8, "decode", 7, 10.600, 10.610, 10.700))
+        assert g["ready_gap_s"] is None and g["device_s"] == pytest.approx(
+            0.100)
+        assert all(r["slow"] is None for r in (a, b, c, d, e, f, g))
+
+    def test_slow_gap_is_classified_by_cause_both_ways(self):
+        st = StepPhaseStats()
+        t = 100.0
+        st.retire(_stamps(1, "decode", None, t, t + 0.01, t + 0.1))
+        for n in range(2, 12):           # ten windows of 100 ms: the mean
+            r = st.retire(_stamps(n, "decode", n - 1, t + 0.05,
+                                  t + 0.06, t + 0.1 * n))
+            assert r["slow"] is None
+        # the device ran long: queued in time (11 ended at t), waited for
+        t = 101.1
+        r = st.retire(_stamps(12, "decode", 11, t - 0.05, t - 0.04, t + 3.0))
+        assert r["slow"] == "device" and r["ready_gap_s"] == pytest.approx(3)
+        # the host came late: dispatched 2 s after the predecessor's end
+        r = st.retire(_stamps(13, "decode", 12, t + 5.0, t + 5.01, t + 5.1))
+        assert r["slow"] == "host" and r["lead_s"] == pytest.approx(-2.0)
+        # the host came late to the FETCH: queued in time, found ready
+        r = st.retire(_stamps(14, "decode", 13, t + 5.05, t + 7.0 - 1e-5,
+                              t + 7.0))
+        assert r["slow"] == "host" and r["found_ready"]
+        # neither floor alone makes a gap slow: 0.3 s is 3x the mean but
+        # under 0.5 s; and the slow ones did not move the mean
+        r = st.retire(_stamps(15, "decode", 14, t + 6.9, t + 7.05, t + 7.3))
+        assert r["slow"] is None
+        assert st._gap_mean["decode"][0] / st._gap_mean["decode"][1] \
+            == pytest.approx((10 * 0.1 + 0.3) / 11, rel=1e-6)
+        # a kind of long programs: 0.8 s each is over the floor, not over
+        # 3x their own mean (the first of a kind only starts the mean)
+        for n in range(16, 20):
+            g = t + 7.3 + 0.8 * (n - 15)
+            r = st.retire(_stamps(n, "mixed", n - 1, g - 0.9, g - 0.4, g))
+            assert r["slow"] is None
+
+    def test_on_step_fills_the_program_series(self):
+        obs = Observability(enabled=True)
+        obs.on_step(_rec(1, "prefill", 2, 0.05, 2, t0=10.0, wait_s=0.030,
+                         tokens=40, padded_tokens=64))
+        obs.on_step(_rec(2, "decode", 2, 0.10, 16, t0=10.04, pred=1,
+                         wait_s=0.080, tokens=16, padded_tokens=16,
+                         mode="greedy"))
+        obs.on_step(_rec(3, "decode", 2, 0.10, 16, t0=10.13, pred=2,
+                         wait_s=0.00001, tokens=16, padded_tokens=32,
+                         mode="greedy"))
+        text = "\n".join(obs.render_prometheus())
+        # 1 and 2 were waited for; 3 was found ready: not observed
+        assert 'kgct_step_device_seconds_count{kind="prefill"} 1' in text
+        assert 'kgct_step_device_seconds_count{kind="decode"} 1' in text
+        assert 'kgct_steps_retired_total{kind="decode",waited="0"} 1' in text
+        assert 'kgct_steps_retired_total{kind="decode",waited="1"} 1' in text
+        assert 'kgct_step_tokens_total{kind="prefill",real="0"} 24' in text
+        assert 'kgct_step_tokens_total{kind="decode",real="1"} 32' in text
+        assert 'kgct_step_tokens_total{kind="decode",real="0"} 16' in text
+        assert 'kgct_step_slow_seconds_total{cause="host"} 0' in text
+        assert 'kgct_step_slow_total{cause="device"} 0' in text
+        assert "kgct_step_seconds_count 3" in text     # one an iteration
+        # the trace slices and the step events carry the program's number
+        [ev] = [e for e in obs.tracer.events() if e.kind == "prefill"]
+        assert ev.args["step"] == 1 and ev.args["device_ms"] == 31.0
+        recs = obs.phases.step_records()
+        assert [r["step"] for r in recs] == [1, 2, 3]
+        assert recs[2]["args"]["exact"] is False
+        obs.on_frame(time.monotonic() - 0.004)
+        obs.on_frame(None)
+        assert obs.frame_delay.count == 1
+        assert 0.004 <= obs.frame_delay.sum < 0.1
+
+    def test_worker_states_sum_to_the_threads_wall(self):
+        """A thread that turns as the worker does (idle on the inbox,
+        blocked on the device, busy) for a few hundred ms: whenever the
+        three states are read, they sum to its life within 1 %."""
+        import threading
+        st = StepPhaseStats()
+        assert sum(st.worker_seconds().values()) == 0.0   # no clock yet
+        started = []
+
+        def worker():
+            st.worker_turn("host")
+            started.append(time.monotonic())
+            for _ in range(6):
+                st.worker_turn("inbox_wait")
+                time.sleep(0.02)
+                st.worker_turn("host")
+                time.sleep(0.005)
+                t = time.monotonic()
+                st.worker_turn("device_wait", t)
+                time.sleep(0.03)
+                t = time.monotonic()
+                st.worker_turn("host", t)
+        th = threading.Thread(target=worker)
+        th.start()
+        reads = []
+        while th.is_alive():
+            time.sleep(0.013)                 # mid-state, from outside
+            if started:
+                got, now = st.worker_seconds(), time.monotonic()
+                reads.append((sum(got.values()), now - started[0]))
+        th.join()
+        got, life = st.worker_seconds(), time.monotonic() - started[0]
+        assert len(reads) > 10
+        for total, wall in reads[3:] + [(sum(got.values()), life)]:
+            assert total == pytest.approx(wall, rel=0.01, abs=2e-4)
+        assert got["inbox_wait"] >= 6 * 0.02
+        assert got["device_wait"] >= 6 * 0.03
+        assert 6 * 0.005 <= got["host"] < life - 0.3
+        lines = [l for l in Observability().render_prometheus()
+                 if l.startswith("kgct_worker_seconds_total")]
+        assert [l.split("{")[1].split("}")[0] for l in lines] == [
+            'state="host"', 'state="device_wait"', 'state="inbox_wait"']
+
+
+# -- the metric files that read the new series, each from two scrapes ---------
+
+_SCRAPE_BEFORE = """
+kgct_step_device_seconds_sum{kind="decode"} 10.0
+kgct_step_device_seconds_count{kind="decode"} 100
+kgct_step_device_seconds_sum{kind="prefill"} 1.0
+kgct_step_device_seconds_count{kind="prefill"} 20
+kgct_worker_seconds_total{state="host"} 5.0
+kgct_worker_seconds_total{state="device_wait"} 40.0
+kgct_worker_seconds_total{state="inbox_wait"} 55.0
+kgct_steps_retired_total{kind="decode",waited="1"} 100
+kgct_step_slow_seconds_total{cause="host"} 21.5
+kgct_step_slow_seconds_total{cause="device"} 0
+kgct_step_tokens_total{kind="decode",real="1"} 1000
+kgct_step_tokens_total{kind="decode",real="0"} 0
+kgct_frame_delay_seconds_sum 1.0
+kgct_frame_delay_seconds_count 1000
+kgct_queue_wait_seconds_sum 2.0
+kgct_queue_wait_seconds_count 10
+kgct_prefill_seconds_sum 3.0
+kgct_prefill_seconds_count 10
+"""
+_SCRAPE_AFTER = """
+kgct_step_device_seconds_sum{kind="decode"} 34.0
+kgct_step_device_seconds_count{kind="decode"} 300
+kgct_step_device_seconds_sum{kind="prefill"} 2.0
+kgct_step_device_seconds_count{kind="prefill"} 40
+kgct_step_device_seconds_sum{kind="mixed"} 7.0
+kgct_step_device_seconds_count{kind="mixed"} 100
+kgct_worker_seconds_total{state="host"} 11.0
+kgct_worker_seconds_total{state="device_wait"} 74.0
+kgct_worker_seconds_total{state="inbox_wait"} 55.0
+kgct_steps_retired_total{kind="decode",waited="1"} 297
+kgct_steps_retired_total{kind="decode",waited="0"} 1
+kgct_steps_retired_total{kind="mixed",waited="1"} 100
+kgct_steps_retired_total{kind="mixed",waited="0"} 2
+kgct_step_slow_seconds_total{cause="host"} 22.25
+kgct_step_slow_seconds_total{cause="device"} 0.75
+kgct_step_tokens_total{kind="decode",real="1"} 2500
+kgct_step_tokens_total{kind="decode",real="0"} 100
+kgct_step_tokens_total{kind="mixed",real="1"} 2000
+kgct_step_tokens_total{kind="mixed",real="0"} 400
+kgct_frame_delay_seconds_sum 4.0
+kgct_frame_delay_seconds_count 2500
+kgct_queue_wait_seconds_sum 2.9
+kgct_queue_wait_seconds_count 40
+kgct_prefill_seconds_sum 5.4
+kgct_prefill_seconds_count 40
+"""
+_NEW_METRICS = {
+    # name: (reader, the value the two scrapes hold)
+    "decode_step_inproc_ms": ("prom_hist_mean_where",
+                              24.0 / 200 / 8 * 1000),
+    "mixed_step_inproc_ms": ("prom_hist_mean_where", 70.0),
+    "prefill_mixed_wall_share": ("prom_ratio", 100.0 * 8 / 32),
+    "worker_host_share": ("prom_ratio", 100.0 * 6 / 40),
+    "steps_found_ready_share": ("prom_ratio", 100.0 * 3 / 300),
+    "slow_step_s_in_window": ("prom_counter_delta", 1.5),
+    "step_padding_share": ("prom_ratio", 100.0 * 500 / 4000),
+    "frame_delay_mean_ms": ("prom_hist_mean", 2.0),
+    "queue_wait_mean_ms": ("prom_hist_mean", 30.0),
+    "prefill_mean_ms": ("prom_hist_mean", 80.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_METRICS))
+def test_new_metric_file_reads_its_value_from_two_scrapes(name):
+    """Each is a data file of its own over a reader of the benchmark, named
+    in BENCHMARK.json as its file says, listed for all four cells; a server
+    without the series (the parent) reads nothing and does not raise."""
+    from perfbench import readers, stats
+    from perfbench.spec import Benchmark
+    bench = Benchmark()
+    spec = bench.layer_metric(name)
+    entry = next(m for m in bench.doc["per_layer"] if m["name"] == name)
+    reader, want = _NEW_METRICS[name]
+    assert spec["reader"] == reader
+    assert {k: spec[k] for k in ("unit", "layer", "moves", "source")} == {
+        k: entry[k] for k in ("unit", "layer", "moves", "source")}
+    assert entry["workloads"] == bench.cell_names()
+    ctx = {"scrape_before": stats.parse_prometheus(_SCRAPE_BEFORE),
+           "scrape_after": stats.parse_prometheus(_SCRAPE_AFTER),
+           "config": {"warmup": {"decode_window": 8}}}
+    assert readers.load(reader)(spec, ctx) == pytest.approx(want)
+    parent = stats.parse_prometheus("kgct_step_seconds_count 5\n")
+    assert readers.load(reader)(
+        spec, dict(ctx, scrape_before=parent, scrape_after=parent)) is None
+
+
+def test_a_clean_window_reads_zero_slow_seconds_not_nothing():
+    """The slow-step series are there from the first scrape, at 0: a clean
+    window reports 0, and only a program without them reports nothing."""
+    from perfbench import readers, stats
+    from perfbench.spec import Benchmark
+    spec = Benchmark().layer_metric("slow_step_s_in_window")
+    fresh = stats.parse_prometheus(
+        "\n".join(Observability().render_prometheus()))
+    assert readers.load(spec["reader"])(
+        spec, {"scrape_before": fresh, "scrape_after": fresh}) == 0.0
+
+
+class TestStepQueueLead:
+    """``trace_step_lead`` (perfbench/readers), on the small synthetic
+    capture ``perfbench/tests/data/step_lead_capture.xplane.pb`` (its
+    generator beside it says what it holds) and on hand-built lists."""
+
+    CAPTURE = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "tests", "data", "step_lead_capture.xplane.pb")
+
+    def test_reads_the_median_lead_from_the_synthetic_capture(self):
+        from pathlib import Path
+
+        from perfbench.readers import load, trace_step_lead
+        from perfbench.spec import Benchmark
+        bench = Benchmark()
+        spec = bench.layer_metric("step_queue_lead_ms")
+        entry = next(m for m in bench.doc["per_layer"]
+                     if m["name"] == "step_queue_lead_ms")
+        assert spec["reader"] == "trace_step_lead"
+        assert entry["source"] == "program_span" == spec["source"]
+        assert entry["better"] == "higher"
+        assert entry["workloads"] == bench.cell_names()
+        spans = trace_step_lead.dispatch_spans(Path(self.CAPTURE))
+        # five name their kind; the sixth (no arguments: a program older
+        # than them) is not there
+        assert [k for _, _, k in spans] == ["decode", "mixed", "decode",
+                                            "decode", "prefill"]
+        # 740, 350 and 10 us: the median, in ms
+        assert load("trace_step_lead")(
+            spec, {"trace_path": self.CAPTURE}) == pytest.approx(0.350)
+        # an untraced run has no capture: nothing, and nothing is looked up
+        assert load("trace_step_lead")(
+            spec, {"trace": None, "profile": {}}) is None
+
+    def test_kind_is_checked_and_edge_modules_are_dropped(self):
+        from perfbench.readers.trace_step_lead import leads_ns
+        modules = [(0.0, 100.0, "jit_decode_window_greedy(1)"),
+                   (100.0, 50.0, "jit_mixed_step(2)"),
+                   (150.0, 5.0, "jit__unstack(3)"),
+                   (160.0, 100.0, "jit_decode_window_sampled(4)"),
+                   (260.0, 40.0, "jit_prefill_hist_step(5)"),
+                   (300.0, 100.0, "jit_decode_window_greedy(1)")]
+        assert leads_ns([(20.0, 30.0, "mixed")], modules) == [70.0]
+        # the first STEP module after the span's start decides, not the
+        # first module; a span whose module is of another kind is left out
+        assert leads_ns([(120.0, 125.0, "decode")], modules) == [35.0]
+        assert leads_ns([(20.0, 30.0, "decode")], modules) == []
+        assert leads_ns([(200.0, 210.0, "prefill")], modules) == [50.0]
+        # the module that ends the capture is dropped, and so is a span
+        # after every module; a late dispatch reads a negative lead
+        assert leads_ns([(270.0, 280.0, "decode"),
+                         (500.0, 510.0, "decode")], modules) == []
+        assert leads_ns([(155.0, 170.0, "decode")], modules) == [-10.0]
+        assert leads_ns([(20.0, 30.0, "mixed")], []) == []

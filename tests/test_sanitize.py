@@ -318,7 +318,7 @@ class _ScriptedEngine:
                 request_id=rid, new_token_ids=[toks[-1]],
                 output_token_ids=list(toks), finished=fin,
                 finish_reason="length" if fin else None,
-                new_logprobs=[], new_top_logprobs=[]))
+                new_logprobs=[], new_top_logprobs=[], t_ready=None))
             if fin:
                 del self._live[rid]
         return outs
@@ -574,7 +574,7 @@ class TestDeviceQueueShadow:
         eng = LLMEngine(cfg, eos_token_id=eos)
         commit = eng._process_window
         eng._process_window = (
-            lambda batch, toks, lps, zombies, carried=frozenset(), **kw:
-            commit(batch, toks, lps, zombies, frozenset(), **kw))
+            lambda rec, toks, lps, carried=frozenset(), **kw:
+            commit(rec, toks, lps, frozenset(), **kw))
         with pytest.raises(SanitizerError, match="device-queue shadow"):
             _staged(eng)

@@ -363,6 +363,51 @@ class TestObservability:
         assert not [e for e in doc["traceEvents"]
                     if e.get("cat") == "request"]
 
+    def test_frame_delay_and_the_workers_three_states_on_a_live_server(
+            self, api_client):
+        """Every token-bearing frame of a stream is held against the end of
+        the program that made its tokens; and the step loop thread's three
+        states, read at two moments, grew by the wall between them (within
+        1 %): idle on the inbox before, blocked and busy through a stream."""
+        import time
+        loop, client = api_client
+        obs = _SERVER["api"].engine.engine.obs
+
+        def read():
+            got, now = obs.phases.worker_seconds(), time.monotonic()
+            return got, now
+        (w0, t0), n0 = read(), obs.frame_delay.count
+
+        async def go():
+            r = await client.post("/v1/completions", json={
+                "prompt": "frames", "max_tokens": 24, "temperature": 0.0,
+                "stream": True})
+            assert r.status == 200
+            return [line async for line in r.content
+                    if line.startswith(b"data: {")]
+        frames = loop.run_until_complete(go())
+        loop.run_until_complete(asyncio.sleep(0.3))     # the inbox again
+        w1, t1 = read()
+        assert 0 < len(frames) <= obs.frame_delay.count - n0 + 1
+        assert obs.frame_delay.count - n0 >= len(frames) - 1
+        assert 0 < obs.frame_delay.sum < 60.0
+        grown = {k: w1[k] - w0[k] for k in w1}
+        assert all(v >= 0 for v in grown.values()), grown
+        assert sum(grown.values()) == pytest.approx(t1 - t0, rel=0.01)
+        assert grown["device_wait"] > 0 and grown["host"] > 0
+        assert grown["inbox_wait"] >= 0.25
+
+        async def scrape():
+            return await (await client.get("/metrics")).text()
+        text = loop.run_until_complete(scrape())
+        for fam in ("kgct_step_device_seconds", "kgct_frame_delay_seconds"):
+            assert f"# TYPE {fam} histogram" in text
+        for fam in ("kgct_steps_retired_total", "kgct_step_slow_total",
+                    "kgct_step_slow_seconds_total", "kgct_step_tokens_total",
+                    "kgct_worker_seconds_total"):
+            assert f"# TYPE {fam} counter" in text
+        _assert_valid_exposition(text)
+
     def test_phase_attribution_bookkeeping(self, api_client):
         loop, client = api_client
 

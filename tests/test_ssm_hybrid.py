@@ -356,6 +356,21 @@ def test_engine_greedy_equals_the_reference(served):
     assert alloc.num_free == alloc.num_pages - 1
 
 
+def test_the_warmed_step_programs_leave_slots_and_pages_as_they_were(served):
+    """``warm_full_window`` and ``warm_short_mixed`` (the serving CLI's,
+    before it listens) run a window and a mixed step of padding alone: they
+    write the scrap slot and the scrap page, so the same prompts are served
+    as before them."""
+    eng, want = served
+    eng.warm_full_window()
+    eng.warm_short_mixed()
+    alloc = eng.scheduler.allocator
+    assert alloc.num_free_slots == alloc.num_state_slots - 1
+    assert alloc.num_free == alloc.num_pages - 1
+    outs = eng.generate(PROMPTS, GREEDY)
+    assert [o.output_token_ids for o in outs] == want
+
+
 def test_slots_are_reused_after_finish_and_preemption(served):
     """Two seats and a page pool that cannot hold both sequences to their
     end: sequences are preempted by recompute (slot freed, prompt and
