@@ -164,6 +164,22 @@ class SchedulerConfig:
         return (self.spec_k_max if self.spec_k_max is not None
                 else self.num_speculative_tokens)
 
+    @property
+    def mixed_chunk_buckets(self) -> tuple[int, ...]:
+        """The mixed step's chunk ladder: ``prefill_buckets`` and one rung
+        more, halfway between the top two where the lower is 1024 tokens or
+        more (1536 on the default grid). There the step is compute-bound,
+        63 rows stand behind it, and a prompt just over the lower bucket
+        paid for twice its tokens; below, the step leans on the weight
+        stream its decode rows pay anyway, and a rung would only be one
+        more program to compile. Packed prefills and solo chunks keep
+        ``prefill_buckets``: every shape is a program to compile and to
+        load at each start, and a rung there has not been measured."""
+        b = self.prefill_buckets
+        if len(b) < 2 or b[-2] < 1024:
+            return b
+        return (*b[:-1], (b[-2] + b[-1]) // 2, b[-1])
+
 
 @dataclasses.dataclass(frozen=True)
 class ParallelConfig:

@@ -117,9 +117,10 @@ def mixed_row_bucket(rows: int, chunk_bucket: int,
     but never under a sixteenth of the chunk's bucket, so padding rows stay
     under 1/16 of the step's tokens (their attention meets no page). Each
     (chunk bucket x row bucket) is a step program of its own to compile, to
-    keep in the compile cache and to load at every start: 35 on the default
-    grid, 11 with this floor, and a server whose seats fill behind prompts of
-    1-2 k tokens meets one program a chunk bucket where it met seven."""
+    keep in the compile cache and to load at every start: 42 on the default
+    grid (``mixed_chunk_buckets``: six chunk buckets), 12 with this floor,
+    and a server whose seats fill behind prompts of 1-2 k tokens meets one
+    program a chunk bucket where it met seven."""
     from .scheduler import _bucket
 
     floor = min(chunk_bucket // 16, decode_buckets[-1])
@@ -230,7 +231,7 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
         head.state_slot = sched.allocator.allocate_slot()
 
     D = len(decode_seqs)
-    Tp = _bucket(chunk, sc.prefill_buckets)
+    Tp = _bucket(chunk, sc.mixed_chunk_buckets)
     R_pad = mixed_row_bucket(D + 1, Tp, sc.decode_buckets)
     T_pad = Tp + R_pad
 
@@ -413,7 +414,7 @@ def build_spec_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     D = len(decode_seqs)
     ps = sched.page_size
     max_len = sched.config.effective_max_len
-    Tp = _bucket(chunk, sc.prefill_buckets)
+    Tp = _bucket(chunk, sc.mixed_chunk_buckets)
     R_pad = _bucket(D, sc.decode_buckets)
     T_pad = Tp + R_pad * S
     pages_bucket = cdiv(max_len, ps)

@@ -11,9 +11,11 @@ compare two dumps. CPU only; nothing here measures time.
     python scripts/step_program_jaxprs.py compare parent.json change.json
 
 ``dump`` builds the tiny presets (dense, int4, latent + experts, the two
-state kinds; meshless, tp=2, pp=2, sp=2, tp x ep) with the XLA references as the CPU engine resolves
-them, runs a staged load (packed and chunked prompts, mixed steps, greedy and
-sampled windows, speculation) and records each program at each shape it met.
+state kinds; meshless, tp=2, pp=2, sp=2, tp x ep; three of them again on a
+server's top prefill buckets, 1024 and 2048) with the XLA references as the
+CPU engine resolves them, runs a staged load (packed and chunked prompts,
+mixed steps, greedy and sampled windows, speculation) and records each
+program at each shape it met.
 Then it traces the same shapes through an engine told ``use_pallas=True`` at a
 width whose kernels trace (what a chip would trace; never run). It also
 records every request's tokens and logprobs.
@@ -48,6 +50,10 @@ CASES = [
     ("moe-tp2ep2", dict(model="debug-moe", mesh=dict(tp=2, ep=2))),
     ("ssm-hybrid", dict(model="debug-ssm-hybrid")),
     ("kda-hybrid", dict(model="debug-kda-hybrid")),
+    # a server's top buckets: prompts of 1100 and 1700 tokens ride mixed steps
+    ("mla-moe-2k", dict(model="debug-mla-moe", long=True)),
+    ("ssm-hybrid-2k", dict(model="debug-ssm-hybrid", long=True)),
+    ("kda-hybrid-2k", dict(model="debug-kda-hybrid", long=True)),
 ]
 
 
@@ -116,7 +122,7 @@ def _struct(x):
 
 
 def _engine(model, mesh=None, spec=False, use_pallas=None, mixed=True,
-            wide=False, quant=None):
+            wide=False, quant=None, long=False):
     import dataclasses
 
     from kubernetes_gpu_cluster_tpu.config import (
@@ -130,20 +136,23 @@ def _engine(model, mesh=None, spec=False, use_pallas=None, mixed=True,
         m = dataclasses.replace(m, num_heads=8, num_kv_heads=4, head_dim=64)
     if quant:
         m = dataclasses.replace(m, quantization=quant)
+    budget, pages = (2048, 513) if long else (32, 129)
     cfg = EngineConfig(
-        model=m, cache=CacheConfig(page_size=8, num_pages=129),
+        model=m, max_model_len=2048 if long else None,
+        cache=CacheConfig(page_size=8, num_pages=pages),
         scheduler=SchedulerConfig(
-            max_num_seqs=4, max_prefill_tokens=32, decode_buckets=(1, 2, 4),
-            prefill_buckets=(16, 32), decode_window=2,
-            mixed_batch_enabled=mixed, spec_decode_enabled=spec,
-            num_speculative_tokens=3))
+            max_num_seqs=4, max_prefill_tokens=budget,
+            decode_buckets=(1, 2, 4), prefill_buckets=(budget // 2, budget),
+            decode_window=2, mixed_batch_enabled=mixed,
+            spec_decode_enabled=spec, num_speculative_tokens=3))
     return LLMEngine(cfg, mesh=make_mesh(**mesh) if mesh else None,
                      use_pallas=use_pallas)
 
 
-def _run_wave(eng, tag):
+def _run_wave(eng, tag, long=False):
     """Staggered arrivals: sub-bucket, bucket-edge and chunked prompts,
-    repetitive ones (n-gram drafts hit), greedy and seeded sampled rows."""
+    repetitive ones (n-gram drafts hit), greedy and seeded sampled rows.
+    ``long``: prompts of 1100 and 1700 tokens beside decoding rows."""
     import numpy as np
 
     from kubernetes_gpu_cluster_tpu.engine import SamplingParams
@@ -152,6 +161,9 @@ def _run_wave(eng, tag):
     prompts = [pattern * 4, rng.integers(1, 200, 12).tolist(), pattern * 7,
                rng.integers(1, 200, 90).tolist(), pattern * 2,
                (pattern * 20)[:70], rng.integers(1, 200, 30).tolist()]
+    if long:
+        prompts = [prompts[1], rng.integers(1, 200, 1100).tolist(),
+                   prompts[6], rng.integers(1, 200, 1700).tolist()]
     pending = [(f"{tag}-{i}", list(p),
                 SamplingParams(max_tokens=8, temperature=0.0) if i % 3 != 1
                 else SamplingParams(max_tokens=8, temperature=0.8, top_k=5,
@@ -203,8 +215,8 @@ def dump(out_path):
             if hasattr(fn, "_cache_size"):
                 wrapper._cache_size = fn._cache_size
             setattr(eng, attr, wrapper)
-        out[f"{tag}:OUTPUTS"] = json.dumps(_run_wave(eng, tag),
-                                           sort_keys=True)
+        out[f"{tag}:OUTPUTS"] = json.dumps(
+            _run_wave(eng, tag, kw.get("long", False)), sort_keys=True)
         print(tag, dict(eng.obs.step_kind_counts))
         eng_k = _engine(use_pallas=True, wide=True, **kw)
         for key, (attr, static, sargs) in shapes.items():

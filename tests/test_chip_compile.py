@@ -121,3 +121,88 @@ def test_kda_chunk_compiles_for_a_v5e_without_copies(one_chip,
     operand = T * H * d * 4
     # u [T, H, d] and the 32 chunks' [H x d, d] states, plus small change
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * operand
+
+
+@pytest.mark.parametrize("preset,overrides,kernels", [
+    ("granite-4.0-h-micro", {},
+     ("flash_prefill_hist", "ssm_update", "paged_decode", "kv_write")),
+    ("kimi-vl-a3b", {"num_hidden_layers": 9},
+     ("flash_prefill", "latent_prefill_hist", "grouped_matmul",
+      "latent_paged_decode", "kv_write")),
+    ("kimi-linear-48b-a3b",
+     {"num_hidden_layers": 9, "experts_held": 64, "vocab_size": 40960},
+     ("kda_chunk", "kda_update", "flash_prefill", "latent_prefill_hist",
+      "grouped_matmul", "latent_paged_decode", "kv_write"))])
+def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
+        one_chip, no_compile_cache, preset, overrides, kernels):
+    """The WHOLE mixed step program a prompt of 1025-1536 tokens rides beside
+    full seats, (1536, 64), at the cut and the pool the ``batch-decode-2k``
+    cells serve, with every kernel on: the scheduler's own batch gives the
+    shapes, no weight is drawn. Its scratch stays inside what the engine
+    sets aside for a step (``step_workspace_bytes``, sized at 2048 + 64)."""
+    import numpy as np
+
+    from kubernetes_gpu_cluster_tpu.config import (
+        CacheConfig, EngineConfig, SchedulerConfig, apply_hf_overrides,
+        get_model_config)
+    from kubernetes_gpu_cluster_tpu.engine import SamplingParams
+    from kubernetes_gpu_cluster_tpu.engine.engine import (
+        LLMEngine, _pack_float_b, _pack_int_b, step_workspace_bytes)
+    from kubernetes_gpu_cluster_tpu.engine.kv_cache import (
+        allocate_kv_cache, default_state_slots)
+    from kubernetes_gpu_cluster_tpu.engine.sampling_params import (
+        LOGIT_BIAS_CAP)
+    from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
+    from kubernetes_gpu_cluster_tpu.engine.sequence import Sequence
+    from kubernetes_gpu_cluster_tpu.models import llama as model_lib
+    from kubernetes_gpu_cluster_tpu.ops.attention import Kernels
+
+    model = apply_hf_overrides(get_model_config(preset),
+                               overrides).replace(dtype="bfloat16")
+    pages, seats = 2049, 64
+    cfg = EngineConfig(model=model, max_model_len=4096,
+                       cache=CacheConfig(page_size=128, num_pages=pages),
+                       scheduler=SchedulerConfig(max_num_seqs=seats))
+    slots = default_state_slots(model, seats)
+    sched = Scheduler(cfg, pages, num_state_slots=slots)
+    rows = [Sequence(f"r{i}", [1, 2, 3, 4], SamplingParams(max_tokens=64))
+            for i in range(seats - 1)]
+    for seq in rows:
+        sched.add(seq)
+    assert sched.schedule().kind == "prefill"
+    for seq in rows:
+        seq.append_token(7)
+    sched.add(Sequence("head", list(range(1, 1101)),
+                       SamplingParams(max_tokens=64)))
+    batch = sched.schedule()
+    assert batch.kind == "mixed" and len(batch.tokens) == 1536 + seats
+
+    # an engine's step program without an engine's weights
+    shell = object.__new__(LLMEngine)
+    shell.config, shell.model_config, shell.mesh = cfg, model, None
+    shell.kernels = Kernels(use_pallas=True, use_pallas_hist=True,
+                            grouped_experts=model.is_moe)
+    shell._last_width = seats
+    B = len(batch.temperature)
+    args = (jax.eval_shape(
+                lambda: model_lib.init_params(model, jax.random.key(0))),
+            jax.eval_shape(
+                lambda: allocate_kv_cache(model, cfg.cache, pages, None,
+                                          slots)),
+            np.zeros(seats, np.int32),
+            np.stack([batch.tokens, batch.seg_ids, batch.positions,
+                      batch.slot_mapping]),
+            _pack_int_b(batch), _pack_float_b(batch), batch.chunk_page_table,
+            np.int32(batch.hist_len), batch.page_tables, batch.context_lens,
+            np.full((B, cfg.effective_max_len), -1, np.int32),
+            np.full((B, LOGIT_BIAS_CAP), -1, np.int32),
+            np.zeros((B, LOGIT_BIAS_CAP), np.float32),
+            jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = shell._build_mixed_fn().lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        args)).compile()
+    text = compiled.as_text()
+    for name in kernels:
+        assert f"%{name}." in text, name
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < step_workspace_bytes(cfg))

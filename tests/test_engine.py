@@ -385,12 +385,13 @@ class TestLogprobs:
 # flight must give what the same engine gives when it schedules each step
 # only after the one before it was fetched ----------------------------------
 
-def _queue_engine(model="debug-tiny", eos=None, num_pages=128, **sched):
+def _queue_engine(model="debug-tiny", eos=None, num_pages=128,
+                  max_model_len=None, **sched):
     kw = dict(max_num_seqs=4, max_prefill_tokens=32, decode_buckets=(1, 2, 4),
               prefill_buckets=(16, 32), decode_window=4)
     kw.update(sched)
     return LLMEngine(EngineConfig(
-        model=get_model_config(model),
+        model=get_model_config(model), max_model_len=max_model_len,
         cache=CacheConfig(page_size=8, num_pages=num_pages),
         scheduler=SchedulerConfig(**kw)), eos_token_id=eos)
 
@@ -621,6 +622,81 @@ def test_warm_short_mixed_meets_a_short_prompts_step_beside_full_seats():
     assert warm.compiled_step_variants() == cold.compiled_step_variants()
     assert warm._mixed_fn._cache_size() == cold._mixed_fn._cache_size()
     assert met < warm.compiled_step_variants()      # the load met others
+
+
+def _rung_engine(monkeypatch, kind, **sched):
+    """An engine on a server's top chunk buckets (the rung of
+    ``mixed_chunk_buckets`` between them), and the token widths of the
+    ``kind`` steps its scheduler hands out."""
+    eng = _queue_engine(sched.pop("model", "debug-tiny"), num_pages=400,
+                        max_model_len=2048, max_prefill_tokens=2048,
+                        prefill_buckets=(32, 1024, 2048), decode_window=2,
+                        **sched)
+    widths = []
+    schedule = eng.scheduler.schedule
+
+    def spy(*args, **kw):
+        batch = schedule(*args, **kw)
+        if batch is not None and batch.kind == kind:
+            widths.append(len(batch.tokens))
+        return batch
+    monkeypatch.setattr(eng.scheduler, "schedule", spy)
+    return eng, widths
+
+
+def _take_the_rung_away(monkeypatch):
+    monkeypatch.setattr(SchedulerConfig, "mixed_chunk_buckets",
+                        property(lambda self: self.prefill_buckets))
+
+
+_LONG = [int(t) for t in np.random.default_rng(3).integers(1, 200, 1100)]
+
+
+@pytest.mark.parametrize("model", ["debug-tiny", "debug-mla-moe",
+                                   "debug-ssm-hybrid", "debug-kda-hybrid"])
+def test_a_chunk_in_the_rung_is_served_as_in_the_bucket_above(model,
+                                                              monkeypatch):
+    """A prompt of 1100 tokens rides its mixed step beside a decoding row in
+    the 1536-token rung of ``mixed_chunk_buckets``; with the rung taken away
+    the same engine runs it in the 2048-token program. Padding tokens carry
+    segment -1 and write the scrap page, and every matmul, scan and
+    attention row is a row's own: both requests get the same tokens
+    whichever program served them, and the decoding row the same logprobs
+    bit for bit. The prompt's own agree to float32 rounding only: the XLA
+    reference attention (what the CPU runs) sums a query's softmax over the
+    step's whole key axis, masked padding included, and that sum's tiling
+    follows the axis' width (0.7e-6 to 1.9e-6 apart in these four presets)."""
+    eng, widths = _rung_engine(monkeypatch, "mixed", model=model)
+    p = SamplingParams(max_tokens=12, temperature=0.0, logprobs=True)
+    arrivals = [(0, "held", _P[1], p), (2, "long", _LONG, p)]
+    with_rung = _drive(eng, arrivals)
+    assert widths == [1536 + 4]
+    _take_the_rung_away(monkeypatch)
+    without = _drive(eng, arrivals)
+    assert widths == [1536 + 4, 2048 + 4]
+    assert without["held"] == with_rung["held"]
+    assert without["long"][:2] == with_rung["long"][:2]
+    assert len(with_rung["long"][2]) == 12
+    np.testing.assert_allclose(without["long"][2], with_rung["long"][2],
+                               rtol=0, atol=1e-5)
+
+
+def test_a_speculative_mixed_step_takes_the_rung_too(monkeypatch):
+    """The chunk of a mixed step that carries verify slices buckets on the
+    same ladder: 1100 tokens beside one speculating row ride 1536 + (k + 1)
+    tokens, and the same tokens come out as from the 2048-token program."""
+    eng, widths = _rung_engine(monkeypatch, "spec_mixed",
+                               spec_decode_enabled=True,
+                               num_speculative_tokens=3)
+    p = SamplingParams(max_tokens=40, temperature=0.0)
+    arrivals = [(0, "held", _P[0][:4] * 7, p), (2, "long", _LONG, p)]
+    with_rung = _drive(eng, arrivals)
+    assert widths == [1536 + 4]
+    _take_the_rung_away(monkeypatch)
+    without = _drive(eng, arrivals)
+    assert widths == [1536 + 4, 2048 + 4]
+    assert {k: v[:2] for k, v in without.items()} == {
+        k: v[:2] for k, v in with_rung.items()}
 
 
 # -- the step record: one a dispatched program, its own number and clock ------

@@ -69,13 +69,16 @@ class TestPolicy:
         assert mixed_row_bucket(rows, chunk_bucket, grid) == want
 
     def test_row_bucket_floor_bounds_the_program_family(self):
-        """(chunk bucket x row bucket) on the default grid: 11 step programs
-        where every row bucket beside every chunk bucket made 35."""
+        """(chunk bucket x row bucket) on the default grid, the rung at 1536
+        tokens among the chunk's: 12 step programs where every row bucket
+        beside every chunk bucket made 42."""
         sc = SchedulerConfig()
+        assert len(sc.mixed_chunk_buckets) * len(sc.decode_buckets) == 42
         met = {(t, mixed_row_bucket(r, t, sc.decode_buckets))
-               for t in sc.prefill_buckets
+               for t in sc.mixed_chunk_buckets
                for r in range(1, sc.decode_buckets[-1] + 1)}
-        assert len(met) == 11
+        assert len(met) == 12
+        assert (1536, 64) in met
         assert all(16 * rows >= min(t, 16 * sc.decode_buckets[-1])
                    for t, rows in met)
 

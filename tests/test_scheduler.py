@@ -215,3 +215,65 @@ class TestDecodeWindow:
         assert batch.kind == "decode"
         # positions 4..9 -> 10 slots -> 3 pages of 4
         assert len(seq.pages) == 3
+
+
+class TestChunkLadders:
+    """The default grid at a server's size: the mixed step's chunk has a
+    rung at 1536 tokens (``SchedulerConfig.mixed_chunk_buckets``); a packed
+    prefill's total and a solo chunk keep ``prefill_buckets``."""
+
+    @staticmethod
+    def _sched():
+        cfg = EngineConfig(
+            model=get_model_config("debug-tiny"), max_model_len=4096,
+            cache=CacheConfig(page_size=16, num_pages=1024),
+            scheduler=SchedulerConfig())
+        return Scheduler(cfg, 1024)
+
+    @pytest.mark.parametrize("tokens,want", [
+        (1024, 1024), (1025, 1536), (1536, 1536), (1537, 2048), (1920, 2048)])
+    def test_mixed_chunk_beside_63_rows(self, tokens, want):
+        sched = self._sched()
+        rows = [_seq(f"r{i}", 4) for i in range(63)]
+        for seq in rows:
+            sched.add(seq)
+        assert sched.schedule().kind == "prefill"
+        for seq in rows:
+            seq.append_token(7)
+        sched.add(_seq("head", tokens))
+        batch = sched.schedule()
+        assert batch.kind == "mixed" and batch.prefill_token_count == tokens
+        assert len(batch.tokens) == want + 64
+        assert len(batch.context_lens) == 64
+
+    @pytest.mark.parametrize("tokens,want", [
+        (1024, 1024), (1025, 2048), (1536, 2048), (1537, 2048), (1920, 2048)])
+    def test_packed_prefill_total_keeps_its_ladder(self, tokens, want):
+        sched = self._sched()
+        sched.add(_seq("a", tokens - 500))
+        sched.add(_seq("b", 500))
+        batch = sched.schedule()
+        assert batch.kind == "prefill" and len(batch.seqs) == 2
+        assert len(batch.tokens) == want
+
+    @pytest.mark.parametrize("tokens,want", [
+        (1024, 1024), (1025, 2048), (1536, 2048), (1537, 2048), (1920, 2048)])
+    def test_solo_chunk_keeps_its_ladder(self, tokens, want):
+        """A prompt over the step's budget with nothing decoding beside it:
+        a chunk of 2048 tokens, then one of ``tokens`` with history."""
+        sched = self._sched()
+        sched.add(_seq("long", 2048 + tokens))
+        assert len(sched.schedule().tokens) == 2048
+        batch = sched.schedule()
+        assert batch.kind == "prefill" and batch.hist_len == 2048
+        assert len(batch.tokens) == want
+
+    @pytest.mark.parametrize("buckets,want", [
+        ((128, 256, 512, 1024, 2048), (128, 256, 512, 1024, 1536, 2048)),
+        ((1024, 4096), (1024, 2560, 4096)),
+        ((16, 32, 64), (16, 32, 64)),        # under 1024: as it was
+        ((512, 1024), (512, 1024)),
+        ((2048,), (2048,))])
+    def test_the_rung_comes_from_the_top_two_buckets(self, buckets, want):
+        sc = SchedulerConfig(prefill_buckets=buckets)
+        assert sc.mixed_chunk_buckets == want
