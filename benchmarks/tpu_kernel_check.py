@@ -273,34 +273,70 @@ def check_prefill(nh, n_kv, hd, T) -> None:
     assert err < TOL, err
 
 
-def check_prefill_history(nh, n_kv, hd, pps, T) -> None:
-    # A full chunk over 3.5 pages of history, stacked pool + layer index.
-    L = 2
-    hist_len = 3 * PS + 70
-    rng = np.random.default_rng(4)
-    q = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((T, n_kv, hd)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((T, n_kv, hd)), jnp.bfloat16)
-    pad = 32
-    seg = jnp.asarray(np.where(np.arange(T) < T - pad, 0, -1), jnp.int32)
-    pos = jnp.asarray(np.where(np.arange(T) < T - pad,
-                               hist_len + np.arange(T), 0), jnp.int32)
-    pool_k = jnp.asarray(rng.standard_normal((L, 1 + pps, PS, n_kv * hd)),
-                         jnp.bfloat16)
-    pool_v = jnp.asarray(rng.standard_normal((L, 1 + pps, PS, n_kv * hd)),
-                         jnp.bfloat16)
-    pt = jnp.asarray(1 + np.arange(pps), jnp.int32)
-    hl = jnp.asarray(hist_len, jnp.int32)
-    scale = hd ** -0.5
-    layer = jnp.asarray(1, jnp.int32)
+# The chunk kernel against the XLA reference at HIGHEST precision on float32
+# copies of the same bf16 values: what is left is the bf16 rounding of the
+# kernel's own output (a relative 2^-8, half a unit in its last place) and
+# a float32 sum's noise.
+HIST_RTOL = 2.0 ** -8 * 1.02
+HIST_ATOL = 2e-5
+HIST_CHAIN = 8
 
-    ref = prefill_history_attention_xla(q, k, v, seg, pos, pool_k, pool_v,
-                                        pt, hl, scale, layer=layer)
-    out = jax.jit(lambda *a: flash_prefill_history(*a, scale, layer=layer))(
-        q, k, v, seg, pos, pool_k, pool_v, pt, hl)
-    err = _err(out, ref, np.asarray(seg) >= 0)
-    print(f"flash_prefill_hist T={T} pps={pps}: max|pallas-xla| = {err:.4f}")
-    assert err < TOL, err
+
+def check_prefill_history(nh, n_kv, hd, scale, chunks=(1536, 2048)) -> None:
+    """``flash_prefill_hist`` at the model's geometry: fresh chunks of the
+    mixed step's sizes (a short prompt's 128 and 256 tokens, a long one's
+    ``chunks``), then a 2048-token chunk over 2048 and 6144 tokens of history
+    (a page table of 64), the last of them with tail padding and a partial
+    page. Prints max |kernel - XLA HIGHEST| and the kernel's time a
+    call; exits 1 where an element is further from the reference than
+    ``HIST_RTOL`` of its value plus ``HIST_ATOL``."""
+    L, T_hist = 2, chunks[-1]
+    cases = [(T, 0, 32, 0) for T in (128, 256) + tuple(chunks)]
+    cases += [(T_hist, 2048, 64, 0), (T_hist, 6144, 64, 0),
+              (T_hist, 6144 - 58, 64, 32)]
+    layer = jnp.asarray(1, jnp.int32)
+    bad = []
+    for T, hist_len, pps, pad in cases:
+        rng = np.random.default_rng(4)
+
+        def bf(*shape):
+            return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+        q, k, v = bf(T, nh, hd), bf(T, n_kv, hd), bf(T, n_kv, hd)
+        real = np.arange(T) < T - pad
+        seg = jnp.asarray(np.where(real, 0, -1), jnp.int32)
+        pos = jnp.asarray(np.where(real, hist_len + np.arange(T), 0),
+                          jnp.int32)
+        pool_k, pool_v = (bf(L, 1 + pps, PS, n_kv * hd) for _ in range(2))
+        pt = jnp.asarray(1 + np.arange(pps), jnp.int32)
+        hl = jnp.asarray(hist_len, jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda q, k, v, pk, pv: prefill_history_attention_xla(
+                q, k, v, seg, pos, pk, pv, pt, hl, scale, layer=layer))(
+                    *(a.astype(jnp.float32)
+                      for a in (q, k, v, pool_k, pool_v)))
+
+        def fn(q, *rest):
+            return flash_prefill_history(q, *rest, scale, layer=layer)
+
+        def chain(q, *rest):
+            # HIST_CHAIN calls in one program, each on the last one's output:
+            # the kernel's time on the device, without a dispatch a call.
+            return jax.lax.fori_loop(0, HIST_CHAIN,
+                                     lambda _, x: fn(x, *rest), q)
+
+        args = (q, k, v, seg, pos, pool_k, pool_v, pt, hl)
+        d = jnp.abs(jax.jit(fn)(*args).astype(jnp.float32) - ref)[real]
+        over = float(jnp.max(d - HIST_RTOL * jnp.abs(ref[real])))
+        dt = _timed(jax.jit(chain), *args, n=5) / HIST_CHAIN
+        print(f"flash_prefill_hist T={T} hist={hist_len} pps={pps} "
+              f"pad={pad}: max|pallas-xla HIGHEST| = {float(jnp.max(d)):.5f}"
+              f" (over rtol 2^-8 by {over:.2e}); {dt * 1e3:.3f} ms a call")
+        if over > HIST_ATOL:
+            bad.append((T, hist_len, over))
+    if bad:
+        sys.exit(f"flash_prefill_hist beyond {HIST_RTOL:.5f} |ref| + "
+                 f"{HIST_ATOL}: {bad}")
 
 
 def check_kv_write(L, n_kv, hd, T) -> None:
@@ -1155,7 +1191,7 @@ def main() -> None:
                            time_decode(nh, n_kv, hd, pps, B,
                                        cfg.num_kv_layers)),
         "prefill": lambda: check_prefill(nh, n_kv, hd, T),
-        "hist": lambda: check_prefill_history(nh, n_kv, hd, pps, T),
+        "hist": lambda: check_prefill_history(nh, n_kv, hd, cfg.attn_scale),
         "kvwrite": lambda: [check_kv_write(cfg.num_kv_layers, n_kv, hd, n)
                             for n in (B, T)],
         "int4": check_int4_matmul,

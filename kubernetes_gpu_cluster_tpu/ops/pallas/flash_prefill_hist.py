@@ -7,35 +7,60 @@ allocation even when history is one page) and materializes [heads, T, H+T]
 scores; long prompts — the entire point of chunked prefill — paid that on
 every chunk (round-3 VERDICT weak #4).
 
-Design: grid (nq, pps + nk) with ALL heads fused into the row axis —
-q block [BQ, nh, hd] collapses (leading-dim reshape only) to [BQ*nh, hd]
-rows, embedded into the paged pool's flattened-lane space [BQ*nh, n_kv*hd]
-with the same compile-time iota-selector matmuls the decode kernel uses
-(Mosaic rejects lane-splitting reshapes AND sub-128 lane blocks — a
-per-head [.., hd=64] slice of the pool is unloadable, so scores for all
-heads come from one full-width contraction whose off-block products are
-zero by construction). The KV grid axis has two phases:
+``flash_prefill_history`` (K|V pools). Mosaic loads no sub-128-lane slice of
+the pool's flattened row [.., n_kv*hd], so the kernel works by LANE BLOCKS:
+``W`` = 128 lanes of the row (two kv heads at head_dim 64, one at 128; a
+head wider than 128 lanes takes its own width). Grid (kd/W lane blocks,
+q blocks); a step holds the q heads of ITS lane block only — block_q tokens
+x (W/hd kv heads x q_per_kv) heads as rows, token-major, laid out so on the
+host — embedded over the block's W lanes by a compile-time selector matmul
+(each row's own kv head's lanes, zeros in the block's other head: 2x the
+products at head_dim 64, no embed at all at 128), and float32 online-softmax
+accumulators [rows, W] whose own head's lanes are extracted at the end.
 
-- j < pps — HISTORY: block j is pool page ``page_table[j]``, addressed by
-  the BlockSpec index_map from the scalar-prefetched table (no gather; only
-  existing pages move, each read ONCE per q block). Every valid row attends
-  (history precedes the chunk); steps past ceil(hist_len/ps) clamp the
-  index_map so the pipeline dedups the fetch and ``pl.when`` skips compute.
-- j >= pps — CHUNK: flat-causal flash sweep over the chunk's K/V, host-
-  flattened to [T, n_kv*hd] so both phases share the same lane space and
-  the fp32 online-softmax accumulators ([BQ*nh, n_kv*hd], diagonal blocks
-  extracted at the end) persist across the whole j sweep.
+What a step walks is what exists, in loops of its own, not in grid steps:
+
+- CHUNK, first: the chunk's K/V for this lane block, host-flattened to
+  [T, kd], sits in VMEM whole (one fetch a lane block, shared by its q
+  blocks); a loop over the tiles of ``block_k`` keys that lie wholly under
+  the q block's first token (no mask at all), then the tile(s) on its
+  diagonal (causal and tail-padding mask). Nothing over the diagonal, and
+  nothing at all for a q block of tail padding.
+- HISTORY, second: ``ceil(hist_len / tile)`` tiles of ``block_k / ps`` pool
+  pages, ``page_table`` entries addressed from the scalar-prefetched table,
+  each tile's (ps, W) page pieces copied HBM->VMEM by the kernel's own
+  DMAs, ``_NUM_BUFS - 1`` tiles ahead of the arithmetic (the first copies
+  fly under the chunk phase); whole tiles unmasked, the last partial one
+  masked by ``hist_len``. No history, no copy and no step. Each page piece
+  is read once a q block.
+
+Tiles are WIDE (512 keys): what a row costs a tile (its statistics, the two
+reductions over its scores, its accumulator's update) does not depend on the
+tile's width; with tiles of 128 keys the kernel took 2.6x as long (one v5e,
+granite-4.0-h-micro's 32 q / 8 kv heads of 64: 1.58 against 0.60 ms a fresh
+2048-token chunk; PERF.md section 6, PR 41).
+
+The MXU sees operands in the pool's dtype with float32 accumulation and no
+precision given up: q . k on bf16 values is exact in one pass (``scale``
+multiplies the float32 scores), and the float32 probabilities go to P . V
+as two bf16 terms, p = hi + lo (2^-17 of a term left over), one pass each.
+A float32 pool (the CPU tests) keeps float32 operands.
 
 The scheduler admits chunked prefills solo with tail padding, so flat order
 equals position order and validity is just ``index < n_valid`` (passed as a
-prefetched scalar). Replaces the vLLM chunked-prefill path the reference
-ran inside CUDA images (engine args surfaced at reference
-``values-01-minimal-example8.yaml:24-38``).
+prefetched scalar); tail-padding rows come out as zeros. Replaces the vLLM
+chunked-prefill path the reference ran inside CUDA images (engine args
+surfaced at reference ``values-01-minimal-example8.yaml:24-38``).
+
+``flash_prefill_history_shared`` (one pool of shared rows, latent
+attention) is the earlier design: grid (q blocks, pages + chunk blocks),
+all heads on the row axis, pages through BlockSpec index maps.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,136 +68,209 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+# Slots of the history stream: one computed on, three tiles of pages in
+# flight ahead of it, as ``paged_decode`` keeps (a tile is 2 x 128 KiB at the
+# served geometry, its arithmetic about three microseconds).
+_NUM_BUFS = 4
+# VMEM for what grows with a q block's rows: accumulator, embedded q, softmax
+# statistics and a step's score tiles.
+_ROW_BUDGET = 12 << 20
+_NN, _NT = (((1,), (0,)), ((), ())), (((1,), (1,)), ((), ()))
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _hist_kernel(
     # scalar prefetch
     pt_ref,       # [pps] int32 page table (this sequence's pages)
     meta_ref,     # [3] int32: (hist_len, layer, n_valid)
-    # blocked inputs
-    q_ref,        # [BQ, nh, hd] VMEM
-    kp_ref,       # [1, 1, ps, kd] VMEM (one pool page, all kv heads' lanes)
-    vp_ref,       # [1, 1, ps, kd]
-    kc_ref,       # [BK, kd] VMEM (chunk keys, heads pre-flattened on host)
-    vc_ref,       # [BK, kd]
-    out_ref,      # [BQ, nh, hd]
+    # inputs
+    q_ref,        # [1, BQ*hq, hd] VMEM: this lane block's heads, token-major
+    kp_hbm,       # [L, P, ps, kd] HBM (the whole pool)
+    vp_hbm,       # [L, P, ps, kd]
+    kc_ref,       # [Tp, W] VMEM (chunk keys, this lane block's lanes)
+    vc_ref,       # [Tp, W]
+    out_ref,      # [1, BQ*hq, hd]
     # scratch
-    m_scr,        # [BQ*nh, 1] f32
-    l_scr,        # [BQ*nh, 1] f32
-    acc_scr,      # [BQ*nh, kd] f32
-    qbd_scr,      # [BQ*nh, kd] f32 (block-diagonal Q, built once per q block)
+    m_scr,        # [BQ*hq, 1] f32
+    l_scr,        # [BQ*hq, 1] f32
+    acc_scr,      # [BQ*hq, W] f32
+    qbd_scr,      # [BQ*hq, W] operand dtype: q over the block's lanes
+    k_buf,        # [NBUF, C*ps, W] pool dtype
+    v_buf,        # [NBUF, C*ps, W]
+    sems,         # DMA semaphores [NBUF, 2, C]
     *,
     scale: float,
     block_q: int,
-    block_k: int,
+    block_k: int,      # keys of a chunk tile
+    group_pages: int,  # C: pool pages of a history tile
     page_size: int,
     pps: int,
-    num_kv: int,
+    heads: int,        # q heads of a lane block (hq)
     q_per_kv: int,
     head_dim: int,
+    lanes: int,        # W
 ):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    nk_total = pl.num_programs(1)
+    b = pl.program_id(0)
+    i = pl.program_id(1)
     hist_len = meta_ref[0]
+    layer = meta_ref[1]
     n_valid = meta_ref[2]
-    ps = page_size
-    nh = num_kv * q_per_kv
-    kd = num_kv * head_dim
-    rows = block_q * nh
+    ps, bk, W, C = page_size, block_k, lanes, group_pages
+    hk = C * ps                        # keys of a history tile
+    rows = block_q * heads
+    cdt = qbd_scr.dtype
+    f32 = jnp.float32
+    q0 = i * block_q
+    live = q0 < n_valid                # else a q block of tail padding
+    lane0 = pl.multiple_of(b * W, W)
 
-    # Selector constants (cheap iota compares; the expensive embed matmul
-    # runs once per q block, below). Row r is (token i*BQ + r//nh, head
-    # r%nh); its kv block is (r%nh)//g.
-    lane_d = jax.lax.broadcasted_iota(jnp.int32, (head_dim, kd), 1) % head_dim
-    row_d = jax.lax.broadcasted_iota(jnp.int32, (head_dim, kd), 0)
-    tiler = (lane_d == row_d).astype(jnp.float32)             # [hd, kd]
-    lane_kv = jax.lax.broadcasted_iota(jnp.int32, (rows, kd), 1) // head_dim
-    row_kv = (jax.lax.broadcasted_iota(jnp.int32, (rows, kd), 0)
-              % nh) // q_per_kv
-    bdmask = (lane_kv == row_kv).astype(jnp.float32)          # [rows, kd]
+    # -- the history stream: tiles of C pages, this block's lanes -----------
+    n_groups = jnp.where(live, pl.cdiv(hist_len, hk), 0)
 
-    @pl.when(j == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, jnp.float32(NEG))
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        # Block-diagonal embed Qbd[r, kb*hd:(kb+1)*hd] = q[r] iff kb == kv(r)
-        # (the decode kernel's reshape-free selector matmul), built ONCE per
-        # q block into scratch — the grid executes all pps+nk steps even when
-        # pl.when skips their compute, and re-embedding per step would cost
-        # ~half an active step's MXU work on every skipped step.
-        q2 = q_ref[...].reshape(rows, head_dim).astype(jnp.float32) * scale
-        qbd_scr[:] = jax.lax.dot_general(
-            q2, tiler, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * bdmask
+    def group_copies(g, slot):
+        for c in range(C):
+            # Entries past the history's last page are the table's padding
+            # (valid memory, masked below).
+            page = pt_ref[jnp.minimum(g * C + c, pps - 1)]
+            for which, (hbm, buf) in enumerate(((kp_hbm, k_buf),
+                                                (vp_hbm, v_buf))):
+                yield pltpu.make_async_copy(
+                    hbm.at[layer, page, :, pl.ds(lane0, W)],
+                    buf.at[slot, pl.ds(c * ps, ps)],
+                    sems.at[slot, which, c])
 
-    qbd = qbd_scr[:]
+    def start_group(g):
+        @pl.when(g < n_groups)
+        def _():
+            for copy in group_copies(g, jax.lax.rem(g, _NUM_BUFS)):
+                copy.start()
 
-    # Per-row token index and validity (tail padding: valid <=> tok < n_valid).
-    row_tok = (i * block_q
-               + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // nh)
-    qvalid = row_tok < n_valid                                # [rows, 1]
+    for d in range(_NUM_BUFS - 1):
+        start_group(jnp.int32(d))
 
-    def online_update(s, mask, vv):
-        s = jnp.where(mask, s, NEG)
+    # -- this q block: statistics, and q over the lane block ----------------
+    m_scr[:] = jnp.full_like(m_scr, f32(NEG))
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    embed = W != head_dim
+    if embed:
+        # Row r is (token q0 + r // hq, head r % hq of the block); its kv
+        # head's lanes are ((r % hq) // q_per_kv) * hd onwards. The embed and
+        # the extraction are matmuls against 0/1 selectors, exact (a bf16
+        # value in one pass, a float32 one at HIGHEST: Mosaic's default
+        # would round it to bf16): no reshape touches the lane dimension.
+        lane_d = jax.lax.broadcasted_iota(jnp.int32, (head_dim, W), 1) % head_dim
+        row_d = jax.lax.broadcasted_iota(jnp.int32, (head_dim, W), 0)
+        tiler = lane_d == row_d                               # [hd, W]
+        lane_kv = jax.lax.broadcasted_iota(jnp.int32, (rows, W), 1) // head_dim
+        row_kv = (jax.lax.broadcasted_iota(jnp.int32, (rows, W), 0)
+                  % heads) // q_per_kv
+        own = lane_kv == row_kv                               # [rows, W]
+        qbd_scr[:] = jnp.where(own, jax.lax.dot_general(
+            q_ref[0], tiler.astype(cdt), _NN, preferred_element_type=f32,
+            precision=None if cdt == jnp.bfloat16 else _HI), 0.0).astype(cdt)
+    else:
+        qbd_scr[:] = q_ref[0]
+    row_tok = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+
+    def online_update(kk, vv, mask):
+        """One tile of keys and values [keys, W]. ``mask`` None: every row
+        attends every column. A masked score's probability is exp(NEG - m)
+        = 0 exactly once its row has met a real column, which the chunk's
+        first block gives every real row (its column 0); tail-padding rows
+        carry garbage and are zeroed at the end."""
+        s = jax.lax.dot_general(qbd_scr[:], kk.astype(cdt), _NT,
+                                preferred_element_type=f32) * scale
+        if mask is not None:
+            s = jnp.where(mask, s, NEG)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, vv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        vv = vv.astype(cdt)
+        if cdt == jnp.bfloat16:
+            # p = hi + lo in two bf16 terms: two one-pass products keep the
+            # float32 probabilities (2^-17 of a term left over).
+            hi = p.astype(cdt)
+            lo = (p - hi.astype(f32)).astype(cdt)
+            pv = (jax.lax.dot_general(hi, vv, _NN, preferred_element_type=f32)
+                  + jax.lax.dot_general(lo, vv, _NN,
+                                        preferred_element_type=f32))
+        else:
+            pv = jax.lax.dot_general(p, vv, _NN, preferred_element_type=f32)
+        acc_scr[:] = acc_scr[:] * alpha + pv
 
-    # -- history phase: one pool page, all valid rows attend -----------------
-    n_pages = pl.cdiv(hist_len, ps)
+    # -- chunk phase: the blocks at or under this q block's diagonal --------
+    def chunk_block(jj, masked):
+        at = pl.ds(pl.multiple_of(jj * bk, bk), bk)
+        mask = None
+        if masked:
+            cols = jj * bk + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+            mask = (cols <= row_tok) & (cols < n_valid)
+        online_update(kc_ref[at, :], vc_ref[at, :], mask)
 
-    @pl.when(jnp.logical_and(j < pps, j < n_pages))
+    # Blocks wholly under the q block's first token need no mask.
+    n_open = jnp.where(live, (q0 + 1) // bk, 0)
+    n_blocks = jnp.where(
+        live, pl.cdiv(jnp.minimum(q0 + block_q, n_valid), bk), 0)
+    jax.lax.fori_loop(0, n_open,
+                      lambda jj, _: chunk_block(jj, False), None)
+    jax.lax.fori_loop(n_open, n_blocks,
+                      lambda jj, _: chunk_block(jj, True), None)
+
+    # -- history phase: every real row attends every history token ----------
+    def history_group(g, masked):
+        slot = jax.lax.rem(g, _NUM_BUFS)
+        start_group(g + _NUM_BUFS - 1)
+        for copy in group_copies(g, slot):
+            copy.wait()
+        mask = None
+        if masked:
+            cols = g * hk + jax.lax.broadcasted_iota(jnp.int32, (rows, hk), 1)
+            mask = cols < hist_len
+        online_update(k_buf[slot], v_buf[slot], mask)
+
+    n_whole = jnp.where(live, hist_len // hk, 0)
+    jax.lax.fori_loop(0, n_whole,
+                      lambda g, _: history_group(g, False), None)
+
+    @pl.when(n_whole < n_groups)
     def _():
-        kk = kp_ref[0, 0].astype(jnp.float32)                 # [ps, kd]
-        vv = vp_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(qbd, kk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        cols = (j * ps
-                + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1))
-        online_update(s, (cols < hist_len) & qvalid, vv)
+        history_group(n_whole, True)
 
-    # -- chunk phase: flat-causal over the in-batch K/V ----------------------
-    jj = j - pps
+    # -- this block's own lanes of the accumulator, over the sum ------------
+    acc = acc_scr[:]
+    if embed:
+        acc = jax.lax.dot_general(jnp.where(own, acc, 0.0),
+                                  tiler.astype(f32), _NT,
+                                  preferred_element_type=f32, precision=_HI)
+    l = l_scr[:]
+    out = acc / jnp.where(l > 0, l, 1.0)
+    out_ref[0] = jnp.where(row_tok < n_valid, out, 0.0).astype(out_ref.dtype)
 
-    @pl.when(jnp.logical_and(j >= pps,
-                             jj * block_k <= i * block_q + block_q - 1))
-    def _():
-        kk = kc_ref[...].astype(jnp.float32)                  # [BK, kd]
-        vv = vc_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(qbd, kk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        cols = (jj * block_k
-                + jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 1))
-        online_update(s, (cols <= row_tok) & (cols < n_valid) & qvalid, vv)
 
-    @pl.when(j == nk_total - 1)
-    def _():
-        l = l_scr[:]
-        safe = jnp.where(l > 0, l, 1.0)   # fully-masked (padding) rows -> 0
-        out = jax.lax.dot_general(acc_scr[:] * bdmask, tiler,
-                                  (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32) / safe
-        out_ref[...] = out.reshape(block_q, nh, head_dim).astype(out_ref.dtype)
+def _lane_block(kd: int, head_dim: int) -> int:
+    """Lanes of the pool's row a grid step works on: whole kv heads in whole
+    128-lane tiles (two heads at head_dim 64, one at 128, a head's own width
+    beyond); a row that is no multiple of 128 lanes (interpret mode only) is
+    one block."""
+    W = math.lcm(head_dim, 128)
+    return W if kd % W == 0 else kd
 
 
 def flash_prefill_history(q, k, v, seg_ids, positions, k_pool, v_pool,
                           page_table, hist_len, scale, *, layer=None,
-                          block_q: int = None, block_k: int = 128,
+                          block_q: int = None, block_k: int = 512,
                           interpret: bool = False):
     """q: [T, nh, hd]; k/v: [T, n_kv, hd] (this chunk); k_pool/v_pool:
     [P, ps, n_kv*hd] or [L, P, ps, n_kv*hd] with ``layer``; page_table:
     [pps] int32; hist_len: [] int32; seg_ids: [T] (0 = chunk token, -1 =
     tail padding). ``positions`` accepted for dispatcher signature parity
-    (flat order implies causality — solo sequence). Returns [T, nh, hd]."""
+    (flat order implies causality — solo sequence). ``block_k``: the keys of
+    a tile (a history tile is whole pages of about as many, a chunk tile no
+    longer than the chunk). Returns [T, nh, hd]."""
     T, nh, hd = q.shape
     n_kv = k.shape[1]
     g = nh // n_kv
@@ -189,81 +287,95 @@ def flash_prefill_history(q, k, v, seg_ids, positions, k_pool, v_pool,
         raise ValueError("layer index required for stacked pool")
     ps = k_pool.shape[2]
     pps = page_table.shape[0]
+    W = _lane_block(kd, hd)
+    nb = kd // W
+    hq = (W // hd) * g                  # q heads of a lane block
+    # The MXU's operands: the pool's dtype where q, the chunk and the pool
+    # agree on bf16, float32 otherwise.
+    bf16 = all(a.dtype == jnp.bfloat16 for a in (q, k, v, k_pool, v_pool))
+    cdt = jnp.bfloat16 if bf16 else jnp.float32
+    isz = jnp.dtype(cdt).itemsize
+    # A tile of keys. A row's statistics, its accumulator and the two
+    # reductions over its scores are paid once a tile whatever its width
+    # (measured on a v5e at 1024 rows: 2.6 us a tile of 128 keys, 2.7 of 256,
+    # 3.05 of 512, of which the tile's products are ~2 at the MXU's rate), so
+    # tiles are wide; wider than 512 they lose on the chunk's diagonal.
+    bk = min(block_k, pl.cdiv(T, 16) * 16)
+    Tp = pl.cdiv(T, bk) * bk
+    C = min(max(1, block_k // ps), pps)       # pool pages of a history tile
+    # VMEM a row takes: its float32 accumulator and embedded q [W], its two
+    # statistics (a 128-lane tile each) and a tile's scores (s, p, the mask
+    # and the two bf16 terms: ~16 bytes a key).
+    per_row = W * (4 + isz) + 2 * 128 * 4 + 16 * max(bk, C * ps)
     if block_q is None:
-        # Every q block re-streams the whole history, so bigger q blocks cut
-        # history DMA bytes linearly; the ceiling is VMEM, where the fp32
-        # accumulator [BQ*nh, kd], the block-diagonal Qbd (same shape), and
-        # the per-iteration score/probability tiles all scale with BQ —
-        # budget the accumulator at ~2 MB (measured: 4 MB OOMs the 16 MB
-        # scoped vmem at BQ=128/kd=256/ps=128). TinyLlama (nh=32, kd=256):
-        # BQ=64; Llama-8B (nh=32, kd=1024): BQ=16.
-        block_q = max(8, min(128, (2 * 1024 * 1024 // (4 * kd * nh)) & ~7))
+        # Every q block re-streams the history, so the larger the better up
+        # to 128 tokens; the ceiling is the VMEM its rows take.
+        block_q = max(8, min(128, (_ROW_BUDGET // (per_row * hq)) & ~15))
     block_q = min(block_q, T)
-    block_k = min(block_k, T)
+    rows = block_q * hq
     nq = pl.cdiv(T, block_q)
-    nk = pl.cdiv(T, block_k)
 
-    # Flatten chunk K/V heads on the host (free in XLA; a lane-merging
-    # reshape inside the kernel would be Mosaic-unsupported).
-    kc = k.reshape(T, kd)
-    vc = v.reshape(T, kd)
+    # Host side (free or cheap in XLA; inside the kernel a lane-merging or
+    # sublane-splitting reshape would be Mosaic-unsupported): chunk K/V with
+    # heads flattened and padded to whole tiles of keys; q by lane block,
+    # rows token-major.
+    kc = k.reshape(T, kd).astype(cdt)
+    vc = v.reshape(T, kd).astype(cdt)
+    if Tp != T:
+        kc = jnp.pad(kc, ((0, Tp - T), (0, 0)))
+        vc = jnp.pad(vc, ((0, Tp - T), (0, 0)))
+    qb = (q.astype(cdt).reshape(T, nb, hq, hd).transpose(1, 0, 2, 3)
+          .reshape(nb, T * hq, hd))
     n_valid = jnp.sum(seg_ids >= 0).astype(jnp.int32)
     meta = jnp.stack([jnp.asarray(hist_len, jnp.int32).reshape(()),
                       jnp.asarray(layer, jnp.int32).reshape(()),
                       n_valid])
 
-    def page_idx(j, pt_ref, meta_ref):
-        # Clamp to the last valid page so steps past n_pages (and the whole
-        # chunk phase) keep a constant index -> the pipeline skips the fetch.
-        n_pages = pl.cdiv(meta_ref[0], ps)
-        return pt_ref[jnp.clip(jnp.minimum(j, n_pages - 1), 0, pps - 1)]
-
     kernel = functools.partial(_hist_kernel, scale=float(scale),
-                               block_q=block_q, block_k=block_k,
-                               page_size=ps, pps=pps, num_kv=n_kv,
-                               q_per_kv=g, head_dim=hd)
-
+                               block_q=block_q, block_k=bk, group_pages=C,
+                               page_size=ps, pps=pps, heads=hq, q_per_kv=g,
+                               head_dim=hd, lanes=W)
+    vmem = (rows * per_row
+            + 2 * 2 * rows * 128 * (isz + q.dtype.itemsize)  # q, out blocks
+            + 2 * 2 * Tp * W * isz                           # chunk K/V
+            + 2 * _NUM_BUFS * C * ps * W * k_pool.dtype.itemsize)  # pages
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nq, pps + nk),
+        grid=(nb, nq),
         in_specs=[
-            pl.BlockSpec((block_q, nh, hd), lambda i, j, pt, meta: (i, 0, 0),
+            pl.BlockSpec((1, rows, hd), lambda b, i, pt, meta: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, ps, kd),
-                         lambda i, j, pt, meta:
-                         (meta[1], page_idx(j, pt, meta), 0, 0),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((Tp, W), lambda b, i, pt, meta: (0, b),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, ps, kd),
-                         lambda i, j, pt, meta:
-                         (meta[1], page_idx(j, pt, meta), 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_k, kd),
-                         lambda i, j, pt, meta:
-                         (jnp.clip(j - pps, 0, nk - 1), 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_k, kd),
-                         lambda i, j, pt, meta:
-                         (jnp.clip(j - pps, 0, nk - 1), 0),
+            pl.BlockSpec((Tp, W), lambda b, i, pt, meta: (0, b),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((block_q, nh, hd),
-                               lambda i, j, pt, meta: (i, 0, 0),
+        out_specs=pl.BlockSpec((1, rows, hd), lambda b, i, pt, meta: (b, i, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((block_q * nh, 1), jnp.float32),
-            pltpu.VMEM((block_q * nh, 1), jnp.float32),
-            pltpu.VMEM((block_q * nh, kd), jnp.float32),
-            pltpu.VMEM((block_q * nh, kd), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, W), jnp.float32),
+            pltpu.VMEM((rows, W), cdt),
+            pltpu.VMEM((_NUM_BUFS, C * ps, W), k_pool.dtype),
+            pltpu.VMEM((_NUM_BUFS, C * ps, W), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((_NUM_BUFS, 2, C)),
         ],
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((T, nh, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((nb, T * hq, hd), q.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=max(32 << 20, 2 * vmem)),
         interpret=interpret,
         name="flash_prefill_hist",
-    )(page_table.astype(jnp.int32), meta, q, k_pool, v_pool, kc, vc)
-    return out
+    )(page_table.astype(jnp.int32), meta, qb, k_pool, v_pool, kc, vc)
+    return (out.reshape(nb, T, hq, hd).transpose(1, 0, 2, 3)
+            .reshape(T, nh, hd))
 
 
 # ---------------------------------------------------------------------------

@@ -123,6 +123,38 @@ def test_kda_chunk_compiles_for_a_v5e_without_copies(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * operand
 
 
+@pytest.mark.parametrize("nh,nkv,hd,T,pps", [
+    (32, 8, 64, 2048, 32),     # granite-4.0-h-micro: four lane blocks of two
+    (32, 8, 128, 2048, 32),    # qwen3-4b: eight lane blocks of one head
+    (32, 8, 128, 128, 32),     # ... a short prompt's chunk: one short tile
+    (4, 1, 128, 2048, 64),     # a tp=8 shard of it: kd 128, one block
+    (32, 4, 64, 2048, 16)],    # tinyllama: 16 q heads a lane block
+    ids=["granite", "qwen", "qwen-128", "tp-shard", "tinyllama"])
+def test_flash_prefill_hist_compiles_for_a_v5e(one_chip, no_compile_cache,
+                                               nh, nkv, hd, T, pps):
+    """The chunk kernel at served geometries, bf16, a stacked pool under a
+    dynamic layer index: Mosaic takes the 128-lane pieces of a page (a
+    dynamic lane offset in the kernel's own DMA), the chunk's K/V whole in
+    VMEM and a tile of 512 keys against block_q x heads rows. Beside q and
+    the output, laid out by lane block, nothing of their size is written."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill_hist import (
+        flash_prefill_history)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    i32 = jnp.int32
+    pool = arr((2, 2, 128, nkv * hd))
+    compiled = jax.jit(
+        lambda q, k, v, seg, pos, kp, vp, pt, hl, lyr: flash_prefill_history(
+            q, k, v, seg, pos, kp, vp, pt, hl, hd ** -0.5, layer=lyr)).lower(
+        arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hd)),
+        arr((T,), i32), arr((T,), i32), pool, pool, arr((pps,), i32),
+        arr((), i32), arr((), i32)).compile()
+    assert "%flash_prefill_hist" in compiled.as_text()
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= 2.5 * T * nh * hd * 2)
+
+
 @pytest.mark.parametrize("preset,overrides,kernels", [
     ("granite-4.0-h-micro", {},
      ("flash_prefill_hist", "ssm_update", "paged_decode", "kv_write")),

@@ -450,6 +450,64 @@ class TestFlashPrefillHistory:
                                    np.asarray(ref)[mask],
                                    rtol=2e-5, atol=2e-5)
 
+    # The geometries the kernel's 128-lane blocks split (two kv heads a
+    # block at head_dim 64, one at 128, a tp shard's single block), in the
+    # pool's dtype. bf16 inputs are held to the XLA reference at
+    # ``Precision.HIGHEST`` on float32 copies of the same bf16 values, within
+    # the rounding of the kernel's own bf16 output: a relative 2^-8 (bf16
+    # keeps 8 significant bits, so rounding to nearest moves a value by at
+    # most 2^-8 of itself), with 1 % of room and 1e-5 for the float32 sums
+    # under it. Anything coarser inside the kernel (a one-term bf16 p, a
+    # selector product that rounds the accumulator) adds its own rounding
+    # and fails. float32 inputs keep the float32 tolerance.
+    GEOMETRIES = {             # nh, nkv, hd
+        "hd64-kd512": (16, 8, 64),      # four lane blocks, two heads each
+        "hd128-kd256": (4, 2, 128),     # two lane blocks, one head each
+        "tp-shard-kd128": (4, 1, 128),  # one block
+    }
+    CASES = {                  # T, hist_len, pad (ps 16, a table of 8)
+        "fresh": (32, 0, 0),
+        "partial-page": (32, 13, 0),
+        "pages-and-tail-padding": (48, 89, 5),
+        "ragged-blocks": (40, 35, 3),   # T no multiple of block_q, block_k
+    }
+
+    def _check(self, geometry, case, dtype, **blocks):
+        from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill_hist import (
+            flash_prefill_history)
+        nh, nkv, hd = self.GEOMETRIES[geometry]
+        T, hist_len, pad = self.CASES[case]
+        (q, k, v, seg, pos, pk, pv, pt, hl, scale, oracle) = self._mk(
+            T, hist_len, nh=nh, nkv=nkv, hd=hd, ps=16, pps=8, pad=pad, seed=5)
+        q, k, v, pk, pv = (a.astype(dtype) for a in (q, k, v, pk, pv))
+        layer = jnp.asarray(1)
+        with jax.default_matmul_precision("highest"):
+            ref = oracle(*(a.astype(jnp.float32) for a in (q, k, v)), seg,
+                         pos, pk.astype(jnp.float32), pv.astype(jnp.float32),
+                         pt, hl, scale, layer=layer)
+        got = flash_prefill_history(q, k, v, seg, pos, pk, pv, pt, hl, scale,
+                                    layer=layer, interpret=True, **blocks)
+        assert got.dtype == dtype and got.shape == q.shape
+        tol = (dict(rtol=2.0 ** -8 * 1.01, atol=1e-5)
+               if dtype == jnp.bfloat16 else dict(rtol=2e-5, atol=2e-5))
+        mask = np.asarray(seg) >= 0
+        got = np.asarray(got.astype(jnp.float32))
+        np.testing.assert_allclose(got[mask], np.asarray(ref)[mask], **tol)
+        assert not got[~mask].any()       # tail padding comes out as zeros
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    @pytest.mark.parametrize("case", list(CASES))
+    @pytest.mark.parametrize("geometry", list(GEOMETRIES))
+    def test_lane_blocks_match_xla_highest(self, geometry, case, dtype):
+        self._check(geometry, case, dtype, block_q=16, block_k=32)
+
+    @pytest.mark.parametrize("geometry", list(GEOMETRIES))
+    def test_default_blocks(self, geometry):
+        """The blocks the wrapper derives itself: a history tile of the
+        whole table, a chunk tile of the chunk's length."""
+        self._check(geometry, "ragged-blocks", jnp.bfloat16)
+
 
 def test_flash_prefill_partial_final_block():
     """T not a multiple of block_k: the partial final K/V block's padding is
