@@ -1139,6 +1139,138 @@ def check_kda_chain(cfg, kernels, steps: int = 512, rows: int = 4) -> dict:
         KDA_CHAIN_LIMIT)
 
 
+# The stream mixers against float64 on the host. ``hc_pre``'s coefficients
+# are float32 values of at most 2: the limit on max |error| is the geometric
+# mean of the largest reading as served (2.09e-6) and the smallest with the
+# coefficients rounded to bfloat16 (3.85e-3). The streams leave both kernels
+# rounded to the model's dtype, which hides a coefficient's low bits from a
+# largest error; what tells them is the share of elements that are NOT the
+# float64 value correctly rounded: as served at most 4.09e-4, with bfloat16
+# coefficients at least 0.196, the limit their geometric mean again. Readings
+# on a v5e at xing4.0's widths, T = 64 and T = 2112 (PERF.md section 2).
+HC_COEF_LIMIT = 9e-5
+HC_MISROUNDED_LIMIT = 9e-3
+
+
+def check_hc_mix(cfg) -> None:
+    """``hc_pre`` / ``hc_post`` at the model's widths, a decode bucket (T =
+    64) and the widest mixed step (T = 2112): values against the equations
+    in float64 on the host, with a control that rounds the coefficients to
+    bfloat16 (``lax.reduce_precision``: the chip elides a pair of casts) and
+    must FAIL, then each kernel's time beside its XLA form's, against the
+    bytes it must move. Exits 1 beyond a limit or where the control passes."""
+    import kubernetes_gpu_cluster_tpu.engine  # noqa: F401 (before models)
+    from kubernetes_gpu_cluster_tpu.models import llama
+    from kubernetes_gpu_cluster_tpu.ops import hyper_conn as hcx
+    from kubernetes_gpu_cluster_tpu.ops.pallas import hc_mix
+    from perfbench import roofline_hc
+    n, d = cfg.hc_mult, cfg.hidden_size
+    hc, dt = hcx.settings(cfg), cfg.jnp_dtype
+    mix = llama._init_stream_mixers(cfg, 1, iter(jax.random.split(
+        jax.random.key(7), 8)), dt)
+    phi, alpha, bias = (mix[f"hc_attn_{k}"][0] for k in ("phi", "alpha",
+                                                         "bias"))
+    pre_l, post_l = np.arange(n), hcx.POST_AT + np.arange(n)
+    res_l = np.asarray(hcx.res_lanes(n))
+    sig = lambda v: 1.0 / (1.0 + np.exp(-v))
+    f64 = lambda a: np.asarray(a.astype(jnp.float32), np.float64)
+    to_dt = lambda a: np.asarray(jnp.asarray(a, jnp.float32).astype(dt)
+                                 .astype(jnp.float32), np.float64)
+    rounded = jax.jit(lambda c: jax.lax.reduce_precision(c, 8, 7))
+    failed = []
+    for T in (64, 2112):
+        ks = jax.random.split(jax.random.key(T), 3)
+        # streams as a model has them: one embedding, the streams apart by
+        # what sublayers wrote
+        x = (jax.random.normal(ks[0], (T, 1, d))
+             + 0.5 * jax.random.normal(ks[1], (T, n, d))).astype(dt).reshape(
+                 T, n * d)
+        f = jax.random.normal(ks[2], (T, d)).astype(dt)
+        X, F, P, A, Bi = f64(x), f64(f), f64(phi), f64(alpha), f64(bias)
+        flat, X = X, X.reshape(T, n, d)
+        u = (flat / np.sqrt((flat ** 2).mean(-1, keepdims=True)
+                            + hc.rms_eps)) @ P
+        h_pre = sig(A[0] * u[:, pre_l] + Bi[pre_l])
+        h_post = 2 * sig(A[1] * u[:, post_l] + Bi[post_l])
+        m = np.exp(np.clip(A[2] * u[:, res_l] + Bi[res_l], *hc.clamp))
+        for _ in range(hc.iters):
+            m = m / (m.sum(1, keepdims=True) + hc.eps)
+            m = m / (m.sum(2, keepdims=True) + hc.eps)
+        y64 = (h_pre[:, :, None] * X).sum(1)
+        new64 = (np.einsum("tij,tjd->tid", m, X)
+                 + h_post[:, :, None] * F[:, None, :])
+        coef64 = np.asarray(hcx.pack(*(jnp.asarray(a) for a in
+                                       (h_pre, h_post, m))), np.float64)
+        print(f"hc_mix T={T}, {n} x {d}: columns of H_res off 1 by at most "
+              f"{np.abs(m.sum(1) - 1).max():.1e} after {hc.iters} rounds")
+
+        y, coef = hc_mix.hc_pre(x, phi, alpha, bias, hc)
+        new = hc_mix.hc_post(x, f, coef)
+        y_x, coef_x = jax.jit(hcx.hc_pre_xla, static_argnums=4)(
+            x, phi, alpha, bias, hc)
+        bad = rounded(coef)
+        y_bad = jnp.sum(bad[:, :n, None] * x.astype(jnp.float32).reshape(
+            T, n, d), axis=1).astype(dt)
+        new_bad = hc_mix.hc_post(x, f, bad)
+        off = lambda got, want: float(
+            (f64(got) != to_dt(want).reshape(got.shape)).mean())
+        readings = {
+            "coef": (float(np.abs(f64(coef) - coef64).max()),
+                     float(np.abs(f64(bad) - coef64).max()), HC_COEF_LIMIT),
+            "y": (off(y, y64), off(y_bad, y64), HC_MISROUNDED_LIMIT),
+            "streams": (off(new, new64), off(new_bad, new64),
+                        HC_MISROUNDED_LIMIT)}
+        print(f"hc_mix T={T}: XLA form's coefficients off float64 by "
+              f"{float(np.abs(f64(coef_x) - coef64).max()):.2e}, its y "
+              f"misrounded {off(y_x, y64):.2e}")
+        for name, (sound, control, limit) in readings.items():
+            what = ("max |coef - float64|" if name == "coef" else
+                    f"share of {name} not the float64 value rounded")
+            print(f"hc_mix T={T} {what}: kernels {sound:.3e}, coefficients "
+                  f"rounded to bf16 {control:.3e}, limit {limit:.1e}")
+            if not sound < limit:
+                failed.append(f"T={T} {name}: {sound:.3e} >= {limit:.1e}")
+            if not control > limit:
+                failed.append(f"T={T} {name}: the bf16 control reads "
+                              f"{control:.3e}, under the limit: blind")
+
+        # Times inside ONE program, as the layer scan runs them (a call of
+        # its own is bound by its dispatch): 16 sublayers of hc_pre +
+        # hc_post, the streams handed on, and 16 of hc_post alone.
+        sizes = {"hc_mult": n, "hidden_size": d, "dtype": cfg.dtype}
+        pre_bytes = roofline_hc.hc_pre_bytes(sizes, T)
+        post_bytes = roofline_hc.hc_post_bytes(sizes, T)
+        forms = {
+            "pallas": (lambda x: hc_mix.hc_pre(x, phi, alpha, bias, hc),
+                       hc_mix.hc_post),
+            "xla": (lambda x: hcx.hc_pre_xla(x, phi, alpha, bias, hc),
+                    hcx.hc_post_xla)}
+        for form, (pre, post) in forms.items():
+            def pair(x, _):
+                y, c = pre(x)
+                return post(x, y, c), None
+
+            def post_only(x, _):
+                return post(x, f, coef), None
+            times = {}
+            for name, body in (("pair", pair), ("post", post_only)):
+                run = jax.jit(lambda x, body=body: jax.lax.scan(
+                    body, x, None, length=16)[0])
+                times[name] = _timed(run, x, n=5) / 16
+            t_pre, t_post = times["pair"] - times["post"], times["post"]
+            print(f"hc_pre[{form}] T={T}: {t_pre * 1e6:.1f} us a call in a "
+                  f"scan; {pre_bytes / 1e6:.1f} MB = "
+                  f"{pre_bytes / 819e9 * 1e6:.1f} us at 819 GB/s: "
+                  f"{100 * pre_bytes / 819e9 / t_pre:.1f} %")
+            print(f"hc_post[{form}] T={T}: {t_post * 1e6:.1f} us a call in a "
+                  f"scan; {post_bytes / 1e6:.1f} MB = "
+                  f"{post_bytes / 819e9 * 1e6:.1f} us at 819 GB/s: "
+                  f"{100 * post_bytes / 819e9 / t_post:.1f} %")
+    if failed:
+        print("hc-mix FAILED:\n  " + "\n  ".join(failed))
+        sys.exit(1)
+
+
 def check_int4_matmul() -> None:
     """W4A16 dequant-fused matmul (ops/pallas/int4_matmul.py): packed tiles
     dequantized in VMEM vs the XLA fusion path, at an 8B-decode-like shape
@@ -1205,9 +1337,11 @@ def main() -> None:
         "kda": lambda: check_kda(cfg, B, T),
         "kda-chunk": lambda: check_kda_chunk(cfg, T),
         "kda-chain": lambda: check_kda_chain(cfg, Kernels(use_pallas=True)),
+        "hc-mix": lambda: check_hc_mix(cfg),
     }
     if cfg.is_mla and args.kernels == ap.get_default("kernels"):
-        args.kernels = "latent,experts"
+        args.kernels = "latent,experts" + (",hc-mix" if cfg.hc_mult > 1
+                                           else "")
     if cfg.state_kind == "kda" and args.kernels in (
             ap.get_default("kernels"), "latent,experts"):
         args.kernels = "latent,kda,kda-chunk,kda-chain"

@@ -73,16 +73,23 @@ MODELS = {
         vocab=40960, long_prompt=3000, gen=256,
         flags=["--hf-overrides", '{"num_hidden_layers": 9, '
                '"experts_held": 64, "vocab_size": 40960}']),
+    # ``--model xing4.0-29b-a4b``: the benchmark's cut, stage 1 of 5: 2
+    # dense + 6 expert layers, four residual streams around every sublayer.
+    "xing4.0-29b-a4b": dict(
+        vocab=131072, long_prompt=3000, gen=256,
+        flags=["--hf-overrides", '{"num_hidden_layers": 8}']),
     # Rehearsal only: max_model_len 512 cannot hold a chunking prompt.
     "debug-tiny": dict(vocab=512, long_prompt=400, gen=96),
     "debug-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
+    "debug-hc-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
     "debug-ssm-hybrid": dict(vocab=512, long_prompt=400, gen=96),
     "debug-kda-hybrid": dict(vocab=512, long_prompt=400, gen=96,
                              flags=["--hf-overrides", '{"experts_held": 4}']),
 }
 REHEARSAL_OF = {"qwen3-4b": "debug-tiny", "kimi-vl-a3b": "debug-mla-moe",
                 "granite-4.0-h-micro": "debug-ssm-hybrid",
-                "kimi-linear-48b-a3b": "debug-kda-hybrid"}
+                "kimi-linear-48b-a3b": "debug-kda-hybrid",
+                "xing4.0-29b-a4b": "debug-hc-mla-moe"}
 HEALTH_TIMEOUT_S = 600
 REQUEST_TIMEOUT_S = 600
 DRAIN_TIMEOUT_S = 150
@@ -391,12 +398,14 @@ def run(args) -> tuple[dict, dict]:
             raise bg["error"]
         statuses["background_stream"] = statuses["mixed_short"] = 200
 
-        if "state_bytes" in health:
+        if "state_bytes" in health or "residual_streams" in health:
             # 7. a state model: three short prompts at once on an idle
             #    server ride ONE packed prefill, whose segment boundaries
             #    fall inside the scan's chunks; each must start as it does
             #    alone (a slot found as another sequence left it, or a
-            #    state carried over a boundary, would not).
+            #    state carried over a boundary, would not). A model with
+            #    residual streams: its mixers' token blocks hold several
+            #    prompts' tokens.
             packed = [dict(prompt=prompt(n), max_tokens=8, temperature=0,
                            logprobs=1, return_tokens_as_token_ids=True)
                       for n in (40, 300, 17)]
@@ -456,6 +465,7 @@ def run(args) -> tuple[dict, dict]:
         "kv_layout": health.get("kv_layout"),
         "weight_bytes": health.get("weight_bytes"),
         "state_bytes": health.get("state_bytes"),
+        "residual_streams": health.get("residual_streams"),
         "kernels": {"use_pallas": health["use_pallas"],
                     "use_pallas_hist": health["use_pallas_hist"]},
         "seconds_to_healthy": round(t_healthy, 1),

@@ -33,8 +33,9 @@ class ModelConfig:
     num_kv_heads: int
     head_dim: int
     rope_theta: float = 10000.0
-    # HF ``rope_scaling`` (llama3 / linear), stored as a sorted (key, value)
-    # tuple so the frozen config stays hashable; see ops/rope.scaled_inv_freq.
+    # HF ``rope_scaling`` (llama3 / linear / yarn), stored as a sorted (key,
+    # value) tuple so the frozen config stays hashable; see
+    # ops/rope.scaled_inv_freq.
     rope_scaling: Optional[tuple] = None
     rms_norm_eps: float = 1e-5
     tie_word_embeddings: bool = False
@@ -64,6 +65,9 @@ class ModelConfig:
     # V; q/k heads are qk_nope_head_dim + qk_rope_head_dim wide, v heads
     # v_head_dim. ``head_dim`` is then the q/k width (softmax scale).
     kv_lora_rank: int = 0
+    # deepseek_v3's low-rank query: q = RMSNorm_q(x W_qa) W_qb through a
+    # latent of this width (0: one full-rank ``wq``).
+    q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
@@ -133,8 +137,29 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
+    # Manifold-constrained hyper-connections (arXiv:2512.24880; xing4_0's
+    # ``hc_*`` / ``mhc_*`` keys): the residual is ``hc_mult`` parallel
+    # streams; every sublayer reads a learned mix of them and writes back
+    # through a learned map that ``hc_sinkhorn_iters`` rounds of column and
+    # row normalisation (``hc_eps`` in the denominators) make doubly
+    # stochastic, its logits clamped to ``hc_res_clamp`` before the exp
+    # (ops/hyper_conn.py). 1: the plain residual.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
 
     def __post_init__(self):
+        if self.hc_mult > 1 and self.residual_multiplier != 1.0:
+            raise ValueError(
+                f"{self.name}: residual_multiplier "
+                f"{self.residual_multiplier} with hc_mult {self.hc_mult}: "
+                "the stream maps carry the branch's weight; a scalar beside "
+                "them is not implemented")
+        if not 1 <= self.hc_mult <= 8:
+            raise ValueError(
+                f"{self.name}: hc_mult {self.hc_mult}: the stream mixers "
+                "hold 1 to 8 residual streams")
         if not 0 <= self.experts_first <= (
                 self.num_experts - self.num_local_experts):
             raise ValueError(
@@ -264,9 +289,13 @@ class ModelConfig:
 
     @property
     def attn_scale(self) -> float:
+        """The softmax scale: granite's multiplier, else head_dim^-1/2, times
+        YaRN's m^2 where the rotation is YaRN-scaled (deepseek_v3: m from
+        ``mscale_all_dim``)."""
         if self.attention_multiplier is not None:
             return self.attention_multiplier
-        return self.head_dim ** -0.5
+        from ..ops.rope import yarn_attn_factor
+        return self.head_dim ** -0.5 * yarn_attn_factor(self.rope_scaling_dict)
 
     @property
     def jnp_dtype(self):
@@ -326,6 +355,19 @@ def _p(name, **kw) -> ModelConfig:
     return ModelConfig(name=name, **kw)
 
 
+def _yarn(factor: float, original: int, **kw) -> tuple:
+    """deepseek_v3's YaRN block as ``ModelConfig.rope_scaling`` holds it."""
+    return tuple(sorted({
+        "type": "yarn", "factor": factor,
+        "original_max_position_embeddings": original, "beta_fast": 32,
+        "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1, **kw}.items()))
+
+
+# YaRN where 16 rope dims and 512 positions show it: the ramp runs over
+# pairs 0 to 3 of 8 and m^2 is 1.296.
+_YARN_DEBUG = _yarn(factor=4.0, original=64)
+
+
 MODEL_PRESETS: dict[str, ModelConfig] = {
     # Tiny configs for tests / CI (CPU mesh) — the fake-backend analogue of the
     # reference's opt-125m smoke model (values-01-minimal-example.yaml:7-8).
@@ -351,6 +393,21 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         first_k_dense_replace=1, scoring_func="sigmoid",
         routed_scaling_factor=2.446, rope_theta=800000.0,
         max_model_len=512, dtype="float32",
+    ),
+    # xing4.0's block at a size the CPU tests can afford: debug-mla-moe's
+    # widths with four residual streams mixed around every sublayer, the
+    # query through a latent of 48, YaRN on the 16 rope dims, 2 dense + 2
+    # expert layers.
+    "debug-hc-mla-moe": _p(
+        "debug-hc-mla-moe", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=4, num_heads=4, num_kv_heads=4,
+        head_dim=48, kv_lora_rank=64, q_lora_rank=48, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, num_experts=8,
+        num_experts_per_tok=3, moe_intermediate_size=64,
+        num_shared_experts=1, first_k_dense_replace=2,
+        scoring_func="sigmoid", routed_scaling_factor=2.0,
+        rope_theta=10000.0, rope_scaling=_YARN_DEBUG, rms_norm_eps=1e-6,
+        hc_mult=4, max_model_len=512, dtype="float32",
     ),
     # granite-4.0-h-micro's block at a size the CPU tests can afford: 2
     # periods of [2 state, 1 attention, 1 state] (the attention layer INSIDE
@@ -490,6 +547,26 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         kda_n_heads=32, kda_head_dim=128, kda_d_conv=4, kda_chunk_size=64,
         max_model_len=4096,
     ),
+    # XingChen-AGI/Xing4.0-29B-A4B (xing4_0): a deepseek_v3 decoder (latent
+    # attention with a low-rank query, 64 sigmoid-routed experts of 1024
+    # top-4 x 2 + 1 shared, two leading dense layers of 9216, YaRN x 64 over
+    # 4096 on the 64 rope dims) whose residual is FOUR streams mixed by
+    # manifold-constrained hyper-connections around every attention and every
+    # MLP. 59 GB of bf16 weights: ``--hf-overrides`` names the depth one
+    # chip holds. Its multi-token-prediction module is not served.
+    "xing4.0-29b-a4b": _p(
+        "xing4.0-29b-a4b", vocab_size=131072, hidden_size=3584,
+        intermediate_size=9216, num_layers=40, num_heads=32,
+        num_kv_heads=32, head_dim=192, kv_lora_rank=512, q_lora_rank=768,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_experts=64, num_experts_per_tok=4, moe_intermediate_size=1024,
+        num_shared_experts=1, first_k_dense_replace=2,
+        scoring_func="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.0, rope_theta=10000.0,
+        rope_scaling=_yarn(factor=64.0, original=4096), rms_norm_eps=1e-6,
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        hc_res_clamp=(-30.0, 30.0), max_model_len=4096,
+    ),
 }
 
 
@@ -510,6 +587,7 @@ HF_SHAPE_KEYS: dict[str, str] = {
     "moe_intermediate_size": "moe_intermediate_size",
     "first_k_dense_replace": "first_k_dense_replace",
     "kv_lora_rank": "kv_lora_rank",
+    "q_lora_rank": "q_lora_rank",
     "qk_nope_head_dim": "qk_nope_head_dim",
     "qk_rope_head_dim": "qk_rope_head_dim",
     "v_head_dim": "v_head_dim",

@@ -509,6 +509,9 @@ class LLMEngine:
             info.update(experts_held=self.model_config.experts_held,
                         experts_first=self.model_config.experts_first,
                         experts_published=self.model_config.num_experts)
+        if self.model_config.hc_mult > 1:
+            # The residual is this many streams, mixed around every sublayer.
+            info["residual_streams"] = self.model_config.hc_mult
         if self.pallas_disabled_reason is not None:
             info["pallas_disabled_reason"] = self.pallas_disabled_reason
         return info
@@ -717,6 +720,20 @@ class LLMEngine:
                 probe("ssm_update", ssm_update, state_pool, arr((), i32),
                       arr((B,), i32), arr((B, di), f32), arr((B, di), f32),
                       arr((B, N), f32), arr((B, N), f32))
+        if cfg.hc_mult > 1:
+            # The stream mixers over a full decode bucket and over the
+            # widest mixed step.
+            from ..ops import hyper_conn
+            from ..ops.pallas.hc_mix import hc_post, hc_pre
+            hc, n, d = hyper_conn.settings(cfg), cfg.hc_mult, cfg.hidden_size
+            f32 = jnp.float32
+            for rows in (B, T + B):
+                probe(f"hc_pre[T={rows}]",
+                      lambda x, phi, a, b: hc_pre(x, phi, a, b, hc),
+                      arr((rows, n * d)), arr((n * d, hyper_conn.COLS)),
+                      arr((3,), f32), arr((hyper_conn.COLS,), f32))
+                probe(f"hc_post[T={rows}]", hc_post, arr((rows, n * d)),
+                      arr((rows, d)), arr((rows, hyper_conn.COLS), f32))
         if cfg.is_mla:
             self._probe_latent_kernels(probe, arr, pool, B, T, pps)
             logger.info("Pallas kernels compiled at the served geometry: %s",
@@ -766,7 +783,7 @@ class LLMEngine:
 
         cfg = self.model_config
         nh, R, i32 = cfg.num_heads, cfg.kv_row_padded, jnp.int32
-        scale = cfg.head_dim ** -0.5
+        scale = cfg.attn_scale
         probe("latent_paged_decode",
               lambda q, kp, tb, ctx, cur, lyr: latent_paged_decode(
                   q, kp, tb, ctx, cur, scale, layer=lyr),
@@ -2831,7 +2848,8 @@ def step_workspace_bytes(config: EngineConfig) -> int:
       dense dispatch, EVERY expert over every token, which bounds its
       grouped path too; a latent-attention model at the grouped layout's
       real rows — and q/k/v/attention-out over the heads);
-    - residual-stream copies; and
+    - residual-stream copies (of every stream a model with hyper-connections
+      carries, and a token's float32 coefficient rows); and
     - the ``[rows, vocab]`` f32 sampling buffers (logits, penalties
       histogram, sort/top-k scratch) at the top decode bucket."""
     m, sc = config.model, config.scheduler
@@ -2856,7 +2874,9 @@ def step_workspace_bytes(config: EngineConfig) -> int:
     else:
         mlp = max(m.num_experts, 1) * T * m.intermediate_size * (4 + 4 + it)
         attn = T * (m.num_heads * m.head_dim + kd) * 2 * (4 + it)
-    resid = 4 * T * m.hidden_size * 4
+    resid = 4 * T * m.hc_mult * m.hidden_size * 4
+    if m.hc_mult > 1:
+        resid += 4 * T * 128 * 4
     sampling = 8 * B * m.vocab_size * 4
     state = 0
     if m.state_kind == "mamba":

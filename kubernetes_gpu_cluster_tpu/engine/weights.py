@@ -71,7 +71,13 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
         raw = {k: v for k, v in hf["rope_scaling"].items()
                if isinstance(v, (str, int, float, bool))}
         # Validate NOW — an unsupported type (yarn, dynamic, ...) must fail
-        # the load, not silently serve with unscaled RoPE.
+        # the load, not silently serve with unscaled RoPE. YaRN is written
+        # as deepseek_v3 scales it (the softmax scale takes m^2): the
+        # latent-attention decoder's, not this one's.
+        if (raw.get("rope_type") or raw.get("type")) == "yarn":
+            raise ValueError(
+                "unsupported rope_scaling type 'yarn' for a K|V decoder "
+                "(supported: llama3, linear; yarn with latent attention)")
         scaled_inv_freq(head_dim, float(hf.get("rope_theta", 10000.0)), raw)
         rope_scaling = tuple(sorted(raw.items()))
     return ModelConfig(
@@ -96,30 +102,67 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
     )
 
 
+_HC_KEYS = {"hc_mult", "hc_sinkhorn_iters", "hc_eps", "mhc_h_res_clamp_min",
+            "mhc_h_res_clamp_max"}
+
+
 def _deepseek_config_from_hf(hf: dict, name: str) -> ModelConfig:
-    """deepseek_v3-class language model (kimi_vl's ``text_config``): latent
-    attention, sigmoid/noaux_tc routing, shared experts, leading dense
-    layers. What the decoder does not implement refuses the load by name."""
+    """deepseek_v3-class language model (kimi_vl's ``text_config``; xing4_0):
+    latent attention, with a low-rank query where ``q_lora_rank`` says so,
+    sigmoid/noaux_tc routing, shared experts, leading dense layers, YaRN on
+    the rope dims; xing4_0's residual streams (the ``hc_*`` / ``mhc_*``
+    keys: manifold-constrained hyper-connections). A multi-token-prediction
+    module (``num_nextn_predict_layers``) is accepted and NOT served: the
+    decoder is the model without it, and the log says so. What the decoder
+    does not implement refuses the load by name."""
     from ..config.model_config import HF_SHAPE_KEYS
-    for key, ok in (("q_lora_rank", hf.get("q_lora_rank") is None),
-                    ("rope_scaling", not hf.get("rope_scaling")),
+    from ..ops.rope import scaled_inv_freq
+    rope_scaling = None
+    if hf.get("rope_scaling"):
+        raw = {k: v for k, v in hf["rope_scaling"].items()
+               if isinstance(v, (str, int, float, bool))}
+        try:    # a type the tree lacks refuses the load, by its name
+            scaled_inv_freq(hf["qk_rope_head_dim"],
+                            float(hf.get("rope_theta", 10000.0)), raw)
+        except ValueError as e:
+            raise ValueError(f"{name}: config.json rope_scaling: {e}") from e
+        rope_scaling = tuple(sorted(raw.items()))
+    unknown = sorted(k for k in hf if k.startswith(("hc_", "mhc_"))
+                     and k not in _HC_KEYS)
+    for key, ok in (("hc_*", not unknown),
                     ("n_group", hf.get("n_group", 1) == 1),
                     ("topk_group", hf.get("topk_group", 1) == 1),
                     ("moe_layer_freq", hf.get("moe_layer_freq", 1) == 1),
                     ("hidden_act", hf.get("hidden_act", "silu") == "silu"),
                     ("attention_bias", not hf.get("attention_bias"))):
         if not ok:
+            what = (f"{key}={hf.get(key)!r}" if key in hf
+                    else ", ".join(unknown))
             raise ValueError(
-                f"{name}: config.json {key}={hf.get(key)!r} is not "
+                f"{name}: config.json {what} is not "
                 "implemented by the latent-attention decoder")
+    if hf.get("num_nextn_predict_layers"):
+        logger.info(
+            "%s: num_nextn_predict_layers=%s: the multi-token-prediction "
+            "module is not served (a draft from it needs a verify step over "
+            "latent pages); the decoder runs without it", name,
+            hf["num_nextn_predict_layers"])
     fields = {ours: hf[theirs] for theirs, ours in HF_SHAPE_KEYS.items()
-              if theirs in hf}
+              if hf.get(theirs) is not None}
     fields["max_model_len"] = min(int(fields.get("max_model_len", 4096)), 8192)
     fields.setdefault("num_kv_heads", fields["num_heads"])
+    if hf.get("hc_mult", 1) > 1:
+        fields.update(
+            hc_mult=hf["hc_mult"],
+            hc_sinkhorn_iters=int(hf.get("hc_sinkhorn_iters", 20)),
+            hc_eps=float(hf.get("hc_eps", 1e-6)),
+            hc_res_clamp=(float(hf.get("mhc_h_res_clamp_min", -30.0)),
+                          float(hf.get("mhc_h_res_clamp_max", 30.0))))
     return ModelConfig(
         name=name,
         head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
         rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rope_scaling=rope_scaling,
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
         tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
         scoring_func=hf.get("scoring_func", "softmax"),
@@ -730,6 +773,13 @@ def load_weights(path: str, cfg: ModelConfig,
     safetensors (see _load_streamed), so per-host RSS is ~shard bytes, not
     model bytes. Without shardings (single device), the full stacked pytree
     is built host-side and uploaded."""
+    if cfg.hc_mult > 1 or cfg.q_lora_rank:
+        raise ValueError(
+            f"{cfg.name}: no loader for a checkpoint with residual streams "
+            "or a low-rank query: none could be fetched to read the tensor "
+            "names from (they are ASSUMED in "
+            "perfbench/configs/xing4.0-29b-a4b-bf16.json); it is served "
+            "with random weights")
     ckpt = _Checkpoint(path)
     dtype = dtype or cfg.jnp_dtype
     if cfg.pos_embedding == "learned":

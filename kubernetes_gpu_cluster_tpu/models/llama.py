@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from ..config import ModelConfig
 from ..engine.kv_cache import KVCache
 from ..ops import quant as quant_ops
+from ..ops import hyper_conn
 from ..ops import kda as kda_ops
 from ..ops import ssm as ssm_ops
 from ..ops.rope import apply_rope, rope_cos_sin
@@ -239,14 +240,22 @@ def _init_mla_mixer(cfg: ModelConfig, L: int, keys, w, dtype) -> Params:
     """``L`` stacked latent-attention mixers with their layer's two norms.
     ``w_uk``/``w_uv`` are ``kv_b_proj`` split per head into its key and
     value halves ([nh, r, nope] and [nh, r, v]): the absorbed form contracts
-    them with q and with the output per head."""
+    them with q and with the output per head. With ``q_lora_rank`` the query
+    passes through a latent with a norm of its own (``w_qa``, ``q_a_norm``,
+    ``w_qb``: deepseek_v3's ``q_a_proj``, ``q_a_layernorm``, ``q_b_proj``)
+    and there is no ``wq``."""
     d, nh = cfg.hidden_size, cfg.num_heads
     r, nope, rope, vd = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
+    qr = cfg.q_lora_rank
+    query = ({"wq": w(next(keys), (L, d, nh * (nope + rope)), d)} if not qr
+             else {"w_qa": w(next(keys), (L, d, qr), d),
+                   "q_a_norm": jnp.ones((L, qr), dtype),
+                   "w_qb": w(next(keys), (L, qr, nh * (nope + rope)), qr)})
     return {
         "input_norm": jnp.ones((L, d), dtype),
         "post_attn_norm": jnp.ones((L, d), dtype),
-        "wq": w(next(keys), (L, d, nh * (nope + rope)), d),
+        **query,
         "w_kva": w(next(keys), (L, d, r + rope), d),
         "kv_norm": jnp.ones((L, r), dtype),
         "w_uk": w(next(keys), (L, nh, r, nope), r),
@@ -280,6 +289,33 @@ def _init_experts(cfg: ModelConfig, L: int, keys, w, dtype) -> Params:
 
 
 _MIXER_INITS = {"mamba": _init_mamba_mixer, "kda": _init_kda_mixer}
+
+HC_SITES = ("attn", "mlp")      # a layer's mixer, its MLP
+
+
+def _init_stream_mixers(cfg: ModelConfig, L: int, keys, dtype) -> Params:
+    """``L`` layers' stream mixers, one a sublayer (``ops/hyper_conn.py``):
+    ``hc_<site>_phi`` [L, n d, COLS] in the model's dtype, the norm's gain
+    (1 in this draw) folded in; ``hc_<site>_alpha`` [L, 3] and
+    ``hc_<site>_bias`` [L, COLS] float32. The draw is chosen so that all
+    three maps move the result and twenty Sinkhorn rounds do converge: Phi ~
+    N(0, 1 / (n d)), alpha = (1, 1, 1/4), b_pre and b_post ~ N(0, 1), b_res
+    = I + N(0, 1/4). (A stronger diagonal converges more slowly: at 3 I +
+    N(0, 1) under alpha 1 the columns still miss 1 by 3e-2 after 20.)"""
+    n, nd = cfg.hc_mult, cfg.hc_mult * cfg.hidden_size
+    normal = lambda shape: jax.random.normal(next(keys), shape, jnp.float32)
+    out: Params = {}
+    for site in HC_SITES:
+        phi = normal((L, nd, 2 * n + n * n)) * (nd ** -0.5)
+        out[f"hc_{site}_phi"] = hyper_conn.pack(
+            phi[..., :n], phi[..., n:2 * n],
+            phi[..., 2 * n:].reshape(L, nd, n, n)).astype(dtype)
+        out[f"hc_{site}_alpha"] = jnp.tile(
+            jnp.array([1.0, 1.0, 0.25], jnp.float32), (L, 1))
+        out[f"hc_{site}_bias"] = hyper_conn.pack(
+            normal((L, n)), normal((L, n)),
+            jnp.eye(n) + 0.5 * normal((L, n, n)))
+    return out
 
 
 def _init_state_layers(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
@@ -346,7 +382,8 @@ def _init_params_deepseek(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
             "model: the absorbed projections and the grouped expert matmuls "
             "have no int8/int4 path")
     d = cfg.hidden_size
-    keys = iter(jax.random.split(key, 64 if cfg.has_state else 32))
+    keys = iter(jax.random.split(
+        key, 96 if cfg.hc_mult > 1 else 64 if cfg.has_state else 32))
     params: Params = {
         "embed": w(next(keys), (cfg.vocab_size, d), d),
         "final_norm": jnp.ones((d,), dtype),
@@ -358,7 +395,9 @@ def _init_params_deepseek(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
             **mixer(cfg, L, keys, w, dtype),
             **(_init_swiglu(L, d, cfg.intermediate_size, keys, w)
                if name.startswith("dense_")
-               else _init_experts(cfg, L, keys, w, dtype))}
+               else _init_experts(cfg, L, keys, w, dtype)),
+            **(_init_stream_mixers(cfg, L, keys, dtype)
+               if cfg.hc_mult > 1 else {})}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (d, cfg.vocab_size), d)
     return params
@@ -822,14 +861,21 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
 def _mla_qkv(lp: Params, cfg: ModelConfig, x: jax.Array,
              positions: jax.Array, use_pallas: Optional[bool] = None):
     """Latent attention's projections. x: [T, d] -> q [T, nh, nope + rope]
-    (RoPE on its last ``rope`` dims) and the cache row [T, kv_row_padded] =
+    (RoPE on its last ``rope`` dims; through a latent of its own where the
+    model has ``q_lora_rank``) and the cache row [T, kv_row_padded] =
     [c (its own RMSNorm) | k_pe (RoPE, one head shared by all) | zeros].
     RoPE is half-split over the rope dims: the loader de-interleaves those
     columns of a checkpoint (engine/weights.py), which rotates the same
     pairs the published code does."""
     T = x.shape[0]
     r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    q = _dot(x, lp, "wq", use_pallas).astype(x.dtype)
+    if "w_qa" in lp:    # the query through its latent and the latent's norm
+        with jax.named_scope("kgct.mla.q_lora"):
+            c_q = rms_norm(_dot(x, lp, "w_qa", use_pallas).astype(x.dtype),
+                           lp["q_a_norm"], cfg.rms_norm_eps)
+            q = _dot(c_q, lp, "w_qb", use_pallas).astype(x.dtype)
+    else:
+        q = _dot(x, lp, "wq", use_pallas).astype(x.dtype)
     q = q.reshape(T, q.shape[-1] // cfg.head_dim, cfg.head_dim)
     a = _dot(x, lp, "w_kva", use_pallas).astype(x.dtype)      # [T, r + rope]
     c = rms_norm(a[:, :r], lp["kv_norm"], cfg.rms_norm_eps)
@@ -919,7 +965,7 @@ def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
     own rows with it in the same shared-row sweep (3.4x the attention FLOPs
     for that part; merging the two forms' softmaxes would spare it). The
     choice is the device's, from ``hist_len``: both are in the program."""
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.attn_scale
 
     def fresh(_):
         k, v = mla_materialise(lp, cfg, row)
@@ -1183,7 +1229,10 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     holds one body a layer kind of each section, whatever the depth. A
     layer is (a mixer: attention or the model's state mixer) x (an MLP:
     dense or experts), and its weights lie in its mixer's stack
-    (``layer_stacks``). Pool layer indices count per kind through all the
+    (``layer_stacks``). ``h``, the carry, is the residual: [T, d], or the
+    [T, n d] streams of a model with hyper-connections; every sublayer
+    enters and leaves it through one pair of functions (``enter``/``leave``
+    below). Pool layer indices count per kind through all the
     sections: an attention layer addresses layer ``i`` of the page pools, a
     state layer layer ``j`` of the slot pools.
 
@@ -1240,9 +1289,34 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     """
     int4 = kernels.int4_pallas
     r = cfg.residual_multiplier
+    hc = hyper_conn.settings(cfg) if cfg.hc_mult > 1 else None
 
     def scaled(branch):     # granite: h += r * branch (r == 1: nothing)
         return branch if r == 1.0 else branch * r
+
+    # THE residual path, at all three sites (an attention layer's mixer, a
+    # state layer's, a layer's MLP). The carry ``h`` is the residual: [T, d],
+    # or a model's ``hc_mult`` streams side by side, [T, n d]
+    # (``ops/hyper_conn.py``).
+    def enter(lp, h, site):
+        """What the sublayer at ``site`` reads of the residual (its own norm
+        comes next), and what ``leave`` needs: the streams' learned mix and
+        the token's coefficients; of one stream, itself and nothing."""
+        if hc is None:
+            return h, None
+        with jax.named_scope("kgct.hc.pre"):
+            return kernels.hc_pre(h, lp[f"hc_{site}_phi"],
+                                  lp[f"hc_{site}_alpha"],
+                                  lp[f"hc_{site}_bias"], hc)
+
+    def leave(h, branch, coef):
+        """The residual behind the sublayer: ``h + r * branch``, or the
+        streams through their doubly stochastic map with the branch added
+        to each by its weight."""
+        if coef is None:
+            return h + scaled(branch).astype(h.dtype)
+        with jax.named_scope("kgct.hc.post"):
+            return kernels.hc_post(h, branch.astype(h.dtype), coef)
 
     def at(stack, i):
         """Layer ``i`` of a stack, read where it lies. (A slice of a scan's
@@ -1257,7 +1331,8 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         deepseek_v3 stack do not), else the dense MLP. ``experts``/``i``:
         the expert tensors of the layer's stack and its index there.
         Returns (h + the branch, the layer's expert load or ())."""
-        x = _norm(cfg, h, lp, "post_attn_norm")
+        y, coef = enter(lp, h, "mlp")
+        x = _norm(cfg, y, lp, "post_attn_norm")
         load = [] if moe_load is not None else None
         if "router" in lp:
             mlp = _moe_mlp(lp, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
@@ -1265,21 +1340,22 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                            stacked=(experts, i), valid=valid)
         else:
             mlp = _dense_mlp(lp, x, cfg, tp_axis=tp_axis, use_pallas=int4)
-        return h + scaled(mlp), tuple(load or ())
+        return leave(h, mlp, coef), tuple(load or ())
 
     def state_layer(stack, carry, layer_idx):
         h, ssm = carry
         lp = at(stack.layers, layer_idx - stack.first)
-        x = _norm(cfg, h, lp, "input_norm")
+        y, coef = enter(lp, h, "attn")
+        x = _norm(cfg, y, lp, "input_norm")
         with jax.named_scope(_STATE_MIXERS[cfg.state_kind].scope):
             out, ssm, conv_rows = state_fn(lp, x, ssm, layer_idx)
-        h = h + scaled(out).astype(h.dtype)
+        h = leave(h, out, coef)
         h, load = mlp_of(lp, h, stack.experts, layer_idx - stack.first)
         return (h, ssm), (conv_rows, load)
 
     def attn_layer(h, lp, stack, layer_idx):
-        resid = h
-        x = _norm(cfg, h, lp, "input_norm")
+        y, coef = enter(lp, h, "attn")
+        x = _norm(cfg, y, lp, "input_norm")
         if cfg.is_mla:
             with jax.named_scope("kgct.mla"):
                 q, row = _mla_qkv(lp, cfg, x, positions, int4)
@@ -1293,7 +1369,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
             o = jax.lax.psum(o, tp_axis)
         if "bo" in lp:           # after the reduce: applied exactly once
             o = o + lp["bo"]
-        h = resid + scaled(o).astype(h.dtype)
+        h = leave(h, o, coef)
         h, load = mlp_of(lp, h, stack.experts, layer_idx - stack.first)
         if cfg.is_mla:
             return h, ((row,), load)
@@ -1452,12 +1528,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     one-token update on the row part, against the slots ``meta.seg_slots``
     and ``meta.row_slots`` name in ``kv.ssm``/``kv.conv``.
 
+    A model with residual streams (``cfg.hc_mult`` > 1) carries [T, n d]
+    between the embedding, fanned out to every stream, and the final norm,
+    which reads the streams' sum (``_layer_scan``'s ``enter``/``leave``).
+
     Returns (normed hidden of ``meta.logits_indices``' tokens, or of every
     token where that is None [*, d]; new_kv, the new state slots in it;
-    raw_hidden [T, d], which is what rotates stage to stage)."""
+    raw_hidden [T, d] ([T, n d] with streams), which is what rotates stage
+    to stage)."""
     scale = cfg.attn_scale
     h = (_embed(params, cfg, tokens, meta.positions)
          if hidden_in is None else hidden_in)
+    if cfg.hc_mult > 1 and hidden_in is None:
+        # Every residual stream starts as the embedding (arXiv:2409.19606);
+        # the streams lie side by side in a row (ops/hyper_conn.py).
+        h = jnp.tile(h, (1, cfg.hc_mult))
     n_rows = (0 if meta.page_tables is None
               else meta.page_tables.shape[0] * row_width)
     n_seg = 0 if meta.seg_ids is None else tokens.shape[0] - n_rows
@@ -1544,6 +1629,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     new_kv = KVCache(*kernels.write_pages(kv.k, kv.v, k_all, v_all,
                                           meta.slot_mapping), ssm, conv)
     selected = h if meta.logits_indices is None else h[meta.logits_indices]
+    if cfg.hc_mult > 1:     # ... and their sum is what the final norm reads
+        selected = jnp.sum(selected.astype(jnp.float32).reshape(
+            selected.shape[0], cfg.hc_mult, -1), axis=1).astype(h.dtype)
     return _norm(cfg, selected, params, "final_norm"), new_kv, h
 
 

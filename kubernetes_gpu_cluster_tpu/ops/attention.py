@@ -428,7 +428,7 @@ def ragged_prefill_attention_tp(mesh, q, k, v, seg_ids, positions, scale, *,
 class Kernels:
     """What the engine decides ONCE, at construction, about how the
     operations a forward pass needs (five of attention and the page pool,
-    one of the state slots) are carried out
+    one of the state slots, two of the residual streams) are carried out
     (``LLMEngine._resolve_use_pallas`` builds it, proves every kernel it
     names by compiling it, and hands it to every step program); nothing
     below the engine decides again. The default is the XLA references
@@ -596,6 +596,27 @@ class Kernels:
         from .pallas.kda_chunk import kda_chunk
         return kda_chunk(q, k, v, g, beta, seg_ids, seg_ends, init_state,
                          init_seg, chunk)
+
+    def hc_pre(self, x, phi, alpha, bias, hc):
+        """What a sublayer reads of the residual streams, and the
+        coefficients it leaves through (``ops/hyper_conn.py``): the Pallas
+        kernel or the XLA form; one device (a model with streams is a latent
+        model, refused under a mesh at start)."""
+        if not self.use_pallas:
+            from .hyper_conn import hc_pre_xla
+            return hc_pre_xla(x, phi, alpha, bias, hc)
+        from .pallas.hc_mix import hc_pre
+        return hc_pre(x, phi, alpha, bias, hc)
+
+    def hc_post(self, x, f, coef):
+        """The streams behind a sublayer: mixed by the doubly stochastic
+        map, the sublayer's result added (``ops/hyper_conn.py``); the kernel
+        writes them over the old ones."""
+        if not self.use_pallas:
+            from .hyper_conn import hc_post_xla
+            return hc_post_xla(x, f, coef)
+        from .pallas.hc_mix import hc_post
+        return hc_post(x, f, coef)
 
 
 NO_KERNELS = Kernels()

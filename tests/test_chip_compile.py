@@ -123,6 +123,33 @@ def test_kda_chunk_compiles_for_a_v5e_without_copies(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * operand
 
 
+@pytest.mark.parametrize("T", [64, 1088, 1600, 2112])
+def test_stream_mixers_compile_for_a_v5e_in_place(one_chip, no_compile_cache,
+                                                  T):
+    """xing4.0's ``hc_pre`` / ``hc_post`` at the published widths (4 streams
+    of 3584, bf16) over a full decode bucket and the three mixed steps of
+    the ``batch-decode-2k`` cell (1024, 1536 and 2048 tokens beside 64
+    rows: the last block of each is partial). The streams are read where
+    they lie, and ``hc_post`` writes over them."""
+    from kubernetes_gpu_cluster_tpu.ops import hyper_conn
+    from kubernetes_gpu_cluster_tpu.ops.pallas import hc_mix
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    n, d, f32 = 4, 3584, jnp.float32
+    hc = hyper_conn.HCSettings(n, 20, 1e-6, (-30.0, 30.0), 1e-6)
+    pre = jax.jit(lambda x, p, a, b: hc_mix.hc_pre(x, p, a, b, hc)).lower(
+        arr((T, n * d)), arr((n * d, hyper_conn.COLS)), arr((3,), f32),
+        arr((hyper_conn.COLS,), f32)).compile()
+    assert "%hc_pre" in pre.as_text()
+    assert pre.memory_analysis().temp_size_in_bytes < 2**20
+    post = jax.jit(hc_mix.hc_post, donate_argnums=0).lower(
+        arr((T, n * d)), arr((T, d)), arr((T, hyper_conn.COLS), f32)).compile()
+    assert "%hc_post" in post.as_text()
+    assert post.memory_analysis().temp_size_in_bytes < 2**20
+    assert post.memory_analysis().alias_size_in_bytes == T * n * d * 2
+
+
 @pytest.mark.parametrize("nh,nkv,hd,T,pps", [
     (32, 8, 64, 2048, 32),     # granite-4.0-h-micro: four lane blocks of two
     (32, 8, 128, 2048, 32),    # qwen3-4b: eight lane blocks of one head
@@ -164,6 +191,9 @@ def test_flash_prefill_hist_compiles_for_a_v5e(one_chip, no_compile_cache,
     ("kimi-linear-48b-a3b",
      {"num_hidden_layers": 9, "experts_held": 64, "vocab_size": 40960},
      ("kda_chunk", "kda_update", "flash_prefill", "latent_prefill_hist",
+      "grouped_matmul", "latent_paged_decode", "kv_write")),
+    ("xing4.0-29b-a4b", {"num_hidden_layers": 8},
+     ("hc_pre", "hc_post", "flash_prefill", "latent_prefill_hist",
       "grouped_matmul", "latent_paged_decode", "kv_write"))])
 def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
         one_chip, no_compile_cache, preset, overrides, kernels):
