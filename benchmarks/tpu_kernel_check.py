@@ -252,25 +252,93 @@ def time_decode(nh, n_kv, hd, pps, B, L) -> None:
               f"{token_bytes / us / 819e3 * 100:.1f} % in tokens that exist")
 
 
-def check_prefill(nh, n_kv, hd, T) -> None:
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((T, n_kv, hd)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((T, n_kv, hd)), jnp.bfloat16)
-    # Three ragged segments + trailing padding.
-    lens = [T * 3 // 8, T * 3 // 8, T * 3 // 16]
-    pad = T - sum(lens)
-    seg = np.concatenate([np.full(n, i) for i, n in enumerate(lens)]
-                         + [np.full(pad, -1)]).astype(np.int32)
-    pos = np.concatenate([np.arange(n) for n in lens]
-                         + [np.zeros(pad)]).astype(np.int32)
-    seg, pos = jnp.asarray(seg), jnp.asarray(pos)
-    scale = hd ** -0.5
-    ref = ragged_prefill_attention_xla(q, k, v, seg, pos, scale)
-    out = jax.jit(lambda *a: flash_ragged_prefill(*a, scale))(q, k, v, seg, pos)
-    err = _err(out, ref, np.asarray(seg) >= 0)
-    print(f"flash_prefill T={T}: max|pallas-xla| = {err:.4f}")
-    assert err < TOL, err
+# The presets of the five served configurations: ``flash_prefill`` is checked
+# at the geometry of each.
+SERVED_MODELS = ("xing4.0-29b-a4b", "kimi-vl-a3b", "kimi-linear-48b-a3b",
+                 "qwen3-4b", "granite-4.0-h-micro")
+
+
+def prefill_geometry(cfg, tp: int = 1):
+    """(heads, kv heads, q/k width, v width, scale) of ``flash_prefill``'s
+    call on one shard. A latent model's materialised form has as many kv
+    heads as heads and a narrower v."""
+    return (cfg.num_heads // tp, cfg.num_kv_heads // tp, cfg.head_dim,
+            cfg.v_head_dim or cfg.head_dim, cfg.attn_scale)
+
+
+def check_prefill(geometries) -> None:
+    """``flash_prefill`` at each of ``geometries`` (name -> heads, kv heads,
+    q/k width, v width, scale): one fresh segment of 1536 and of 2048 tokens
+    (a prompt's ride in a mixed step) and a packed batch of three ragged
+    segments with tail padding in 2048. Held to XLA at ``HIGHEST`` on float32
+    copies of the same bf16 values, as ``flash_prefill_hist`` is, with the
+    kernel's two DECLARED roundings counted: the reference's q is ``q x
+    scale`` rounded to bf16 (the value the MXU is handed), and beside the
+    bf16 output's own rounding (``HIST_RTOL`` of the value, ``HIST_ATOL``) an
+    element may be off by 2^-8 of the attention-weighted mean of |v|: p goes
+    to P . V as ONE bf16 term. The gap to the exact reference (q and p in
+    float32) is printed beside it. Timed as ``HIST_CHAIN`` calls chained in
+    one program (each on the last one's output: through v where it has the
+    output's shape, else through q), so that no dispatch is in the time.
+    Exits 1 beyond the yardstick."""
+    bad = []
+    for name, (nh, n_kv, hd, hv, scale) in geometries.items():
+        for T, lens in ((1536, [1536]), (2048, [2048]),
+                        (2048, [768, 768, 384])):
+            rng = np.random.default_rng(1)
+
+            def bf(*shape):
+                return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+            q, k, v = bf(T, nh, hd), bf(T, n_kv, hd), bf(T, n_kv, hv)
+            pad = T - sum(lens)
+            seg = np.concatenate([np.full(n, i) for i, n in enumerate(lens)]
+                                 + [np.full(pad, -1)]).astype(np.int32)
+            pos = np.concatenate([np.arange(n) for n in lens]
+                                 + [np.zeros(pad)]).astype(np.int32)
+            real = seg >= 0
+            seg, pos = jnp.asarray(seg), jnp.asarray(pos)
+
+            def reference(q, k, v):
+                # reduce_precision: XLA on the TPU elides a round trip
+                # through astype as allowed excess precision.
+                q_r = jax.lax.reduce_precision(q * scale, 8, 7)
+                return (ragged_prefill_attention_xla(q, k, v, seg, pos, scale),
+                        ragged_prefill_attention_xla(q_r, k, v, seg, pos, 1.0),
+                        ragged_prefill_attention_xla(q_r, k, jnp.abs(v), seg,
+                                                     pos, 1.0))
+            with jax.default_matmul_precision("highest"):
+                exact, ref, mean_abs_v = (a[real] for a in jax.jit(reference)(
+                    *(a.astype(jnp.float32) for a in (q, k, v))))
+
+            def fn(q, k, v):
+                return flash_ragged_prefill(q, k, v, seg, pos, scale)
+
+            def chain(q, k, v):
+                if n_kv == nh:
+                    return jax.lax.fori_loop(
+                        0, HIST_CHAIN, lambda _, x: fn(q, k, x), v)
+                return jax.lax.fori_loop(
+                    0, HIST_CHAIN, lambda _, x: fn(x, k, v), q)
+
+            out = jax.jit(fn)(q, k, v).astype(jnp.float32)[real]
+            d = jnp.abs(out - ref)
+            over = float(jnp.max(d - HIST_RTOL * jnp.abs(ref)
+                                 - 2.0 ** -8 * mean_abs_v))
+            d_exact = jnp.abs(out - exact)
+            over_exact = float(jnp.max(d_exact - HIST_RTOL * jnp.abs(exact)))
+            dt = _timed(jax.jit(chain), q, k, v, n=5) / HIST_CHAIN
+            print(f"flash_prefill {name} {nh}/{n_kv} x {hd}/{hv} T={T} "
+                  f"segments={lens}: max|pallas-xla HIGHEST| = "
+                  f"{float(jnp.max(d)):.5f} with q x scale in bf16 (over "
+                  f"rtol 2^-8 and p's one bf16 term by {over:.2e}), "
+                  f"{float(jnp.max(d_exact)):.5f} against the exact one (over "
+                  f"rtol 2^-8 by {over_exact:.2e}); {dt * 1e3:.3f} ms a call")
+            if over > HIST_ATOL:
+                bad.append((name, T, len(lens), over))
+    if bad:
+        sys.exit(f"flash_prefill beyond {HIST_RTOL:.5f} |ref| + 2^-8 "
+                 f"mean|v| + {HIST_ATOL}: {bad}")
 
 
 # The chunk kernel against the XLA reference at HIGHEST precision on float32
@@ -1318,11 +1386,17 @@ def main() -> None:
     B, T = sc.decode_buckets[-1], sc.prefill_buckets[-1]
     print(f"{cfg.name} tp={args.tp}: {nh}q/{n_kv}kv x {hd}, kd={n_kv * hd}, "
           f"page {PS}, pages/seq {pps}, B={B}, T={T}")
+    # The five served geometries, and this model's shard where it is another.
+    prefill_geometries = {m: prefill_geometry(get_model_config(m))
+                          for m in SERVED_MODELS}
+    own = prefill_geometry(cfg, args.tp)
+    if own not in prefill_geometries.values():
+        prefill_geometries[f"{cfg.name} tp={args.tp}"] = own
     checks = {
         "decode": lambda: (check_decode(nh, n_kv, hd, pps, B),
                            time_decode(nh, n_kv, hd, pps, B,
                                        cfg.num_kv_layers)),
-        "prefill": lambda: check_prefill(nh, n_kv, hd, T),
+        "prefill": lambda: check_prefill(prefill_geometries),
         "hist": lambda: check_prefill_history(nh, n_kv, hd, cfg.attn_scale),
         "kvwrite": lambda: [check_kv_write(cfg.num_kv_layers, n_kv, hd, n)
                             for n in (B, T)],

@@ -9,7 +9,11 @@ reference ``values-01-minimal-example*.yaml`` deploy):
   HBM->VMEM with double-buffered DMA and online softmax (the XLA fallback
   gathers the full padded page table instead).
 - flash_prefill.py — ragged (segment-causal) flash attention for prefill,
-  O(T) memory (the XLA fallback materializes the O(T^2) score matrix).
+  O(T) memory (the XLA fallback materializes the O(T^2) score matrix): by
+  blocks of kv heads whose K/V lie in VMEM whole, a kv head's q heads
+  stacked as rows, a loop over the 512-key tiles from a q block's segment
+  start to its diagonal (masked only on the diagonal and where a segment
+  boundary lies), operands in the input's dtype.
 - flash_prefill_hist.py — chunked prefill: the chunk against its own
   history pages in the pool.
 - kv_write.py — the post-scan KV page write: read-modify-write of the

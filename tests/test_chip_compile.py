@@ -182,6 +182,36 @@ def test_flash_prefill_hist_compiles_for_a_v5e(one_chip, no_compile_cache,
             <= 2.5 * T * nh * hd * 2)
 
 
+@pytest.mark.parametrize("nh,nkv,hd,hv,T", [
+    (32, 32, 192, 128, 2048),  # xing, kimi-linear: as many kv heads as heads,
+    (16, 16, 192, 128, 2048),  # kimi-vl:           a narrower v
+    (32, 32, 192, 128, 64),    # ... the smallest bucket: one short tile
+    (32, 8, 128, 128, 2048),   # qwen3-4b: two kv heads a step, 512 rows each
+    (32, 8, 64, 64, 2048),     # granite-4.0-h-micro
+    (4, 1, 128, 128, 2048)],   # a tp=8 shard of qwen: one kv head
+    ids=["xing", "kimi-vl", "xing-64", "qwen", "granite", "tp-shard"])
+def test_flash_prefill_compiles_for_a_v5e(one_chip, no_compile_cache,
+                                          nh, nkv, hd, hv, T):
+    """The fresh-segment kernel at served geometries, bf16: Mosaic takes a
+    block of kv heads' K/V whole in VMEM, the q heads of a kv head merged
+    into rows, and tiles of 512 keys against them. Beside the head-major
+    copies of q, k, v and the output nothing of their size is written."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill import (
+        flash_ragged_prefill)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda q, k, v, seg, pos: flash_ragged_prefill(
+            q, k, v, seg, pos, hd ** -0.5)).lower(
+        arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hv)),
+        arr((T,), i32), arr((T,), i32)).compile()
+    assert "%flash_prefill" in compiled.as_text()
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= T * (nh * (hd + hv) + nkv * (hd + hv)) * 2 + 2**20)
+
+
 @pytest.mark.parametrize("preset,overrides,kernels", [
     ("granite-4.0-h-micro", {},
      ("flash_prefill_hist", "ssm_update", "paged_decode", "kv_write")),

@@ -225,6 +225,108 @@ class TestFlashPrefillKernel:
                                    rtol=2e-5, atol=2e-5)
 
 
+# (heads, kv heads, q/k width, v width): a latent model's materialised form
+# (as many kv heads as heads, a narrower v), GQA 4 at 128 and at 64.
+PREFILL_GEOMETRIES = {"g1-192-128": (4, 4, 192, 128),
+                      "gqa4-128": (8, 2, 128, 128),
+                      "gqa4-64": (8, 2, 64, 64)}
+# name -> (T, segment lengths, block_q, block_k); what is left of T is tail
+# padding. Key tiles of 32 and q blocks of 16 unless said.
+PREFILL_BATCHES = {
+    "one-segment": (96, [96], 16, 32),
+    # a boundary inside key tile [32, 64) and q block [32, 48); one on the
+    # edge of q block [80, 96) that is no tile's edge
+    "boundaries-in-tile-and-on-q-edge": (96, [40, 40, 16], 16, 32),
+    # 100 = 3 tiles and 4 keys = 6 q blocks and 4 rows
+    "T-no-multiple-of-the-tile": (100, [60, 40], 16, 32),
+    "tail-padding-inside-a-q-block": (96, [70], 16, 32),
+    "q-blocks-of-padding-alone": (96, [30, 20], 16, 32),
+    # the blocks the wrapper derives itself: 512 keys a tile, a boundary
+    # inside tile [512, 1024), the window of the last segment starting there
+    "default-blocks": (1100, [600, 300], None, 512),
+}
+
+
+class TestFlashPrefillWalk:
+    """``flash_ragged_prefill`` by blocks of kv heads, loops over the key
+    tiles that exist and masks only where a diagonal or a segment boundary
+    lies: every geometry x every kind of batch against the dense reference."""
+
+    @staticmethod
+    def _batch(T, lens, nh, nkv, hd, hv, dtype):
+        rng = np.random.default_rng(T + sum(lens) + nh)
+        q = jnp.asarray(rng.standard_normal((T, nh, hd)), dtype)
+        k = jnp.asarray(rng.standard_normal((T, nkv, hd)), dtype)
+        v = jnp.asarray(rng.standard_normal((T, nkv, hv)), dtype)
+        pad = T - sum(lens)
+        seg = np.concatenate([np.full(n, i) for i, n in enumerate(lens)]
+                             + [np.full(pad, -1)]).astype(np.int32)
+        pos = np.concatenate([np.arange(n) for n in lens]
+                             + [np.zeros(pad)]).astype(np.int32)
+        return q, k, v, jnp.asarray(seg), jnp.asarray(pos), seg >= 0
+
+    @pytest.mark.parametrize("batch", list(PREFILL_BATCHES))
+    @pytest.mark.parametrize("geometry", list(PREFILL_GEOMETRIES))
+    def test_matches_xla(self, geometry, batch):
+        nh, nkv, hd, hv = PREFILL_GEOMETRIES[geometry]
+        T, lens, bq, bk = PREFILL_BATCHES[batch]
+        q, k, v, seg, pos, real = self._batch(T, lens, nh, nkv, hd, hv,
+                                              jnp.float32)
+        ref = ragged_prefill_attention_xla(q, k, v, seg, pos, 0.125)
+        got = np.asarray(flash_ragged_prefill(
+            q, k, v, seg, pos, 0.125, block_q=bq, block_k=bk, interpret=True))
+        assert got.shape == (T, nh, hv)
+        np.testing.assert_allclose(got[real], np.asarray(ref)[real],
+                                   rtol=2e-5, atol=2e-5)
+        assert not got[~real].any()           # padding rows come out zeros
+
+    @pytest.mark.parametrize("geometry", list(PREFILL_GEOMETRIES))
+    def test_bf16_operands_within_their_declared_roundings(self, geometry):
+        """bf16 inputs go to the MXU as they are, q times ``scale`` as one
+        bf16 value and p as one bf16 term. Against a float32 reference of the
+        same values with that q, an element is off by the bf16 output's own
+        rounding (half a unit in its last place) and at most 2^-8 of the
+        attention-weighted mean of |v| (p's rounding)."""
+        nh, nkv, hd, hv = PREFILL_GEOMETRIES[geometry]
+        T, lens, bq, bk = PREFILL_BATCHES["boundaries-in-tile-and-on-q-edge"]
+        q, k, v, seg, pos, real = self._batch(T, lens, nh, nkv, hd, hv,
+                                              jnp.bfloat16)
+        f32 = jnp.float32
+        q_r = (q.astype(f32) * 0.125).astype(jnp.bfloat16).astype(f32)
+        ref, mean_abs_v = (
+            np.asarray(ragged_prefill_attention_xla(
+                q_r, k.astype(f32), vv, seg, pos, 1.0))[real]
+            for vv in (v.astype(f32), jnp.abs(v.astype(f32))))
+        got = flash_ragged_prefill(q, k, v, seg, pos, 0.125, block_q=bq,
+                                   block_k=bk, interpret=True)
+        assert got.dtype == jnp.bfloat16
+        d = np.abs(np.asarray(got.astype(f32))[real] - ref)
+        assert (d <= 2.0 ** -8 * 1.02 * np.abs(ref) + 2.0 ** -8 * mean_abs_v
+                + 2e-5).all()
+
+    def test_windows_walk_what_exists(self):
+        """The four tile indices of a q block: from its first row's segment
+        start to its last row's diagonal, unmasked where the tile lies under
+        the first row inside the block's one segment, empty for padding."""
+        from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill import (
+            _windows)
+        seg = np.concatenate([np.full(600, 0), np.full(1100, 1),
+                              np.full(348, -1)]).astype(np.int32)
+        win = np.asarray(_windows(jnp.asarray(seg), 2048, 256, 512))
+        win = win.reshape(8, 4).tolist()
+        assert win[0] == [0, 0, 0, 1]     # rows 0-255: the diagonal alone
+        assert win[1] == [0, 0, 0, 1]     # rows 256-511: still tile 0's
+        assert win[2] == [0, 0, 0, 2]     # the boundary at 600 crosses it
+        assert win[3] == [1, 2, 2, 2]     # segment 1 starts inside tile 1
+        assert win[4] == [1, 2, 2, 3]     # rows 1024-1279: tile 1 masked,
+        assert win[5] == [1, 2, 2, 3]     # no whole tile under the rows yet
+        assert win[6] == [1, 1, 1, 4]     # padding from 1700 crosses it
+        assert win[7][0] == win[7][3]     # padding alone: nothing walked
+        one = np.asarray(_windows(jnp.zeros(2048, jnp.int32), 2048, 256,
+                                  512)).reshape(8, 4).tolist()
+        assert one[7] == [0, 0, 3, 4] and one[2] == [0, 0, 1, 2]
+
+
 class TestInt4MatmulKernel:
     """W4A16 dequant-fused matmul kernel (ops/pallas/int4_matmul.py) vs the
     XLA fusion path and the explicit dequant reference — interpret mode
@@ -300,17 +402,20 @@ class TestPallasUnderMesh:
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=2e-5, atol=2e-5)
 
-    def test_flash_prefill_tp_matches_oracle(self):
+    @pytest.mark.parametrize("nh,nkv,hd,hv", [
+        (4, 2, 32, 32),      # one kv head a shard, two q heads on it
+        (2, 2, 48, 32)])     # one head a shard, a narrower v
+    def test_flash_prefill_tp_matches_oracle(self, nh, nkv, hd, hv):
         from kubernetes_gpu_cluster_tpu.ops.attention import (
             ragged_prefill_attention_tp)
         from kubernetes_gpu_cluster_tpu.parallel import make_mesh
 
         mesh = make_mesh(tp=2)
-        T, nh, nkv, hd = 64, 4, 2, 32
+        T = 64
         rng = np.random.default_rng(6)
         q = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((T, nkv, hd)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((T, nkv, hd)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((T, nkv, hv)), jnp.float32)
         seg = np.concatenate([np.full(30, 0), np.full(20, 1), np.full(14, -1)])
         pos = np.concatenate([np.arange(30), np.arange(20), np.zeros(14)])
         seg = jnp.asarray(seg, jnp.int32)
