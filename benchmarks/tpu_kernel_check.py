@@ -788,15 +788,13 @@ def check_ssm(cfg, B, T) -> None:
     the Pallas kernel against the XLA reference (values, the untouched slots
     bitwise), then each timed alone over the SERVED pool (every state layer,
     B + 1 slots, float32), a call a layer chained in one program, against
-    the bytes of the rows' slots. The chunked scan (XLA einsums, no kernel):
-    timed alone over one prompt of the cell's mean length in the top prefill
-    bucket, against its FLOPs."""
+    the bytes of the rows' slots. The chunked scan: ``check_ssm_scan``, at the
+    two chunk buckets of the cell's mixed steps."""
     from kubernetes_gpu_cluster_tpu.ops import ssm as ssm_ops
     from kubernetes_gpu_cluster_tpu.ops.pallas.ssm_update import ssm_update
     Ls, N, di = cfg.num_state_layers, cfg.mamba_d_state, cfg.mamba_d_inner
-    H, P, Q = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_chunk_size
     f32 = jnp.float32
-    k = jax.random.split(jax.random.key(11), 8)
+    k = jax.random.split(jax.random.key(11), 6)
     slots = jnp.concatenate([jax.random.permutation(k[0], B)[:B - 3] + 1,
                              jnp.zeros(3, jnp.int32)]).astype(jnp.int32)
     decay = jax.random.uniform(k[1], (B, di), f32, 0.5, 1.0)
@@ -851,27 +849,69 @@ def check_ssm(cfg, B, T) -> None:
               f"({least / 819e9 * 1e6 / us:.1%})")
         del pool, acc
 
-    n = 1472        # the cell's mean prompt, alone in the 2048 bucket
-    dt = cfg.jnp_dtype
-    x = jax.random.normal(k[6], (T, H, P), f32).astype(dt)
-    dtv = jax.nn.softplus(jax.random.normal(k[7], (T, H), f32) - 2.0)
-    dA = dtv * -jnp.exp(jax.random.uniform(k[0], (H,), f32, 0.0, 2.5))
-    Bs, Cs = (jax.random.normal(k[i], (T, N), f32).astype(dt) for i in (1, 2))
+    for n_tok in (T, T - T // 4):
+        check_ssm_scan(cfg, n_tok)
+
+
+def check_ssm_scan(cfg, T, n=1472) -> None:
+    """The chunked scan's two forms (XLA einsums, the ``ssm_chunk`` kernel)
+    over one prompt of the cell's mean length in a mixed step's chunk
+    bucket, x a ``[T, d_inner]`` array as the conv leaves it: each against
+    the recurrence token by token (y and the final state), then timed alone
+    against the bytes (x in the model's dtype, y float32) and against the
+    MXU passes of the kernel's own products."""
+    from kubernetes_gpu_cluster_tpu.ops import ssm as ssm_ops
+    from kubernetes_gpu_cluster_tpu.ops.pallas import ssm_chunk as kernel
+    N, di = cfg.mamba_d_state, cfg.mamba_d_inner
+    H, P, Q = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_chunk_size
+    f32, dt = jnp.float32, cfg.jnp_dtype
+    k = jax.random.split(jax.random.key(13), 6)
+    x = jax.random.normal(k[0], (T, di), f32).astype(dt)
+    dtv = jax.nn.softplus(jax.random.normal(k[1], (T, H), f32) - 2.0)
+    A = -jnp.exp(jax.random.uniform(k[2], (H,), f32, 0.0, 2.5))
+    Bs, Cs = (jax.random.normal(k[i], (T, N), f32).astype(dt) for i in (3, 4))
     seg = jnp.where(jnp.arange(T) < n, 0, -1).astype(jnp.int32)
     ends = jnp.asarray([n - 1], jnp.int32)
-    init = jnp.zeros((N, di), f32)
-    scan = jax.jit(lambda *a: ssm_ops.ssm_chunk_scan_xla(*a, -2, Q))
-    s = _timed(scan, x, dtv, dA, Bs, Cs, seg, ends, init)
-    y, final = scan(x, dtv, dA, Bs, Cs, seg, ends, init)
+    init = jax.random.normal(k[5], (N, di), f32)
     y_ref, f_ref = jax.jit(ssm_ops.ssm_recurrence)(
-        x[:n], dtv[:n], dA[:n], Bs[:n], Cs[:n], init)
-    flops = T * (2 * Q * N + 2 * Q * di + 4 * N * di)
-    print(f"ssm_chunk_scan_xla alone, T={T} ({n} real), {H} x {P}, N={N}, "
-          f"chunk {Q}: {s * 1e3:.2f} ms a layer; {flops / 1e9:.1f} GFLOP = "
-          f"{flops / 197e12 * 1e3:.3f} ms at 197 TFLOP/s; max|chunked - "
-          f"token by token| y={_err(y[:n], y_ref):.2e} (max|y| "
-          f"{float(jnp.max(jnp.abs(y_ref))):.1f}; the products that feed y "
-          f"run in {dt.__name__}) state={_err(final[0], f_ref):.2e}")
+        x[:n].reshape(n, H, P), dtv[:n], dtv[:n] * A, Bs[:n], Cs[:n], init)
+    nbytes = T * di * (jnp.dtype(dt).itemsize + 4)
+    # a product a head of a 128-lane tile inside a sub-chunk; the carry in
+    # three passes, the read-out in two (ops/pallas/ssm_chunk.py)
+    passes = 2 * T * di * (128 // P * kernel.SUB + 5 * N)
+    print(f"the chunked scan, T={T} ({n} real, from a state), {H} x {P}, "
+          f"N={N}, x {jnp.dtype(dt).name}: {nbytes / 1e6:.0f} MB of x and y "
+          f"= {nbytes / 819e9 * 1e3:.3f} ms at 819 GB/s; the kernel's MXU "
+          f"passes {passes / 1e9:.1f} GFLOP = {passes / 197e12 * 1e3:.3f} "
+          f"ms at 197 TFLOP/s; max|y| {float(jnp.max(jnp.abs(y_ref))):.1f}, "
+          f"max|S| {float(jnp.max(jnp.abs(f_ref))):.1f}")
+    calls = 16
+    for name, form in (("xla", ssm_ops.ssm_chunk_scan_xla),
+                       ("pallas", kernel.ssm_chunk)):
+        def scan(init, hang=0.0):
+            y, final = form((x + hang).reshape(T, H, P), dtv + hang,
+                            (dtv + hang) * A, Bs, Cs, seg, ends, init, 0, Q)
+            return y.reshape(T, di), final
+        y, final = jax.jit(scan)(init)
+
+        # One program (a dispatch costs more than the kernel), each call's
+        # x, dt and state hanging on the call before it, so that nothing of
+        # a call can be lifted out of the loop (the XLA form's re-tiling of
+        # x is a layer's cost in the step program); y carried as the gate
+        # takes it, [T, d_inner]. The hanging costs both forms one pass
+        # over x.
+        def after(_, carry):
+            y, final = carry
+            return scan(final[0], (0.0 * y[0, 0]).astype(dt))
+        chain = jax.jit(lambda init: jax.lax.fori_loop(
+            0, calls, after, (jnp.zeros((T, di), f32), init[None])))
+        s = _timed(chain, init, n=3) / calls
+        e_y = _err(y[:n], y_ref.reshape(n, di))
+        e_s = _err(final[0], f_ref)
+        print(f"ssm_chunk[{name}] alone, {calls} calls chained in one "
+              f"program: {s * 1e3:.3f} ms a layer; max|form - token by "
+              f"token| y={e_y:.2e} state={e_s:.2e}")
+        assert bool(jnp.isfinite(y).all()) and e_s < 1e-3, (name, e_y, e_s)
 
 
 def _state_chain(cfg, name: str, update, inputs, state_ref, y_ref, real,

@@ -716,10 +716,18 @@ class LLMEngine:
                       *(arr((T, H, hd), f32),) * 4, arr((T, H), f32),
                       arr((T,), i32), arr((1,), i32), arr((N, di), f32))
             else:
+                from ..ops.pallas.ssm_chunk import ssm_chunk
                 from ..ops.pallas.ssm_update import ssm_update
                 probe("ssm_update", ssm_update, state_pool, arr((), i32),
                       arr((B,), i32), arr((B, di), f32), arr((B, di), f32),
                       arr((B, N), f32), arr((B, N), f32))
+                # The chunked scan over a full prefill bucket.
+                H, P = cfg.mamba_n_heads, cfg.mamba_d_head
+                probe("ssm_chunk",
+                      lambda *a: ssm_chunk(*a, -2, cfg.mamba_chunk_size),
+                      arr((T, H, P)), arr((T, H), f32), arr((T, H), f32),
+                      arr((T, N)), arr((T, N)), arr((T,), i32),
+                      arr((1,), i32), arr((N, di), f32))
         if cfg.hc_mult > 1:
             # The stream mixers over a full decode bucket and over the
             # widest mixed step.

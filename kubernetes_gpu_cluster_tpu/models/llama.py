@@ -1030,7 +1030,7 @@ def _mamba_segments(cfg, kernels, xs, per_token, consts, seg, seg_ends,
     H, P, N, di = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
                    cfg.mamba_d_inner)
     (dt,), (A, D), n = per_token, consts, xs.shape[0]
-    y, final = ssm_ops.ssm_chunk_scan_xla(
+    y, final = kernels.ssm_chunk(
         xs[:, :di].reshape(n, H, P), dt, dt * A, xs[:, di:di + N],
         xs[:, di + N:], seg, seg_ends, state0, init_seg,
         cfg.mamba_chunk_size)

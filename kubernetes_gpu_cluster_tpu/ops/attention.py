@@ -574,6 +574,19 @@ class Kernels:
         from .pallas.ssm_update import ssm_update
         return ssm_update(pool, layer, slots, decay, dtx, B, C)
 
+    def ssm_chunk(self, x, dt, dA, B, C, seg_ids, seg_ends, init_state,
+                  init_seg, chunk):
+        """A Mamba-2 layer's segment part, a chunk at a time
+        (``ops/ssm.py``): the Pallas kernel or the XLA form; one device, as
+        ``ssm_update``."""
+        if not self.use_pallas:
+            from .ssm import ssm_chunk_scan_xla
+            return ssm_chunk_scan_xla(x, dt, dA, B, C, seg_ids, seg_ends,
+                                      init_state, init_seg, chunk)
+        from .pallas.ssm_chunk import ssm_chunk
+        return ssm_chunk(x, dt, dA, B, C, seg_ids, seg_ends, init_state,
+                         init_seg, chunk)
+
     def kda_update(self, pool, layer, slots, g, beta, q, k, v):
         """A delta-rule layer's one-token update of the rows' slots, in
         place in the carried pool (``ops/kda.py``): the Pallas kernel or the

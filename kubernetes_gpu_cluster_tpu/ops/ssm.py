@@ -15,9 +15,12 @@ token is plain lane-dense arithmetic,
 with ``decay`` and ``dt`` repeated over each head's P lanes.
 
 The references here are what the CPU runs, what ``NO_KERNELS`` names and
-what the Pallas ``ssm_update`` (``ops/pallas/ssm_update.py``) is held to;
-``ops.attention.Kernels.ssm_update`` chooses. The chunked scan has no kernel:
-its products are einsums the MXU takes as they are.
+what the Pallas kernels are held to: ``ssm_update``
+(``ops/pallas/ssm_update.py``) and, for the chunked scan, ``ssm_chunk``
+(``ops/pallas/ssm_chunk.py``: the channels stay on lanes and a lane block's
+state in VMEM from chunk to chunk; as einsums over ``[T, H, P]`` views the
+scan's time on the chip is re-tilings and copies, not its products).
+``ops.attention.Kernels.ssm_update`` and ``.ssm_chunk`` choose.
 """
 
 from __future__ import annotations
@@ -112,6 +115,33 @@ def ssm_update_xla(pool: jax.Array, layer, slots: jax.Array,
     return write_slots(pool, S, slots, layer), y
 
 
+def segment_finals(x_s: jax.Array, B_s: jax.Array, cs_s: jax.Array,
+                   dt_s: jax.Array, sc_s: jax.Array, S_in_s: jax.Array,
+                   seg_in_s: jax.Array, o_s: jax.Array) -> jax.Array:
+    """Each segment's state at ITS last token: the carry into that token's
+    chunk, decayed, plus what the segment's tokens up to there add. Every
+    operand is of the segment's last chunk: x_s [S, Q, H, P], B_s [S, Q, N],
+    cs_s [S, H, Q] the running sum of the chunk's log decay, dt_s [S, H, Q],
+    sc_s [S, Q] its seg_ids, S_in_s [S, N, H*P] the state it was handed, of
+    segment seg_in_s [S], o_s [S] the token's place in the chunk. Shared by
+    the XLA form and the kernel (``ops/pallas/ssm_chunk.py``), whose chunks
+    are shorter. Returns [S, N, H*P] float32."""
+    S, Q, H, P = x_s.shape
+    f32 = jnp.float32
+    cs_o = jnp.take_along_axis(cs_s, o_s[:, None, None], axis=-1)
+    seg_of = jnp.take_along_axis(sc_s, o_s[:, None], axis=-1)      # [S, 1]
+    upto = (jnp.arange(Q)[None, :] <= o_s[:, None]) & (sc_s == seg_of)
+    w = jnp.exp(jnp.where(upto[:, None, :], cs_o - cs_s, -jnp.inf)
+                ) * dt_s                                           # [S, H, Q]
+    xw = (x_s.astype(f32) * w.transpose(0, 2, 1)[..., None]
+          ).reshape(S, Q, H * P)
+    kept = jnp.repeat(jnp.exp(cs_o[..., 0])
+                      * (seg_in_s[:, None] == seg_of), P, axis=-1)
+    return (S_in_s * kept[:, None, :]
+            + jnp.einsum("sjn,sjd->snd", B_s.astype(f32), xw,
+                         precision=jax.lax.Precision.HIGHEST))
+
+
 def ssm_chunk_scan_xla(x: jax.Array, dt: jax.Array, dA: jax.Array,
                        B: jax.Array, C: jax.Array, seg_ids: jax.Array,
                        seg_ends: jax.Array, init_state: jax.Array,
@@ -190,23 +220,11 @@ def ssm_chunk_scan_xla(x: jax.Array, dt: jax.Array, dA: jax.Array,
         y = y + (jnp.einsum("cin,cnd->cid", Cc.astype(f32), S_in)
                  .reshape(nc, Q, H, P) * reach[..., None])
 
-    # Each segment's state at ITS last token: the carry into that token's
-    # chunk, decayed, plus what the segment's tokens up to there add.
     with jax.named_scope("kgct.ssm.scan.final"):
         e = jnp.maximum(seg_ends, 0)
-        c_s, o_s = e // Q, e % Q
-        cs_s, sc_s = cs[c_s], sc[c_s]                          # [S,H,Q] [S,Q]
-        cs_o = jnp.take_along_axis(cs_s, o_s[:, None, None], axis=-1)
-        seg_of = jnp.take_along_axis(sc_s, o_s[:, None], axis=-1)  # [S, 1]
-        upto = (jnp.arange(Q)[None, :] <= o_s[:, None]) & (sc_s == seg_of)
-        w = jnp.exp(jnp.where(upto[:, None, :], cs_o - cs_s, -jnp.inf)
-                    ) * dtc[c_s]                               # [S, H, Q]
-        xw = (xc[c_s].astype(f32) * w.transpose(0, 2, 1)[..., None]
-              ).reshape(-1, Q, H * P)
-        final = (S_in[c_s] * per_lane(
-            jnp.exp(cs_o[..., 0]) * (seg_in[c_s][:, None] == seg_of)
-        )[:, None, :] + jnp.einsum("sjn,sjd->snd", Bc[c_s].astype(f32), xw,
-                                   precision=jax.lax.Precision.HIGHEST))
+        c_s = e // Q
+        final = segment_finals(xc[c_s], Bc[c_s], cs[c_s], dtc[c_s], sc[c_s],
+                               S_in[c_s], seg_in[c_s], e % Q)
     return y.reshape(nc * Q, H, P)[:T], final
 
 

@@ -134,6 +134,9 @@ def _engine(model, mesh=None, spec=False, use_pallas=None, mixed=True,
         m = dataclasses.replace(m, moe_intermediate_size=128)
     elif wide:                  # kd = 256: 128 lanes a shard under tp=2
         m = dataclasses.replace(m, num_heads=8, num_kv_heads=4, head_dim=64)
+        if m.mamba_n_heads:     # ssm_update, ssm_chunk: granite's state
+            m = dataclasses.replace(m, mamba_n_heads=64, mamba_d_head=64,
+                                    mamba_d_state=128, mamba_chunk_size=256)
     if quant:
         m = dataclasses.replace(m, quantization=quant)
     budget, pages = (2048, 513) if long else (32, 129)

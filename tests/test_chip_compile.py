@@ -123,6 +123,33 @@ def test_kda_chunk_compiles_for_a_v5e_without_copies(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * operand
 
 
+def test_ssm_chunk_compiles_for_a_v5e_without_copies(one_chip,
+                                                     no_compile_cache):
+    """granite-4.0-h-micro's chunked scan over the segment part of the
+    widest mixed step (2112 tokens: 17 of the kernel's chunks, the last
+    partial), 64 heads of 64, N = 128, x bfloat16 as the conv leaves it,
+    ``[T, d_inner]``. x is read and y written where they lie: beside y and
+    the states kept for the segment's final nothing of their size is
+    written."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.ssm_chunk import ssm_chunk
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    T, H, P, N = 2112, 64, 64, 128
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(
+        lambda x, *a: ssm_chunk(x.reshape(T, H, P), *a, 0, 256)[0]
+        .reshape(T, H * P)).lower(
+        arr((T, H * P), bf16), arr((T, H)), arr((T, H)), arr((T, N), bf16),
+        arr((T, N), bf16), arr((T,), jnp.int32), arr((1,), jnp.int32),
+        arr((N, H * P))).compile()
+    assert "%ssm_chunk" in compiled.as_text()
+    # the padded tail's copies of x and y (T is not whole chunks here; in
+    # the step program the segment part is) and two states [N, d_inner]
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= (T + 128) * H * P * (2 + 4) + 3 * N * H * P * 4)
+
+
 @pytest.mark.parametrize("T", [64, 1088, 1600, 2112])
 def test_stream_mixers_compile_for_a_v5e_in_place(one_chip, no_compile_cache,
                                                   T):
@@ -214,7 +241,8 @@ def test_flash_prefill_compiles_for_a_v5e(one_chip, no_compile_cache,
 
 @pytest.mark.parametrize("preset,overrides,kernels", [
     ("granite-4.0-h-micro", {},
-     ("flash_prefill_hist", "ssm_update", "paged_decode", "kv_write")),
+     ("flash_prefill_hist", "ssm_chunk", "ssm_update", "paged_decode",
+      "kv_write")),
     ("kimi-vl-a3b", {"num_hidden_layers": 9},
      ("flash_prefill", "latent_prefill_hist", "grouped_matmul",
       "latent_paged_decode", "kv_write")),

@@ -240,27 +240,79 @@ def test_padding_rows_touch_only_the_scrap_slot(params):
 
 # -- the scan and the update, alone ----------------------------------------------
 
+def _pallas_scan(*args):
+    from kubernetes_gpu_cluster_tpu.ops.pallas.ssm_chunk import ssm_chunk
+    return ssm_chunk(*args, interpret=True)
+
+
+# The chunked scan's two implementations: what the CPU and NO_KERNELS run,
+# and the chip's kernel in interpret mode.
+_SCAN_FORMS = [pytest.param(ssm_ops.ssm_chunk_scan_xla, id="xla"),
+               pytest.param(_pallas_scan, id="pallas")]
+
+
+def _scan_inputs(T, H, P, N, key, dtype=jnp.float32):
+    k = jax.random.split(key, 6)
+    x = jax.random.normal(k[0], (T, H, P)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (T, H)) - 2.0)
+    dA = dt * -jnp.exp(jax.random.uniform(k[2], (H,), minval=0.0, maxval=2.5))
+    B, C = (jax.random.normal(k[i], (T, N)).astype(dtype) for i in (3, 4))
+    return (x, dt, dA, B, C), jax.random.normal(k[5], (N, H * P))
+
+
+@pytest.mark.parametrize("form", _SCAN_FORMS)
 @pytest.mark.parametrize("chunk", [8, 256])
-def test_chunked_scan_equals_the_recurrence(chunk):
+def test_chunked_scan_equals_the_recurrence(chunk, form):
     """Three segments in 300 tokens (boundaries at 70 and 201: inside
     chunks at both sizes), the first continuing from a state."""
     T, H, P, N = 300, 4, 8, 16
-    k = jax.random.split(jax.random.key(chunk), 6)
-    x = jax.random.normal(k[0], (T, H, P))
-    dt = jax.nn.softplus(jax.random.normal(k[1], (T, H)) - 2.0)
-    dA = dt * -jnp.exp(jax.random.uniform(k[2], (H,), minval=0.0, maxval=2.5))
-    B, C = jax.random.normal(k[3], (T, N)), jax.random.normal(k[4], (T, N))
-    init = jax.random.normal(k[5], (N, H * P))
+    (x, dt, dA, B, C), init = _scan_inputs(T, H, P, N, jax.random.key(chunk))
     seg = jnp.asarray([0] * 70 + [1] * 131 + [2] * 79 + [-1] * 20)
     ends = jnp.asarray([69, 200, 279, -1])
-    y, final = ssm_ops.ssm_chunk_scan_xla(x, dt, dA, B, C, seg, ends, init,
-                                          0, chunk)
+    y, final = form(x, dt, dA, B, C, seg, ends, init, 0, chunk)
     for s, (a, b) in enumerate(((0, 70), (70, 201), (201, 280))):
         y_s, f_s = ssm_ops.ssm_recurrence(
             x[a:b], dt[a:b], dA[a:b], B[a:b], C[a:b],
             init if s == 0 else jnp.zeros_like(init))
         np.testing.assert_allclose(y[a:b], y_s, atol=2e-4, rtol=1e-4)
         np.testing.assert_allclose(final[s], f_s, atol=2e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("bounds,history", [
+    pytest.param([(0, 300)], False, id="fresh"),
+    pytest.param([(0, 300)], True, id="history"),
+    pytest.param([(0, 70), (70, 201), (201, 300)], True, id="three-packed"),
+    pytest.param([], False, id="no-real-token"),
+])
+def test_the_scan_kernel_in_interpret_mode_equals_the_xla_form(bounds,
+                                                               history):
+    """``ops/pallas/ssm_chunk.py`` against ``ssm_chunk_scan_xla`` at the
+    served heads' lane tiling (64 heads of 64, N = 128: two heads a 128-lane
+    tile, two blocks of 2048 lanes), float32, over 512 tokens: one prompt
+    from nothing and from a slot's state (``init_seg`` 0), a padding tail
+    behind it that ends in a chunk with no real token (zeros there); three
+    prompts packed, both boundaries inside chunks of both forms; no real
+    token at all; absent segments (``seg_ends`` -1) beside the present
+    ones. y and every final, to the order of the sums (the kernel's chunks
+    are 128 tokens, the XLA form's 256)."""
+    T, H, P, N, S = 512, 64, 64, 128, 3
+    inputs, init = _scan_inputs(T, H, P, N, jax.random.key(5))
+    seg = np.full(T, -1, np.int32)
+    for s, (a, b) in enumerate(bounds):
+        seg[a:b] = s
+    ends = jnp.asarray([b - 1 for _, b in bounds]
+                       + [-1] * (S - len(bounds)), jnp.int32)
+    args = (*inputs, jnp.asarray(seg), ends, init, 0 if history else -2, 256)
+    want_y, want_f = ssm_ops.ssm_chunk_scan_xla(*args)
+    got_y, got_f = _pallas_scan(*args)
+    n = bounds[-1][1] if bounds else 0
+    scale = float(jnp.max(jnp.abs(want_y[:n]))) if n else 1.0
+    np.testing.assert_allclose(got_y[:n], want_y[:n], atol=2e-5 * scale)
+    np.testing.assert_allclose(got_f[:len(bounds)], want_f[:len(bounds)],
+                               atol=1e-4)
+    assert bool(jnp.isfinite(got_y).all()) and bool(jnp.isfinite(got_f).all())
+    whole = -(-n // 128) * 128              # the kernel's chunks of padding
+    assert whole < T and not np.asarray(got_y[whole:]).any()
 
 
 def test_pallas_update_in_interpret_mode_equals_the_reference():
