@@ -20,15 +20,15 @@ from .rules import ALL_RULES, rules_by_code
 from .sarif import to_sarif
 
 # Default lint scope: the package itself (this file's grandparent) plus the
-# repo-root scripts (bench, chip smoke) when invoked from a checkout.
+# repo-root chip smoke script when invoked from a checkout.
 PACKAGE_ROOT = Path(__file__).resolve().parents[1]
 
 
 def default_paths() -> list:
     paths = [PACKAGE_ROOT]
-    for script in ("bench.py", "chip_smoke.py"):
-        if (PACKAGE_ROOT.parent / script).is_file():
-            paths.append(PACKAGE_ROOT.parent / script)
+    script = PACKAGE_ROOT.parent / "chip_smoke.py"
+    if script.is_file():
+        paths.append(script)
     return paths
 
 
@@ -42,7 +42,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
                      "baseline (tests/test_lint_clean.py)."))
     p.add_argument("paths", nargs="*", type=Path,
                    help="files/directories to lint (default: the installed "
-                        "package + bench.py + chip_smoke.py)")
+                        "package + chip_smoke.py)")
     p.add_argument("--select", default="",
                    help="comma-separated rule codes or names to run "
                         "(default: all)")

@@ -103,10 +103,9 @@ class SchedulerConfig:
     # running decodes and waiting prefill work coexist, one device step
     # carries every running sequence's decode token PLUS a budgeted chunk of
     # the queue-head prompt — prefills no longer stall decode and decode no
-    # longer starves prefill (engine/mixed_batch.py). ON by default since the
-    # PR-3 CPU A/B showed sustained p50 TTFT 2408->2117 ms with mixing on;
-    # serving opts out via --disable-mixed-batch, bench via
-    # KGCT_BENCH_MIXED=0 (legacy prefill-else-decode policy).
+    # longer starves prefill (engine/mixed_batch.py). ON by default; every
+    # benchmark cell runs with it on. --disable-mixed-batch selects the
+    # legacy prefill-else-decode policy, which no cell measures.
     mixed_batch_enabled: bool = True
     # Per-mixed-step token budget. Decode rows claim their tokens FIRST
     # (decode is never dropped from a mixed step); the head prompt's chunk
@@ -118,8 +117,8 @@ class SchedulerConfig:
     # prompt-lookup proposer (no draft model) and verify all drafts in ONE
     # dispatched device program; acceptance is exact-match for greedy and
     # lossless rejection sampling for sampled decode, so outputs keep the
-    # target distribution. Off by default: serving enables it via
-    # --enable-spec-decode, bench via KGCT_BENCH_SPEC.
+    # target distribution. Off by default (--enable-spec-decode turns it
+    # on); no benchmark cell runs it.
     spec_decode_enabled: bool = False
     # Draft length k per spec step. STATIC: the verify program compiles per
     # (decode bucket) at token width B_pad * (k + 1), so k is part of the

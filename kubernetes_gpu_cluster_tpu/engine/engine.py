@@ -67,8 +67,8 @@ def _maybe_bias(logits, bias_ids, bias_vals):
 
 @dataclasses.dataclass
 class EngineStats:
-    """Aggregate serving counters, consumed by serving.metrics (/metrics) and
-    bench.py. Latency distributions (TTFT, step time, …) live in the engine's
+    """Aggregate serving counters, consumed by serving.metrics (/metrics).
+    Latency distributions (TTFT, step time, …) live in the engine's
     Observability histograms — the host-side sample deques and quantile()
     this class used to carry were superseded and removed with them."""
     tokens_generated: int = 0
@@ -270,7 +270,7 @@ class LLMEngine:
         # One Observability per engine, shared with the scheduler: lifecycle
         # trace events, step-phase attribution, and the /metrics histograms
         # all accumulate here (serving.metrics renders it; /debug/trace
-        # exports it; bench.py reads the TTFT decomposition).
+        # exports it).
         self.obs = Observability()
         if config.model.experts_held:   # the gauges are over this share
             self.obs.moe_held = (config.model.experts_first,

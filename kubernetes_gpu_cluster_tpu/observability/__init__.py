@@ -3,7 +3,7 @@
 One ``Observability`` object per engine, shared with its scheduler: the step
 loop and scheduler call the ``on_*`` lifecycle hooks; serving/metrics.py
 renders the histogram state into /metrics; serving/api_server.py exports the
-trace ring via /debug/trace; bench.py reads the TTFT decomposition deques.
+trace ring via /debug/trace.
 Everything here is bounded (rings + fixed-bucket histograms) and lock-free
 on the hot path — the engine step loop must never block on observability.
 
@@ -20,10 +20,8 @@ always-on series ``kgct_step_device_seconds``, ``kgct_steps_retired_total``,
 (``kgct_worker_seconds_total``) and the frame delay
 (``kgct_frame_delay_seconds``) are kept beside them.
 
-Disable entirely with ``KGCT_TRACE=0`` (hooks become cheap early-returns;
-histograms still fill — they are the /metrics contract). The black-box
-flight recorder (flightrecorder.py) mirrors the same events into its own
-always-on ring (kill switch ``KGCT_FLIGHT=0``) and is NOT touched by
+The black-box flight recorder (flightrecorder.py) mirrors the same events
+into its own ring (kill switch ``KGCT_FLIGHT=0``) and is NOT touched by
 ``/debug/trace?clear=1`` — a scoped capture must never erase the crash
 evidence.
 """
@@ -129,8 +127,7 @@ class SLOTracker:
         return tokens / span
 
     def clear(self) -> None:
-        """Reset the rolling windows (bench phase boundaries); the budget
-        stays."""
+        """Reset the rolling windows; the budget stays."""
         self._ttfts.clear()
         self._good.clear()
         self._window_start = time.monotonic()
@@ -158,15 +155,12 @@ def _outcome(seq, reason) -> str:
 
 
 class Observability:
-    def __init__(self, trace_capacity: int = 8192,
-                 enabled: bool = None):
+    def __init__(self, trace_capacity: int = 8192):
         # Black-box flight recorder: mirrors every trace emit into its own
         # bounded ring (plus periodic state snapshots) and dumps to a JSON
         # file on fatal transitions — independent kill switch KGCT_FLIGHT=0.
         self.flight = FlightRecorder()
-        # enabled=None: the tracer resolves the KGCT_TRACE kill switch
-        # itself (the one definition, shared with the router's tracer).
-        self.tracer = RequestTracer(capacity=trace_capacity, enabled=enabled,
+        self.tracer = RequestTracer(capacity=trace_capacity,
                                     recorder=self.flight)
         # Rolling SLO layer: TTFT attainment + goodput, the autoscaler
         # signals. The API server points ttft_budget_ms at the admission
@@ -220,18 +214,9 @@ class Observability:
         self.e2e_latency = Histogram(
             "kgct_request_e2e_seconds", "arrival to finish",
             labels=("outcome",))
-        # TTFT decomposition samples for bench.py (queue wait / prefill
-        # compute / first-window device->host fetch).
-        self.ttft_queue_s: deque = deque(maxlen=1024)
-        self.ttft_prefill_s: deque = deque(maxlen=1024)
-        self.ttft_fetch_s: deque = deque(maxlen=1024)
-        # Sampled-vs-greedy decode throughput regression guard: tokens and
-        # wall seconds accumulated per decode program mode by the step loop.
-        self.decode_mode_tokens = {"greedy": 0, "sampled": 0}
-        self.decode_mode_wall_s = {"greedy": 0.0, "sampled": 0.0}
         # Mixed (stall-free) batching: device steps by kind plus the
         # cumulative prefill/decode token split of mixed steps — feeds the
-        # kgct_mixed_step_ratio gauge and the bench mixed readout.
+        # kgct_mixed_step_ratio gauge.
         self.step_kind_counts = {kind: 0 for kind in STEP_KINDS}
         self.mixed_prefill_tokens = 0
         self.mixed_decode_tokens = 0
@@ -255,8 +240,8 @@ class Observability:
         self.moe_grouped_tile_fill_share = 0.0
         # Speculative decoding: cumulative drafted vs accepted draft tokens
         # (bonus tokens excluded from both) — feeds the
-        # kgct_spec_acceptance_ratio gauge, the kgct_spec_*_tokens_total
-        # counters, and the bench speculative readout.
+        # kgct_spec_acceptance_ratio gauge and the kgct_spec_*_tokens_total
+        # counters.
         self.spec_drafted_tokens = 0
         self.spec_accepted_tokens = 0
         # Draft PHASE telemetry (n-gram lookups or draft-model dispatches,
@@ -427,14 +412,9 @@ class Observability:
         tier_slo = self._tier_slo(seq)
         if tier_slo is not None:
             tier_slo.on_first_token(ttft)
-        queue = ((seq.scheduled_time - seq.arrival_time)
-                 if seq.scheduled_time is not None else 0.0)
-        prefill = max(ttft - queue - fetch_s, 0.0)
         if seq.scheduled_time is not None:
-            self.prefill_latency.observe(prefill)
-        self.ttft_queue_s.append(queue)
-        self.ttft_prefill_s.append(prefill)
-        self.ttft_fetch_s.append(fetch_s)
+            queue = seq.scheduled_time - seq.arrival_time
+            self.prefill_latency.observe(max(ttft - queue - fetch_s, 0.0))
         self.tracer.emit("first_token", seq.request_id,
                          ttft_ms=round(ttft * 1e3, 2), step=step)
 
@@ -579,9 +559,6 @@ class Observability:
         event = {"step": rec["step"], "batch": rows, **timing}
         if kind == "decode":
             event.update(tokens=new_tokens, mode=mode)
-            if mode in self.decode_mode_tokens:
-                self.decode_mode_tokens[mode] += new_tokens
-                self.decode_mode_wall_s[mode] += duration_s
         if kind in ("mixed", "spec_mixed"):
             # The stall-free batching signal: how this step's token budget
             # split between the prefill chunk and the decode rows (a
@@ -631,33 +608,12 @@ class Observability:
         """accepted/drafted draft tokens over all spec steps, or None
         before any spec step ran. The capacity signal for n-gram drafting:
         near-0 means the workload has no lookup structure (spec steps are
-        pure overhead — disable or switch proposers); the bench's
-        repetitive-suffix phase expects it high."""
+        pure overhead — disable or switch proposers)."""
         if self.spec_drafted_tokens <= 0:
             return None
         return self.spec_accepted_tokens / self.spec_drafted_tokens
 
-    def sampled_decode_ratio(self):
-        """sampled/greedy decode tok/s ratio, or None until both modes have
-        run (round-4 target: >= 0.9)."""
-        tg, ts = self.decode_mode_tokens["greedy"], self.decode_mode_tokens["sampled"]
-        wg, ws = self.decode_mode_wall_s["greedy"], self.decode_mode_wall_s["sampled"]
-        if tg <= 0 or ts <= 0 or wg <= 0 or ws <= 0:
-            return None
-        return (ts / ws) / (tg / wg)
-
     # -- rendering / export --------------------------------------------------
-
-    def ttft_decomposition(self) -> dict:
-        """Median queue / prefill / first-fetch split of recent TTFTs (ms) —
-        the decomposition bench.py reports and QoS PRs will regress against."""
-        def med_ms(xs):
-            xs = sorted(xs)
-            return round(xs[len(xs) // 2] * 1e3, 2) if xs else 0.0
-        return {"queue_ms": med_ms(self.ttft_queue_s),
-                "prefill_ms": med_ms(self.ttft_prefill_s),
-                "first_fetch_ms": med_ms(self.ttft_fetch_s),
-                "samples": len(self.ttft_queue_s)}
 
     def render_prometheus(self) -> list[str]:
         lines: list[str] = []
@@ -715,8 +671,6 @@ class Observability:
                 lines.append(
                     f'kgct_qos_requests_finished_total{{tier="{name}"}} '
                     f"{self.finished_by_tier[name]}")
-        lines.extend(render_gauge("kgct_sampled_decode_ratio",
-                                  self.sampled_decode_ratio()))
         lines.extend(render_gauge("kgct_mixed_step_ratio",
                                   self.mixed_step_ratio()))
         lines.append("# HELP kgct_steps_dispatched_total step programs "

@@ -281,16 +281,3 @@ class StepPhaseStats:
         capture); cumulative totals/counts — the /metrics contract — stay."""
         self._ring.clear()
         self._detached.clear()
-
-    def breakdown(self) -> dict:
-        """Aggregate phase attribution: total seconds and mean ms per
-        occurrence for each phase — the dict bench.py folds into its JSON."""
-        out = {}
-        for p in PHASES:
-            n = self.counts.get(p, 0)
-            out[p] = {
-                "total_s": round(self.totals.get(p, 0.0), 6),
-                "count": n,
-                "mean_ms": (round(self.totals[p] / n * 1e3, 3) if n else 0.0),
-            }
-        return out

@@ -19,7 +19,6 @@ prefill, and fetch.
 
 from __future__ import annotations
 
-import os
 import time
 from collections import deque
 from typing import Optional
@@ -64,21 +63,12 @@ class TraceEvent:
 
 
 class RequestTracer:
-    def __init__(self, capacity: int = 8192,
-                 enabled: Optional[bool] = None, recorder=None):
-        """``enabled`` None resolves the ``KGCT_TRACE`` kill switch here —
-        the ONE definition of the toggle, shared by the engine's
-        Observability and the router's span stream.
-
-        ``recorder``: an optional flight recorder (flightrecorder.py)
+    def __init__(self, capacity: int = 8192, recorder=None):
+        """``recorder``: an optional flight recorder (flightrecorder.py)
         every emit is MIRRORED into — one extra deque append, so the
         black-box capture rides the same call sites as the trace ring. The
-        mirror is independent of ``enabled``: the flight recorder is the
-        always-on crash-capture surface and has its own kill switch
+        recorder is the crash-capture surface and has its own kill switch
         (KGCT_FLIGHT=0)."""
-        if enabled is None:
-            enabled = os.environ.get("KGCT_TRACE", "1") != "0"
-        self.enabled = enabled
         self.recorder = recorder
         self._ring: deque[TraceEvent] = deque(maxlen=capacity)
         # Engine-wide events (empty request id — one "decode" instant per
@@ -91,8 +81,6 @@ class RequestTracer:
         rec = self.recorder
         if rec is not None:
             rec.record(kind, request_id, args)
-        if not self.enabled:
-            return
         ring = self._ring if request_id else self._step_ring
         ring.append(TraceEvent(time.monotonic(), kind, request_id, args))
 

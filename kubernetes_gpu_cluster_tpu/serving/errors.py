@@ -95,9 +95,8 @@ def valid_request_id(rid: Optional[str]) -> Optional[str]:
 def overloaded_error(status: int, message: str,
                      retry_after_s: float) -> web.Response:
     """Shed/drain/no-capacity rejection: OpenAI-shaped error body plus a
-    Retry-After header so well-behaved clients (and bench.py's overload
-    phase) back off for the time the backlog actually needs instead of
-    hammering a doomed queue."""
+    Retry-After header so well-behaved clients back off for the time the
+    backlog actually needs instead of hammering a doomed queue."""
     return web.json_response(
         {"error": {"message": message, "type": "overloaded_error",
                    "code": status}},

@@ -15,10 +15,10 @@ composes:
   prefill FLOPs (the DistServe/Mooncake observation); per token the two
   sides are ``kv_bytes_per_token / link_bandwidth`` against
   ``prefill_flops_per_token / achievable_flops`` — never fetch what is
-  cheaper to re-prefill. The FLOPs model mirrors bench.py's prefill
-  roofline matmul term (the quadratic attention term is EXCLUDED: that
-  underestimates recompute cost, which biases the gate toward skipping —
-  the safe anti-thrash direction);
+  cheaper to re-prefill. The FLOPs model is prefill's matmul term alone
+  (the quadratic attention term is EXCLUDED: that underestimates
+  recompute cost, which biases the gate toward skipping — the safe
+  anti-thrash direction);
 - the BOUNDED spill queue: eviction runs on the engine worker thread and
   must never block on a socket, so the remote-spill hook only enqueues
   (drop-oldest beyond the cap) and an async serving task drains the queue
@@ -110,9 +110,9 @@ class PullPolicy:
 
 def prefill_flops_per_token(model_cfg) -> float:
     """Matmul FLOPs to prefill one token (2 FLOPs/MAC over the attention
-    projections + routed MLP experts, every layer) — the same accounting
-    as bench.py's prefill roofline, minus the T^2 attention term (see
-    module docstring for why excluding it is the safe direction)."""
+    projections + routed MLP experts, every layer), without the T^2
+    attention term (see the module docstring for why excluding it is the
+    safe direction)."""
     h, inter = model_cfg.hidden_size, model_cfg.intermediate_size
     nh, nkv, hd = (model_cfg.num_heads, model_cfg.num_kv_heads,
                    model_cfg.head_dim)

@@ -362,8 +362,6 @@ class Router:
         # scrape straggler discipline: skipped and counted, never hung on.
         self.flight = FlightRecorder()
         self.flight.set_snapshot_source(self._flight_snapshot)
-        # enabled=None: the tracer resolves the KGCT_TRACE kill switch
-        # itself (one definition, shared with the engine's Observability).
         self.tracer = RequestTracer(capacity=4096, recorder=self.flight)
         self.trace_timeout_s = trace_timeout_s
         self.trace_scrape_errors_total = 0

@@ -12,7 +12,8 @@ reference ``old_README.md:998-1002,1130``).
 (``logger.info(..., extra={"request_id": rid})``) — the same ids the
 request-lifecycle tracer records, so a log pipeline (Loki/ELK) joins logs
 with ``/debug/trace`` spans on the id. Logs always go to stderr: stdout is
-reserved for program output (bench.py's result line depends on this).
+reserved for program output (``chip_smoke.py``'s and the benchmark's result
+lines depend on this).
 """
 
 import json

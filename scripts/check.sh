@@ -33,7 +33,7 @@ done
 
 echo ">> kgct-lint (empty-baseline gate)"
 rm -f /tmp/_kgct_check.sarif
-LINT_ARGS=(kubernetes_gpu_cluster_tpu bench.py chip_smoke.py --sarif /tmp/_kgct_check.sarif)
+LINT_ARGS=(kubernetes_gpu_cluster_tpu chip_smoke.py --sarif /tmp/_kgct_check.sarif)
 if [[ -n "${CHANGED_REF}" ]]; then
   LINT_ARGS+=(--changed "${CHANGED_REF}")
 fi

@@ -18,12 +18,29 @@ import jax  # noqa: E402
 
 assert jax.device_count() == 8, f"expected 8 virtual CPU devices, got {jax.devices()}"
 
+# -- the benchmark's own tests ride along -------------------------------------
+# perfbench/tests (readers, rooflines, the spec loader, the trace parser)
+# guard what the driver measures with and live beside it, so a run that
+# names this directory collects that one too. ``pytest tests/test_x.py``
+# stays what it was.
+
+_TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
+_PERFBENCH_TESTS = os.path.join(
+    os.path.dirname(_TESTS_DIR), "perfbench", "tests")
+
+
+def pytest_configure(config):
+    named = {os.path.abspath(a.split("::")[0]) for a in config.args}
+    if _TESTS_DIR in named and _PERFBENCH_TESTS not in named:
+        config.args.append(_PERFBENCH_TESTS)
+
+
 # -- per-test timeout fallback ----------------------------------------------
 # pytest-timeout (wired via pyproject [tool.pytest.ini_options]) is the real
 # implementation when installed; this container does not ship it, so a
 # minimal SIGALRM fallback enforces the same contract: a regressed hang
 # fails ONE test fast (default 300 s, tighter via @pytest.mark.timeout(N))
-# instead of eating the whole 870 s tier-1 budget.
+# instead of eating the whole 1,470 s tier-1 budget.
 
 try:
     import pytest_timeout  # noqa: F401

@@ -6,8 +6,7 @@ acceptance contract — a disaggregated run (prefill-with-hold -> export ->
 wire round-trip -> import -> decode resume) is BYTE-IDENTICAL to a
 colocated run for greedy and seeded-sampled decoding — plus engine-free
 codec/fetch pins. The multi-engine HTTP topology (role-split replicas
-behind the real router) and the bench phase are @slow, per the tier-1
-budget guard.
+behind the real router) is @slow, per the tier-1 budget guard.
 """
 
 import asyncio
@@ -424,23 +423,3 @@ class TestDisaggServing:
                 for runner in reversed(runners):
                     await runner.cleanup()
         asyncio.run(scenario())
-
-    def test_bench_disagg_phase_structure(self):
-        """The KGCT_BENCH_DISAGG A/B end-to-end: both arms report TPOT
-        p95/TTFT p50 from one router scrape, handoffs really happened, and
-        the ratio headline is present. On one CPU core both arms serialize
-        on the same device, so the honest expectation is PARITY (~1.03
-        measured with fair warmup) — the ratio bound below only guards
-        against a regression that makes the handoff path itself slow the
-        decode pool down; the separation the A/B exists to show needs
-        parallel devices (ROADMAP TPU capture)."""
-        import bench
-
-        out = bench._measure_disagg()
-        assert out["disagg"]["handoffs_ok"] > 0
-        for arm in ("colocated", "disagg"):
-            assert out[arm]["decode_tpot_p95_ms"] is not None
-            assert out[arm]["ttft_p50_ms"] is not None
-        assert out["tpot_p95_ratio"] is not None
-        # Parity within single-core scheduling noise.
-        assert out["tpot_p95_ratio"] <= 1.25

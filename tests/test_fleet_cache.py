@@ -5,8 +5,8 @@ proves the acceptance contract — a prefix pulled from a peer's cache and
 streamed into the local cache yields BYTE-IDENTICAL output to recomputing
 it (greedy AND seeded) — plus engine-free codec/policy/queue pins and ONE
 two-server HTTP scenario (pull ok / roofline skip / allowlist /
-kv_pull_fail chaos) on the same tiny engines. Full-topology soaks through
-the router belong to the bench phase (KGCT_BENCH_FLEET_CACHE).
+kv_pull_fail chaos) on the same tiny engines. The router's control plane
+under churn is tests/test_fleet_soak.py's.
 """
 
 import asyncio
@@ -104,7 +104,7 @@ class TestPullPolicy:
         assert pol.kv_bytes_per_token == kv_bytes_per_token(mcfg, 4)
         assert pol.flops_per_token == prefill_flops_per_token(mcfg)
         assert pol.min_tokens == 16
-        # The FLOPs model is bench.py's prefill matmul term: 2 FLOPs/MAC
+        # The FLOPs model is prefill's matmul term: 2 FLOPs/MAC
         # over attention projections + MLP, every layer.
         h, inter = mcfg.hidden_size, mcfg.intermediate_size
         attn = (h * mcfg.num_heads * mcfg.head_dim

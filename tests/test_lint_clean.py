@@ -2,7 +2,7 @@
 
 This is the enforcement half of the static-analysis subsystem: every rule
 in analysis/rules runs over every package module (plus the repo-root
-scripts, bench.py and chip_smoke.py) and the
+script, chip_smoke.py) and the
 baseline is EMPTY. A hot-path host sync, a trace-unsafe branch, a donated
 buffer read, an unbounded metric label — any regression fails here, in
 tests, instead of shipping as a silent perf/correctness cliff. There is
@@ -17,7 +17,7 @@ from kubernetes_gpu_cluster_tpu.analysis.cli import main as lint_main
 
 REPO = Path(__file__).resolve().parent.parent
 PACKAGE = REPO / "kubernetes_gpu_cluster_tpu"
-SCRIPTS = [REPO / "bench.py", REPO / "chip_smoke.py"]
+SCRIPTS = [REPO / "chip_smoke.py"]
 
 
 def test_package_is_lint_clean_empty_baseline():

@@ -657,28 +657,3 @@ class TestLiveMigrationServing:
                 for runner in reversed(runners):
                     await runner.cleanup()
         asyncio.run(scenario())
-
-
-@pytest.mark.slow
-def test_bench_drain_phase_structure():
-    """The KGCT_BENCH_DRAIN A/B end-to-end: both arms deliver EVERY client
-    stream (survivability is not the variable — drain time is), the
-    migrate arm actually migrated, the wait arm actually fell back, and
-    the headline ratio is present. On one CPU core the separation is
-    structural (transfer-bound vs decode-bound), so only a loose bound
-    guards against the migration path itself slowing the drain down."""
-    import bench
-
-    out = bench._measure_drain()
-    for arm in ("wait", "migrate"):
-        assert out[arm]["complete_streams"] == out[arm]["sessions"], arm
-        assert out[arm]["drain_seconds"] > 0
-    assert out["migrate"]["migrations_push_ok"] > 0
-    assert out["wait"]["migrations_push_fallback"] > 0
-    assert out["wait"]["migrations_push_ok"] == 0
-    resumed = out["migrate"]["failovers"]
-    assert resumed["import"] + resumed["recompute"] > 0
-    assert resumed["failed"] == 0
-    assert out["drain_migrate_over_wait_seconds"] is not None
-    # Loose regression bound, not a perf pin (the bench's job to measure).
-    assert out["drain_migrate_over_wait_seconds"] < 1.5

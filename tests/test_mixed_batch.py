@@ -385,7 +385,7 @@ class TestEngineParity:
 class TestObservability:
     def test_fresh_engine_ratio_is_none_and_renders_clean(self):
         from kubernetes_gpu_cluster_tpu.observability import Observability
-        obs = Observability(enabled=True)
+        obs = Observability()
         assert obs.mixed_step_ratio() is None
         text = "\n".join(obs.render_prometheus())
         assert "nan" not in text.lower()

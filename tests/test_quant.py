@@ -367,11 +367,15 @@ def test_opt_class_int8_specs_and_engine():
 
 
 def _matmul_bytes(params):
-    """Buffer bytes of the quantized-matmul surface (weights + scales) — the
-    SAME accounting the bench reports (bench._param_bytes), so this pin and
-    the bench's `matmul_weight_bytes` field cannot drift."""
-    import bench
-    return bench._param_bytes(params)[1]
+    """Buffer bytes of the quantized-matmul surface as uploaded: every
+    QUANT_LAYER_KEYS weight and the head, each with its scales."""
+    matmul = 0
+    for key in QUANT_LAYER_KEYS + ("lm_head",):
+        store = params if key == "lm_head" else params["layers"]
+        for k in (key, key + "_scale"):
+            if k in store:
+                matmul += store[k].size * store[k].dtype.itemsize
+    return matmul
 
 
 @pytest.mark.parametrize("model", ["debug-tiny", "debug-moe"])
@@ -397,19 +401,3 @@ def test_int4_buffer_bytes_half_of_int8_no_dequant_copy(model):
         s4 = p4["layers"][key + "_scale"]
         assert s4.dtype == jnp.float32
         assert s4.shape[-2] == w8.shape[-2] // GROUP     # one row per group
-
-
-def test_roofline_int4_weight_stream_half_of_int8():
-    """bench roofline accounting: int4 weight_stream_bytes reflects packed
-    bytes + scales — about half of int8's, never more than 0.55x."""
-    import bench
-    for model in ("llama-3-8b", "qwen3-14b", "mixtral-8x7b", "debug-tiny"):
-        mcfg = get_model_config(model)
-        s8 = bench._weight_stream_bytes(mcfg, "int8")
-        s4 = bench._weight_stream_bytes(mcfg, "int4")
-        assert 0.45 * s8 <= s4 <= 0.55 * s8, (model, s4, s8)
-        ctx = 512
-        r8 = bench._roofline(mcfg, "int8", 8, ctx)
-        r4 = bench._roofline(mcfg, "int4", 8, ctx)
-        assert r4["weight_stream_bytes"] == s4
-        assert r4["kv_bytes_per_step"] == r8["kv_bytes_per_step"]  # KV bf16

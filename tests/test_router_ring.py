@@ -1,7 +1,6 @@
 """Cache-aware fleet routing: hash-ring properties, bounded-load pick
 policy, session stickiness, and membership-churn remap — all pure-Python /
-aiohttp simulation, no engines (the multi-engine router bench phase is the
-one @slow test at the bottom).
+aiohttp simulation, no engines.
 
 Correctness here is a DISTRIBUTION property: the ring must be
 deterministic across router instances (hashlib, never the salted builtin
@@ -652,10 +651,6 @@ class TestMergedFleetTrace:
         asyncio.run(scenario())
 
 
-# ---------------------------------------------------------------------------
-# The multi-engine bench phase (real engines behind the real router)
-# ---------------------------------------------------------------------------
-
 @pytest.mark.slow
 @pytest.mark.chaos
 class TestRemapContractSoak:
@@ -731,31 +726,6 @@ class TestRemapContractSoak:
                 for runner in runners:
                     await runner.cleanup()
         asyncio.run(scenario())
-
-
-@pytest.mark.slow
-class TestRouterBenchPhase:
-    def test_affinity_concentrates_locality_over_least_inflight(self):
-        """The KGCT_BENCH_ROUTER A/B end-to-end: the affinity arm routes
-        every session to its ring owner (hit ratio 1.0, zero remaps), the
-        owner replica's prefix-cache hit ratio strictly exceeds the
-        least-inflight arm's best, and the headline ratio is present. The
-        routing-count assertions are deterministic; wall-clock only gets a
-        loose sanity bound (this is the bench's job to measure)."""
-        import bench
-
-        out = bench._measure_router()
-        li, aff = out["least_inflight"], out["prefix_affinity"]
-        assert aff["affinity_hit_ratio"] == 1.0
-        assert aff["ring_remaps"] == 0
-        li_best = max((p["hit_ratio"] or 0.0) for p in li["per_replica"])
-        owner_ratios = [p["hit_ratio"] for p in aff["per_replica"]
-                        if p["requests"] > 0]
-        assert owner_ratios and min(owner_ratios) > li_best
-        # Sessions scattered under least-inflight (both replicas served)...
-        assert all(p["requests"] > 0 for p in li["per_replica"])
-        assert out["warm_ttft_ratio"] is not None
-        assert out["warm_ttft_ratio"] < 1.5   # loose: not a perf pin
 
 
 class TestDisaggRouting:

@@ -421,14 +421,11 @@ class TestObservability:
         for phase in ("schedule", "host_prep", "device_dispatch",
                       "device_fetch", "postproc", "detokenize"):
             assert obs.phases.totals[phase] > 0.0, f"{phase} never recorded"
-        b = obs.phases.breakdown()
-        assert b["device_dispatch"]["count"] > 0
-        assert b["device_dispatch"]["mean_ms"] >= 0
-        # The TTFT decomposition bench.py folds into its JSON line.
-        d = obs.ttft_decomposition()
-        assert d["samples"] > 0
-        assert all(k in d for k in ("queue_ms", "prefill_ms",
-                                    "first_fetch_ms"))
+        assert obs.phases.counts["device_dispatch"] > 0
+        # What a TTFT is made of, as /metrics carries it: queue wait and
+        # prefill, one observation each a first token.
+        assert obs.ttft.count > 0
+        assert obs.queue_wait.count > 0 and obs.prefill_latency.count > 0
 
 
 class TestFleetTelemetry:
@@ -541,8 +538,8 @@ class TestRequestIdPropagation:
             await rs.read()
         loop.run_until_complete(go())
 
-    def test_tracing_and_recorder_off_byte_identical(self, api_client):
-        """The acceptance pin: tracer+recorder only OBSERVE — toggling both
+    def test_recorder_off_byte_identical(self, api_client):
+        """The acceptance pin: the recorder only OBSERVES — toggling it
         off must not perturb engine outputs (greedy, same warm engine)."""
         loop, client = api_client
         obs = _SERVER["api"].engine.engine.obs
@@ -554,12 +551,10 @@ class TestRequestIdPropagation:
             assert r.status == 200
             return (await r.json())["choices"][0]["text"]
         text_on = loop.run_until_complete(one())
-        obs.tracer.enabled = False
         obs.flight.enabled = False
         try:
             text_off = loop.run_until_complete(one())
         finally:
-            obs.tracer.enabled = True
             obs.flight.enabled = True
         assert text_on == text_off
 
