@@ -1378,6 +1378,183 @@ def check_hc_mix(cfg) -> None:
         print("hc-mix FAILED:\n  " + "\n  ".join(failed))
         sys.exit(1)
 
+# The indexer's gate (``--kernels dsa``). A position the device chose and
+# float64 did not is a NEAR TIE, not a fault, where its float64 score lies
+# within this much of the row's k-th float64 score, as a share of the row's
+# largest |score|: float32 sums of 32 x 128 products of bf16 values differ
+# from float64's by up to ~4096 x 2^-24 = 2^-12 of the largest term in the
+# worst case and ~2^-18 in practice; 2^-14 leaves both room and is 1/500 of
+# the spacing of neighbouring scores at the k-th rank (~1/k of the spread).
+DSA_TIE_LIMIT = 2.0 ** -14
+# ... and a device score is a fault where it leaves float64's by more than
+# 2^-12 of the row's largest |score|.
+DSA_SCORE_LIMIT = 2.0 ** -12
+# The chunk's masked attention meets p as ONE bfloat16 term
+# (``ops.dsa.attend_masked``, as ``flash_prefill``): beyond its bf16
+# output's own rounding it may be off by this much, absolute, of outputs
+# of size ~0.02 (one term read 6.5e-5 on the v5e; the rows' attention, two
+# terms, keeps ``HIST_ATOL``).
+DSA_CHUNK_ATOL = 2e-4
+
+
+def check_dsa(cfg) -> None:
+    """A sparse-attention model's indexer and chosen-row attention
+    (``ops/dsa.py``) at the served geometry:
+
+    - index scores at 64 rows over 8192 keys each (the decode form) and at a
+      2048-token chunk behind 6144 tokens of history (the segment form)
+      against float64 on the host, within ``DSA_SCORE_LIMIT``;
+    - the share of the chosen positions that are float64's choice, with the
+      score gap at each disagreement (``DSA_TIE_LIMIT``: a near tie is not a
+      fault);
+    - the rows' attention over 2048 gathered rows and the chunk's masked
+      attention against the same in float32 at HIGHEST, within the bf16
+      output's rounding (``HIST_RTOL``, ``HIST_ATOL``);
+    - a planted choice that is off by one page must FAIL that comparison.
+
+    Each piece is timed as calls chained in one program. Exit 1 beyond a
+    limit, or where the planted fault passes."""
+    from kubernetes_gpu_cluster_tpu.ops import dsa
+    H, D, k = cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk
+    nh, W, r = cfg.num_heads, cfg.kv_row_padded, cfg.kv_lora_rank
+    rng = np.random.default_rng(11)
+    failed = []
+
+    def bf(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, jnp.bfloat16)
+
+    def f64(a):
+        return np.asarray(a.astype(jnp.float32), np.float64)
+
+    def scores64(q, w, keys):       # q [n, H, D], w [n, H], keys [m, D]
+        s = np.einsum("nhd,md->nhm", f64(q), f64(keys))
+        return np.einsum("nhm,nh->nm", np.maximum(s, 0.0), np.asarray(w, np.float64))
+
+    def agree(name, got, want, allowed):
+        """Scores within the limit; the device's choice against float64's."""
+        top = np.abs(np.where(allowed, want, 0.0)).max(axis=1, keepdims=True)
+        off = float((np.abs(np.where(allowed, got - want, 0.0)) / top).max())
+        mask = np.asarray(dsa.topk_mask(jnp.asarray(got, jnp.float32),
+                                        jnp.asarray(allowed), k))
+        masked = np.where(allowed, want, -np.inf)
+        kth = -np.sort(-masked, axis=1)[:, k - 1:k]
+        mine = mask & (masked < kth)          # chosen here, not by float64
+        gap = np.where(mine, (kth - masked) / top, 0.0)
+        same = 1.0 - mine.sum() / max(mask.sum(), 1)
+        print(f"dsa {name}: scores off float64 by {off:.2e} of a row's "
+              f"largest (limit {DSA_SCORE_LIMIT:.2e}); {100 * same:.4f} % of "
+              f"{int(mask.sum())} chosen positions are float64's choice, "
+              f"{int(mine.sum())} disagree, the widest by {gap.max():.2e} of "
+              f"a row's largest (limit {DSA_TIE_LIMIT:.2e})")
+        if off > DSA_SCORE_LIMIT:
+            failed.append(f"{name}: a score off by {off:.2e}")
+        if gap.max() > DSA_TIE_LIMIT:
+            failed.append(f"{name}: a chosen position {gap.max():.2e} under "
+                          "float64's k-th score")
+
+    # -- index scores and the choice ---------------------------------------
+    R, S = 64, 8192
+    q, w, keys = bf(R, H, D), jnp.asarray(
+        rng.standard_normal((R, H)) * (H * D) ** -0.5, jnp.float32), bf(
+            R, S, D)
+    ctx = rng.integers(S // 2, S, R)
+    allowed = np.arange(S)[None, :] < ctx[:, None]
+    got = np.asarray(jax.jit(dsa.row_scores)(q, w, keys))
+    want = np.stack([scores64(q[i:i + 1], w[i:i + 1], keys[i])[0]
+                     for i in range(R)])
+    agree(f"{R} rows x {S} keys", got, want, allowed)
+    dt = _timed(jax.jit(dsa.row_scores), q, w, keys, n=5)
+    print(f"dsa row_scores {R} x {S}: {dt * 1e3:.3f} ms a call")
+
+    T, hist = 2048, 6144
+    q, w, keys = bf(T, H, D), jnp.asarray(
+        rng.standard_normal((T, H)) * (H * D) ** -0.5, jnp.float32), bf(
+            hist + T, D)
+    allowed = np.arange(hist + T)[None, :] <= hist + np.arange(T)[:, None]
+    scores = jax.jit(dsa.index_scores)(q, w, keys)
+    agree(f"a {T}-token chunk behind {hist}", np.asarray(scores),
+          scores64(q, w, keys), allowed)
+    allowed_d = jnp.asarray(allowed)
+    dt_s = _timed(jax.jit(dsa.index_scores), q, w, keys, n=3)
+    dt_m = _timed(jax.jit(lambda s: dsa.topk_mask(s, allowed_d, k)), scores,
+                  n=3)
+    print(f"dsa index_scores {T} x {hist + T}: {dt_s * 1e3:.3f} ms; "
+          f"topk_mask: {dt_m * 1e3:.3f} ms")
+    rs = jnp.asarray(rng.standard_normal((16, 12289)), jnp.float32)
+    ra = jnp.asarray(np.arange(12289)[None, :] < 8000)
+    dt_i = _timed(jax.jit(lambda s: dsa.topk_indices(s, ra, k)[0]), rs, n=5)
+    print(f"dsa topk_indices 16 x 12289: {dt_i * 1e3:.3f} ms")
+
+    # -- attention over the chosen rows ------------------------------------
+    def within(name, out, ref, must_fail=False):
+        d = jnp.abs(out.astype(jnp.float32) - ref)
+        over = float(jnp.max(d - HIST_RTOL * jnp.abs(ref)))
+        print(f"dsa {name}: max|served - XLA HIGHEST| = "
+              f"{float(jnp.max(d)):.5f} (over rtol 2^-8 by {over:.2e})")
+        if (over > HIST_ATOL) != must_fail:
+            failed.append(f"{name}: over by {over:.2e}"
+                          + (" (the planted fault PASSED)" if must_fail
+                             else ""))
+
+    scale = cfg.attn_scale
+    R, P = 16, 96
+    pool = bf(P * PS, W, scale=0.3)
+    qa = bf(R, nh, W)
+    idx = jnp.asarray(np.stack([rng.choice(P * PS - PS, k, replace=False)
+                                for _ in range(R)]), jnp.int32)
+    valid = jnp.asarray(rng.random((R, k)) < 0.95)
+
+    def rows_attend(pool, qa, idx, valid):
+        return dsa.attend_gathered(qa, pool[idx], valid, scale, r)
+
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(rows_attend)(pool.astype(jnp.float32),
+                                   qa.astype(jnp.float32), idx, valid)
+    within(f"rows' attention, {R} x {k} gathered rows",
+           jax.jit(rows_attend)(pool, qa, idx, valid), ref)
+    within("rows' attention, the choice off by one page",
+           jax.jit(rows_attend)(pool, qa, idx + PS, valid), ref,
+           must_fail=True)
+    dt = _timed(jax.jit(rows_attend), pool, qa, idx, valid, n=10)
+    print(f"dsa gather + attend {R} x {k}: {dt * 1e3:.3f} ms a call "
+          f"({R * k * W * 2 / dt / 1e9:.0f} GB/s of chosen rows)")
+
+    T, m = 2048, 10240
+    hd, vd = cfg.head_dim, cfg.v_head_dim
+    qm, km, vm = bf(T, nh, hd), bf(m, nh, hd), bf(m, nh, vd, scale=0.3)
+    mask = dsa.topk_mask(scores, allowed_d, k)
+    mask = jnp.pad(mask, ((0, 0), (0, m - mask.shape[1])))
+    attend = lambda q, kk, v: dsa.attend_masked(q, kk, v, mask, scale)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(attend)(*(a.astype(jnp.float32)
+                                for a in (qm, km, vm)))
+    # p as ONE bfloat16 term, as flash_prefill has it: its own limit
+    d = jnp.abs(jax.jit(attend)(qm, km, vm).astype(jnp.float32) - ref)
+    over = float(jnp.max(d - HIST_RTOL * jnp.abs(ref)))
+    print(f"dsa a chunk's masked attention, {T} x {m}: max|served - XLA "
+          f"HIGHEST| = {float(jnp.max(d)):.5f} (over rtol 2^-8 by "
+          f"{over:.2e}; limit {DSA_CHUNK_ATOL:.0e})")
+    if over > DSA_CHUNK_ATOL:
+        failed.append(f"a chunk's masked attention: over by {over:.2e}")
+    dt = _timed(jax.jit(attend), qm, km, vm, n=3)
+    print(f"dsa attend_masked {T} x {m}: {dt * 1e3:.3f} ms a call")
+    # the k-th score by counting against a sort's
+    keyed = jax.jit(lambda s: dsa._ordered(jnp.where(allowed_d, s, -jnp.inf)))(
+        scores)
+    by_sort = jax.jit(lambda s: jax.lax.top_k(
+        jnp.where(allowed_d, s, -jnp.inf), k)[0][:, -1:])
+    same = bool(jnp.all(dsa._ordered(by_sort(scores))
+                        == jax.jit(lambda x: dsa.kth_largest(x, k))(keyed)))
+    print(f"dsa kth_largest {T} x {hist + T}: counting "
+          f"{_timed(jax.jit(lambda x: dsa.kth_largest(x, k)), keyed, n=3) * 1e3:.3f}"
+          f" ms, top_k {_timed(by_sort, scores, n=3) * 1e3:.3f} ms; "
+          f"the same values: {same}")
+    if not same:
+        failed.append("kth_largest by counting is not top_k's k-th value")
+    if failed:
+        print("dsa FAILED:\n  " + "\n  ".join(failed))
+        sys.exit(1)
+
 
 def check_int4_matmul() -> None:
     """W4A16 dequant-fused matmul (ops/pallas/int4_matmul.py): packed tiles
@@ -1452,10 +1629,12 @@ def main() -> None:
         "kda-chunk": lambda: check_kda_chunk(cfg, T),
         "kda-chain": lambda: check_kda_chain(cfg, Kernels(use_pallas=True)),
         "hc-mix": lambda: check_hc_mix(cfg),
+        "dsa": lambda: check_dsa(cfg),
     }
     if cfg.is_mla and args.kernels == ap.get_default("kernels"):
-        args.kernels = "latent,experts" + (",hc-mix" if cfg.hc_mult > 1
-                                           else "")
+        args.kernels = ("dsa,experts" if cfg.index_topk else
+                        "latent,experts" + (",hc-mix" if cfg.hc_mult > 1
+                                            else ""))
     if cfg.state_kind == "kda" and args.kernels in (
             ap.get_default("kernels"), "latent,experts"):
         args.kernels = "latent,kda,kda-chunk,kda-chain"

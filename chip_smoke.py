@@ -78,10 +78,21 @@ MODELS = {
     "xing4.0-29b-a4b": dict(
         vocab=131072, long_prompt=3000, gen=256,
         flags=["--hf-overrides", '{"num_hidden_layers": 8}']),
+    # ``--model glm-5.2``: the benchmark's cut, published layers 2-7 (one
+    # dense + five expert layers, indexers in two of them), experts 0-15 of
+    # 256, ids 0-19,359; a 5000-token prompt is three chunks, the later two
+    # choosing 2048 of their history.
+    "glm-5.2": dict(
+        vocab=19360, long_prompt=5000, gen=256,
+        flags=["--hf-overrides", '{"num_hidden_layers": 6, '
+               '"layers_from": 2, "experts_held": 16, '
+               '"vocab_size": 19360}', "--max-model-len", "12288",
+               "--max-num-seqs", "16"]),
     # Rehearsal only: max_model_len 512 cannot hold a chunking prompt.
     "debug-tiny": dict(vocab=512, long_prompt=400, gen=96),
     "debug-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
     "debug-hc-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
+    "debug-dsa-mla-moe": dict(vocab=512, long_prompt=400, gen=96),
     "debug-ssm-hybrid": dict(vocab=512, long_prompt=400, gen=96),
     "debug-kda-hybrid": dict(vocab=512, long_prompt=400, gen=96,
                              flags=["--hf-overrides", '{"experts_held": 4}']),
@@ -89,7 +100,8 @@ MODELS = {
 REHEARSAL_OF = {"qwen3-4b": "debug-tiny", "kimi-vl-a3b": "debug-mla-moe",
                 "granite-4.0-h-micro": "debug-ssm-hybrid",
                 "kimi-linear-48b-a3b": "debug-kda-hybrid",
-                "xing4.0-29b-a4b": "debug-hc-mla-moe"}
+                "xing4.0-29b-a4b": "debug-hc-mla-moe",
+                "glm-5.2": "debug-dsa-mla-moe"}
 HEALTH_TIMEOUT_S = 600
 REQUEST_TIMEOUT_S = 600
 DRAIN_TIMEOUT_S = 150
@@ -398,14 +410,16 @@ def run(args) -> tuple[dict, dict]:
             raise bg["error"]
         statuses["background_stream"] = statuses["mixed_short"] = 200
 
-        if "state_bytes" in health or "residual_streams" in health:
+        if ("state_bytes" in health or "residual_streams" in health
+                or "index_topk" in health):
             # 7. a state model: three short prompts at once on an idle
             #    server ride ONE packed prefill, whose segment boundaries
             #    fall inside the scan's chunks; each must start as it does
             #    alone (a slot found as another sequence left it, or a
             #    state carried over a boundary, would not). A model with
             #    residual streams: its mixers' token blocks hold several
-            #    prompts' tokens.
+            #    prompts' tokens. A model that chooses: no prompt's choice
+            #    may reach into its neighbour's tokens.
             packed = [dict(prompt=prompt(n), max_tokens=8, temperature=0,
                            logprobs=1, return_tokens_as_token_ids=True)
                       for n in (40, 300, 17)]

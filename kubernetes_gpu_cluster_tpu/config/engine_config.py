@@ -95,6 +95,11 @@ class SchedulerConfig:
     # are checked on the host after each window; tokens generated past a stop
     # are discarded.
     decode_window: int = 8
+    # Prompt lengths whose mixed steps beside full seats the serving CLI
+    # meets before it listens (``LLMEngine.warm_mixed_steps``): one program
+    # a (chunk rung, history width) such a prompt passes through. A prompt
+    # of a few tokens by default; a deployment of long prompts names theirs.
+    warm_prompt_lens: tuple[int, ...] = (1,)
     # Automatic prefix caching (vLLM enablePrefixCaching parity): completed
     # prompts' full KV pages are content-addressed and reused by later
     # requests sharing a page-aligned prefix (engine/kv_cache.PrefixCache).

@@ -148,8 +148,36 @@ class ModelConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: tuple = (-30.0, 30.0)
+    # DeepSeek Sparse Attention (glm_moe_dsa's ``index_*`` keys), on when
+    # ``index_topk`` > 0: a lightning indexer (``index_n_heads`` heads of
+    # ``index_head_dim``, ONE key a token) scores every earlier token, and
+    # attention runs over the ``index_topk`` best of them only
+    # (ops/dsa.py). ``indexer_types`` says, a layer, who chooses: "full"
+    # layers hold an indexer, an index-key pool layer beside their latent
+    # pages, and choose; "shared" layers attend over the choice of the
+    # nearest "full" layer before them.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: Optional[tuple] = None
 
     def __post_init__(self):
+        if self.index_topk:
+            types = self.indexer_types or ()
+            if (len(types) != self.num_layers or types[0] != "full"
+                    or set(types) - {"full", "shared"}):
+                raise ValueError(
+                    f"{self.name}: index_topk {self.index_topk} needs "
+                    f"indexer_types, 'full' or 'shared' for each of the "
+                    f"{self.num_layers} layers, the first of them 'full' "
+                    f"(a 'shared' layer attends over the choice of the "
+                    f"'full' layer before it); got {types!r}")
+            if not (self.is_mla and self.q_lora_rank
+                    and self.layer_types is None):
+                raise ValueError(
+                    f"{self.name}: the indexer reads the query latent of "
+                    "latent attention (q_lora_rank) and chooses among "
+                    "latent pages; every layer an attention layer")
         if self.hc_mult > 1 and self.residual_multiplier != 1.0:
             raise ValueError(
                 f"{self.name}: residual_multiplier "
@@ -339,6 +367,13 @@ class ModelConfig:
         return -(-self.kv_row_dim // 128) * 128 if self.is_mla else self.kv_row_dim
 
     @property
+    def index_layers(self) -> tuple:
+        """The layers that hold an indexer and index keys, in order: the
+        depth of the index-key pool and of the ``indexer`` weight stack."""
+        return tuple(i for i, t in enumerate(self.indexer_types or ())
+                     if t == "full") if self.index_topk else ()
+
+    @property
     def kv_pools(self) -> int:
         """Pools of rows the cache keeps: K and V, or the one latent pool."""
         return 1 if self.is_mla else 2
@@ -408,6 +443,26 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         scoring_func="sigmoid", routed_scaling_factor=2.0,
         rope_theta=10000.0, rope_scaling=_YARN_DEBUG, rms_norm_eps=1e-6,
         hc_mult=4, max_model_len=512, dtype="float32",
+    ),
+    # glm-5.2's block at a size the CPU tests can afford: debug-hc-mla-moe's
+    # latent attention (one residual stream, plain rope) behind a learned
+    # top-16 choice: 2 index heads of 32 (16 of them rotated), indexers in
+    # layers 0 and 3, 1 dense + 4 expert layers. 16 is small enough that
+    # every form chooses, a fresh chunk included. (A scaling factor of 3:
+    # with glm's 2.5 a greedy stream of chip_smoke's rehearsal meets the
+    # end-of-sequence id, one of only 512.)
+    "debug-dsa-mla-moe": _p(
+        "debug-dsa-mla-moe", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=5, num_heads=4, num_kv_heads=4,
+        head_dim=48, kv_lora_rank=64, q_lora_rank=48, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, num_experts=8,
+        num_experts_per_tok=3, moe_intermediate_size=64,
+        num_shared_experts=1, first_k_dense_replace=1,
+        scoring_func="sigmoid", routed_scaling_factor=3.0,
+        rope_theta=8000000.0, rms_norm_eps=1e-6, index_topk=16,
+        index_n_heads=2, index_head_dim=32,
+        indexer_types=("full", "shared", "shared", "full", "shared"),
+        max_model_len=512, dtype="float32",
     ),
     # granite-4.0-h-micro's block at a size the CPU tests can afford: 2
     # periods of [2 state, 1 attention, 1 state] (the attention layer INSIDE
@@ -567,6 +622,28 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
         hc_res_clamp=(-30.0, 30.0), max_model_len=4096,
     ),
+    # zai-org/GLM-5.2 (glm_moe_dsa): a deepseek_v3 decoder (latent attention
+    # behind a low-rank query, 256 sigmoid-routed experts of 2048 top-8 x 2.5
+    # + 1 shared, three leading dense layers of 12288, plain rope at theta
+    # 8e6) with DeepSeek Sparse Attention: indexers of 32 heads x 128 in
+    # layers 0-2 and every fourth layer from 6 on, whose top-2048 choice the
+    # three layers behind each share. 1.5 TB of bf16 weights:
+    # ``--hf-overrides`` names the share one chip holds. Its
+    # multi-token-prediction module is not served.
+    "glm-5.2": _p(
+        "glm-5.2", vocab_size=154880, hidden_size=6144,
+        intermediate_size=12288, num_layers=78, num_heads=64,
+        num_kv_heads=64, head_dim=256, kv_lora_rank=512, q_lora_rank=2048,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        num_experts=256, num_experts_per_tok=8, moe_intermediate_size=2048,
+        num_shared_experts=1, first_k_dense_replace=3,
+        scoring_func="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, rope_theta=8000000.0, rms_norm_eps=1e-5,
+        index_topk=2048, index_n_heads=32, index_head_dim=128,
+        indexer_types=tuple("full" if i < 3 or (i - 3) % 4 == 3 else "shared"
+                            for i in range(78)),
+        max_model_len=4096,
+    ),
 }
 
 
@@ -594,13 +671,17 @@ HF_SHAPE_KEYS: dict[str, str] = {
     # Not HF's: the share of the routed experts this process holds.
     "experts_first": "experts_first",
     "experts_held": "experts_held",
+    # Not HF's, and no field: the first of the published layers this
+    # process holds (``num_hidden_layers`` of them: one pipeline stage).
+    "layers_from": "layers_from",
 }
 
 
 def apply_hf_overrides(cfg: ModelConfig, overrides: dict) -> ModelConfig:
     """``--hf-overrides '{"num_hidden_layers": 9}'``: HF shape keys laid
-    over a config. Anything that is not a whole-number shape key is refused
-    by name."""
+    over a config (``layers_from`` k: the layers held are the published
+    ones from k on, not from 0). Anything that is not a whole-number shape
+    key is refused by name."""
     fields = {}
     for key, val in overrides.items():
         if key not in HF_SHAPE_KEYS:
@@ -611,8 +692,27 @@ def apply_hf_overrides(cfg: ModelConfig, overrides: dict) -> ModelConfig:
             raise ValueError(
                 f"--hf-overrides: {key} must be a whole number, not {val!r}")
         fields[HF_SHAPE_KEYS[key]] = val
+    start = fields.pop("layers_from", 0)
     depth = fields.get("num_layers")
-    if (cfg.layer_types is not None and depth is not None
+    if start:
+        # A window of the published layers (one pipeline stage): the
+        # leading dense layers are those of the published ones inside it,
+        # and every per-layer list keeps the entries of the layers held.
+        depth = fields.setdefault("num_layers", cfg.num_layers - start)
+        if depth < 1 or start + depth > cfg.num_layers:
+            raise ValueError(
+                f"--hf-overrides: layers {start} to {start + depth - 1} are "
+                f"not among {cfg.name}'s {cfg.num_layers}")
+        if "first_k_dense_replace" in fields:
+            raise ValueError(
+                "--hf-overrides: layers_from says which of the leading "
+                "dense layers are held; first_k_dense_replace beside it "
+                "is refused")
+        fields["first_k_dense_replace"] = max(
+            cfg.first_k_dense_replace - start, 0)
+        if cfg.layer_types is not None:
+            fields["layer_types"] = cfg.layer_types[start:start + depth]
+    elif (cfg.layer_types is not None and depth is not None
             and depth != cfg.num_layers):
         period, nd = cfg.layer_period, cfg.num_dense_layers
         if depth < nd or (depth - nd) % len(period):
@@ -623,6 +723,9 @@ def apply_hf_overrides(cfg: ModelConfig, overrides: dict) -> ModelConfig:
                 f"({len(period)} layers: {', '.join(period)})")
         fields["layer_types"] = (cfg.layer_types[:nd]
                                  + period * ((depth - nd) // len(period)))
+    if cfg.indexer_types is not None:
+        fields["indexer_types"] = cfg.indexer_types[start:][
+            :fields.get("num_layers", cfg.num_layers)]
     cfg = cfg.replace(**fields)
     if cfg.is_mla:
         cfg = cfg.replace(head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)

@@ -32,6 +32,7 @@ import numpy as np
 
 from ..analysis.sanitize import build_step_sanitizer
 from ..config import EngineConfig, cache_kind_refusal
+from ..utils.math import next_power_of_2
 from ..models import llama as model_lib
 from ..observability import Observability
 from ..models.llama import StepMeta
@@ -47,7 +48,8 @@ from .kv_cache import (KVCache, KVPageIO, KVTransferPrograms,
                        default_state_slots, derive_num_pages,
                        kv_cache_bytes_per_token, kv_cache_dtype,
                        kv_row_padding_share, state_bytes_per_seq)
-from .mixed_batch import mixed_row_bucket, padding_mixed_batch
+from .mixed_batch import (mixed_row_bucket, mixed_steps_of_prompt,
+                          padding_mixed_batch)
 from .sampling_params import LOGIT_BIAS_CAP, SamplingParams
 from .scheduler import CannotChain, ScheduledBatch, Scheduler, _bucket
 from .sequence import FinishReason, Sequence, SequenceStatus
@@ -509,6 +511,13 @@ class LLMEngine:
             info.update(experts_held=self.model_config.experts_held,
                         experts_first=self.model_config.experts_first,
                         experts_published=self.model_config.num_experts)
+        if self.model_config.index_topk:
+            # A third kind of row: index keys, in the layers that choose.
+            m = self.model_config
+            info.update(
+                index_topk=m.index_topk, indexer_layers=len(m.index_layers),
+                index_cache_bytes=int(self.kv_cache.idx.size
+                                      * self.kv_cache.idx.dtype.itemsize))
         if self.model_config.hc_mult > 1:
             # The residual is this many streams, mixed around every sublayer.
             info["residual_streams"] = self.model_config.hc_mult
@@ -792,6 +801,13 @@ class LLMEngine:
         cfg = self.model_config
         nh, R, i32 = cfg.num_heads, cfg.kv_row_padded, jnp.int32
         scale = cfg.attn_scale
+        if cfg.index_topk:
+            # A sparse-attention model attends over chosen rows in XLA
+            # (ops/dsa.py): of the latent kernels it runs the materialised
+            # prefill of packed prompts and the page writes, its index-key
+            # pool's among them.
+            self._probe_page_writes(probe, arr, pool, B, T)
+            return
         probe("latent_paged_decode",
               lambda q, kp, tb, ctx, cur, lyr: latent_paged_decode(
                   q, kp, tb, ctx, cur, scale, layer=lyr),
@@ -809,11 +825,34 @@ class LLMEngine:
               arr((T, nh, R)), arr((T, 1, R)), arr((T,), i32),
               arr((T,), i32), pool, arr((pps,), i32), arr((), i32),
               arr((), i32))
-        deep_pool = arr((cfg.num_kv_layers, 2, pool.shape[2], R), pool.dtype)
-        for n in (B, T):
-            probe(f"kv_write[T={n}]",
-                  lambda p, rows, slots: kv_write(p, None, rows, None, slots),
-                  deep_pool, arr((cfg.num_kv_layers, n, R)), arr((n,), i32))
+        self._probe_page_writes(probe, arr, pool, B, T)
+
+    def _probe_page_writes(self, probe, arr, pool, B, T) -> None:
+        """A latent model's materialised prefill and its one-pool page
+        writes at the pools' real depths: the latent pool's, and a
+        sparse-attention model's index-key pool's."""
+        from ..ops.pallas.flash_prefill import flash_ragged_prefill
+        from ..ops.pallas.kv_write import kv_write
+
+        cfg = self.model_config
+        nh, i32 = cfg.num_heads, jnp.int32
+        if cfg.index_topk:
+            probe("flash_prefill",
+                  lambda q, k, v, seg, pos: flash_ragged_prefill(
+                      q, k, v, seg, pos, cfg.attn_scale),
+                  arr((T, nh, cfg.head_dim)), arr((T, nh, cfg.head_dim)),
+                  arr((T, nh, cfg.v_head_dim)), arr((T,), i32),
+                  arr((T,), i32))
+        for name, L, kd in (
+                ("kv_write", cfg.num_kv_layers, cfg.kv_row_padded),
+                ("index_kv_write", len(cfg.index_layers),
+                 cfg.index_head_dim)):
+            for n in (B, T) if L else ():
+                probe(f"{name}[T={n}]",
+                      lambda p, rows, slots: kv_write(p, None, rows, None,
+                                                      slots),
+                      arr((L, 2, pool.shape[2], kd), pool.dtype),
+                      arr((L, n, kd)), arr((n,), i32))
 
     @property
     def _grouped_experts(self) -> bool:
@@ -2170,15 +2209,19 @@ class LLMEngine:
         logger.info("the window at %d rows met before the first request: "
                     "%.1f s", len(batch.tokens), time.monotonic() - t0)
 
-    def warm_short_mixed(self) -> None:
-        """Dispatch the mixed step of the smallest prefill bucket beside the
-        largest row bucket over padding alone, and wait for it: the program
-        a server with every seat taken runs when a prompt of a few tokens
-        arrives. Its first use stands every open stream still for 2-3 s
-        with a warm compile cache and ~30 s without (PERF.md section 6,
-        PR 37: the benchmark's probe beside the load met it in four runs of
-        six, and the requests alive then carried the stall in their
-        ``tpot_p90_ms``). As ``warm_full_window``: the scrap page and the
+    def warm_mixed_steps(self) -> None:
+        """Dispatch, over padding alone, the mixed steps that prompts of
+        ``SchedulerConfig.warm_prompt_lens`` tokens ride beside full seats,
+        and wait for them. A chunk's step program is one a (chunk rung,
+        width of its history table): a prompt of a few tokens meets the
+        smallest rung at width one (the default list: its first use stands
+        every open stream still for 2-3 s with a warm compile cache and
+        ~30 s without: PERF.md section 6, PR 37), a prompt of several
+        chunks one program a width its context grows through (most of a
+        minute each for a sparse-attention model, inside requests that
+        outlast a benchmark's pre-roll: PERF.md section 6, PR 46). The list
+        is the operator's: every program is a compile at a cold start and a
+        load at a warm one. As ``warm_full_window``: the scrap page and the
         scrap slot, no sequence, counter or random key of the engine. No
         mixed step (pp, sp, ``mixed_batch_enabled`` off) or a speculative
         one in its place: nothing to meet."""
@@ -2187,21 +2230,26 @@ class LLMEngine:
                 or self.scheduler.spec_enabled):
             return
         t0 = time.monotonic()
-        Tp = min(sc.prefill_buckets)
-        batch = padding_mixed_batch(
-            self.scheduler, Tp,
-            mixed_row_bucket(sc.max_num_seqs, Tp, sc.decode_buckets))
+        steps = list(dict.fromkeys(
+            step for n in sc.warm_prompt_lens
+            for step in mixed_steps_of_prompt(self.scheduler, n)))
         prev = self._no_pred
-        for _ in range(2):
-            # the second behind the first, as in warm_full_window
-            rec = self._record(batch, None, t0, [])
-            self._dispatch_prefill(rec, prev, _pack_float_b(batch),
-                                   jax.random.key(0))
-            prev = rec["last"]
-        jax.block_until_ready(prev)
-        logger.info("the mixed step of %d tokens beside %d rows met before "
-                    "the first request: %.1f s", Tp,
-                    len(batch.context_lens), time.monotonic() - t0)
+        for Tp, width in steps:
+            batch = padding_mixed_batch(
+                self.scheduler, Tp,
+                mixed_row_bucket(sc.max_num_seqs, Tp, sc.decode_buckets),
+                width)
+            for _ in range(2):
+                # the second behind the first, as in warm_full_window
+                rec = self._record(batch, None, t0, [])
+                self._dispatch_prefill(rec, prev, _pack_float_b(batch),
+                                       jax.random.key(0))
+                prev = rec["last"]
+            jax.block_until_ready(prev)
+        logger.info("%d mixed steps (chunk rung, history width) %s beside "
+                    "%d rows met before the first request: %.1f s",
+                    len(steps), steps, sc.max_num_seqs,
+                    time.monotonic() - t0)
 
     def _dispatch_prefill(self, rec: dict, prev, float_b, step_key) -> None:
         """Dispatch a prefill, a chunk with history or a mixed step; what
@@ -2222,6 +2270,8 @@ class LLMEngine:
                 page_tables = jnp.asarray(batch.page_tables)
                 out_tokens = self._penalty_out_tokens(batch)
             if mixed:
+                self._count_chosen(
+                    batch.context_lens[batch.context_lens > 0])
                 chunk_pt = jnp.asarray(batch.chunk_page_table)
                 context_lens = jnp.asarray(batch.context_lens)
         if mixed:
@@ -2602,9 +2652,23 @@ class LLMEngine:
                     self.params, self.kv_cache, prev, int_b, float_b,
                     step_key, counts, out_tokens, jnp.asarray(rebuild),
                     bias_ids, bias_vals)
+        self._count_chosen(batch.positions[:len(batch.seqs)] + 1,
+                           self.config.scheduler.decode_window)
         rec.update(t_dispatched=time.monotonic(), toks=dev_out, lps=dev_lp,
                    tids=dev_tid, tlps=dev_tlp, last=last, load=(),
                    zombies=set(), counts=counts, greedy=greedy)
+
+    def _count_chosen(self, contexts: np.ndarray, steps: int = 1) -> None:
+        """A sparse-attention model's decode rows, from the lengths the
+        host holds: the tokens each row could see over ``steps`` steps of
+        growing context, and the ``index_topk`` of them at most it attended
+        to."""
+        topk = self.model_config.index_topk
+        if topk and len(contexts):
+            ctx = (np.asarray(contexts, np.int64)[:, None]
+                   + np.arange(steps)[None, :])
+            self.obs.dsa_visible_tokens += int(ctx.sum())
+            self.obs.dsa_chosen_tokens += int(np.minimum(ctx, topk).sum())
 
     def _process_window(self, rec: dict, next_tokens: np.ndarray,
                         logprobs: np.ndarray,
@@ -2879,6 +2943,19 @@ def step_workspace_bytes(config: EngineConfig) -> int:
                          m.num_shared_experts * m.expert_width) * (4 + 4 + it))
         # q, the absorbed q and the latent output at the padded row width.
         attn = T * m.num_heads * (m.head_dim + 2 * kd) * (4 + it)
+        if m.index_topk:
+            # A "full" layer (ops/dsa.py): a block of queries' index scores
+            # a head and of attention scores a head over the widest chunk's
+            # candidates, float32, twice (the scores and what is made of
+            # them); every query's scores, the mask from them and the
+            # mask carried; the rows' gathered index keys and chosen rows.
+            cand = sc.prefill_buckets[-1] + next_power_of_2(
+                config.effective_max_len)
+            attn += (2 * 64 * cand * 4 * (m.index_n_heads + m.num_heads)
+                     + T * cand * (4 + 4 + 2)
+                     + B * (config.effective_max_len
+                            * (m.index_head_dim * it + 4 * m.index_n_heads)
+                            + m.index_topk * kd * (it + 4)))
     else:
         mlp = max(m.num_experts, 1) * T * m.intermediate_size * (4 + 4 + it)
         attn = T * (m.num_heads * m.head_dim + kd) * 2 * (4 + it)

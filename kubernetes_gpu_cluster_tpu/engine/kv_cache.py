@@ -80,6 +80,11 @@ class KVCache(NamedTuple):
     v: Optional[jax.Array]
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    # A sparse-attention model's index keys (``ops/dsa.py``): [Li, P,
+    # page_size, index_head_dim], ONE key a token in each layer that holds
+    # an indexer (``ModelConfig.index_layers``: Li of the L layers), on the
+    # latent pool's page table: a page id names the same tokens in both.
+    idx: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -118,7 +123,12 @@ def allocate_kv_cache(
         slots = dict(
             ssm=jnp.zeros(lead + model.state_shape, STATE_DTYPE),
             conv=jnp.zeros(lead + model.state_conv_shape, model.jnp_dtype))
-    return KVCache(k=mk(), v=mk() if model.kv_pools == 2 else None, **slots)
+    idx = None
+    if model.index_topk:    # one key a token in each layer that chooses
+        idx = jnp.zeros((len(model.index_layers), num_pages, cache.page_size,
+                         model.index_head_dim), dtype)
+    return KVCache(k=mk(), v=mk() if model.kv_pools == 2 else None, idx=idx,
+                   **slots)
 
 
 # The recurrent state is held and updated in float32: rounded to bfloat16 at
@@ -147,9 +157,15 @@ def state_bytes_per_seq(model: ModelConfig) -> int:
 def kv_cache_bytes_per_token(model: ModelConfig, cache: CacheConfig) -> int:
     """Bytes one cached token really holds over the layers that hold pages,
     padding of a latent row included (``kv_row_padding_share`` says how much
-    of it)."""
-    return (model.kv_pools * model.num_kv_layers * model.kv_row_padded
-            * kv_cache_dtype(model, cache).itemsize)
+    of it), and a sparse-attention model's index keys beside them."""
+    return ((model.kv_pools * model.num_kv_layers * model.kv_row_padded
+             + index_cache_row(model)) * kv_cache_dtype(model, cache).itemsize)
+
+
+def index_cache_row(model: ModelConfig) -> int:
+    """Elements one cached token holds in the index-key pool, all its
+    layers: 0 for a model without an indexer."""
+    return len(model.index_layers) * model.index_head_dim
 
 
 def kv_row_padding_share(model: ModelConfig) -> float:

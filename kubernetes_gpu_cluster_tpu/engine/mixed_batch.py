@@ -127,6 +127,26 @@ def mixed_row_bucket(rows: int, chunk_bucket: int,
     return _bucket(max(rows, floor), decode_buckets)
 
 
+def mixed_steps_of_prompt(sched: "Scheduler", n: int) -> list:
+    """The (chunk rung, history-table width) of each mixed step a prompt of
+    ``n`` tokens passes through beside full seats, as ``build_mixed_batch``
+    plans its chunks: a step program each (``LLMEngine.warm_mixed_steps``)."""
+    from .scheduler import _bucket
+
+    sc, steps, done = sched.config.scheduler, [], 0
+    n = min(n, sched.config.effective_max_len - 1)
+    while done < n:
+        chunk = plan_chunk_tokens(n - done, sc.max_num_seqs - 1,
+                                  sc.decode_priority_token_budget,
+                                  sc.max_prefill_tokens)
+        if chunk <= 0:
+            break
+        done += chunk
+        steps.append((_bucket(chunk, sc.mixed_chunk_buckets),
+                      sched.chunk_table_width(cdiv(done, sched.page_size))))
+    return steps
+
+
 def build_mixed_batch(sched: "Scheduler", behind: bool = False
                       ) -> Optional["ScheduledBatch"]:
     """Assemble one mixed step from the scheduler's live state, or return
@@ -283,13 +303,14 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
         **sched._sampling_arrays(seqs, R_pad))
 
 
-def padding_mixed_batch(sched: "Scheduler", Tp: int,
-                        R_pad: int) -> "ScheduledBatch":
+def padding_mixed_batch(sched: "Scheduler", Tp: int, R_pad: int,
+                        hist_width: int = 1) -> "ScheduledBatch":
     """A mixed step's batch at the chunk bucket ``Tp`` beside ``R_pad`` rows
-    with no sequence at all (what ``LLMEngine.warm_short_mixed``
+    with no sequence at all (what ``LLMEngine.warm_mixed_steps``
     dispatches): a chunk of one token with no history whose page table is
-    one page wide, as a prompt inside a page has it, written like every
-    padding token and row to the scrap page and the scrap slot."""
+    ``hist_width`` pages wide (one, as a prompt inside a page has it),
+    written like every padding token and row to the scrap page and the
+    scrap slot."""
     from .scheduler import ScheduledBatch
 
     T_pad = Tp + R_pad
@@ -304,7 +325,7 @@ def padding_mixed_batch(sched: "Scheduler", Tp: int,
         page_tables=np.zeros((R_pad, pages_bucket), np.int32),
         context_lens=np.zeros(R_pad, np.int32),
         tok_src=np.full(R_pad, -1, np.int32),
-        chunk_page_table=np.zeros((1, 1), np.int32), hist_len=0,
+        chunk_page_table=np.zeros((1, hist_width), np.int32), hist_len=0,
         seg_slots=sched._state_slots([], R_pad),
         row_slots=sched._state_slots([], R_pad),
         **sched._sampling_arrays([], R_pad))

@@ -960,6 +960,12 @@ class Scheduler:
         held = [s.state_slot for s in seqs]
         return np.asarray(held + [0] * (n - len(held)), np.int32)
 
+    def chunk_table_width(self, pages: int) -> int:
+        """The width of a chunk's history table over ``pages`` pages: the
+        next power of two, at most a full-length sequence's pages."""
+        return min(next_power_of_2(max(pages, 1)),
+                   cdiv(self.config.effective_max_len, self.page_size))
+
     def _chunk_page_table(self, seq: Sequence) -> np.ndarray:
         """[1, width] page table for a chunk's history attention. Width
         buckets to the ACTUAL context (few power-of-2 compile shapes), not
@@ -967,8 +973,7 @@ class Scheduler:
         scores, so a max-len-wide table would make every small chunk pay
         max-model-len memory/FLOPs. Single source for the solo-chunk and
         mixed paths so their compile-shape families cannot diverge."""
-        max_pages = cdiv(self.config.effective_max_len, self.page_size)
-        width = min(next_power_of_2(max(len(seq.pages), 1)), max_pages)
+        width = self.chunk_table_width(len(seq.pages))
         table = np.zeros((1, width), np.int32)
         table[0, :len(seq.pages)] = seq.pages
         return table

@@ -102,6 +102,53 @@ def config_from_hf(path: str, name: Optional[str] = None) -> ModelConfig:
     )
 
 
+# glm_moe_dsa's sparse-attention keys the decoder implements (ops/dsa.py).
+# ``index_share_for_mtp_iteration`` speaks of the multi-token-prediction
+# module, which is not served; ``indexer_rope_interleave`` of the
+# checkpoint's column order, which a loader undoes (half-split here).
+_INDEX_KEYS = {"index_topk", "index_n_heads", "index_head_dim",
+               "indexer_types", "index_topk_freq", "index_skip_topk_offset",
+               "index_topk_pattern", "index_share_for_mtp_iteration",
+               "indexer_rope_interleave"}
+
+
+def _index_fields(hf: dict, name: str) -> dict:
+    """The ``index_*`` keys of a deepseek_v3-class config as ModelConfig
+    fields; {} for a model without an indexer. A key the decoder does not
+    implement refuses the load by its name: served without it the model
+    would attend densely under a sparse model's name."""
+    keys = sorted(k for k in hf if k.startswith(("index_", "indexer_")))
+    if not keys:
+        return {}
+    unknown = [k for k in keys if k not in _INDEX_KEYS]
+    if unknown or hf.get("index_topk_pattern") is not None:
+        raise ValueError(
+            f"{name}: config.json "
+            f"{', '.join(unknown) or 'index_topk_pattern'} is not "
+            "implemented by the latent-attention decoder's indexer")
+    topk, types = hf.get("index_topk"), hf.get("indexer_types")
+    if not topk:
+        raise ValueError(f"{name}: config.json {', '.join(keys)} without "
+                         "index_topk: nothing says how many tokens a query "
+                         "keeps")
+    if not types or not hf.get("q_lora_rank"):
+        raise ValueError(
+            f"{name}: config.json index_topk {topk} without "
+            + ("indexer_types" if not types else "q_lora_rank")
+            + ": the decoder does not guess which layers choose and which "
+            "share, and the indexer's query reads the query latent")
+    freq, off = hf.get("index_topk_freq"), hf.get("index_skip_topk_offset")
+    if freq is not None and off is not None and list(types) != [
+            "full" if i < off or (i - off) % freq == freq - 1 else "shared"
+            for i in range(len(types))]:
+        raise ValueError(
+            f"{name}: config.json indexer_types is not what "
+            f"index_topk_freq {freq} and index_skip_topk_offset {off} give")
+    return dict(index_topk=int(topk), index_n_heads=int(hf["index_n_heads"]),
+                index_head_dim=int(hf["index_head_dim"]),
+                indexer_types=tuple(types))
+
+
 _HC_KEYS = {"hc_mult", "hc_sinkhorn_iters", "hc_eps", "mhc_h_res_clamp_min",
             "mhc_h_res_clamp_max"}
 
@@ -118,6 +165,23 @@ def _deepseek_config_from_hf(hf: dict, name: str) -> ModelConfig:
     from ..config.model_config import HF_SHAPE_KEYS
     from ..ops.rope import scaled_inv_freq
     rope_scaling = None
+    if hf.get("rope_parameters"):   # glm_moe_dsa: theta and type in a group
+        rp = hf["rope_parameters"]
+        if rp.get("rope_type", "default") != "default":
+            raise ValueError(
+                f"{name}: config.json rope_parameters rope_type="
+                f"{rp['rope_type']!r} is not implemented by the "
+                "latent-attention decoder")
+        hf = {**hf, "rope_theta": rp.get("rope_theta",
+                                         hf.get("rope_theta", 10000.0))}
+    nd = hf.get("first_k_dense_replace", 0)
+    mlp_types = hf.get("mlp_layer_types")
+    if mlp_types is not None and list(mlp_types) != (
+            ["dense"] * nd + ["sparse"] * (len(mlp_types) - nd)):
+        raise ValueError(
+            f"{name}: config.json mlp_layer_types is not "
+            f"first_k_dense_replace={nd} dense layers and expert layers "
+            "behind them")
     if hf.get("rope_scaling"):
         raw = {k: v for k, v in hf["rope_scaling"].items()
                if isinstance(v, (str, int, float, bool))}
@@ -151,6 +215,7 @@ def _deepseek_config_from_hf(hf: dict, name: str) -> ModelConfig:
               if hf.get(theirs) is not None}
     fields["max_model_len"] = min(int(fields.get("max_model_len", 4096)), 8192)
     fields.setdefault("num_kv_heads", fields["num_heads"])
+    fields.update(_index_fields(hf, name))
     if hf.get("hc_mult", 1) > 1:
         fields.update(
             hc_mult=hf["hc_mult"],

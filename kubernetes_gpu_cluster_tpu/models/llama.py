@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from ..config import ModelConfig
 from ..engine.kv_cache import KVCache
 from ..ops import quant as quant_ops
+from ..ops import dsa
 from ..ops import hyper_conn
 from ..ops import kda as kda_ops
 from ..ops import ssm as ssm_ops
@@ -264,6 +265,21 @@ def _init_mla_mixer(cfg: ModelConfig, L: int, keys, w, dtype) -> Params:
     }
 
 
+def _init_indexers(cfg: ModelConfig, keys, w, dtype) -> Params:
+    """The ``indexer`` stack: one entry a "full" layer
+    (``cfg.index_layers``), in the layers' order, whichever weight stack the
+    layer itself lies in. ``wq_b`` [q_lora_rank, H * D] reads the query
+    latent, ``wk`` [d, D] and ``w_w`` [d, H] the layer's normed input; the
+    key's LayerNorm has a bias (``ops/dsa.py``)."""
+    n, d, qr = len(cfg.index_layers), cfg.hidden_size, cfg.q_lora_rank
+    H, D = cfg.index_n_heads, cfg.index_head_dim
+    return {"wq_b": w(next(keys), (n, qr, H * D), qr),
+            "wk": w(next(keys), (n, d, D), d),
+            "k_norm": jnp.ones((n, D), dtype),
+            "k_norm_b": jnp.zeros((n, D), dtype),
+            "w_w": w(next(keys), (n, d, H), d)}
+
+
 def _init_experts(cfg: ModelConfig, L: int, keys, w, dtype) -> Params:
     """``L`` stacked DeepSeek-V3-class expert MLPs: the router over ALL
     ``num_experts`` (float32) with its choice bias, drawn small and NOT zero
@@ -400,6 +416,8 @@ def _init_params_deepseek(cfg: ModelConfig, key: jax.Array, dtype, w) -> Params:
                if cfg.hc_mult > 1 else {})}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (d, cfg.vocab_size), d)
+    if cfg.index_topk:
+        params["indexer"] = _init_indexers(cfg, keys, w, dtype)
     return params
 
 
@@ -859,16 +877,19 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig,
 
 
 def _mla_qkv(lp: Params, cfg: ModelConfig, x: jax.Array,
-             positions: jax.Array, use_pallas: Optional[bool] = None):
+             positions: jax.Array, use_pallas: Optional[bool] = None,
+             with_latent: bool = False):
     """Latent attention's projections. x: [T, d] -> q [T, nh, nope + rope]
     (RoPE on its last ``rope`` dims; through a latent of its own where the
     model has ``q_lora_rank``) and the cache row [T, kv_row_padded] =
     [c (its own RMSNorm) | k_pe (RoPE, one head shared by all) | zeros].
     RoPE is half-split over the rope dims: the loader de-interleaves those
     columns of a checkpoint (engine/weights.py), which rotates the same
-    pairs the published code does."""
+    pairs the published code does. ``with_latent``: the query latent c_q
+    [T, q_lora_rank] comes back third (an indexer reads it)."""
     T = x.shape[0]
     r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    c_q = None
     if "w_qa" in lp:    # the query through its latent and the latent's norm
         with jax.named_scope("kgct.mla.q_lora"):
             c_q = rms_norm(_dot(x, lp, "w_qa", use_pallas).astype(x.dtype),
@@ -888,7 +909,8 @@ def _mla_qkv(lp: Params, cfg: ModelConfig, x: jax.Array,
             [q[..., :-rope], apply_rope(q[..., -rope:], cos, sin)], axis=-1)
         k_pe = apply_rope(k_pe[:, None], cos, sin)[:, 0]
     pad = jnp.zeros((T, cfg.kv_row_padded - cfg.kv_row_dim), x.dtype)
-    return q, jnp.concatenate([c, k_pe, pad], axis=-1)
+    row = jnp.concatenate([c, k_pe, pad], axis=-1)
+    return (q, row, c_q) if with_latent else (q, row)
 
 
 def mla_materialise(lp: Params, cfg: ModelConfig, row: jax.Array):
@@ -978,6 +1000,125 @@ def mla_chunk_attention(lp: Params, cfg: ModelConfig, q: jax.Array,
                 hist_len, scale, layer=layer_idx))
 
     return jax.lax.cond(hist_len == 0, fresh, with_history, None)
+
+
+class _SparseAttention:
+    """A sparse-attention model's choice over one step's token axis
+    (``ops/dsa.py``), wired to the step's pools: what ``forward`` hands
+    ``_layer_scan`` (``choose``, ``start``) and its two attention parts
+    (``segment``, ``rows``). The choice is (the segment part's mask [n_seg,
+    m] over [the chunk's history | the part's own tokens], or None where it
+    has no more candidates than ``index_topk`` and keeps what it may attend
+    to; the rows' chosen tokens [R, k], each the row of a latent-pool LAYER
+    it lies in or -1 for the row's own token, and which of them exist): a
+    row's candidates are its pages' positions and, last, its own token.
+    (The pool rows are looked up where the choice is made and not in each
+    layer that attends over it: the lookup in the page table is a gather of
+    its own, 0.26 ms for 16 rows on the v5e.)"""
+
+    def __init__(self, cfg: ModelConfig, meta: StepMeta, kv: KVCache,
+                 n_seg: int, n_rows: int):
+        self.cfg, self.meta, self.kv = cfg, meta, kv
+        self.n_seg, self.n_rows = n_seg, n_rows
+
+    @staticmethod
+    def _pool_rows(pool, layer, pages):
+        """Rows of one layer of a stacked pool, ``pages`` [...] whole: a
+        gather of the pool as it lies, no slice of the layer."""
+        flat = pool.reshape((-1,) + pool.shape[2:])
+        got = flat[layer * pool.shape[1] + pages]
+        return got.reshape(pages.shape[:-1] + (-1, pool.shape[-1]))
+
+    def _allowed(self):
+        """[n_seg, m] bool: which of its candidates a token of the segment
+        part may attend to."""
+        meta, n = self.meta, self.n_seg
+        seg, pos = meta.seg_ids[:n], meta.positions[:n]
+        own = ((seg[:, None] == seg[None, :]) & (seg[:, None] >= 0)
+               & (pos[:, None] >= pos[None, :]))
+        if meta.chunk_page_table is None:
+            return own
+        H = meta.chunk_page_table.shape[0] * self.kv.page_size
+        hist = (jnp.arange(H)[None, :] < meta.hist_len) & (seg[:, None] >= 0)
+        return jnp.concatenate([hist, own], axis=1)
+
+    def choose(self, q_i, w_i, k_i, j):
+        """The choice of "full" layer number j, from its indexer's
+        projections over the step's tokens."""
+        meta, kv, n = self.meta, self.kv, self.n_seg
+        topk = self.cfg.index_topk
+        seg_mask = rows = None
+        if n:
+            allowed = self._allowed()
+            if allowed.shape[1] > topk:
+                with jax.named_scope("kgct.dsa.index"):
+                    keys = k_i[:n]
+                    if meta.chunk_page_table is not None:
+                        keys = jnp.concatenate([self._pool_rows(
+                            kv.idx, j, meta.chunk_page_table), keys])
+                    scores = dsa.index_scores(q_i[:n], w_i[:n], keys)
+                with jax.named_scope("kgct.dsa.select"):
+                    seg_mask = dsa.topk_mask(scores, allowed, topk)
+        if self.n_rows:
+            with jax.named_scope("kgct.dsa.index"):
+                keys = self._pool_rows(kv.idx, j, meta.page_tables)
+                scores = jnp.concatenate(
+                    [dsa.row_scores(q_i[n:], w_i[n:], keys),
+                     dsa.row_scores(q_i[n:], w_i[n:], k_i[n:, None])],
+                    axis=1)
+            with jax.named_scope("kgct.dsa.select"):
+                allowed = jnp.concatenate(
+                    [jnp.arange(keys.shape[1])[None, :]
+                     < meta.context_lens[:, None] - 1,
+                     jnp.ones((self.n_rows, 1), bool)], axis=1)
+                idx, exists = dsa.topk_indices(scores, allowed, topk)
+                S, ps = keys.shape[1], kv.page_size
+                at = jnp.minimum(idx, S - 1)
+                page = jnp.take_along_axis(meta.page_tables, at // ps, axis=1)
+                rows = (jnp.where(idx == S, -1, page * ps + at % ps), exists)
+        return seg_mask, rows
+
+    def start(self, dtype):
+        """A choice of the step's structure to start the layers' carry from
+        (the first layer is "full": it is never read)."""
+        cfg, T = self.cfg, self.n_seg + self.n_rows
+        shapes = jax.eval_shape(
+            self.choose,
+            jax.ShapeDtypeStruct((T, cfg.index_n_heads, cfg.index_head_dim),
+                                 dtype),
+            jax.ShapeDtypeStruct((T, cfg.index_n_heads), jnp.float32),
+            jax.ShapeDtypeStruct((T, cfg.index_head_dim), dtype),
+            jax.ShapeDtypeStruct((), jnp.int32))
+        return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), shapes)
+
+    def segment(self, lp, q, row, choice, layer_idx):
+        """The segment part over what its mask keeps of [the chunk's
+        history | the part's own rows], materialised."""
+        meta, rows = self.meta, row
+        if meta.chunk_page_table is not None:
+            rows = jnp.concatenate([self._pool_rows(
+                self.kv.k, layer_idx, meta.chunk_page_table), rows])
+        mask = choice[0] if choice[0] is not None else self._allowed()
+        with jax.named_scope("kgct.dsa.attend"):
+            return dsa.attend_masked(
+                q, *mla_materialise(lp, self.cfg, rows), mask,
+                self.cfg.attn_scale)
+
+    def rows(self, lp, q, row, choice, layer_idx):
+        """A running row over its chosen positions: their latent rows
+        gathered from its pages, its own row where it chose itself."""
+        cfg, pool = self.cfg, self.kv.k
+        at, exists = choice[1]
+
+        def attend(qa, _):
+            with jax.named_scope("kgct.dsa.attend"):
+                flat = pool.reshape((-1, pool.shape[-1]))
+                got = flat[layer_idx * pool.shape[1] * pool.shape[2]
+                           + jnp.maximum(at, 0)]
+                got = jnp.where((at < 0)[..., None], row[:, None, :], got)
+                return dsa.attend_gathered(qa, got, exists, cfg.attn_scale,
+                                           cfg.kv_lora_rank)
+        return mla_absorbed(lp, cfg, q, row, attend)
 
 
 class _Stack(NamedTuple):
@@ -1216,7 +1357,8 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                 ep_axis: Optional[str] = None,
                 moe_load: Optional[list] = None,
                 valid: Optional[jax.Array] = None,
-                state_fn=None, ssm: Optional[jax.Array] = None):
+                state_fn=None, ssm: Optional[jax.Array] = None,
+                choose_fn=None, choice=None):
     """Run the stack section by section (``cfg.layer_sections``), each a
     scan of ONE PERIOD of typed layers over the stacked weights: the
     leading dense layers, then the shortest run of layer types whose whole
@@ -1279,6 +1421,16 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     the updated state, so it cannot wait for the end of the scan as the
     page write does. The conv rows do wait: they come back stacked
     [state layers, S + R, ...].
+
+    A sparse-attention model (``cfg.index_topk``): ``choose_fn(q_i, w_i,
+    k_i, j) -> choice`` turns the indexer's projections of "full" layer
+    number ``j`` (``ops.dsa.project``) into what ``attn_fn`` is handed in
+    v's place: the rows each query attends to. ``choice`` (any value of
+    that structure to start from) is carried from layer to layer beside the
+    residual: a "full" layer replaces it, a "shared" layer passes it on,
+    both in one body (the layer's type is scanned, the branch is the
+    device's). v_all is then the index keys [layers, T, index_head_dim],
+    zeros for a "shared" layer.
 
     ``moe_load``: a list the caller owns; where the stack has expert layers
     the step's real routed pairs of each expert of each layer, [n_layers, E]
@@ -1353,10 +1505,40 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
         h, load = mlp_of(lp, h, stack.experts, layer_idx - stack.first)
         return (h, ssm), (conv_rows, load)
 
-    def attn_layer(h, lp, stack, layer_idx):
+    if cfg.index_topk:
+        # layer -> which entry of the ``indexer`` stack (and layer of the
+        # index-key pool) it chooses with; -1: it shares.
+        index_of = jnp.asarray(
+            [cfg.index_layers.index(i) if t == "full" else -1
+             for i, t in enumerate(cfg.indexer_types)], jnp.int32)
+
+    def chosen_rows(lp, x, c_q, choice, layer_idx):
+        """The layer's choice and its new index keys: a "full" layer's own,
+        a "shared" layer's the one it was handed (and no keys)."""
+        j = index_of[layer_idx]
+
+        def full(_):
+            with jax.named_scope("kgct.dsa.index"):
+                q_i, w_i, k_i = dsa.project(
+                    at(params["indexer"], j), cfg, c_q, x, positions)
+            return choose_fn(q_i, w_i, k_i, j), k_i
+
+        def shared(_):
+            return choice, jnp.zeros((x.shape[0], cfg.index_head_dim),
+                                     x.dtype)
+        return jax.lax.cond(j >= 0, full, shared, None)
+
+    def attn_layer(h, lp, stack, layer_idx, choice):
         y, coef = enter(lp, h, "attn")
         x = _norm(cfg, y, lp, "input_norm")
-        if cfg.is_mla:
+        if cfg.index_topk:
+            with jax.named_scope("kgct.mla"):
+                q, row, c_q = _mla_qkv(lp, cfg, x, positions, int4,
+                                       with_latent=True)
+            choice, k_i = chosen_rows(lp, x, c_q, choice, layer_idx)
+            with jax.named_scope("kgct.mla"):
+                attn_out = attn_fn(lp, q, row, choice, layer_idx)
+        elif cfg.is_mla:
             with jax.named_scope("kgct.mla"):
                 q, row = _mla_qkv(lp, cfg, x, positions, int4)
                 attn_out = attn_fn(lp, q, row, None, layer_idx)
@@ -1371,10 +1553,12 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
             o = o + lp["bo"]
         h = leave(h, o, coef)
         h, load = mlp_of(lp, h, stack.experts, layer_idx - stack.first)
+        if cfg.index_topk:
+            return h, choice, ((row, k_i), load)
         if cfg.is_mla:
-            return h, ((row,), load)
-        return h, ((k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1)),
-                   load)
+            return h, choice, ((row,), load)
+        return h, choice, ((k.reshape(k.shape[0], -1),
+                            v.reshape(v.shape[0], -1)), load)
 
     def split(stack):
         """A stack's expert tensors stay OUT of what the bodies index or
@@ -1408,7 +1592,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
             attn.layers)[0].shape[0]
 
         def period_body(carry, xs):
-            (h, ssm), (attn_p, attn_idx, ssm_idx) = carry, xs
+            (h, ssm, choice), (attn_p, attn_idx, ssm_idx) = carry, xs
             rows, loads, conv_rows = [], [], []
             for kind, start, count in runs:
                 if kind == "state":
@@ -1421,16 +1605,16 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
                     continue
                 for j in range(start, start + count):
                     idx = attn_idx + j if j else attn_idx
-                    h, (row, load) = attn_layer(
+                    h, choice, (row, load) = attn_layer(
                         h, attn_p if as_xs else at(attn.layers,
                                                    idx - attn.first),
-                        attn, idx)
+                        attn, idx, choice)
                     rows.append(row)
                     loads.extend(a[None] for a in load)
             rows = tuple(jnp.stack(a) for a in zip(*rows))  # [n_attn, T, ..]
             loads = jnp.concatenate(loads) if loads else ()
             conv_rows = jnp.concatenate(conv_rows) if conv_rows else ()
-            return (h, ssm), (rows, loads, conv_rows)
+            return (h, ssm, choice), (rows, loads, conv_rows)
 
         idx = lambda stack, n: (jnp.arange(
             stack.at, stack.at + repeats * n, n, dtype=jnp.int32)
@@ -1446,7 +1630,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
     # The stack, section by section (``cfg.layer_sections``): the leading
     # dense layers, the periods, the tail; pool layer indices count on per
     # kind through all of them, a stack's own indices from its first layer.
-    carry, rows, loads, conv_rows = (h, ssm), [], [], []
+    carry, rows, loads, conv_rows = (h, ssm, choice), [], [], []
     pool_at = {"attention": 0, "state": 0}
     stack_at: dict = {}
     held = layer_stacks(cfg)
@@ -1477,7 +1661,7 @@ def _layer_scan(params: Params, cfg: ModelConfig, h: jax.Array,
             loads.append(load)
         if conv is not None:
             conv_rows.append(conv)
-    h, ssm = carry
+    h, ssm, _ = carry
     if loads:    # [expert layers, E], in the sections' order
         moe_load.append(loads[0] if len(loads) == 1
                         else jnp.concatenate(loads))
@@ -1551,7 +1735,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             f"{tokens.shape[0]} tokens are not {n_seg} segment tokens + "
             f"{n_rows} row tokens")
 
+    sparse = (_SparseAttention(cfg, meta, kv, n_seg, n_rows)
+              if cfg.index_topk else None)
+
     def segment_attn(lp, q, k, v, seg_ids, positions, layer_idx):
+        if sparse and (meta.chunk_page_table is not None
+                       or q.shape[0] > cfg.index_topk):
+            # A chunk (a first one too: over no history the mask keeps what
+            # is causal), or packed prompts long enough to choose.
+            return sparse.segment(lp, q, k, v, layer_idx)
         if meta.chunk_page_table is None:
             # Each sequence's whole prompt is in this batch: tokens attend
             # within the in-batch k/v only (a latent model's in the
@@ -1576,6 +1768,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             return kernels.verify_attention(
                 q, k, v, kv.k, kv.v, meta.page_tables, meta.context_lens,
                 scale, layer=layer_idx)
+        if sparse:
+            return sparse.rows(lp, q, k, v, layer_idx)
         if cfg.is_mla:
             # Absorbed form: each latent page is read ONCE, as key and value.
             return mla_absorbed(
@@ -1592,9 +1786,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                 layer_idx)
         if not n_seg:
             return row_attn(lp, q, k, v, layer_idx)
-        # v is None for a latent model (k is its cache row).
-        qs, ks, vs = (a if a is None else a[:n_seg] for a in (q, k, v))
-        qr, kr, vr = (a if a is None else a[n_seg:] for a in (q, k, v))
+        # v is None for a latent model (k is its cache row), or its
+        # indexer's choice, of which each part reads its own half.
+        qs, ks, qr, kr = q[:n_seg], k[:n_seg], q[n_seg:], k[n_seg:]
+        vs, vr = (v, v) if v is None or sparse else (v[:n_seg], v[n_seg:])
         return jnp.concatenate(
             [segment_attn(lp, qs, ks, vs, meta.seg_ids[:n_seg],
                           meta.positions[:n_seg], layer_idx),
@@ -1618,7 +1813,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     h, k_all, v_all, ssm, conv_rows = _layer_scan(
         params, cfg, h, meta.positions, attn_fn, kernels, tp_axis=tp_axis,
         ep_axis=ep_axis, moe_load=moe_load, valid=real, state_fn=state_fn,
-        ssm=kv.ssm)
+        ssm=kv.ssm, **(dict(choose_fn=sparse.choose,
+                            choice=sparse.start(h.dtype)) if sparse else {}))
     conv = kv.conv
     if cfg.has_state:
         # The state layers' new conv rows, every layer's at once, as the
@@ -1626,8 +1822,17 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         conv = ssm_ops.write_slots(conv, conv_rows, jnp.concatenate(
             ([meta.seg_slots] if n_seg else [])
             + ([meta.row_slots] if n_rows else [])))
+    idx_pool = kv.idx
+    if sparse:
+        # The "full" layers' index keys go where their latent rows go: the
+        # same slots of the index-key pool's own layers.
+        idx_pool, _ = kernels.write_pages(
+            kv.idx, None, v_all[jnp.asarray(cfg.index_layers)], None,
+            meta.slot_mapping)
+        v_all = None
     new_kv = KVCache(*kernels.write_pages(kv.k, kv.v, k_all, v_all,
-                                          meta.slot_mapping), ssm, conv)
+                                          meta.slot_mapping), ssm, conv,
+                     idx_pool)
     selected = h if meta.logits_indices is None else h[meta.logits_indices]
     if cfg.hc_mult > 1:     # ... and their sum is what the final norm reads
         selected = jnp.sum(selected.astype(jnp.float32).reshape(

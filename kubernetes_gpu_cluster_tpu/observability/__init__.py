@@ -238,6 +238,11 @@ class Observability:
         self.moe_pairs_held_share: Optional[float] = None
         self.moe_held: Optional[tuple] = None
         self.moe_grouped_tile_fill_share = 0.0
+        # Sparse attention (a model with an indexer): over its decode rows,
+        # the tokens a row could see and those it attended to (its
+        # ``index_topk`` at most), from the lengths the host holds.
+        self.dsa_visible_tokens = 0
+        self.dsa_chosen_tokens = 0
         # Speculative decoding: cumulative drafted vs accepted draft tokens
         # (bonus tokens excluded from both) — feeds the
         # kgct_spec_acceptance_ratio gauge and the kgct_spec_*_tokens_total
@@ -752,6 +757,19 @@ class Observability:
                 lines.append("# TYPE kgct_moe_pairs_held_share gauge")
                 lines.append("kgct_moe_pairs_held_share %.4f"
                              % self.moe_pairs_held_share)
+        if self.dsa_visible_tokens:
+            lines.append("# HELP kgct_dsa_chosen_tokens_total cached tokens "
+                         "the decode rows attended to (index_topk a row at "
+                         "most)")
+            lines.append("# TYPE kgct_dsa_chosen_tokens_total counter")
+            lines.append("kgct_dsa_chosen_tokens_total %d"
+                         % self.dsa_chosen_tokens)
+            lines.append("# HELP kgct_dsa_visible_tokens_total cached tokens "
+                         "the decode rows could have attended to (their "
+                         "contexts)")
+            lines.append("# TYPE kgct_dsa_visible_tokens_total counter")
+            lines.append("kgct_dsa_visible_tokens_total %d"
+                         % self.dsa_visible_tokens)
         lines.append("# TYPE kgct_mixed_prefill_tokens_total counter")
         lines.append("kgct_mixed_prefill_tokens_total %d"
                      % self.mixed_prefill_tokens)

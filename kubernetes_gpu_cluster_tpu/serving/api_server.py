@@ -2324,6 +2324,14 @@ def main(argv: Optional[list[str]] = None) -> None:
                    "is free once the weights are resident and one step's "
                    "workspace is set aside")
     p.add_argument("--max-num-seqs", type=int, default=64)
+    p.add_argument("--warm-prompt-lens", default="1",
+                   help="comma-separated prompt lengths in tokens whose "
+                   "mixed steps beside full seats are met (compiled, or "
+                   "loaded from the compile cache) before the server "
+                   "listens: one program a (chunk rung, history width) "
+                   "that a prompt of such a length passes through, each a "
+                   "compile at a cold start. Default: a prompt of a few "
+                   "tokens")
     p.add_argument("--swap-space-gb", "--swap-space", dest="swap_space_gb",
                    type=float, default=0.0,
                    help="host-DRAM KV swap space in GB (vLLM swap-space "
@@ -2566,12 +2574,21 @@ def main(argv: Optional[list[str]] = None) -> None:
         if args.qos_default_tier not in {t.name for t in qos_tiers}:
             p.error(f"--qos-default-tier {args.qos_default_tier!r} is not "
                     "a configured tier")
+    try:
+        warm_prompt_lens = tuple(
+            int(n) for n in args.warm_prompt_lens.split(",") if n.strip())
+        if any(n < 1 for n in warm_prompt_lens):
+            raise ValueError
+    except ValueError:
+        p.error(f"--warm-prompt-lens {args.warm_prompt_lens!r}: whole "
+                "numbers of tokens, comma-separated")
     config = EngineConfig(
         model=model_cfg,
         cache=CacheConfig(hbm_utilization=args.hbm_utilization,
                           swap_space_gb=args.swap_space_gb),
         scheduler=SchedulerConfig(
             max_num_seqs=args.max_num_seqs,
+            warm_prompt_lens=warm_prompt_lens,
             enable_prefix_caching=args.enable_prefix_caching,
             mixed_batch_enabled=not args.disable_mixed_batch,
             decode_priority_token_budget=args.decode_priority_token_budget,
@@ -2680,7 +2697,7 @@ def main(argv: Optional[list[str]] = None) -> None:
         # multi-process mesh every dispatch is a directive: the followers
         # would have to be told.)
         server.engine.engine.warm_full_window()
-        server.engine.engine.warm_short_mixed()
+        server.engine.engine.warm_mixed_steps()
     app = server.build_app()
 
     async def _arm_sigterm(app_):

@@ -31,7 +31,8 @@ METRICS = sorted((REPO / "perfbench" / "layer_metrics").glob("*.json"))
 PROM_READERS = {"prom_hist_mean", "prom_hist_mean_where",
                 "prom_hist_quantile", "prom_counter_delta",
                 "prom_counter_delta_diff", "prom_gauge_sampled",
-                "prom_gauge_window_mean", "prom_ratio"}
+                "prom_gauge_window_mean", "prom_ratio",
+                "prom_counter_ratio"}
 FAMILY_KEYS = ("family", "minus", "num", "den")
 KERNEL_READERS = {"kernel_flops_share", "hc_kernel_hbm_share",
                   "kda_kernel_hbm_share", "latent_kernel_hbm_share",
@@ -40,7 +41,10 @@ KERNEL_READERS = {"kernel_flops_share", "hc_kernel_hbm_share",
 # another metric (``step_metric``), the device's busy intervals.
 NO_PROGRAM_NAME = {"client", "roofline", "hc_step_hbm_share",
                    "hybrid_step_hbm_share", "kda_step_hbm_share",
-                   "latent_moe_step_hbm_share", "trace_idle"}
+                   "latent_moe_step_hbm_share", "trace_idle",
+                   "dsa_step_hbm_share",
+                   # ... and arrays of the configuration's own shapes
+                   "dsa_rows_attend_hbm_share", "dsa_select_share"}
 # Needles of ``trace_op_share`` that are XLA's words, not the program's.
 XLA_OWN = {"custom-call", "custom_call", "pallas"}
 
@@ -166,6 +170,12 @@ def test_metric_names_what_the_program_has(path, program):
     elif reader in KERNEL_READERS:
         assert program.names_a_kernel(spec["contains"]), (
             f"{path.name}: no pallas_call is named {spec['contains']!r}")
+    elif reader == "dsa_index_roofline":
+        # operations told by the configuration's own shapes, inside one of
+        # the program's step functions
+        assert any(spec["module"] in f for f in program.step_functions), (
+            f"{path.name}: no jitted step function is named "
+            f"{spec['module']!r}")
     elif reader == "trace_module_time":
         assert any(spec["contains"] in f for f in program.step_functions), (
             f"{path.name}: no jitted step function is named "

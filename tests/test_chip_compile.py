@@ -252,7 +252,13 @@ def test_flash_prefill_compiles_for_a_v5e(one_chip, no_compile_cache,
       "grouped_matmul", "latent_paged_decode", "kv_write")),
     ("xing4.0-29b-a4b", {"num_hidden_layers": 8},
      ("hc_pre", "hc_post", "flash_prefill", "latent_prefill_hist",
-      "grouped_matmul", "latent_paged_decode", "kv_write"))])
+      "grouped_matmul", "latent_paged_decode", "kv_write")),
+    # the indexers and the chosen rows' attention are XLA's (ops/dsa.py):
+    # of the kernels the step holds the experts' and the two page writes
+    ("glm-5.2",
+     {"num_hidden_layers": 6, "layers_from": 2,
+      "experts_held": 16, "vocab_size": 19360},
+     ("grouped_matmul", "kv_write"))])
 def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
         one_chip, no_compile_cache, preset, overrides, kernels):
     """The WHOLE mixed step program a prompt of 1025-1536 tokens rides beside
@@ -326,3 +332,14 @@ def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
         assert f"%{name}." in text, name
     assert (compiled.memory_analysis().temp_size_in_bytes
             < step_workspace_bytes(cfg))
+    if model.index_topk:
+        # What the benchmark's readers of the indexers' device time tell
+        # an indexer by: ONE conditional of the step's several returns the
+        # rows' choice (the scanned expert layers' body; the one dense
+        # layer's indexer is inlined, its predicate a constant).
+        from perfbench.readers.dsa_index_roofline import (
+            is_indexer_conditional)
+        conds = [line.strip() for line in text.splitlines()
+                 if " conditional(" in line]
+        assert sum(is_indexer_conditional(c, model.index_topk)
+                   for c in conds) == 1 < len(conds)

@@ -596,8 +596,8 @@ def test_warm_full_window_meets_the_full_seat_program_and_nothing_else():
     assert warm.compiled_step_variants() == cold.compiled_step_variants()
 
 
-def test_warm_short_mixed_meets_a_short_prompts_step_beside_full_seats():
-    """``warm_short_mixed`` runs the mixed step of the smallest prefill
+def test_warm_mixed_steps_meets_a_short_prompts_step_beside_full_seats():
+    """``warm_mixed_steps`` runs the mixed step of the smallest prefill
     bucket at the largest row bucket over padding alone: one step program
     more has met its shape, no page, step or key of the engine is used up,
     what is served afterwards is what an engine that was never warmed
@@ -610,7 +610,7 @@ def test_warm_short_mixed_meets_a_short_prompts_step_beside_full_seats():
         (9, "short2", _P[5], _GREEDY)]
     cold, warm = _queue_engine(), _queue_engine()
     before = warm.compiled_step_variants()
-    warm.warm_short_mixed()
+    warm.warm_mixed_steps()
     assert warm.compiled_step_variants() == before + 1
     assert warm.step_count == 0 and warm.stats.prefill_tokens == 0
     alloc = warm.scheduler.allocator
@@ -622,6 +622,31 @@ def test_warm_short_mixed_meets_a_short_prompts_step_beside_full_seats():
     assert warm.compiled_step_variants() == cold.compiled_step_variants()
     assert warm._mixed_fn._cache_size() == cold._mixed_fn._cache_size()
     assert met < warm.compiled_step_variants()      # the load met others
+
+
+def test_warm_mixed_steps_meets_every_step_of_a_long_prompt_it_was_named():
+    """``warm_prompt_lens`` names a prompt of several chunks: what is warmed
+    is one step program a (chunk rung, history width) such a prompt passes
+    through beside full seats, beside the short prompt's, and those ARE the
+    programs its chunks run: the load compiles no mixed step more, where
+    the unwarmed engine compiles at first use each one it meets."""
+    long = SamplingParams(max_tokens=40, temperature=0.0)
+    arrivals = [(0, f"r{i}", _P[i], long) for i in (1, 2, 4)] + [
+        (4, "doc", _P[3], _GREEDY), (30, "short", _P[0], _GREEDY)]
+    cold, warm = _queue_engine(), _queue_engine(warm_prompt_lens=(1, 80))
+    assert cold.config.scheduler.warm_prompt_lens == (1,)
+    before = warm.compiled_step_variants()
+    warm.warm_mixed_steps()
+    # 80 tokens beside 3 rows: chunks of 29, 29 and 22 tokens, all on the
+    # rung of 32, over 4, 8 and 10 pages: widths 4, 8, 16; and (16, 1)
+    assert warm.compiled_step_variants() == before + 4
+    assert warm.step_count == 0 and warm.stats.prefill_tokens == 0
+    met = warm._mixed_fn._cache_size()
+    want = _drive(cold, arrivals)
+    assert _drive(warm, arrivals) == want
+    # (the unwarmed engine met the two full chunks' and the short one's)
+    assert warm._mixed_fn._cache_size() == met
+    assert 3 <= cold._mixed_fn._cache_size() <= met
 
 
 def _rung_engine(monkeypatch, kind, **sched):

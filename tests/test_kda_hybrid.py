@@ -38,6 +38,7 @@ from kubernetes_gpu_cluster_tpu.models import llama
 from kubernetes_gpu_cluster_tpu.observability import Observability
 from kubernetes_gpu_cluster_tpu.ops import kda as kda_ops
 from kubernetes_gpu_cluster_tpu.ops.attention import NO_KERNELS, Kernels
+from perfbench.reference import glm5_2 as glm_ref
 from perfbench.reference import kimi_linear as ref
 
 CFG = get_model_config("debug-kda-hybrid")
@@ -499,40 +500,55 @@ def _share_of(lp, first, held):
             for k, a in lp.items()}
 
 
+# The deployments that hold a share of their experts: kimi-linear's four
+# chips (16 debug experts in shares of 4), glm-5.2's sixteen (its debug
+# block widened to 32 experts, top-4, in shares of 2), each against its own
+# plain reference.
+_SHARED_OUT = {
+    "kimi-linear": (CFG, ref, 4),
+    "glm-5.2": (get_model_config("debug-dsa-mla-moe").replace(
+        num_experts=32, num_experts_per_tok=4), glm_ref, 16)}
+
+
+@pytest.mark.parametrize("model", list(_SHARED_OUT))
 @pytest.mark.parametrize("kernels", [NO_KERNELS,
                                      Kernels(grouped_experts=True)],
                          ids=["dense", "grouped"])
-def test_four_shares_add_up_to_the_whole_layer(kernels):
-    """The routed parts the four shares give plus the shared expert counted
-    ONCE equal the uncut reference's whole layer, on either dispatch path
-    (200 tokens: over the dense rule's 128, so ``grouped`` is grouped);
-    padding tokens add nothing and are in no expert's load."""
-    whole = CFG.replace(experts_held=0)
-    lp, x = _expert_layer(jax.random.key(7), CFG, 200)
+def test_four_shares_add_up_to_the_whole_layer(kernels, model):
+    """The routed parts the shares give (four of kimi-linear's, all sixteen
+    of glm-5.2's) plus the shared expert counted ONCE equal the uncut
+    reference's whole layer, on either dispatch path (200 tokens: over the
+    dense rule's 128, so ``grouped`` is grouped); padding tokens add nothing
+    and are in no expert's load."""
+    base, ref, shares = _SHARED_OUT[model]
+    held = base.num_experts // shares
+    whole = base.replace(experts_held=0)
+    lp, x = _expert_layer(jax.random.key(7), base, 200)
     valid = jnp.arange(200) < 190
     want = ref._experts(lp, whole, x)
     shared = ref._swiglu(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
     total, loads = shared, []
-    for first in (0, 4, 8, 12):
-        cfg = CFG.replace(experts_first=first, experts_held=4)
+    for first in range(0, base.num_experts, held):
+        cfg = base.replace(experts_first=first, experts_held=held)
         assert llama.grouped_dispatch(200, cfg, kernels) == \
             kernels.grouped_experts
         load = []
-        out = llama._moe_mlp(_share_of(lp, first, 4), x, cfg, kernels=kernels,
-                             load_out=load, valid=valid)
+        out = llama._moe_mlp(_share_of(lp, first, held), x, cfg,
+                             kernels=kernels, load_out=load, valid=valid)
         # the program's share is the reference's, given the same share
         # (float32 sums in another order: terms of size ~1)
         np.testing.assert_allclose(
-            out[:190], ref._experts(_share_of(lp, first, 4), cfg, x)[:190],
+            out[:190],
+            ref._experts(_share_of(lp, first, held), cfg, x)[:190],
             atol=2e-5)
         total = total + (out - shared)
         loads.append(load[0])
-    # float32 sums in another order: 16 experts' terms of size ~1
+    # float32 sums in another order: every expert's terms of size ~1
     np.testing.assert_allclose(total[:190], want[:190], atol=5e-5)
-    # every share counts the SAME load, over all 16 experts, padding left out
+    # every share counts the SAME load, over all experts, padding left out
     for load in loads:
         np.testing.assert_array_equal(load, loads[0])
-    assert int(loads[0].sum()) == 190 * CFG.num_experts_per_tok
+    assert int(loads[0].sum()) == 190 * base.num_experts_per_tok
 
 
 def test_the_dispatch_rule_and_the_load_are_over_the_experts_held():
@@ -602,10 +618,10 @@ def test_engine_greedy_equals_the_reference(served):
     assert any(behind for _, behind in eng.obs.steps_dispatched)
 
 
-@pytest.mark.parametrize("warm", ["warm_full_window", "warm_short_mixed"])
+@pytest.mark.parametrize("warm", ["warm_full_window", "warm_mixed_steps"])
 def test_a_warmed_step_program_leaves_slots_and_pages_as_they_were(
         served, warm):
-    """``warm_full_window`` and ``warm_short_mixed`` (the serving CLI's,
+    """``warm_full_window`` and ``warm_mixed_steps`` (the serving CLI's,
     before it listens) run a window, a mixed step, of padding alone: they
     write the scrap slot and the scrap page, so the same prompts are served
     as before them."""

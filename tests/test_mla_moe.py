@@ -53,7 +53,7 @@ def _prompt(n, seed):
 
 
 def test_the_warmed_step_programs_leave_the_latent_pool_as_it_was(params):
-    """``warm_full_window`` and ``warm_short_mixed`` over latent pages and
+    """``warm_full_window`` and ``warm_mixed_steps`` over latent pages and
     routed experts: padding alone, written to the scrap page, so a warmed
     engine serves what an unwarmed one serves, and the switches that leave
     no mixed step to meet (``mixed_batch_enabled`` off) make the second a
@@ -65,14 +65,14 @@ def test_the_warmed_step_programs_leave_the_latent_pool_as_it_was(params):
     eng = _engine(params)
     before = eng.compiled_step_variants()
     eng.warm_full_window()
-    eng.warm_short_mixed()
+    eng.warm_mixed_steps()
     assert eng.compiled_step_variants() == before + 2
     alloc = eng.scheduler.allocator
     assert alloc.num_free == alloc.num_pages - 1
     assert [o.output_token_ids for o in eng.generate(prompts, greedy)] == want
     off = _engine(params, mixed_batch_enabled=False)
     before = off.compiled_step_variants()
-    off.warm_short_mixed()
+    off.warm_mixed_steps()
     assert off.compiled_step_variants() == before
 
 
