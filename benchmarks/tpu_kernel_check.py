@@ -914,6 +914,102 @@ def check_ssm_scan(cfg, T, n=1472) -> None:
         assert bool(jnp.isfinite(y).all()) and e_s < 1e-3, (name, e_y, e_s)
 
 
+# The conv stage's gate (``--kernels conv``): the state models whose mixed
+# steps run it, and how far the kernel's activated pieces may lie from the
+# XLA form's on the chip, in units in the last place of the piece's dtype.
+# The taps' sum is held BITWISE (the same products in the same order); what
+# follows it could differ by the two compilers' exp, reciprocal and rsqrt,
+# and read 0 at every piece of both models on a v5e (PERF.md section 6, PR
+# 47); on the CPU the two programs' lane sums differ by up to 4.
+CONV_MODELS = ("kimi-linear-48b-a3b", "granite-4.0-h-micro")
+CONV_ULP_LIMIT = 4
+
+
+def ulps(got, want) -> int:
+    """The largest distance of two arrays of one float dtype in units in
+    the last place (the floats' bit patterns as ordered integers)."""
+    def ordered(a):
+        a = np.asarray(a)
+        bits = a.view({2: np.int16, 4: np.int32}[a.dtype.itemsize]
+                      ).astype(np.int64)
+        return np.where(bits < 0, -(bits & (2 ** (8 * a.dtype.itemsize - 1)
+                                            - 1)), bits)
+    return int(np.abs(ordered(got) - ordered(want)).max())
+
+
+def check_conv(chunks=(1536, 2048)) -> None:
+    """The conv stage of a segment part (``ops/pallas/conv_segments.py``)
+    against its XLA twin at both state models' widths, over the chunk
+    buckets a mixed step of the ``-2k`` cells runs: three packed segments
+    (one shorter than the taps reach), a padding tail, the first continuing
+    from a slot's rows. The taps' sum bitwise, the activated pieces within
+    ``CONV_ULP_LIMIT`` (exit 1 beyond); then each form timed alone as 16
+    calls chained in one program, against the time of its bytes (xbc once
+    in the model's dtype, the pieces once)."""
+    import kubernetes_gpu_cluster_tpu.engine  # noqa: F401 (before models)
+    from kubernetes_gpu_cluster_tpu.models.llama import state_conv_split
+    from kubernetes_gpu_cluster_tpu.ops import ssm as ssm_ops
+    from kubernetes_gpu_cluster_tpu.ops.pallas.conv_segments import (
+        conv_segments)
+    f32, calls, rows = jnp.float32, 16, 64
+    for model in CONV_MODELS:
+        cfg = get_model_config(model)
+        split, dt = state_conv_split(cfg), cfg.jnp_dtype
+        K1, C = cfg.state_conv_shape
+        for T in chunks:
+            k = jax.random.split(jax.random.key(T), 4)
+            xbc = jax.random.normal(k[0], (T + rows, C), f32).astype(dt)
+            w = (jax.random.normal(k[1], (K1 + 1, C), f32) * 0.5).astype(dt)
+            b = jax.random.normal(k[2], (C,), f32).astype(dt)
+            init = jax.random.normal(k[3], (K1, C), f32).astype(dt)
+            n = T - 200
+            bounds = [(0, n // 2), (n // 2, n // 2 + 2), (n // 2 + 2, n)]
+            seg = np.full(T, -1, np.int32)
+            for s, (lo, hi) in enumerate(bounds):
+                seg[lo:hi] = s
+            args = (xbc, jnp.asarray(seg), init, w, b)
+
+            want_sum = jax.jit(ssm_ops.conv_segments)(xbc[:T], *args[1:])
+            got_sum = jnp.concatenate(
+                [p.reshape(T, -1) for p in conv_segments(
+                    *args, split, activate=False)], axis=1)
+            want = jax.jit(ssm_ops.conv_operands_xla, static_argnums=5)(
+                *args, split)
+            got = conv_segments(*args, split)
+            same_sum = bool((got_sum[:n] == want_sum[:n]).all())
+            readings = [ulps(g[:n], w_[:n]) for g, w_ in zip(got, want)]
+            out_bytes = sum(int(np.prod(g.shape)) * g.dtype.itemsize
+                            for g in got)
+            nbytes = T * C * jnp.dtype(dt).itemsize + out_bytes
+            print(f"conv_segments {model} T={T} ({n} real in 3 segments, "
+                  f"from a slot's rows), C={C}, pieces "
+                  f"{[list(g.shape) for g in got]} {got[0].dtype}: the taps' "
+                  f"sum bitwise {same_sum}, the pieces within {readings} ulp; "
+                  f"{nbytes / 1e6:.0f} MB = {nbytes / 819e9 * 1e6:.0f} us "
+                  "at 819 GB/s")
+            assert same_sum and max(readings) <= CONV_ULP_LIMIT, (
+                model, T, same_sum, readings)
+
+            for name, form in (
+                    ("xla", lambda *a: ssm_ops.conv_operands_xla(*a, split)),
+                    ("pallas", lambda *a: conv_segments(*a, split))):
+                # One program, each call's taps hanging on the call before
+                # it: nothing of a call can be lifted out of the loop, and
+                # xbc is read as the projection left it.
+                def after(_, carry):
+                    hang = sum(p[0].reshape(-1)[0] for p in carry)
+                    return form(xbc, args[1], init,
+                                w + (0.0 * hang).astype(dt), b)
+                zero = jax.tree.map(jnp.zeros_like, got)
+                chain = jax.jit(lambda z: jax.lax.fori_loop(
+                    0, calls, after, z))
+                s = _timed(chain, zero, n=3) / calls
+                print(f"conv_segments[{name}] {model} T={T} alone, {calls} "
+                      f"calls chained in one program: {s * 1e6:.0f} us a "
+                      f"layer = {nbytes / 819e9 / s * 100:.0f} % of its "
+                      "bytes' time")
+
+
 def _state_chain(cfg, name: str, update, inputs, state_ref, y_ref, real,
                  slots, limit: float) -> dict:
     """The common part of the two gates of a recurrent state's precision
@@ -1163,8 +1259,14 @@ def check_kda_chunk(cfg, T) -> None:
                 for s, (a, b) in enumerate(bounds)]
         errs = {}
         for form, fn in forms:
-            run = jax.jit(lambda *a, fn=fn: fn(*a, init_seg, Q))
-            args = (q, k, v, g, beta, jnp.asarray(seg), ends, init)
+            # q, k, v, g as the step program hands them over: [T, H x d],
+            # the heads on lanes (the conv stage's and the projection's),
+            # NAMED [T, H, d] (the kernel's wrapper undoes the name; for
+            # XLA it is a re-tiling)
+            run = jax.jit(lambda *a, fn=fn: fn(
+                *(x.reshape(-1, H, hd) for x in a[:4]), *a[4:], init_seg, Q))
+            args = (*(x.reshape(n_tok, H * hd) for x in (q, k, v, g)), beta,
+                    jnp.asarray(seg), ends, init)
             o, final = run(*args)
             finite = bool(jnp.isfinite(o).all() & jnp.isfinite(
                 final[:len(bounds)]).all())
@@ -1625,6 +1727,7 @@ def main() -> None:
         "ssm": lambda: check_ssm(cfg, B, T),
         "ssm-chain": lambda: check_ssm_chain(cfg,
                                              Kernels(use_pallas=True)),
+        "conv": check_conv,
         "kda": lambda: check_kda(cfg, B, T),
         "kda-chunk": lambda: check_kda_chunk(cfg, T),
         "kda-chain": lambda: check_kda_chain(cfg, Kernels(use_pallas=True)),

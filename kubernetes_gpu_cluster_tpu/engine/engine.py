@@ -737,6 +737,15 @@ class LLMEngine:
                       arr((T, H, P)), arr((T, H), f32), arr((T, H), f32),
                       arr((T, N)), arr((T, N)), arr((T,), i32),
                       arr((1,), i32), arr((N, di), f32))
+            # The conv stage of a full prefill bucket, read out of the
+            # widest mixed step's projection.
+            from ..ops.pallas.conv_segments import conv_segments
+            K1, C = cfg.state_conv_shape
+            split = model_lib.state_conv_split(cfg)
+            probe("conv_segments",
+                  lambda *a: conv_segments(*a, split),
+                  arr((T + B, C)), arr((T,), i32), arr((K1, C)),
+                  arr((K1 + 1, C)), arr((C,)))
         if cfg.hc_mult > 1:
             # The stream mixers over a full decode bucket and over the
             # widest mixed step.

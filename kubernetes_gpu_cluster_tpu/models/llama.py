@@ -36,7 +36,6 @@ from ..engine.kv_cache import KVCache
 from ..ops import quant as quant_ops
 from ..ops import dsa
 from ..ops import hyper_conn
-from ..ops import kda as kda_ops
 from ..ops import ssm as ssm_ops
 from ..ops.rope import apply_rope, rope_cos_sin
 from ..ops.attention import NO_KERNELS, Kernels
@@ -1139,22 +1138,23 @@ class _StateKind(NamedTuple):
     - ``project(lp, cfg, x) -> (conv_in [T, C], per_token, consts)``: the
       conv's input, the recurrence's other per-token inputs ([T, ...] each)
       and its constants;
-    - ``segments(cfg, kernels, xs [n, C], per_token, consts, seg, seg_ends,
+    - ``split(cfg) -> ops.ssm.ConvSplit``: the pieces the conv's activated
+      output [n, C] leaves the conv stage in, the recurrence's operands,
+      and what it is rounded to before the recurrence (the conv ROWS a slot
+      keeps are the model's dtype either way);
+    - ``segments(cfg, kernels, pieces, per_token, consts, seg, seg_ends,
       state0, init_seg) -> (y [n, width] float32, final [S,
       *cfg.state_shape])``;
     - ``rows(cfg, kernels, pool, layer, slots, xr [R, C] float32,
       per_token, consts) -> (pool, y [R, width] float32)``;
-    - ``gate(lp, cfg, x, y [T, width]) -> out [T, d] float32``;
-    - ``conv_dtype``: what the conv's activated output is rounded to before
-      the recurrence (None: the model's dtype; the conv ROWS a slot keeps
-      are the model's dtype either way)."""
+    - ``gate(lp, cfg, x, y [T, width]) -> out [T, d] float32``."""
     scope: str
     segment_scope: str
     project: Any
+    split: Any
     segments: Any
     rows: Any
     gate: Any
-    conv_dtype: Any = None
 
 
 def _mamba_project(lp, cfg, x):
@@ -1166,16 +1166,20 @@ def _mamba_project(lp, cfg, x):
     return xbc, (dt,), (A, D)
 
 
-def _mamba_segments(cfg, kernels, xs, per_token, consts, seg, seg_ends,
+def _mamba_split(cfg):
+    """[x | B | C], each where ``ssm_chunk`` reads it."""
+    return ssm_ops.ConvSplit(
+        (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_state))
+
+
+def _mamba_segments(cfg, kernels, pieces, per_token, consts, seg, seg_ends,
                     state0, init_seg):
-    H, P, N, di = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
-                   cfg.mamba_d_inner)
-    (dt,), (A, D), n = per_token, consts, xs.shape[0]
+    H, P = cfg.mamba_n_heads, cfg.mamba_d_head
+    (dt,), (A, D), (x, B, C) = per_token, consts, pieces
     y, final = kernels.ssm_chunk(
-        xs[:, :di].reshape(n, H, P), dt, dt * A, xs[:, di:di + N],
-        xs[:, di + N:], seg, seg_ends, state0, init_seg,
-        cfg.mamba_chunk_size)
-    return y.reshape(n, di) + D * xs[:, :di].astype(jnp.float32), final
+        x.reshape(-1, H, P), dt, dt * A, B, C, seg, seg_ends, state0,
+        init_seg, cfg.mamba_chunk_size)
+    return y.reshape(x.shape) + D * x.astype(jnp.float32), final
 
 
 def _mamba_rows(cfg, kernels, pool, layer, slots, xr, per_token, consts):
@@ -1205,35 +1209,40 @@ def _low_rank(x, lp, name):
 
 
 def _kda_project(lp, cfg, x):
-    H, hd = cfg.kda_n_heads, cfg.kda_head_dim
+    """g stays [T, H hd] here, the heads on lanes as the projection leaves
+    it, and is named [T, H, hd] where a recurrence takes it: made [T, H,
+    hd] HERE it is another tiled layout on the chip, which XLA reached by a
+    copy into one padded fourfold, a slice of the segment part and a copy
+    back (0.9 ms a layer at 2 k tokens: PERF.md section 6, PR 47)."""
     qkv = _dot(x, lp, "w_qkv").astype(x.dtype)                # [T, 3 H hd]
-    g = -jnp.exp(lp["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
-        _low_rank(x, lp, "w_f") + lp["dt_bias"]).reshape(-1, H, hd)
+    g = jnp.repeat(-jnp.exp(lp["A_log"].astype(jnp.float32)),
+                   cfg.kda_head_dim) * jax.nn.softplus(
+        _low_rank(x, lp, "w_f") + lp["dt_bias"])
     return qkv, (g, jax.nn.sigmoid(_dot(x, lp, "w_beta"))), ()
 
 
-def _kda_qkv(cfg, xs):
+def _kda_split(cfg):
     """The conv's output [n, 3 H hd] as unit q (scaled), unit k, v: float32
     [n, H, hd] each."""
     H, hd = cfg.kda_n_heads, cfg.kda_head_dim
-    q, k, v = (xs[:, i * H * hd:(i + 1) * H * hd].reshape(-1, H, hd)
-               for i in range(3))
-    return (kda_ops.l2_normalise(q) * hd ** -0.5, kda_ops.l2_normalise(k),
-            v.astype(jnp.float32))
+    return ssm_ops.ConvSplit((H * hd,) * 3, jnp.float32, hd,
+                             (hd ** -0.5, 1.0, None))
 
 
-def _kda_segments(cfg, kernels, xs, per_token, consts, seg, seg_ends,
+def _kda_segments(cfg, kernels, pieces, per_token, consts, seg, seg_ends,
                   state0, init_seg):
-    (g, beta), (q, k, v) = per_token, _kda_qkv(cfg, xs)
+    (g, beta), (q, k, v) = per_token, pieces
     o, final = kernels.kda_chunk(
-        q, k, v, g, beta, seg, seg_ends, state0, init_seg,
+        q, k, v, g.reshape(k.shape), beta, seg, seg_ends, state0, init_seg,
         cfg.kda_chunk_size)
-    return o.reshape(xs.shape[0], -1), final
+    return o.reshape(q.shape[0], -1), final
 
 
 def _kda_rows(cfg, kernels, pool, layer, slots, xr, per_token, consts):
-    (g, beta), (q, k, v) = per_token, _kda_qkv(cfg, xr)
-    return kernels.kda_update(pool, layer, slots, g, beta, q, k, v)
+    (g, beta), (q, k, v) = per_token, ssm_ops.split_activated(
+        xr, _kda_split(cfg))
+    return kernels.kda_update(pool, layer, slots, g.reshape(k.shape), beta,
+                              q, k, v)
 
 
 def _kda_gate(lp, cfg, x, y):
@@ -1246,13 +1255,18 @@ def _kda_gate(lp, cfg, x, y):
 
 
 _STATE_MIXERS = {
-    "mamba": _StateKind("kgct.ssm", "scan", _mamba_project, _mamba_segments,
-                        _mamba_rows, _mamba_gate),
+    "mamba": _StateKind("kgct.ssm", "scan", _mamba_project, _mamba_split,
+                        _mamba_segments, _mamba_rows, _mamba_gate),
     # q, k and v stay float32 from the conv to the recurrence: k and q are
     # normalised next, and the state they write is float32.
-    "kda": _StateKind("kgct.kda", "chunk", _kda_project, _kda_segments,
-                      _kda_rows, _kda_gate, jnp.float32),
+    "kda": _StateKind("kgct.kda", "chunk", _kda_project, _kda_split,
+                      _kda_segments, _kda_rows, _kda_gate),
 }
+
+
+def state_conv_split(cfg: ModelConfig) -> ssm_ops.ConvSplit:
+    """The pieces a state layer's conv stage leaves its output in."""
+    return _STATE_MIXERS[cfg.state_kind].split(cfg)
 
 
 def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
@@ -1298,7 +1312,7 @@ def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
     f32 = jnp.float32
     with scope("proj"):
         xbc, per_token, consts = kind.project(lp, cfg, x)
-    ys, conv_new = [], []
+    ys, conv_new, xbc_rows = [], [], xbc
     if n_seg:
         seg = meta.seg_ids[:n_seg]
         n_segs = meta.seg_slots.shape[0]
@@ -1317,14 +1331,22 @@ def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
             return jnp.where(resumes, pool[layer, slot0], first)
 
         with scope("conv"):
-            c_out, rows = ssm_ops.conv_segments(
-                xbc[:n_seg], seg, seg_ends, start(conv), lp["conv_w"],
-                lp.get("conv_b"))
-            xs = jax.nn.silu(c_out).astype(kind.conv_dtype or x.dtype)
+            # The projection's small readers (each segment's new rows, the
+            # row part's tokens) BEFORE its large one: scheduled behind the
+            # recurrence, XLA computed the whole projection again for each
+            # (``%convolution_convert_fusion.N.remat``, 4 ms of granite's
+            # 1600-token mixed step).
+            init = start(conv)
+            xbc, rows, xbc_rows = jax.lax.optimization_barrier(
+                (xbc, ssm_ops.segment_conv_rows(xbc, seg, seg_ends, init),
+                 xbc[n_seg:]))
+            pieces = kernels.conv_segments(
+                xbc, seg, init, lp["conv_w"], lp.get("conv_b"),
+                state_conv_split(cfg))
             conv_new.append(rows)
         with scope(kind.segment_scope):
             y, final = kind.segments(
-                cfg, kernels, xs, [a[:n_seg] for a in per_token], consts,
+                cfg, kernels, pieces, [a[:n_seg] for a in per_token], consts,
                 seg, seg_ends, start(ssm), 0 if resumes is not None else -2)
             ssm = ssm_ops.write_slots(ssm, final, meta.seg_slots, layer)
             ys.append(y)
@@ -1332,10 +1354,10 @@ def state_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, meta: StepMeta,
         slots = meta.row_slots
         with scope("conv"):
             c_out, rows = ssm_ops.conv_rows(
-                xbc[n_seg:], conv[layer, slots], lp["conv_w"],
+                xbc_rows, conv[layer, slots], lp["conv_w"],
                 lp.get("conv_b"))
             xr = jax.nn.silu(c_out).astype(
-                kind.conv_dtype or x.dtype).astype(f32)
+                state_conv_split(cfg).dtype or x.dtype).astype(f32)
             conv_new.append(rows)
         with scope("update"):
             ssm, y = kind.rows(cfg, kernels, ssm, layer, slots, xr,

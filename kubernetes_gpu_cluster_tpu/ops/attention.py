@@ -428,7 +428,7 @@ def ragged_prefill_attention_tp(mesh, q, k, v, seg_ids, positions, scale, *,
 class Kernels:
     """What the engine decides ONCE, at construction, about how the
     operations a forward pass needs (five of attention and the page pool,
-    one of the state slots, two of the residual streams) are carried out
+    five of the state slots, two of the residual streams) are carried out
     (``LLMEngine._resolve_use_pallas`` builds it, proves every kernel it
     names by compiling it, and hands it to every step program); nothing
     below the engine decides again. The default is the XLA references
@@ -573,6 +573,17 @@ class Kernels:
             return ssm_update_xla(pool, layer, slots, decay, dtx, B, C)
         from .pallas.ssm_update import ssm_update
         return ssm_update(pool, layer, slots, decay, dtx, B, C)
+
+    def conv_segments(self, xbc, seg_ids, init_rows, w, b, split):
+        """A state layer's conv stage over the segment part, from the
+        projected ``xbc`` (its first ``len(seg_ids)`` rows) to the
+        recurrence's operands, the pieces ``split`` names (``ops/ssm.py``):
+        the Pallas pass or the XLA passes; one device, as ``ssm_update``."""
+        if not self.use_pallas:
+            from .ssm import conv_operands_xla
+            return conv_operands_xla(xbc, seg_ids, init_rows, w, b, split)
+        from .pallas.conv_segments import conv_segments
+        return conv_segments(xbc, seg_ids, init_rows, w, b, split)
 
     def ssm_chunk(self, x, dt, dA, B, C, seg_ids, seg_ends, init_state,
                   init_seg, chunk):

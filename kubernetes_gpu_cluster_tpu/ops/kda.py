@@ -36,18 +36,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .ssm import write_slots
+from .ssm import l2_normalise, write_slots  # noqa: F401 (l2_normalise: its callers name it here)
 
 _HI = jax.lax.Precision.HIGHEST
 # Tokens of a chunk whose decay differences are formed pair by pair; between
 # such sub-chunks they pass through the sub-chunk's first position.
 SUB_CHUNK = 16
-
-
-def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
-    """x / sqrt(sum x^2 + eps) over the last axis, float32."""
-    xf = x.astype(jnp.float32)
-    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
 
 
 def heads_first(state: jax.Array, H: int) -> jax.Array:

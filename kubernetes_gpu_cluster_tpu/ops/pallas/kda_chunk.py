@@ -9,11 +9,16 @@ grid step is (a block of eight heads, one chunk), the chunks walked in order
 with the heads' states in VMEM scratch between them; inside it a loop takes
 the heads two at a time (a group), its body unrolled:
 
-1. q, k, v, g arrive as ``(Q, heads of the block, d)`` blocks of the
-   operands AS THEY ARE, ``[T, H, d]`` (a token's eight heads are one tile:
-   a head's ``[Q, d]`` is read with a stride over sublanes), and o leaves
-   the same way: no transpose and no copy in HBM (``[T, H x d]`` with the
-   heads on lanes is another TILED layout, 0.8 ms of copies a layer).
+1. q, k, v, g arrive as ``(Q, heads of the block x d)`` blocks of ``[T, H
+   x d]``, the heads on lanes: how the conv stage (``conv_segments``) and
+   the gate's projection leave them, so a head's ``[Q, d]`` is a slice of
+   whole lane tiles (the contract's ``[T, H, d]`` is undone by a reshape
+   that meets the producer's own and moves no byte; where XLA has to MAKE
+   ``[T, H, d]`` it is another TILED layout, 0.3-0.9 ms of copies an
+   operand and layer: PERF.md section 6, PR 47). o leaves as ``(Q, heads,
+   d)`` blocks of ``[T, H, d]``, a token's eight heads one tile (a head's
+   rows written with a stride over sublanes): what the gate's norm over
+   each head reads.
 2. ``G`` is the running sum of g (one product with the lower-triangular
    ones, a group of heads at a time). The decays between two tokens of one
    16-row sub-chunk are formed pair by pair, ``exp(G_t - G_s)``, ONCE, and
@@ -151,7 +156,7 @@ def _unit_lower_inverse(A, own, sub, Q):
 
 def _kernel(n_real_ref, seg_in_ref, seg_last_ref, q_ref, k_ref, v_ref, g_ref,
             beta_ref, seg_col_ref, seg_row_ref, init_ref, o_ref, u_ref,
-            s_in_ref, state_ref, *, hb, group, dv, sub):
+            s_in_ref, state_ref, *, hb, group, dk, dv, sub):
     c = pl.program_id(1)
     Q = q_ref.shape[0]
     R, f32 = group * Q, jnp.float32
@@ -190,15 +195,16 @@ def _kernel(n_real_ref, seg_in_ref, seg_last_ref, q_ref, k_ref, v_ref, g_ref,
             # cache, at every start).
             js = [n * group + h for h in range(group)]
             rows = [pl.ds(pl.multiple_of(j * dv, dv), dv) for j in js]
-            G2 = _dot(ones, stack([g_ref[:, j, :] for j in js]))
-            heads = [(q_ref[:, j, :], k_ref[:, j, :], G2[h * Q:(h + 1) * Q])
-                     for h, j in enumerate(js)]
+            of_k = [pl.ds(pl.multiple_of(j * dk, dk), dk) for j in js]
+            G2 = _dot(ones, stack([g_ref[:, c] for c in of_k]))
+            heads = [(q_ref[:, c], k_ref[:, c], G2[h * Q:(h + 1) * Q])
+                     for h, c in enumerate(of_k)]
             eG = [jnp.exp(G) for _, _, G in heads]
             A, P = _decay_dots(heads, sub)
             beta = stack([beta_ref[j] for j in js])             # [R, 1]
             X = _dot(_unit_lower_inverse(A * beta * m_a, own, sub, Q),
                      beta * jnp.concatenate(
-                         [stack([v_ref[:, j, :] for j in js]),
+                         [stack([v_ref[:, r] for r in rows]),
                           stack([k * e for (_, k, _), e in zip(heads, eG)])],
                          axis=1))
             St = [state_ref[r, :] for r in rows]                # [d_v, d_k]
@@ -263,6 +269,9 @@ def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     # token are the first n_real.
     n_real = jnp.sum(jnp.any(sc >= 0, axis=1)).astype(jnp.int32)[None]
 
+    # The heads on lanes, as the producers leave them (the module's
+    # docstring, 1).
+    flat = [a.astype(f32).reshape(Tp, -1) for a in (q, k, v, g)]
     # A head's beta as a column, [H, T, 1]; 4 T H bytes.
     beta_b = beta.astype(f32).T[..., None]
     init_t = init_state.astype(f32).reshape(H, dk, dv).swapaxes(1, 2)
@@ -270,8 +279,8 @@ def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 
     def tokens(width):       # a chunk without a real token fetches nothing
         return pl.BlockSpec(
-            (Q, hb, width), lambda h, c, n, *_: (
-                jnp.minimum(c, jnp.maximum(n[0], 1) - 1), h, 0))
+            (Q, hb * width), lambda h, c, n, *_: (
+                jnp.minimum(c, jnp.maximum(n[0], 1) - 1), h))
 
     # What only ``segment_finals`` reads, of chunks that hold a segment's
     # last token: the chunks behind the real ones share ONE block, written
@@ -297,7 +306,8 @@ def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((hb * dv, dk), f32)])
     o, U, S_in = pl.pallas_call(
-        functools.partial(_kernel, hb=hb, group=group, dv=dv, sub=sub),
+        functools.partial(_kernel, hb=hb, group=group, dk=dk, dv=dv,
+                          sub=sub),
         out_shape=[jax.ShapeDtypeStruct((Tp, H, dv), f32),
                    jax.ShapeDtypeStruct((Tp, H, dv), f32),
                    jax.ShapeDtypeStruct((nc, H * dv, dk), f32)],
@@ -313,8 +323,7 @@ def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
             + 4 * nc * H * dk * dv),
         interpret=interpret,
         name="kda_chunk",
-    )(n_real, seg_in, seg_last, *(a.astype(f32) for a in (q, k, v, g)),
-      beta_b,
+    )(n_real, seg_in, seg_last, *flat, beta_b,
       seg_rows[:, :, None], seg_rows[:, None, :],
       init_t.reshape(H * dv, dk))
 
@@ -322,10 +331,13 @@ def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     with jax.named_scope("kgct.kda.chunk.final"):
         e = jnp.maximum(seg_ends, 0)
         c_s = e // Q
-        per_chunk = lambda a, d: (a.astype(f32).reshape(nc, Q, H, d)[c_s]
-                                  .swapaxes(1, 2))
+        # (of k and g the chunks are picked BEFORE the heads are named:
+        # [T, H x d] is another tiled layout than [T, H, d])
+        per_chunk = lambda a: (a.reshape((nc, Q) + a.shape[1:])[c_s]
+                               .reshape(-1, Q, H, a.size // (Tp * H))
+                               .swapaxes(1, 2))
         final = segment_finals(
-            per_chunk(k, dk), jnp.cumsum(per_chunk(g, dk), axis=2), sc[c_s],
-            S_in.reshape(nc, H, dv, dk)[c_s].swapaxes(2, 3),
-            per_chunk(U, dv), seg_in[c_s], e % Q)
+            per_chunk(flat[1]), jnp.cumsum(per_chunk(flat[3]), axis=2),
+            sc[c_s], S_in.reshape(nc, H, dv, dk)[c_s].swapaxes(2, 3),
+            per_chunk(U), seg_in[c_s], e % Q)
     return o[:T], slot_layout(final)

@@ -25,6 +25,9 @@ scan's time on the chip is re-tilings and copies, not its products).
 
 from __future__ import annotations
 
+import itertools
+from typing import Any, NamedTuple, Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -53,20 +56,56 @@ def write_slots(pool: jax.Array, rows: jax.Array, slots: jax.Array,
 # Causal depthwise conv over [x | B | C]
 # ---------------------------------------------------------------------------
 
-def conv_segments(xbc: jax.Array, seg_ids: jax.Array, seg_ends: jax.Array,
-                  init_rows: jax.Array, w: jax.Array, b: jax.Array):
+class ConvSplit(NamedTuple):
+    """What leaves the conv stage of a segment part, for the recurrence:
+    the activated ``[T, C]`` cut into pieces of ``widths`` channels, in
+    order. ``head_dim`` None: each piece ``[T, width]`` rounded to ``dtype``
+    (None: the input's). Else each piece float32 heads ``[T, width //
+    head_dim, head_dim]``, piece i brought to unit length over every head
+    and scaled by ``unit[i]`` (None: left as it is)."""
+    widths: tuple
+    dtype: Any = None
+    head_dim: Optional[int] = None
+    unit: tuple = ()
+
+
+L2_EPS = 1e-6
+
+
+def l2_normalise(x: jax.Array, eps: float = L2_EPS) -> jax.Array:
+    """x / sqrt(sum x^2 + eps) over the last axis, float32."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
+
+
+def segment_conv_rows(xbc: jax.Array, seg_ids: jax.Array, seg_ends: jax.Array,
+                      init_rows: jax.Array) -> jax.Array:
+    """Each segment's new conv rows [S, K-1, C] in xbc's dtype: its last
+    K-1 inputs, zeros or ``init_rows`` (the segment that starts at token 0)
+    where it is shorter; seg_ends [S] the last token of each segment (-1:
+    absent). A gather of S x (K-1) rows of xbc as it lies (its rows behind
+    the segment part are never named)."""
+    K1 = init_rows.shape[0]
+    e = jnp.maximum(seg_ends, 0)
+    at = e[:, None] - (K1 - 1) + jnp.arange(K1)[None, :]      # row of xbc
+    seg_at = jnp.where(at >= 0, seg_ids[jnp.maximum(at, 0)], seg_ids[0])
+    rows = jnp.where((at >= 0)[..., None], xbc[jnp.maximum(at, 0)],
+                     init_rows.astype(xbc.dtype)[jnp.clip(at + K1, 0, K1 - 1)])
+    return jnp.where((seg_at == seg_ids[e][:, None])[..., None], rows,
+                     0).astype(xbc.dtype)
+
+
+def conv_segments(xbc: jax.Array, seg_ids: jax.Array, init_rows: jax.Array,
+                  w: jax.Array, b: jax.Array) -> jax.Array:
     """The conv over the segment part: token t reads its own row and the
     ``K - 1`` before it OF ITS SEGMENT; before a segment's first token lie
     zeros, or ``init_rows`` for the segment that starts at token 0 (a chunk
     with history: the slot's rows; else zeros).
 
-    xbc [T, C]; seg_ids [T]; seg_ends [S] the last token of each segment
-    (-1: absent); init_rows [K-1, C]; w [K, C] (tap K-1 meets the token
-    itself, as torch's conv1d weight [C, 1, K] does); b [C] or None (a conv
-    without bias).
-    Returns (conv + bias [T, C] float32, each segment's new conv rows
-    [S, K-1, C] in xbc's dtype: its last K-1 inputs, zeros or ``init_rows``
-    where it is shorter)."""
+    xbc [T, C]; seg_ids [T]; init_rows [K-1, C]; w [K, C] (tap K-1 meets
+    the token itself, as torch's conv1d weight [C, 1, K] does); b [C] or
+    None (a conv without bias). Returns conv + bias [T, C] float32. (What a
+    slot keeps of each segment is ``segment_conv_rows``.)"""
     T = xbc.shape[0]
     K = w.shape[0]
     ext = jnp.concatenate([init_rows.astype(xbc.dtype), xbc], axis=0)
@@ -79,9 +118,32 @@ def conv_segments(xbc: jax.Array, seg_ids: jax.Array, seg_ends: jax.Array,
         same = seg_ext[k:k + T] == seg_ids
         out = out + wf[k] * jnp.where(
             same[:, None], ext[k:k + T].astype(jnp.float32), 0.0)
-    idx = jnp.maximum(seg_ends, 0)[:, None] + 1 + jnp.arange(K - 1)[None, :]
-    own = seg_ext[idx] == seg_ext[jnp.maximum(seg_ends, 0) + K - 1][:, None]
-    return out, jnp.where(own[..., None], ext[idx], 0).astype(xbc.dtype)
+    return out
+
+
+def split_activated(xs: jax.Array, split: ConvSplit):
+    """The activated conv output [n, C], float32 or already rounded, as the
+    pieces ``split`` names."""
+    pieces = jnp.split(xs, list(itertools.accumulate(split.widths))[:-1],
+                       axis=1)
+    if split.head_dim is None:
+        return tuple(pieces)
+    heads = [p.reshape(p.shape[0], -1, split.head_dim) for p in pieces]
+    return tuple(p.astype(jnp.float32) if unit is None
+                 else l2_normalise(p) * unit
+                 for p, unit in zip(heads, split.unit))
+
+
+def conv_operands_xla(xbc: jax.Array, seg_ids: jax.Array,
+                      init_rows: jax.Array, w: jax.Array, b,
+                      split: ConvSplit):
+    """The conv stage of a segment part as XLA passes: ``conv_segments``
+    over the first T = len(seg_ids) rows of xbc [>= T, C], SiLU, the cast,
+    the pieces (a tuple). What the CPU runs, what ``NO_KERNELS`` names and
+    what the kernel (``ops/pallas/conv_segments.py``) is held to."""
+    out = conv_segments(xbc[:seg_ids.shape[0]], seg_ids, init_rows, w, b)
+    return split_activated(
+        jax.nn.silu(out).astype(split.dtype or xbc.dtype), split)
 
 
 def conv_rows(xbc: jax.Array, state: jax.Array, w: jax.Array, b: jax.Array):

@@ -10,6 +10,8 @@ worker that got another of these cases, a process that holds a chip): the
 cases may land on any worker. Only a machine without the TPU's compiler
 skips; any other failure to describe the chip fails."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -105,7 +107,9 @@ def test_kda_update_compiles_for_a_v5e_in_place(one_chip, no_compile_cache):
 def test_kda_chunk_compiles_for_a_v5e_without_copies(one_chip,
                                                      no_compile_cache):
     """kimi-linear's chunked delta-rule form over a full prefill bucket: 2048
-    tokens of 32 heads of 128 in chunks of 64, four segments. The operands
+    tokens of 32 heads of 128 in chunks of 64, four segments. q, k, v and g
+    arrive as the conv stage and the gate's projection leave them, ``[T, H
+    x d]`` with the heads on lanes, named ``[T, H, d]`` for the call, and
     are read as they are: beside the kernel's own results (o, u and the
     states handed to each chunk) nothing of their size is written, so no
     operand was copied to another layout on its way in or out."""
@@ -114,8 +118,9 @@ def test_kda_chunk_compiles_for_a_v5e_without_copies(one_chip,
     def arr(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     T, H, d = 2048, 32, 128
-    compiled = jax.jit(lambda *a: kda_chunk(*a, 0, 64)).lower(
-        *(arr((T, H, d)),) * 4, arr((T, H)), arr((T,), jnp.int32),
+    compiled = jax.jit(lambda *a: kda_chunk(
+        *(x.reshape(T, H, d) for x in a[:4]), *a[4:], 0, 64)).lower(
+        *(arr((T, H * d)),) * 4, arr((T, H)), arr((T,), jnp.int32),
         arr((4,), jnp.int32), arr((H * d, d))).compile()
     assert "tpu_custom_call" in compiled.as_text()
     operand = T * H * d * 4
@@ -148,6 +153,34 @@ def test_ssm_chunk_compiles_for_a_v5e_without_copies(one_chip,
     # the step program the segment part is) and two states [N, d_inner]
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= (T + 128) * H * P * (2 + 4) + 3 * N * H * P * 4)
+
+
+@pytest.mark.parametrize("T", [1536, 2048])
+@pytest.mark.parametrize("preset", ["kimi-linear-48b-a3b",
+                                    "granite-4.0-h-micro"])
+def test_conv_segments_compiles_for_a_v5e_without_copies(
+        one_chip, no_compile_cache, preset, T):
+    """Both state models' conv stage over the segment part of a mixed step
+    beside full seats (the projection's ``[T + 64, C]`` bfloat16 handed over
+    whole): kimi-linear's q | k | v as float32 ``[T, 32, 128]`` heads,
+    granite's [x | B | C] in bfloat16. xbc is read and every piece written
+    where it lies: nothing of their size is written beside the pieces."""
+    import kubernetes_gpu_cluster_tpu.engine  # noqa: F401 (before models)
+    from kubernetes_gpu_cluster_tpu.config import get_model_config
+    from kubernetes_gpu_cluster_tpu.models.llama import state_conv_split
+    from kubernetes_gpu_cluster_tpu.ops.pallas.conv_segments import (
+        conv_segments)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    cfg = get_model_config(preset)
+    split, (K1, C) = state_conv_split(cfg), cfg.state_conv_shape
+    compiled = jax.jit(lambda *a: conv_segments(*a, split)).lower(
+        arr((T + 64, C)), arr((T,), jnp.int32), arr((K1, C)),
+        arr((K1 + 1, C)), arr((C,))).compile()
+    assert "%conv_segments" in compiled.as_text()
+    # the taps, the bias and the slot's rows as float32, the taps' bits
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
 @pytest.mark.parametrize("T", [64, 1088, 1600, 2112])
@@ -241,15 +274,16 @@ def test_flash_prefill_compiles_for_a_v5e(one_chip, no_compile_cache,
 
 @pytest.mark.parametrize("preset,overrides,kernels", [
     ("granite-4.0-h-micro", {},
-     ("flash_prefill_hist", "ssm_chunk", "ssm_update", "paged_decode",
-      "kv_write")),
+     ("flash_prefill_hist", "conv_segments", "ssm_chunk", "ssm_update",
+      "paged_decode", "kv_write")),
     ("kimi-vl-a3b", {"num_hidden_layers": 9},
      ("flash_prefill", "latent_prefill_hist", "grouped_matmul",
       "latent_paged_decode", "kv_write")),
     ("kimi-linear-48b-a3b",
      {"num_hidden_layers": 9, "experts_held": 64, "vocab_size": 40960},
-     ("kda_chunk", "kda_update", "flash_prefill", "latent_prefill_hist",
-      "grouped_matmul", "latent_paged_decode", "kv_write")),
+     ("conv_segments", "kda_chunk", "kda_update", "flash_prefill",
+      "latent_prefill_hist", "grouped_matmul", "latent_paged_decode",
+      "kv_write")),
     ("xing4.0-29b-a4b", {"num_hidden_layers": 8},
      ("hc_pre", "hc_post", "flash_prefill", "latent_prefill_hist",
       "grouped_matmul", "latent_paged_decode", "kv_write")),
@@ -332,6 +366,10 @@ def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
         assert f"%{name}." in text, name
     assert (compiled.memory_analysis().temp_size_in_bytes
             < step_workspace_bytes(cfg))
+    # The conv of a segment part is the kernel's: XLA has no convolution
+    # left to compute twice (until PR 47 granite's (1536, 64) step held
+    # ``%convolution_convert_fusion.N.remat`` twins).
+    assert not re.search(r"%convolution[\w.\-]*\.remat", text)
     if model.index_topk:
         # What the benchmark's readers of the indexers' device time tell
         # an indexer by: ONE conditional of the step's several returns the

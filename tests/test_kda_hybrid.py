@@ -257,9 +257,11 @@ def test_two_chunks_with_history_equal_one(params, split):
                                atol=SLOT_TOL)
 
 
-def test_a_mixed_step_matches_the_reference_and_the_two_pure_steps(params):
-    """A chunk with history beside two decode rows in ONE program, against
-    the reference and against the chunk step and the decode step alone."""
+def _mixed_step(params):
+    """A chunk with history beside two decode rows, [segment tokens | row
+    tokens]: (the three sequences, tokens, meta, the pool before it, the
+    chunk step's and the decode step's hidden states, the pool behind the
+    two)."""
     a, b, c = _tokens(12, 21), _tokens(9, 22), _tokens(30, 23)
     tok, meta = _segments([a, b], [[1], [2]], [1, 2], 32)
     _, kv0, _ = _fwd(params, tok, meta, _pool())
@@ -281,8 +283,15 @@ def test_a_mixed_step_matches_the_reference_and_the_two_pure_steps(params):
         logits_indices=jnp.asarray([16, 17, 12], jnp.int32),
         page_tables=meta_r.page_tables, context_lens=meta_r.context_lens,
         row_slots=meta_r.row_slots)
-    hid_m, kv_m, _ = _fwd(params, jnp.concatenate([tok_s, tok_r]), meta_m,
-                          kv0)
+    return ((a, b, c), jnp.concatenate([tok_s, tok_r]), meta_m, kv0, hid_s,
+            hid_r, kv_p)
+
+
+def test_a_mixed_step_matches_the_reference_and_the_two_pure_steps(params):
+    """A chunk with history beside two decode rows in ONE program, against
+    the reference and against the chunk step and the decode step alone."""
+    (a, b, c), tok, meta_m, kv0, hid_s, hid_r, kv_p = _mixed_step(params)
+    hid_m, kv_m, _ = _fwd(params, tok, meta_m, kv0)
     logits = _logits(params, hid_m)
     for row, seq in ((0, list(a) + [5]), (1, list(b) + [6]), (2, c)):
         assert float(jnp.max(jnp.abs(
@@ -293,6 +302,34 @@ def test_a_mixed_step_matches_the_reference_and_the_two_pure_steps(params):
         if got is not None:
             np.testing.assert_allclose(got[:, 1:], want[:, 1:],
                                        atol=SLOT_TOL)
+
+
+class ConvKernel(Kernels):
+    """The XLA references, but for the conv stage: the chip's kernel in
+    interpret mode."""
+
+    def conv_segments(self, *args):
+        from kubernetes_gpu_cluster_tpu.ops.pallas.conv_segments import (
+            conv_segments)
+        return conv_segments(*args, interpret=True)
+
+
+def test_a_mixed_step_through_the_conv_kernel_equals_the_xla_form(params):
+    """The same mixed step with the conv stage of its segment part as
+    ``ops/pallas/conv_segments.py`` (q, k and v leave it as the ``[T, H,
+    d]`` heads the chunked form reads; a chunk of 16 tokens with history:
+    the slot's rows before token 0): logits, slots and conv rows as the XLA
+    form's, to the order of the sums."""
+    _, tok, meta_m, kv0, *_ = _mixed_step(params)
+    hid_x, kv_x, _ = _fwd(params, tok, meta_m, kv0)
+    hid_k, kv_k, _ = jax.jit(lambda p, t, m, kv: llama.forward(
+        p, CFG, t, m, kv, ConvKernel()))(params, tok, meta_m, kv0)
+    want = _logits(params, hid_x)
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(_logits(params, hid_k), want,
+                               atol=2e-5 * scale)
+    np.testing.assert_allclose(kv_k.ssm, kv_x.ssm, atol=1e-4)
+    np.testing.assert_allclose(kv_k.conv, kv_x.conv, atol=1e-5)
 
 
 def test_a_stale_slot_does_not_leak_into_a_new_sequence(params):

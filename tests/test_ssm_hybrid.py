@@ -15,6 +15,7 @@ model without state layers carries nothing of all this.
 """
 
 import dataclasses
+import functools
 import json
 
 import jax
@@ -34,6 +35,7 @@ from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
 from kubernetes_gpu_cluster_tpu.engine.sequence import Sequence
 from kubernetes_gpu_cluster_tpu.models import llama
 from kubernetes_gpu_cluster_tpu.ops import ssm as ssm_ops
+from kubernetes_gpu_cluster_tpu.ops.attention import Kernels
 from perfbench.reference import granite_4_0_h as ref
 
 CFG = get_model_config("debug-ssm-hybrid")
@@ -197,9 +199,10 @@ def test_two_chunks_with_history_equal_one(params, split):
     np.testing.assert_allclose(kv1.conv[:, 2], kv2.conv[:, 2], atol=1e-5)
 
 
-def test_a_mixed_step_equals_the_two_pure_steps(params):
-    """A chunk with history beside two decode rows in ONE program, against
-    the chunk step and the decode step each alone."""
+def _mixed_step(params):
+    """A chunk with history beside two decode rows, [segment tokens | row
+    tokens]: (tokens, meta, the pool before it, the chunk step's and the
+    decode step's hidden states, the pool behind the two)."""
     a, b, c = _tokens(12, 21), _tokens(9, 22), _tokens(30, 23)
     tok, meta = _segments([a, b], [[1], [2]], [1, 2], 32)
     _, kv0, _ = _fwd(params, tok, meta, _pool())
@@ -212,7 +215,6 @@ def test_a_mixed_step_equals_the_two_pure_steps(params):
     hid_s, kv_p, _ = _fwd(params, tok_s, meta_s, kv0)
     tok_r, meta_r = _rows([5, 6], [12, 9], [[1], [2]], [1, 2], 4)
     hid_r, kv_p, _ = _fwd(params, tok_r, meta_r, kv_p)
-    # mixed: [segment tokens | row tokens]
     meta_m = meta_s._replace(
         seg_ids=jnp.concatenate([meta_s.seg_ids, jnp.full(4, -1)]),
         positions=jnp.concatenate([meta_s.positions, meta_r.positions]),
@@ -221,13 +223,47 @@ def test_a_mixed_step_equals_the_two_pure_steps(params):
         logits_indices=jnp.asarray([16, 17, 12], jnp.int32),
         page_tables=meta_r.page_tables, context_lens=meta_r.context_lens,
         row_slots=meta_r.row_slots)
-    hid_m, kv_m, _ = _fwd(params, jnp.concatenate([tok_s, tok_r]), meta_m,
-                          kv0)
+    return jnp.concatenate([tok_s, tok_r]), meta_m, kv0, hid_s, hid_r, kv_p
+
+
+def test_a_mixed_step_equals_the_two_pure_steps(params):
+    """A chunk with history beside two decode rows in ONE program, against
+    the chunk step and the decode step each alone."""
+    tok, meta_m, kv0, hid_s, hid_r, kv_p = _mixed_step(params)
+    hid_m, kv_m, _ = _fwd(params, tok, meta_m, kv0)
     np.testing.assert_allclose(hid_m[:2], hid_r[:2], atol=1e-5)
     np.testing.assert_allclose(hid_m[2], hid_s[0], atol=1e-5)
     for got, want in zip(kv_m, kv_p):
         if got is not None:     # (no index keys: this model has no indexer)
             np.testing.assert_allclose(got[:, 1:], want[:, 1:], atol=1e-5)
+
+
+class ConvKernel(Kernels):
+    """The XLA references, but for the conv stage: the chip's kernel in
+    interpret mode."""
+
+    def conv_segments(self, *args):
+        from kubernetes_gpu_cluster_tpu.ops.pallas.conv_segments import (
+            conv_segments)
+        return conv_segments(*args, interpret=True)
+
+
+def test_a_mixed_step_through_the_conv_kernel_equals_the_xla_form(params):
+    """The same mixed step with the conv stage of its segment part as
+    ``ops/pallas/conv_segments.py`` (a chunk of 16 tokens with history: the
+    slot's rows before token 0, a token block that ends behind the chunk):
+    logits, slots and conv rows as the XLA form's, to the order of the
+    sums."""
+    tok, meta_m, kv0, *_ = _mixed_step(params)
+    hid_x, kv_x, _ = _fwd(params, tok, meta_m, kv0)
+    hid_k, kv_k, _ = jax.jit(lambda p, t, m, kv: llama.forward(
+        p, CFG, t, m, kv, ConvKernel()))(params, tok, meta_m, kv0)
+    want = _logits(params, hid_x)
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(_logits(params, hid_k), want,
+                               atol=2e-5 * scale)
+    np.testing.assert_allclose(kv_k.ssm, kv_x.ssm, atol=1e-4)
+    np.testing.assert_allclose(kv_k.conv, kv_x.conv, atol=1e-5)
 
 
 def test_padding_rows_touch_only_the_scrap_slot(params):
@@ -340,6 +376,87 @@ def test_pallas_update_in_interpret_mode_equals_the_reference():
                                   np.asarray(pool)[untouched])
 
 
+# -- the conv stage, alone ------------------------------------------------------
+
+# The two kinds' pieces at widths of whole tiles of their own: [x | B | C]
+# in the model's dtype, and unit q (scaled), unit k, v as float32 heads.
+CONV_SPLITS = {
+    "mamba": (288, ssm_ops.ConvSplit((256, 16, 16))),
+    "kda": (384, ssm_ops.ConvSplit((128,) * 3, jnp.float32, 32,
+                                   (32 ** -0.5, 1.0, None))),
+}
+CONV_SEGMENTS = {
+    "one": lambda T: [(0, T)],
+    "three-packed": lambda T: [(0, T // 2), (T // 2, T // 2 + 2),
+                               (T // 2 + 2, T)],
+    "padding-tail": lambda T: [(0, T // 3), (T // 3, T - 45)],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no-bias"])
+@pytest.mark.parametrize("history", [False, True], ids=["zeros", "slot-rows"])
+@pytest.mark.parametrize("segments", list(CONV_SEGMENTS))
+@pytest.mark.parametrize("T", [512, 328], ids=["whole-blocks", "ragged"])
+@pytest.mark.parametrize("kind", list(CONV_SPLITS))
+def test_the_conv_kernel_in_interpret_mode_equals_the_xla_form(
+        kind, T, segments, history, bias, dtype):
+    """``ops/pallas/conv_segments.py`` against ``conv_operands_xla``: two
+    token blocks and one and a part (the rows behind the segment part are
+    the step's row tokens), one segment, three packed of which one is
+    shorter than the taps reach, a padding tail; from zeros and from a
+    slot's rows; with and without bias; both dtypes. The taps' sum BITWISE
+    (the same products in the same order; the inputs are whole bfloat16
+    values in either dtype, so that a product is exact and the CPU's fused
+    multiply-adds, which the two programs place differently, round as the
+    plain ones do), the activated pieces within 2 units in the last place
+    of float32 (unit q and k within 4: the lane sums' order differs), an
+    output in bfloat16 equal or its neighbour; and the new conv rows both
+    forms share (``segment_conv_rows``) bitwise a segment's last three
+    inputs, the slot's rows or zeros where it is shorter."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.conv_segments import (
+        conv_segments)
+    C, split = CONV_SPLITS[kind]
+    k = jax.random.split(jax.random.key(T), 4)
+    draw = lambda key, shape: jax.random.normal(key, shape).astype(
+        jnp.bfloat16).astype(dtype)
+    xbc, w = draw(k[0], (T + 8, C)), draw(k[1], (4, C))
+    b = draw(k[2], (C,)) if bias else None
+    init = draw(k[3], (3, C)) if history else jnp.zeros((3, C), dtype)
+    bounds = CONV_SEGMENTS[segments](T)
+    seg = np.full(T, -1, np.int32)
+    for s, (lo, hi) in enumerate(bounds):
+        seg[lo:hi] = s
+    args = (xbc, jnp.asarray(seg), init, w, b)
+
+    got_sum = conv_segments(*args, split, activate=False, interpret=True)
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(p).reshape(T, -1) for p in got_sum], 1),
+        np.asarray(ssm_ops.conv_segments(xbc[:T], *args[1:])))
+
+    ends = jnp.asarray([hi - 1 for _, hi in bounds]
+                       + [-1] * (4 - len(bounds)), jnp.int32)
+    rows = ssm_ops.segment_conv_rows(xbc, args[1], ends, init)
+    assert rows.dtype == xbc.dtype and rows.shape == (4, 3, C)
+    ext = np.concatenate([np.asarray(init.astype(jnp.float32)),
+                          np.asarray(xbc.astype(jnp.float32))])
+    for s, (lo, hi) in enumerate(bounds):       # ext row r is token r - 3
+        own = ext[hi:hi + 3] * (np.arange(hi - 3, hi) >= (lo and lo))[:, None]
+        own[:max(lo + 3 - hi, 0)] *= lo == 0    # before token 0: the slot's
+        np.testing.assert_array_equal(
+            np.asarray(rows[s].astype(jnp.float32)), own)
+
+    want = ssm_ops.conv_operands_xla(*args, split)
+    got = conv_segments(*args, split, interpret=True)
+    for i, (g, w_) in enumerate(zip(got, want)):
+        assert g.shape == w_.shape and g.dtype == w_.dtype
+        unit = bool(split.unit) and split.unit[i] is not None
+        limit = 1 if g.dtype == jnp.bfloat16 else 4 if unit else 2
+        assert _chain_gate().ulps(g, w_) <= limit, i
+
+
+@functools.lru_cache(maxsize=None)
 def _chain_gate():
     """``benchmarks/tpu_kernel_check.py``'s gate of the state's precision
     (what the chip runs over the Pallas update at the published widths),
