@@ -86,7 +86,11 @@ class SchedulerConfig:
     max_num_seqs: int = 64             # max sequences resident per step
     max_prefill_tokens: int = 2048     # token budget per prefill step
     # Shape bucketing to keep the XLA jit cache small: decode batch sizes and
-    # prefill token counts are padded up to these buckets.
+    # prefill token counts are padded up to these buckets. The ladder is the
+    # grid, not the server: with fewer seats than its top (a long-context
+    # server at 8, 16 or 32) no step is built past the seats' own bucket
+    # (``seat_bucket``), which is also what bounds a mixed step's row floor
+    # (``engine.mixed_batch.mixed_row_bucket``).
     decode_buckets: tuple[int, ...] = (1, 2, 4, 8, 16, 32, 64)
     prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 2048)
     # Multi-step decode: run this many autoregressive decode steps inside one
@@ -167,6 +171,16 @@ class SchedulerConfig:
         the proposer is built for."""
         return (self.spec_k_max if self.spec_k_max is not None
                 else self.num_speculative_tokens)
+
+    @property
+    def seat_bucket(self) -> int:
+        """The smallest bucket of ``decode_buckets`` that holds every seat
+        (``max_num_seqs``); the ladder's top where the seats exceed it (such
+        a server keeps the legacy policy for the steps the ladder does not
+        cover). With the seats inside the ladder no mixed step is built for
+        more rows: it bounds the floor of a mixed step's row bucket."""
+        return next((b for b in self.decode_buckets
+                     if b >= self.max_num_seqs), self.decode_buckets[-1])
 
     @property
     def mixed_chunk_buckets(self) -> tuple[int, ...]:

@@ -2246,7 +2246,7 @@ class LLMEngine:
         for Tp, width in steps:
             batch = padding_mixed_batch(
                 self.scheduler, Tp,
-                mixed_row_bucket(sc.max_num_seqs, Tp, sc.decode_buckets),
+                mixed_row_bucket(sc.max_num_seqs, Tp, sc),
                 width)
             for _ in range(2):
                 # the second behind the first, as in warm_full_window

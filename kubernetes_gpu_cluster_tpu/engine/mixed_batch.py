@@ -30,12 +30,14 @@ Unified ragged layout over one padded token axis ``[Tp_bucket | R_pad]``:
     logits_indices [R_pad]  sampled rows: decode row i at Tp_bucket + i,
                             the chunk's last token at chunk_len - 1
 
-Sampling rows always include the chunk row (R = D + 1, bucketed by the
-decode buckets) so the compiled shape depends only on (Tp_bucket, R_pad,
-hist width) — bounded like every other jit shape in the engine. A partial
+Sampling rows include the chunk row (R = D + 1, bucketed by the decode
+buckets) so the compiled shape depends only on (Tp_bucket, R_pad, hist
+width) — bounded like every other jit shape in the engine. A partial
 chunk's sampled token is discarded by the engine (same contract as the
 solo chunked-prefill path); a final chunk's sampled token is the
-sequence's first generated token.
+sequence's first generated token. The one step without a chunk row: a
+partial chunk beside a server whose every seat decodes, where that row
+would be the only one past the seats' bucket (``mixed_row_bucket``).
 
 Invariants preserved from the legacy policy:
 
@@ -68,6 +70,7 @@ import numpy as np
 from ..utils import cdiv, get_logger
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard (scheduler imports us)
+    from ..config import SchedulerConfig
     from .scheduler import ScheduledBatch, Scheduler
 
 logger = get_logger("mixed_batch")
@@ -112,19 +115,34 @@ def plan_chunk_tokens(remaining: int, n_decode: int, budget: Optional[int],
 
 
 def mixed_row_bucket(rows: int, chunk_bucket: int,
-                     decode_buckets: tuple[int, ...]) -> int:
+                     sc: "SchedulerConfig") -> int:
     """The sampled-row bucket of a mixed step: the decode bucket of ``rows``,
     but never under a sixteenth of the chunk's bucket, so padding rows stay
-    under 1/16 of the step's tokens (their attention meets no page). Each
-    (chunk bucket x row bucket) is a step program of its own to compile, to
-    keep in the compile cache and to load at every start: 42 on the default
-    grid (``mixed_chunk_buckets``: six chunk buckets), 12 with this floor,
-    and a server whose seats fill behind prompts of 1-2 k tokens meets one
-    program a chunk bucket where it met seven."""
+    under 1/16 of the step's tokens, and that floor never over the bucket of
+    the server's seats (``SchedulerConfig.seat_bucket``): a step is not
+    built for rows that no seat can fill. Each (chunk bucket x row bucket)
+    is a step program of its own to compile, to keep in the compile cache
+    and to load at every start: 42 on the default grid
+    (``mixed_chunk_buckets``: six chunk buckets), 12 with this floor at 64
+    seats, and a server whose seats fill behind prompts of 1-2 k tokens
+    meets one program a chunk bucket where it met seven.
+
+    A padding row is cheap, not free. Dense and latent attention skip it
+    (its context is 0: it meets no page) and it costs its share of the
+    projections, the head and the sampler. A sparse-attention model's
+    choice has no such skip: the indexer scores, sorts and looks up
+    ``index_topk`` positions for every row of the bucket, and every layer
+    gathers and attends over that many latent rows a row, padding or not
+    (glm-5.2 on 16 seats beside a 2048-token chunk: 64 rows where 16 exist
+    were an eighth of the step, PERF.md section 6, PR 48). Hence the seats'
+    bucket and not the ladder's top. (One row more than the seats' bucket
+    would exist where every seat decodes and a waiting head's chunk is not
+    its last: ``build_mixed_batch`` leaves that chunk's row out, it samples
+    nothing that counts.)"""
     from .scheduler import _bucket
 
-    floor = min(chunk_bucket // 16, decode_buckets[-1])
-    return _bucket(max(rows, floor), decode_buckets)
+    floor = min(chunk_bucket // 16, sc.seat_bucket)
+    return _bucket(max(rows, floor), sc.decode_buckets)
 
 
 def mixed_steps_of_prompt(sched: "Scheduler", n: int) -> list:
@@ -252,7 +270,12 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
 
     D = len(decode_seqs)
     Tp = _bucket(chunk, sc.mixed_chunk_buckets)
-    R_pad = mixed_row_bucket(D + 1, Tp, sc.decode_buckets)
+    # A chunk that is not its prompt's last samples nothing that counts
+    # (the engine discards it). Beside a server whose every seat decodes,
+    # its row would be the one row past the seats' bucket: a step program
+    # of its own, twice the rows, that no warm-up meets. It is left out.
+    chunk_row = final or D < sc.seat_bucket
+    R_pad = mixed_row_bucket(D + chunk_row, Tp, sc)
     T_pad = Tp + R_pad
 
     tokens = np.zeros(T_pad, np.int32)
@@ -284,7 +307,8 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     # -- sampled rows -------------------------------------------------------
     logits_indices = np.zeros(R_pad, np.int32)
     logits_indices[:D] = Tp + np.arange(D)
-    logits_indices[D] = chunk - 1          # the chunk's last token's hidden
+    if chunk_row:
+        logits_indices[D] = chunk - 1      # the chunk's last token's hidden
 
     # -- chunk progress bookkeeping (mirrors Scheduler._schedule_chunk) -----
     hist_len = _commit_chunk_progress(sched, head, end, D, final,
@@ -300,7 +324,7 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
         hist_len=hist_len, partial=not final, prefill_token_count=chunk,
         seg_slots=sched._state_slots([head], R_pad),
         row_slots=sched._state_slots(decode_seqs, R_pad),
-        **sched._sampling_arrays(seqs, R_pad))
+        **sched._sampling_arrays(seqs if chunk_row else decode_seqs, R_pad))
 
 
 def padding_mixed_batch(sched: "Scheduler", Tp: int, R_pad: int,

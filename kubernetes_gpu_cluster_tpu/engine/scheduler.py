@@ -104,13 +104,15 @@ class ScheduledBatch:
 
     def device_seq_rows(self):
         """(device row, seq) pairs — identity except for the spec_mixed
-        chunk row remap. The seam engine-side per-seq array builders
-        iterate so one spelling serves every batch kind."""
+        chunk row remap, and for a mixed step's partial chunk beside full
+        seats, which has no row (``build_mixed_batch``). The seam
+        engine-side per-seq array builders iterate so one spelling serves
+        every batch kind."""
         for s, seq in enumerate(self.seqs):
             if (self.chunk_device_row is not None
                     and s == len(self.seqs) - 1):
                 yield self.chunk_device_row, seq
-            else:
+            elif s < len(self.temperature):
                 yield s, seq
     # sampling arrays [B_pad]
     temperature: Optional[np.ndarray] = None

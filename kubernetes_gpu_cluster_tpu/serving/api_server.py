@@ -2323,7 +2323,12 @@ def main(argv: Optional[list[str]] = None) -> None:
                    help="fraction the KV page pool takes of the HBM that "
                    "is free once the weights are resident and one step's "
                    "workspace is set aside")
-    p.add_argument("--max-num-seqs", type=int, default=64)
+    p.add_argument("--max-num-seqs", type=int, default=64,
+                   help="the seats: sequences resident a step. Also what "
+                   "the step programs are built for: the decode window at "
+                   "full seats and a mixed step's row floor ride the "
+                   "smallest row bucket that holds them, not the ladder's "
+                   "top")
     p.add_argument("--warm-prompt-lens", default="1",
                    help="comma-separated prompt lengths in tokens whose "
                    "mixed steps beside full seats are met (compiled, or "

@@ -272,6 +272,83 @@ def test_flash_prefill_compiles_for_a_v5e(one_chip, no_compile_cache,
             <= T * (nh * (hd + hv) + nkv * (hd + hv)) * 2 + 2**20)
 
 
+def _compiled_mixed_step(one_chip, cfg, pages, prompt_len, steps=1):
+    """The mixed step program the ``steps``-th chunk of a prompt of
+    ``prompt_len`` tokens rides beside full seats under ``cfg``, compiled
+    for the described chip with every kernel on: the scheduler's own batch
+    gives the shapes, no weight is drawn. Returns (compiled, batch)."""
+    import numpy as np
+
+    from kubernetes_gpu_cluster_tpu.engine import SamplingParams
+    from kubernetes_gpu_cluster_tpu.engine.engine import (
+        LLMEngine, _pack_float_b, _pack_int_b)
+    from kubernetes_gpu_cluster_tpu.engine.kv_cache import (
+        allocate_kv_cache, default_state_slots)
+    from kubernetes_gpu_cluster_tpu.engine.sampling_params import (
+        LOGIT_BIAS_CAP)
+    from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
+    from kubernetes_gpu_cluster_tpu.engine.sequence import Sequence
+    from kubernetes_gpu_cluster_tpu.models import llama as model_lib
+    from kubernetes_gpu_cluster_tpu.ops.attention import Kernels
+
+    model, seats = cfg.model, cfg.scheduler.max_num_seqs
+    slots = default_state_slots(model, seats)
+    sched = Scheduler(cfg, pages, num_state_slots=slots)
+    rows = [Sequence(f"r{i}", [1, 2, 3, 4], SamplingParams(max_tokens=64))
+            for i in range(seats - 1)]
+    for seq in rows:
+        sched.add(seq)
+    assert sched.schedule().kind == "prefill"
+    sched.add(Sequence("head", list(range(1, prompt_len + 1)),
+                       SamplingParams(max_tokens=64)))
+    for _ in range(steps):
+        for seq in rows:
+            seq.append_token(7)
+        batch = sched.schedule()
+        assert batch.kind == "mixed"
+
+    # an engine's step program without an engine's weights
+    shell = object.__new__(LLMEngine)
+    shell.config, shell.model_config, shell.mesh = cfg, model, None
+    shell.kernels = Kernels(use_pallas=True, use_pallas_hist=True,
+                            grouped_experts=model.is_moe)
+    shell._last_width = cfg.scheduler.decode_buckets[-1]
+    B = len(batch.temperature)
+    args = (jax.eval_shape(
+                lambda: model_lib.init_params(model, jax.random.key(0))),
+            jax.eval_shape(
+                lambda: allocate_kv_cache(model, cfg.cache, pages, None,
+                                          slots)),
+            np.zeros(shell._last_width, np.int32),
+            np.stack([batch.tokens, batch.seg_ids, batch.positions,
+                      batch.slot_mapping]),
+            _pack_int_b(batch), _pack_float_b(batch), batch.chunk_page_table,
+            np.int32(batch.hist_len), batch.page_tables, batch.context_lens,
+            np.full((B, cfg.effective_max_len), -1, np.int32),
+            np.full((B, LOGIT_BIAS_CAP), -1, np.int32),
+            np.zeros((B, LOGIT_BIAS_CAP), np.float32),
+            jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = shell._build_mixed_fn().lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        args)).compile()
+    return compiled, batch
+
+
+def _cut(preset, overrides, seats, max_model_len, pages):
+    from kubernetes_gpu_cluster_tpu.config import (
+        CacheConfig, EngineConfig, SchedulerConfig, apply_hf_overrides,
+        get_model_config)
+    model = apply_hf_overrides(get_model_config(preset),
+                               overrides).replace(dtype="bfloat16")
+    return EngineConfig(model=model, max_model_len=max_model_len,
+                        cache=CacheConfig(page_size=128, num_pages=pages),
+                        scheduler=SchedulerConfig(max_num_seqs=seats))
+
+
+GLM_CUT = {"num_hidden_layers": 6, "layers_from": 2,
+           "experts_held": 16, "vocab_size": 19360}
+
+
 @pytest.mark.parametrize("preset,overrides,kernels", [
     ("granite-4.0-h-micro", {},
      ("flash_prefill_hist", "conv_segments", "ssm_chunk", "ssm_update",
@@ -289,10 +366,7 @@ def test_flash_prefill_compiles_for_a_v5e(one_chip, no_compile_cache,
       "grouped_matmul", "latent_paged_decode", "kv_write")),
     # the indexers and the chosen rows' attention are XLA's (ops/dsa.py):
     # of the kernels the step holds the experts' and the two page writes
-    ("glm-5.2",
-     {"num_hidden_layers": 6, "layers_from": 2,
-      "experts_held": 16, "vocab_size": 19360},
-     ("grouped_matmul", "kv_write"))])
+    ("glm-5.2", GLM_CUT, ("grouped_matmul", "kv_write"))])
 def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
         one_chip, no_compile_cache, preset, overrides, kernels):
     """The WHOLE mixed step program a prompt of 1025-1536 tokens rides beside
@@ -300,67 +374,12 @@ def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
     cells serve, with every kernel on: the scheduler's own batch gives the
     shapes, no weight is drawn. Its scratch stays inside what the engine
     sets aside for a step (``step_workspace_bytes``, sized at 2048 + 64)."""
-    import numpy as np
+    from kubernetes_gpu_cluster_tpu.engine.engine import step_workspace_bytes
 
-    from kubernetes_gpu_cluster_tpu.config import (
-        CacheConfig, EngineConfig, SchedulerConfig, apply_hf_overrides,
-        get_model_config)
-    from kubernetes_gpu_cluster_tpu.engine import SamplingParams
-    from kubernetes_gpu_cluster_tpu.engine.engine import (
-        LLMEngine, _pack_float_b, _pack_int_b, step_workspace_bytes)
-    from kubernetes_gpu_cluster_tpu.engine.kv_cache import (
-        allocate_kv_cache, default_state_slots)
-    from kubernetes_gpu_cluster_tpu.engine.sampling_params import (
-        LOGIT_BIAS_CAP)
-    from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
-    from kubernetes_gpu_cluster_tpu.engine.sequence import Sequence
-    from kubernetes_gpu_cluster_tpu.models import llama as model_lib
-    from kubernetes_gpu_cluster_tpu.ops.attention import Kernels
-
-    model = apply_hf_overrides(get_model_config(preset),
-                               overrides).replace(dtype="bfloat16")
-    pages, seats = 2049, 64
-    cfg = EngineConfig(model=model, max_model_len=4096,
-                       cache=CacheConfig(page_size=128, num_pages=pages),
-                       scheduler=SchedulerConfig(max_num_seqs=seats))
-    slots = default_state_slots(model, seats)
-    sched = Scheduler(cfg, pages, num_state_slots=slots)
-    rows = [Sequence(f"r{i}", [1, 2, 3, 4], SamplingParams(max_tokens=64))
-            for i in range(seats - 1)]
-    for seq in rows:
-        sched.add(seq)
-    assert sched.schedule().kind == "prefill"
-    for seq in rows:
-        seq.append_token(7)
-    sched.add(Sequence("head", list(range(1, 1101)),
-                       SamplingParams(max_tokens=64)))
-    batch = sched.schedule()
-    assert batch.kind == "mixed" and len(batch.tokens) == 1536 + seats
-
-    # an engine's step program without an engine's weights
-    shell = object.__new__(LLMEngine)
-    shell.config, shell.model_config, shell.mesh = cfg, model, None
-    shell.kernels = Kernels(use_pallas=True, use_pallas_hist=True,
-                            grouped_experts=model.is_moe)
-    shell._last_width = seats
-    B = len(batch.temperature)
-    args = (jax.eval_shape(
-                lambda: model_lib.init_params(model, jax.random.key(0))),
-            jax.eval_shape(
-                lambda: allocate_kv_cache(model, cfg.cache, pages, None,
-                                          slots)),
-            np.zeros(seats, np.int32),
-            np.stack([batch.tokens, batch.seg_ids, batch.positions,
-                      batch.slot_mapping]),
-            _pack_int_b(batch), _pack_float_b(batch), batch.chunk_page_table,
-            np.int32(batch.hist_len), batch.page_tables, batch.context_lens,
-            np.full((B, cfg.effective_max_len), -1, np.int32),
-            np.full((B, LOGIT_BIAS_CAP), -1, np.int32),
-            np.zeros((B, LOGIT_BIAS_CAP), np.float32),
-            jax.eval_shape(lambda: jax.random.key(0)))
-    compiled = shell._build_mixed_fn().lower(*jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        args)).compile()
+    cfg = _cut(preset, overrides, 64, 4096, 2049)
+    model = cfg.model
+    compiled, batch = _compiled_mixed_step(one_chip, cfg, 2049, 1100)
+    assert len(batch.tokens) == 1536 + 64
     text = compiled.as_text()
     for name in kernels:
         assert f"%{name}." in text, name
@@ -381,3 +400,27 @@ def test_mixed_step_at_the_chunk_rung_compiles_for_a_v5e(
                  if " conditional(" in line]
         assert sum(is_indexer_conditional(c, model.index_topk)
                    for c in conds) == 1 < len(conds)
+
+
+def test_glm_chunk_step_is_built_for_the_servers_16_seats(one_chip,
+                                                          no_compile_cache):
+    """The chunk step of the ``glm-5.2-bf16.batch-long-8k`` cell's server
+    (16 seats under the default ladder, 12288 positions, 1537 pages): a full
+    chunk with history beside 15 rows is (2048, 16). The rows' part chooses,
+    gathers and attends for the 16 rows the seats can fill,
+    ``[16, 2048, 640]`` (what ``dsa_chosen_attend_hbm_share`` reads), and
+    holds nothing of 64 rows: until PR 48 the row floor stood at the
+    ladder's top and every chunk step paid for 48 rows that no seat had."""
+    cfg = _cut("glm-5.2", GLM_CUT, 16, 12288, 1537)
+    model = cfg.model
+    compiled, batch = _compiled_mixed_step(one_chip, cfg, 1537, 8064, steps=3)
+    assert len(batch.tokens) == 2048 + 16 and batch.hist_len == 2 * 2033
+    assert batch.chunk_page_table.shape == (1, 64)
+    text = compiled.as_text()
+    k, w = model.index_topk, 640
+    assert (k, model.kv_row_padded) == (2048, w)
+    assert f"bf16[16,{k},{w}]" in text
+    for rows in (32, 64):
+        for shape in (f"[{rows},{k},{w}]", f"[{rows * k},{w}]",
+                      f"[{rows},12289]", f"[{rows},{k}]"):
+            assert shape not in text, shape

@@ -31,6 +31,10 @@ from kubernetes_gpu_cluster_tpu.engine import LLMEngine, SamplingParams
 from kubernetes_gpu_cluster_tpu.engine import kv_cache as kvc
 from kubernetes_gpu_cluster_tpu.engine import weights
 from kubernetes_gpu_cluster_tpu.engine.engine import step_workspace_bytes
+from kubernetes_gpu_cluster_tpu.engine.mixed_batch import (
+    mixed_row_bucket, mixed_steps_of_prompt)
+from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
+from kubernetes_gpu_cluster_tpu.engine.sequence import Sequence
 from kubernetes_gpu_cluster_tpu.models import llama
 from kubernetes_gpu_cluster_tpu.ops import dsa
 from perfbench.reference import glm5_2 as ref
@@ -372,9 +376,6 @@ def test_the_cells_flags_warm_every_mixed_step_its_chunks_can_ride():
     4416-token probe passes through, and none it cannot."""
     from pathlib import Path
 
-    from kubernetes_gpu_cluster_tpu.engine.mixed_batch import (
-        mixed_steps_of_prompt)
-    from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
     doc = json.loads((Path(__file__).parent.parent / "perfbench" / "configs"
                       / "glm-5.2-bf16.json").read_text())
     flags = doc["server_flags"]
@@ -394,6 +395,71 @@ def test_the_cells_flags_warm_every_mixed_step_its_chunks_can_ride():
     # every prompt length of the traffic, beside 15 rows, rides these
     for n in range(7168, 8065, 7):
         assert set(mixed_steps_of_prompt(sched, n)) <= warmed, n
+    # "beside a chunk of 1024 tokens and more the engine pads the rows to 16
+    # whatever their count" (the configuration's ``prefill_buckets_why``):
+    # the server's seats, not the ladder's 64: every row of the bucket is
+    # chosen for, gathered for and attended for in every layer
+    sc = cfg.scheduler
+    assert sc.decode_buckets[-1] == 64 and sc.seat_bucket == 16
+    assert sc.seat_bucket == doc["warmup"]["decode_buckets"][-1]
+    for rung, _ in warmed - {(128, 1)}:
+        assert {mixed_row_bucket(rows, rung, sc)
+                for rows in range(1, 17)} == {16}, rung
+
+
+@pytest.mark.parametrize("seats", [16, 64])
+def test_warm_up_meets_the_programs_the_scheduler_builds(params, seats):
+    """``warm_mixed_steps`` and ``build_mixed_batch`` take a mixed step's
+    row bucket from one place (``mixed_row_bucket`` over the scheduler's
+    configuration): the (chunk rung, row bucket, history width) the warm-up
+    dispatches for ``warm_prompt_lens`` ARE the ones the scheduler builds
+    for prompts of those lengths beside full seats (one seat left for the
+    prompt, or every seat decoding and the prompt waiting), at 16 seats
+    (the floor stops at the seats' bucket, and a chunk beside 16 decoding
+    rows has no row of its own) as at 64 (the ladder's top): no mixed step
+    of such a prompt compiles under open streams."""
+    lens = (1, 300, 511)
+    eng = _engine(params, pages=160, max_num_seqs=seats,
+                  max_prefill_tokens=512, prefill_buckets=(128, 256, 512),
+                  decode_buckets=(1, 2, 4, 8, 16, 32, 64),
+                  warm_prompt_lens=lens)
+    def shape(b):
+        return (len(b.tokens) - len(b.context_lens), len(b.context_lens),
+                b.chunk_page_table.shape[1])
+    warmed = set()
+
+    def spy(rec, prev, float_b, key):
+        warmed.add(shape(rec["batch"]))
+        rec["last"] = prev
+    eng._dispatch_prefill = spy
+    eng.warm_mixed_steps()
+
+    built = set()
+    for n, running in [(n, r) for n in lens for r in (seats - 1, seats)]:
+        sched = Scheduler(eng.config, 160)
+        rows = [Sequence(f"r{i}", [5 + i], SamplingParams(max_tokens=64))
+                for i in range(running)]
+        for seq in rows:
+            sched.add(seq)
+        assert sched.schedule().kind == "prefill"
+        head = Sequence("head", _prompt(n, n), SamplingParams(max_tokens=4))
+        sched.add(head)
+        while head in sched.waiting:
+            for seq in rows:
+                seq.append_token(9)
+            batch = sched.schedule()
+            if running == seats and batch.kind == "decode":
+                # every seat decodes: a last chunk waits for a seat, and
+                # at the ladder's top so does every chunk
+                assert seats == 64 or head.num_prefilled > n - 512
+                break
+            assert batch.kind == "mixed" and len(batch.seqs) == running + 1
+            built.add(shape(batch))
+    assert built == warmed
+    # a prompt inside a page; 300 and 497 tokens on the rung of 512 over
+    # 19 and 32 pages; the 14 tokens left of 511 behind them
+    assert warmed == {(128, seats, 1), (512, seats, 32), (128, seats, 32)}
+    assert {r for _, r, _ in warmed} == {eng.config.scheduler.seat_bucket}
 
 
 def test_layers_from_slices_every_per_layer_list_of_any_model():

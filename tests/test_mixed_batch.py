@@ -26,13 +26,14 @@ from kubernetes_gpu_cluster_tpu.engine.sequence import (Sequence,
 
 
 def _cfg(mixed=True, num_pages=65, page_size=4, max_num_seqs=4,
-         max_prefill_tokens=16, budget=None, decode_window=2):
+         max_prefill_tokens=16, budget=None, decode_window=2,
+         decode_buckets=(1, 2, 4)):
     return EngineConfig(
         model=get_model_config("debug-tiny"),
         cache=CacheConfig(page_size=page_size, num_pages=num_pages),
         scheduler=SchedulerConfig(
             max_num_seqs=max_num_seqs, max_prefill_tokens=max_prefill_tokens,
-            decode_buckets=(1, 2, 4), prefill_buckets=(16, 32, 64),
+            decode_buckets=decode_buckets, prefill_buckets=(16, 32, 64),
             decode_window=decode_window, mixed_batch_enabled=mixed,
             decode_priority_token_budget=budget))
 
@@ -54,33 +55,75 @@ class TestPolicy:
         # explicit smaller budget wins
         assert plan_chunk_tokens(100, 1, 8, 16) == 7
 
-    @pytest.mark.parametrize("rows,chunk_bucket,want", [
-        (1, 2048, 64),    # a long prompt's padding rows: 3 % of the step
-        (33, 1024, 64),
-        (2, 512, 32),
-        (40, 512, 64),    # more rows than the floor: their own bucket
-        (2, 128, 8),
-        (9, 128, 16),
-        (2, 16, 2),       # a sixteenth of the chunk under every row count
+    @pytest.mark.parametrize("rows,chunk_bucket,seats,want", [
+        (1, 2048, 64, 64),    # a long prompt's padding rows: 3 % of the step
+        (33, 1024, 64, 64),
+        (2, 512, 64, 32),
+        (40, 512, 64, 64),    # more rows than the floor: their own bucket
+        (2, 128, 64, 8),
+        (9, 128, 64, 16),
+        (2, 16, 64, 2),       # a sixteenth of the chunk under every row count
+        # 16 seats: the floor stops at the seats' bucket, whatever the chunk
+        *[(rows, chunk, 16, 16) for chunk in (2048, 1536, 1024, 512)
+          for rows in (1, 15, 16)],
+        (1, 128, 16, 8),      # under the seats' bucket the rule is the old one
+        (15, 128, 16, 16),
+        (16, 128, 16, 16),
+        (17, 2048, 16, 32),   # every seat decoding beside a chunk not the last
+        # 24 seats sit in the bucket of 32
+        (1, 2048, 24, 32),
+        (24, 1536, 24, 32),
+        (2, 512, 24, 32),
+        (2, 128, 24, 8),
+        # more seats than the ladder's top: the top, as before
+        (1, 2048, 128, 64),
+        (33, 1024, 128, 64),
+        (2, 512, 128, 32),
+        (9, 128, 128, 16),
     ])
     def test_row_bucket_floor_is_a_sixteenth_of_the_chunk(self, rows,
-                                                          chunk_bucket, want):
-        grid = (1, 2, 4, 8, 16, 32, 64)
-        assert mixed_row_bucket(rows, chunk_bucket, grid) == want
+                                                          chunk_bucket, seats,
+                                                          want):
+        """... and never over the bucket of the server's seats: a step is
+        not built for rows that no seat can fill."""
+        sc = SchedulerConfig(max_num_seqs=seats)
+        assert sc.decode_buckets == (1, 2, 4, 8, 16, 32, 64)
+        assert mixed_row_bucket(rows, chunk_bucket, sc) == want
 
-    def test_row_bucket_floor_bounds_the_program_family(self):
+    @pytest.mark.parametrize("seats,top,grid,want", [
+        (64, 64, None, 64), (16, 64, None, 16), (24, 64, None, 32),
+        (1, 64, None, 1), (128, 64, None, 64), (65, 64, None, 64),
+        (4, 4, (1, 2, 4), 4), (3, 4, (1, 2, 4), 4), (6, 4, (1, 2, 4), 4),
+    ])
+    def test_seat_bucket_is_the_seats_rung_of_the_ladder(self, seats, top,
+                                                         grid, want):
+        kw = {} if grid is None else {"decode_buckets": grid}
+        sc = SchedulerConfig(max_num_seqs=seats, **kw)
+        assert sc.decode_buckets[-1] == top
+        assert sc.seat_bucket == want
+
+    @pytest.mark.parametrize("seats,programs", [
+        (64, {(128, 8), (128, 16), (128, 32), (128, 64),
+              (256, 16), (256, 32), (256, 64), (512, 32), (512, 64),
+              (1024, 64), (1536, 64), (2048, 64)}),
+        (16, {(128, 8), (128, 16), (256, 16), (512, 16),
+              (1024, 16), (1536, 16), (2048, 16)}),
+    ])
+    def test_row_bucket_floor_bounds_the_program_family(self, seats,
+                                                        programs):
         """(chunk bucket x row bucket) on the default grid, the rung at 1536
-        tokens among the chunk's: 12 step programs where every row bucket
-        beside every chunk bucket made 42."""
-        sc = SchedulerConfig()
+        tokens among the chunk's: 12 step programs at 64 seats where every
+        row bucket beside every chunk bucket made 42; at 16 seats seven,
+        none of them for more rows than the seats' bucket."""
+        sc = SchedulerConfig(max_num_seqs=seats)
         assert len(sc.mixed_chunk_buckets) * len(sc.decode_buckets) == 42
-        met = {(t, mixed_row_bucket(r, t, sc.decode_buckets))
+        met = {(t, mixed_row_bucket(r, t, sc))
                for t in sc.mixed_chunk_buckets
-               for r in range(1, sc.decode_buckets[-1] + 1)}
-        assert len(met) == 12
-        assert (1536, 64) in met
-        assert all(16 * rows >= min(t, 16 * sc.decode_buckets[-1])
-                   for t, rows in met)
+               for r in range(1, seats + 1)}
+        assert met == programs
+        assert (1536, sc.seat_bucket) in met
+        assert all(16 * rows >= min(t, 16 * sc.seat_bucket)
+                   and rows <= sc.seat_bucket for t, rows in met)
 
     def test_mixed_only_when_decode_and_prefill_coexist(self):
         sched = Scheduler(_cfg(), 65)
@@ -148,6 +191,42 @@ class TestPolicy:
         sched.add(_seq("long", 40))                   # chunkable head
         batch = sched.schedule()
         assert batch.kind != "mixed"
+
+    def test_partial_chunk_beside_full_seats_gives_up_its_row(self):
+        """Fewer seats than the ladder's top, every one decoding, a long
+        prompt waiting: its chunks that are not the last ride mixed steps in
+        the SEATS' bucket, without a sampled row of their own (what they
+        would sample is discarded anyway; with it the step would be the one
+        program past the seats' bucket, which no warm-up meets). The last
+        chunk needs a seat and waits for one."""
+        sched = Scheduler(_cfg(max_num_seqs=4, decode_buckets=(1, 2, 4, 8)),
+                          65)
+        assert sched.config.scheduler.seat_bucket == 4
+        seqs = [_seq(f"r{i}", 4) for i in range(4)]
+        for s in seqs:
+            sched.add(s)
+        assert sched.schedule().kind == "prefill"
+        long = _seq("long", 30)
+        sched.add(long)
+        for done in (12, 24):                  # budget 16 - 4 decode rows
+            for s in seqs:
+                s.append_token(9)
+            batch = sched.schedule()
+            assert batch.kind == "mixed" and batch.partial
+            assert batch.seqs == seqs + [long] and long.num_prefilled == done
+            assert batch.tokens.shape == (16 + 4,)
+            np.testing.assert_array_equal(batch.logits_indices,
+                                          16 + np.arange(4))
+            assert len(batch.temperature) == len(batch.context_lens) == 4
+            assert [seq for _, seq in batch.device_seq_rows()] == seqs
+        for s in seqs:
+            s.append_token(9)
+        assert sched.schedule().kind == "decode"    # no seat for the head
+        sched.finish(seqs[0], "stop")
+        batch = sched.schedule()                    # 3 rows + the last chunk
+        assert batch.kind == "mixed" and not batch.partial
+        assert batch.tokens.shape == (16 + 4,)
+        assert batch.logits_indices[3] == 30 - 24 - 1
 
     def test_budget_full_of_decodes_falls_back_to_pure_decode(self):
         cfg = _cfg(budget=1)   # 1 decode row already exhausts the budget
@@ -380,6 +459,58 @@ class TestEngineParity:
         assert mixed_events
         assert all(e.args["prefill_tokens"] > 0
                    and e.args["decode_tokens"] > 0 for e in mixed_events)
+
+
+    def test_full_seats_on_a_lower_rung_serve_as_the_legacy_policy(self):
+        """Two seats under a ladder up to 4, both decoding when a prompt of
+        several chunks arrives: its partial chunks ride mixed steps of TWO
+        rows (no row of their own), and every request, a penalised and a
+        biased one among them, gets what the legacy policy gives it."""
+        rng = np.random.default_rng(1)
+        prompts = [rng.integers(1, 500, n).tolist() for n in (9, 11, 70, 8)]
+        params = [
+            SamplingParams(max_tokens=24, temperature=0.0,
+                           presence_penalty=0.5),
+            SamplingParams(max_tokens=24, temperature=1.0, top_k=40, seed=5,
+                           logit_bias={7: 2.0}),
+            SamplingParams(max_tokens=6, temperature=0.0, logprobs=1),
+            SamplingParams(max_tokens=6, temperature=0.0)]
+
+        def serve(mixed):
+            eng = LLMEngine(_cfg(mixed=mixed, max_num_seqs=2,
+                                 max_prefill_tokens=32))
+            assert eng.config.scheduler.seat_bucket == 2
+            widths, schedule = [], eng.scheduler.schedule
+
+            def spy(*a, **kw):
+                batch = schedule(*a, **kw)
+                if batch is not None and batch.kind == "mixed":
+                    widths.append((len(batch.context_lens), batch.partial,
+                                   len(batch.seqs)))
+                return batch
+            eng.scheduler.schedule = spy
+            outs = {}
+            for i in (0, 1):
+                eng.add_request(f"r{i}", prompts[i], params[i])
+            for _ in range(2):
+                eng.step()
+            for i in (2, 3):
+                eng.add_request(f"r{i}", prompts[i], params[i])
+            while eng.has_unfinished_requests():
+                for o in eng.step():
+                    if o.finished:
+                        outs[o.request_id] = (o.output_token_ids,
+                                              o.output_logprobs)
+            return outs, widths
+
+        ref, none = serve(False)
+        got, widths = serve(True)
+        assert not none
+        assert (2, True, 3) in widths          # two rows and a rowless chunk
+        assert all(rows == 2 for rows, _, _ in widths)
+        assert {k: v[0] for k, v in got.items()} == {
+            k: v[0] for k, v in ref.items()}
+        np.testing.assert_allclose(got["r2"][1], ref["r2"][1], atol=2e-5)
 
 
 class TestObservability:
