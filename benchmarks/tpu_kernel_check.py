@@ -1658,6 +1658,104 @@ def check_dsa(cfg) -> None:
         sys.exit(1)
 
 
+def check_block_attend(cfg, pps, B) -> None:
+    """A block model's pass (``ops/pallas/block_attend.py``) at the served
+    geometry, B rows of ``block_length`` positions over up to 2.7 k cached
+    tokens a row (the ``batch-decode-2k`` cell's longest: 1920 + 768), held
+    to FLOAT64 on the host: q and the block's own K/V float32 (so the
+    output is not rounded to bf16 and a fault cannot hide in that rounding),
+    the pool bf16 as served. Rows 0-3 have 0, 1, 5 and 130 cached tokens
+    (no page, part of one, two pages), the last the longest. Then the
+    planted fault, a causal mask INSIDE the block (the kernel's own
+    ``causal=True``), which must read over the limit; and the kernel timed,
+    one call a layer in one program, against the XLA twin."""
+    from kubernetes_gpu_cluster_tpu.ops.attention import (
+        spec_verify_attention_xla)
+    from kubernetes_gpu_cluster_tpu.ops.pallas.block_attend import (
+        block_attend)
+    S, nh, n_kv, hd = (cfg.block_length, cfg.num_heads, cfg.num_kv_heads,
+                       cfg.head_dim)
+    L, kd, scale = cfg.num_kv_layers, cfg.num_kv_heads * cfg.head_dim, \
+        cfg.attn_scale
+    rng = np.random.default_rng(0)
+    ctx = rng.integers(1024, 2689, B).astype(np.int32) + 1
+    ctx[:4], ctx[-1] = (1, 2, 6, 131), 2689
+    tables, P = _page_tables(ctx, pps)
+
+    def bf(shape):      # bf16-representable values, whatever the dtype
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    q = bf((B * S, nh, hd)).astype(jnp.float32)
+    k = bf((B * S, n_kv, hd)).astype(jnp.float32)
+    v = bf((B * S, n_kv, hd)).astype(jnp.float32)
+    k_pool, v_pool = bf((L, P, PS, kd)), bf((L, P, PS, kd))
+    tables_d, ctx_d = jnp.asarray(tables), jnp.asarray(ctx)
+    layer = L - 1
+
+    def host64(causal):
+        kp = np.asarray(k_pool[layer].astype(jnp.float32), np.float64)
+        vp = np.asarray(v_pool[layer].astype(jnp.float32), np.float64)
+        q64, k64, v64 = (np.asarray(a, np.float64).reshape(B, S, -1, hd)
+                         for a in (q, k, v))
+        out = np.zeros((B, S, nh, hd))
+        g = nh // n_kv
+        for b in range(B):
+            n = int(ctx[b]) - 1
+            hist_k = kp[tables[b]].reshape(-1, n_kv, hd)[:n]
+            hist_v = vp[tables[b]].reshape(-1, n_kv, hd)[:n]
+            for s in range(S):
+                own = s + 1 if causal else S
+                keys = np.concatenate([hist_k, k64[b, :own]])
+                vals = np.concatenate([hist_v, v64[b, :own]])
+                for h in range(nh):
+                    sc = keys[:, h // g] @ q64[b, s, h] * scale
+                    p = np.exp(sc - sc.max())
+                    out[b, s, h] = (p / p.sum()) @ vals[:, h // g]
+        return out.reshape(B * S, nh, hd)
+
+    run = jax.jit(lambda causal: block_attend(
+        q, k, v, k_pool, v_pool, tables_d, ctx_d, scale,
+        layer=jnp.int32(layer), causal=causal), static_argnums=0)
+    want = host64(False)
+    err = float(np.max(np.abs(np.asarray(run(False), np.float64) - want)))
+    fault = float(np.max(np.abs(np.asarray(run(True), np.float64) - want)))
+    twin = float(np.max(np.abs(np.asarray(spec_verify_attention_xla(
+        q, k, v, k_pool, v_pool, tables_d, ctx_d, scale,
+        layer=jnp.int32(layer), causal=False), np.float64) - want)))
+    limit = BLOCK_ATTEND_LIMIT
+    print(f"block_attend B={B} S={S} {nh}q/{n_kv}kv x {hd}, up to "
+          f"{int(ctx.max()) - 1} cached tokens: max|kernel - float64| = "
+          f"{err:.2e}, XLA twin {twin:.2e}, planted causal-inside-the-block "
+          f"fault {fault:.2e} (limit {limit:.0e})")
+    assert err < limit < fault, (err, fault)
+
+    qb, kb, vb = (a.astype(jnp.bfloat16) for a in (q, k, v))
+
+    def layers(fn):
+        def go(q, k, v, kp, vp):
+            out = jnp.zeros(q.shape, jnp.float32)
+            for l in range(L):
+                out += fn(q, k, v, kp, vp, tables_d, ctx_d, scale,
+                          layer=jnp.int32(l)).astype(jnp.float32)
+            return out
+        return jax.jit(go)
+    t_k = _timed(layers(block_attend), qb, kb, vb, k_pool, v_pool) / L
+    t_x = _timed(layers(lambda *a, **kw: spec_verify_attention_xla(
+        *a, **kw, causal=False)), qb, kb, vb, k_pool, v_pool) / L
+    pages = sum(cdiv(int(n) - 1, PS) for n in ctx)
+    least = pages * 2 * PS * kd * 2 / 819e9
+    print(f"block_attend a layer: {t_k * 1e6:.0f} us ({least / t_k:.1%} of "
+          f"819 GB/s in whole pages), XLA twin {t_x * 1e6:.0f} us")
+
+
+# float64 against a float32-output kernel over a bf16 pool: Mosaic's
+# products take their float32 operands in bfloat16 passes, which leaves
+# 8.9e-3 on outputs of O(1) (my chip run, PR 50; the first limit, 2e-3, was
+# set before any reading and refused the clean kernel); the planted fault
+# moves a row with no history by 4.19. The limit is 5x the one and 80x under
+# the other.
+BLOCK_ATTEND_LIMIT = 5e-2
+
+
 def check_int4_matmul() -> None:
     """W4A16 dequant-fused matmul (ops/pallas/int4_matmul.py): packed tiles
     dequantized in VMEM vs the XLA fusion path, at an 8B-decode-like shape
@@ -1733,6 +1831,7 @@ def main() -> None:
         "kda-chain": lambda: check_kda_chain(cfg, Kernels(use_pallas=True)),
         "hc-mix": lambda: check_hc_mix(cfg),
         "dsa": lambda: check_dsa(cfg),
+        "block-attend": lambda: check_block_attend(cfg, pps, B),
     }
     if cfg.is_mla and args.kernels == ap.get_default("kernels"):
         args.kernels = ("dsa,experts" if cfg.index_topk else

@@ -276,7 +276,7 @@ def cache_kind_refusal(config: EngineConfig, mesh_shape=None, *,
     degrades in silence. ``mesh_shape``: the engine's actual mesh axes,
     where it was handed a mesh and not flags."""
     m = config.model
-    if not (m.is_mla or m.has_state):
+    if not (m.is_mla or m.has_state or m.block_length > 1):
         return None
     axes = dict(mesh_shape or {})
     par = config.parallel
@@ -314,8 +314,29 @@ def cache_kind_refusal(config: EngineConfig, mesh_shape=None, *,
         "quant": "the state layers' projections and conv have no int8/int4 "
                  "layout",
         "moves": "pages of K and V, not a sequence's recurrent state"}
-    kinds = [k for k, has in ((latent, m.is_mla), (state, m.has_state))
-             if has]
+    # A block model (generation by diffusion over blocks): its pages are K
+    # and V, but a sequence is pages PLUS an open block the host holds, and
+    # its step program is W passes over block_length positions a row.
+    block = {
+        "tp": "the block pass's attention kernel and the pages' write at "
+              "a block's commit run unsharded",
+        "pp": "the pipeline stages a one-token decode step, not a pass "
+              "over a row's open block",
+        "sp": "ring attention is causal by token; the block-causal mask "
+              "has no ring form",
+        "ep": "the grouped expert matmuls run over every expert on one "
+              "device; no all-to-all dispatch exists",
+        "prefix": "a cached prefix is whole pages of K and V; nothing holds "
+                  "its end to a block boundary, and a block's K/V depend on "
+                  "every token of the block",
+        "spec": "a pass already yields up to block_length tokens a row; "
+                "no draft-and-verify step exists over an open block",
+        "quant": "no int8/int4 run of the block passes has been held to "
+                 "the reference",
+        "moves": "pages of K and V, not a sequence's open block (ids and "
+                 "masked flags the host holds)"}
+    kinds = [k for k, has in ((latent, m.is_mla), (state, m.has_state),
+                              (block, m.block_length > 1)) if has]
 
     def why(key: str) -> Optional[str]:
         return next((k[key] for k in kinds if key in k), None)

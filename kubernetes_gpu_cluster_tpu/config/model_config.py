@@ -160,8 +160,45 @@ class ModelConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     indexer_types: Optional[tuple] = None
+    # Generation by diffusion over blocks (sdar_moe), on when
+    # ``block_length`` > 1: attention is block-causal (key j visible to
+    # query i iff j // B <= i // B), a sequence holds an OPEN BLOCK of B
+    # positions beyond its committed length, each an id or masked (the
+    # input embedding of a masked position is ``mask_token_id``'s); a
+    # denoising pass runs the block over the pages, writes nothing, and
+    # transfers every masked position whose confidence exceeds
+    # ``confidence_threshold`` if there are at least
+    # ``block_length / denoising_steps`` of them, else that many of the
+    # most confident (``remasking`` "low_confidence_dynamic", the one
+    # rule served); a full block's K/V reach the pages in a commit pass.
+    # 1: an autoregressive model, whose open block is its next position.
+    block_length: int = 1
+    denoising_steps: int = 1
+    confidence_threshold: float = 0.9
+    remasking: str = "low_confidence_dynamic"
+    mask_token_id: int = 0
 
     def __post_init__(self):
+        if self.block_length > 1:
+            B, n = self.block_length, self.denoising_steps
+            if B & (B - 1) or n < 1 or B % n:
+                raise ValueError(
+                    f"{self.name}: block_length {B} must be a power of two "
+                    f"and a multiple of denoising_steps {n}")
+            if self.remasking != "low_confidence_dynamic":
+                raise ValueError(
+                    f"{self.name}: remasking {self.remasking!r}: only "
+                    "'low_confidence_dynamic' is served")
+            if not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(
+                    f"{self.name}: mask_token_id {self.mask_token_id} is "
+                    f"outside the vocabulary of {self.vocab_size}")
+            if (self.is_mla or self.layer_types is not None
+                    or self.max_model_len % B):
+                raise ValueError(
+                    f"{self.name}: block_length {B} is served over K and V "
+                    "pages of attention layers alone, and max_model_len "
+                    "must be a multiple of it")
         if self.index_topk:
             types = self.indexer_types or ()
             if (len(types) != self.num_layers or types[0] != "full"
@@ -419,6 +456,17 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
     # kimi-vl-a3b's block at a size the CPU tests can afford: latent
     # attention, 1 dense + 3 expert layers, sigmoid router with a choice
     # bias, 2 shared experts.
+    # sdar_moe's block at a size the CPU tests can afford: qk-norm GQA, a
+    # softmax router over 8 experts top-2 with no shared expert and no
+    # dense layer, generation by diffusion over blocks of 4.
+    "debug-block-moe": _p(
+        "debug-block-moe", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=2, num_heads=4, num_kv_heads=2,
+        head_dim=32, qk_norm=True, rope_theta=1000000.0, rms_norm_eps=1e-6,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=64,
+        block_length=4, denoising_steps=4, mask_token_id=511,
+        max_model_len=512, dtype="float32",
+    ),
     "debug-mla-moe": _p(
         "debug-mla-moe", vocab_size=512, hidden_size=128, intermediate_size=256,
         num_layers=4, num_heads=4, num_kv_heads=4, head_dim=48,
@@ -546,6 +594,21 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         num_layers=32, num_heads=32, num_kv_heads=8, head_dim=128,
         rope_theta=1000000.0, max_model_len=8192,
         num_experts=8, num_experts_per_tok=2,
+    ),
+    # JetLM/SDAR-30B-A3B-Chat (``sdar_moe``): qwen3_moe's decoder (qk-norm
+    # GQA, 128 routed experts of width 768 top-8 by softmax with
+    # norm_topk_prob, no shared expert, no dense layer) that generates by
+    # diffusion over blocks of 4 (``block_diffusion_generate``). The block
+    # length, the steps, the rule and the threshold are the family's
+    # convention, not config.json's (perfbench/configs/
+    # sdar-30b-a3b-chat-bf16.json: ``assumed``).
+    "sdar-30b-a3b-chat": _p(
+        "sdar-30b-a3b-chat", vocab_size=151936, hidden_size=2048,
+        intermediate_size=6144, num_layers=48, num_heads=32, num_kv_heads=4,
+        head_dim=128, qk_norm=True, rope_theta=1000000.0, rms_norm_eps=1e-6,
+        num_experts=128, num_experts_per_tok=8, moe_intermediate_size=768,
+        norm_topk_prob=True, block_length=4, denoising_steps=4,
+        confidence_threshold=0.9, mask_token_id=151669, max_model_len=4096,
     ),
     # The language model of moonshotai/Kimi-VL-A3B-Instruct (config.json's
     # text_config, a deepseek_v3 decoder): MLA, 64 routed experts top-6 with

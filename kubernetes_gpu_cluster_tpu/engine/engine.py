@@ -53,6 +53,7 @@ from .mixed_batch import (mixed_row_bucket, mixed_steps_of_prompt,
 from .sampling_params import LOGIT_BIAS_CAP, SamplingParams
 from .scheduler import CannotChain, ScheduledBatch, Scheduler, _bucket
 from .sequence import FinishReason, Sequence, SequenceStatus
+from . import block as block_steps
 
 logger = get_logger("engine")
 
@@ -327,6 +328,11 @@ class LLMEngine:
         # variant exists: the pipelined layer regime and ring attention both
         # replace the kernels this path splits the token axis between, so
         # those meshes keep the legacy prefill-else-decode policy.
+        # A block model's window and mixed step (engine/block.py): W passes
+        # over the rows' open blocks, and one pass beside a chunk.
+        self._block_window_fn, self._block_mixed_fn = (
+            block_steps.build_block_fns(self)
+            if config.model.block_length > 1 else (None, None))
         if self.pp_size == 1 and self.sp_size == 1:
             self._mixed_fn = self._build_mixed_fn()
         else:
@@ -521,6 +527,14 @@ class LLMEngine:
         if self.model_config.hc_mult > 1:
             # The residual is this many streams, mixed around every sublayer.
             info["residual_streams"] = self.model_config.hc_mult
+        if self.model_config.block_length > 1:
+            # Generation by diffusion over blocks: a pass yields 0 to
+            # block_length tokens a row.
+            m = self.model_config
+            info.update(block_length=m.block_length,
+                        denoising_steps=m.denoising_steps,
+                        remasking=m.remasking,
+                        confidence_threshold=m.confidence_threshold)
         if self.pallas_disabled_reason is not None:
             info["pallas_disabled_reason"] = self.pallas_disabled_reason
         return info
@@ -554,7 +568,8 @@ class LLMEngine:
         arriving."""
         fns = [self._prefill_fn, self._prefill_hist_fn, self._mixed_fn,
                self._decode_fn, self._decode_fn_greedy, self._spec_verify_fn,
-               self._spec_mixed_fn]
+               self._spec_mixed_fn, self._block_window_fn,
+               self._block_mixed_fn]
         # The shared pair counts once: swapper and kv_io both run it.
         fns += [self._kv_programs._gather_fn, self._kv_programs._scatter_fn]
         total = sum(fn._cache_size() for fn in fns
@@ -635,7 +650,8 @@ class LLMEngine:
             use_pallas_hist=use_pallas and self._hist_kernel_eligible(),
             tp_mesh=self.mesh if use_pallas and gspmd else None,
             ring_prefill=ring,
-            grouped_experts=self._grouped_experts)
+            grouped_experts=self._grouped_experts,
+            block=cfg.block_length)
 
     def _hist_kernel_eligible(self) -> bool:
         """Where the Pallas history-prefill kernel can serve: meshless
@@ -765,23 +781,37 @@ class LLMEngine:
             logger.info("Pallas kernels compiled at the served geometry: %s",
                         ", ".join(compiled))
             return
-        probe("paged_decode",
-              lambda q, kp, vp, tb, ctx, kc, vc, lyr: pallas_paged_decode(
-                  q, kp, vp, tb, ctx, kc, vc, scale, layer=lyr),
-              arr((B, nh, hd)), pool, pool, arr((B, pps), i32),
-              arr((B,), i32), arr((B, nkv, hd)), arr((B, nkv, hd)),
-              arr((1,), i32))
+        blk = {"block": cfg.block_length} if cfg.block_length > 1 else {}
+        if blk:
+            # A block model's rows run ``block_attend`` (block_length
+            # query positions a row), never the one-token decode kernel.
+            from ..ops.pallas.block_attend import block_attend
+            S = cfg.block_length
+            probe("block_attend",
+                  lambda q, k, v, kp, vp, tb, ctx, lyr: block_attend(
+                      q, k, v, kp, vp, tb, ctx, scale, layer=lyr),
+                  arr((B * S, nh, hd)), arr((B * S, nkv, hd)),
+                  arr((B * S, nkv, hd)), pool, pool, arr((B, pps), i32),
+                  arr((B,), i32), arr((1,), i32))
+        else:
+            probe("paged_decode",
+                  lambda q, kp, vp, tb, ctx, kc, vc, lyr:
+                  pallas_paged_decode(q, kp, vp, tb, ctx, kc, vc, scale,
+                                      layer=lyr),
+                  arr((B, nh, hd)), pool, pool, arr((B, pps), i32),
+                  arr((B,), i32), arr((B, nkv, hd)), arr((B, nkv, hd)),
+                  arr((1,), i32))
         if self.sp_size == 1:   # ring attention replaces it under sp
             probe("flash_prefill",
                   lambda q, k, v, seg, pos: flash_ragged_prefill(
-                      q, k, v, seg, pos, scale),
+                      q, k, v, seg, pos, scale, **blk),
                   arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hd)),
                   arr((T,), i32), arr((T,), i32))
         if self._hist_kernel_eligible():
             probe("flash_prefill_hist",
                   lambda q, k, v, seg, pos, kp, vp, pt, hl, lyr:
                   flash_prefill_history(q, k, v, seg, pos, kp, vp, pt, hl,
-                                        scale, layer=lyr),
+                                        scale, layer=lyr, **blk),
                   arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hd)),
                   arr((T,), i32), arr((T,), i32), pool, pool,
                   arr((pps,), i32), arr((), i32), arr((), i32))
@@ -789,7 +819,7 @@ class LLMEngine:
         # VMEM blocks) for the largest decode and prefill flushes.
         layers = cfg.num_kv_layers // self.pp_size
         deep_pool = arr((layers, 2, ps, nkv * hd), pool.dtype)
-        for n in (B, T):
+        for n in (B * cfg.block_length, T):
             rows = arr((layers, n, nkv * hd))
             probe(f"kv_write[T={n}]", kv_write, deep_pool, deep_pool,
                   rows, rows, arr((n,), i32))
@@ -1457,8 +1487,11 @@ class LLMEngine:
                 raise ValueError(
                     f"logit_bias token ids {bad[:5]} out of range for "
                     f"vocab_size {V}")
+        if self.model_config.block_length > 1:
+            block_steps.refuse_request(params)
         seq = Sequence(request_id, prompt_token_ids, params,
-                       eos_token_id=self.eos_token_id)
+                       eos_token_id=self.eos_token_id,
+                       block_length=self.model_config.block_length)
         seq.hold_kv = hold_kv
         if arrival_t0 is not None:
             seq.arrival_time = min(arrival_t0, seq.arrival_time)
@@ -2058,6 +2091,11 @@ class LLMEngine:
         """Why no step may be scheduled while ``pred`` is in flight, read
         from what the engine holds; None when one may."""
         sched = self.scheduler
+        if self.model_config.block_length > 1:
+            # The host holds every row's open block between programs
+            # (engine/block.py): the next one is scheduled from what this
+            # one transferred.
+            return "block"
         if sched.spec_enabled:
             # Draft verification IS the speculation, and a chained window
             # would pin the engine in legacy decode after n-gram matches
@@ -2147,7 +2185,19 @@ class LLMEngine:
         ``t_wait`` and ``t_ready``; ``_retired`` adds ``t_retired`` and
         hands it to ``Observability.on_step``."""
         kind, rows, padded = batch.kind, batch.num_seqs, len(batch.tokens)
-        if kind == "decode":
+        if batch.block is not None:
+            # A block program: positions computed over its passes; the
+            # tokens it TRANSFERRED are known when it retires
+            # (``block.retire`` writes them over this).
+            passes = (self.config.scheduler.decode_window
+                      if kind == "decode" else 1)
+            width = passes * self.model_config.block_length
+            # (a mixed step's head, last in ``seqs``, has no row)
+            tokens = (batch.prefill_token_count
+                      + max(rows - (kind == "mixed"), 0) * width)
+            padded = (batch.prefill_token_count
+                      + len(batch.temperature) * width)
+        elif kind == "decode":
             window = self.config.scheduler.decode_window
             tokens, padded = rows * window, padded * window
         elif kind == "prefill":
@@ -2214,7 +2264,8 @@ class LLMEngine:
             self._dispatch_window(rec, prev, _pack_float_b(batch),
                                   jax.random.key(0), None)
             prev = rec["last"]
-        jax.block_until_ready(prev)
+        # (a block program hands its successor no token: wait for its own)
+        jax.block_until_ready((prev, rec.get("toks")))
         logger.info("the window at %d rows met before the first request: "
                     "%.1f s", len(batch.tokens), time.monotonic() - t0)
 
@@ -2254,7 +2305,7 @@ class LLMEngine:
                 self._dispatch_prefill(rec, prev, _pack_float_b(batch),
                                        jax.random.key(0))
                 prev = rec["last"]
-            jax.block_until_ready(prev)
+            jax.block_until_ready((prev, rec.get("toks")))
         logger.info("%d mixed steps (chunk rung, history width) %s beside "
                     "%d rows met before the first request: %.1f s",
                     len(steps), steps, sc.max_num_seqs,
@@ -2268,6 +2319,8 @@ class LLMEngine:
         skips it with no output, no stats, no stop checks."""
         ph = self.obs.phases.phase
         batch = rec["batch"]
+        if batch.block is not None:     # a block model's pass beside a chunk
+            return block_steps.dispatch(self, rec, float_b, step_key)
         mixed = batch.kind == "mixed"
         with ph("host_prep"):
             int_t = jnp.asarray(np.stack(
@@ -2310,7 +2363,11 @@ class LLMEngine:
                     bias_ids, bias_vals, step_key)
         rec.update(t_dispatched=time.monotonic(), toks=toks, lps=lps,
                    tids=tids, tlps=tlps, last=last, load=load, counts=None,
-                   zombies=({batch.seqs[-1].request_id} if batch.partial
+                   zombies=({s.request_id for s in batch.seqs}
+                            if self.model_config.block_length > 1
+                            # a block model's prefill samples nothing: the
+                            # prompt's tail opens the first block
+                            else {batch.seqs[-1].request_id} if batch.partial
                             else set()))
 
     def _retire(self, step: dict,
@@ -2318,6 +2375,8 @@ class LLMEngine:
         """Fetch the tokens of ``step`` (the wait for the device, under its
         ``successor`` when one was dispatched), commit them, and release
         what no dispatched program can write any more."""
+        if step.get("block"):
+            return block_steps.retire(self, step)
         ph = self.obs.phases.phase
         # The fetch and the post-processing serve THIS program, whatever
         # the iteration launched before it came here.
@@ -2375,6 +2434,8 @@ class LLMEngine:
         last-token output with the newest. Rows of finished sequences and
         a partial chunk's (the zombies) are sampled nothing that counts."""
         batch = step["batch"]
+        if self.model_config.block_length > 1:
+            return      # fetched before anything else is scheduled
         n = (self.config.scheduler.decode_window
              if step["kind"] == "decode" else 1)
         for row, seq in batch.device_seq_rows():
@@ -2601,6 +2662,8 @@ class LLMEngine:
         it already holds the tokens in flight)."""
         ph = self.obs.phases.phase
         batch = rec["batch"]
+        if batch.block is not None:     # a block model's W passes
+            return block_steps.dispatch(self, rec, float_b, step_key)
         if self._sanitizer is not None:
             self._sanitizer.on_decode_dispatch(
                 batch.seqs, batch.positions,
@@ -2934,8 +2997,9 @@ def step_workspace_bytes(config: EngineConfig) -> int:
     - the ``[rows, vocab]`` f32 sampling buffers (logits, penalties
       histogram, sort/top-k scratch) at the top decode bucket."""
     m, sc = config.model, config.scheduler
-    T = sc.prefill_buckets[-1] + sc.decode_buckets[-1]   # mixed step width
-    B = sc.decode_buckets[-1]
+    # mixed step width (a block model's rows are block_length positions)
+    B = sc.decode_buckets[-1] * m.block_length
+    T = sc.prefill_buckets[-1] + B
     it = m.jnp_dtype.itemsize
     kd = m.kv_row_padded
     kv_rows = (m.kv_pools * m.num_kv_layers * T * kd
@@ -2966,7 +3030,15 @@ def step_workspace_bytes(config: EngineConfig) -> int:
                             * (m.index_head_dim * it + 4 * m.index_n_heads)
                             + m.index_topk * kd * (it + 4)))
     else:
-        mlp = max(m.num_experts, 1) * T * m.intermediate_size * (4 + 4 + it)
+        if m.is_moe and m.moe_intermediate_size:
+            # qwen3_moe's class at grouped dispatch: the routed pairs' rows
+            # as in the latent branch; no shared expert, no dense layer.
+            pairs = T * m.num_experts_per_tok
+            mlp = pairs * (m.expert_width * (4 + 4 + it)
+                           + m.hidden_size * (it + 4 + 4))
+        else:
+            mlp = (max(m.num_experts, 1) * T * m.intermediate_size
+                   * (4 + 4 + it))
         attn = T * (m.num_heads * m.head_dim + kd) * 2 * (4 + it)
     resid = 4 * T * m.hc_mult * m.hidden_size * 4
     if m.hc_mult > 1:

@@ -93,8 +93,7 @@ def _commit_chunk_progress(sched: "Scheduler", head, end: int, n_rows: int,
     sched.obs.on_prefill_chunk(head, hist_len, end, head.num_tokens)
     if final:
         sched.waiting.popleft()
-        head.status = SequenceStatus.RUNNING
-        sched.running.append(head)
+        sched._enter_running(head)
         sched._register_prefix(head)
     else:
         logger.info("%s prefill chunk [%d:%d) of %d (%s)",
@@ -152,11 +151,14 @@ def mixed_steps_of_prompt(sched: "Scheduler", n: int) -> list:
     from .scheduler import _bucket
 
     sc, steps, done = sched.config.scheduler, [], 0
+    B = sched.block_length
     n = min(n, sched.config.effective_max_len - 1)
+    n = max(n - n % B, B)       # a prefill computes whole blocks
     while done < n:
         chunk = plan_chunk_tokens(n - done, sc.max_num_seqs - 1,
                                   sc.decode_priority_token_budget,
                                   sc.max_prefill_tokens)
+        chunk -= chunk % B
         if chunk <= 0:
             break
         done += chunk
@@ -179,6 +181,9 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
 
     sc = sched.config.scheduler
     head = sched.waiting[0]
+    # The model's block length: a chunk ends on a multiple of it, a row is
+    # that many positions (1: a token a row, as ever).
+    B = sched.block_length
     sched._try_prefix_reuse(head)
 
     # -- policy probes (no state mutation until all pass) -------------------
@@ -188,7 +193,7 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     # one step, exactly the legacy prefill-else-decode cost.
     if (sched.qos is not None
             and (head.num_prefilled > 0
-                 or head.num_tokens > sc.max_prefill_tokens)
+                 or head.prefill_len > sc.max_prefill_tokens)
             and sched._qos_defer_chunk(head)):
         return None
     # Sampled-row count D+1 must stay inside the configured decode-bucket
@@ -208,24 +213,33 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     # The scan mirrors legacy lookahead depth: a chunkable prompt at
     # waiting[1] must not mask packable small prompts behind it.
     if (head.num_prefilled == 0
-            and head.num_tokens <= sc.max_prefill_tokens
+            and head.prefill_len <= sc.max_prefill_tokens
             and len(sched.running) + 2 <= sched.max_num_seqs):
         packable, total = 0, 0
         for i in range(min(len(sched.waiting), sched.PREFILL_LOOKAHEAD + 1)):
             seq = sched.waiting[i]
             if (seq.num_prefilled == 0
-                    and total + seq.num_tokens <= sc.max_prefill_tokens):
+                    and total + seq.prefill_len <= sc.max_prefill_tokens):
                 packable += 1
-                total += seq.num_tokens
+                total += seq.prefill_len
                 if packable >= 2:
                     return None
-    remaining = head.num_tokens - head.num_prefilled
-    chunk = plan_chunk_tokens(remaining, len(sched.running),
-                              sc.decode_priority_token_budget,
-                              sc.max_prefill_tokens)
+    remaining = head.prefill_len - head.num_prefilled
+
+    def plan(rows: int) -> int:
+        # A row claims ONE token of the step's budget whatever its width
+        # (a block model's row is B positions of the same weight stream):
+        # beside full seats a chunk is 2048 - rows tokens floored to B, so
+        # a prompt of the admitted traffic (<= 1920) stays one mixed step.
+        chunk = plan_chunk_tokens(remaining, rows,
+                                  sc.decode_priority_token_budget,
+                                  sc.max_prefill_tokens)
+        return chunk - chunk % B
+
+    chunk = plan(len(sched.running))
     if chunk <= 0:
         return None
-    if (head.num_prefilled + chunk >= head.num_tokens
+    if (head.num_prefilled + chunk >= head.prefill_len
             and len(sched.running) >= sched.max_num_seqs):
         # No seat for the head once its prompt completes: let the pure
         # decode path run until a running sequence finishes.
@@ -248,14 +262,12 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     # Recompute the chunk with the post-growth decode-row count (preemption
     # can only shrink D, which only widens the chunk's budget room; it also
     # frees a running seat, so a now-final chunk still has one).
-    chunk = plan_chunk_tokens(remaining, len(decode_seqs),
-                              sc.decode_priority_token_budget,
-                              sc.max_prefill_tokens)
+    chunk = plan(len(decode_seqs))
     if chunk <= 0:
         return None
     end = head.num_prefilled + chunk
-    final = end >= head.num_tokens
-    need = cdiv(end, sched.page_size) - len(head.pages)
+    final = end >= head.prefill_len
+    need = cdiv(head.admit_tokens(end), sched.page_size) - len(head.pages)
     if sched.needs_slot(head) and not sched.allocator.num_free_slots:
         return None     # a state model's head waits for a slot as for pages
     if need > 0:
@@ -274,9 +286,11 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     # (the engine discards it). Beside a server whose every seat decodes,
     # its row would be the one row past the seats' bucket: a step program
     # of its own, twice the rows, that no warm-up meets. It is left out.
-    chunk_row = final or D < sc.seat_bucket
+    # A block model's chunk never has one: its prompt's tail opens the
+    # first block, no token is sampled behind a prefill.
+    chunk_row = (final or D < sc.seat_bucket) and B == 1
     R_pad = mixed_row_bucket(D + chunk_row, Tp, sc)
-    T_pad = Tp + R_pad
+    T_pad = Tp + R_pad * B
 
     tokens = np.zeros(T_pad, np.int32)
     seg_ids = np.full(T_pad, -1, np.int32)
@@ -291,7 +305,8 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     head_pages = np.asarray(head.pages, np.int64)
     slot_mapping[:chunk] = (head_pages[tok_pos // sched.page_size] *
                             sched.page_size + tok_pos % sched.page_size)
-    chunk_page_table = sched._chunk_page_table(head)
+    chunk_page_table = sched._chunk_page_table(head,
+                                               end if B > 1 else None)
 
     # -- decode slice [Tp:Tp+R_pad) -----------------------------------------
     # Static table width: never recompiles as contexts grow (same rationale
@@ -300,9 +315,14 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     page_tables = np.zeros((R_pad, pages_bucket), np.int32)
     context_lens = np.zeros(R_pad, np.int32)
     tok_src = np.full(R_pad, -1, np.int32)
-    for s, seq in enumerate(decode_seqs):
-        sched._fill_decode_row(seq, s, Tp, tokens, positions, slot_mapping,
-                               page_tables, context_lens, tok_src)
+    rows = dict(page_tables=page_tables, context_lens=context_lens)
+    if B > 1:       # the rows' open blocks, laid out on the device
+        rows = sched.fill_block_rows(decode_seqs, R_pad)
+    else:
+        for s, seq in enumerate(decode_seqs):
+            sched._fill_decode_row(seq, s, Tp, tokens, positions,
+                                   slot_mapping, page_tables, context_lens,
+                                   tok_src)
 
     # -- sampled rows -------------------------------------------------------
     logits_indices = np.zeros(R_pad, np.int32)
@@ -318,8 +338,7 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     return ScheduledBatch(
         kind="mixed", seqs=seqs, tokens=tokens, positions=positions,
         slot_mapping=slot_mapping, seg_ids=seg_ids,
-        logits_indices=logits_indices, page_tables=page_tables,
-        context_lens=context_lens, tok_src=tok_src,
+        logits_indices=logits_indices, tok_src=tok_src, **rows,
         chunk_page_table=chunk_page_table,
         hist_len=hist_len, partial=not final, prefill_token_count=chunk,
         seg_slots=sched._state_slots([head], R_pad),
@@ -337,17 +356,19 @@ def padding_mixed_batch(sched: "Scheduler", Tp: int, R_pad: int,
     scrap slot."""
     from .scheduler import ScheduledBatch
 
-    T_pad = Tp + R_pad
+    B = sched.block_length
+    T_pad = Tp + R_pad * B
     seg_ids = np.full(T_pad, -1, np.int32)
     seg_ids[0] = 0
     pages_bucket = cdiv(sched.config.effective_max_len, sched.page_size)
+    rows = (sched.fill_block_rows([], R_pad) if B > 1 else dict(
+        page_tables=np.zeros((R_pad, pages_bucket), np.int32),
+        context_lens=np.zeros(R_pad, np.int32)))
     return ScheduledBatch(
         kind="mixed", seqs=[], tokens=np.zeros(T_pad, np.int32),
         positions=np.zeros(T_pad, np.int32),
         slot_mapping=np.zeros(T_pad, np.int32), seg_ids=seg_ids,
-        logits_indices=np.zeros(R_pad, np.int32),
-        page_tables=np.zeros((R_pad, pages_bucket), np.int32),
-        context_lens=np.zeros(R_pad, np.int32),
+        logits_indices=np.zeros(R_pad, np.int32), **rows,
         tok_src=np.full(R_pad, -1, np.int32),
         chunk_page_table=np.zeros((1, hist_width), np.int32), hist_len=0,
         seg_slots=sched._state_slots([], R_pad),
@@ -404,7 +425,7 @@ def build_spec_mixed_batch(sched: "Scheduler") -> Optional["ScheduledBatch"]:
     # -- policy probes (no state mutation until all pass) -------------------
     if (sched.qos is not None
             and (head.num_prefilled > 0
-                 or head.num_tokens > sc.max_prefill_tokens)
+                 or head.prefill_len > sc.max_prefill_tokens)
             and sched._qos_defer_chunk(head)):
         return None
     # Spec rows bucket like the pure spec step; the chunk rides one row

@@ -92,6 +92,9 @@ class ScheduledBatch:
     # were padded with filler drafts; the split feeds acceptance metrics),
     # the step's verify-slice width S = k+1 (adaptive k varies it between
     # steps), and the draft phase's wall time (trace attribution).
+    # a block model's rows (decode + mixed): [B_pad, 2 * block_length + 3]
+    # int32, each row's open block (``Scheduler.fill_block_rows``)
+    block: Optional[np.ndarray] = None
     draft_lens: Optional[np.ndarray] = None        # [B_pad]
     spec_S: Optional[int] = None
     draft_time_s: float = 0.0
@@ -174,6 +177,10 @@ class Scheduler:
                 self.spec_controller = AdaptiveK(sc.effective_spec_k_max)
         self.decode_buckets = sc.decode_buckets
         self.prefill_buckets = sc.prefill_buckets
+        # The model's block length B (1: autoregressive). Prefills compute
+        # ``Sequence.prefill_len`` tokens, whole blocks, and chunks end on
+        # multiples of B; a block model's rows are B positions a pass.
+        self.block_length = config.model.block_length
         self.page_size = config.cache.page_size
         # A state model's slots, the second resource of the same manager: a
         # seat each and the scrap slot, unless the caller holds fewer. (A
@@ -384,6 +391,8 @@ class Scheduler:
         self._release(seq)
         seq.status = SequenceStatus.PREEMPTED
         seq.num_prefilled = 0        # pages gone: chunk progress recomputes
+        seq.num_committed = 0        # ... and a block model's open block
+        seq.block_ids, seq.block_masked, seq.block_marks = [], [], []
         seq.prefix_checked = False   # re-lookup on readmission (cheap TTFT
                                      # recovery when the prefix is cached)
         if self.waiting and (behind_head
@@ -760,7 +769,7 @@ class Scheduler:
         if self.waiting:
             head = self.waiting[0]
             self._try_prefix_reuse(head)
-            if head.num_prefilled > 0 or head.num_tokens > self.max_prefill_tokens:
+            if head.num_prefilled > 0 or head.prefill_len > self.max_prefill_tokens:
                 # QoS chunk-gate: a mid-chunk lower-priority head yields
                 # this step's prefill budget to an owed higher-priority
                 # waiter (admitted by the lookahead loop below); the head
@@ -791,14 +800,14 @@ class Scheduler:
                 skipped += 1
                 i += 1
                 continue
-            if seq.num_tokens > self.max_prefill_tokens:
+            if seq.prefill_len > self.max_prefill_tokens:
                 # Chunkable sequence mid-queue: solo-only, skip for this batch.
                 skipped += 1
                 i += 1
                 continue
             fits_budget = (not admitted or
-                           total_tokens + seq.num_tokens <= self.max_prefill_tokens)
-            need = cdiv(seq.num_tokens, self.page_size)
+                           total_tokens + seq.prefill_len <= self.max_prefill_tokens)
+            need = cdiv(seq.admit_tokens(), self.page_size)
             # Budget first: can_allocate may EVICT prefix-cache entries to
             # satisfy the probe, which must not happen for candidates the
             # token budget rejects anyway.
@@ -835,10 +844,16 @@ class Scheduler:
             seq.state_slot = self.allocator.allocate_slot()
             del self.waiting[i]
             admitted.append(seq)
-            total_tokens += seq.num_tokens
+            total_tokens += seq.prefill_len
             self._register_prefix(seq)
         if not admitted:
             return None
+        if not total_tokens:
+            # Block-model prompts shorter than a block: nothing to prefill,
+            # each is its own first open block.
+            for seq in admitted:
+                self._enter_running(seq, len(admitted))
+            return self._schedule_inner(behind)
 
         T = _bucket(total_tokens, self.prefill_buckets)
         B = _bucket(len(admitted), self.decode_buckets)
@@ -849,8 +864,8 @@ class Scheduler:
         logits_indices = np.zeros(B, np.int32)
         i = 0
         for s, seq in enumerate(admitted):
-            n = seq.num_tokens
-            tokens[i:i + n] = seq.all_token_ids
+            n = seq.prefill_len
+            tokens[i:i + n] = seq.all_token_ids[:n]
             seg_ids[i:i + n] = s
             positions[i:i + n] = np.arange(n)
             page_arr = np.asarray(seq.pages, np.int64)
@@ -858,10 +873,8 @@ class Scheduler:
             slot_mapping[i:i + n] = (page_arr[tok_pos // self.page_size] *
                                      self.page_size + tok_pos % self.page_size)
             i += n
-            logits_indices[s] = i - 1
-            seq.status = SequenceStatus.RUNNING
-            self.running.append(seq)
-            self.obs.on_scheduled(seq, len(admitted))
+            logits_indices[s] = max(i - 1, 0)
+            self._enter_running(seq, len(admitted))
 
         return ScheduledBatch(
             kind="prefill", seqs=admitted, tokens=tokens, positions=positions,
@@ -876,12 +889,13 @@ class Scheduler:
         the sequence's committed pool history. On the final chunk the
         sequence joins running (its sampled token is the first generation);
         earlier chunks leave it at the queue head with progress advanced."""
-        remaining = seq.num_tokens - seq.num_prefilled
+        remaining = seq.prefill_len - seq.num_prefilled
         chunk = min(remaining, self.max_prefill_tokens)
+        chunk -= chunk % self.block_length      # chunks end on block edges
         if len(self.running) >= self.max_num_seqs:
             return None
         end = seq.num_prefilled + chunk
-        need = cdiv(end, self.page_size) - len(seq.pages)
+        need = cdiv(seq.admit_tokens(end), self.page_size) - len(seq.pages)
         if self.needs_slot(seq) and not self.allocator.num_free_slots:
             return None        # wait for a finish to free a state slot
         if need > 0 and not self.allocator.can_allocate(need):
@@ -903,7 +917,7 @@ class Scheduler:
         if self.needs_slot(seq):
             seq.state_slot = self.allocator.allocate_slot()
 
-        partial = end < seq.num_tokens
+        partial = end < seq.prefill_len
         T = _bucket(chunk, self.prefill_buckets)
         tokens = np.zeros(T, np.int32)
         seg_ids = np.full(T, -1, np.int32)
@@ -916,7 +930,8 @@ class Scheduler:
         page_arr = np.asarray(seq.pages, np.int64)
         slot_mapping[:chunk] = (page_arr[tok_pos // self.page_size] *
                                 self.page_size + tok_pos % self.page_size)
-        page_table = self._chunk_page_table(seq)
+        page_table = self._chunk_page_table(
+            seq, end if self.block_length > 1 else None)
         B = _bucket(1, self.decode_buckets)
         logits_indices = np.zeros(B, np.int32)
         logits_indices[0] = chunk - 1
@@ -936,8 +951,7 @@ class Scheduler:
                         extra={"request_id": seq.request_id})
         else:
             self.waiting.popleft()
-            seq.status = SequenceStatus.RUNNING
-            self.running.append(seq)
+            self._enter_running(seq)
             self._register_prefix(seq)
 
         return ScheduledBatch(
@@ -947,6 +961,21 @@ class Scheduler:
             hist_len=hist_len, partial=partial,
             seg_slots=self._state_slots([seq], B),
             **self._sampling_arrays([seq], B))
+
+    def _enter_running(self, seq: Sequence,
+                       admitted: Optional[int] = None) -> None:
+        """``seq`` joins the running set behind the prefill that was just
+        scheduled for it (``admitted``: a packed prefill of that many, which
+        is also where its queue wait ends). A block model's sequence holds
+        ``prefill_len`` committed positions from here on and opens its
+        first block over the rest."""
+        seq.status = SequenceStatus.RUNNING
+        self.running.append(seq)
+        if admitted is not None:
+            self.obs.on_scheduled(seq, admitted)
+        if seq.block_length > 1:
+            seq.num_committed = seq.prefill_len
+            seq.open_block()
 
     def needs_slot(self, seq: Sequence) -> bool:
         """Whether a state model's sequence is still without its slot (its
@@ -968,16 +997,19 @@ class Scheduler:
         return min(next_power_of_2(max(pages, 1)),
                    cdiv(self.config.effective_max_len, self.page_size))
 
-    def _chunk_page_table(self, seq: Sequence) -> np.ndarray:
+    def _chunk_page_table(self, seq: Sequence,
+                          end: Optional[int] = None) -> np.ndarray:
         """[1, width] page table for a chunk's history attention. Width
         buckets to the ACTUAL context (few power-of-2 compile shapes), not
         the model cap — the attention materializes [heads, T, width*ps]
         scores, so a max-len-wide table would make every small chunk pay
         max-model-len memory/FLOPs. Single source for the solo-chunk and
         mixed paths so their compile-shape families cannot diverge."""
-        width = self.chunk_table_width(len(seq.pages))
-        table = np.zeros((1, width), np.int32)
-        table[0, :len(seq.pages)] = seq.pages
+        # (``end``: a block model's sequence holds pages past its chunk's
+        # end, for its first open blocks; the table is the chunk's.)
+        n = len(seq.pages) if end is None else cdiv(end, self.page_size)
+        table = np.zeros((1, self.chunk_table_width(n)), np.int32)
+        table[0, :n] = seq.pages[:n]
         return table
 
     def _fill_decode_row(self, seq: Sequence, row: int, offset: int,
@@ -1064,8 +1096,7 @@ class Scheduler:
                 continue
             # Window inputs occupy positions num_tokens-1 .. num_tokens+W-2
             # (see Sequence.last_window_pos for the clamp rationale).
-            last_pos = seq.last_window_pos(
-                seq.sched_tokens - 1, window, max_len)
+            last_pos = seq.window_last_pos(window, max_len)
             pages_needed = cdiv(last_pos + 1, self.page_size)
             grow = pages_needed - len(seq.pages)
             if grow > 0:
@@ -1096,6 +1127,26 @@ class Scheduler:
         return self.decode_batch(
             scheduled, _bucket(len(scheduled), self.decode_buckets))
 
+    def fill_block_rows(self, seqs: list[Sequence], R: int) -> dict:
+        """A block model's rows, ``R`` of them (padding past ``seqs``): what
+        a pass over every row's open block reads. ``block`` [R, 2 B + 3]
+        int32 = (the block's ids, its masked flags, its start, the
+        positions the row's pages cover (a commit past them goes to the
+        scrap page; 0: a padding row), the passes the block has taken),
+        and the page tables."""
+        B = self.block_length
+        pages_bucket = cdiv(self.config.effective_max_len, self.page_size)
+        block = np.zeros((R, 2 * B + 3), np.int32)
+        page_tables = np.zeros((R, pages_bucket), np.int32)
+        context_lens = np.zeros(R, np.int32)
+        for r, seq in enumerate(seqs):
+            block[r] = (*seq.block_ids, *seq.block_masked, seq.num_committed,
+                        len(seq.pages) * self.page_size, seq.block_passes)
+            page_tables[r, :len(seq.pages)] = seq.pages
+            context_lens[r] = seq.num_committed + 1
+        return dict(block=block, page_tables=page_tables,
+                    context_lens=context_lens)
+
     def decode_batch(self, scheduled: list[Sequence], B: int
                      ) -> ScheduledBatch:
         """A decode window's batch over ``scheduled`` at ``B`` rows; the
@@ -1110,9 +1161,16 @@ class Scheduler:
         tokens = np.zeros(B, np.int32)
         positions = np.zeros(B, np.int32)
         slot_mapping = np.zeros(B, np.int32)
+        tok_src = np.full(B, -1, np.int32)
+        if self.block_length > 1:
+            # The rows' open blocks take the place of one token a row.
+            return ScheduledBatch(
+                kind="decode", seqs=scheduled, tokens=tokens,
+                positions=positions, slot_mapping=slot_mapping,
+                tok_src=tok_src, **self.fill_block_rows(scheduled, B),
+                **self._sampling_arrays(scheduled, B))
         page_tables = np.zeros((B, pages_bucket), np.int32)
         context_lens = np.zeros(B, np.int32)
-        tok_src = np.full(B, -1, np.int32)
         for s, seq in enumerate(scheduled):
             self._fill_decode_row(seq, s, 0, tokens, positions, slot_mapping,
                                   page_tables, context_lens, tok_src)

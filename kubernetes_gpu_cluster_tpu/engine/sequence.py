@@ -32,8 +32,26 @@ class Sequence:
     PageAllocator; this object just records which pages back it."""
 
     def __init__(self, request_id: str, prompt_token_ids: list[int],
-                 params: SamplingParams, eos_token_id: Optional[int] = None):
+                 params: SamplingParams, eos_token_id: Optional[int] = None,
+                 block_length: int = 1):
         self.request_id = request_id
+        # The model's ``block_length`` B. A sequence is its committed
+        # prefix (K/V in the pages) and an OPEN BLOCK of B positions
+        # beyond it, each an id or masked: an autoregressive model is
+        # B = 1, whose open block is its one next position. For B > 1 the
+        # host holds the block between step programs: ``num_committed``
+        # positions lie in the pages (a multiple of B), ``block_ids`` /
+        # ``block_masked`` are the open block at
+        # [num_committed, num_committed + B), ``block_marks`` what the pass
+        # that transferred a position said of it (log-probability, top
+        # alternatives) until the position leaves in position order, and
+        # ``block_passes`` the passes the open block has taken so far.
+        self.block_length = block_length
+        self.num_committed = 0
+        self.block_ids: list[int] = []
+        self.block_masked: list[bool] = []
+        self.block_marks: list = []
+        self.block_passes = 0
         self.prompt_token_ids = list(prompt_token_ids)
         self.output_token_ids: list[int] = []
         self.output_logprobs: list[float] = []
@@ -105,6 +123,48 @@ class Sequence:
     @property
     def num_tokens(self) -> int:
         return self.num_prompt_tokens + self.num_output_tokens
+
+    @property
+    def prefill_len(self) -> int:
+        """The tokens a prefill computes and writes: whole blocks of what
+        the sequence holds (all of it at B = 1); the other
+        ``num_tokens % B`` open the first block."""
+        return self.num_tokens - self.num_tokens % self.block_length
+
+    def admit_tokens(self, end: Optional[int] = None) -> int:
+        """The positions a prefill that ends at ``end`` (default: the whole
+        of it) must hold pages for. A block model's LAST chunk also holds
+        them for the open block and the one after, which the first pass
+        beside it needs (``window_last_pos(1)``): a sequence admitted with
+        less would be preempted by its own first pass, for ever."""
+        whole, B = self.prefill_len, self.block_length
+        end = whole if end is None else end
+        return end + 2 * B if B > 1 and end >= whole else end
+
+    def open_block(self) -> None:
+        """The open block at ``num_committed``: what the sequence already
+        holds there (a prompt's tail, or tokens that left before a
+        preemption), the rest masked."""
+        held = self.all_token_ids[self.num_committed:]
+        n, B = len(held), self.block_length
+        self.block_ids = held + [0] * (B - n)
+        self.block_masked = [False] * n + [True] * (B - n)
+        self.block_marks = [None] * B
+        self.block_passes = 0
+
+    def window_last_pos(self, passes: int, max_len: int) -> int:
+        """Highest position a step program of ``passes`` passes can write:
+        ``last_window_pos`` for one token a pass; for a block model the end
+        of the block that is open after the most commits the passes allow
+        (a block takes a denoising pass at least before its commit), capped
+        by the model's length and the last block this request can reach."""
+        B = self.block_length
+        if B == 1:
+            return self.last_window_pos(self.sched_tokens - 1, passes,
+                                        max_len)
+        end = self.num_committed + B * ((passes + 1) // 2 + 1)
+        cap = -(-(self.num_prompt_tokens + self.params.max_tokens) // B) * B
+        return min(end, max_len, cap) - 1
 
     @property
     def sched_tokens(self) -> int:

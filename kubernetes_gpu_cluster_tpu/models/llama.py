@@ -129,7 +129,17 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = N
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, hd), dtype)
         layers["k_norm"] = jnp.ones((L, hd), dtype)
-    if cfg.is_moe:
+    if cfg.is_moe and cfg.moe_intermediate_size:
+        # qwen3_moe's class (sdar_moe): experts of a width of their own,
+        # 128 of them a layer, drawn a layer at a time (``_stacked_normal``).
+        ffe = cfg.moe_intermediate_size
+        layers["router"] = w(next(keys), (L, d, E), d)
+        layers["w_gate"] = _stacked_normal(next(keys), (L, E, d, ffe), d,
+                                           dtype)
+        layers["w_up"] = _stacked_normal(next(keys), (L, E, d, ffe), d, dtype)
+        layers["w_down"] = _stacked_normal(next(keys), (L, E, ffe, d), ffe,
+                                           dtype)
+    elif cfg.is_moe:
         layers["router"] = w(next(keys), (L, d, E), d)
         layers["w_gate"] = w(next(keys), (L, E, d, ff), d)
         layers["w_up"] = w(next(keys), (L, E, d, ff), d)
@@ -1786,6 +1796,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         # The pool holds positions 0..ctx-2. The STACKED pool + dynamic
         # layer index go straight to the kernel: no per-layer pool slice is
         # ever materialised (see _layer_scan).
+        if cfg.block_length > 1:
+            # A block model's pass: the row's open block over its pages.
+            with jax.named_scope("kgct.block.attend"):
+                return kernels.block_attention(
+                    q, k, v, kv.k, kv.v, meta.page_tables,
+                    meta.context_lens, scale, layer=layer_idx)
         if row_width > 1:
             return kernels.verify_attention(
                 q, k, v, kv.k, kv.v, meta.page_tables, meta.context_lens,

@@ -243,6 +243,20 @@ class Observability:
         # ``index_topk`` at most), from the lengths the host holds.
         self.dsa_visible_tokens = 0
         self.dsa_chosen_tokens = 0
+        # A block model (generation by diffusion over blocks), counted a
+        # ROW: the passes its rows took (a denoising pass or a commit), the
+        # commits among them, the tokens the passes transferred, the
+        # positions they computed (block_length a row-pass, padding rows
+        # apart), and the passes a committed block took, its commit
+        # included (engine/block.py).
+        self.block_passes = 0
+        self.block_commit_passes = 0
+        self.block_tokens_transferred = 0
+        self.block_positions_computed = 0
+        self.block_passes_per_block = Histogram(
+            "kgct_block_passes_per_block",
+            "passes a committed block took, its commit included",
+            buckets=(1, 2, 3, 4, 5, 6, 8, 9, 12, 17, 33))
         # Speculative decoding: cumulative drafted vs accepted draft tokens
         # (bonus tokens excluded from both) — feeds the
         # kgct_spec_acceptance_ratio gauge and the kgct_spec_*_tokens_total
@@ -770,6 +784,25 @@ class Observability:
             lines.append("# TYPE kgct_dsa_visible_tokens_total counter")
             lines.append("kgct_dsa_visible_tokens_total %d"
                          % self.dsa_visible_tokens)
+        if self.block_passes:
+            for name, help_, n in (
+                    ("kgct_block_passes_total",
+                     "passes a block model's rows took (denoising or "
+                     "commit), counted a row", self.block_passes),
+                    ("kgct_block_commit_passes_total",
+                     "of them, the commits (a block's K/V written)",
+                     self.block_commit_passes),
+                    ("kgct_block_tokens_transferred_total",
+                     "tokens the denoising passes transferred",
+                     self.block_tokens_transferred),
+                    ("kgct_block_positions_computed_total",
+                     "positions the passes computed (block_length a "
+                     "row-pass, padding rows apart)",
+                     self.block_positions_computed)):
+                lines.append(f"# HELP {name} {help_}")
+                lines.append(f"# TYPE {name} counter")
+                lines.append(f"{name} {n}")
+            lines.extend(self.block_passes_per_block.render())
         lines.append("# TYPE kgct_mixed_prefill_tokens_total counter")
         lines.append("kgct_mixed_prefill_tokens_total %d"
                      % self.mixed_prefill_tokens)

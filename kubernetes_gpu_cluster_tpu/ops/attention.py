@@ -93,6 +93,12 @@ def write_kv_pages_all_xla(kv_k: jax.Array, kv_v: Optional[jax.Array],
 # Ragged prefill attention
 # ---------------------------------------------------------------------------
 
+def _block_end(positions: jax.Array, block: int) -> jax.Array:
+    """The last position a query at ``positions`` sees: its own (causal),
+    or the end of its block of ``block`` positions (block-causal)."""
+    return positions if block == 1 else positions | (block - 1)
+
+
 def ragged_prefill_attention_xla(
     q: jax.Array,            # [T, n_heads, hd] (post-RoPE)
     k: jax.Array,            # [T, n_kv, hd]
@@ -100,8 +106,11 @@ def ragged_prefill_attention_xla(
     seg_ids: jax.Array,      # [T] int32 segment id per token; padding = -1
     positions: jax.Array,    # [T] int32 position within segment
     scale: float,
+    block: int = 1,
 ) -> jax.Array:
-    """Dense masked reference implementation: causal within each segment.
+    """Dense masked reference implementation: causal within each segment
+    (``block`` B > 1: block-causal, key j visible to query i iff
+    j // B <= i // B; B is a power of two).
     O(T^2) memory in the score matrix — fine for test shapes and moderate
     prefill buckets; TPU uses the flash-style Pallas kernel instead."""
     T, n_heads, hd = q.shape
@@ -116,7 +125,7 @@ def ragged_prefill_attention_xla(
     scores = jnp.einsum("tkgh,skh->kgts", qg, kf)            # [n_kv, g, T, T]
 
     same_seg = (seg_ids[:, None] == seg_ids[None, :]) & (seg_ids[:, None] >= 0)
-    causal = positions[:, None] >= positions[None, :]
+    causal = _block_end(positions, block)[:, None] >= positions[None, :]
     mask = same_seg & causal                                  # [T, T]
     scores = jnp.where(mask[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -138,8 +147,10 @@ def prefill_history_attention_xla(
     hist_len: jax.Array,     # [] int32 tokens already committed to the pool
     scale: float,
     layer: Optional[jax.Array] = None,
+    block: int = 1,
 ) -> jax.Array:
-    """Chunked-prefill attention: causal within the chunk PLUS full attention
+    """Chunked-prefill attention: causal within the chunk (block-causal
+    with ``block`` > 1) PLUS full attention
     to the sequence's already-committed history in the paged pool.
 
     This is what lets a prompt longer than the prefill token budget stream
@@ -172,7 +183,7 @@ def prefill_history_attention_xla(
     vf = v.astype(jnp.float32)
     s_b = jnp.einsum("tkgh,skh->kgts", qg, kf)              # [n_kv, g, T, T]
     same = (seg_ids[:, None] == seg_ids[None, :]) & (seg_ids[:, None] >= 0)
-    causal = positions[:, None] >= positions[None, :]
+    causal = _block_end(positions, block)[:, None] >= positions[None, :]
     s_b = jnp.where((same & causal)[None, None], s_b, -jnp.inf)
 
     s = jnp.concatenate([s_h, s_b], axis=-1)                # [n_kv, g, T, H+T]
@@ -251,10 +262,14 @@ def spec_verify_attention_xla(
     context_lens: jax.Array, # [B] committed tokens incl. the slice's first
     scale: float,
     layer: Optional[jax.Array] = None,
+    causal: bool = True,
 ) -> jax.Array:
     """Batched draft verification: B sequences, S = k+1 tokens each
     (``[last committed token, k drafts]``), every token attending to its
     sequence's paged-pool history PLUS the earlier slice tokens causally.
+    ``causal=False`` is a block model's pass (``Kernels.block_attention``'s
+    XLA twin): the same gather with every token of the slice, a row's open
+    block, visible to every other.
 
     This is ``paged_decode_attention_xla`` generalized from one query/row to
     S queries/row — the pool gather is identical; the "current token" term
@@ -296,8 +311,9 @@ def spec_verify_attention_xla(
     # In-slice scores: causal within the row's S tokens (the slice is
     # contiguous append-order, so a static lower-triangular mask suffices).
     s_b = jnp.einsum("bskgh,btkh->bkgst", qg, kf)         # [B,n_kv,g,S,S]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s_b = jnp.where(causal[None, None, None], s_b, -jnp.inf)
+    if causal:
+        tril = jnp.tril(jnp.ones((S, S), bool))
+        s_b = jnp.where(tril[None, None, None], s_b, -jnp.inf)
 
     s = jnp.concatenate([s_h, s_b], axis=-1)              # [B,n_kv,g,S,L+S]
     p = jax.nn.softmax(s, axis=-1)
@@ -457,6 +473,9 @@ class Kernels:
     # matmuls (a custom call with no partitioning rule) may be handed the
     # stack as it is (``models.llama._moe_mlp``).
     grouped_experts: bool = False
+    # The model's ``block_length``: 1 is causal attention; B > 1 makes the
+    # prompt's attention block-causal and the rows' pass ``block_attention``.
+    block: int = 1
 
     def xla_only(self) -> "Kernels":
         """The same deployment with every kernel off."""
@@ -479,14 +498,15 @@ class Kernels:
         under a tp mesh) or the dense reference."""
         if self.ring_prefill is not None:
             return self.ring_prefill(q, k, v, seg_ids, positions)
+        blk = {"block": self.block} if self.block > 1 else {}
         if not self.use_pallas:
             return ragged_prefill_attention_xla(q, k, v, seg_ids, positions,
-                                                scale)
+                                                scale, **blk)
         if self.tp_mesh is not None:
             return ragged_prefill_attention_tp(self.tp_mesh, q, k, v, seg_ids,
                                                positions, scale)
         from .pallas.flash_prefill import flash_ragged_prefill
-        return flash_ragged_prefill(q, k, v, seg_ids, positions, scale)
+        return flash_ragged_prefill(q, k, v, seg_ids, positions, scale, **blk)
 
     def chunk_attention(self, q, k, v, seg_ids, positions, k_pool, v_pool,
                         page_table, hist_len, scale, *, layer=None):
@@ -495,10 +515,11 @@ class Kernels:
         history kernel is ineligible the chunk runs the XLA reference
         (which GSPMD partitions under any mesh) while the other operations
         keep their kernels."""
+        blk = {"block": self.block} if self.block > 1 else {}
         if not self.use_pallas_hist:
             return prefill_history_attention_xla(
                 q, k, v, seg_ids, positions, k_pool, v_pool, page_table,
-                hist_len, scale, layer=layer)
+                hist_len, scale, layer=layer, **blk)
         if v_pool is None:
             from .pallas.flash_prefill_hist import (
                 flash_prefill_history_shared)
@@ -512,7 +533,7 @@ class Kernels:
         from .pallas.flash_prefill_hist import flash_prefill_history
         return flash_prefill_history(q, k, v, seg_ids, positions, k_pool,
                                      v_pool, page_table, hist_len, scale,
-                                     layer=layer)
+                                     layer=layer, **blk)
 
     def decode_attention(self, q, k_pool, v_pool, page_tables, context_lens,
                          k_cur, v_cur, scale, *, layer=None):
@@ -549,6 +570,21 @@ class Kernels:
         return spec_verify_attention_xla(q, k, v, k_pool, v_pool,
                                          page_tables, context_lens, scale,
                                          layer=layer)
+
+    def block_attention(self, q, k, v, k_pool, v_pool, page_tables,
+                        context_lens, scale, *, layer=None):
+        """A block model's pass: ``block`` query positions a sequence (its
+        open block) over its pages, read ONCE for all of them, plus the
+        block's own keys, every one visible to every other. The kernel
+        (``ops.pallas.block_attend``) or ``spec_verify_attention_xla``
+        with no in-slice mask."""
+        if not self.use_pallas:
+            return spec_verify_attention_xla(
+                q, k, v, k_pool, v_pool, page_tables, context_lens, scale,
+                layer=layer, causal=False)
+        from .pallas.block_attend import block_attend
+        return block_attend(q, k, v, k_pool, v_pool, page_tables,
+                            context_lens, scale, layer=layer)
 
     def write_pages(self, kv_k, kv_v, k_all, v_all, slot_mapping):
         """The step's new rows into the donated pool, in place: the DMA

@@ -98,6 +98,7 @@ def _prefill_kernel(
     *,
     block_q: int,
     block_k: int,
+    block: int = 1,
 ):
     i = pl.program_id(1)
     G, g, _, hd = q_ref.shape
@@ -114,6 +115,12 @@ def _prefill_kernel(
     row_tok = i * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (rows, 1), 0) % block_q
     row_seg = jnp.concatenate([qseg_ref[...]] * g, axis=0)      # [rows, 1]
+    if block > 1:
+        # Block-causal: a row sees to the END of its block of ``block``
+        # tokens (a power of two; segments start on block edges, so the flat
+        # index serves). Tile and q-block edges are multiples of it: the
+        # windows are the causal ones, only the masked tiles differ.
+        row_tok = row_tok | (block - 1)
 
     def tile(jj, masked):
         """Key tile jj against every row of the step. ``masked`` False: every
@@ -183,7 +190,7 @@ def _windows(seg, T: int, block_q: int, block_k: int):
 
 def flash_ragged_prefill(q, k, v, seg_ids, positions, scale, *,
                          block_q: int = None, block_k: int = 512,
-                         interpret: bool = False):
+                         interpret: bool = False, block: int = 1):
     """q: [T, nh, hd]; k: [T, n_kv, hd]; v: [T, n_kv, hv]; seg_ids: [T]
     (-1 = padding). positions are implied by the flat order (causal within
     segment) and are accepted only for dispatcher signature parity.
@@ -224,6 +231,12 @@ def flash_ragged_prefill(q, k, v, seg_ids, positions, scale, *,
         Tp // bk, 1, bk)
 
     kernel = functools.partial(_prefill_kernel, block_q=block_q, block_k=bk)
+    if block > 1:
+        if T % block or block_q % block or bk % block:
+            raise ValueError(
+                f"block-causal prefill: {T} tokens in q blocks of {block_q} "
+                f"and key tiles of {bk} are not whole blocks of {block}")
+        kernel = functools.partial(kernel, block=block)
 
     def lanes(n):
         return pl.cdiv(n, 128) * 128

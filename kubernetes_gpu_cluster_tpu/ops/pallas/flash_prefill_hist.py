@@ -109,6 +109,7 @@ def _hist_kernel(
     q_per_kv: int,
     head_dim: int,
     lanes: int,        # W
+    block: int = 1,    # > 1: block-causal within the chunk
 ):
     b = pl.program_id(0)
     i = pl.program_id(1)
@@ -208,7 +209,10 @@ def _hist_kernel(
         mask = None
         if masked:
             cols = jj * bk + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
-            mask = (cols <= row_tok) & (cols < n_valid)
+            # block-causal: a row sees to the end of its block (the chunk
+            # starts on a block edge, q blocks and tiles are whole blocks)
+            sees = row_tok if block == 1 else row_tok | (block - 1)
+            mask = (cols <= sees) & (cols < n_valid)
         online_update(kc_ref[at, :], vc_ref[at, :], mask)
 
     # Blocks wholly under the q block's first token need no mask.
@@ -263,7 +267,7 @@ def _lane_block(kd: int, head_dim: int) -> int:
 def flash_prefill_history(q, k, v, seg_ids, positions, k_pool, v_pool,
                           page_table, hist_len, scale, *, layer=None,
                           block_q: int = None, block_k: int = 512,
-                          interpret: bool = False):
+                          interpret: bool = False, block: int = 1):
     """q: [T, nh, hd]; k/v: [T, n_kv, hd] (this chunk); k_pool/v_pool:
     [P, ps, n_kv*hd] or [L, P, ps, n_kv*hd] with ``layer``; page_table:
     [pps] int32; hist_len: [] int32; seg_ids: [T] (0 = chunk token, -1 =
@@ -335,6 +339,12 @@ def flash_prefill_history(q, k, v, seg_ids, positions, k_pool, v_pool,
                                block_q=block_q, block_k=bk, group_pages=C,
                                page_size=ps, pps=pps, heads=hq, q_per_kv=g,
                                head_dim=hd, lanes=W)
+    if block > 1:
+        if T % block or block_q % block or bk % block:
+            raise ValueError(
+                f"block-causal chunk: {T} tokens in q blocks of {block_q} "
+                f"and key tiles of {bk} are not whole blocks of {block}")
+        kernel = functools.partial(kernel, block=block)
     vmem = (rows * per_row
             + 2 * 2 * rows * 128 * (isz + q.dtype.itemsize)  # q, out blocks
             + 2 * 2 * Tp * W * isz                           # chunk K/V

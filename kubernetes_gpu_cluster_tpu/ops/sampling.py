@@ -433,3 +433,20 @@ def token_logprobs(logits: jax.Array, tokens: jax.Array,
         safe_temp = jnp.where(temperature <= 0, 1.0, temperature)
         logits = logits / safe_temp[:, None]
     return _chosen_logprobs(logits, tokens)
+
+
+def block_transfer(confidence: jax.Array, masked: jax.Array, n: int,
+                   threshold: float) -> jax.Array:
+    """A block model's transfer rule (``low_confidence_dynamic`` of
+    ``block_diffusion_generate``): of a row's MASKED positions, every one
+    whose confidence exceeds ``threshold`` if there are at least ``n`` of
+    them, else the ``n`` most confident (ties to the lower position; fewer
+    are masked: all of them). confidence [R, B] float32 (the probability of
+    the candidate id), masked [R, B] bool -> [R, B] bool."""
+    conf = jnp.where(masked, confidence, -jnp.inf)
+    high = conf > threshold
+    order = jnp.argsort(-conf, axis=1, stable=True)
+    rank = jnp.argsort(order, axis=1, stable=True)   # a position's place
+    most = (rank < n) & masked
+    enough = jnp.sum(high, axis=1, keepdims=True) >= n
+    return jnp.where(enough, high, most)

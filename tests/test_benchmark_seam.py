@@ -36,13 +36,13 @@ PROM_READERS = {"prom_hist_mean", "prom_hist_mean_where",
 FAMILY_KEYS = ("family", "minus", "num", "den")
 KERNEL_READERS = {"kernel_flops_share", "hc_kernel_hbm_share",
                   "kda_kernel_hbm_share", "latent_kernel_hbm_share",
-                  "ssm_kernel_hbm_share"}
+                  "ssm_kernel_hbm_share", "block_attend_kernel_hbm_share"}
 # Nothing from the program: the client's own clock, byte models over
 # another metric (``step_metric``), the device's busy intervals.
 NO_PROGRAM_NAME = {"client", "roofline", "hc_step_hbm_share",
                    "hybrid_step_hbm_share", "kda_step_hbm_share",
                    "latent_moe_step_hbm_share", "trace_idle",
-                   "dsa_step_hbm_share",
+                   "dsa_step_hbm_share", "block_step_hbm_share",
                    # ... and arrays of the configuration's own shapes
                    "dsa_rows_attend_hbm_share", "dsa_select_share"}
 # Needles of ``trace_op_share`` that are XLA's words, not the program's.
@@ -196,3 +196,32 @@ def test_metric_names_what_the_program_has(path, program):
     else:
         pytest.fail(f"{path.name}: reader {reader} is not classified here: "
                     "say what it takes from the program")
+
+
+def test_a_block_model_s_names_are_the_ones_its_metrics_read(program):
+    """ISSUE 50's tracing: the counters ``block_tokens_per_pass`` divides
+    (two families without labels, which is what ``prom_counter_ratio``
+    takes), the two beside them, the histogram, the kernel
+    ``block_attend_kernel_hbm_share`` finds by name, the three named
+    scopes, and the step functions ``decode_step_ms`` / ``mixed_step_ms``
+    find a block model's programs by."""
+    for family in ("kgct_block_passes_total",
+                   "kgct_block_commit_passes_total",
+                   "kgct_block_tokens_transferred_total",
+                   "kgct_block_positions_computed_total"):
+        assert program.labels.get(family) == set(), family
+    assert "kgct_block_passes_per_block" in program.histograms
+    assert "block_attend" in program.kernels
+    for scope in ("kgct.block.attend", "kgct.block.transfer",
+                  "kgct.block.commit"):
+        assert scope in program.strings, scope
+    block = LintModule(PACKAGE / "engine" / "block.py", root=REPO)
+    names = {getattr(j.node, "name", "") for j in block.jitted_functions}
+    assert any("decode_window" in n for n in names), names
+    assert any("mixed_step" in n for n in names), names
+    for name in ("block_step_hbm_share", "block_attend_kernel_hbm_share",
+                 "block_tokens_per_pass"):
+        spec = json.loads((REPO / "perfbench" / "layer_metrics"
+                           / f"{name}.json").read_text())
+        assert (REPO / "perfbench" / "readers"
+                / f"{spec['reader']}.py").is_file()

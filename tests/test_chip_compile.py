@@ -424,3 +424,53 @@ def test_glm_chunk_step_is_built_for_the_servers_16_seats(one_chip,
         for shape in (f"[{rows},{k},{w}]", f"[{rows * k},{w}]",
                       f"[{rows},12289]", f"[{rows},{k}]"):
             assert shape not in text, shape
+
+
+def test_block_attend_compiles_for_a_v5e_at_the_cell_s_shapes(
+        one_chip, no_compile_cache):
+    """sdar-30b-a3b-chat's pass at the served cut: 64 rows of 4 positions,
+    32 q / 4 kv heads of 128, over a 6-layer bf16 pool of 2049 pages under
+    a dynamic layer index, tables 32 pages wide. The pool is read where it
+    lies: nothing of its size is copied."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.block_attend import (
+        block_attend)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    R, S, nh, nkv, hd = 64, 4, 32, 4, 128
+    pool = arr((6, 2049, 128, nkv * hd))
+    compiled = jax.jit(lambda q, k, v, kp, vp, tb, ctx, lyr: block_attend(
+        q, k, v, kp, vp, tb, ctx, hd ** -0.5, layer=lyr)).lower(
+        arr((R * S, nh, hd)), arr((R * S, nkv, hd)), arr((R * S, nkv, hd)),
+        pool, pool, arr((R, 32), jnp.int32), arr((R,), jnp.int32),
+        arr((1,), jnp.int32)).compile()
+    assert "%block_attend" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**22
+
+
+@pytest.mark.parametrize("kernel", ["flash_prefill", "flash_prefill_hist"])
+def test_block_causal_flash_kernels_compile_for_a_v5e(one_chip,
+                                                      no_compile_cache,
+                                                      kernel):
+    """The same cut's prompt: a 2048-token bucket under the block-causal
+    mask (block 4), fresh and as a chunk with history."""
+    from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill import (
+        flash_ragged_prefill)
+    from kubernetes_gpu_cluster_tpu.ops.pallas.flash_prefill_hist import (
+        flash_prefill_history)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    T, nh, nkv, hd, i32 = 2048, 32, 4, 128, jnp.int32
+    qkv = (arr((T, nh, hd)), arr((T, nkv, hd)), arr((T, nkv, hd)),
+           arr((T,), i32), arr((T,), i32))
+    if kernel == "flash_prefill":
+        compiled = jax.jit(lambda *a: flash_ragged_prefill(
+            *a, hd ** -0.5, block=4)).lower(*qkv).compile()
+    else:
+        pool = arr((6, 2049, 128, nkv * hd))
+        compiled = jax.jit(lambda *a: flash_prefill_history(
+            *a[:-1], hd ** -0.5, layer=a[-1], block=4)).lower(
+            *qkv, pool, pool, arr((32,), i32), arr((), i32),
+            arr((), i32)).compile()
+    assert f"%{kernel}" in compiled.as_text()

@@ -280,3 +280,42 @@ def test_swap_load_compile_count_bounded():
     _run_swap_wave(eng, "w2")
     assert _compiled_variants(eng) == first, \
         "second identical swap wave triggered new XLA compilations"
+
+
+# -- a block model: W passes over the rows' open blocks ----------------------
+
+def _block_engine():
+    cfg = EngineConfig(
+        model=get_model_config("debug-block-moe"),
+        cache=CacheConfig(page_size=8, num_pages=129),
+        scheduler=SchedulerConfig(
+            max_num_seqs=4, max_prefill_tokens=32,
+            decode_buckets=DECODE_BUCKETS, prefill_buckets=PREFILL_BUCKETS,
+            decode_window=3, mixed_batch_enabled=True))
+    return LLMEngine(cfg)
+
+
+def test_block_load_compile_count_bounded():
+    """A block model's family: ONE window program a decode row bucket
+    (whatever phases its rows' blocks are in, however many passes their
+    blocks have taken), one mixed program a (chunk bucket, row bucket,
+    history width), and the prefill programs of every model; the
+    autoregressive window and mixed step are never traced."""
+    eng = _block_engine()
+    _run_wave(eng, "w1")
+    first = _compiled_variants(eng)
+    assert eng.obs.step_kind_counts["mixed"] > 0
+    assert eng.obs.block_commit_passes > 0
+    n_tp, n_rows = len(PREFILL_BUCKETS), len(DECODE_BUCKETS)
+    assert 0 < eng._block_window_fn._cache_size() <= n_rows
+    assert 0 < eng._block_mixed_fn._cache_size() <= n_tp * n_rows * 3
+    for fn in (eng._decode_fn, eng._decode_fn_greedy, eng._mixed_fn):
+        assert fn._cache_size() == 0
+    bound = (n_tp * n_rows          # pure prefill
+             + n_tp * n_rows * 3    # block mixed
+             + n_tp * 3             # solo chunk
+             + n_rows)              # the block window: one mode
+    assert first <= bound, (first, bound)
+    _run_wave(eng, "w2")
+    assert _compiled_variants(eng) == first, \
+        "second identical load wave triggered new XLA compilations"
