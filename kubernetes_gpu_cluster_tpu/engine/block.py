@@ -28,10 +28,18 @@ built and measured, PERF.md section 6, PR 50: 4 % off ``tpot`` and a
 ``mixed_step`` readers find them: a pass over all rows is this model's
 decode step.
 
-A block program is dispatched with nothing in flight and fetched before
-the next is scheduled (``LLMEngine._chain_break``: "block"): the host holds
-the truth of every open block between programs. Chaining them would carry
-the blocks on the device as ``_chained_tokens`` carries one token a row.
+A block program is dispatched BEHIND the one in flight, as every other
+step program (``LLMEngine._step``): each hands on its rows' final state
+(ids, masked flags, start, passes: ``hand_on``, one shape whatever the row
+bucket), and a row of the next names the row of it that holds its open
+block (``Scheduler.fill_block_rows``'s source column), as
+``_chained_tokens`` carries one token a row. The host replays a program
+one program late (``replay``): a row it finds finished rides the program
+already dispatched as a zombie, its pages held until that one is fetched;
+pages are grown for what the passes in flight may commit
+(``Sequence.window_last_pos``). The chain breaks for what breaks it for
+every model: an import since the last schedule, and a batch that needs a
+preemption (the victim's open block is on the chip: fetch first).
 """
 
 from __future__ import annotations
@@ -67,17 +75,37 @@ def build_block_fns(engine: "LLMEngine"):
     threshold = cfg.confidence_threshold
     reports_load = engine._reports_expert_load
 
-    C = 2 * B + 3       # columns of a row's block in the packed buffer
+    S = 2 * B + 2       # a row's state: ids, masked flags, start, passes
+    C = S + 2           # columns of a row's block in the packed buffer
+    last_width = engine._last_width
 
-    def unpack(int_b):
-        """int_b [R, 2B+3 | 3 | pages]: the row's block (ids, masked flags,
-        start, the positions its pages cover (0: a padding row), passes the
-        block has taken), (top_k, seed, top_n), its page table: ONE upload,
-        as the autoregressive programs pack theirs."""
-        blk = int_b[:, :C]
-        state = (blk[:, :B], blk[:, B:2 * B] > 0, blk[:, 2 * B],
-                 blk[:, 2 * B + 1], blk[:, 2 * B + 2])
+    def unpack(int_b, prev):
+        """int_b [R, 2B+4 | 3 | pages]: the row's block (its state as the
+        host last fetched it, the positions its pages cover (0: a padding
+        row), the row of ``prev`` that holds the state instead or -1),
+        (top_k, seed, top_n), its page table: ONE upload, as the
+        autoregressive programs pack theirs. ``prev``: the final state of
+        the program dispatched before this one (``hand_on``), still on the
+        device."""
+        src = int_b[:, S + 1]
+        st = jnp.where((src >= 0)[:, None],
+                       jnp.take(prev, jnp.maximum(src, 0), axis=0),
+                       int_b[:, :S])
+        state = (st[:, :B], st[:, B:2 * B] > 0, st[:, 2 * B], int_b[:, S],
+                 st[:, 2 * B + 1])
         return state, int_b[:, C + 3:], int_b[:, C:C + 3]
+
+    def hand_on(state):
+        """A program's final state a row as ``[last_width, 2B+2]`` int32,
+        whatever its row bucket: what ``unpack`` of the next program reads,
+        one shape for every predecessor, so that a step kind stays one
+        program (``_last_tokens``, for blocks)."""
+        ids, masked, start, _, passes = state
+        rows = jnp.concatenate(
+            [ids, masked.astype(jnp.int32), start[:, None],
+             passes[:, None]], axis=1)
+        return jnp.zeros((last_width, S), jnp.int32).at[
+            :rows.shape[0]].set(rows)
 
     def pack(out):
         """A pass's outputs a row as ONE int32 array [R, 2B+1] (transferred
@@ -153,8 +181,8 @@ def build_block_fns(engine: "LLMEngine"):
                tlps.reshape(R, B, TOP_LOGPROBS), full)
         return kv, (new_ids, new_masked, new_start, limit, new_passes), out
 
-    def decode_window_block(params, kv: KVCache, int_b, float_b, key):
-        state, page_tables, samp = unpack(int_b)
+    def decode_window_block(params, kv: KVCache, prev, int_b, float_b, key):
+        state, page_tables, samp = unpack(int_b, prev)
 
         def one(carry, w):
             kv, state = carry
@@ -163,18 +191,19 @@ def build_block_fns(engine: "LLMEngine"):
                 jax.random.fold_in(key, w))
             return (kv, state), pack(out)
 
-        (kv, _), outs = jax.lax.scan(one, (kv, state), jnp.arange(W))
-        return (*outs, kv)          # each [W, R, ...]
+        (kv, state), outs = jax.lax.scan(one, (kv, state), jnp.arange(W))
+        return (*outs, hand_on(state), kv)          # outs: each [W, R, ...]
 
-    def mixed_step_block(params, kv: KVCache, int_t, chunk_page_table,
+    def mixed_step_block(params, kv: KVCache, prev, int_t, chunk_page_table,
                          hist_len, int_b, float_b, key):
         # int_t: [4, Tp] the chunk alone (tokens, seg_ids, positions, slots)
-        state, page_tables, samp = unpack(int_b)
+        state, page_tables, samp = unpack(int_b, prev)
         load = [] if reports_load else None
-        kv, _, out = block_pass(
+        kv, state, out = block_pass(
             params, kv, state, page_tables, samp, float_b, key,
             chunk=(int_t, chunk_page_table, hist_len), load=load)
-        return (*(o[None] for o in pack(out)), kv, *(load or ()))
+        return (*(o[None] for o in pack(out)), hand_on(state), kv,
+                *(load or ()))
 
     return (engine._maybe_jit(decode_window_block, donate_argnums=(1,)),
             engine._maybe_jit(mixed_step_block, donate_argnums=(1,)))
@@ -190,9 +219,12 @@ def refuse_request(params) -> None:
             "in any order; no histogram of 'tokens so far' exists for it")
 
 
-def dispatch(engine: "LLMEngine", rec: dict, float_b, step_key) -> None:
-    """Dispatch a block window or a block mixed step; what its fetch needs
-    goes into ``rec``."""
+def dispatch(engine: "LLMEngine", rec: dict, prev, float_b,
+             step_key) -> None:
+    """Dispatch a block window or a block mixed step, its chained rows'
+    blocks read from ``prev`` (the final state of the program dispatched
+    before it); what its fetch needs goes into ``rec``, and its own final
+    state as ``last``."""
     ph = engine.obs.phases.phase
     batch = rec["batch"]
     mixed = batch.kind == "mixed"
@@ -210,122 +242,129 @@ def dispatch(engine: "LLMEngine", rec: dict, float_b, step_key) -> None:
     with ph("device_dispatch", rec):
         if mixed:
             engine.stats.prefill_tokens += batch.prefill_token_count
-            (toks, tids, tlps, engine.kv_cache,
+            (toks, tids, tlps, last, engine.kv_cache,
              *load) = engine._block_mixed_fn(
-                engine.params, engine.kv_cache, int_t, chunk_pt,
+                engine.params, engine.kv_cache, prev, int_t, chunk_pt,
                 jnp.int32(batch.hist_len), int_b, float_b, step_key)
         else:
-            (toks, tids, tlps,
+            (toks, tids, tlps, last,
              engine.kv_cache) = engine._block_window_fn(
-                engine.params, engine.kv_cache, int_b, float_b, step_key)
+                engine.params, engine.kv_cache, prev, int_b, float_b,
+                step_key)
             load = ()
     rec.update(t_dispatched=time.monotonic(), toks=toks, tids=tids,
-               tlps=tlps, load=load, counts=None, last=engine._no_pred,
-               zombies=set(), block=True)
+               tlps=tlps, load=load, counts=None, last=last, zombies=set())
 
 
-def retire(engine: "LLMEngine", step: dict) -> list:
-    """Fetch a block program's passes, replay them over the rows' open
-    blocks and hand on the tokens that became final in position order."""
+def fetch(engine: "LLMEngine", step: dict) -> tuple:
+    """Copy what a block program's replay needs off the device (inside
+    ``LLMEngine._fetching``): every pass's transferred ids,
+    log-probabilities and commit flags, and the top alternatives if a
+    request asked."""
+    batch = step["batch"]
+    B = engine.model_config.block_length
+    packed = np.asarray(step["toks"])        # [W, R, 2B+1]: ``pack``
+    toks = packed[:, :, :B].tolist()
+    lps = packed[:, :, B:2 * B].view(np.float32).tolist()
+    commit = packed[:, :, 2 * B].tolist()
+    top_i = top_l = None
+    if any(s.params.top_logprobs for s in batch.seqs):
+        top_i = np.asarray(step["tids"])
+        top_l = np.asarray(step["tlps"])
+    if batch.kind == "mixed":
+        engine.obs.on_expert_load(
+            step["load"], model_lib.grouped_dispatch(
+                len(batch.tokens), engine.model_config, engine.kernels))
+    return toks, lps, commit, top_i, top_l
+
+
+def replay(engine: "LLMEngine", step: dict, fetched: tuple,
+           carried: frozenset) -> tuple[list, dict]:
+    """Replay a fetched block program's passes over the host's copy of its
+    rows' open blocks and hand on the tokens that became final in position
+    order; returns the outputs and what the step's record adds. The
+    program's successor may be running already: a row that finishes here
+    and has a row there (``carried``) keeps its pages until that one is
+    fetched (``LLMEngine._finish_row``), and rides it as a zombie, whose
+    passes and tokens are counted nowhere."""
     from .engine import RequestOutput
 
-    ph = engine.obs.phases.phase
-    engine.obs.phases.file_under(step["phases"])
+    toks, lps, commit, top_i, top_l = fetched
     batch = step["batch"]
-    cfg = engine.model_config
-    B = cfg.block_length
-    with engine._fetching(step):
-        packed = np.asarray(step["toks"])        # [W, R, 2B+1]: ``pack``
-        toks = packed[:, :, :B].tolist()
-        lps = packed[:, :, B:2 * B].view(np.float32).tolist()
-        commit = packed[:, :, 2 * B].tolist()
-        top_i = top_l = None
-        if any(s.params.top_logprobs for s in batch.seqs):
-            top_i = np.asarray(step["tids"])
-            top_l = np.asarray(step["tlps"])
-        if batch.kind == "mixed":
-            engine.obs.on_expert_load(
-                step["load"], model_lib.grouped_dispatch(
-                    len(batch.tokens), cfg, engine.kernels))
-    if engine._sanitizer is not None:
-        engine._sanitizer.on_step_retire()
+    B = engine.model_config.block_length
     W = len(toks)
-    # (a mixed step's head, last in ``seqs``, has no row)
-    rows = batch.seqs[:len(batch.seqs) - (batch.kind == "mixed")]
     max_len = engine.config.effective_max_len
     outputs, passes, commits, moved = [], 0, 0, 0
-    with ph("postproc"):
-        for r, seq in enumerate(rows):
-            if seq.request_id in step["zombies"] or seq.is_finished:
+    for r, seq in batch.device_seq_rows():
+        if seq.request_id in step["zombies"] or seq.is_finished:
+            continue
+        had_first = seq.first_token_time is not None
+        want_lps = seq.params.logprobs
+        want_top = seq.params.top_logprobs if top_i is not None else 0
+        new_tokens, new_lps, new_tops = [], [], []
+        for w in range(W):
+            passes += 1
+            if commit[w][r]:
+                commits += 1
+                engine.obs.block_passes_per_block.observe(
+                    seq.block_passes + 1)
+                seq.num_committed += B
+                seq.open_block()
                 continue
-            had_first = seq.first_token_time is not None
-            want_lps = seq.params.logprobs
-            want_top = seq.params.top_logprobs if top_i is not None else 0
-            new_tokens, new_lps, new_tops = [], [], []
-            for w in range(W):
-                passes += 1
-                if commit[w][r]:
-                    commits += 1
-                    engine.obs.block_passes_per_block.observe(
-                        seq.block_passes + 1)
-                    seq.num_committed += B
-                    seq.open_block()
+            seq.block_passes += 1
+            for i, tok in enumerate(toks[w][r]):
+                if tok < 0:
                     continue
-                seq.block_passes += 1
-                for i, tok in enumerate(toks[w][r]):
-                    if tok < 0:
-                        continue
-                    moved += 1
-                    seq.block_ids[i] = tok
-                    seq.block_masked[i] = False
-                    top = None
-                    if want_top:
-                        top = [(int(t), float(v)) for t, v in
-                               zip(top_i[w, r, i, :want_top],
-                                   top_l[w, r, i, :want_top])]
-                        if tok not in (t for t, _ in top):
-                            top.append((tok, lps[w][r][i]))
-                    seq.block_marks[i] = (lps[w][r][i], top)
-                # What is final in position order leaves now.
-                at = seq.num_tokens - seq.num_committed
-                while at < B and not seq.block_masked[at] \
-                        and not seq.is_finished:
-                    lp, top = seq.block_marks[at]
-                    seq.append_token(seq.block_ids[at],
-                                     lp if want_lps else None, top)
-                    new_tokens.append(seq.block_ids[at])
-                    if want_lps:
-                        new_lps.append(lp)
-                    if want_top:
-                        new_tops.append(top)
-                    reason = seq.check_stop(max_len)
-                    if reason is not None:
-                        engine.scheduler.finish(seq, reason)
-                    at += 1
-                if seq.is_finished:
-                    break
-            engine.stats.tokens_generated += len(new_tokens)
-            if not had_first and seq.first_token_time is not None:
-                engine.obs.on_first_token(seq, fetch_s=step["transfer_s"],
-                                          step=step["step"])
+                moved += 1
+                seq.block_ids[i] = tok
+                seq.block_masked[i] = False
+                top = None
+                if want_top:
+                    top = [(int(t), float(v)) for t, v in
+                           zip(top_i[w, r, i, :want_top],
+                               top_l[w, r, i, :want_top])]
+                    if tok not in (t for t, _ in top):
+                        top.append((tok, lps[w][r][i]))
+                seq.block_marks[i] = (lps[w][r][i], top)
+            # What is final in position order leaves now.
+            at = seq.num_tokens - seq.num_committed
+            while at < B and not seq.block_masked[at] \
+                    and not seq.is_finished:
+                lp, top = seq.block_marks[at]
+                seq.append_token(seq.block_ids[at],
+                                 lp if want_lps else None, top)
+                new_tokens.append(seq.block_ids[at])
+                if want_lps:
+                    new_lps.append(lp)
+                if want_top:
+                    new_tops.append(top)
+                reason = seq.check_stop(max_len)
+                if reason is not None:
+                    engine._finish_row(seq, reason, carried)
+                at += 1
             if seq.is_finished:
-                engine.stats.requests_finished += 1
-            outputs.append(RequestOutput(
-                request_id=seq.request_id,
-                prompt_token_ids=seq.prompt_token_ids,
-                output_token_ids=list(seq.output_token_ids),
-                finished=seq.is_finished,
-                finish_reason=(seq.finish_reason.value
-                               if seq.finish_reason else None),
-                new_token_ids=new_tokens,
-                new_logprobs=new_lps if want_lps else None,
-                output_logprobs=(list(seq.output_logprobs)
-                                 if want_lps else None),
-                new_top_logprobs=new_tops if want_top else None,
-                output_top_logprobs=(list(seq.output_top_logprobs)
-                                     if seq.params.top_logprobs else None),
-                t_ready=step["t_ready"]))
-        engine._drain_deferred()
+                break
+        engine.stats.tokens_generated += len(new_tokens)
+        if not had_first and seq.first_token_time is not None:
+            engine.obs.on_first_token(seq, fetch_s=step["transfer_s"],
+                                      step=step["step"])
+        if seq.is_finished:
+            engine.stats.requests_finished += 1
+        outputs.append(RequestOutput(
+            request_id=seq.request_id,
+            prompt_token_ids=seq.prompt_token_ids,
+            output_token_ids=list(seq.output_token_ids),
+            finished=seq.is_finished,
+            finish_reason=(seq.finish_reason.value
+                           if seq.finish_reason else None),
+            new_token_ids=new_tokens,
+            new_logprobs=new_lps if want_lps else None,
+            output_logprobs=(list(seq.output_logprobs)
+                             if want_lps else None),
+            new_top_logprobs=new_tops if want_top else None,
+            output_top_logprobs=(list(seq.output_top_logprobs)
+                                 if seq.params.top_logprobs else None),
+            t_ready=step["t_ready"]))
     obs = engine.obs
     obs.block_passes += passes
     obs.block_commit_passes += commits
@@ -334,7 +373,8 @@ def retire(engine: "LLMEngine", step: dict) -> list:
     # The record counts what the program did for what it is: tokens
     # TRANSFERRED, positions computed (padding rows' included in
     # ``padded_tokens``), and its passes.
-    extra = engine._routed(passes * B)
+    extra = dict(engine._routed(passes * B), passes=passes,
+                 commit_passes=commits, positions=passes * B)
     step["tokens"] = moved
     if batch.kind == "mixed":
         extra.update(prefill_tokens=batch.prefill_token_count,
@@ -342,6 +382,4 @@ def retire(engine: "LLMEngine", step: dict) -> list:
         step["tokens"] += batch.prefill_token_count
     else:
         extra["mode"] = "block"
-    engine._retired(step, outputs, passes=passes, commit_passes=commits,
-                    positions=passes * B, **extra)
-    return outputs
+    return outputs, extra

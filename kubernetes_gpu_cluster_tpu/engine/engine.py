@@ -305,11 +305,15 @@ class LLMEngine:
         # such output (or these zeros), so that a step dispatched behind an
         # unfetched one reads its decode rows' input tokens on the device.
         # One width, the largest row bucket there is, keeps each step kind
-        # ONE program whatever precedes it.
+        # ONE program whatever precedes it. A block model's programs hand
+        # on their rows' open blocks instead (engine/block.py: ``hand_on``).
         sc = config.scheduler
         self._last_width = max(sc.decode_buckets[-1],
                                _bucket(sc.max_num_seqs, sc.decode_buckets))
-        self._no_pred = jnp.zeros(self._last_width, jnp.int32)
+        B = config.model.block_length
+        self._no_pred = jnp.zeros(
+            self._last_width if B == 1 else (self._last_width, 2 * B + 2),
+            jnp.int32)
         self._prefill_fn = self._build_prefill_fn()
         # Two compiled window programs: all-greedy batches (the common
         # serving case) never trace sampling at all — argmax only. Selection
@@ -2060,12 +2064,15 @@ class LLMEngine:
         flight a sequence" (``Sequence.sched_tokens``) and dispatched
         BEHIND the step in flight; its decode rows read their input token
         from that step's device-resident last-token output
-        (``_chained_tokens``). So the device-to-host download of step n,
-        its post-processing, the hand-over of its tokens and the scheduling
-        of step n+2 all run under step n+1, and an admission or a finish no
-        longer empties the queue. A row found finished in step n rides
-        n+1, already dispatched, as a zombie and no further; a row whose
-        ``max_tokens`` falls inside n is left out of n+1. What a finished
+        (``_chained_tokens``; a block model's rows their open block from
+        its final state, engine/block.py). So the device-to-host download
+        of step n, its post-processing, the hand-over of its tokens and the
+        scheduling of step n+2 all run under step n+1, and an admission or
+        a finish no longer empties the queue. A row found finished in step
+        n rides n+1, already dispatched, as a zombie and no further; a row
+        whose ``max_tokens`` falls inside n is left out of n+1 (a block
+        model's cannot be told beforehand, a pass yields 0 to B tokens: it
+        rides n+1). What a finished
         sequence holds is released when the last dispatched program that
         carries it has been fetched (``_drain_deferred``).
 
@@ -2089,13 +2096,10 @@ class LLMEngine:
 
     def _chain_break(self, pred: dict) -> Optional[str]:
         """Why no step may be scheduled while ``pred`` is in flight, read
-        from what the engine holds; None when one may."""
+        from what the engine holds; None when one may. (A block model
+        takes neither speculation nor penalties: its chain breaks when
+        stale, and for want of pages in ``schedule``.)"""
         sched = self.scheduler
-        if self.model_config.block_length > 1:
-            # The host holds every row's open block between programs
-            # (engine/block.py): the next one is scheduled from what this
-            # one transferred.
-            return "block"
         if sched.spec_enabled:
             # Draft verification IS the speculation, and a chained window
             # would pin the engine in legacy decode after n-gram matches
@@ -2264,8 +2268,7 @@ class LLMEngine:
             self._dispatch_window(rec, prev, _pack_float_b(batch),
                                   jax.random.key(0), None)
             prev = rec["last"]
-        # (a block program hands its successor no token: wait for its own)
-        jax.block_until_ready((prev, rec.get("toks")))
+        jax.block_until_ready(prev)
         logger.info("the window at %d rows met before the first request: "
                     "%.1f s", len(batch.tokens), time.monotonic() - t0)
 
@@ -2305,7 +2308,7 @@ class LLMEngine:
                 self._dispatch_prefill(rec, prev, _pack_float_b(batch),
                                        jax.random.key(0))
                 prev = rec["last"]
-            jax.block_until_ready((prev, rec.get("toks")))
+            jax.block_until_ready(prev)
         logger.info("%d mixed steps (chunk rung, history width) %s beside "
                     "%d rows met before the first request: %.1f s",
                     len(steps), steps, sc.max_num_seqs,
@@ -2320,7 +2323,7 @@ class LLMEngine:
         ph = self.obs.phases.phase
         batch = rec["batch"]
         if batch.block is not None:     # a block model's pass beside a chunk
-            return block_steps.dispatch(self, rec, float_b, step_key)
+            return block_steps.dispatch(self, rec, prev, float_b, step_key)
         mixed = batch.kind == "mixed"
         with ph("host_prep"):
             int_t = jnp.asarray(np.stack(
@@ -2361,53 +2364,65 @@ class LLMEngine:
                  *load) = self._prefill_fn(
                     self.params, self.kv_cache, int_t, int_b, float_b,
                     bias_ids, bias_vals, step_key)
+        zombies = {batch.seqs[-1].request_id} if batch.partial else set()
+        if self.model_config.block_length > 1:
+            # A block model's prefill samples nothing (the prompt's tail
+            # opens the first block) and touches no open block: it hands
+            # on its predecessor's final state.
+            zombies, last = {s.request_id for s in batch.seqs}, prev
         rec.update(t_dispatched=time.monotonic(), toks=toks, lps=lps,
                    tids=tids, tlps=tlps, last=last, load=load, counts=None,
-                   zombies=({s.request_id for s in batch.seqs}
-                            if self.model_config.block_length > 1
-                            # a block model's prefill samples nothing: the
-                            # prompt's tail opens the first block
-                            else {batch.seqs[-1].request_id} if batch.partial
-                            else set()))
+                   zombies=zombies)
 
     def _retire(self, step: dict,
                 successor: Optional[dict]) -> list[RequestOutput]:
         """Fetch the tokens of ``step`` (the wait for the device, under its
         ``successor`` when one was dispatched), commit them, and release
-        what no dispatched program can write any more."""
-        if step.get("block"):
-            return block_steps.retire(self, step)
+        what no dispatched program can write any more. A block program's
+        passes are replayed over the host's copy of its rows' open blocks
+        (engine/block.py) where another's tokens are appended."""
         ph = self.obs.phases.phase
         # The fetch and the post-processing serve THIS program, whatever
         # the iteration launched before it came here.
         self.obs.phases.file_under(step["phases"])
         batch = step["batch"]
         window = step["kind"] == "decode"
+        block = batch.block is not None
         with self._fetching(step):
-            toks = np.asarray(step["toks"])
-            lps = np.asarray(step["lps"])
-            top_i = top_l = None
-            if any(s.params.top_logprobs for s in batch.seqs):
-                # Alternatives ride the device outputs unconditionally; the
-                # device->host TRANSFER happens only when someone asked.
-                top_i = np.asarray(step["tids"])
-                top_l = np.asarray(step["tlps"])
-            if not window:
-                toks, lps = toks[:, None], lps[:, None]
-                if top_i is not None:
-                    top_i, top_l = top_i[:, None], top_l[:, None]
-                self.obs.on_expert_load(
-                    step["load"], model_lib.grouped_dispatch(
-                        len(batch.tokens), self.model_config, self.kernels))
+            if block:
+                fetched = block_steps.fetch(self, step)
+            else:
+                toks = np.asarray(step["toks"])
+                lps = np.asarray(step["lps"])
+                top_i = top_l = None
+                if any(s.params.top_logprobs for s in batch.seqs):
+                    # Alternatives ride the device outputs unconditionally;
+                    # the device->host TRANSFER happens only when someone
+                    # asked.
+                    top_i = np.asarray(step["tids"])
+                    top_l = np.asarray(step["tlps"])
+                if not window:
+                    toks, lps = toks[:, None], lps[:, None]
+                    if top_i is not None:
+                        top_i, top_l = top_i[:, None], top_l[:, None]
+                    self.obs.on_expert_load(
+                        step["load"], model_lib.grouped_dispatch(
+                            len(batch.tokens), self.model_config,
+                            self.kernels))
         if self._sanitizer is not None:
             self._sanitizer.on_step_retire()
         for seq in batch.seqs:
-            seq.inflight_tokens, seq.inflight_row = 0, -1
+            seq.inflight_tokens = seq.inflight_passes = 0
+            seq.inflight_row = -1
         carried = (frozenset() if successor is None
                    else frozenset(map(id, successor["batch"].seqs)))
         with ph("postproc"):
-            outputs = self._process_window(
-                step, toks, lps, carried, top_ids=top_i, top_lps=top_l)
+            if block:
+                outputs, extra = block_steps.replay(self, step, fetched,
+                                                    carried)
+            else:
+                outputs = self._process_window(
+                    step, toks, lps, carried, top_ids=top_i, top_lps=top_l)
             if successor is not None:
                 successor["zombies"].update(
                     s.request_id for s in successor["batch"].seqs
@@ -2419,28 +2434,34 @@ class LLMEngine:
                 # histogram along, donated: ``_dispatch_window``)
                 self._counts_pool[counts.shape[0]] = counts
             self._drain_deferred(carried)
-        extra = self._routed(step["tokens"])
-        if window:
-            extra["mode"] = "greedy" if step["greedy"] else "sampled"
-        elif step["kind"] == "mixed":
-            extra.update(prefill_tokens=batch.prefill_token_count,
-                         decode_tokens=batch.num_seqs - 1)
+        if not block:       # (``replay`` counted what a block program did)
+            extra = self._routed(step["tokens"])
+            if window:
+                extra["mode"] = "greedy" if step["greedy"] else "sampled"
+            elif step["kind"] == "mixed":
+                extra.update(prefill_tokens=batch.prefill_token_count,
+                             decode_tokens=batch.num_seqs - 1)
         self._retired(step, outputs, **extra)
         return outputs
 
     def _mark_in_flight(self, step: dict) -> None:
         """Tell the sequences of ``step``, now the only unfetched program,
         what it holds for them: how many tokens, and the row of its
-        last-token output with the newest. Rows of finished sequences and
-        a partial chunk's (the zombies) are sampled nothing that counts."""
+        last-token output with the newest; for a block program how many
+        passes, and the row of its final state with the sequence's open
+        block. Rows of finished sequences and a partial chunk's (the
+        zombies) are sampled nothing that counts."""
         batch = step["batch"]
-        if self.model_config.block_length > 1:
-            return      # fetched before anything else is scheduled
         n = (self.config.scheduler.decode_window
              if step["kind"] == "decode" else 1)
         for row, seq in batch.device_seq_rows():
-            if seq.request_id not in step["zombies"]:
-                seq.inflight_tokens, seq.inflight_row = n, row
+            if seq.request_id in step["zombies"]:
+                continue
+            seq.inflight_row = row
+            if batch.block is not None:
+                seq.inflight_passes = n
+            else:
+                seq.inflight_tokens = n
 
     def _routed(self, tokens: int) -> dict:
         """``on_step``'s count of (token, expert) pairs a step of ``tokens``
@@ -2663,7 +2684,7 @@ class LLMEngine:
         ph = self.obs.phases.phase
         batch = rec["batch"]
         if batch.block is not None:     # a block model's W passes
-            return block_steps.dispatch(self, rec, float_b, step_key)
+            return block_steps.dispatch(self, rec, prev, float_b, step_key)
         if self._sanitizer is not None:
             self._sanitizer.on_decode_dispatch(
                 batch.seqs, batch.positions,
@@ -2807,15 +2828,7 @@ class LLMEngine:
                     new_lps.append(float(lp))
                 reason = seq.check_stop(self.config.effective_max_len)
                 if reason is not None:
-                    if id(seq) in carried:
-                        seq.status = SequenceStatus.FINISHED
-                        seq.finish_reason = reason
-                        if seq in self.scheduler.running:
-                            self.scheduler.running.remove(seq)
-                        self._deferred_release.append(seq)
-                        self.obs.on_finish(seq, reason)
-                    else:
-                        self.scheduler.finish(seq, reason)
+                    self._finish_row(seq, reason, carried)
                     break
             self.stats.tokens_generated += len(new_tokens)
             if not had_first and seq.first_token_time is not None:
@@ -2841,6 +2854,21 @@ class LLMEngine:
                                      if seq.params.top_logprobs else None),
                 t_ready=rec["t_ready"]))
         return outputs
+
+    def _finish_row(self, seq: Sequence, reason: FinishReason,
+                    carried: frozenset) -> None:
+        """``seq`` met a stop condition in the program being retired. If
+        its successor, already dispatched, has a row for it (``carried``),
+        what it holds stays its own until that one is fetched
+        (``_drain_deferred``)."""
+        if id(seq) not in carried:
+            return self.scheduler.finish(seq, reason)
+        seq.status = SequenceStatus.FINISHED
+        seq.finish_reason = reason
+        if seq in self.scheduler.running:
+            self.scheduler.running.remove(seq)
+        self._deferred_release.append(seq)
+        self.obs.on_finish(seq, reason)
 
     def _drain_terminally_finished(self) -> list[RequestOutput]:
         """Sequences the scheduler finished on its own (grown past pool

@@ -92,7 +92,7 @@ class ScheduledBatch:
     # were padded with filler drafts; the split feeds acceptance metrics),
     # the step's verify-slice width S = k+1 (adaptive k varies it between
     # steps), and the draft phase's wall time (trace attribution).
-    # a block model's rows (decode + mixed): [B_pad, 2 * block_length + 3]
+    # a block model's rows (decode + mixed): [B_pad, 2 * block_length + 4]
     # int32, each row's open block (``Scheduler.fill_block_rows``)
     block: Optional[np.ndarray] = None
     draft_lens: Optional[np.ndarray] = None        # [B_pad]
@@ -107,15 +107,18 @@ class ScheduledBatch:
 
     def device_seq_rows(self):
         """(device row, seq) pairs — identity except for the spec_mixed
-        chunk row remap, and for a mixed step's partial chunk beside full
-        seats, which has no row (``build_mixed_batch``). The seam
-        engine-side per-seq array builders iterate so one spelling serves
-        every batch kind."""
+        chunk row remap, and for a mixed step's chunk that has no row: a
+        partial one beside full seats, and a block model's always
+        (``build_mixed_batch``). The seam engine-side per-seq array
+        builders iterate so one spelling serves every batch kind."""
+        rows = len(self.temperature)
+        if self.block is not None and self.kind == "mixed":
+            rows = len(self.seqs) - 1
         for s, seq in enumerate(self.seqs):
             if (self.chunk_device_row is not None
                     and s == len(self.seqs) - 1):
                 yield self.chunk_device_row, seq
-            elif s < len(self.temperature):
+            elif s < rows:
                 yield s, seq
     # sampling arrays [B_pad]
     temperature: Optional[np.ndarray] = None
@@ -1129,19 +1132,25 @@ class Scheduler:
 
     def fill_block_rows(self, seqs: list[Sequence], R: int) -> dict:
         """A block model's rows, ``R`` of them (padding past ``seqs``): what
-        a pass over every row's open block reads. ``block`` [R, 2 B + 3]
-        int32 = (the block's ids, its masked flags, its start, the
+        a pass over every row's open block reads. ``block`` [R, 2 B + 4]
+        int32 = (the block's ids, its masked flags, its start, the passes
+        it has taken: the STATE, as the host last fetched it; then the
         positions the row's pages cover (a commit past them goes to the
-        scrap page; 0: a padding row), the passes the block has taken),
-        and the page tables."""
+        scrap page; 0: a padding row) and the state's SOURCE: the row of
+        the final state of the program in flight that holds this block,
+        which the device reads in place of the host's columns; -1 where the
+        host's are the truth: a padding row, a fresh admission's first
+        block, every row when nothing is in flight), and the page tables."""
         B = self.block_length
         pages_bucket = cdiv(self.config.effective_max_len, self.page_size)
-        block = np.zeros((R, 2 * B + 3), np.int32)
+        block = np.zeros((R, 2 * B + 4), np.int32)
+        block[:, -1] = -1
         page_tables = np.zeros((R, pages_bucket), np.int32)
         context_lens = np.zeros(R, np.int32)
         for r, seq in enumerate(seqs):
             block[r] = (*seq.block_ids, *seq.block_masked, seq.num_committed,
-                        len(seq.pages) * self.page_size, seq.block_passes)
+                        seq.block_passes, len(seq.pages) * self.page_size,
+                        seq.inflight_row)
             page_tables[r, :len(seq.pages)] = seq.pages
             context_lens[r] = seq.num_committed + 1
         return dict(block=block, page_tables=page_tables,

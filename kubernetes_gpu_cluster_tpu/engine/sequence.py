@@ -39,9 +39,10 @@ class Sequence:
         # prefix (K/V in the pages) and an OPEN BLOCK of B positions
         # beyond it, each an id or masked: an autoregressive model is
         # B = 1, whose open block is its one next position. For B > 1 the
-        # host holds the block between step programs: ``num_committed``
-        # positions lie in the pages (a multiple of B), ``block_ids`` /
-        # ``block_masked`` are the open block at
+        # host holds the block AS OF THE LAST PROGRAM IT FETCHED (the
+        # program in flight carries it on, on the device: engine/block.py):
+        # ``num_committed`` positions lie in the pages (a multiple of B),
+        # ``block_ids`` / ``block_masked`` are the open block at
         # [num_committed, num_committed + B), ``block_marks`` what the pass
         # that transferred a position said of it (log-probability, top
         # alternatives) until the position leaves in position order, and
@@ -102,8 +103,12 @@ class Sequence:
         # 1 of a mixed or prefill step), and the row of that program's
         # last-token output that holds the newest. The engine sets both
         # when the step's predecessor retires and clears them when the
-        # step itself does; 0 and -1 whenever nothing is in flight.
+        # step itself does; 0 and -1 whenever nothing is in flight. A block
+        # model's row is told the PASSES in flight instead (a pass yields 0
+        # to B tokens: which, the host learns at the fetch), and the row of
+        # that program's final state that holds its open block.
         self.inflight_tokens = 0
+        self.inflight_passes = 0
         self.inflight_row = -1
 
     @property
@@ -156,12 +161,15 @@ class Sequence:
         """Highest position a step program of ``passes`` passes can write:
         ``last_window_pos`` for one token a pass; for a block model the end
         of the block that is open after the most commits the passes allow
-        (a block takes a denoising pass at least before its commit), capped
-        by the model's length and the last block this request can reach."""
+        (a block takes a denoising pass at least before its commit), the
+        passes in flight counted beside the program's own since
+        ``num_committed`` is as of the last fetch, capped by the model's
+        length and the last block this request can reach."""
         B = self.block_length
         if B == 1:
             return self.last_window_pos(self.sched_tokens - 1, passes,
                                         max_len)
+        passes += self.inflight_passes
         end = self.num_committed + B * ((passes + 1) // 2 + 1)
         cap = -(-(self.num_prompt_tokens + self.params.max_tokens) // B) * B
         return min(end, max_len, cap) - 1

@@ -701,7 +701,8 @@ class Observability:
                 'kgct_steps_dispatched_total{kind="%s",behind="%d"} %d'
                 % (kind, behind, n))
         lines.append("# HELP kgct_chain_breaks_total times no step could "
-                     "be scheduled behind the one in flight, by reason")
+                     "be scheduled behind the one in flight, by reason "
+                     "(spec, stale, no_pages, penalties)")
         lines.append("# TYPE kgct_chain_breaks_total counter")
         for reason, n in sorted(self.chain_breaks.items()):
             lines.append('kgct_chain_breaks_total{reason="%s"} %d'

@@ -201,6 +201,82 @@ def test_sampled_requests_are_reproducible_by_seed(params):
     assert c[0].output_token_ids != a
 
 
+# -- (g) programs behind one another: pages for what is in flight -------------
+
+def test_an_exactly_sized_pool_serves_with_passes_in_flight(
+        params, monkeypatch):
+    """A pool of just the pages its requests can ever hold (``admit_tokens``
+    at admission, then the last block ``max_tokens`` reaches), arrivals
+    spread so that windows and mixed steps queue behind one another: pages
+    grown for what the passes in flight may commit never ask for one more
+    (no chain break for pages, no preemption), no page is released under a
+    dispatched program (the sanitizer's device-queue shadow), and no commit
+    went to the scrap page for want of one (the ids are the reference's)."""
+    monkeypatch.setenv("KGCT_SANITIZE", "1")
+    lens, max_tokens = (30, 21, 13, 38), (40, 23, 30, 26)
+    prompts = [_prompt(n, n) for n in lens]
+    pages = 1 + sum(
+        -(-max(n - n % B + 2 * B, -(-(n + m) // B) * B) // PS)
+        for n, m in zip(lens, max_tokens))
+    eng = _engine(params, pages=pages, decode_window=4)
+    assert eng._sanitizer is not None
+    outs, call = {}, 0
+    while call < len(prompts) * 3 or eng.has_unfinished_requests():
+        if call % 3 == 0 and call // 3 < len(prompts):
+            i = call // 3
+            eng.add_request(str(i), prompts[i], SamplingParams(
+                max_tokens=max_tokens[i], temperature=0.0, logprobs=True))
+        for o in eng.step():
+            outs[o.request_id] = o
+        call += 1
+    for i, (p, m) in enumerate(zip(prompts, max_tokens)):
+        assert outs[str(i)].finish_reason == "length"
+        assert _distance(outs[str(i)], ref.generate(params, CFG, p, m)) \
+            < LOGIT_TOL, i
+    sched = eng.scheduler
+    assert sched.num_preemptions == 0 and not eng.obs.chain_breaks
+    assert sched.allocator.num_free == pages - 1
+    behind = {k for (k, b), n in eng.obs.steps_dispatched.items() if b and n}
+    assert behind >= {"decode", "mixed"}
+
+
+@pytest.mark.parametrize("inflight,passes,committed,max_tokens,want", [
+    (0, 8, 8, 64, 8 + 4 * 5 - 1),       # nothing in flight: as scheduled
+    (8, 8, 8, 64, 8 + 4 * 9 - 1),       # a window behind a window
+    (1, 8, 8, 64, 8 + 4 * 6 - 1),       # a window behind a mixed step
+    (8, 1, 8, 64, 8 + 4 * 6 - 1),       # a mixed step behind a window
+    (8, 8, 8, 5, 15),                   # the last block the request reaches
+    (8, 8, 496, 1000, 511)])            # the model's length
+def test_pages_are_held_for_what_the_passes_in_flight_may_commit(
+        inflight, passes, committed, max_tokens, want):
+    from kubernetes_gpu_cluster_tpu.engine.sequence import Sequence
+
+    seq = Sequence("r", list(range(10)),
+                   SamplingParams(max_tokens=max_tokens), block_length=B)
+    seq.num_committed, seq.inflight_passes = committed, inflight
+    assert seq.window_last_pos(passes, 512) == want
+
+
+@pytest.mark.parametrize("outputs,inflight,window,max_tokens,want", [
+    (0, 0, 8, 64, 16), (5, 0, 8, 64, 21), (5, 8, 8, 64, 29),
+    (5, 1, 8, 64, 22), (5, 8, 1, 64, 22), (5, 8, 8, 10, 19),
+    (0, 0, 1, 64, 9), (200, 8, 8, 400, 99)])
+def test_a_token_models_window_reaches_where_it_always_did(
+        outputs, inflight, window, max_tokens, want):
+    """``window_last_pos`` at B = 1 (a prompt of 10, the model's length
+    100): the numbers of the parent commit, whatever passes a block
+    model's field would count."""
+    from kubernetes_gpu_cluster_tpu.engine.sequence import Sequence
+
+    seq = Sequence("r", list(range(10)), SamplingParams(max_tokens=max_tokens))
+    for t in range(outputs):
+        seq.append_token(t)
+    seq.inflight_tokens = inflight
+    assert seq.window_last_pos(window, 100) == want
+    assert want == min(10 + outputs + inflight + window - 2, 99,
+                       10 + max_tokens - 1)
+
+
 # -- (b) planted faults -------------------------------------------------------
 
 @pytest.fixture(scope="module")

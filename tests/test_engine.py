@@ -428,6 +428,7 @@ _RNG = np.random.default_rng(7)
 _P = [[int(t) for t in _RNG.integers(1, 200, n)]
       for n in (5, 12, 9, 80, 20, 7, 30)]
 _GREEDY = SamplingParams(max_tokens=21, temperature=0.0)
+_BLOCK = "debug-block-moe"      # block_length 4: a pass yields 0-4 tokens
 
 
 def _staggered(params, prompts=_P[:3] + _P[4:6], every=2):
@@ -514,6 +515,58 @@ QUEUE_CASES = {
     "state_slots": lambda: dict(
         model="debug-ssm-hybrid",
         arrivals=_staggered(_GREEDY) + [(4, "long", _P[3], _GREEDY)]),
+    # A block model (engine/block.py): a program's rows read their open
+    # blocks from the final state of the one in flight, and the host
+    # replays each program one program late. 80 tokens at a budget of 32
+    # beside decode rows: three mixed steps, each one pass.
+    "block_chunks_mixed": lambda: dict(
+        model=_BLOCK,
+        arrivals=_staggered(_GREEDY) + [(4, "long", _P[3], _GREEDY)],
+        kinds={"mixed": 3}),
+    # a stop id and two ``max_tokens`` (one off a block's edge, one on it)
+    # fall inside windows: each row rides the program behind as a zombie
+    "block_stop_and_max_tokens_in_window": lambda: dict(
+        model=_BLOCK,
+        arrivals=[(0, "r0", _P[0], SamplingParams(max_tokens=40,
+                                                  temperature=0.0)),
+                  (0, "r1", _P[1], SamplingParams(max_tokens=6,
+                                                  temperature=0.0,
+                                                  ignore_eos=True)),
+                  (1, "r2", _P[2], SamplingParams(max_tokens=16,
+                                                  temperature=0.0,
+                                                  ignore_eos=True)),
+                  (7, "r3", _P[5], _GREEDY)],
+        eos=_eos_of(_BLOCK), reasons={"r0": "stop", "r1": "length",
+                                      "r2": "length"},
+        tokens={"r0": 5, "r1": 6, "r2": 16}),
+    # (a call later than the token models': a block model's prefill samples
+    # nothing, its first tokens leave with the next program)
+    "block_abort_in_flight": lambda: dict(
+        model=_BLOCK,
+        arrivals=_staggered(SamplingParams(max_tokens=30, temperature=0.0)),
+        aborts=[(5, "r1"), (10, "r3")]),
+    # the victim's open block is on the chip: fetched first, preempted then
+    # (``redrawn`` in the test: what a victim's ids are held to)
+    "block_preemption": lambda: dict(
+        model=_BLOCK,
+        arrivals=_staggered(SamplingParams(max_tokens=40, temperature=0.0),
+                            every=1),
+        num_pages=14, preempts=True),
+    # the sampler's keys take the DEVICE's count of a block's passes
+    "block_seeded_top_logprobs_5": lambda: dict(
+        model=_BLOCK,
+        arrivals=_staggered(
+            [SamplingParams(max_tokens=18 + i, temperature=0.8, top_k=20,
+                            seed=11 + i, logprobs=True, top_logprobs=5)
+             for i in range(5)])),
+    # prompts of one length modulo the block, a position a pass: the rows
+    # end in one pass, and both loops build the very same batches
+    "block_same_batches_bitwise": lambda: dict(
+        model=_BLOCK,
+        arrivals=[(0, f"r{i}", p, SamplingParams(
+            max_tokens=20, temperature=0.0, logprobs=True, top_logprobs=5))
+            for i, p in enumerate((_P[0], _P[2]))],
+        bitwise=True),
 }
 
 
@@ -527,15 +580,31 @@ def test_device_queue_matches_chain_broken_every_step(case):
     kw = dict(model=spec.get("model", "debug-tiny"), eos=spec.get("eos"),
               num_pages=spec.get("num_pages", 128), **spec.get("sched", {}))
     results = {}
+    # A block model's victim drops its open block and denoises it again
+    # from the tokens that had left: positions it had transferred ahead of
+    # a masked one are drawn anew, beside other neighbours, so from there
+    # on its ids are those of WHEN it was preempted (either loop's are a
+    # generation of the model; tests/test_block_diffusion.py). Such a row
+    # is held to its finish reason and length; every other row, preempted
+    # or not, to every id.
+    redrawn = set()
     for mode in ("queued", "broken"):
         eng = _queue_engine(**kw)
         if mode == "broken":
             eng._chain_break = lambda pred: "forced"
+
+        def requeue(seq, _requeue=eng.scheduler._requeue_for_recompute,
+                    **how):
+            if not all(seq.block_masked[seq.num_tokens - seq.num_committed:]):
+                redrawn.add(seq.request_id)
+            return _requeue(seq, **how)
+        eng.scheduler._requeue_for_recompute = requeue
         results[mode] = _drive(eng, spec["arrivals"], spec.get("aborts", ()))
         alloc = eng.scheduler.allocator
         assert alloc.num_free == alloc.num_pages - 1, (mode, "pages leaked")
         assert not eng._deferred_release and eng._inflight is None
         behind = sum(n for (_, b), n in eng.obs.steps_dispatched.items() if b)
+        assert "block" not in eng.obs.chain_breaks
         if mode == "queued":
             assert behind > 0 and "forced" not in eng.obs.chain_breaks
             if spec.get("preempts"):
@@ -555,6 +624,10 @@ def test_device_queue_matches_chain_broken_every_step(case):
             n = min(len(a), len(b))
             assert a[:n] == b[:n]
             continue
+        if rid in redrawn:
+            assert queued[rid][1] == broken[rid][1], rid
+            assert len(queued[rid][0]) == len(broken[rid][0]), rid
+            continue
         # ids, finish reason and the alternatives' ids: equal. The values:
         # to float32 rounding, for a row's logits come from programs of
         # other row counts where a prompt rides a window later.
@@ -570,6 +643,9 @@ def test_device_queue_matches_chain_broken_every_step(case):
         assert queued[rid][1] == reason
     for rid, n in spec.get("tokens", {}).items():
         assert len(queued[rid][0]) == n
+    # (only the block model's tight pool meets it, and not on every row)
+    assert len(redrawn) < len(queued)
+    assert not redrawn or (spec.get("preempts") and kw["model"] == _BLOCK)
 
 
 def test_warm_full_window_meets_the_full_seat_program_and_nothing_else():
