@@ -364,7 +364,7 @@ def replay(engine: "LLMEngine", step: dict, fetched: tuple,
             new_top_logprobs=new_tops if want_top else None,
             output_top_logprobs=(list(seq.output_top_logprobs)
                                  if seq.params.top_logprobs else None),
-            t_ready=step["t_ready"]))
+            clock=step["clock"]))
     obs = engine.obs
     obs.block_passes += passes
     obs.block_commit_passes += commits

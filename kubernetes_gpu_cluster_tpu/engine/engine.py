@@ -34,7 +34,7 @@ from ..analysis.sanitize import build_step_sanitizer
 from ..config import EngineConfig, cache_kind_refusal
 from ..utils.math import next_power_of_2
 from ..models import llama as model_lib
-from ..observability import Observability
+from ..observability import Observability, StepClock
 from ..models.llama import StepMeta
 from ..ops.attention import Kernels
 from ..ops.sampling import (apply_logit_bias, apply_penalties, build_counts,
@@ -94,7 +94,9 @@ class RequestOutput:
     # of the N most likely tokens (N = SamplingParams.top_logprobs).
     new_top_logprobs: Optional[list[list[tuple[int, float]]]] = None
     output_top_logprobs: Optional[list[list[tuple[int, float]]]] = None
-    t_ready: Optional[float] = None  # monotonic: new_token_ids' program ended
+    # The clock of the program that produced new_token_ids: ONE object for
+    # all its rows' outputs (observability/phases.py); None: no program did.
+    clock: Optional[StepClock] = None
 
 
 def _prefill_penalties(cfg, logits, int_t, prompt_lens, presence, frequency):
@@ -2186,8 +2188,9 @@ class LLMEngine:
         computes and its bucket's size, whether and behind which program
         it is queued, and the first of its stamps. The dispatchers add
         what the fetch needs and ``t_dispatched``; ``_fetching`` adds
-        ``t_wait`` and ``t_ready``; ``_retired`` adds ``t_retired`` and
-        hands it to ``Observability.on_step``."""
+        ``t_wait``, ``t_ready`` and ``clock`` (what its rows' outputs take
+        along of its stamps); ``_retired`` adds ``t_retired`` and hands it
+        to ``Observability.on_step``."""
         kind, rows, padded = batch.kind, batch.num_seqs, len(batch.tokens)
         if batch.block is not None:
             # A block program: positions computed over its passes; the
@@ -2236,13 +2239,14 @@ class LLMEngine:
             rec["toks"].block_until_ready()
             rec["t_ready"] = t = time.monotonic()
             turn("host", t)
+            rec["clock"] = StepClock(rec["step"], t)
             yield
         rec["transfer_s"] = time.monotonic() - rec["t_ready"]
 
     def _retired(self, rec: dict, outs: list[RequestOutput], **extra) -> None:
         """Post-processing of the program of ``rec`` is done: the last
         stamp, what it committed, and the record goes to the accounts."""
-        rec["t_retired"] = time.monotonic()
+        rec["t_retired"] = rec["clock"].t_retired = time.monotonic()
         rec["new_tokens"] = sum(len(o.new_token_ids or []) for o in outs)
         rec.update(extra)
         self.obs.on_step(rec)
@@ -2771,8 +2775,8 @@ class LLMEngine:
                         emit_counts: Optional[np.ndarray] = None,
                         ) -> list[RequestOutput]:
         """``rec``: the record of the program whose tokens these are (its
-        batch, its zombies, its number and stamps for the first-token event
-        and the frame delay). next_tokens/logprobs: [B_pad, W]. Append
+        batch, its zombies, its number for the first-token event and its
+        clock for the frames). next_tokens/logprobs: [B_pad, W]. Append
         window tokens per sequence
         until a stop condition fires; tokens generated past the stop are
         discarded.
@@ -2852,7 +2856,7 @@ class LLMEngine:
                 new_top_logprobs=new_tops if want_top else None,
                 output_top_logprobs=(list(seq.output_top_logprobs)
                                      if seq.params.top_logprobs else None),
-                t_ready=rec["t_ready"]))
+                clock=rec["clock"]))
         return outputs
 
     def _finish_row(self, seq: Sequence, reason: FinishReason,

@@ -15,10 +15,12 @@ of the program just retired (``step`` its own number, ``kind``, ``rows``,
 ``t_dispatched``, ``t_wait``, ``t_ready``, ``t_retired`` on
 ``time.monotonic``; phases.py says what is derived from them), files the
 phases that served it under its number in ``/debug/trace`` and fills the
-always-on series ``kgct_step_device_seconds``, ``kgct_steps_retired_total``,
+always-on series ``kgct_step_device_seconds``, ``kgct_step_lead_seconds``,
+``kgct_device_starved_seconds_total``, ``kgct_steps_retired_total``,
 ``kgct_step_slow_*``, ``kgct_step_tokens_total``; the worker's three states
-(``kgct_worker_seconds_total``) and the frame delay
-(``kgct_frame_delay_seconds``) are kept beside them.
+(``kgct_worker_seconds_total``) and, per frame written, the delay behind
+its program's end and its five stages (``kgct_frame_delay_seconds``,
+``kgct_frame_stage_seconds``: ``on_frame``) are kept beside them.
 
 The black-box flight recorder (flightrecorder.py) mirrors the same events
 into its own ring (kill switch ``KGCT_FLIGHT=0``) and is NOT touched by
@@ -35,21 +37,26 @@ from typing import Optional
 import numpy as np
 
 from .flightrecorder import FlightRecorder
-from .phases import PHASES, STEP_KINDS, WORKER_STATES, StepPhaseStats
-from .prometheus import (BATCH_BUCKETS, LATENCY_BUCKETS_S, STEP_BUCKETS_S,
-                         Histogram, fmt, render_gauge)
+from .phases import (FRAME_STAGES, PHASES, STEP_KINDS, WORKER_STATES,
+                     FrameClock, StepClock, StepPhaseStats)
+from .prometheus import (BATCH_BUCKETS, FRAME_STAGE_BUCKETS_S,
+                         LATENCY_BUCKETS_S, STEP_BUCKETS_S, Histogram, fmt,
+                         render_gauge)
 from .trace import EVENT_KINDS, RequestTracer, merge_perfetto
 
 __all__ = ["Observability", "Histogram", "RequestTracer", "StepPhaseStats",
-           "FlightRecorder", "SLOTracker", "merge_perfetto",
-           "EVENT_KINDS", "PHASES", "LATENCY_BUCKETS_S", "BATCH_BUCKETS",
-           "render_gauge", "fmt"]
+           "StepClock", "FrameClock", "FlightRecorder", "SLOTracker",
+           "merge_perfetto", "EVENT_KINDS", "PHASES", "LATENCY_BUCKETS_S",
+           "BATCH_BUCKETS", "render_gauge", "fmt"]
 
 # The attainment bar when no admission-control budget is configured: the
 # north-star "p50 TTFT <= 1 s" target. An operator budget
 # (ResilienceConfig.default_ttft_budget_ms, wired by the API server)
 # overrides it so the SLO gauge and the 429 shed line agree on one number.
 SLO_DEFAULT_TTFT_BUDGET_MS = 1000.0
+
+# kgct_frame_stage_seconds' label sets, in FrameClock.stages' order.
+_STAGE_LABELS = tuple((stage,) for stage in FRAME_STAGES)
 
 
 class SLOTracker:
@@ -193,6 +200,17 @@ class Observability:
             "end of its predecessor (or its own dispatch) to its own end; "
             "only programs that were waited for, behind one that was",
             buckets=STEP_BUCKETS_S, labels=("kind",))
+        # How long a program had been queued when the chip came to it, by
+        # step kind: only behind a predecessor that was waited for (both
+        # ends of the distance are then stamps the chip set).
+        self.step_lead = Histogram(
+            "kgct_step_lead_seconds", "end of a step program's predecessor "
+            "minus its own dispatch, floored at 0: how far the host ran "
+            "ahead of the chip; only behind a predecessor that was waited "
+            "for", buckets=STEP_BUCKETS_S, labels=("kind",))
+        # kind of the program that came late -> seconds the chip had
+        # nothing queued before it (phases.retire: ``starved_s``).
+        self.device_starved = {kind: 0.0 for kind in STEP_KINDS}
         # (kind, waited) -> programs retired; waited False: found ready.
         self.steps_retired: dict[tuple[str, bool], int] = {}
         # cause -> [seconds, count] of ready gaps that counted as slow.
@@ -201,10 +219,19 @@ class Observability:
         # bucket's padding.
         self.step_tokens: dict[tuple[str, bool], int] = {}
         # Per frame the event loop wrote: now - t_ready of the program whose
-        # tokens it carries (the HTTP layer's own share of a token's gap).
+        # tokens it carries (the HTTP layer's own share of a token's gap),
+        # and the same distance cut at the frame's stamps into
+        # phases.FRAME_STAGES: five observations a frame, whose sums add up
+        # to the delay's.
         self.frame_delay = Histogram(
             "kgct_frame_delay_seconds", "end of a step program to the "
             "write of the frame that carries its tokens")
+        self.frame_stage = Histogram(
+            "kgct_frame_stage_seconds", "the frame delay by stage: retire "
+            "(program ready to post-processed), post (to the row's "
+            "hand-over), wake (to the event loop's callback), queue (to the "
+            "consumer's resumption), render (to the write's return)",
+            buckets=FRAME_STAGE_BUCKETS_S, labels=("stage",))
         # The number the program being scheduled will get: the request
         # events of a schedule() name the step that serves them.
         self.step_launching = 0
@@ -546,6 +573,10 @@ class Observability:
         self.batch_size.observe(rows)
         if rec["exact"]:
             self.step_device.observe(rec["device_s"], (kind,))
+        if rec["lead_exact"]:
+            self.step_lead.observe(max(rec["lead_s"], 0.0), (kind,))
+        if rec["starved_s"]:
+            self.device_starved[kind] += rec["starved_s"]
         key = (kind, not rec["found_ready"])
         self.steps_retired[key] = self.steps_retired.get(key, 0) + 1
         if rec["slow"] is not None:
@@ -557,7 +588,8 @@ class Observability:
             self.step_tokens[(kind, real)] = (
                 self.step_tokens.get((kind, real), 0) + n)
         timing = {"device_ms": _ms(rec["device_s"]), "exact": rec["exact"],
-                  "wait_ms": _ms(rec["wait_s"]), "lead_ms": _ms(rec["lead_s"])}
+                  "wait_ms": _ms(rec["wait_s"]), "lead_ms": _ms(rec["lead_s"]),
+                  "starved_ms": _ms(rec["starved_s"])}
         self.phases.end_step(step=rec["step"], kind=kind, batch=rows,
                              duration_s=duration_s, phases=rec["phases"],
                              **timing)
@@ -604,11 +636,18 @@ class Observability:
                              max(duration_s - draft_s, 0.0) * 1e3, 3))
         self.tracer.emit(kind, "", **event)
 
-    def on_frame(self, t_ready: Optional[float]) -> None:
-        """The event loop wrote a frame whose tokens the program that was
-        ready at ``t_ready`` (``time.monotonic``) produced."""
-        if t_ready is not None:
-            self.frame_delay.observe(time.monotonic() - t_ready)
+    def on_frame(self, clock: Optional[FrameClock]) -> None:
+        """The event loop wrote a frame, just now, whose tokens the program
+        of ``clock`` produced (None: no program did, nothing to hold it
+        against): the delay behind that program's end, whole and by
+        stage."""
+        if clock is None:
+            return
+        now = time.monotonic()
+        observe = self.frame_stage.observe
+        for labels, seconds in zip(_STAGE_LABELS, clock.stages(now)):
+            observe(seconds, labels)
+        self.frame_delay.observe(now - clock.program.t_ready)
 
     def mixed_step_ratio(self):
         """Fraction of device steps that carried a prefill chunk alongside
@@ -708,6 +747,16 @@ class Observability:
             lines.append('kgct_chain_breaks_total{reason="%s"} %d'
                          % (reason, n))
         lines.extend(self.step_device.render())
+        lines.extend(self.step_lead.render())
+        lines.append("# HELP kgct_device_starved_seconds_total time the "
+                     "chip had nothing queued while the engine held "
+                     "unfinished requests: a program's dispatch minus the "
+                     "end of the program retired before it, where positive, "
+                     "by the kind of the program that came late")
+        lines.append("# TYPE kgct_device_starved_seconds_total counter")
+        for kind in sorted(self.device_starved):
+            lines.append('kgct_device_starved_seconds_total{kind="%s"} %s'
+                         % (kind, fmt(round(self.device_starved[kind], 6))))
         lines.append("# HELP kgct_steps_retired_total step programs retired, "
                      "by kind; waited=0: found ready, the host came after "
                      "the chip")
@@ -743,6 +792,7 @@ class Observability:
             lines.append('kgct_worker_seconds_total{state="%s"} %s'
                          % (state, fmt(round(worker[state], 6))))
         lines.extend(self.frame_delay.render())
+        lines.extend(self.frame_stage.render())
         if self.moe_routed_pairs:
             lines.append("# HELP kgct_moe_routed_pairs_total (token, expert) "
                          "pairs sent through the expert layers, by step kind")

@@ -24,10 +24,25 @@ under the program they served (``start_step`` / ``file_under``).
 ``retire()`` derives from the stamps, once: ``wait_s``, whether the program
 was found ready (the host came after the chip), its device time and whether
 that is exact, the gap to its predecessor's end, the host's lead over the
-chip, and whether the gap counts as slow and by whose fault. The worker
-thread's wall is split at its own turns into three states
-(``worker_turn``): waiting for the device, waiting for the inbox, and
-everything else.
+chip and whether both ends of that lead are the chip's, the time the chip
+had nothing queued before it (``starved_s``), and whether the gap counts as
+slow and by whose fault. The worker thread's wall is split at its own turns
+into three states (``worker_turn``): waiting for the device, waiting for
+the inbox, and everything else; the last idle turn is kept, so that a pause
+for want of requests is never read as a starved chip.
+
+**The frame's clock** goes on from the record's last two stamps, on the same
+clock. What the rows of one program share is one ``StepClock`` (``step``,
+``t_ready``, ``t_retired``: the engine's ``_fetching`` and ``_retired``
+stamp it, every ``RequestOutput`` of the program holds the same object,
+never the record, which holds the batch and device arrays). A frame's own
+stamps ride a ``FrameClock`` on its ``StreamChunk``: ``t_posted`` (the
+worker, at the row's ``call_soon_threadsafe``), ``t_woken`` (the event loop
+runs the row's callback), ``t_resumed`` (the consumer comes back from
+``await queue.get()``); the write's end is the moment
+``Observability.on_frame`` is called. ``FRAME_STAGES`` names the five
+distances between the six stamps; they add up to the frame delay without
+remainder.
 
 Cost per phase is two clock reads and a list append; per program a handful
 of clock reads, subtractions and dict adds — microseconds against steps of
@@ -64,8 +79,43 @@ SLOW_GAP_S = 0.5
 SLOW_GAP_RATIO = 3.0
 
 
+# The frame's path from the end of its program on the device to the write of
+# the frame, cut at the stamps of StepClock and FrameClock.
+FRAME_STAGES = ("retire", "post", "wake", "queue", "render")
+
 # What span() hands out while no capture runs: one shared, reusable object.
 _NO_SPAN = contextlib.nullcontext()
+
+
+class StepClock:
+    """What the frames of one step program share: its number and the last
+    two stamps of its record (``time.monotonic``). One object a program,
+    made when it is ready; ``t_retired`` is None until its post-processing
+    is done, which is before any of its outputs leaves ``step()``."""
+    __slots__ = ("step", "t_ready", "t_retired")
+
+    def __init__(self, step: int, t_ready: float):
+        self.step = step
+        self.t_ready = t_ready
+        self.t_retired = None
+
+
+class FrameClock:
+    """One frame's stamps behind its program's (module docstring): who
+    stamps what, and ``stages`` for the five distances."""
+    __slots__ = ("program", "t_posted", "t_woken", "t_resumed")
+
+    def __init__(self, program: StepClock):
+        self.program = program
+        self.t_posted = self.t_woken = self.t_resumed = None
+
+    def stages(self, t_written: float) -> tuple:
+        """Seconds by FRAME_STAGES for a frame whose write returned at
+        ``t_written``; their sum is ``t_written - program.t_ready``."""
+        p = self.program
+        return (p.t_retired - p.t_ready, self.t_posted - p.t_retired,
+                self.t_woken - self.t_posted, self.t_resumed - self.t_woken,
+                t_written - self.t_resumed)
 
 
 def _annotation(name: str, **args):
@@ -141,6 +191,10 @@ class StepPhaseStats:
         # WORKER_STATES), ONE tuple swapped whole at every turn, so that a
         # scrape on another thread reads a consistent triple.
         self._worker: tuple = (None, 0.0, (0.0,) * len(WORKER_STATES))
+        # When the worker last turned to ``inbox_wait``: the engine held no
+        # unfinished request then, so a distance between two programs that
+        # spans it is a pause, not a starved chip.
+        self._t_idle = float("-inf")
 
     def phase(self, name: str, rec: dict = None) -> _PhaseCtx:
         """``rec``: the record of the program a dispatch or a fetch serves;
@@ -211,7 +265,17 @@ class StepPhaseStats:
           without one), exact or not;
         - ``lead_s`` = the predecessor's t_ready − t_dispatched: how long
           the program had been queued when the chip came to it (negative:
-          the chip waited for the host);
+          the chip waited for the host); ``lead_exact`` when that
+          predecessor was waited for (one found ready has the host's
+          arrival as its end, not the chip's);
+        - ``starved_s`` = t_dispatched − the t_ready of the program retired
+          before it, where positive: the chip had nothing queued for that
+          long. A chained program dispatched after its predecessor's end
+          (``lead_s`` < 0), or one launched with nothing in flight behind a
+          program already retired (a chain break: the whole
+          fetch-then-schedule distance). 0.0 for the first program, for a
+          predecessor that never came back, and where the worker turned to
+          ``inbox_wait`` in between (no request, no work to queue);
         - ``slow``: None, or why the gap counts as slow ("host": found
           ready, or dispatched after the predecessor was ready — a compile,
           the GIL, the machine standing still; "device": waited for), when
@@ -222,10 +286,15 @@ class StepPhaseStats:
         wait_s = t_ready - rec["t_wait"]
         found = wait_s < FOUND_READY_S
         p_step, p_ready, p_found = self._ready
-        has_pred = p_step is not None and p_step == rec.get("pred")
+        pred = rec.get("pred")
+        has_pred = p_step is not None and p_step == pred
         self._ready = (rec["step"], t_ready, found)
         gap = lead = slow = None
         start, exact = t_disp, not found
+        starved = 0.0
+        if (p_step is not None and (has_pred or pred is None)
+                and self._t_idle < p_ready):
+            starved = max(t_disp - p_ready, 0.0)
         if has_pred:
             gap = t_ready - p_ready
             lead = p_ready - t_disp
@@ -240,7 +309,8 @@ class StepPhaseStats:
                 mean[1] += 1
         rec.update(wait_s=wait_s, found_ready=found,
                    device_s=t_ready - start, exact=exact, ready_gap_s=gap,
-                   lead_s=lead, slow=slow)
+                   lead_s=lead, lead_exact=has_pred and not p_found,
+                   starved_s=starved, slow=slow)
         return rec
 
     def worker_turn(self, state: str, now: float = None) -> None:
@@ -254,6 +324,8 @@ class StepPhaseStats:
             i = WORKER_STATES.index(cur)
             totals = totals[:i] + (totals[i] + now - since,) + totals[i + 1:]
         self._worker = (state, now, totals)
+        if state == "inbox_wait":
+            self._t_idle = now
 
     def worker_seconds(self) -> dict:
         """Seconds by state up to now, the running state's share included:

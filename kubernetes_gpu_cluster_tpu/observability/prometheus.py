@@ -15,6 +15,7 @@ bucket counts are monotone because they are accumulated that way.
 
 from __future__ import annotations
 
+from bisect import bisect_left
 from typing import Optional
 
 # Latency buckets (seconds): µs-scale device steps up to multi-second TTFT
@@ -27,6 +28,11 @@ LATENCY_BUCKETS_S = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
 # mean comes from _sum/_count either way).
 STEP_BUCKETS_S = (0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5,
                   1.0, 2.0, 5.0, 10.0)
+
+# One stage of a frame's path from the chip to the socket: 1-2-5 from 100 us
+# (a hand-over, a turn of the event loop) to 1 s.
+FRAME_STAGE_BUCKETS_S = (0.0001, 0.0002, 0.0005, 0.001, 0.002, 0.005, 0.01,
+                         0.02, 0.05, 0.1, 0.2, 0.5, 1.0)
 
 # Batch-size-per-step buckets: powers of two matching the scheduler's padded
 # decode buckets, so the histogram reads as "which compiled shape ran".
@@ -75,11 +81,10 @@ class Histogram:
         # strict parsers and the exposition validator enforce).
         cell[1] += value
         cell[2] += 1
-        counts, _, _ = cell
-        for i, bound in enumerate(self.buckets):
-            if value <= bound:
-                counts[i] += 1
-                break
+        # the first bucket whose bound is >= value; none above the last
+        i = bisect_left(self.buckets, value)
+        if i < len(self.buckets):
+            cell[0][i] += 1
 
     @property
     def count(self) -> int:

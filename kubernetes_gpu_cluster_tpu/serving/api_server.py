@@ -837,12 +837,15 @@ class APIServer:
 
     async def _write_frame(self, resp, body: dict, chunk) -> None:
         """Write one SSE frame of a stream (the span ``kgct.http.write`` in
-        a capture), and hold the moment it is written against the end of
-        the step program that produced its tokens."""
+        a capture, with the number of the step program that produced its
+        tokens), and hold the moment it is written against that program's
+        end, whole and by stage (``chunk.clock``)."""
         obs = self.engine.engine.obs
-        with obs.phases.span("http.write"):
+        clock = chunk.clock
+        step = -1 if clock is None else clock.program.step
+        with obs.phases.span("http.write", step=step):
             await resp.write(_sse(body))
-        obs.on_frame(chunk.t_ready)
+        obs.on_frame(clock)
 
     async def profile(self, request: web.Request) -> web.Response:
         """Capture a jax.profiler trace of live serving traffic.

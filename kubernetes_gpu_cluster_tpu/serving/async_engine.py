@@ -18,11 +18,13 @@ import asyncio
 import dataclasses
 import itertools
 import threading
+import time
 from typing import AsyncIterator, Optional
 
 from ..analysis.sanitize import build_interleave_sanitizer
 from ..config import EngineConfig
 from ..engine import LLMEngine, RequestOutput, SamplingParams
+from ..observability import FrameClock
 from ..utils import get_logger
 from ..utils.stack import roomy_stack
 
@@ -31,7 +33,20 @@ logger = get_logger("serving.async_engine")
 
 @dataclasses.dataclass
 class StreamChunk:
-    """One step's worth of progress for a request."""
+    """One step's worth of progress for a request.
+
+    ``clock`` is the frame's clock (observability/phases.py), all of it on
+    ``time.monotonic``: the program's part (``clock.program``: its number,
+    ``t_ready``, ``t_retired``, one object shared by the program's rows,
+    stamped by the engine's worker in ``_fetching`` and ``_retired``), and
+    this frame's own: ``t_posted`` (the worker, in ``_post``, at the row's
+    hand-over to the event loop), ``t_woken`` (the loop, in ``_deliver``,
+    when the row's callback runs) and ``t_resumed`` (the loop, in
+    ``generate``, when the consumer comes back from ``await queue.get()``).
+    The HTTP layer closes it at the write's return
+    (``Observability.on_frame``: ``kgct_frame_delay_seconds`` and its five
+    stages). None where no program made the chunk (abort, import,
+    migration): such a frame is held against nothing."""
     request_id: str
     new_token_ids: list[int]
     output_token_ids: list[int]
@@ -39,10 +54,7 @@ class StreamChunk:
     finish_reason: Optional[str]
     new_logprobs: list[float] = dataclasses.field(default_factory=list)
     new_top_logprobs: list = dataclasses.field(default_factory=list)
-    # time.monotonic at which the program that produced these tokens was
-    # ready on the device (None: no program did); the frame that carries
-    # them is held against it (kgct_frame_delay_seconds).
-    t_ready: Optional[float] = None
+    clock: Optional[FrameClock] = None
 
 
 class AsyncLLMEngine:
@@ -236,6 +248,9 @@ class AsyncLLMEngine:
         try:
             while True:
                 chunk = await queue.get()
+                clock = getattr(chunk, "clock", None)   # (or an Exception)
+                if clock is not None:
+                    clock.t_resumed = time.monotonic()
                 if izer is not None and izer.decide("generate.stream")[0]:
                     await asyncio.sleep(0)
                 if isinstance(chunk, Exception):
@@ -462,7 +477,7 @@ class AsyncLLMEngine:
                     wd.arm()
                 try:
                     outs = self.engine.step()
-                    with span("worker.post"):
+                    with span("worker.post", **_posted(outs)):
                         for out in outs:
                             self._post(_chunk_of(out))
                 except Exception as e:  # engine wedged: fail all waiters
@@ -496,12 +511,31 @@ class AsyncLLMEngine:
     def _post(self, chunk: StreamChunk) -> None:
         queue = self._queues.get(chunk.request_id)
         if queue is not None and self._loop is not None:
-            self._loop.call_soon_threadsafe(queue.put_nowait, chunk)
+            if chunk.clock is not None:
+                chunk.clock.t_posted = time.monotonic()
+            self._loop.call_soon_threadsafe(_deliver, queue, chunk)
 
     def _post_exc(self, request_id: str, exc: Exception) -> None:
         queue = self._queues.get(request_id)
         if queue is not None and self._loop is not None:
             self._loop.call_soon_threadsafe(queue.put_nowait, exc)
+
+
+def _deliver(queue: asyncio.Queue, chunk: StreamChunk) -> None:
+    """On the event loop, the callback of one row's hand-over: the loop's
+    turn came (the end of the frame's ``wake`` stage), the chunk joins its
+    request's queue."""
+    if chunk.clock is not None:
+        chunk.clock.t_woken = time.monotonic()
+    queue.put_nowait(chunk)
+
+
+def _posted(outs: list) -> dict:
+    """Arguments of the span ``kgct.worker.post`` over ``outs``: the rows
+    it hands over and the number of the program that made them (the one
+    ``engine.step()`` just retired; -1: none did)."""
+    step = next((o.clock.step for o in outs if o.clock is not None), -1)
+    return {"step": step, "rows": len(outs)}
 
 
 def _chunk_of(out: RequestOutput) -> StreamChunk:
@@ -513,4 +547,4 @@ def _chunk_of(out: RequestOutput) -> StreamChunk:
         finish_reason=out.finish_reason,
         new_logprobs=list(out.new_logprobs or []),
         new_top_logprobs=list(out.new_top_logprobs or []),
-        t_ready=out.t_ready)
+        clock=None if out.clock is None else FrameClock(out.clock))

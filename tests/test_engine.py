@@ -2,6 +2,8 @@
 preemption — automated versions of the reference's manual serving smoke
 checks (SURVEY §4)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -858,6 +860,56 @@ def test_every_retired_program_has_one_record_and_the_times_add_up(
     assert sum(eng.obs.steps_retired.values()) == len(records)
     assert eng.obs.step_tokens[("mixed", True)] == sum(
         r["tokens"] for r in records if r["kind"] == "mixed")
+    # the lead: a chained program is dispatched before its predecessor's
+    # fetch begins, so it is never negative here; observed where that
+    # predecessor was waited for. The starved seconds: what lies between
+    # two chains (a program launched with nothing in flight, behind one
+    # already retired), by the late program's kind; every output of a
+    # program shares ONE clock, closed before it left ``step()``
+    for chain in chains:
+        for prev, r in zip(chain, chain[1:]):
+            assert r["lead_s"] >= 0 and r["starved_s"] == 0.0
+            assert r["lead_exact"] == (not prev["found_ready"])
+    led = [r for r in records if r["lead_exact"]]
+    assert eng.obs.step_lead.count == len(led) > 3
+    assert eng.obs.step_lead.sum == pytest.approx(
+        sum(r["lead_s"] for r in led))
+    for prev, r in zip(records, records[1:]):
+        if r["pred"] is None:
+            assert r["starved_s"] == r["t_dispatched"] - prev["t_ready"] > 0
+    assert records[0]["starved_s"] == 0.0
+    assert sum(eng.obs.device_starved.values()) == pytest.approx(
+        sum(r["starved_s"] for r in records))
+    for r in records:
+        clock = r["clock"]
+        assert (clock.step, clock.t_ready, clock.t_retired) == (
+            r["step"], r["t_ready"], r["t_retired"])
+
+
+@pytest.mark.parametrize("model", ["debug-tiny", "debug-block-moe"])
+def test_the_outputs_of_one_program_share_its_clock(model):
+    """Both output paths (``_process_window``; a block program's replay):
+    what one call of ``step()`` returns with a clock holds the SAME object,
+    that of the program it retired, closed; a drained output has none."""
+    eng = _queue_engine(model)
+    sp = SamplingParams(max_tokens=9, temperature=0.0)
+    for i in range(3):
+        eng.add_request(f"r{i}", _P[i], sp)
+    retired = []
+    on_step = eng.obs.on_step
+    eng.obs.on_step = lambda rec: (on_step(rec), retired.append(rec))[0]
+    shared = 0
+    while eng.has_unfinished_requests():
+        n = len(retired)
+        outs = eng.step()
+        clocks = {id(o.clock): o.clock for o in outs if o.clock is not None}
+        assert len(clocks) <= 1
+        for clock in clocks.values():
+            [rec] = retired[n:]
+            assert clock is rec["clock"] and clock.step == rec["step"]
+            assert clock.t_ready <= clock.t_retired <= time.monotonic()
+            shared += sum(o.clock is clock for o in outs) > 1
+    assert shared > 0
 
 
 def test_trace_slices_carry_the_step_and_kind_of_the_program_they_served(

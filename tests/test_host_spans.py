@@ -403,7 +403,7 @@ class TestProfileEndpoint:
             "plugins/profile/*/*.xplane.pb"))
         assert len(files) == 1
         assert Path(reply["trace_dir"]) == Path(served.server._profile_dir)
-        names, clock = set(), []
+        names, clock, args = set(), [], {}
         for plane in ProfileData.from_file(str(files[0])).planes:
             for line in plane.lines:
                 for ev in line.events:
@@ -411,8 +411,18 @@ class TestProfileEndpoint:
                         names.add(ev.name)
                     if ev.name == "kgct.clock":
                         clock.append((ev.start_ns, dict(ev.stats)))
+                    if ev.name in ("kgct.worker.post", "kgct.http.write",
+                                   "kgct.device_fetch"):
+                        args.setdefault(ev.name, []).append(dict(ev.stats))
         assert {"kgct.step", "kgct.worker.post", "kgct.http.write",
                 "kgct.clock"} | {f"kgct.{p}" for p in STEP_PHASES} <= names
+        # a frame's spans carry the number of the program whose fetch they
+        # follow: the hand-over (with its rows) and the write
+        fetched = {int(a["step"]) for a in args["kgct.device_fetch"]}
+        posted = [a for a in args["kgct.worker.post"] if int(a["rows"])]
+        assert posted and {int(a["step"]) for a in posted} <= fetched
+        assert {int(a["step"]) for a in args["kgct.http.write"]} \
+            & fetched
         # the dispatch spans carry their program's kind as the benchmark's
         # reader of the host's lead finds it (perfbench trace_step_lead)
         from perfbench.readers import trace_step_lead

@@ -16,7 +16,7 @@ from kubernetes_gpu_cluster_tpu.observability import (  # noqa: E402
 from kubernetes_gpu_cluster_tpu.observability.flightrecorder import (  # noqa: E402
     FlightRecorder)
 from kubernetes_gpu_cluster_tpu.observability.phases import (  # noqa: E402
-    StepPhaseStats)
+    FRAME_STAGES, FrameClock, StepClock, StepPhaseStats)
 from kubernetes_gpu_cluster_tpu.observability.trace import (  # noqa: E402
     RequestTracer, merge_perfetto)
 
@@ -71,6 +71,17 @@ class TestHistogram:
         assert any(l == "t_seconds_count 5" for l in lines)
         [s] = [float(l.split()[-1]) for l in lines if l.startswith("t_seconds_sum")]
         assert abs(s - 56.05) < 1e-9
+
+    @pytest.mark.parametrize("value,cums", [
+        (0.05, [1, 1, 1, 1]), (0.1, [1, 1, 1, 1]), (0.1000001, [0, 1, 1, 1]),
+        (10.0, [0, 0, 1, 1]), (10.5, [0, 0, 0, 1])])
+    def test_a_value_on_a_bound_falls_in_that_bucket(self, value, cums):
+        """``le``: the first bucket whose bound is >= the value; above the
+        last bound only +Inf counts it."""
+        h = Histogram("t_seconds", buckets=(0.1, 1.0, 10.0))
+        h.observe(value)
+        assert [int(l.split()[-1]) for l in h.render()
+                if "_bucket" in l] == cums
 
     def test_nan_observation_dropped(self):
         h = Histogram("t_seconds")
@@ -567,6 +578,36 @@ class TestStepClock:
         assert g["ready_gap_s"] is None and g["device_s"] == pytest.approx(
             0.100)
         assert all(r["slow"] is None for r in (a, b, c, d, e, f, g))
+        # the lead counts where the predecessor was waited for: not for 1
+        # (none), 5 (behind 4, found ready) and 8 (7 never came back)
+        assert [r["lead_exact"] for r in (a, b, c, d, e, f, g)] == [
+            False, True, True, True, False, True, False]
+        # the chip had nothing queued only before 3: 20 ms
+        assert [r["starved_s"] for r in (a, b, d, e, f, g)] == [0.0] * 6
+        assert c["starved_s"] == pytest.approx(0.020)
+
+    def test_starved_seconds_at_a_chain_break_and_not_across_an_idle_turn(
+            self):
+        st = StepPhaseStats()
+        st.worker_turn("host", 19.0)
+        st.retire(_stamps(1, "decode", None, 20.000, 20.010, 20.100))
+        # a chain break: 2 was scheduled only once 1 was fetched, with
+        # nothing in flight (pred None): the chip stood from 1's end to
+        # 2's dispatch
+        b = st.retire(_stamps(2, "prefill", None, 20.130, 20.140, 20.200))
+        assert b["starved_s"] == pytest.approx(0.030)
+        assert b["lead_s"] is None and not b["lead_exact"]
+        # the worker waited on its inbox between 2's end and 3's dispatch:
+        # there was no request, the chip was not starved of anything
+        st.worker_turn("inbox_wait", 20.250)
+        st.worker_turn("host", 25.000)
+        c = st.retire(_stamps(3, "prefill", None, 25.010, 25.020, 25.100))
+        assert c["starved_s"] == 0.0
+        # and the idle turn is behind: 4, chained to 3 but dispatched 5 ms
+        # after its end, counts again
+        d = st.retire(_stamps(4, "decode", 3, 25.105, 25.110, 25.200))
+        assert d["starved_s"] == pytest.approx(0.005)
+        assert d["lead_s"] == pytest.approx(-0.005) and d["lead_exact"]
 
     def test_slow_gap_is_classified_by_cause_both_ways(self):
         st = StepPhaseStats()
@@ -606,10 +647,10 @@ class TestStepClock:
                          tokens=40, padded_tokens=64))
         obs.on_step(_rec(2, "decode", 2, 0.10, 16, t0=10.04, pred=1,
                          wait_s=0.080, tokens=16, padded_tokens=16,
-                         mode="greedy"))
+                         mode="greedy", t_dispatched=10.020))
         obs.on_step(_rec(3, "decode", 2, 0.10, 16, t0=10.13, pred=2,
                          wait_s=0.00001, tokens=16, padded_tokens=32,
-                         mode="greedy"))
+                         mode="greedy", t_dispatched=10.112))
         text = "\n".join(obs.render_prometheus())
         # 1 and 2 were waited for; 3 was found ready: not observed
         assert 'kgct_step_device_seconds_count{kind="prefill"} 1' in text
@@ -628,10 +669,42 @@ class TestStepClock:
         recs = obs.phases.step_records()
         assert [r["step"] for r in recs] == [1, 2, 3]
         assert recs[2]["args"]["exact"] is False
-        obs.on_frame(time.monotonic() - 0.004)
+        # the lead: 2 behind 1 and 3 behind 2 (both waited for) count, 12
+        # and 10 ms ahead of the chip; 4, launched with nothing in flight
+        # 8 ms after 3's end (found ready: the host's arrival), adds that
+        # to the starved seconds of ITS kind and nothing to the lead
+        assert obs.step_lead.count == 2
+        assert obs.step_lead.sum == pytest.approx(0.022)
+        obs.on_step(_rec(4, "mixed", 2, 0.10, 2, t0=10.13901, wait_s=0.05,
+                         tokens=8, padded_tokens=8))
+        assert obs.step_lead.count == 2
+        text = "\n".join(obs.render_prometheus())
+        assert 'kgct_step_lead_seconds_count{kind="decode"} 2' in text
+        assert 'kgct_device_starved_seconds_total{kind="mixed"} 0.008' in text
+        assert 'kgct_device_starved_seconds_total{kind="decode"} 0' in text
+        assert recs[1]["args"]["starved_ms"] == 0.0
+        assert obs.phases.step_records()[3]["args"]["starved_ms"] == 8.0
+        [ev] = [e for e in obs.tracer.events() if e.kind == "mixed"]
+        assert ev.args["starved_ms"] == 8.0 and ev.args["lead_ms"] is None
+        # a frame of program 3, written now: the delay whole and in five
+        # stages that add up to it; a chunk no program made counts nowhere
+        now = time.monotonic()
+        clock = FrameClock(StepClock(3, now - 0.010))
+        clock.program.t_retired = now - 0.008
+        clock.t_posted, clock.t_woken = now - 0.007, now - 0.004
+        clock.t_resumed = now - 0.003
+        obs.on_frame(clock)
         obs.on_frame(None)
         assert obs.frame_delay.count == 1
-        assert 0.004 <= obs.frame_delay.sum < 0.1
+        assert 0.010 <= obs.frame_delay.sum < 0.1
+        cells = obs.frame_stage._cells
+        assert list(cells) == [(s,) for s in FRAME_STAGES]
+        assert [round(cells[(s,)][1], 6) for s in FRAME_STAGES[:4]] == [
+            0.002, 0.001, 0.003, 0.001]
+        assert 0.003 <= cells[("render",)][1] < 0.1
+        assert obs.frame_stage.count == 5
+        assert obs.frame_stage.sum == pytest.approx(obs.frame_delay.sum,
+                                                    rel=1e-9)
 
     def test_worker_states_sum_to_the_threads_wall(self):
         """A thread that turns as the worker does (idle on the inbox,
@@ -698,6 +771,21 @@ kgct_queue_wait_seconds_sum 2.0
 kgct_queue_wait_seconds_count 10
 kgct_prefill_seconds_sum 3.0
 kgct_prefill_seconds_count 10
+kgct_frame_stage_seconds_sum{stage="retire"} 0.1
+kgct_frame_stage_seconds_count{stage="retire"} 1000
+kgct_frame_stage_seconds_sum{stage="post"} 0.2
+kgct_frame_stage_seconds_count{stage="post"} 1000
+kgct_frame_stage_seconds_sum{stage="wake"} 0.3
+kgct_frame_stage_seconds_count{stage="wake"} 1000
+kgct_frame_stage_seconds_sum{stage="queue"} 0.3
+kgct_frame_stage_seconds_count{stage="queue"} 1000
+kgct_frame_stage_seconds_sum{stage="render"} 0.1
+kgct_frame_stage_seconds_count{stage="render"} 1000
+kgct_step_lead_seconds_sum{kind="decode"} 2.0
+kgct_step_lead_seconds_count{kind="decode"} 100
+kgct_device_starved_seconds_total{kind="decode"} 0
+kgct_device_starved_seconds_total{kind="mixed"} 0.5
+kgct_device_starved_seconds_total{kind="prefill"} 0
 """
 _SCRAPE_AFTER = """
 kgct_step_device_seconds_sum{kind="decode"} 34.0
@@ -725,6 +813,23 @@ kgct_queue_wait_seconds_sum 2.9
 kgct_queue_wait_seconds_count 40
 kgct_prefill_seconds_sum 5.4
 kgct_prefill_seconds_count 40
+kgct_frame_stage_seconds_sum{stage="retire"} 0.25
+kgct_frame_stage_seconds_count{stage="retire"} 2500
+kgct_frame_stage_seconds_sum{stage="post"} 0.5
+kgct_frame_stage_seconds_count{stage="post"} 2500
+kgct_frame_stage_seconds_sum{stage="wake"} 1.2
+kgct_frame_stage_seconds_count{stage="wake"} 2500
+kgct_frame_stage_seconds_sum{stage="queue"} 1.5
+kgct_frame_stage_seconds_count{stage="queue"} 2500
+kgct_frame_stage_seconds_sum{stage="render"} 0.55
+kgct_frame_stage_seconds_count{stage="render"} 2500
+kgct_step_lead_seconds_sum{kind="decode"} 5.6
+kgct_step_lead_seconds_count{kind="decode"} 300
+kgct_step_lead_seconds_sum{kind="mixed"} 12.0
+kgct_step_lead_seconds_count{kind="mixed"} 100
+kgct_device_starved_seconds_total{kind="decode"} 0.04
+kgct_device_starved_seconds_total{kind="mixed"} 0.75
+kgct_device_starved_seconds_total{kind="prefill"} 0
 """
 _NEW_METRICS = {
     # name: (reader, the value the two scrapes hold)
@@ -739,24 +844,59 @@ _NEW_METRICS = {
     "frame_delay_mean_ms": ("prom_hist_mean", 2.0),
     "queue_wait_mean_ms": ("prom_hist_mean", 30.0),
     "prefill_mean_ms": ("prom_hist_mean", 80.0),
+    # the frame delay's five stages: they add up to frame_delay_mean_ms
+    "frame_retire_mean_ms": ("prom_hist_mean_where", 0.1),
+    "frame_post_mean_ms": ("prom_hist_mean_where", 0.2),
+    "frame_wake_mean_ms": ("prom_hist_mean_where", 0.6),
+    "frame_queue_mean_ms": ("prom_hist_mean_where", 0.8),
+    "frame_render_mean_ms": ("prom_hist_mean_where", 0.3),
+    # the lead by kind (a kind first seen inside the window counts from 0)
+    "step_lead_decode_inproc_ms": ("prom_hist_mean_where", 18.0),
+    "step_lead_mixed_inproc_ms": ("prom_hist_mean_where", 120.0),
+    "device_starved_s_in_window": ("prom_counter_delta", 0.29),
 }
+# PR 52's eight: the data file is there, the entry in BENCHMARK.json is a
+# `benchmark` PR's to add (PERF.md section 7: entries go at the list's end,
+# where perfbench/tests/test_roofline_block.py holds sdar's three to be)
+_NO_ENTRY_YET = {
+    "frame_retire_mean_ms", "frame_post_mean_ms", "frame_wake_mean_ms",
+    "frame_queue_mean_ms", "frame_render_mean_ms",
+    "step_lead_decode_inproc_ms", "step_lead_mixed_inproc_ms",
+    "device_starved_s_in_window"}
+
+
+def test_the_five_frame_stages_add_up_to_the_frame_delay():
+    assert sum(v for n, (_, v) in _NEW_METRICS.items()
+               if n.startswith("frame_") and n != "frame_delay_mean_ms") \
+        == pytest.approx(_NEW_METRICS["frame_delay_mean_ms"][1])
 
 
 @pytest.mark.parametrize("name", sorted(_NEW_METRICS))
 def test_new_metric_file_reads_its_value_from_two_scrapes(name):
     """Each is a data file of its own over a reader of the benchmark, named
-    in BENCHMARK.json as its file says, listed for all four cells; a server
-    without the series (the parent) reads nothing and does not raise."""
+    in BENCHMARK.json as its file says, listed for every cell; a server
+    without the series (the parent) reads nothing and does not raise.
+    PR 52's eight have their file and no entry yet (``_NO_ENTRY_YET``):
+    their file is held to a layer and an end-to-end metric the benchmark
+    has, which is what the entry will have to say."""
     from perfbench import readers, stats
     from perfbench.spec import Benchmark
     bench = Benchmark()
     spec = bench.layer_metric(name)
-    entry = next(m for m in bench.doc["per_layer"] if m["name"] == name)
+    entry = next((m for m in bench.doc["per_layer"] if m["name"] == name),
+                 None)
     reader, want = _NEW_METRICS[name]
     assert spec["reader"] == reader
-    assert {k: spec[k] for k in ("unit", "layer", "moves", "source")} == {
-        k: entry[k] for k in ("unit", "layer", "moves", "source")}
-    assert entry["workloads"] == bench.cell_names()
+    assert (entry is None) == (name in _NO_ENTRY_YET)
+    if entry is None:
+        assert spec["layer"] in {m["layer"] for m in bench.doc["per_layer"]}
+        assert spec["moves"] in {m["name"] for m in bench.doc["end_to_end"]}
+        assert spec["source"] in ("program_span", "program_counter")
+        assert spec["better"] in ("lower", "higher") and spec["unit"]
+    else:
+        assert {k: spec[k] for k in ("unit", "layer", "moves", "source")} \
+            == {k: entry[k] for k in ("unit", "layer", "moves", "source")}
+        assert entry["workloads"] == bench.cell_names()
     ctx = {"scrape_before": stats.parse_prometheus(_SCRAPE_BEFORE),
            "scrape_after": stats.parse_prometheus(_SCRAPE_AFTER),
            "config": {"warmup": {"decode_window": 8}}}
@@ -766,12 +906,15 @@ def test_new_metric_file_reads_its_value_from_two_scrapes(name):
         spec, dict(ctx, scrape_before=parent, scrape_after=parent)) is None
 
 
-def test_a_clean_window_reads_zero_slow_seconds_not_nothing():
-    """The slow-step series are there from the first scrape, at 0: a clean
-    window reports 0, and only a program without them reports nothing."""
+@pytest.mark.parametrize("name", ["slow_step_s_in_window",
+                                  "device_starved_s_in_window"])
+def test_a_clean_window_reads_zero_slow_seconds_not_nothing(name):
+    """The slow-step and the starved series are there from the first
+    scrape, at 0: a clean window reports 0, and only a program without them
+    reports nothing."""
     from perfbench import readers, stats
     from perfbench.spec import Benchmark
-    spec = Benchmark().layer_metric("slow_step_s_in_window")
+    spec = Benchmark().layer_metric(name)
     fresh = stats.parse_prometheus(
         "\n".join(Observability().render_prometheus()))
     assert readers.load(spec["reader"])(

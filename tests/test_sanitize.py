@@ -318,7 +318,7 @@ class _ScriptedEngine:
                 request_id=rid, new_token_ids=[toks[-1]],
                 output_token_ids=list(toks), finished=fin,
                 finish_reason="length" if fin else None,
-                new_logprobs=[], new_top_logprobs=[], t_ready=None))
+                new_logprobs=[], new_top_logprobs=[], clock=None))
             if fin:
                 del self._live[rid]
         return outs

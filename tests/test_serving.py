@@ -366,17 +366,26 @@ class TestObservability:
     def test_frame_delay_and_the_workers_three_states_on_a_live_server(
             self, api_client):
         """Every token-bearing frame of a stream is held against the end of
-        the program that made its tokens; and the step loop thread's three
-        states, read at two moments, grew by the wall between them (within
-        1 %): idle on the inbox before, blocked and busy through a stream."""
+        the program that made its tokens, whole and in five stages that add
+        up to it; and the step loop thread's three states, read at two
+        moments, grew by the wall between them (within 1 %): idle on the
+        inbox before, blocked and busy through a stream."""
         import time
+        from kubernetes_gpu_cluster_tpu.observability.phases import (
+            FRAME_STAGES)
         loop, client = api_client
         obs = _SERVER["api"].engine.engine.obs
 
         def read():
             got, now = obs.phases.worker_seconds(), time.monotonic()
             return got, now
+
+        def stages():
+            # stage -> (sum, count), a copy
+            return {s: tuple(obs.frame_stage._cells.get((s,), [0, 0.0, 0])[1:])
+                    for s in FRAME_STAGES}
         (w0, t0), n0 = read(), obs.frame_delay.count
+        sum0, stage0 = obs.frame_delay.sum, stages()
 
         async def go():
             r = await client.post("/v1/completions", json={
@@ -391,6 +400,15 @@ class TestObservability:
         assert 0 < len(frames) <= obs.frame_delay.count - n0 + 1
         assert obs.frame_delay.count - n0 >= len(frames) - 1
         assert 0 < obs.frame_delay.sum < 60.0
+        # the same frames, the same stamps: every stage counted once a
+        # frame, none negative, and the five sums are the delay's sum
+        stage1 = stages()
+        by_stage = {s: stage1[s][0] - stage0[s][0] for s in FRAME_STAGES}
+        assert [stage1[s][1] - stage0[s][1] for s in FRAME_STAGES] \
+            == [obs.frame_delay.count - n0] * 5
+        assert all(v >= 0 for v in by_stage.values()), by_stage
+        assert sum(by_stage.values()) == pytest.approx(
+            obs.frame_delay.sum - sum0, rel=1e-6)
         grown = {k: w1[k] - w0[k] for k in w1}
         assert all(v >= 0 for v in grown.values()), grown
         assert sum(grown.values()) == pytest.approx(t1 - t0, rel=0.01)
@@ -400,9 +418,16 @@ class TestObservability:
         async def scrape():
             return await (await client.get("/metrics")).text()
         text = loop.run_until_complete(scrape())
-        for fam in ("kgct_step_device_seconds", "kgct_frame_delay_seconds"):
+        for fam in ("kgct_step_device_seconds", "kgct_frame_delay_seconds",
+                    "kgct_frame_stage_seconds", "kgct_step_lead_seconds"):
             assert f"# TYPE {fam} histogram" in text
+        for stage in FRAME_STAGES:
+            assert ('kgct_frame_stage_seconds_count{stage="%s"} %d'
+                    % (stage, obs.frame_delay.count)) in text
+        assert 'kgct_step_lead_seconds_count{kind="decode"}' in text
+        assert 'kgct_device_starved_seconds_total{kind="decode"} ' in text
         for fam in ("kgct_steps_retired_total", "kgct_step_slow_total",
+                    "kgct_device_starved_seconds_total",
                     "kgct_step_slow_seconds_total", "kgct_step_tokens_total",
                     "kgct_worker_seconds_total"):
             assert f"# TYPE {fam} counter" in text
