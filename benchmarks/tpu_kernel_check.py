@@ -1660,91 +1660,129 @@ def check_dsa(cfg) -> None:
 
 def check_block_attend(cfg, pps, B) -> None:
     """A block model's pass (``ops/pallas/block_attend.py``) at the served
-    geometry, B rows of ``block_length`` positions over up to 2.7 k cached
-    tokens a row (the ``batch-decode-2k`` cell's longest: 1920 + 768), held
-    to FLOAT64 on the host: q and the block's own K/V float32 (so the
-    output is not rounded to bf16 and a fault cannot hide in that rounding),
-    the pool bf16 as served. Rows 0-3 have 0, 1, 5 and 130 cached tokens
-    (no page, part of one, two pages), the last the longest. Then the
-    planted fault, a causal mask INSIDE the block (the kernel's own
-    ``causal=True``), which must read over the limit; and the kernel timed,
-    one call a layer in one program, against the XLA twin."""
+    geometry, B rows over up to 2.7 k cached tokens a row (the
+    ``batch-decode-2k`` cell's longest: 1920 + 768), held to FLOAT64 on the
+    host: q and the rows' own K/V float32 (so the output is not rounded to
+    bf16 and a fault cannot hide in that rounding), the pool bf16 as served.
+    Rows 0-3 have 0, 1, 5 and 130 cached tokens (no page, part of one, two
+    pages), the last the longest. At ONE block a row (``block_length``
+    positions: what a pass was until PR 53) and at TWO, as served ([the
+    block awaiting its commit | the open block], the block-causal mask
+    between them). Then the planted faults, each of which must read over
+    the limit: a causal mask INSIDE the block (``own="causal"``), and at
+    two blocks the first seeing the second (``own="all"``). And the kernel
+    timed, one call a layer in one program, against the XLA twin: at one
+    block, at two with every row's second computed, and at two with one
+    row in four holding a second (the sampler's floor: the others' second
+    halves are skipped)."""
     from kubernetes_gpu_cluster_tpu.ops.attention import (
         spec_verify_attention_xla)
     from kubernetes_gpu_cluster_tpu.ops.pallas.block_attend import (
         block_attend)
-    S, nh, n_kv, hd = (cfg.block_length, cfg.num_heads, cfg.num_kv_heads,
-                       cfg.head_dim)
+    blk, nh, n_kv, hd = (cfg.block_length, cfg.num_heads, cfg.num_kv_heads,
+                         cfg.head_dim)
     L, kd, scale = cfg.num_kv_layers, cfg.num_kv_heads * cfg.head_dim, \
         cfg.attn_scale
     rng = np.random.default_rng(0)
-    ctx = rng.integers(1024, 2689, B).astype(np.int32) + 1
-    ctx[:4], ctx[-1] = (1, 2, 6, 131), 2689
+    top = min(2688, pps * PS)       # (a debug preset's length is shorter)
+    ctx = rng.integers(top * 8 // 21, top + 1, B).astype(np.int32) + 1
+    ctx[:4], ctx[-1] = (1, 2, 6, 131), top + 1
     tables, P = _page_tables(ctx, pps)
 
     def bf(shape):      # bf16-representable values, whatever the dtype
         return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    q = bf((B * S, nh, hd)).astype(jnp.float32)
-    k = bf((B * S, n_kv, hd)).astype(jnp.float32)
-    v = bf((B * S, n_kv, hd)).astype(jnp.float32)
     k_pool, v_pool = bf((L, P, PS, kd)), bf((L, P, PS, kd))
     tables_d, ctx_d = jnp.asarray(tables), jnp.asarray(ctx)
     layer = L - 1
-
-    def host64(causal):
-        kp = np.asarray(k_pool[layer].astype(jnp.float32), np.float64)
-        vp = np.asarray(v_pool[layer].astype(jnp.float32), np.float64)
-        q64, k64, v64 = (np.asarray(a, np.float64).reshape(B, S, -1, hd)
-                         for a in (q, k, v))
-        out = np.zeros((B, S, nh, hd))
-        g = nh // n_kv
-        for b in range(B):
-            n = int(ctx[b]) - 1
-            hist_k = kp[tables[b]].reshape(-1, n_kv, hd)[:n]
-            hist_v = vp[tables[b]].reshape(-1, n_kv, hd)[:n]
-            for s in range(S):
-                own = s + 1 if causal else S
-                keys = np.concatenate([hist_k, k64[b, :own]])
-                vals = np.concatenate([hist_v, v64[b, :own]])
-                for h in range(nh):
-                    sc = keys[:, h // g] @ q64[b, s, h] * scale
-                    p = np.exp(sc - sc.max())
-                    out[b, s, h] = (p / p.sum()) @ vals[:, h // g]
-        return out.reshape(B * S, nh, hd)
-
-    run = jax.jit(lambda causal: block_attend(
-        q, k, v, k_pool, v_pool, tables_d, ctx_d, scale,
-        layer=jnp.int32(layer), causal=causal), static_argnums=0)
-    want = host64(False)
-    err = float(np.max(np.abs(np.asarray(run(False), np.float64) - want)))
-    fault = float(np.max(np.abs(np.asarray(run(True), np.float64) - want)))
-    twin = float(np.max(np.abs(np.asarray(spec_verify_attention_xla(
-        q, k, v, k_pool, v_pool, tables_d, ctx_d, scale,
-        layer=jnp.int32(layer), causal=False), np.float64) - want)))
     limit = BLOCK_ATTEND_LIMIT
-    print(f"block_attend B={B} S={S} {nh}q/{n_kv}kv x {hd}, up to "
-          f"{int(ctx.max()) - 1} cached tokens: max|kernel - float64| = "
-          f"{err:.2e}, XLA twin {twin:.2e}, planted causal-inside-the-block "
-          f"fault {fault:.2e} (limit {limit:.0e})")
-    assert err < limit < fault, (err, fault)
-
-    qb, kb, vb = (a.astype(jnp.bfloat16) for a in (q, k, v))
-
-    def layers(fn):
-        def go(q, k, v, kp, vp):
-            out = jnp.zeros(q.shape, jnp.float32)
-            for l in range(L):
-                out += fn(q, k, v, kp, vp, tables_d, ctx_d, scale,
-                          layer=jnp.int32(l)).astype(jnp.float32)
-            return out
-        return jax.jit(go)
-    t_k = _timed(layers(block_attend), qb, kb, vb, k_pool, v_pool) / L
-    t_x = _timed(layers(lambda *a, **kw: spec_verify_attention_xla(
-        *a, **kw, causal=False)), qb, kb, vb, k_pool, v_pool) / L
+    floor = jnp.asarray(np.arange(B) % 4 == 0, jnp.int32)
     pages = sum(cdiv(int(n) - 1, PS) for n in ctx)
     least = pages * 2 * PS * kd * 2 / 819e9
-    print(f"block_attend a layer: {t_k * 1e6:.0f} us ({least / t_k:.1%} of "
-          f"819 GB/s in whole pages), XLA twin {t_x * 1e6:.0f} us")
+
+    for S in (blk, 2 * blk):
+        q = bf((B * S, nh, hd)).astype(jnp.float32)
+        k = bf((B * S, n_kv, hd)).astype(jnp.float32)
+        v = bf((B * S, n_kv, hd)).astype(jnp.float32)
+
+        def host64(own):
+            kp = np.asarray(k_pool[layer].astype(jnp.float32), np.float64)
+            vp = np.asarray(v_pool[layer].astype(jnp.float32), np.float64)
+            q64, k64, v64 = (np.asarray(a, np.float64).reshape(B, S, -1, hd)
+                             for a in (q, k, v))
+            out = np.zeros((B, S, nh, hd))
+            g = nh // n_kv
+            for b in range(B):
+                n = int(ctx[b]) - 1
+                hist_k = kp[tables[b]].reshape(-1, n_kv, hd)[:n]
+                hist_v = vp[tables[b]].reshape(-1, n_kv, hd)[:n]
+                for s in range(S):
+                    seen = {"block": (s // blk + 1) * blk, "causal": s + 1,
+                            "all": S}[own]
+                    keys = np.concatenate([hist_k, k64[b, :seen]])
+                    vals = np.concatenate([hist_v, v64[b, :seen]])
+                    for h in range(nh):
+                        sc = keys[:, h // g] @ q64[b, s, h] * scale
+                        p = np.exp(sc - sc.max())
+                        out[b, s, h] = (p / p.sum()) @ vals[:, h // g]
+            return out.reshape(B * S, nh, hd)
+
+        # (the arrays as arguments: closed over, each compile would hold
+        # the 2 GB pool as a constant of its executable)
+        jitted = jax.jit(lambda own, wide, *a: block_attend(
+            *a, tables_d, ctx_d, scale, layer=jnp.int32(layer), block=blk,
+            wide=wide, own=own), static_argnums=0)
+
+        def run(own, wide=None):
+            return jitted(own, wide, q, k, v, k_pool, v_pool)
+        want = host64("block")
+
+        def far(out):
+            return float(np.max(np.abs(np.asarray(out, np.float64) - want)))
+        err = far(run("block"))
+        faults = {"causal-inside-the-block": far(run("causal"))}
+        if S > blk:
+            faults["first-block-sees-the-second"] = far(run("all"))
+            # a row without a second block: zeros there, its first intact
+            part = np.asarray(run("block", floor),
+                              np.float64).reshape(B, 2, -1)
+            whole = want.reshape(B, 2, -1)
+            held = np.asarray(floor, bool)
+            assert not part[~held, 1].any()
+            err = max(err, float(np.abs(part[:, 0] - whole[:, 0]).max()),
+                      float(np.abs(part[held, 1] - whole[held, 1]).max()))
+        twin = far(spec_verify_attention_xla(
+            q, k, v, k_pool, v_pool, tables_d, ctx_d, scale,
+            layer=jnp.int32(layer), causal=False, block=blk))
+        print(f"block_attend B={B} S={S} (block {blk}) {nh}q/{n_kv}kv x "
+              f"{hd}, up to {int(ctx.max()) - 1} cached tokens: "
+              f"max|kernel - float64| = {err:.2e}, XLA twin {twin:.2e}, "
+              "planted faults " + ", ".join(
+                  f"{n} {f:.2e}" for n, f in faults.items())
+              + f" (limit {limit:.0e})")
+        assert err < limit < min(faults.values()), (err, faults)
+        assert twin < limit, twin
+
+        qb, kb, vb = (a.astype(jnp.bfloat16) for a in (q, k, v))
+
+        def layers(fn):
+            def go(q, k, v, kp, vp):
+                out = jnp.zeros(q.shape, jnp.float32)
+                for l in range(L):
+                    out += fn(q, k, v, kp, vp, tables_d, ctx_d, scale,
+                              layer=jnp.int32(l)).astype(jnp.float32)
+                return out
+            return jax.jit(go)
+        t_x = _timed(layers(lambda *a, **kw: spec_verify_attention_xla(
+            *a, **kw, causal=False, block=blk)), qb, kb, vb, k_pool,
+            v_pool) / L
+        for name, wide in (("every row", None),) + (
+                (("one row in four", floor),) if S > blk else ()):
+            t_k = _timed(layers(functools.partial(
+                block_attend, block=blk, wide=wide)), qb, kb, vb, k_pool,
+                v_pool) / L
+            print(f"block_attend a layer, S={S}, {name} whole: "
+                  f"{t_k * 1e6:.0f} us ({least / t_k:.1%} of 819 GB/s in "
+                  f"whole pages), XLA twin {t_x * 1e6:.0f} us")
 
 
 # float64 against a float32-output kernel over a bf16 pool: Mosaic's
@@ -1752,7 +1790,8 @@ def check_block_attend(cfg, pps, B) -> None:
 # 8.9e-3 on outputs of O(1) (my chip run, PR 50; the first limit, 2e-3, was
 # set before any reading and refused the clean kernel); the planted fault
 # moves a row with no history by 4.19. The limit is 5x the one and 80x under
-# the other.
+# the other. At two blocks a row (my chip runs, PR 53): the kernel 8.1e-3,
+# the XLA twin 1.6e-2, the first block seeing the second 2.95.
 BLOCK_ATTEND_LIMIT = 5e-2
 
 

@@ -170,13 +170,21 @@ class ModelConfig:
     # ``confidence_threshold`` if there are at least
     # ``block_length / denoising_steps`` of them, else that many of the
     # most confident (``remasking`` "low_confidence_dynamic", the one
-    # rule served); a full block's K/V reach the pages in a commit pass.
+    # rule served); a whole block's K/V reach the pages in its commit,
+    # which rides the next block's first denoising pass (``row_width``).
     # 1: an autoregressive model, whose open block is its next position.
     block_length: int = 1
     denoising_steps: int = 1
     confidence_threshold: float = 0.9
     remasking: str = "low_confidence_dynamic"
     mask_token_id: int = 0
+
+    @property
+    def row_width(self) -> int:
+        """Positions a running sequence takes of a step program's pass: one
+        token, or a block model's two blocks, [the block awaiting its
+        commit | the open block] (engine/block.py)."""
+        return 2 * self.block_length if self.block_length > 1 else 1
 
     def __post_init__(self):
         if self.block_length > 1:

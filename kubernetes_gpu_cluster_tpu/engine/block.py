@@ -1,45 +1,64 @@
 """A block model's step programs and what the host makes of them.
 
 Generation by diffusion over blocks (``ModelConfig.block_length`` B > 1,
-sdar_moe): a running sequence is its committed prefix in the pages and an
-OPEN BLOCK of B positions the host holds (``Sequence.block_ids`` /
-``block_masked``). A PASS runs every row's open block, masked positions
-carrying ``mask_token_id``, over the row's pages (``Kernels.block_attention``)
-and gives logits AT each position. Rows of one program are in different
-phases of their blocks:
+sdar_moe): a running sequence is its committed prefix in the pages, at most
+one PENDING block behind it (whole and final, its K/V in no page yet) and
+an OPEN BLOCK of B positions the host holds (``Sequence.block_ids`` /
+``block_masked`` / ``block_pending``). A PASS runs 2 B positions a row over
+the row's pages (``Kernels.block_attention``), [the pending block | the open
+block] from ``start - B``, or [the open block | padding] from ``start``
+where nothing is pending, masked positions carrying ``mask_token_id``;
+block-causal attention inside the row (the first block never sees the
+second) makes both the published passes at once:
 
-- a row with a masked position DENOISES: per masked position the sampled id
-  and its confidence (the probability the sampler reports for it), then the
-  transfer rule (``ops.sampling.block_transfer``); nothing reaches the pages
-  (the pass's K/V go to the scrap page: they are of masked inputs);
-- a row with none COMMITS: the pass ran the block's final ids, its K/V go
-  to the row's pages, and the next block opens all masked.
+- the open block DENOISES: logits AT each of its positions (the head and
+  the sampler see the open block's B positions a row, never the 2 B), per
+  masked position the sampled id and its confidence (the probability the
+  sampler reports for it), then the transfer rule
+  (``ops.sampling.block_transfer``); its K/V go to the scrap page (they are
+  of masked inputs);
+- the pending block COMMITS beside it: the pass ran its final ids over the
+  same prefix a commit pass of its own would have, its K/V go to the row's
+  pages, and the open block behind it saw them in the pass itself.
 
-The commit of block n and the first denoising pass of block n+1 are two
-passes here, as published; fusing them (2 B positions a row) is the same
-mathematics and is not built (PERF.md section 7).
+A block whose last masked position a pass transfers is whole: it becomes
+the pending block of the pass's new state, ``start`` advances by B and the
+next block opens all masked. So no pass exists only to write K/V (as
+published the commit of block n is a pass of its own before the first
+denoising pass of block n+1: the same mathematics, a fifth more passes at
+the sampler's floor), and a request that ends with a block pending never
+writes it.
 
 ``block_window``: W passes in one device program, a ``lax.scan`` over the
-rows' blocks (ids, masked flags, start, passes taken): one upload, one
-download. ``block_mixed``: ONE pass beside a budgeted chunk of the queue
-head's prompt. (A whole window with the chunk beside its first pass was
-built and measured, PERF.md section 6, PR 50: 4 % off ``tpot`` and a
-3 s capture that may hold no pure window for ``decode_step_ms`` to read.) Both are named so that a profile's ``decode_window`` /
-``mixed_step`` readers find them: a pass over all rows is this model's
-decode step.
+rows' state: one upload, one download. ``block_mixed``: a budgeted chunk of
+the queue head's prompt and ONE pass of the rows in one program, as two
+forwards: the chunk's (its K/V are all it is for: a block model's prefill
+samples nothing), then the rows' pass exactly as a window runs it. (Until
+PR 53 the two shared a forward, the chunk's tokens beside the rows'; at 2 B
+positions a row that program rounded a row's positions otherwise than a
+window's pass does, 0.08 nat at one position a pass, which at random
+weights is enough to cross a near tie in the order of a block's transfers:
+PERF.md section 6, PR 53. As two forwards the rows read what a window
+reads, digit for digit. A whole window with the chunk beside its first
+pass was built and measured, PERF.md section 6, PR 50: 4 % off ``tpot`` and
+a 3 s capture that may hold no pure window for ``decode_step_ms`` to read.)
+Both are named so that a profile's ``decode_window`` / ``mixed_step``
+readers find them: a pass over all rows is this model's decode step.
 
 A block program is dispatched BEHIND the one in flight, as every other
 step program (``LLMEngine._step``): each hands on its rows' final state
-(ids, masked flags, start, passes: ``hand_on``, one shape whatever the row
-bucket), and a row of the next names the row of it that holds its open
-block (``Scheduler.fill_block_rows``'s source column), as
-``_chained_tokens`` carries one token a row. The host replays a program
+(``state_width`` columns a row: ``hand_on``, one shape whatever the row
+bucket), and a row of the next names the row of it that holds its blocks
+(``Scheduler.fill_block_rows``'s source column), as ``_chained_tokens``
+carries one token a row: a block that turns pending in a program's last
+pass is written by the first pass of the next. The host replays a program
 one program late (``replay``): a row it finds finished rides the program
 already dispatched as a zombie, its pages held until that one is fetched;
-pages are grown for what the passes in flight may commit
+pages are grown for every block the passes in flight may make whole
 (``Sequence.window_last_pos``). The chain breaks for what breaks it for
 every model: an import since the last schedule, and a batch that needs a
-preemption (the victim's open block is on the chip: fetch first).
+preemption (the victim's blocks are on the chip: fetch first; its pending
+block's ids are tokens that have left, re-prefilled like any other).
 """
 
 from __future__ import annotations
@@ -61,6 +80,13 @@ if TYPE_CHECKING:  # pragma: no cover
     from .engine import LLMEngine
 
 
+def state_width(block_length: int) -> int:
+    """Columns of a row's state on the device: the ids of the block
+    awaiting its commit, the open block's ids and masked flags, its start,
+    the passes it has taken, whether a block awaits its commit."""
+    return 3 * block_length + 3
+
+
 def build_block_fns(engine: "LLMEngine"):
     """(block_window, block_mixed): the two jitted programs of a block
     model, one a decode row bucket and one a (chunk bucket, row bucket,
@@ -75,12 +101,12 @@ def build_block_fns(engine: "LLMEngine"):
     threshold = cfg.confidence_threshold
     reports_load = engine._reports_expert_load
 
-    S = 2 * B + 2       # a row's state: ids, masked flags, start, passes
+    S = state_width(B)
     C = S + 2           # columns of a row's block in the packed buffer
     last_width = engine._last_width
 
     def unpack(int_b, prev):
-        """int_b [R, 2B+4 | 3 | pages]: the row's block (its state as the
+        """int_b [R, 3B+5 | 3 | pages]: the row's block (its state as the
         host last fetched it, the positions its pages cover (0: a padding
         row), the row of ``prev`` that holds the state instead or -1),
         (top_k, seed, top_n), its page table: ONE upload, as the
@@ -91,78 +117,79 @@ def build_block_fns(engine: "LLMEngine"):
         st = jnp.where((src >= 0)[:, None],
                        jnp.take(prev, jnp.maximum(src, 0), axis=0),
                        int_b[:, :S])
-        state = (st[:, :B], st[:, B:2 * B] > 0, st[:, 2 * B], int_b[:, S],
-                 st[:, 2 * B + 1])
+        state = (st[:, :B], st[:, B:2 * B], st[:, 2 * B:3 * B] > 0,
+                 st[:, 3 * B], int_b[:, S], st[:, 3 * B + 1],
+                 st[:, 3 * B + 2] > 0)
         return state, int_b[:, C + 3:], int_b[:, C:C + 3]
 
     def hand_on(state):
-        """A program's final state a row as ``[last_width, 2B+2]`` int32,
+        """A program's final state a row as ``[last_width, 3B+3]`` int32,
         whatever its row bucket: what ``unpack`` of the next program reads,
         one shape for every predecessor, so that a step kind stays one
         program (``_last_tokens``, for blocks)."""
-        ids, masked, start, _, passes = state
+        pend, ids, masked, start, _, passes, pending = state
         rows = jnp.concatenate(
-            [ids, masked.astype(jnp.int32), start[:, None],
-             passes[:, None]], axis=1)
+            [pend, ids, masked.astype(jnp.int32), start[:, None],
+             passes[:, None], pending[:, None].astype(jnp.int32)], axis=1)
         return jnp.zeros((last_width, S), jnp.int32).at[
             :rows.shape[0]].set(rows)
 
     def pack(out):
         """A pass's outputs a row as ONE int32 array [R, 2B+1] (transferred
-        id or -1, the bits of its log-probability, whether the row
-        committed): one download a program; the top alternatives stay
-        behind unless a request asked."""
-        toks, lps, tids, tlps, full = out
+        id or -1, the bits of its log-probability, whether the pass wrote
+        a block to the row's pages): one download a program; the top
+        alternatives stay behind unless a request asked."""
+        toks, lps, tids, tlps, wrote = out
         return (jnp.concatenate(
             [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
-             full[:, None].astype(jnp.int32)], axis=1), tids, tlps)
+             wrote[:, None].astype(jnp.int32)], axis=1), tids, tlps)
 
-    def block_pass(params, kv, state, page_tables, int_b, float_b, key,
-                   chunk=None, load=None):
-        """One pass over every row's open block (and ``chunk``: the queue
-        head's prompt tokens beside them). Returns the new cache, the new
-        state and what the pass did: (transferred id or -1 [R, B], its
+    def block_pass(params, kv, state, page_tables, int_b, float_b, key):
+        """One pass over every row's two blocks. Returns the new cache, the
+        new state and what the pass did: (transferred id or -1 [R, B], its
         log-probability, the top alternatives at its position, whether the
-        row committed [R])."""
-        ids, masked, start, limit, passes = state
+        row's pages were written [R])."""
+        pend, ids, masked, start, limit, passes, pending = state
         R = ids.shape[0]
         live = limit > 0
-        full = ~jnp.any(masked, axis=1)
-        pos = start[:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]
+        wide = pending & live
+        # [the block awaiting its commit | the open block] from start - B,
+        # or [the open block | padding] from start.
+        base = jnp.where(pending, start - B, start)
+        pos = base[:, None] + jnp.arange(2 * B, dtype=jnp.int32)[None, :]
         pos_c = jnp.minimum(pos, max_len - 1)
-        tokens = jnp.where(masked, jnp.int32(cfg.mask_token_id), ids)
-        # Only a committing row's K/V reach its pages, and only where its
-        # pages reach: everything else goes to the scrap page.
+        opened = jnp.where(masked, jnp.int32(cfg.mask_token_id), ids)
+        tokens = jnp.where(pending[:, None],
+                           jnp.concatenate([pend, opened], axis=1),
+                           jnp.concatenate([opened, jnp.zeros_like(ids)],
+                                           axis=1))
+        # Only the K/V of a block that awaited its commit reach the row's
+        # pages, and only where its pages reach: everything else (an open
+        # block's are of masked inputs) goes to the scrap page.
         page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
-        lands = (full & live)[:, None] & (pos < limit[:, None]) \
-            & (pos < max_len)
+        lands = wide[:, None] & (jnp.arange(2 * B) < B)[None, :] \
+            & (pos < limit[:, None]) & (pos < max_len)
         slot = jnp.where(lands, page * ps + pos_c % ps, pos_c % ps)
-        meta = dict(positions=pos_c.reshape(-1), slot_mapping=slot.reshape(-1))
-        toks = tokens.reshape(-1)
-        if chunk is not None:
-            int_t, chunk_page_table, hist_len = chunk
-            n_seg = int_t.shape[1]
-            toks = jnp.concatenate([int_t[0], toks])
-            meta = dict(
-                seg_ids=jnp.concatenate(
-                    [int_t[1], jnp.full((R * B,), -1, jnp.int32)]),
-                positions=jnp.concatenate([int_t[2], meta["positions"]]),
-                slot_mapping=jnp.concatenate(
-                    [int_t[3], meta["slot_mapping"]]),
-                logits_indices=n_seg + jnp.arange(R * B, dtype=jnp.int32),
-                chunk_page_table=chunk_page_table[0], hist_len=hist_len)
-        meta = StepMeta(page_tables=page_tables,
-                        context_lens=jnp.where(live, start + 1, 0), **meta)
-        hidden, kv, _ = model_lib.forward(params, cfg, toks, meta, kv,
-                                          kernels, row_width=B,
-                                          moe_load=load)
+        # Logits, the sampler and the transfer are the OPEN block's.
+        at = (2 * B * jnp.arange(R, dtype=jnp.int32)
+              + jnp.where(pending, B, 0))[:, None] \
+            + jnp.arange(B, dtype=jnp.int32)[None, :]
+        meta = StepMeta(positions=pos_c.reshape(-1),
+                        slot_mapping=slot.reshape(-1),
+                        page_tables=page_tables,
+                        context_lens=jnp.where(live, base + 1, 0),
+                        logits_indices=at.reshape(-1), row_wide=wide)
+        hidden, kv, _ = model_lib.forward(params, cfg, tokens.reshape(-1),
+                                          meta, kv, kernels,
+                                          row_width=2 * B)
         logits = model_lib.compute_logits(params, cfg, hidden, kernels)
         with jax.named_scope("kgct.block.transfer"):
             rep = lambda a: jnp.repeat(a, B, axis=0)    # noqa: E731
             # A position is drawn afresh each pass of its block.
+            open_pos = start[:, None] + jnp.arange(B, dtype=jnp.int32)[None]
             keys = row_sample_keys(
                 key, rep(int_b[:, 1]),
-                pos.reshape(-1) + max_len * rep(passes))
+                open_pos.reshape(-1) + max_len * rep(passes))
             cand, lps, tids, tlps = sample_and_logprobs(
                 logits, keys, rep(float_b[:, 0]), rep(int_b[:, 0]),
                 rep(float_b[:, 1]), row_keys=True,
@@ -171,15 +198,20 @@ def build_block_fns(engine: "LLMEngine"):
             xfer = block_transfer(jnp.exp(lps), masked, n_min, threshold) \
                 & masked
         with jax.named_scope("kgct.block.commit"):
-            # A committed row's next block opens all masked.
-            new_ids = jnp.where(full[:, None], 0, jnp.where(xfer, cand, ids))
-            new_masked = full[:, None] | (masked & ~xfer)
-            new_start = jnp.where(full, start + B, start)
-            new_passes = jnp.where(full, 0, passes + 1)
+            # A block whose last masked position was transferred awaits
+            # its commit from here on, and the next opens all masked.
+            ids = jnp.where(xfer, cand, ids)
+            masked = masked & ~xfer
+            done = ~jnp.any(masked, axis=1)
+            new_state = (jnp.where(done[:, None], ids, 0),
+                         jnp.where(done[:, None], 0, ids),
+                         done[:, None] | masked,
+                         jnp.where(done, start + B, start), limit,
+                         jnp.where(done, 0, passes + 1), done)
         out = (jnp.where(xfer, cand, -1), lps,
                tids.reshape(R, B, TOP_LOGPROBS),
-               tlps.reshape(R, B, TOP_LOGPROBS), full)
-        return kv, (new_ids, new_masked, new_start, limit, new_passes), out
+               tlps.reshape(R, B, TOP_LOGPROBS), wide)
+        return kv, new_state, out
 
     def decode_window_block(params, kv: KVCache, prev, int_b, float_b, key):
         state, page_tables, samp = unpack(int_b, prev)
@@ -199,9 +231,17 @@ def build_block_fns(engine: "LLMEngine"):
         # int_t: [4, Tp] the chunk alone (tokens, seg_ids, positions, slots)
         state, page_tables, samp = unpack(int_b, prev)
         load = [] if reports_load else None
+        # The chunk first, a forward of its own (a block model's prefill
+        # samples nothing: its K/V are what it is for), then the rows' pass
+        # as a window runs it.
+        _, kv, _ = model_lib.forward(
+            params, cfg, int_t[0], StepMeta(
+                seg_ids=int_t[1], positions=int_t[2], slot_mapping=int_t[3],
+                logits_indices=jnp.zeros((1,), jnp.int32),
+                chunk_page_table=chunk_page_table[0], hist_len=hist_len),
+            kv, kernels, moe_load=load)
         kv, state, out = block_pass(
-            params, kv, state, page_tables, samp, float_b, key,
-            chunk=(int_t, chunk_page_table, hist_len), load=load)
+            params, kv, state, page_tables, samp, float_b, key)
         return (*(o[None] for o in pack(out)), hand_on(state), kv,
                 *(load or ()))
 
@@ -234,7 +274,7 @@ def dispatch(engine: "LLMEngine", rec: dict, prev, float_b,
             [batch.block, np.stack([batch.top_k, batch.seed, batch.top_n],
                                    axis=1), batch.page_tables], axis=1))
         if mixed:
-            Tp = len(batch.tokens) - rows * engine.model_config.block_length
+            Tp = len(batch.tokens) - rows * engine.model_config.row_width
             int_t = jnp.asarray(np.stack(
                 [batch.tokens[:Tp], batch.seg_ids[:Tp], batch.positions[:Tp],
                  batch.slot_mapping[:Tp]]))
@@ -259,8 +299,8 @@ def dispatch(engine: "LLMEngine", rec: dict, prev, float_b,
 def fetch(engine: "LLMEngine", step: dict) -> tuple:
     """Copy what a block program's replay needs off the device (inside
     ``LLMEngine._fetching``): every pass's transferred ids,
-    log-probabilities and commit flags, and the top alternatives if a
-    request asked."""
+    log-probabilities and whether it wrote a block to the row's pages, and
+    the top alternatives if a request asked."""
     batch = step["batch"]
     B = engine.model_config.block_length
     packed = np.asarray(step["toks"])        # [W, R, 2B+1]: ``pack``
@@ -272,17 +312,23 @@ def fetch(engine: "LLMEngine", step: dict) -> tuple:
         top_i = np.asarray(step["tids"])
         top_l = np.asarray(step["tlps"])
     if batch.kind == "mixed":
+        # (the load is the chunk's forward's: its tokens alone decide the
+        # dispatch it ran)
+        chunk = len(batch.tokens) \
+            - len(batch.temperature) * engine.model_config.row_width
         engine.obs.on_expert_load(
             step["load"], model_lib.grouped_dispatch(
-                len(batch.tokens), engine.model_config, engine.kernels))
+                chunk, engine.model_config, engine.kernels))
     return toks, lps, commit, top_i, top_l
 
 
 def replay(engine: "LLMEngine", step: dict, fetched: tuple,
            carried: frozenset) -> tuple[list, dict]:
     """Replay a fetched block program's passes over the host's copy of its
-    rows' open blocks and hand on the tokens that became final in position
-    order; returns the outputs and what the step's record adds. The
+    rows' blocks and hand on the tokens that became final in position
+    order; returns the outputs and what the step's record adds. A block
+    that a pass makes whole is pending from there on (``num_committed``
+    advances, the next opens); the pass behind it writes it. The
     program's successor may be running already: a row that finishes here
     and has a row there (``carried``) keeps its pages until that one is
     fetched (``LLMEngine._finish_row``), and rides it as a zombie, whose
@@ -305,12 +351,10 @@ def replay(engine: "LLMEngine", step: dict, fetched: tuple,
         for w in range(W):
             passes += 1
             if commit[w][r]:
+                # The block that awaited its commit is in the pages now,
+                # written beside this pass of the open block.
                 commits += 1
-                engine.obs.block_passes_per_block.observe(
-                    seq.block_passes + 1)
-                seq.num_committed += B
-                seq.open_block()
-                continue
+                seq.block_pending = False
             seq.block_passes += 1
             for i, tok in enumerate(toks[w][r]):
                 if tok < 0:
@@ -343,7 +387,14 @@ def replay(engine: "LLMEngine", step: dict, fetched: tuple,
                     engine._finish_row(seq, reason, carried)
                 at += 1
             if seq.is_finished:
-                break
+                break       # (a block it leaves awaiting is never written)
+            if not any(seq.block_masked):
+                # The block is whole: it awaits its commit, which rides
+                # the next pass, and the next block opens all masked.
+                engine.obs.block_passes_per_block.observe(seq.block_passes)
+                seq.num_committed += B
+                seq.block_pending = True
+                seq.open_block()
         engine.stats.tokens_generated += len(new_tokens)
         if not had_first and seq.first_token_time is not None:
             engine.obs.on_first_token(seq, fetch_s=step["transfer_s"],
@@ -368,13 +419,16 @@ def replay(engine: "LLMEngine", step: dict, fetched: tuple,
     obs = engine.obs
     obs.block_passes += passes
     obs.block_commit_passes += commits
+    obs.block_commits += commits
     obs.block_tokens_transferred += moved
-    obs.block_positions_computed += passes * B
+    obs.block_positions_computed += passes * 2 * B
     # The record counts what the program did for what it is: tokens
-    # TRANSFERRED, positions computed (padding rows' included in
-    # ``padded_tokens``), and its passes.
-    extra = dict(engine._routed(passes * B), passes=passes,
-                 commit_passes=commits, positions=passes * B)
+    # TRANSFERRED, positions computed (two blocks a row-pass, the second
+    # padding where nothing awaited its commit; padding rows' included in
+    # ``padded_tokens``), and its passes. The experts saw the open blocks
+    # and the blocks written.
+    extra = dict(engine._routed((passes + commits) * B), passes=passes,
+                 commit_passes=commits, positions=passes * 2 * B)
     step["tokens"] = moved
     if batch.kind == "mixed":
         extra.update(prefill_tokens=batch.prefill_token_count,

@@ -314,8 +314,8 @@ class LLMEngine:
                                _bucket(sc.max_num_seqs, sc.decode_buckets))
         B = config.model.block_length
         self._no_pred = jnp.zeros(
-            self._last_width if B == 1 else (self._last_width, 2 * B + 2),
-            jnp.int32)
+            self._last_width if B == 1
+            else (self._last_width, block_steps.state_width(B)), jnp.int32)
         self._prefill_fn = self._build_prefill_fn()
         # Two compiled window programs: all-greedy batches (the common
         # serving case) never trace sampling at all — argmax only. Selection
@@ -789,16 +789,17 @@ class LLMEngine:
             return
         blk = {"block": cfg.block_length} if cfg.block_length > 1 else {}
         if blk:
-            # A block model's rows run ``block_attend`` (block_length
+            # A block model's rows run ``block_attend`` (two blocks of
             # query positions a row), never the one-token decode kernel.
             from ..ops.pallas.block_attend import block_attend
-            S = cfg.block_length
+            S = cfg.row_width
             probe("block_attend",
-                  lambda q, k, v, kp, vp, tb, ctx, lyr: block_attend(
-                      q, k, v, kp, vp, tb, ctx, scale, layer=lyr),
+                  lambda q, k, v, kp, vp, tb, ctx, lyr, wide: block_attend(
+                      q, k, v, kp, vp, tb, ctx, scale, layer=lyr,
+                      block=cfg.block_length, wide=wide),
                   arr((B * S, nh, hd)), arr((B * S, nkv, hd)),
                   arr((B * S, nkv, hd)), pool, pool, arr((B, pps), i32),
-                  arr((B,), i32), arr((1,), i32))
+                  arr((B,), i32), arr((1,), i32), arr((B,), i32))
         else:
             probe("paged_decode",
                   lambda q, kp, vp, tb, ctx, kc, vc, lyr:
@@ -825,7 +826,7 @@ class LLMEngine:
         # VMEM blocks) for the largest decode and prefill flushes.
         layers = cfg.num_kv_layers // self.pp_size
         deep_pool = arr((layers, 2, ps, nkv * hd), pool.dtype)
-        for n in (B * cfg.block_length, T):
+        for n in (B * cfg.row_width, T):
             rows = arr((layers, n, nkv * hd))
             probe(f"kv_write[T={n}]", kv_write, deep_pool, deep_pool,
                   rows, rows, arr((n,), i32))
@@ -2198,7 +2199,7 @@ class LLMEngine:
             # (``block.retire`` writes them over this).
             passes = (self.config.scheduler.decode_window
                       if kind == "decode" else 1)
-            width = passes * self.model_config.block_length
+            width = passes * self.model_config.row_width
             # (a mixed step's head, last in ``seqs``, has no row)
             tokens = (batch.prefill_token_count
                       + max(rows - (kind == "mixed"), 0) * width)
@@ -3029,8 +3030,8 @@ def step_workspace_bytes(config: EngineConfig) -> int:
     - the ``[rows, vocab]`` f32 sampling buffers (logits, penalties
       histogram, sort/top-k scratch) at the top decode bucket."""
     m, sc = config.model, config.scheduler
-    # mixed step width (a block model's rows are block_length positions)
-    B = sc.decode_buckets[-1] * m.block_length
+    # mixed step width (a block model's rows are two blocks of positions)
+    B = sc.decode_buckets[-1] * m.row_width
     T = sc.prefill_buckets[-1] + B
     it = m.jnp_dtype.itemsize
     kd = m.kv_row_padded

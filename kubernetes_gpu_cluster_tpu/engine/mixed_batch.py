@@ -290,7 +290,7 @@ def build_mixed_batch(sched: "Scheduler", behind: bool = False
     # first block, no token is sampled behind a prefill.
     chunk_row = (final or D < sc.seat_bucket) and B == 1
     R_pad = mixed_row_bucket(D + chunk_row, Tp, sc)
-    T_pad = Tp + R_pad * B
+    T_pad = Tp + R_pad * sched.row_width
 
     tokens = np.zeros(T_pad, np.int32)
     seg_ids = np.full(T_pad, -1, np.int32)
@@ -357,7 +357,7 @@ def padding_mixed_batch(sched: "Scheduler", Tp: int, R_pad: int,
     from .scheduler import ScheduledBatch
 
     B = sched.block_length
-    T_pad = Tp + R_pad * B
+    T_pad = Tp + R_pad * sched.row_width
     seg_ids = np.full(T_pad, -1, np.int32)
     seg_ids[0] = 0
     pages_bucket = cdiv(sched.config.effective_max_len, sched.page_size)

@@ -92,8 +92,8 @@ class ScheduledBatch:
     # were padded with filler drafts; the split feeds acceptance metrics),
     # the step's verify-slice width S = k+1 (adaptive k varies it between
     # steps), and the draft phase's wall time (trace attribution).
-    # a block model's rows (decode + mixed): [B_pad, 2 * block_length + 4]
-    # int32, each row's open block (``Scheduler.fill_block_rows``)
+    # a block model's rows (decode + mixed): [B_pad, 3 * block_length + 5]
+    # int32, each row's two blocks (``Scheduler.fill_block_rows``)
     block: Optional[np.ndarray] = None
     draft_lens: Optional[np.ndarray] = None        # [B_pad]
     spec_S: Optional[int] = None
@@ -182,8 +182,11 @@ class Scheduler:
         self.prefill_buckets = sc.prefill_buckets
         # The model's block length B (1: autoregressive). Prefills compute
         # ``Sequence.prefill_len`` tokens, whole blocks, and chunks end on
-        # multiples of B; a block model's rows are B positions a pass.
+        # multiples of B; a block model's rows are 2 B positions a pass,
+        # the block awaiting its commit beside the open one
+        # (engine/block.py).
         self.block_length = config.model.block_length
+        self.row_width = config.model.row_width
         self.page_size = config.cache.page_size
         # A state model's slots, the second resource of the same manager: a
         # seat each and the scrap slot, unless the caller holds fewer. (A
@@ -396,6 +399,7 @@ class Scheduler:
         seq.num_prefilled = 0        # pages gone: chunk progress recomputes
         seq.num_committed = 0        # ... and a block model's open block
         seq.block_ids, seq.block_masked, seq.block_marks = [], [], []
+        seq.block_pending = False    # (its tokens are re-prefilled)
         seq.prefix_checked = False   # re-lookup on readmission (cheap TTFT
                                      # recovery when the prefix is cached)
         if self.waiting and (behind_head
@@ -1132,27 +1136,33 @@ class Scheduler:
 
     def fill_block_rows(self, seqs: list[Sequence], R: int) -> dict:
         """A block model's rows, ``R`` of them (padding past ``seqs``): what
-        a pass over every row's open block reads. ``block`` [R, 2 B + 4]
-        int32 = (the block's ids, its masked flags, its start, the passes
-        it has taken: the STATE, as the host last fetched it; then the
-        positions the row's pages cover (a commit past them goes to the
-        scrap page; 0: a padding row) and the state's SOURCE: the row of
-        the final state of the program in flight that holds this block,
-        which the device reads in place of the host's columns; -1 where the
-        host's are the truth: a padding row, a fresh admission's first
-        block, every row when nothing is in flight), and the page tables."""
+        a pass over every row's two blocks reads. ``block`` [R, 3 B + 5]
+        int32 = (the ids of the block awaiting its commit, the open block's
+        ids, its masked flags, its start, the passes it has taken, whether
+        a block awaits its commit: the STATE, as the host last fetched it
+        (``block.state_width``); then the positions the row's pages cover (a
+        commit past them goes to the scrap page; 0: a padding row) and the
+        state's SOURCE: the row of the final state of the program in flight
+        that holds this block, which the device reads in place of the
+        host's columns; -1 where the host's are the truth: a padding row, a
+        fresh admission's first block, every row when nothing is in
+        flight), and the page tables. ``context_lens`` counts what the
+        pages hold, which a pending block is not in yet."""
         B = self.block_length
         pages_bucket = cdiv(self.config.effective_max_len, self.page_size)
-        block = np.zeros((R, 2 * B + 4), np.int32)
+        block = np.zeros((R, 3 * B + 5), np.int32)
         block[:, -1] = -1
         page_tables = np.zeros((R, pages_bucket), np.int32)
         context_lens = np.zeros(R, np.int32)
         for r, seq in enumerate(seqs):
-            block[r] = (*seq.block_ids, *seq.block_masked, seq.num_committed,
-                        seq.block_passes, len(seq.pages) * self.page_size,
-                        seq.inflight_row)
+            at = seq.num_committed
+            pending = (seq.all_token_ids[at - B:at] if seq.block_pending
+                       else [0] * B)
+            block[r] = (*pending, *seq.block_ids, *seq.block_masked, at,
+                        seq.block_passes, seq.block_pending,
+                        len(seq.pages) * self.page_size, seq.inflight_row)
             page_tables[r, :len(seq.pages)] = seq.pages
-            context_lens[r] = seq.num_committed + 1
+            context_lens[r] = at + 1 - B * seq.block_pending
         return dict(block=block, page_tables=page_tables,
                     context_lens=context_lens)
 

@@ -41,18 +41,27 @@ class Sequence:
         # B = 1, whose open block is its one next position. For B > 1 the
         # host holds the block AS OF THE LAST PROGRAM IT FETCHED (the
         # program in flight carries it on, on the device: engine/block.py):
-        # ``num_committed`` positions lie in the pages (a multiple of B),
+        # ``num_committed`` positions are final (a multiple of B),
         # ``block_ids`` / ``block_masked`` are the open block at
         # [num_committed, num_committed + B), ``block_marks`` what the pass
         # that transferred a position said of it (log-probability, top
         # alternatives) until the position leaves in position order, and
         # ``block_passes`` the passes the open block has taken so far.
+        # A block whose last masked position was transferred is final at
+        # once (``num_committed`` advances, the next block opens), but its
+        # K/V reach the pages one pass later, written beside the next
+        # block's first denoising pass: until then it is the PENDING block,
+        # ``block_pending``, at [num_committed - B, num_committed), its ids
+        # the sequence's own tokens there. A request that ends with a
+        # block pending never writes it; a preemption forgets the flag
+        # with the pages (the tokens are re-prefilled like any other).
         self.block_length = block_length
         self.num_committed = 0
         self.block_ids: list[int] = []
         self.block_masked: list[bool] = []
         self.block_marks: list = []
         self.block_passes = 0
+        self.block_pending = False
         self.prompt_token_ids = list(prompt_token_ids)
         self.output_token_ids: list[int] = []
         self.output_logprobs: list[float] = []
@@ -139,12 +148,12 @@ class Sequence:
     def admit_tokens(self, end: Optional[int] = None) -> int:
         """The positions a prefill that ends at ``end`` (default: the whole
         of it) must hold pages for. A block model's LAST chunk also holds
-        them for the open block and the one after, which the first pass
-        beside it needs (``window_last_pos(1)``): a sequence admitted with
-        less would be preempted by its own first pass, for ever."""
+        them for the block beyond it, which the first pass beside it may
+        make whole (``window_last_pos(1)``): a sequence admitted with less
+        would be preempted by its own first pass, for ever."""
         whole, B = self.prefill_len, self.block_length
         end = whole if end is None else end
-        return end + 2 * B if B > 1 and end >= whole else end
+        return end + B if B > 1 and end >= whole else end
 
     def open_block(self) -> None:
         """The open block at ``num_committed``: what the sequence already
@@ -158,19 +167,21 @@ class Sequence:
         self.block_passes = 0
 
     def window_last_pos(self, passes: int, max_len: int) -> int:
-        """Highest position a step program of ``passes`` passes can write:
-        ``last_window_pos`` for one token a pass; for a block model the end
-        of the block that is open after the most commits the passes allow
-        (a block takes a denoising pass at least before its commit), the
-        passes in flight counted beside the program's own since
-        ``num_committed`` is as of the last fetch, capped by the model's
-        length and the last block this request can reach."""
+        """Highest position a step program of ``passes`` passes needs a
+        page for: ``last_window_pos`` for one token a pass; for a block
+        model the end of the last block the passes can make whole (every
+        pass may complete the open block, and the pass after it writes
+        it: the block pending at the program's end is written by the
+        next program's first pass, out of pages held already), the passes
+        in flight counted beside the program's own since ``num_committed``
+        is as of the last fetch, capped by the model's length and the last
+        block this request can reach."""
         B = self.block_length
         if B == 1:
             return self.last_window_pos(self.sched_tokens - 1, passes,
                                         max_len)
         passes += self.inflight_passes
-        end = self.num_committed + B * ((passes + 1) // 2 + 1)
+        end = self.num_committed + B * passes
         cap = -(-(self.num_prompt_tokens + self.params.max_tokens) // B) * B
         return min(end, max_len, cap) - 1
 

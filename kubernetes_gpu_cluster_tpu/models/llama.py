@@ -54,7 +54,8 @@ class StepMeta(NamedTuple):
       attends to what it already has in the pool.
     - The **row part** (``page_tables`` given) is the running sequences'
       tokens against their pages: ``forward``'s static ``row_width`` tokens
-      a sequence (1: decode; ``S = k + 1``: a slice of draft verification).
+      a sequence (1: decode; ``S = k + 1``: a slice of draft verification;
+      ``2 * block_length``: a block model's pass).
 
     The split is static per compiled shape: the row part is the last
     ``page_tables.shape[0] * row_width`` tokens. The two parts' sequences
@@ -85,6 +86,10 @@ class StepMeta(NamedTuple):
     # (padding rows: the scrap slot).
     seg_slots: Optional[jax.Array] = None
     row_slots: Optional[jax.Array] = None
+    # A block model's rows are two blocks wide, [the block awaiting its
+    # commit | the open block] (engine/block.py): [R] bool, False where a
+    # row has none awaiting and its second block is padding.
+    row_wide: Optional[jax.Array] = None
 
 
 # ---------------------------------------------------------------------------
@@ -1723,6 +1728,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     segment part, with history    ``kernels.chunk_attention``
     row part, ``row_width == 1``  ``kernels.decode_attention``
     row part, ``row_width > 1``   ``kernels.verify_attention``
+    row part, a block model's     ``kernels.block_attention``
     ============================  =======================================
 
     A part that is absent adds no operation: a pure decode or a pure
@@ -1797,11 +1803,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         # layer index go straight to the kernel: no per-layer pool slice is
         # ever materialised (see _layer_scan).
         if cfg.block_length > 1:
-            # A block model's pass: the row's open block over its pages.
+            # A block model's pass: the row's two blocks over its pages.
             with jax.named_scope("kgct.block.attend"):
                 return kernels.block_attention(
                     q, k, v, kv.k, kv.v, meta.page_tables,
-                    meta.context_lens, scale, layer=layer_idx)
+                    meta.context_lens, scale, layer=layer_idx,
+                    wide=meta.row_wide)
         if row_width > 1:
             return kernels.verify_attention(
                 q, k, v, kv.k, kv.v, meta.page_tables, meta.context_lens,
@@ -1839,10 +1846,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # rows count a context of 1 and pass for real, as before.)
     real = None
     if cfg.is_moe:
-        real = jnp.concatenate(
-            ([meta.seg_ids[:n_seg] >= 0] if n_seg else [])
-            + ([jnp.repeat(meta.context_lens > 0, row_width)]
-               if n_rows else []))
+        parts = [meta.seg_ids[:n_seg] >= 0] if n_seg else []
+        if n_rows:
+            parts.append(jnp.repeat(meta.context_lens > 0, row_width))
+        if meta.row_wide is not None:
+            # ... and of a block model's row its second block only where
+            # it has two.
+            parts[-1] &= (meta.row_wide[:, None] | (
+                jnp.arange(row_width) < cfg.block_length)).reshape(-1)
+        real = jnp.concatenate(parts)
     state_fn = None
     if cfg.has_state:
         def state_fn(lp, x, ssm, layer_idx):

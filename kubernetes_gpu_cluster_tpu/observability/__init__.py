@@ -271,18 +271,22 @@ class Observability:
         self.dsa_visible_tokens = 0
         self.dsa_chosen_tokens = 0
         # A block model (generation by diffusion over blocks), counted a
-        # ROW: the passes its rows took (a denoising pass or a commit), the
-        # commits among them, the tokens the passes transferred, the
-        # positions they computed (block_length a row-pass, padding rows
-        # apart), and the passes a committed block took, its commit
-        # included (engine/block.py).
+        # ROW: the denoising passes its rows took, those among them beside
+        # which a block's commit rode (the K/V of the block before, written
+        # in the same pass), the blocks committed (every commit rides a
+        # pass: the two are equal, their ratio is the mechanism's
+        # engagement), the tokens the passes transferred, the positions
+        # they computed (two blocks a row-pass, padding rows apart), and
+        # the passes a whole block took (engine/block.py).
         self.block_passes = 0
         self.block_commit_passes = 0
+        self.block_commits = 0
         self.block_tokens_transferred = 0
         self.block_positions_computed = 0
         self.block_passes_per_block = Histogram(
             "kgct_block_passes_per_block",
-            "passes a committed block took, its commit included",
+            "denoising passes a whole block took (its commit rides the "
+            "next block's first)",
             buckets=(1, 2, 3, 4, 5, 6, 8, 9, 12, 17, 33))
         # Speculative decoding: cumulative drafted vs accepted draft tokens
         # (bonus tokens excluded from both) — feeds the
@@ -838,16 +842,20 @@ class Observability:
         if self.block_passes:
             for name, help_, n in (
                     ("kgct_block_passes_total",
-                     "passes a block model's rows took (denoising or "
-                     "commit), counted a row", self.block_passes),
+                     "denoising passes a block model's rows took, counted "
+                     "a row", self.block_passes),
                     ("kgct_block_commit_passes_total",
-                     "of them, the commits (a block's K/V written)",
+                     "of them, those that carried a commit beside (the "
+                     "K/V of the block before, written in the same pass)",
                      self.block_commit_passes),
+                    ("kgct_block_commits_total",
+                     "blocks committed (their K/V written to the pages)",
+                     self.block_commits),
                     ("kgct_block_tokens_transferred_total",
                      "tokens the denoising passes transferred",
                      self.block_tokens_transferred),
                     ("kgct_block_positions_computed_total",
-                     "positions the passes computed (block_length a "
+                     "positions the passes computed (two blocks a "
                      "row-pass, padding rows apart)",
                      self.block_positions_computed)):
                 lines.append(f"# HELP {name} {help_}")

@@ -263,13 +263,17 @@ def spec_verify_attention_xla(
     scale: float,
     layer: Optional[jax.Array] = None,
     causal: bool = True,
+    block: Optional[int] = None,
 ) -> jax.Array:
     """Batched draft verification: B sequences, S = k+1 tokens each
     (``[last committed token, k drafts]``), every token attending to its
     sequence's paged-pool history PLUS the earlier slice tokens causally.
     ``causal=False`` is a block model's pass (``Kernels.block_attention``'s
-    XLA twin): the same gather with every token of the slice, a row's open
-    block, visible to every other.
+    XLA twin): the same gather with the slice's tokens under the
+    block-causal mask among themselves, key j visible to query i iff
+    ``j // block <= i // block`` (``block`` None or S: one block, every
+    token visible to every other; S = 2 blocks: the block awaiting its
+    commit never sees the open block behind it).
 
     This is ``paged_decode_attention_xla`` generalized from one query/row to
     S queries/row — the pool gather is identical; the "current token" term
@@ -314,6 +318,10 @@ def spec_verify_attention_xla(
     if causal:
         tril = jnp.tril(jnp.ones((S, S), bool))
         s_b = jnp.where(tril[None, None, None], s_b, -jnp.inf)
+    elif block is not None and block < S:
+        of = jnp.arange(S) // block
+        s_b = jnp.where((of[None, :] <= of[:, None])[None, None, None], s_b,
+                        -jnp.inf)
 
     s = jnp.concatenate([s_h, s_b], axis=-1)              # [B,n_kv,g,S,L+S]
     p = jax.nn.softmax(s, axis=-1)
@@ -572,19 +580,22 @@ class Kernels:
                                          layer=layer)
 
     def block_attention(self, q, k, v, k_pool, v_pool, page_tables,
-                        context_lens, scale, *, layer=None):
-        """A block model's pass: ``block`` query positions a sequence (its
-        open block) over its pages, read ONCE for all of them, plus the
-        block's own keys, every one visible to every other. The kernel
-        (``ops.pallas.block_attend``) or ``spec_verify_attention_xla``
-        with no in-slice mask."""
+                        context_lens, scale, *, layer=None, wide=None):
+        """A block model's pass: two blocks of query positions a sequence
+        ([the block awaiting its commit | the open block], or [the open
+        block | padding] where ``wide`` [B] is False) over its pages, read
+        ONCE for all of them, plus the sequence's own keys under the
+        block-causal mask. The kernel (``ops.pallas.block_attend``, which
+        computes nothing for a padding half) or
+        ``spec_verify_attention_xla``."""
         if not self.use_pallas:
             return spec_verify_attention_xla(
                 q, k, v, k_pool, v_pool, page_tables, context_lens, scale,
-                layer=layer, causal=False)
+                layer=layer, causal=False, block=self.block)
         from .pallas.block_attend import block_attend
         return block_attend(q, k, v, k_pool, v_pool, page_tables,
-                            context_lens, scale, layer=layer)
+                            context_lens, scale, layer=layer,
+                            block=self.block, wide=wide)
 
     def write_pages(self, kv_k, kv_v, k_all, v_all, slot_mapping):
         """The step's new rows into the donated pool, in place: the DMA

@@ -1,6 +1,7 @@
 """Generation by diffusion over blocks on the serving path (sdar_moe: a step
 that yields 0 to ``block_length`` tokens a row, block-causal attention, pages
-written at a block's commit), held to the plain float32 reference
+written at a block's commit, which rides the next block's first denoising
+pass), held to the plain float32 reference
 (perfbench/reference/sdar_moe.py: the ONE copy, the benchmark's, which also
 writes the cell's goldens) on ``debug-block-moe`` with seeded weights, B = 4.
 
@@ -100,8 +101,11 @@ def test_served_ids_and_logprobs_are_the_reference_s(params, steps):
     _held_to_reference(eng, params, prompts, [10, 7, 5, 12], cfg)
     obs = eng.obs
     assert obs.block_tokens_transferred >= 34
-    assert obs.block_positions_computed == B * obs.block_passes
-    assert 0 < obs.block_commit_passes < obs.block_passes
+    # two blocks a row-pass: [the block awaiting its commit | the open one]
+    assert obs.block_positions_computed == 2 * B * obs.block_passes
+    # every commit rode a denoising pass; no pass was a commit alone
+    assert 0 < obs.block_commits == obs.block_commit_passes \
+        < obs.block_passes
 
 
 @pytest.mark.parametrize("threshold", [0.0036, 0.0042])
@@ -114,8 +118,7 @@ def test_a_low_threshold_transfers_several_positions_a_pass(params, threshold):
     prompts = [_prompt(n, n) for n in (9, 14, 23, 32)]
     eng = _engine(params, cfg)
     _held_to_reference(eng, params, prompts, [12, 9, 11, 6], cfg)
-    per_pass = eng.obs.block_tokens_transferred / (
-        eng.obs.block_passes - eng.obs.block_commit_passes)
+    per_pass = eng.obs.block_tokens_transferred / eng.obs.block_passes
     assert 1.0 < per_pass < B, per_pass
 
 
@@ -201,6 +204,65 @@ def test_sampled_requests_are_reproducible_by_seed(params):
     assert c[0].output_token_ids != a
 
 
+# -- (h) the commit rides the next block's first denoising pass ---------------
+
+@pytest.mark.parametrize("steps,n_prompt,max_tokens,window", [
+    (1, 8, 16, 4),      # a block whole in ONE pass: every pass completes one
+    (1, 20, 24, 8),     # ... six of them, in one window
+    (4, 8, 8, 4),       # the floor, a request that ends on a block's edge
+    (4, 12, 16, 4),     # ... whose blocks turn whole in a window's LAST pass
+    (2, 8, 12, 8)])     # two positions a pass
+def test_a_commit_rides_the_pass_behind_it_and_the_last_block_has_none(
+        params, steps, n_prompt, max_tokens, window):
+    """One request from a block's edge to a block's edge, at the sampler's
+    floor of each ``denoising_steps``: every pass transfers
+    ``B / steps`` positions, so ``block_tokens_transferred / block_passes``
+    is exactly that (1.0 at ``steps`` 4, where a commit as a pass of its
+    own read 0.8), every whole block but the LAST is written, each beside
+    the pass behind the one that made it whole, and the last, pending at
+    the request's end, is never written: its pages are freed with the
+    rest."""
+    cfg = CFG.replace(denoising_steps=steps)
+    eng = _engine(params, cfg, decode_window=window)
+    recs, orig = [], eng.obs.on_step
+    eng.obs.on_step = lambda rec: (recs.append(rec), orig(rec))[1]
+    _held_to_reference(eng, params, [_prompt(n_prompt, 5)], [max_tokens], cfg)
+    obs, per_pass = eng.obs, B // steps
+    assert obs.block_tokens_transferred == max_tokens
+    assert obs.block_passes == -(-max_tokens // per_pass)
+    written = max_tokens // B - 1       # every block it filled but the last
+    assert obs.block_commits == obs.block_commit_passes == written
+    assert obs.block_positions_computed == 2 * B * obs.block_passes
+    assert obs.block_passes_per_block.count == written
+    assert obs.block_passes_per_block.sum == written * steps
+    alloc = eng.scheduler.allocator
+    assert alloc.num_free == alloc.num_pages - 1
+    # no pass of any program was a commit alone: each yielded its tokens
+    assert all(r["tokens"] == r["passes"] * per_pass for r in recs
+               if r.get("mode") == "block")
+
+
+@pytest.mark.parametrize("window", [4, 8])
+def test_a_preemption_with_a_block_pending_serves_the_same_generation(
+        params, window):
+    """Prompts on a block's edge at the floor and a window of a multiple of
+    B passes: every program ends with each row's block just made whole and
+    PENDING (its K/V in no page). A pool too small for four sequences
+    preempts some of them so: the pending block's ids are tokens that have
+    left, they are re-prefilled like any other, and the generation is the
+    unpreempted one's (the reference's), token for token."""
+    eng = _engine(params, pages=9, decode_window=window)
+    pending, requeue = [], eng.scheduler._requeue_for_recompute
+    eng.scheduler._requeue_for_recompute = lambda seq, **how: (
+        pending.append(seq.block_pending), requeue(seq, **how))[1]
+    prompts = [_prompt(32, s) for s in (1, 2, 3, 4)]
+    _held_to_reference(eng, params, prompts, [40, 40, 40, 40])
+    assert eng.scheduler.num_preemptions > 0 and any(pending)
+    alloc = eng.scheduler.allocator
+    assert alloc.num_free == alloc.num_pages - 1
+    assert not any(s.block_pending for s in eng.scheduler.waiting)
+
+
 # -- (g) programs behind one another: pages for what is in flight -------------
 
 def test_an_exactly_sized_pool_serves_with_passes_in_flight(
@@ -216,7 +278,7 @@ def test_an_exactly_sized_pool_serves_with_passes_in_flight(
     lens, max_tokens = (30, 21, 13, 38), (40, 23, 30, 26)
     prompts = [_prompt(n, n) for n in lens]
     pages = 1 + sum(
-        -(-max(n - n % B + 2 * B, -(-(n + m) // B) * B) // PS)
+        -(-max(n - n % B + B, -(-(n + m) // B) * B) // PS)
         for n, m in zip(lens, max_tokens))
     eng = _engine(params, pages=pages, decode_window=4)
     assert eng._sanitizer is not None
@@ -241,10 +303,11 @@ def test_an_exactly_sized_pool_serves_with_passes_in_flight(
 
 
 @pytest.mark.parametrize("inflight,passes,committed,max_tokens,want", [
-    (0, 8, 8, 64, 8 + 4 * 5 - 1),       # nothing in flight: as scheduled
-    (8, 8, 8, 64, 8 + 4 * 9 - 1),       # a window behind a window
-    (1, 8, 8, 64, 8 + 4 * 6 - 1),       # a window behind a mixed step
-    (8, 1, 8, 64, 8 + 4 * 6 - 1),       # a mixed step behind a window
+    # every pass may make a block whole (and the pass behind it writes it)
+    (0, 8, 8, 64, 8 + 4 * 8 - 1),       # nothing in flight: as scheduled
+    (8, 8, 8, 64, 8 + 4 * 16 - 1),      # a window behind a window
+    (1, 8, 8, 64, 8 + 4 * 9 - 1),       # a window behind a mixed step
+    (8, 1, 8, 64, 8 + 4 * 9 - 1),       # a mixed step behind a window
     (8, 8, 8, 5, 15),                   # the last block the request reaches
     (8, 8, 496, 1000, 511)])            # the model's length
 def test_pages_are_held_for_what_the_passes_in_flight_may_commit(
@@ -317,20 +380,24 @@ def test_health_and_metrics_name_the_block_mechanism(params):
                  SamplingParams(max_tokens=8, temperature=0.0))
     text = "\n".join(eng.obs.render_prometheus())
     for name in ("kgct_block_passes_total", "kgct_block_commit_passes_total",
+                 "kgct_block_commits_total",
                  "kgct_block_tokens_transferred_total",
                  "kgct_block_positions_computed_total",
                  "kgct_block_passes_per_block_bucket"):
         assert f"\n{name}" in text, name
-    # random weights: one position a pass, a block is 4 passes and a commit
+    # random weights: one position a pass, a block is 4 passes, and its
+    # commit rides the fifth (the next block's first): every commit rode
     assert 16 <= eng.obs.block_tokens_transferred <= 16 + 2 * (B - 1)
-    assert eng.obs.block_tokens_transferred == (
-        eng.obs.block_passes - eng.obs.block_commit_passes)
+    assert eng.obs.block_tokens_transferred == eng.obs.block_passes
+    assert 0 < eng.obs.block_commits == eng.obs.block_commit_passes
     windows = [r for r in recs if r["kind"] == "decode"]
     assert windows and all(r["mode"] == "block" for r in windows)
     assert sum(r["passes"] for r in recs if "passes" in r) \
         == eng.obs.block_passes
-    assert all(r["positions"] == B * r["passes"] for r in recs
+    assert all(r["positions"] == 2 * B * r["passes"] for r in recs
                if "passes" in r)
+    assert sum(r["commit_passes"] for r in recs if "passes" in r) \
+        == eng.obs.block_commits
     assert sum(r["tokens"] for r in windows) <= eng.obs.block_tokens_transferred
     # a prefill of a block model samples nothing
     assert all(r["new_tokens"] == 0 for r in recs if r["kind"] == "prefill")

@@ -67,14 +67,89 @@ def test_block_attend_interpreted_is_its_xla_twin(rows, ctx, causal):
     want = att.spec_verify_attention_xla(*args, layer=jnp.int32(1),
                                          causal=causal)
     got = block_attend(*args, layer=jnp.int32(1), interpret=True,
-                       causal=causal)
+                       own="causal" if causal else "block")
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     if not causal and rows > 1:
         # the planted fault (a causal mask inside the block) is not a
         # rounding: a row with little history moves by O(0.1)
         wrong = block_attend(*args, layer=jnp.int32(1), interpret=True,
-                             causal=True)
+                             own="causal")
         assert float(jnp.max(jnp.abs(wrong - want))) > 1e-2
+
+
+def _materialised(q, kk, vv, kp, vp, pt, ctx, scale, layer, block):
+    """A sequence's S queries over [its pool history | its own S keys]
+    under a mask written out whole, in float64 on the host: key j of the
+    own ones visible to query i iff ``j // block <= i // block``."""
+    rows, S = pt.shape[0], q.shape[0] // pt.shape[0]
+    nh, nkv, hd = q.shape[1], kk.shape[1], q.shape[2]
+    q, kk, vv = (np.asarray(a, np.float64).reshape(rows, S, -1, hd)
+                 for a in (q, kk, vv))
+    kp, vp = (np.asarray(a[layer], np.float64) for a in (kp, vp))
+    out = np.zeros((rows, S, nh, hd))
+    for r in range(rows):
+        n = max(int(ctx[r]) - 1, 0)
+        keys = np.concatenate(
+            [kp[np.asarray(pt[r])].reshape(-1, nkv, hd)[:n], kk[r]])
+        vals = np.concatenate(
+            [vp[np.asarray(pt[r])].reshape(-1, nkv, hd)[:n], vv[r]])
+        mask = np.ones((S, n + S), bool)
+        mask[:, n:] = (np.arange(S)[None, :] // block
+                       <= np.arange(S)[:, None] // block)
+        for h in range(nh):
+            sc = np.einsum("sd,td->st", q[r, :, h],
+                           keys[:, h // (nh // nkv)]) * scale
+            sc = np.where(mask, sc, -np.inf)
+            p = np.exp(sc - sc.max(axis=-1, keepdims=True))
+            out[r, :, h] = (p / p.sum(-1, keepdims=True)) \
+                @ vals[:, h // (nh // nkv)]
+    return out.reshape(rows * S, nh, hd)
+
+
+@pytest.mark.parametrize("rows,ctx,wide", [
+    (3, (1, 37, 0), (1, 0, 1)), (4, (17, 16, 65, 2), (0, 1, 1, 0)),
+    (2, (90, 33), (1, 1)), (1, (1,), (0,))])
+@pytest.mark.parametrize("width", [B, 2 * B])
+def test_a_row_of_two_blocks_is_the_materialised_block_causal_mask(
+        rows, ctx, wide, width):
+    """The XLA twin's ``block=`` and the kernel (interpreted) at one block
+    a sequence and at two, against a mask written out whole: at two, the
+    block awaiting its commit never sees the open block behind it; a
+    sequence without a second block (``wide`` 0) gets zeros there from the
+    kernel and its first block is untouched by that; and the planted fault
+    (the first block sees the second) is no rounding."""
+    nh, nkv, hd, P, pps, L = 4, 2, 32, 24, 6, 2
+    k = jax.random.split(jax.random.key(rows + width), 6)
+    q = jax.random.normal(k[0], (rows * width, nh, hd))
+    kk = jax.random.normal(k[1], (rows * width, nkv, hd))
+    vv = jax.random.normal(k[2], (rows * width, nkv, hd))
+    kp = jax.random.normal(k[3], (L, P, PS, nkv * hd))
+    vp = jax.random.normal(k[4], (L, P, PS, nkv * hd))
+    pt = jnp.asarray(np.random.default_rng(0).integers(1, P, (rows, pps)),
+                     jnp.int32)
+    ctx = jnp.asarray(ctx, jnp.int32)
+    args = (q, kk, vv, kp, vp, pt, ctx, hd ** -0.5)
+    want = _materialised(*args, 1, B)
+    twin = att.spec_verify_attention_xla(*args, layer=jnp.int32(1),
+                                         causal=False, block=B)
+    assert float(np.max(np.abs(np.asarray(twin) - want))) < 1e-5
+    got = np.asarray(block_attend(*args, layer=jnp.int32(1), interpret=True,
+                                  block=B))
+    assert float(np.max(np.abs(got - want))) < 1e-5
+    if width == B:
+        return
+    skipped = np.asarray(block_attend(
+        *args, layer=jnp.int32(1), interpret=True, block=B,
+        wide=jnp.asarray(wide))).reshape(rows, 2, B, nh, hd)
+    got = got.reshape(skipped.shape)
+    for r, w in enumerate(wide):
+        assert (skipped[r, 0] == got[r, 0]).all()
+        assert (skipped[r, 1] == (got[r, 1] if w else 0)).all()
+    wrong = np.asarray(block_attend(*args, layer=jnp.int32(1),
+                                    interpret=True, block=B, own="all"))
+    wrong, want = (a.reshape(rows, 2, -1) for a in (wrong, want))
+    assert float(np.max(np.abs(wrong - want)[:, 0])) > 1e-2
+    assert float(np.max(np.abs(wrong - want)[:, 1])) < 1e-5
 
 
 @pytest.mark.parametrize("T,segs", [(64, (24, 40)), (32, (32,)),
@@ -188,8 +263,8 @@ def test_a_prefill_computes_whole_blocks(n_prompt, outputs, want):
 
 
 @pytest.mark.parametrize("passes,committed,max_tokens,want", [
-    (8, 8, 64, 8 + 4 * 5 - 1),      # four commits at the most, and one open
-    (1, 8, 64, 8 + 4 * 2 - 1),      # a mixed step: one pass
+    (8, 8, 64, 8 + 4 * 8 - 1),      # every pass may make a block whole
+    (1, 8, 64, 8 + 4 * 1 - 1),      # a mixed step: one pass
     (8, 8, 5, 15),                  # the last block this request reaches
     (8, 504, 1000, 511)])           # the model's length
 def test_pages_are_held_for_every_block_a_program_can_commit(

@@ -426,26 +426,79 @@ def test_glm_chunk_step_is_built_for_the_servers_16_seats(one_chip,
             assert shape not in text, shape
 
 
+@pytest.mark.parametrize("S", [4, 8])
 def test_block_attend_compiles_for_a_v5e_at_the_cell_s_shapes(
-        one_chip, no_compile_cache):
-    """sdar-30b-a3b-chat's pass at the served cut: 64 rows of 4 positions,
-    32 q / 4 kv heads of 128, over a 6-layer bf16 pool of 2049 pages under
-    a dynamic layer index, tables 32 pages wide. The pool is read where it
-    lies: nothing of its size is copied."""
+        one_chip, no_compile_cache, S):
+    """sdar-30b-a3b-chat's pass at the served cut: 64 rows of 8 positions
+    (two blocks of 4: the block awaiting its commit and the open block; 4:
+    one block, what the kernel check times beside it), 32 q / 4 kv heads of
+    128, over a 6-layer bf16 pool of 2049 pages under a dynamic layer
+    index, tables 32 pages wide. The pool is read where it lies: nothing
+    of its size is copied."""
     from kubernetes_gpu_cluster_tpu.ops.pallas.block_attend import (
         block_attend)
 
     def arr(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    R, S, nh, nkv, hd = 64, 4, 32, 4, 128
+    R, nh, nkv, hd = 64, 32, 4, 128
     pool = arr((6, 2049, 128, nkv * hd))
-    compiled = jax.jit(lambda q, k, v, kp, vp, tb, ctx, lyr: block_attend(
-        q, k, v, kp, vp, tb, ctx, hd ** -0.5, layer=lyr)).lower(
+    compiled = jax.jit(lambda q, k, v, kp, vp, tb, ctx, lyr, wide:
+                       block_attend(q, k, v, kp, vp, tb, ctx, hd ** -0.5,
+                                    layer=lyr, block=4, wide=wide)).lower(
         arr((R * S, nh, hd)), arr((R * S, nkv, hd)), arr((R * S, nkv, hd)),
         pool, pool, arr((R, 32), jnp.int32), arr((R,), jnp.int32),
-        arr((1,), jnp.int32)).compile()
+        arr((1,), jnp.int32), arr((R,), jnp.int32)).compile()
     assert "%block_attend" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2**22
+
+
+def test_block_window_compiles_for_a_v5e_at_the_cell_s_cut(one_chip,
+                                                          no_compile_cache):
+    """The WHOLE window program of ``sdar-30b-a3b-chat-bf16.batch-decode-2k``
+    (6 layers of 128 experts, 64 seats, 4096 positions, 2049 pages, 8
+    passes), every kernel on: a pass is 64 rows of TWO blocks (512
+    positions through the layers), and the head and the sampler see the
+    open block's 256 alone. Its scratch stays inside what the engine sets
+    aside for a step."""
+    import numpy as np
+
+    from kubernetes_gpu_cluster_tpu.engine import block as block_steps
+    from kubernetes_gpu_cluster_tpu.engine.engine import (
+        LLMEngine, _pack_float_b, step_workspace_bytes)
+    from kubernetes_gpu_cluster_tpu.engine.kv_cache import allocate_kv_cache
+    from kubernetes_gpu_cluster_tpu.engine.scheduler import Scheduler
+    from kubernetes_gpu_cluster_tpu.models import llama as model_lib
+    from kubernetes_gpu_cluster_tpu.ops.attention import Kernels
+
+    cfg = _cut("sdar-30b-a3b-chat", {"num_hidden_layers": 6}, 64, 4096, 2049)
+    model = cfg.model
+    batch = Scheduler(cfg, 2049).decode_batch([], 64)
+    assert batch.block.shape == (64, block_steps.state_width(4) + 2)
+    shell = object.__new__(LLMEngine)
+    shell.config, shell.model_config, shell.mesh = cfg, model, None
+    shell.kernels = Kernels(use_pallas=True, use_pallas_hist=True,
+                            grouped_experts=True, block=model.block_length)
+    shell._last_width = 64
+    window, _ = block_steps.build_block_fns(shell)
+    args = (jax.eval_shape(
+                lambda: model_lib.init_params(model, jax.random.key(0))),
+            jax.eval_shape(
+                lambda: allocate_kv_cache(model, cfg.cache, 2049, None, 0)),
+            np.zeros((64, block_steps.state_width(4)), np.int32),
+            np.concatenate([batch.block, np.stack(
+                [batch.top_k, batch.seed, batch.top_n], axis=1),
+                batch.page_tables], axis=1),
+            _pack_float_b(batch), jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = window.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        args)).compile()
+    text = compiled.as_text()
+    for name in ("block_attend", "grouped_matmul", "kv_write"):
+        assert f"%{name}." in text, name
+    assert "[512,2048]" in text and "[256,151936]" in text
+    assert "[512,151936]" not in text
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < step_workspace_bytes(cfg))
 
 
 @pytest.mark.parametrize("kernel", ["flash_prefill", "flash_prefill_hist"])

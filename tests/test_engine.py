@@ -561,12 +561,24 @@ QUEUE_CASES = {
             [SamplingParams(max_tokens=18 + i, temperature=0.8, top_k=20,
                             seed=11 + i, logprobs=True, top_logprobs=5)
              for i in range(5)])),
-    # prompts of one length modulo the block, a position a pass: the rows
-    # end in one pass, and both loops build the very same batches
-    "block_same_batches_bitwise": lambda: dict(
+    # prompts on a block's edge, a position a pass, a window of block_length
+    # passes: every row's block turns whole (PENDING: its K/V in no page) in
+    # the LAST pass of a program and is written by the FIRST pass of the
+    # next, which reads the flag and the ids from the final state handed on
+    "block_pending_across_programs": lambda: dict(
         model=_BLOCK,
         arrivals=[(0, f"r{i}", p, SamplingParams(
             max_tokens=20, temperature=0.0, logprobs=True, top_logprobs=5))
+            for i, p in enumerate((_P[1], _P[4]))],
+        pending_across=True, bitwise=True),
+    # prompts of one length modulo the block, a position a pass, outputs
+    # that end on a block's edge (whatever the order of the last block's
+    # transfers): the rows end in one pass, and both loops build the very
+    # same batches
+    "block_same_batches_bitwise": lambda: dict(
+        model=_BLOCK,
+        arrivals=[(0, f"r{i}", p, SamplingParams(
+            max_tokens=19, temperature=0.0, logprobs=True, top_logprobs=5))
             for i, p in enumerate((_P[0], _P[2]))],
         bitwise=True),
 }
@@ -589,11 +601,17 @@ def test_device_queue_matches_chain_broken_every_step(case):
     # generation of the model; tests/test_block_diffusion.py). Such a row
     # is held to its finish reason and length; every other row, preempted
     # or not, to every id.
-    redrawn = set()
+    redrawn, pending, commits = set(), {}, {}
     for mode in ("queued", "broken"):
         eng = _queue_engine(**kw)
         if mode == "broken":
             eng._chain_break = lambda pred: "forced"
+
+        def fill(seqs, R, _fill=eng.scheduler.fill_block_rows, mode=mode):
+            pending[mode] = pending.get(mode, 0) + sum(
+                s.block_pending for s in seqs)
+            return _fill(seqs, R)
+        eng.scheduler.fill_block_rows = fill
 
         def requeue(seq, _requeue=eng.scheduler._requeue_for_recompute,
                     **how):
@@ -602,6 +620,7 @@ def test_device_queue_matches_chain_broken_every_step(case):
             return _requeue(seq, **how)
         eng.scheduler._requeue_for_recompute = requeue
         results[mode] = _drive(eng, spec["arrivals"], spec.get("aborts", ()))
+        commits[mode] = eng.obs.block_commits
         alloc = eng.scheduler.allocator
         assert alloc.num_free == alloc.num_pages - 1, (mode, "pages leaked")
         assert not eng._deferred_release and eng._inflight is None
@@ -618,6 +637,11 @@ def test_device_queue_matches_chain_broken_every_step(case):
             assert behind == 0 and eng.obs.chain_breaks["forced"] > 0
     queued, broken = results["queued"], results["broken"]
     assert queued.keys() == broken.keys()
+    if spec.get("pending_across"):
+        # fetched first, the host sees every program start on pending rows;
+        # chained, the device hands the flag on: the same blocks are written
+        assert pending["broken"] >= commits["broken"] - 1 > 0
+        assert commits["queued"] == commits["broken"]
     aborted = {rid for _, rid in spec.get("aborts", ())}
     for rid in queued:
         if rid in aborted:
